@@ -12,7 +12,7 @@ has a hardware advantage. On-chip, the honest external anchors stay the
 published per-chip numbers quoted in docs/perf.md (no GPU here, and
 torch_xla is not in the image — recorded in docs/perf.md per VERDICT r04 #9).
 
-    TNN_PLATFORM=cpu python -m benchmarks.torch_ab [--batch 32] [--iters 8]
+    JAX_PLATFORMS=cpu python -m benchmarks.torch_ab [--batch 32] [--iters 8]
 
 Prints one JSON row per framework plus a ratio row; wall-parity within ~2x is
 the expectation (different compilers, same math), gross divergence flags a
@@ -134,7 +134,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    from tnn_tpu.utils.platform import apply_env_platform
-
-    apply_env_platform()
     main()

@@ -8,8 +8,8 @@ benchmarks/results/. This is the functional-correctness evidence behind the
 flagship pipeline (round-3 artifact: rel_diff <= 6e-5 at v=1); --virtual 2
 exercises the interleaved schedule on the same model (round-4, VERDICT #3).
 
-    TNN_PLATFORM=cpu TNN_NUM_DEVICES=8 python scripts/pipeline_equivalence.py \
-        --virtual 2 --steps 3
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python scripts/pipeline_equivalence.py --virtual 2 --steps 3
 
 Runs anywhere; the committed artifacts come from the virtual 8-device CPU
 mesh (numerics are platform-independent at f32) and chip runs when available.
@@ -28,10 +28,6 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_cpu_collective_call_warn_stuck_timeout_seconds=600"
     + " --xla_cpu_collective_call_terminate_timeout_seconds=3600")
-
-from tnn_tpu.utils.platform import apply_env_platform
-
-apply_env_platform()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

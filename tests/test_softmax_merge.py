@@ -100,9 +100,9 @@ def test_merge_psum_matches_merge(rng):
         out = sm.finalize(m, l, acc)
         return sm.merge_psum(out, m, l, "seq")[None]
 
-    out = mesh_lib.shard_map_unchecked(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=(P("seq"), P("seq")),
-        out_specs=P("seq"))(lgs, vs)
+        out_specs=P("seq"), check_vma=False)(lgs, vs)
     states = [_state(lgs[i], vs[i]) for i in range(sp)]
     want = states[0]
     for s in states[1:]:

@@ -13,11 +13,7 @@ import argparse
 import json
 
 
-from tnn_tpu.utils.platform import apply_env_platform  # noqa: E402
-
-apply_env_platform()  # TNN_PLATFORM=cpu routes around the pinned TPU platform
-
-from tnn_tpu.distributed import Coordinator  # noqa: E402
+from tnn_tpu.distributed import Coordinator
 
 
 from tnn_tpu.cli import console_entry

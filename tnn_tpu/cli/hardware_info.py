@@ -8,12 +8,8 @@ import argparse
 import json
 
 
-from tnn_tpu.utils.platform import apply_env_platform  # noqa: E402
-
-apply_env_platform()  # TNN_PLATFORM=cpu routes around the pinned TPU platform
-
-from tnn_tpu.utils import affinity  # noqa: E402
-from tnn_tpu.utils.hardware import (cpu_topology, device_info,  # noqa: E402
+from tnn_tpu.utils import affinity
+from tnn_tpu.utils.hardware import (cpu_topology, device_info,
                                     hbm_stats, memory_usage_kb)
 
 

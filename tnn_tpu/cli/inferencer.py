@@ -11,18 +11,14 @@ import argparse
 import time
 
 
-from tnn_tpu.utils.platform import apply_env_platform  # noqa: E402
+import jax
+import numpy as np
 
-apply_env_platform()  # TNN_PLATFORM=cpu routes around the pinned TPU platform
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-
-from tnn_tpu import checkpoint as ckpt_lib  # noqa: E402
-from tnn_tpu import models  # noqa: E402
-from tnn_tpu.data import factory  # noqa: E402
-from tnn_tpu.data.loader import SyntheticDataLoader, prefetch  # noqa: E402
-from tnn_tpu.train import make_predict  # noqa: E402
+from tnn_tpu import checkpoint as ckpt_lib
+from tnn_tpu import models
+from tnn_tpu.data import factory
+from tnn_tpu.data.loader import SyntheticDataLoader, prefetch
+from tnn_tpu.train import make_predict
 
 
 from tnn_tpu.cli import console_entry

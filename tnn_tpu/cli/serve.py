@@ -52,20 +52,18 @@ import signal
 import sys
 
 
-from tnn_tpu.utils.platform import apply_env_platform  # noqa: E402
+import jax
+import numpy as np
 
-apply_env_platform()  # TNN_PLATFORM=cpu routes around the pinned TPU platform
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-
-from tnn_tpu import checkpoint as ckpt_lib  # noqa: E402
-from tnn_tpu import models  # noqa: E402
-from tnn_tpu.data.tokenizer import Tokenizer  # noqa: E402
-from tnn_tpu.profiling.profiler import Profiler  # noqa: E402
-from tnn_tpu.serving import (AdmissionRejected, EngineSupervisor,  # noqa: E402
+from tnn_tpu import checkpoint as ckpt_lib
+from tnn_tpu import models
+from tnn_tpu.data.tokenizer import Tokenizer
+from tnn_tpu.profiling.profiler import Profiler
+from tnn_tpu.serving import (AdmissionRejected, EngineSupervisor,
                              InferenceEngine, Router, ShuttingDown,
                              run_server)
+from tnn_tpu.utils import compile_cache
+from tnn_tpu.utils.hardware import device_line
 
 
 from tnn_tpu.cli import console_entry
@@ -76,8 +74,20 @@ def _emit(obj):
     sys.stdout.flush()
 
 
-def _stdin_ready(timeout: float) -> bool:
-    return bool(select.select([sys.stdin], [], [], timeout)[0])
+def _read_stdin_lines(fd: int, pending: bytes, timeout: float):
+    """Lines that arrived on ``fd`` within ``timeout``: (lines, pending, eof).
+
+    Reads the descriptor itself. A buffered ``readline()`` pulls every line
+    the client has sent so far into Python's buffer, where ``select`` cannot
+    see them: a client that wrote two requests at once would have its second
+    one sit there until it wrote again."""
+    if not select.select([fd], [], [], timeout)[0]:
+        return [], pending, False
+    chunk = os.read(fd, 1 << 16)
+    if not chunk:           # EOF: an unterminated last line still counts
+        return ([pending] if pending else []), b"", True
+    *lines, pending = (pending + chunk).split(b"\n")
+    return lines, pending, False
 
 
 def main(argv=None):
@@ -137,7 +147,9 @@ def main(argv=None):
                          "sp=1. Requires sp <= device count; pick ONE of "
                          "--sp / --tp per replica")
     ap.add_argument("--compile-cache", default="",
-                    help="persistent XLA compilation cache directory: step "
+                    help="persistent XLA compilation cache directory "
+                         "(default <checkout>/.jax_cache; ignored when "
+                         "JAX_COMPILATION_CACHE_DIR is set): step "
                          "programs compiled on a previous run (or by a "
                          "sibling replica on shared storage) are reloaded "
                          "instead of recompiled, cutting restart and "
@@ -389,6 +401,11 @@ def main(argv=None):
                      "--num-blocks/--block-size) so ceil(max_seq_len / "
                      "block_size) is a multiple of sp")
 
+    # before the first compile (the weight init below is one)
+    cache_dir = compile_cache.enable(args.compile_cache or None)
+    print(f"compile cache: {compile_cache.describe(cache_dir)}",
+          file=sys.stderr)
+
     if params is None:
         print(f"no --model-file: random-weight {args.model} "
               "(smoke/benchmark mode)", file=sys.stderr)
@@ -404,6 +421,13 @@ def main(argv=None):
               "draft checkpoint for real acceptance rates)", file=sys.stderr)
 
     profilers = []
+
+    # a fleet of single-chip replicas spreads over the chips: replica i on
+    # local device i (wrapping when there are more replicas than chips). A
+    # tp/sp replica spans its own mesh, and one device leaves nothing to place
+    devices = jax.local_devices()
+    spread = (args.replicas > 1 or autoscale is not None) \
+        and args.tp == 1 and args.sp == 1 and len(devices) > 1
 
     def build_engine(idx=0):
         prof = None
@@ -431,7 +455,8 @@ def main(argv=None):
             overlap=not args.no_overlap,
             kv_dtype=args.kv_dtype, quant_weights=args.quant_weights,
             tp=args.tp, sp=args.sp, host_tier_bytes=args.host_tier_bytes,
-            seed=args.seed)
+            seed=args.seed,
+            device=devices[idx % len(devices)] if spread else None)
 
     def build_supervisor(eng, idx=0):
         # each replica dumps into its own subdirectory so the per-reason
@@ -444,16 +469,12 @@ def main(argv=None):
             drain_deadline_s=args.drain_deadline_s or None,
             flight_dir=flight_dir)
 
-    if args.compile_cache:
-        from tnn_tpu.serving import compile_cache
-
-        cache_dir = compile_cache.enable(args.compile_cache)
-        warm = compile_cache.entry_count(cache_dir)
-        print(f"compile cache: {cache_dir} "
-              f"({'warm, %d entries' % warm if warm else 'cold'})",
-              file=sys.stderr)
-
     engine = build_engine()
+    # one line saying where this process really runs and which decode path
+    # the engine resolved: a server that landed on the CPU, interprets its
+    # kernels or fell off the paged path must not look like one that did not
+    print(f"tnn-serve: {device_line()} "
+          f"decode_path={engine.stats()['decode_path']}", file=sys.stderr)
     if args.host_tier_bytes:
         print(f"host KV tier: {args.host_tier_bytes} bytes, verified "
               "re-admission (corrupt blocks degrade to uncached misses)",
@@ -505,7 +526,9 @@ def main(argv=None):
             handoff_kv=not args.no_handoff_kv,
             fleet_prefix=args.fleet_prefix,
             seed=args.seed, profiler=router_prof)
-        print(f"router: {n0} supervised replicas", file=sys.stderr)
+        print(f"router: {n0} supervised replicas"
+              + (f", one per device over {len(devices)} devices" if spread
+                 else ""), file=sys.stderr)
         if roles is not None:
             kv = "recompute-resume only" if args.no_handoff_kv \
                 else "verified KV-block handoff"
@@ -651,21 +674,20 @@ def _serve_stdin(supervisor, model, tokenizer, args):
         pass  # not the main thread (embedded use): signals stay external
 
     try:
-        eof = False
+        fd, pending, eof = sys.stdin.fileno(), b"", False
         while not supervisor.finished:
             flush_events()
             if eof or supervisor.draining:
                 supervisor.join(0.05)  # drain in progress: just wait
                 continue
-            if _stdin_ready(0.05):
-                line = sys.stdin.readline()
-                if not line:
-                    eof = True
-                    # EOF drains: in-flight work finishes instead of being
-                    # dropped on the floor by a process exit
-                    supervisor.request_drain("stdin EOF")
-                elif line.strip():
-                    handle_line(line)
+            lines, pending, eof = _read_stdin_lines(fd, pending, 0.05)
+            for raw in lines:
+                if raw.strip():
+                    handle_line(raw.decode(errors="replace"))
+            if eof:
+                # EOF drains: in-flight work finishes instead of being
+                # dropped on the floor by a process exit
+                supervisor.request_drain("stdin EOF")
         flush_events()
         # finished flips before the worker threads (replicas + router
         # monitor) run their last instructions; exiting the interpreter

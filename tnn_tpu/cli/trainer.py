@@ -13,16 +13,14 @@ for smoke runs and benchmarks).
 import argparse
 
 
-from tnn_tpu.utils.platform import apply_env_platform  # noqa: E402
-
-apply_env_platform()  # TNN_PLATFORM=cpu routes around the pinned TPU platform
-
-from tnn_tpu import models  # noqa: E402
-from tnn_tpu.data import factory  # noqa: E402
-from tnn_tpu.data.loader import SyntheticDataLoader  # noqa: E402
-from tnn_tpu.train import train_model  # noqa: E402
-from tnn_tpu.utils.config import TrainingConfig  # noqa: E402
-from tnn_tpu.utils.env import load_env_file  # noqa: E402
+from tnn_tpu import models
+from tnn_tpu.data import factory
+from tnn_tpu.data.loader import SyntheticDataLoader
+from tnn_tpu.train import train_model
+from tnn_tpu.utils import compile_cache
+from tnn_tpu.utils.config import TrainingConfig
+from tnn_tpu.utils.env import load_env_file
+from tnn_tpu.utils.hardware import device_line
 
 
 def build_loaders(cfg: TrainingConfig, synthetic_classes: int):
@@ -111,6 +109,11 @@ def main(argv=None):
         os.makedirs(d, exist_ok=True)
         open(args.history_out, "a").close()
 
+    cache_dir = compile_cache.enable()
+    # where this process really runs: a trainer that landed on the CPU must
+    # not look like one that did not
+    print(f"tnn-trainer: {device_line()} "
+          f"compile_cache={compile_cache.describe(cache_dir)}")
     model = models.create(cfg.model_name)
     train_loader, val_loader = build_loaders(cfg, args.num_classes)
     state, history = train_model(model, cfg, train_loader, val_loader)

@@ -18,8 +18,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-NEG_INF = jnp.float32(-1e30)  # large-negative beats -inf: 0*inf NaN hazards
+# large-negative beats -inf: 0*inf NaN hazards. A NumPy scalar, not a jnp one:
+# a device array at import time would initialize the backend (and take the
+# chip) in every process that merely imports the serving package.
+NEG_INF = np.float32(-1e30)
 
 
 def _is_perrow(x) -> bool:

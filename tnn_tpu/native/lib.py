@@ -1,9 +1,10 @@
-"""Load (building if needed) libtnn_host.so."""
+"""Build (incrementally, through make) and load libtnn_host.so."""
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -16,8 +17,8 @@ _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 # TNN_NATIVE_LIB points at an alternative .so — used to run the suite against
 # the sanitizer builds (native/build-debug, native/build-tsan) or an installed
 # layout where native/ is not a sibling of the package
-_SO_PATH = os.environ.get("TNN_NATIVE_LIB") or os.path.join(
-    _NATIVE_DIR, "build", "libtnn_host.so")
+_SO_OVERRIDE = os.environ.get("TNN_NATIVE_LIB")
+_SO_PATH = _SO_OVERRIDE or os.path.join(_NATIVE_DIR, "build", "libtnn_host.so")
 
 
 def build_native(force: bool = False) -> str:
@@ -100,7 +101,7 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.tnn_decode_png_batch.argtypes = [p(c.c_char_p), i64, c.c_int, c.c_int,
                                          p(u8), p(u8)]
     # unified PNG+JPEG entry (declared here so a stale .so without the symbol
-    # raises AttributeError and triggers get_lib()'s force-rebuild path)
+    # raises AttributeError and get_lib() falls back, loudly)
     lib.tnn_decode_image_batch.restype = i64
     lib.tnn_decode_image_batch.argtypes = [p(c.c_char_p), i64, c.c_int,
                                            c.c_int, p(u8), p(u8)]
@@ -121,18 +122,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         try:
-            if not os.path.isfile(_SO_PATH):
+            # always through make: native/build/ is git-ignored, so a .so
+            # found there may predate native/src. make is incremental — a
+            # current build costs one stat pass. An explicit TNN_NATIVE_LIB
+            # is somebody else's build and is loaded as given.
+            if not _SO_OVERRIDE:
                 build_native()
-            try:
-                lib = ctypes.CDLL(_SO_PATH)
-                _configure(lib)
-            except AttributeError:
-                # stale .so from before a symbol was added — rebuild once
-                build_native(force=True)
-                lib = ctypes.CDLL(_SO_PATH)
-                _configure(lib)
+            lib = ctypes.CDLL(_SO_PATH)
+            _configure(lib)   # AttributeError: a stale override lacks a symbol
             _lib = lib
-        except (OSError, RuntimeError, AttributeError, subprocess.SubprocessError):
+        except (OSError, RuntimeError, AttributeError,
+                subprocess.SubprocessError) as e:
+            # the Python fallbacks are complete, so this is not fatal — but
+            # it is never silent: the data path just got slower
+            last = (str(e).strip().splitlines() or [""])[-1]
+            print(f"tnn_tpu.native: python fallback ({type(e).__name__}: "
+                  f"{last})", file=sys.stderr)
             _lib = None
     return _lib
 

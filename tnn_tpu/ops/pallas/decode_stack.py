@@ -42,10 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x spells it TPUCompilerParams; the kwargs used here are identical
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 _HP = 128  # heads padded to one lane tile; H <= 128 covers every GPT-2 size
 
 
@@ -244,7 +240,7 @@ def fused_decode_stack(x, t, k_cache, v_cache, stacks: Dict[str, Any], *,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )

@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import interpret_default
 
-# jax 0.4.x spells it TPUCompilerParams; the kwargs used here are identical
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # Tuned on v5e (honest difference-timing, B=8/H=12/D=64). Forward is best at
 # 1024/1024 (S=1024: 0.42ms = 30.9 TFLOP/s; S=4096: 5.36ms = 38.5 TFLOP/s —
 # 4-5x the stock jax.experimental pallas flash kernel on the same shapes, and
@@ -321,7 +317,7 @@ def _flash_fwd(q, k, v, mask, off, causal, scale, block_q, block_k,
         ],
         # scratch carries only along the innermost (ki) sweep; bh and qi
         # iterations are independent, which lets Mosaic pipeline them
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_default(),
     )(*inputs)
@@ -582,7 +578,7 @@ def _flash_bwd(causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)
@@ -624,7 +620,7 @@ def _flash_bwd(causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
                    jax.ShapeDtypeStruct((b * h, skv_p, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputsT)
@@ -740,7 +736,7 @@ def _flash_bwd_fused(causal, scale, bq, bk, clamp_dead, residuals, g):
         # (re-initialized at (0, 0)). The explicit VMEM budget keeps the
         # full-seq scratch from tripping Mosaic's conservative default check
         # at S=16384.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 2**20),
         interpret=interpret_default(),

@@ -29,10 +29,14 @@ Because the grid's head axis never mixes heads, tensor-parallel serving
 slice holds ``H_kv/tp`` heads of every page, the kernel sweeps it with the
 same block tables (replicated host-side), and the head axis of q/out is just
 locally smaller.
-Grouped-query attention is zero-copy: q is viewed as (B, Q, H_kv, G, Dh) and
-each grid step attends the whole (Q * G)-row query block against one fetched
-kv page. Pages past a row's live length clamp their fetch index to the last
-live page, so the Pallas pipeline elides the dead DMAs (same trick as
+Grouped-query attention shares each fetched kv page across its query group:
+the wrapper lays q (and out, and the stats) out HEAD-MAJOR, (B, H_kv, Q * G,
+Dh) with row ``t * G + i`` = token t, group member i, so a grid step's block
+is already the 2-D (Q * G, Dh) tile the body multiplies — the v5e Mosaic
+refuses in-kernel shape casts between (Q, G, Dh) and (Q * G, Dh). For the
+decode form (Q = 1) that layout is a pure reshape; a multi-token step pays
+one transpose of the small q/out tensors per call, never of KV. Pages past
+a row's live length clamp their fetch index to the last live page, so the Pallas pipeline elides the dead DMAs (same trick as
 flash_attention's causal dead-block clamp), and ``pl.when`` skips their
 compute.
 
@@ -65,10 +69,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import interpret_default
-
-# jax 0.4.x spells it TPUCompilerParams; the kwargs used here are identical
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 _NEG_INF = -1e30
 
@@ -106,7 +106,7 @@ def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, k_ref,
     del layer_ref  # consumed by the index maps, not the body
 
     def load_kv():
-        return k_ref[0, 0, 0], v_ref[0, 0, 0]    # (bs, Dh) — one page
+        return k_ref[...], v_ref[...]    # (bs, Dh) — one page
 
     _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs,
                scale=scale, bs=bs, g=g, qw=qw, stats=stats)
@@ -124,8 +124,8 @@ def _attn_kernel_int8(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref,
         # block's compute — dead pages fetch nothing extra). NOTE: int8's
         # minimum TPU tile is (32, 128) sublane x lane; blocks smaller than
         # that lean on Mosaic's relayout and lose part of the traffic win.
-        k = k_ref[0, 0, 0].astype(jnp.float32) * ks_ref[0, 0, 0]
-        v = v_ref[0, 0, 0].astype(jnp.float32) * vs_ref[0, 0, 0]
+        k = k_ref[...].astype(jnp.float32) * ks_ref[...]
+        v = v_ref[...].astype(jnp.float32) * vs_ref[...]
         return k, v
 
     _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs,
@@ -150,7 +150,6 @@ def _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs, *,
     b = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
-    dh = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -166,13 +165,17 @@ def _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs, *,
     # it to page 0 and this predicate skips the block entirely
     @pl.when((j * bs < kv_len) & (tables_ref[b, j] >= 0))
     def _block():
-        q = q_ref[0, :, 0].reshape(qw * g, dh)   # whole ragged query chunk
+        # the whole ragged query chunk, (Q*g, Dh) with row = t*g + group
+        # member: the WRAPPER lays q/out/stats out that way, because the v5e
+        # Mosaic refuses the (Q, g, Dh) <-> (Q*g, Dh) shape casts in-kernel
+        q = q_ref[...]
         k, v = load_kv()
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (qw * g, bs), 1)
-        trow = jax.lax.broadcasted_iota(jnp.int32, (qw, g), 0) \
-            .reshape(qw * g, 1)
+        trow = jax.lax.broadcasted_iota(jnp.int32, (qw * g, 1), 0)
+        if g > 1:
+            trow = jax.lax.div(trow, jnp.int32(g))
         # query token t sits at absolute position start + t with
         # start = kv_len - q_live: causal over its own chunk AND over every
         # previously written position; rows past q_live are fully masked
@@ -193,11 +196,10 @@ def _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs, *,
     def _final():
         l = l_scr[:]
         lsafe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> exactly 0
-        o_ref[0, :, 0] = (acc_scr[:] / lsafe).astype(o_ref.dtype) \
-            .reshape(qw, g, dh)
+        o_ref[...] = (acc_scr[:] / lsafe).astype(o_ref.dtype)
         if stats:
-            m_ref[0, :, 0] = m_scr[:].reshape(qw, g, 1)
-            l_ref[0, :, 0] = l[:].reshape(qw, g, 1)
+            m_ref[...] = m_scr[:]
+            l_ref[...] = l
 
 
 def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
@@ -207,7 +209,9 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
     _, _, hkv, bs, _ = (pages_k.data if quant else pages_k).shape
     g = h // hkv
     nb = block_tables.shape[1]
-    qg = q.reshape(b, qw, hkv, g, dh)
+    # head-major query rows: (B, H_kv, Q*g, Dh), so one grid step's block is
+    # already the 2-D (Q*g, Dh) tile the body works on (no in-kernel reshape)
+    qg = _to_head_major(q, hkv)
     tables = block_tables.astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
     qlens = q_lens.astype(jnp.int32)
@@ -224,19 +228,19 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
                 hi, 0, 0)
 
     def q_index(bi, hi, j, tbl, ln, qln, ly):
-        return (bi, 0, hi, 0, 0)
+        return (bi, hi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, qw, 1, g, dh), q_index),
-        pl.BlockSpec((1, 1, 1, bs, dh), kv_index),
-        pl.BlockSpec((1, 1, 1, bs, dh), kv_index),
+        pl.BlockSpec((None, None, qw * g, dh), q_index),
+        pl.BlockSpec((None, None, None, bs, dh), kv_index),
+        pl.BlockSpec((None, None, None, bs, dh), kv_index),
     ]
     operands = [qg]
     if quant:
         # the scale sidecars chase the SAME block-table index maps as their
         # pages, so a clamped dead-page fetch elides both DMAs together
-        in_specs += [pl.BlockSpec((1, 1, 1, bs, 1), kv_index),
-                     pl.BlockSpec((1, 1, 1, bs, 1), kv_index)]
+        in_specs += [pl.BlockSpec((None, None, None, bs, 1), kv_index),
+                     pl.BlockSpec((None, None, None, bs, 1), kv_index)]
         operands += [pages_k.data, pages_v.data, pages_k.scale,
                      pages_v.scale]
         kernel = _attn_kernel_int8
@@ -244,13 +248,13 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
         operands += [pages_k, pages_v]
         kernel = _attn_kernel
 
-    out_specs = pl.BlockSpec((1, qw, 1, g, dh), q_index)
-    out_shape = jax.ShapeDtypeStruct((b, qw, hkv, g, dh), q.dtype)
+    out_specs = pl.BlockSpec((None, None, qw * g, dh), q_index)
+    out_shape = jax.ShapeDtypeStruct((b, hkv, qw * g, dh), q.dtype)
     if stats:
         # per-row online-softmax state rides along as two extra outputs —
         # the sequence-parallel merge's inputs (ops.softmax_merge)
-        stat_spec = pl.BlockSpec((1, qw, 1, g, 1), q_index)
-        stat_shape = jax.ShapeDtypeStruct((b, qw, hkv, g, 1), jnp.float32)
+        stat_spec = pl.BlockSpec((None, None, qw * g, 1), q_index)
+        stat_shape = jax.ShapeDtypeStruct((b, hkv, qw * g, 1), jnp.float32)
         out_specs = (out_specs, stat_spec, stat_spec)
         out_shape = (out_shape, stat_shape, stat_shape)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -270,15 +274,30 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
         grid_spec=grid_spec,
         out_shape=out_shape,
         # scratch carries only along the innermost (page) sweep
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lens, qlens, layer_arr, *operands)
     if stats:
-        o, m, l = out  # noqa: E741
-        return (o.reshape(b, qw, h, dh), m.reshape(b, qw, h, 1),
-                l.reshape(b, qw, h, 1))
-    return out.reshape(b, qw, h, dh)
+        return tuple(_from_head_major(x, qw) for x in out)
+    return _from_head_major(out, qw)
+
+
+def _to_head_major(x, hkv):
+    """(B, Q, H, D) -> (B, H_kv, Q*g, D), row t*g + i = token t, group
+    member i. A pure reshape for the decode form (Q == 1)."""
+    b, qw, h, d = x.shape
+    g = h // hkv
+    return x.reshape(b, qw, hkv, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, hkv, qw * g, d)
+
+
+def _from_head_major(x, qw):
+    """Inverse of ``_to_head_major``: (B, H_kv, Q*g, D) -> (B, Q, H, D)."""
+    b, hkv, rows, d = x.shape
+    g = rows // qw
+    return x.reshape(b, hkv, qw, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, qw, hkv * g, d)
 
 
 def _gather_pages(pages, block_tables, layer, b, hkv, t, dh):
@@ -343,7 +362,12 @@ def _paged_attention_xla(q, pages_k, pages_v, block_tables, kv_lens, layer,
 def _paged_attention_xla_mq(q, pages_k, pages_v, block_tables, kv_lens,
                             q_lens, layer, scale, stats=False):
     """Multi-token-query reference: same ragged causal mask as the kernel
-    (and the same dead -1 table-entry masking)."""
+    (and the same dead -1 table-entry masking).
+
+    Works in the kernel's head-major row layout (B, H_kv, Q*g, ·): that
+    keeps both contractions in the batched-matmul form of the decode
+    reference — XLA's CPU backend has no bf16 x bf16 -> f32 dot for the
+    token-major ``bqhgt,bhtd`` form."""
     b, qw, h, dh = q.shape
     _, _, hkv, bs, _ = _pages_shape(pages_k)
     g = h // hkv
@@ -352,34 +376,33 @@ def _paged_attention_xla_mq(q, pages_k, pages_v, block_tables, kv_lens,
     tbl = jnp.maximum(block_tables, 0)   # clamp -1 holes for the gather
     k = _gather_pages(pages_k, tbl, layer, b, hkv, t, dh)
     v = _gather_pages(pages_v, tbl, layer, b, hkv, t, dh)
-    qg = q.reshape(b, qw, hkv, g, dh)
-    s = jnp.einsum("bqhgd,bhtd->bqhgt", qg, k,
+    s = jnp.einsum("bhrd,bhtd->bhrt", _to_head_major(q, hkv), k,
                    preferred_element_type=jnp.float32) * scale
     start = (kv_lens - q_lens)[:, None]                   # (B, 1)
-    tpos = jnp.arange(qw)[None, :]                        # (1, Q)
+    tpos = jnp.repeat(jnp.arange(qw), g)[None, :]         # (1, Q*g) token/row
     kpos = jnp.arange(t)
     live = (kpos[None, None, :] <= (start + tpos)[:, :, None]) \
-        & (tpos < q_lens[:, None])[:, :, None]            # (B, Q, T)
+        & (tpos < q_lens[:, None])[:, :, None]            # (B, Q*g, T)
     live = live & jnp.repeat(block_tables >= 0, bs, axis=1)[:, None, :]
-    s = jnp.where(live[:, :, None, None, :], s, _NEG_INF)
+    s = jnp.where(live[:, None], s, _NEG_INF)
     if stats:
-        m = jnp.max(s, axis=-1, keepdims=True)        # (B, Q, Hkv, G, 1)
-        p = jnp.where(live[:, :, None, None, :], jnp.exp(s - m), 0.0)
+        m = jnp.max(s, axis=-1, keepdims=True)        # (B, Hkv, Q*g, 1)
+        p = jnp.where(live[:, None], jnp.exp(s - m), 0.0)
         l = jnp.sum(p, axis=-1, keepdims=True)  # noqa: E741
-        out = jnp.einsum("bqhgt,bhtd->bqhgd", p.astype(v.dtype), v,
+        out = jnp.einsum("bhrt,bhtd->bhrd", p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
         out = out / jnp.where(l == 0.0, 1.0, l)
-        return (out.astype(q.dtype).reshape(b, qw, h, dh),
-                m.reshape(b, qw, h, 1), l.reshape(b, qw, h, 1))
+        return (_from_head_major(out.astype(q.dtype), qw),
+                _from_head_major(m, qw), _from_head_major(l, qw))
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked query rows (padding past q_lens, or q_lens/kv_lens == 0)
     # output exactly 0, matching the kernel's l == 0 guard
-    row_live = (tpos < q_lens[:, None]) & (start + tpos >= 0)   # (B, Q)
+    row_live = (tpos < q_lens[:, None]) & (start + tpos >= 0)   # (B, Q*g)
     row_live = row_live & jnp.any(live, axis=-1)
-    p = jnp.where(row_live[:, :, None, None, None], p, 0.0)
-    out = jnp.einsum("bqhgt,bhtd->bqhgd", p.astype(v.dtype), v,
+    p = jnp.where(row_live[:, None, :, None], p, 0.0)
+    out = jnp.einsum("bhrt,bhtd->bhrd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype).reshape(b, qw, h, dh)
+    return _from_head_major(out.astype(q.dtype), qw)
 
 
 def paged_attention_reference(q, pages_k, pages_v, block_tables, kv_lens, *,

@@ -40,10 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import interpret_default
 
-# jax 0.4.x spells it TPUCompilerParams; the kwargs used here are identical
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # Upper bounds on block sizes (VMEM: x 256x2048x2 + q 1024x2048x1 + acc
 # 256x1024x4 + out ≈ 6 MB with double buffering — comfortably inside VMEM).
 MAX_BLOCK_M = 256
@@ -204,7 +200,7 @@ def int8_matmul(x, q, scale, *, n: int | None = None, k: int | None = None,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_default(),
     )(xf, q, sp)

@@ -26,113 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 AXES = ("data", "fsdp", "model", "pipe", "seq", "expert")
 
 
-def shard_map(body, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions: jax>=0.5 exposes ``jax.shard_map``
-    with ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with the analogous knob spelled ``check_rep``.
-
-    On 0.4.x the fallback forces ``check_rep=True`` regardless of
-    ``check_vma``: with replication tracking OFF, grad-of-shard_map infers
-    fully-sharded out-specs for the residuals it threads to the backward
-    pass, which is unsatisfiable for scalar residuals (loss accumulators)
-    and raises ``_SpecError``. Tracking costs a little trace time and
-    enables the efficient transpose; programs that are correct under
-    ``check_vma=False`` on new jax are also correct under it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _install_04x_shard_map_fixes()
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=True)
-
-
-def shard_map_unchecked(body, *, mesh, in_specs, out_specs):
-    """shard_map with replication tracking OFF — for inference-only bodies.
-
-    The serving hot path never differentiates through the mapped body, so
-    the transpose machinery that forces ``check_rep=True`` above is dead
-    weight here. More importantly, 0.4.x's replication validator has no
-    rules for several primitives that appear in serving step bodies
-    (``pallas_call`` from the paged-attention kernel, threefry sampling),
-    so unregistered ops get pessimistically tagged "unreplicated" and the
-    replicated out-specs the engine relies on (tokens, keys) fail the
-    check even though the values are genuinely device-invariant. With
-    tracking off, replicated out-specs simply take the (identical) value
-    from each shard."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-_FIXES_04X_DONE = False
-
-
-def _install_04x_shard_map_fixes() -> None:
-    """Two targeted backports that make grad-of-shard_map work on 0.4.x.
-
-    1. Tolerant cond replication check. 0.4.x's ``check_rep`` validator
-       demands every ``lax.cond``/``switch`` branch produce IDENTICAL
-       replication types. Under ``jax.grad`` that is unsatisfiable for any
-       switch over branches with different parameters: partial-eval appends
-       each branch's grad residuals as extra outputs, zero-filled in the
-       other branches, and constant zeros check as "replicated" where real
-       residuals are "varying". jax's own lowering rewrite
-       (``_cond_rewrite``) already tolerates this by intersecting the
-       branch reps and pbroadcasting each branch to the meet — and later
-       jax versions replaced the strict check with exactly that
-       union-of-varying semantics. Install the same meet as the check rule
-       so the validator agrees with the rewrite. ``None`` (unknown rep)
-       meets to ``None``.
-
-    2. Instantiate symbolic-zero output cotangents before transpose. The
-       0.4.x transpose rule threads ``ad.Zero`` placeholders (outputs with
-       no cotangent — e.g. the aux new-state rows of a loss function) into
-       the inner bind, where the rewrite interpreter crashes
-       (``'Zero' object has no attribute 'reshape'``). Materialize them as
-       real zeros first; XLA folds the dead zeros away. float0 cotangents
-       (integer outputs) are left symbolic — the rule special-cases them."""
-    global _FIXES_04X_DONE
-    if _FIXES_04X_DONE:
-        return
-    _FIXES_04X_DONE = True
-    from jax._src import dtypes as _dtypes
-    from jax._src.interpreters import ad as _ad
-    from jax._src.lax.control_flow import conditionals as _conds
-    from jax.experimental import shard_map as _smod
-
-    def _meet(a, b):
-        if a is None or b is None:
-            return None
-        return a & b
-
-    def _cond_rule(mesh, *in_rep, branches):
-        pred_rep, *args_rep = in_rep
-        out_rep = _smod._check_rep(mesh, branches[0].jaxpr, args_rep)
-        for branch in branches[1:]:
-            out_rep = [_meet(r1, r2) for r1, r2 in zip(
-                out_rep, _smod._check_rep(mesh, branch.jaxpr, args_rep))]
-        return [_meet(pred_rep, r) for r in out_rep]
-
-    _smod._check_rules[_conds.cond_p] = _cond_rule
-
-    _orig_transpose = _ad.primitive_transposes[_smod.shard_map_p]
-
-    def _transpose_inst_zeros(out_cts, *args, **params):
-        out_cts = [
-            _ad.instantiate_zeros(ct)
-            if type(ct) is _ad.Zero and ct.aval.dtype != _dtypes.float0
-            else ct for ct in out_cts]
-        return _orig_transpose(out_cts, *args, **params)
-
-    _ad.primitive_transposes[_smod.shard_map_p] = _transpose_inst_zeros
-
-
 def make_mesh(data: int = 1, fsdp: int = 1, model: int = 1, pipe: int = 1,
               seq: int = 1, expert: int = 1,
               devices: Optional[Sequence] = None) -> Mesh:
@@ -173,16 +66,6 @@ def axis_size(mesh: Mesh, name: str) -> int:
     return int(mesh.shape.get(name, 1))
 
 
-def mapped_axis_size(axis: str) -> int:
-    """Size of a mapped axis from INSIDE a shard_map body, as a static int.
-
-    jax>=0.5 has jax.lax.axis_size; on 0.4.x a psum of the literal 1
-    constant-folds to the axis size at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    return int(jax.lax.psum(1, axis))
-
-
 def seq_shard_map(body, mesh: Mesh, axis: str, batch_axis=None):
     """Wrap a per-device (q, k, v) -> out body for context-parallel attention.
 
@@ -200,8 +83,8 @@ def seq_shard_map(body, mesh: Mesh, axis: str, batch_axis=None):
         live = tuple(n for n in names if axis_size(mesh, n) > 1)
         ba = live or None
     spec = PartitionSpec(ba, None, axis, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def local_mesh_info() -> Dict[str, int]:

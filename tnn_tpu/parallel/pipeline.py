@@ -117,8 +117,8 @@ def spmd_pipeline(block_fn: Callable, stacked_params, x_microbatches, mesh: Mesh
 
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stacked_params), P())
     out_specs = P(axis)
-    fn = mesh_lib.shard_map(per_device, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     stacked_out = fn(stacked_params, x_microbatches)  # (pp, num_mb, ...)
     return stacked_out[-1]
 
@@ -206,7 +206,7 @@ def spmd_pipeline_interleaved(block_fn: Callable, stacked_params, x_microbatches
         return outputs[None]
 
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), placed), P())
-    fn = mesh_lib.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=P(axis), check_vma=False)
     stacked_out = fn(placed, x_microbatches)  # (pp, num_mb, ...)
     return stacked_out[-1]
@@ -469,24 +469,9 @@ class HeteroPipeline:
             else:
                 loss = aux
                 corr = jnp.zeros((), jnp.float32)
-            return (self._vary(self._encode(out)), self._vary(new_s_vec),
-                    self._vary(loss), self._vary(corr))
+            return self._encode(out), new_s_vec, loss, corr
 
         return branch
-
-    def _vary(self, x):
-        """Join ``x``'s replication type to "varying over pipe (+data)".
-
-        Under shard_map replication tracking (``check_rep=True`` on jax
-        0.4.x), ``lax.switch`` requires every branch to produce identical
-        replication types. Non-last branches return constant-zero
-        loss/corrects (inferred replicated) while the last branch computes
-        them from device-varying data — add a zero derived from
-        ``axis_index`` so all branches agree. XLA folds the add away."""
-        bump = jax.lax.axis_index(self.axis)
-        if self.data_axis is not None:
-            bump = bump + jax.lax.axis_index(self.data_axis)
-        return x + (0 * bump).astype(x.dtype)
 
     def _prep(self, data, labels, train: bool):
         """Shared prologue: reshape the batch to (num_mb, mb_global, ...) and
@@ -613,7 +598,7 @@ class HeteroPipeline:
             # local (v, s_len) rows concatenate over pipe to (L, s_len)
             return s_rows_l, loss_acc[None], corr_acc[None]
 
-        fn = mesh_lib.shard_map(
+        fn = jax.shard_map(
             per_device, mesh=self.mesh, in_specs=self._in_specs(),
             out_specs=(P(self.axis), P(self.axis), P(self.axis)),
             check_vma=False)
@@ -632,9 +617,9 @@ class HeteroPipeline:
         its own packed rows, with the collectives transposed per device
         (ppermute -> inverse permutation, psum -> identity + a manual psum of
         the row grads over the data axis). shard_map's own transpose rule is
-        never invoked — on jax 0.4.x it mishandles grad-of-switch programs
-        (scalar residual out-specs, symbolic-zero cotangents), and this path
-        sidesteps all of it while staying exactly as parallel.
+        never invoked (no scalar residual out-specs, no symbolic-zero
+        cotangents to thread through it) and the path stays exactly as
+        parallel.
         """
         data, labels, mb_global, branches, n_ticks = self._prep(
             data, labels, True)
@@ -656,7 +641,7 @@ class HeteroPipeline:
                 gp = jax.lax.psum(gp, self.data_axis)
             return gp, s_l, loss_acc[None], corr_acc[None]
 
-        fn = mesh_lib.shard_map(
+        fn = jax.shard_map(
             per_device, mesh=self.mesh, in_specs=self._in_specs(),
             out_specs=(P(self.axis),) * 4, check_vma=False)
         grads, new_state, losses, corrects = fn(

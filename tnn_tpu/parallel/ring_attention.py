@@ -27,7 +27,7 @@ def _ring_attention_local(q, k, v, *, axis: str, causal: bool, scale: float):
     """Per-device body under shard_map. q: (B, H, S_local, D); k/v may carry
     H_kv < H heads (GQA) — the blocks ROTATE at H_kv size (the ICI-traffic
     win scales with the cache shrink) and repeat to H only at compute."""
-    ring = mesh_lib.mapped_axis_size(axis)
+    ring = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     s_local = q.shape[-2]
     group = q.shape[1] // k.shape[1]

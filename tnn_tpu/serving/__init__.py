@@ -14,7 +14,7 @@ with crash recovery, a step-latency watchdog, and graceful drain;
 docs/serving.md for the architecture, request lifecycle, failure-mode
 matrix, and operations guide.
 """
-from . import compile_cache
+from ..utils import compile_cache
 from .autoscaler import Autoscaler
 from .engine import InferenceEngine
 from .faults import EngineCrash, FaultInjected, FaultPlan

@@ -223,6 +223,9 @@ class InferenceEngine:
         Token-exact vs overlap-off on every decode path — a direct
         ``step()`` call stays fully synchronous either way. Default off;
         ``tnn-serve`` turns it on (``--no-overlap`` opts out).
+    device : the one device this engine's params, pool and step inputs live
+        on (None = JAX's default device). How a fleet puts replica i on
+        chip i; a ``tp``/``sp`` engine spans a mesh instead and refuses it.
     """
 
     def __init__(self, model, params, *, num_blocks: int = 64,
@@ -242,7 +245,7 @@ class InferenceEngine:
                  profiler: Optional[Profiler] = None, trace: bool = False,
                  overlap: bool = False, kv_dtype: str = "f32",
                  quant_weights: bool = False, tp: int = 1, sp: int = 1,
-                 host_tier_bytes: int = 0, seed: int = 0):
+                 host_tier_bytes: int = 0, seed: int = 0, device=None):
         if getattr(model, "kv_cache_dtype", None):
             raise ValueError(
                 "the paged pool stores compute-dtype pages; "
@@ -284,6 +287,13 @@ class InferenceEngine:
                 "block's pages live on one context-mesh shard and the "
                 "re-admission write would need per-shard routing; run the "
                 "host tier on single-chip replicas")
+        if device is not None and (tp > 1 or sp > 1):
+            raise ValueError(
+                "device= places a single-chip engine; a tp/sp engine spans "
+                "its own mesh")
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
         self.drafter: Optional[spec_decode.Drafter] = None
         self.spec_mode = spec if isinstance(spec, str) else \
             getattr(spec, "name", "custom")
@@ -391,14 +401,19 @@ class InferenceEngine:
             params = _quant.quantize_for_decode(params)
         self.params = params
         self.head_dim = model.d_model // model.num_heads
+        if self._tp is not None:
+            page_sharding = self._tp.page_sharding
+        elif self._sp is not None:
+            page_sharding = self._sp.page_sharding
+        elif device is not None:
+            page_sharding = jax.sharding.SingleDeviceSharding(device)
+        else:
+            page_sharding = None
         self.pool = PagedKVPool(
             num_layers=model.num_layers, num_kv_heads=model.num_kv_heads,
             head_dim=self.head_dim, num_blocks=num_blocks,
             block_size=block_size, dtype=model.policy.compute_dtype,
-            kv_dtype=kv_dtype,
-            sharding=(self._tp.page_sharding if self._tp
-                      else self._sp.page_sharding if self._sp else None),
-            sp=self.sp)
+            kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp)
         self.pool.fault_plan = faults
         # static gauge extras spliced into every _health_gauges refresh:
         # lets operators spot a misconfigured replica from /healthz alone
@@ -492,7 +507,9 @@ class InferenceEngine:
             "tier_blocks": 0, **self._gauge_extras}
         self.requests: Dict[int, Request] = {}
         self._rid = itertools.count()
-        self._key = jax.random.PRNGKey(seed)
+        # on the engine's device, so every split (and the step it feeds)
+        # stays there instead of hopping over from the default device
+        self._key = jax.device_put(jax.random.PRNGKey(seed), device)
         self._jit: Dict[Any, Any] = {}
         # TNN_DEBUG_SYNC=1: run every step under jax.transfer_guard
         # ("disallow") — the dynamic complement to tnnlint's static
@@ -982,7 +999,7 @@ class InferenceEngine:
             return self._tp.put_replicated(np.asarray(x, dtype))
         if self._sp is not None:
             return self._sp.put_replicated(np.asarray(x, dtype))
-        return jax.device_put(np.asarray(x, dtype))
+        return jax.device_put(np.asarray(x, dtype), self.device)
 
     def _put_tables(self, tables):
         """Stage a step's GLOBAL block tables: a plain replicated put at

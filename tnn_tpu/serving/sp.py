@@ -137,9 +137,10 @@ class SPContext:
                 args[t_idx] = args[t_idx][0]  # (1, B, nb) -> (B, nb)
                 return fn(*args)
 
-        body = mesh_lib.shard_map_unchecked(
+        body = jax.shard_map(
             inner, mesh=self.mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs if n_outs > 1 else out_specs[0])
+            out_specs=out_specs if n_outs > 1 else out_specs[0],
+            check_vma=False)
         jitted = jax.jit(body, donate_argnums=donate_argnums)
         ctx = self
 

@@ -172,9 +172,10 @@ class TPContext:
             pages_out = (n_outs - 2, n_outs - 1)
         out_specs = tuple(self.page_spec if i in pages_out else P()
                           for i in range(n_outs))
-        body = mesh_lib.shard_map_unchecked(
+        body = jax.shard_map(
             fn, mesh=self.mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs if n_outs > 1 else out_specs[0])
+            out_specs=out_specs if n_outs > 1 else out_specs[0],
+            check_vma=False)
         jitted = jax.jit(body, donate_argnums=donate_argnums)
         ctx = self
 

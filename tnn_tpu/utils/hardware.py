@@ -114,13 +114,25 @@ def device_info() -> List[Dict[str, Any]]:
 
 
 def hbm_stats(device=None) -> Dict[str, int]:
-    """Per-device HBM usage in bytes, when the PJRT backend reports it."""
+    """Per-device HBM usage in bytes; empty on a backend that reports none
+    (the CPU backend's ``memory_stats()`` is None). Errors propagate."""
     import jax
 
     d = device or jax.devices()[0]
-    try:
-        stats = d.memory_stats() or {}
-    except Exception:
-        return {}
+    stats = d.memory_stats() or {}
     return {k: int(v) for k, v in stats.items()
             if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+
+
+def device_line() -> str:
+    """What this process actually runs on, for an entry point's one startup
+    line: platform, device kind and count as JAX reports them, and whether
+    the Pallas kernels will interpret instead of compiling (they do on every
+    backend but ``tpu`` — a run that silently landed on the CPU says so)."""
+    import jax
+
+    from ..ops.pallas.runtime import interpret_default
+
+    devs = jax.devices()
+    return (f"platform={devs[0].platform} device_kind={devs[0].device_kind!r} "
+            f"devices={len(devs)} pallas_interpret={interpret_default()}")

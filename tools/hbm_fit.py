@@ -6,16 +6,12 @@ moments + BatchNorm-style state; no device memory touched) and bounds the
 training activation footprint under remat (per-block boundary activations +
 one block's interior). Decode rows: bf16 vs int8 weight bytes + KV cache.
 
-    TNN_PLATFORM=cpu python -m tools.hbm_fit [--seq 1024] [--hbm-gb 16]
+    JAX_PLATFORMS=cpu python -m tools.hbm_fit [--seq 1024] [--hbm-gb 16]
 """
 import argparse
 
-from tnn_tpu.utils.platform import apply_env_platform
-
-apply_env_platform()
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
 
 def tree_bytes(t) -> int:

@@ -70,7 +70,7 @@ def trace_wrn(out: str, batch: int = 256, steps: int = 3):
     with jax.profiler.trace(out):
         for _ in range(steps):
             state, m = step(state, x, y)
-        print("loss fetch:", float(m["loss"]))  # real sync on the relay
+        print("loss fetch:", float(m["loss"]))  # value fetch = sync
 
 
 def trace_gpt2_train(out: str, batch: int = 8, seq: int = 512, steps: int = 2,
