@@ -14,8 +14,19 @@ Four contracts:
   a week-long serve must not grow per-request lists without bound.
 * The Prometheus text exposition parses: HELP/TYPE headers, cumulative
   histogram buckets, labeled per-replica series through the Router.
+
+PR 24 adds the second sink and the device-side names: under
+``jax.profiler.start_trace`` the engine's phase spans and the training
+spans land in the profile's host plane with their attributes as stats, and
+the lowered step programs carry every scope and kernel name of
+docs/observability.md's catalog.
 """
+import glob
 import json
+import os
+import re
+import threading
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -76,11 +87,15 @@ class TestSpanName:
 
 class TestTracer:
     def test_disabled_without_profiler(self):
+        """No profiler session and no ``Profiler``: a span and an instant
+        record nothing anywhere and raise nothing (the annotation sink is
+        an atomic load outside a session)."""
         tr = Tracer()
         assert not tr.enabled
         with tr.span("serve.step", rid=1):
             pass
-        tr.instant("serve.submit", rid=1)  # no-ops, nothing raised
+        tr.instant("serve.submit", rid=1, trace=None)
+        assert tr.profiler is None
 
     def test_span_and_instant_record_events(self):
         prof = Profiler(source="engine")
@@ -374,3 +389,232 @@ class TestPrometheusExposition:
         # supervisor-level families present under the replica label
         assert any(m == "tnn_serve_supervisor_restarts" for m, _, _ in
                    samples)
+
+
+# ------------------------------------------------- PR 24: the second sink ----
+
+def _host_events(trace_dir, prefixes=("serve.", "front.", "train.")):
+    """[(thread line, name, start ns, end ns, stats)] of the program's spans
+    in the profile ``start_trace`` wrote under ``trace_dir``."""
+    pb = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                   recursive=True)[0]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    out.append((line.name, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def _start_trace(path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # the program's spans, not its frames
+    jax.profiler.start_trace(str(path), profiler_options=opts)
+
+
+PHASES = ("serve.build", "serve.dispatch", "serve.fetch", "serve.commit")
+
+
+class TestProfilerSink:
+    def test_engine_phases_in_the_host_plane(self, tiny_lm, tmp_path):
+        """A plain engine (no ``trace=True``, no ``Profiler``) under a
+        profiler session: every step leaves its four phase spans, with the
+        step as a stat and not as part of the name, on the one thread that
+        drove it, disjoint or properly nested."""
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **KW)
+        rng = np.random.default_rng(3)
+        rids = [eng.submit(rng.integers(0, 128, 7).astype(np.int32), 4)
+                for _ in range(2)]
+        eng.step()                       # compile outside the session
+        _start_trace(tmp_path)
+        try:
+            out = eng.run_until_complete()
+        finally:
+            jax.profiler.stop_trace()
+        assert all(len(out[r]) == 4 for r in rids)
+        assert eng.profiler is None and not eng.tracer.enabled
+        evs = _host_events(tmp_path)
+        by_name = {}
+        for ev in evs:
+            by_name.setdefault(ev[1], []).append(ev)
+        for name in PHASES:
+            assert by_name.get(name), f"no {name} event in the host plane"
+            assert all("step" in ev[4] for ev in by_name[name]), name
+        assert {"kind", "key"} <= set(by_name["serve.dispatch"][0][4])
+        assert not any(" " in ev[1] or "=" in ev[1] for ev in evs)
+        assert len({ev[0] for ev in evs if ev[1] in PHASES}) == 1
+        # the phases tile the worker's time: no two of them overlap, and
+        # anything else on the thread lies inside one of them or outside all
+        spans = sorted(ev[2:4] for ev in evs if ev[1] in PHASES)
+        assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+        for ev in evs:
+            if ev[1] not in PHASES:
+                assert all(ev[3] <= s or ev[2] >= e
+                           or (s <= ev[2] and ev[3] <= e) for s, e in spans)
+        # a step's phases share its step stat, in order
+        first = min(ev[4]["step"] for ev in by_name["serve.fetch"])
+        order = [ev[1] for ev in sorted(evs, key=lambda ev: ev[2])
+                 if ev[1] in PHASES and ev[4]["step"] == first + 1]
+        assert order == list(PHASES)
+        # and the reservoirs were fed where the work happened
+        s = eng.metrics.summary()
+        assert s["build_ms_p50"] > 0 and s["commit_ms_p50"] > 0
+        assert "serve.host_gap" not in by_name      # the gap is between spans
+
+    def test_training_spans_in_the_host_plane(self, tmp_path):
+        from tnn_tpu import nn
+        from tnn_tpu.data.token_stream import TokenStreamDataLoader
+        from tnn_tpu.models.gpt2 import GPT2
+        from tnn_tpu.train.step import create_train_state, make_train_step
+
+        path = tmp_path / "train.bin"
+        np.random.default_rng(0).integers(0, 64, 4096, dtype=np.uint16) \
+            .tofile(path)
+        loader = TokenStreamDataLoader(str(path), 16)
+        model = GPT2(vocab_size=64, max_len=16, num_layers=1, d_model=16,
+                     num_heads=2)
+        opt = nn.AdamW(lr=1e-3, grad_clip_norm=1.0)
+        state = create_train_state(model, opt, jax.random.PRNGKey(0), (2, 16))
+        step = make_train_step(model, opt, compute_accuracy=False)
+
+        def one(state):
+            data, labels = loader.random_windows(2)
+            return step(state, jax.numpy.asarray(data, jax.numpy.int32),
+                        jax.numpy.asarray(labels, jax.numpy.int32))[0]
+
+        state = one(state)
+        _start_trace(tmp_path / "trace")
+        try:
+            for _ in range(3):
+                state = one(state)
+            jax.block_until_ready(state)
+        finally:
+            jax.profiler.stop_trace()
+        evs = _host_events(tmp_path / "trace")
+        inputs = [ev for ev in evs if ev[1] == "train.input"]
+        assert len(inputs) == 3 and inputs[0][4] == {"rows": 2}
+        assert len([ev for ev in evs if ev[1] == "train.dispatch"]) == 3
+
+    def test_front_end_records_emit_delay_into_the_current_registry(
+            self, tiny_lm, monkeypatch, capsys):
+        """``_serve_stdin`` stamps token events as the worker hands them
+        over and records the wait at flush, into whatever registry the
+        engine holds THEN (the benchmark swaps it to mark a window)."""
+        import argparse
+
+        import tnn_tpu.cli.serve as serve_cli
+
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **KW)
+        sup = EngineSupervisor(eng)
+        rfd, wfd = os.pipe()
+        seen = {}
+
+        def feed():
+            with os.fdopen(wfd, "w") as w:
+                w.write(json.dumps({"id": "a", "tokens": [1, 2, 3],
+                                    "max_new_tokens": 3}) + "\n")
+                w.flush()
+                seen["before"] = eng.metrics
+                eng.metrics = seen["swapped"] = ServingMetrics()
+                w.write(json.dumps({"id": "b", "tokens": [4, 5, 6],
+                                    "max_new_tokens": 3}) + "\n")
+
+        with os.fdopen(rfd, "r") as rd:
+            monkeypatch.setattr("sys.stdin", rd)
+            t = threading.Thread(target=feed)
+            t.start()
+            rc = serve_cli._serve_stdin(
+                sup, model, None,
+                argparse.Namespace(max_new_tokens=3, deadline_s=0.0))
+            t.join()
+        assert rc == 0
+        events = [json.loads(ln) for ln in
+                  capsys.readouterr().out.splitlines() if ln.startswith("{")]
+        assert sum(ev["event"] == "token" for ev in events) == 6
+        assert all(set(ev) == {"event", "id", "token"}
+                   for ev in events if ev["event"] == "token")
+        n = len(seen["before"].emit_delay_s) + len(seen["swapped"].emit_delay_s)
+        assert n == 6 and len(seen["swapped"].emit_delay_s) >= 1
+        assert seen["swapped"].summary()["emit_delay_ms_p50"] >= 0.0
+
+
+# ------------------------------- PR 24: names that survive a refactor ----
+
+BLOCK_SCOPES = ("attn_qkv", "kv_write", "paged_attn", "attn_out", "mlp")
+MODEL_SCOPES = ("embed", "h0", "h1", "ln_f", "lm_head")
+
+
+def _lowered(jitted, *args):
+    """A jitted program's lowered text with its ops' scope paths."""
+    return jitted.lower(*args).as_text(debug_info=True)
+
+
+class TestStableNames:
+    @pytest.fixture()
+    def as_on_the_chip(self, monkeypatch):
+        """Take the kernels' path (``backend="auto"`` asks the default
+        backend) in interpret mode: the names are the chip's."""
+        monkeypatch.setenv("TNN_PALLAS_INTERPRET", "1")
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            yield
+
+    def test_serving_steps_carry_the_catalog(self, tiny_lm, as_on_the_chip):
+        jnp = jax.numpy
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, decode_path="paged", **KW)
+        b, nb = KW["max_batch_size"], eng.blocks_per_seq
+        row = dict(t=jnp.zeros(b), k=jnp.zeros(b, jnp.int32), p=jnp.zeros(b))
+        tables = jnp.zeros((b, nb), jnp.int32)
+        ints = jnp.zeros(b, jnp.int32)
+        pages = (eng.pool.pages_k, eng.pool.pages_v)
+        decode = _lowered(
+            eng._paged_decode_fn(b, nb), params, *pages, ints, ints, tables,
+            row["t"], row["k"], row["p"], eng._key, row["t"])
+        mixed = _lowered(
+            eng._mixed_paged_fn(b, 8, nb), params, *pages,
+            jnp.zeros((b, 8), jnp.int32), ints, ints + 1, tables,
+            row["t"], row["k"], row["p"], eng._key, row["t"])
+        for text, module in ((decode, "jit_tnn_serve_decode"),
+                             (mixed, "jit_tnn_serve_mixed_w8")):
+            assert f"module @{module} " in text
+            for scope in MODEL_SCOPES + BLOCK_SCOPES + ("sample",):
+                assert re.search(rf"[/(]{scope}[/)]", text), (module, scope)
+            assert "/paged_attn/tnn_paged_attention/" in text
+            assert re.search(r"/h1/kv_write/scatter", text)
+
+    def test_train_step_carries_the_catalog(self, as_on_the_chip):
+        from tnn_tpu import nn
+        from tnn_tpu.models.gpt2 import GPT2
+        from tnn_tpu.train.step import create_train_state, make_train_step
+
+        jnp = jax.numpy
+        model = GPT2(vocab_size=64, max_len=128, num_layers=2, d_model=32,
+                     num_heads=2, backend="pallas")
+        opt = nn.AdamW(lr=1e-3, grad_clip_norm=1.0)
+        state = create_train_state(model, opt, jax.random.PRNGKey(0),
+                                   (2, 128))
+        made = []
+        real_jit = jax.jit
+        with mock.patch.object(
+                jax, "jit", lambda f, **kw: made.append(
+                    real_jit(f, **kw)) or made[-1]):
+            make_train_step(model, opt, compute_accuracy=True)
+        ids = jnp.zeros((2, 128), jnp.int32)
+        text = _lowered(made[-1], state, ids, ids, jnp.ones(()))
+        assert "module @jit_tnn_train_step " in text
+        for scope in MODEL_SCOPES + ("attn_qkv", "flash_attn", "attn_out",
+                                     "mlp", "loss", "metrics", "optimizer",
+                                     "grad_clip"):
+            assert re.search(rf"[/(]{scope}[/)]", text), scope
+        # one word finds forward and backward
+        assert re.search(r"/jvp\(h1\)/mlp/", text)
+        assert re.search(r"/transpose\(jvp\(h1\)\)/mlp/", text)
+        assert "/flash_attn/tnn_flash_fwd/" in text
+        assert re.search(r"/flash_attn/tnn_flash_bwd_\w+/", text)
+        assert "/optimizer/grad_clip/" in text

@@ -50,6 +50,7 @@ import queue
 import select
 import signal
 import sys
+import time
 
 
 import jax
@@ -58,7 +59,7 @@ import numpy as np
 from tnn_tpu import checkpoint as ckpt_lib
 from tnn_tpu import models
 from tnn_tpu.data.tokenizer import Tokenizer
-from tnn_tpu.profiling.profiler import Profiler
+from tnn_tpu.profiling.profiler import Profiler, span
 from tnn_tpu.serving import (AdmissionRejected, EngineSupervisor,
                              InferenceEngine, Router, ShuttingDown,
                              run_server)
@@ -592,9 +593,15 @@ def main(argv=None):
 def _serve_stdin(supervisor, model, tokenizer, args):
     """Stdin JSON-lines loop as a thin client of the supervisor: requests
     marshal onto the worker thread, events flow back through the sink
-    queue, and SIGINT/SIGTERM/EOF all converge on one graceful drain."""
+    queue, and SIGINT/SIGTERM/EOF all converge on one graceful drain.
+
+    The loop's phases are spans on the JAX profiler's clock (``front.read``
+    the stdin poll, ``front.submit`` one request line, ``front.flush`` the
+    events written out), and every ``token`` event is stamped when the
+    worker hands it over, right after its step's commit, so a flush can
+    record how long it waited (``observe_emit_delay``)."""
     out_q: "queue.Queue" = queue.Queue()
-    supervisor.event_sink = out_q.put
+    supervisor.event_sink = lambda ev: out_q.put((time.perf_counter(), ev))
 
     ids_by_rid = {}
     rid_by_user = {}
@@ -607,6 +614,10 @@ def _serve_stdin(supervisor, model, tokenizer, args):
         except json.JSONDecodeError as e:
             _emit({"event": "error", "reason": f"bad json: {e}"})
             return
+        with span("front.submit", rid=req.get("id")):
+            submit(req)
+
+    def submit(req):
         if req.get("op") == "cancel":
             user_id = req.get("id")
             rid = rid_by_user.get(user_id)
@@ -657,11 +668,24 @@ def _serve_stdin(supervisor, model, tokenizer, args):
         _emit(out)
 
     def flush_events():
-        while True:
-            try:
-                emit_event(out_q.get_nowait())
-            except queue.Empty:
-                return
+        if out_q.empty():
+            return
+        # the engine's CURRENT registry, looked up at every flush: a caller
+        # may swap it to mark a window (never one captured at start-up)
+        metrics = getattr(supervisor, "engine", supervisor).metrics
+        n = 0
+        with span("front.flush") as flush:
+            while True:
+                try:
+                    t_commit, ev = out_q.get_nowait()
+                except queue.Empty:
+                    break
+                emit_event(ev)
+                n += 1
+                if ev.get("event") == "token":
+                    metrics.observe_emit_delay(
+                        time.perf_counter() - t_commit)
+            flush.set_metadata(n=n)
 
     supervisor.start()
     old_handlers = {}
@@ -680,7 +704,8 @@ def _serve_stdin(supervisor, model, tokenizer, args):
             if eof or supervisor.draining:
                 supervisor.join(0.05)  # drain in progress: just wait
                 continue
-            lines, pending, eof = _read_stdin_lines(fd, pending, 0.05)
+            with span("front.read"):
+                lines, pending, eof = _read_stdin_lines(fd, pending, 0.05)
             for raw in lines:
                 if raw.strip():
                     handle_line(raw.decode(errors="replace"))

@@ -15,6 +15,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..profiling.profiler import span
 from .loader import DataLoader
 
 
@@ -75,8 +76,9 @@ class TokenStreamDataLoader(DataLoader):
                 f"context_length={self.context_length} (need at least "
                 f"{self.context_length + 1})")
         rng = rng or self._rng
-        starts = rng.integers(0, self._num_samples, batch_size)
-        return self._get(starts)
+        with span("train.input", rows=batch_size):    # the window draw
+            starts = rng.integers(0, self._num_samples, batch_size)
+            return self._get(starts)
 
 
 class OpenWebTextDataLoader(TokenStreamDataLoader):

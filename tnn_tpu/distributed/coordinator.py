@@ -29,6 +29,7 @@ class WorkerHandle:
         self.info = info
         self.last_heartbeat = time.monotonic()
         self.alive = True
+        self.announced = True   # False while a death's callback still runs
 
 
 class Coordinator:
@@ -157,12 +158,18 @@ class Coordinator:
             if h is None or not h.alive:
                 return
             h.alive = False
+            h.announced = False
             rank = h.rank
         self._log.warning("worker %d disconnected", rank)
         # callback BEFORE waking wait_failed() — a waiter acting on the death
-        # must be able to assume the failure callback has already run
-        if self.on_failure:
-            self.on_failure(rank)
+        # must be able to assume the failure callback has already run (a
+        # waiter's own polling lap must not see the death first either:
+        # ``announced`` holds it back)
+        try:
+            if self.on_failure:
+                self.on_failure(rank)
+        finally:
+            h.announced = True
         with self._member_cv:
             self._member_cv.notify_all()
 
@@ -220,7 +227,13 @@ class Coordinator:
         """Block until ``rank`` is considered dead. Event-driven: a disconnect
         wakes this immediately; only heartbeat *staleness* (which generates no
         event by nature) is re-checked on a short cadence."""
-        self._wait_member(lambda: rank in self.failed_workers(), timeout,
+        def dead_and_announced():
+            with self._lock:
+                h = self._workers.get(rank)
+            return (h is None or h.announced) \
+                and rank in self.failed_workers()
+
+        self._wait_member(dead_and_announced, timeout,
                           f"rank {rank} still alive after {timeout}s")
 
     def wait_alive(self, rank: int, timeout: float = 60.0) -> None:

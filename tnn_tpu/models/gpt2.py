@@ -75,6 +75,7 @@ class GPT2(Module):
             params["head"] = head.init(keys[2], emb_shape)["params"]
         return params, state
 
+    @jax.named_scope("embed")
     def _trunk(self, params, ids, train, rng, offset=0):
         keys = rnglib.split_for(rng, self.num_layers + 1)
         x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
@@ -82,6 +83,7 @@ class GPT2(Module):
         x, _ = self.drop.apply({}, x, train=train, rng=keys[-1])
         return x, keys
 
+    @jax.named_scope("lm_head")
     def _head(self, params, x):
         if self.tie_embeddings:
             logits = self.wte.attend(params["wte"], x)
@@ -100,13 +102,18 @@ class GPT2(Module):
         x, keys = self._trunk(params, ids, train, rng)
         new_state = {}
         for i, block in enumerate(self.blocks):
-            x, st = block.apply(
-                {"params": params[f"h{i}"], "state": state.get(f"h{i}", {})},
-                x, train=train, rng=keys[i])
+            with jax.named_scope(f"h{i}"):
+                x, st = block.apply(
+                    {"params": params[f"h{i}"],
+                     "state": state.get(f"h{i}", {})},
+                    x, train=train, rng=keys[i])
             if st:
                 new_state[f"h{i}"] = st
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return x, new_state
+        return self._ln_f(params, x), new_state
+
+    @jax.named_scope("ln_f")
+    def _ln_f(self, params, x):
+        return self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)[0]
 
     def apply_hidden(self, variables, ids, *, train=False, rng=None):
         """(N, S) ids -> post-ln_f hidden (N, S, D), WITHOUT the head matmul.
@@ -143,10 +150,11 @@ class GPT2(Module):
         x, _ = self._trunk(params, ids, False, None, offset=offset)
         new_caches = []
         for i, block in enumerate(self.blocks):
-            x, c = block.apply_cached(params[f"h{i}"], x, caches[i], offset)
+            with jax.named_scope(f"h{i}"):
+                x, c = block.apply_cached(params[f"h{i}"], x, caches[i],
+                                          offset)
             new_caches.append(c)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), new_caches
+        return self._head(params, self._ln_f(params, x)), new_caches
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):
@@ -162,10 +170,11 @@ class GPT2(Module):
         """
         x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
         for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope(f"h{i}"):
+                x, pages_k, pages_v = block.apply_paged(
+                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
+                    offsets, layer=i)
+        x = self._ln_f(params, x)
         return self._head(params, x)[:, -1], pages_k, pages_v
 
     def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
@@ -181,11 +190,11 @@ class GPT2(Module):
         """
         x, _ = self._trunk(params, toks, False, None, offset=offsets)
         for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i, q_lens=q_lens)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), pages_k, pages_v
+            with jax.named_scope(f"h{i}"):
+                x, pages_k, pages_v = block.apply_paged(
+                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
+                    offsets, layer=i, q_lens=q_lens)
+        return self._head(params, self._ln_f(params, x)), pages_k, pages_v
 
     def _config(self):
         cfg = {"vocab_size": self.vocab_size, "max_len": self.max_len,

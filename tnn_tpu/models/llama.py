@@ -68,6 +68,7 @@ class LlamaBlock(Module):
             "down": down.init(k6, hidden_shape)["params"],
         }, {}
 
+    @jax.named_scope("mlp")
     def _swiglu(self, params, h, train):
         d = h.shape[-1]
         g, _ = self.gate.apply({"params": params["gate"], "state": {}}, h,
@@ -168,6 +169,7 @@ class Llama(Module):
             params["head"] = head.init(keys[2], emb_shape)["params"]
         return params, {}
 
+    @jax.named_scope("lm_head")
     def _head(self, params, x):
         if self.tie_embeddings:
             return self.wte.attend(params["wte"], x)
@@ -178,11 +180,15 @@ class Llama(Module):
 
     def _hidden(self, params, ids, train, rng):
         keys = rnglib.split_for(rng, self.num_layers)
-        x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
+        with jax.named_scope("embed"):
+            x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
         for i, block in enumerate(self.blocks):
-            x, _ = block.apply({"params": params[f"h{i}"], "state": {}}, x,
-                               train=train, rng=keys[i])
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope(f"h{i}"):
+                x, _ = block.apply(
+                    {"params": params[f"h{i}"], "state": {}}, x,
+                    train=train, rng=keys[i])
+        with jax.named_scope("ln_f"):
+            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
         return x
 
     def _apply(self, params, state, ids, *, train, rng):
@@ -207,12 +213,16 @@ class Llama(Module):
         return [b.init_cache(batch, max_len, self.d_model) for b in self.blocks]
 
     def apply_cached(self, params, ids, caches, offset):
-        x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
+        with jax.named_scope("embed"):
+            x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
         new_caches = []
         for i, block in enumerate(self.blocks):
-            x, c = block.apply_cached(params[f"h{i}"], x, caches[i], offset)
+            with jax.named_scope(f"h{i}"):
+                x, c = block.apply_cached(params[f"h{i}"], x, caches[i],
+                                          offset)
             new_caches.append(c)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+        with jax.named_scope("ln_f"):
+            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
         return self._head(params, x), new_caches
 
     def _config(self):

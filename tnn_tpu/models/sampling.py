@@ -80,6 +80,7 @@ def filter_logits(logits, temperature, top_k, top_p):
     return _top_p_filter(x, p_eff)
 
 
+@jax.named_scope("sample")
 def sample_ragged(logits, key, temperature, top_k, top_p):
     """Vectorized sampling with per-row parameters.
 

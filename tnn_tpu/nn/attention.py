@@ -138,15 +138,18 @@ def sdpa(q, k, v, *, causal: bool = False, mask: Optional[jax.Array] = None,
         if _RING_CTX["method"] == "ulysses":
             from ..parallel.ulysses import ulysses_attention
 
-            return ulysses_attention(q, k, v, _RING_CTX["mesh"],
-                                     axis=_RING_CTX["axis"], causal=causal,
-                                     scale=scale,
-                                     batch_axis=_RING_CTX["batch_axis"])
+            with jax.named_scope("ring_attn"):
+                return ulysses_attention(
+                    q, k, v, _RING_CTX["mesh"], axis=_RING_CTX["axis"],
+                    causal=causal, scale=scale,
+                    batch_axis=_RING_CTX["batch_axis"])
         from ..parallel.ring_attention import ring_attention
 
-        return ring_attention(q, k, v, _RING_CTX["mesh"],
-                              axis=_RING_CTX["axis"], causal=causal,
-                              scale=scale, batch_axis=_RING_CTX["batch_axis"])
+        with jax.named_scope("ring_attn"):
+            return ring_attention(
+                q, k, v, _RING_CTX["mesh"], axis=_RING_CTX["axis"],
+                causal=causal, scale=scale,
+                batch_axis=_RING_CTX["batch_axis"])
     if backend == "ring":
         raise RuntimeError(
             "backend='ring' needs an enclosing nn.attention.ring_context(mesh)"
@@ -160,10 +163,12 @@ def sdpa(q, k, v, *, causal: bool = False, mask: Optional[jax.Array] = None,
         # ops.pallas.paged_attention, no assembled cache at all)
         from ..ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               mask=mask, kv_offset=kv_offset)
-    return local_xla_attention(q, k, v, causal=causal, mask=mask, scale=scale,
-                               kv_offset=kv_offset)
+        with jax.named_scope("flash_attn"):
+            return flash_attention(q, k, v, causal=causal, scale=scale,
+                                   mask=mask, kv_offset=kv_offset)
+    with jax.named_scope("sdpa"):
+        return local_xla_attention(q, k, v, causal=causal, mask=mask,
+                                   scale=scale, kv_offset=kv_offset)
 
 
 def apply_rope(x, offset=0, theta: float = 10000.0):
@@ -310,6 +315,7 @@ class MultiHeadAttention(Module):
         n, h, s, dh = x.shape
         return x.transpose(0, 2, 1, 3).reshape(n, s, h * dh)
 
+    @jax.named_scope("attn_qkv")
     def _project_qkv(self, params, x):
         from ..ops.pallas.quant_matmul import qmatmul
 
@@ -324,6 +330,7 @@ class MultiHeadAttention(Module):
         return (self._split_heads(q), self._split_heads(k, self.num_kv_heads),
                 self._split_heads(v, self.num_kv_heads))
 
+    @jax.named_scope("attn_out")
     def _project_out(self, params, attn, train, rng):
         from ..ops.pallas.quant_matmul import qmatmul
 
@@ -338,8 +345,9 @@ class MultiHeadAttention(Module):
     def _apply(self, params, state, x, *, train, rng):
         q, k, v = self._project_qkv(params, x)
         if self.rope_theta:
-            q = apply_rope(q, 0, self.rope_theta)
-            k = apply_rope(k, 0, self.rope_theta)
+            with jax.named_scope("attn_qkv"):
+                q = apply_rope(q, 0, self.rope_theta)
+                k = apply_rope(k, 0, self.rope_theta)
         attn = sdpa(q, k, v, causal=self.causal, backend=self.backend)
         return self._project_out(params, attn, train, rng), state
 
@@ -385,8 +393,9 @@ class MultiHeadAttention(Module):
         if self.rope_theta:
             # rotation depends on ABSOLUTE position: rotate q and the new
             # keys at their true offsets; the cache stores rotated keys
-            q = apply_rope(q, offset, self.rope_theta)
-            k_new = apply_rope(k_new, offset, self.rope_theta)
+            with jax.named_scope("attn_qkv"):
+                q = apply_rope(q, offset, self.rope_theta)
+                k_new = apply_rope(k_new, offset, self.rope_theta)
         if getattr(offset, "ndim", 0):  # per-row write positions
             upd = lambda buf, new: jax.vmap(  # noqa: E731
                 lambda b, n, o: jax.lax.dynamic_update_slice_in_dim(
@@ -395,11 +404,12 @@ class MultiHeadAttention(Module):
             upd = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
                 buf, new, offset, axis=2)
         if self.kv_cache_dtype == "int8":
-            kq, ks = self._quant_rows(k_new)
-            vq, vs = self._quant_rows(v_new)
-            cache = {"k": upd(cache["k"], kq), "v": upd(cache["v"], vq),
-                     "k_scale": upd(cache["k_scale"], ks),
-                     "v_scale": upd(cache["v_scale"], vs)}
+            with jax.named_scope("kv_write"):
+                kq, ks = self._quant_rows(k_new)
+                vq, vs = self._quant_rows(v_new)
+                cache = {"k": upd(cache["k"], kq), "v": upd(cache["v"], vq),
+                         "k_scale": upd(cache["k_scale"], ks),
+                         "v_scale": upd(cache["v_scale"], vs)}
             cd = self.policy.compute_dtype
             # dequant at use. On the XLA backend the int8 read + scale can
             # fuse into the attention contraction (traffic = int8 bytes); on
@@ -413,7 +423,9 @@ class MultiHeadAttention(Module):
             k = (cache["k"].astype(jnp.float32) * cache["k_scale"]).astype(cd)
             v = (cache["v"].astype(jnp.float32) * cache["v_scale"]).astype(cd)
         else:
-            cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
+            with jax.named_scope("kv_write"):
+                cache = {"k": upd(cache["k"], k_new),
+                         "v": upd(cache["v"], v_new)}
             k, v = cache["k"], cache["v"]
         # decode follows the model's configured backend — a "pallas" model
         # runs the flash kernel with kv_offset instead of falling back to XLA
@@ -454,8 +466,9 @@ class MultiHeadAttention(Module):
         params = variables["params"]
         q, k_new, v_new = self._project_qkv(params, x)   # (B, H*, Q, Dh)
         if self.rope_theta:
-            q = apply_rope(q, offsets, self.rope_theta)
-            k_new = apply_rope(k_new, offsets, self.rope_theta)
+            with jax.named_scope("attn_qkv"):
+                q = apply_rope(q, offsets, self.rope_theta)
+                k_new = apply_rope(k_new, offsets, self.rope_theta)
         from ..ops.pallas import paged_attention as pa
 
         quant_pool = isinstance(pages_k, pa.QuantPages)

@@ -69,7 +69,8 @@ class Optimizer:
         """Returns (new_params, new_state). Pure; call inside jit."""
         grads = _tree_map(lambda g, p: g.astype(jnp.float32), grads, params)
         if self.grad_clip_norm is not None:
-            grads = clip_by_global_norm(grads, self.grad_clip_norm)
+            with jax.named_scope("grad_clip"):
+                grads = clip_by_global_norm(grads, self.grad_clip_norm)
         step = state["step"] + 1
         lr = self.lr * lr_scale
         new_params, new_state = self._update(grads, state, params, lr, step)

@@ -104,41 +104,55 @@ class GPTBlock(Module):
         else:
             k1, k2, k3 = rnglib.split_for(rng, 3)
             k4 = None
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
+        h = self._ln1(params, x)
         h, _ = self.attn.apply({"params": params["attn"], "state": {}}, h,
                                train=train, rng=k1)
-        h, _ = self.drop.apply({}, h, train=train, rng=k2)
-        x = x + h
-        h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        h, new_state = self._mlp(params, h, train, k4)
-        h, _ = self.drop.apply({}, h, train=train, rng=k3)
-        return x + h, new_state
+        with jax.named_scope("attn_out"):
+            h, _ = self.drop.apply({}, h, train=train, rng=k2)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
+            h, new_state = self._mlp(params, h, train, k4)
+            h, _ = self.drop.apply({}, h, train=train, rng=k3)
+            return x + h, new_state
 
     # -- cached decode --------------------------------------------------------
 
     def init_cache(self, batch: int, max_len: int, d_model: int):
         return self.attn.init_cache(batch, max_len, d_model)
 
-    def apply_cached(self, params, x, cache, offset):
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
-        h, new_cache = self.attn.apply_cached({"params": params["attn"]}, h, cache, offset)
-        x = x + h
+    # Layer scopes of the device profile (docs/observability.md): the block's
+    # first norm counts as ``attn_qkv``, each residual add as the sublayer it
+    # closes (``attn_out`` / ``mlp``); the attention module names the rest.
+
+    @jax.named_scope("attn_qkv")
+    def _ln1(self, params, x):
+        return self.ln1.apply({"params": params["ln1"], "state": {}}, x)[0]
+
+    @jax.named_scope("mlp")
+    def _mlp_residual(self, params, x):
         h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
         h, _ = self._mlp(params, h, False, None)
-        return x + h, new_cache
+        return x + h
+
+    def apply_cached(self, params, x, cache, offset):
+        h = self._ln1(params, x)
+        h, new_cache = self.attn.apply_cached({"params": params["attn"]}, h, cache, offset)
+        with jax.named_scope("attn_out"):
+            x = x + h
+        return self._mlp_residual(params, x), new_cache
 
     def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
                     layer, q_lens=None):
         """apply_cached against the paged KV pool instead of an assembled
         cache — see MultiHeadAttention.apply_paged for the contract."""
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
+        h = self._ln1(params, x)
         h, pages_k, pages_v = self.attn.apply_paged(
             {"params": params["attn"]}, h, pages_k, pages_v, block_tables,
             offsets, layer=layer, q_lens=q_lens)
-        x = x + h
-        h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        h, _ = self._mlp(params, h, False, None)
-        return x + h, pages_k, pages_v
+        with jax.named_scope("attn_out"):
+            x = x + h
+        return self._mlp_residual(params, x), pages_k, pages_v
 
     def output_shape(self, input_shape):
         return tuple(input_shape)

@@ -229,7 +229,7 @@ def fused_decode_stack(x, t, k_cache, v_cache, stacks: Dict[str, Any], *,
     kern = functools.partial(_decode_kernel, num_heads=num_heads,
                              chunks=chunks, scale=scale)
     f = pl.pallas_call(
-        kern, grid=grid,
+        kern, name="tnn_decode_stack", grid=grid,
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         input_output_aliases={2: 1, 3: 2},
         scratch_shapes=[

@@ -298,6 +298,7 @@ def _flash_fwd(q, k, v, mask, off, causal, scale, block_q, block_k,
         inputs.append(mp)
     out, lse = pl.pallas_call(
         kernel,
+        name="tnn_flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -573,6 +574,7 @@ def _flash_bwd(causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
         inputs.append(maskp)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
+        name="tnn_flash_bwd_dq",
         grid=(b * h, sq_p // bq, skv_p // bk),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -613,6 +615,7 @@ def _flash_bwd(causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
         inputsT.append(maskp)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
+        name="tnn_flash_bwd_dkv",
         grid=(b * h, skv_p // bk, sq_p // bq),
         in_specs=in_specsT,
         out_specs=[kvT_spec, kvT_spec],
@@ -711,6 +714,7 @@ def _flash_bwd_fused(causal, scale, bq, bk, clamp_dead, residuals, g):
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, kv_len=skv, has_mask=has_mask),
+        name="tnn_flash_bwd_fused",
         grid=(b * h, skv_p // bk, sq_p // bq),
         in_specs=in_specs,
         out_specs=[

@@ -271,6 +271,9 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
     out = pl.pallas_call(
         functools.partial(kernel, scale=scale, bs=bs, g=g, qw=qw,
                           stats=stats),
+        # the name the device profile shows; the variants are other kernels
+        name="tnn_paged_attention" + ("_int8" if quant else "")
+        + ("_stats" if stats else ""),
         grid_spec=grid_spec,
         out_shape=out_shape,
         # scratch carries only along the innermost (page) sweep
@@ -471,6 +474,7 @@ def _check_args(q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale):
     return q, was_3d, q_lens, pages_k, pages_v, scale
 
 
+@jax.named_scope("paged_attn")
 def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
                     q_lens=None, layer=0, scale: Optional[float] = None,
                     backend: str = "auto",
@@ -537,6 +541,7 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
     return out[:, 0] if was_3d else out
 
 
+@jax.named_scope("kv_write")
 def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):
     """Write one new KV row per sequence at its decode position.
 
@@ -574,6 +579,7 @@ def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):
     return pages.at[blk, :, slot, :].set(rows)
 
 
+@jax.named_scope("kv_write")
 def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
                      layer=None):
     """Write a ragged chunk of new KV rows per sequence.

@@ -187,6 +187,7 @@ def int8_matmul(x, q, scale, *, n: int | None = None, k: int | None = None,
 
     out = pl.pallas_call(
         functools.partial(_kernel, nk=kp // bk),
+        name="tnn_quant_matmul",
         grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki),

@@ -6,16 +6,17 @@ re-bases timestamps (profiler.hpp:52-63), process-global ``GlobalProfiler``
 (profiler.hpp:132). Rendered by visualizers/visualize_profiler.py as a Gantt chart; here
 the export is standard Chrome trace JSON (chrome://tracing / Perfetto) instead.
 
-TPU-first addition: ``device_trace`` wraps ``jax.profiler`` so device-side XPlane traces
-(per-op HLO timing on the TPU) are captured alongside the host-side event timeline.
+TPU-first addition: ``span`` writes a host span into the JAX profiler's own trace
+(``jax.profiler.TraceAnnotation``), so that under ``jax.profiler.start_trace`` the
+program's phases sit on one clock with the per-op HLO timing of the device.
 """
 from .profiler import (
     Event,
     EventType,
     GlobalProfiler,
     Profiler,
-    device_trace,
     profiled,
+    span,
 )
 
 __all__ = [
@@ -23,6 +24,6 @@ __all__ = [
     "EventType",
     "Profiler",
     "GlobalProfiler",
-    "device_trace",
     "profiled",
+    "span",
 ]

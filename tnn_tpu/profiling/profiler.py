@@ -11,6 +11,9 @@ Parity map (reference -> here):
 - serialized Profiler travelling the control plane as a message payload
   (message.hpp:21, binary_serializer.hpp:46) -> ``to_dict``/``from_dict`` (JSON-safe).
 - communicator per-key microsecond counters (communicator.hpp:157-184) -> ``counters``.
+
+``span`` is the other sink: the same host spans written into the JAX profiler's
+trace (``jax.profiler.TraceAnnotation``), on one clock with the device ops.
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
+
+import jax
 
 
 class EventType(enum.Enum):
@@ -214,15 +219,10 @@ def profiled(name: str, type: EventType = EventType.COMPUTE,
         yield
 
 
-@contextlib.contextmanager
-def device_trace(logdir: str) -> Iterator[None]:
-    """Capture a device-side XPlane trace via jax.profiler (view with xprof/
-    tensorboard). The TPU-native analog of the reference's COMPUTE event stream —
-    per-HLO timing straight from the runtime rather than host-side wall clocks."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+#: A host span on the JAX profiler's own clock: ``with span(name, **attrs):``
+#: enters a ``jax.profiler.TraceAnnotation`` whose ``attrs`` become the
+#: event's stats (never part of its name). While somebody records a profile
+#: (``jax.profiler.start_trace``, the benchmark's ``--trace 1``) the span
+#: lands in the host plane beside the device ops; outside a session entering
+#: it costs an atomic load, so call sites carry no flag.
+span = jax.profiler.TraceAnnotation

@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import sampling
-from ..profiling.profiler import EventType, Profiler, profiled
+from ..profiling.profiler import EventType, Profiler
 from ..utils.bucketing import pow2_bucket
 from . import kv_pool as kv_pool_lib
 from . import spec_decode
@@ -202,14 +202,17 @@ class InferenceEngine:
         publishes while live-request pool occupancy exceeds this fraction
         (growing the evictable set under pressure just churns reclaims;
         matching stays on). Counted in ``stats()["publish_suspended"]``.
-    profiler : optional profiling.Profiler for span/counter wiring.
+    profiler : optional profiling.Profiler, the second sink of the engine's
+        spans (the first, the JAX profiler's trace, is always on: see
+        ``tracing.Tracer`` and docs/observability.md).
     trace : request-scoped tracing — every request gets a ``trace_id`` and
-        the engine emits admission/chunk/preemption/publish/finish instants
-        (plus the compute spans ``profiler`` already records) into the
-        profiler timeline, one Perfetto track per profiler ``source``.
-        Auto-creates a ``Profiler(source="engine")`` when none is given.
-        Tracing is host-side only: traced runs are token-exact vs untraced
-        and the TNN_DEBUG_SYNC transfer guard stays clean.
+        the engine's phase spans (``serve.build`` / ``dispatch`` / ``fetch``
+        / ``commit`` / ``deferred``) and admission/chunk/preemption/publish/
+        finish instants also land in the ``Profiler`` timeline, one Perfetto
+        track per profiler ``source``. Auto-creates a
+        ``Profiler(source="engine")`` when none is given. Tracing is
+        host-side only: traced runs are token-exact vs untraced and the
+        TNN_DEBUG_SYNC transfer guard stays clean.
     overlap : double-buffered engine loop. ``begin_step`` builds and
         DISPATCHES a step without fetching its results; ``finish_step``
         later fetches the step's one sampled-token/ok/accepts bundle and
@@ -498,6 +501,9 @@ class InferenceEngine:
         # reuses it so the key-consumption sequence matches overlap-off
         self._reuse_key = None
         self._t_fetch_done: Optional[float] = None
+        # the open phase of the worker's time (``_open_phase``): (span,
+        # start, observer) of a serve.build or serve.commit in progress
+        self._phase: Optional[tuple] = None
         # last step's wall time, exposed through the health gauges so the
         # router's health scoring can see a gray-slow replica without ever
         # reaching into the engine
@@ -679,8 +685,7 @@ class InferenceEngine:
             "tier_blocks": len(self.kv_tier) if self.kv_tier is not None
             else 0,
             **self._gauge_extras}
-        if self.tracer.enabled:
-            self.tracer.instant("serve.submit", trace=req.trace_id, rid=rid)
+        self.tracer.instant("serve.submit", trace=req.trace_id, rid=rid)
         return rid
 
     def cancel(self, rid: int, reason: str = "cancelled by client") -> bool:
@@ -777,10 +782,9 @@ class InferenceEngine:
             self.pool.free(req.block_table)
             req.block_table = []
         self.scheduler.terminate(req, state, error)
-        if self.tracer.enabled:
-            self.tracer.instant("serve.terminal", trace=req.trace_id,
-                                rid=req.rid, state=state.value,
-                                step=self.step_seq)
+        self.tracer.instant("serve.terminal", trace=req.trace_id,
+                            rid=req.rid, state=state.value,
+                            step=self.step_seq)
         if state is RequestState.FAILED:
             self.metrics.observe_failed()
         elif state is RequestState.CANCELLED:
@@ -801,9 +805,8 @@ class InferenceEngine:
         self.metrics.observe_queue_wait(wait)
         req.phase = "prefill"
         req.phase_t0 = now
-        if self.tracer.enabled:
-            self.tracer.instant("serve.admit", trace=req.trace_id,
-                                rid=req.rid, step=self.step_seq)
+        self.tracer.instant("serve.admit", trace=req.trace_id,
+                            rid=req.rid, step=self.step_seq)
 
     def _note_prefill_done(self, req: Request, now: float) -> None:
         """Prompt fully resident: close the prefill clock, open decode."""
@@ -884,12 +887,14 @@ class InferenceEngine:
             r.rid: r.num_generated for r in self.scheduler.running
             if r.state is RequestState.RUNNING
             and r.cache_len >= r.prefill_len}
+        self._open_phase("serve.build", "observe_build")
         try:
             with self._sync_guard():
                 self._build_step(flight)
         except BaseException:
             self._finalize_note(flight)
             raise
+        self._close_phase()         # a step that launched nothing
         self._flight = flight
         return flight
 
@@ -905,7 +910,7 @@ class InferenceEngine:
             raise RuntimeError("no step in flight")
         try:
             with self._sync_guard():
-                self._commit_step(flight)
+                self._commit_step(flight)   # serve.commit opens at the fetch
         finally:
             flight.done = True
             self._flight = None
@@ -921,6 +926,7 @@ class InferenceEngine:
                     r.num_generated == flight.gen_before.get(r.rid, -1):
                 r.stall_s += flight.latency_s
         self._resolve_speculation(flight)
+        self._close_phase()
         return flight.events
 
     def run_deferred(self) -> int:
@@ -929,11 +935,42 @@ class InferenceEngine:
         overlapped drive loops run this while the next step executes
         on-device; the synchronous ``step()`` drains it before returning,
         so overlap-off behavior is unchanged. Returns the items run."""
+        if not self._deferred:
+            return 0
         n = 0
-        while self._deferred:
-            self._deferred.pop(0)()
-            n += 1
+        with self.tracer.span("serve.deferred", step=self.step_seq):
+            while self._deferred:
+                self._deferred.pop(0)()
+                n += 1
         return n
+
+    def _open_phase(self, name: str, observe: str) -> None:
+        """Open one of the worker's phases that end somewhere else than
+        they start (``serve.build`` ends at the step's first launch,
+        ``serve.commit`` starts inside the fetch helper): a span in the
+        tracer's sinks, and its length into the ``observe`` method of the
+        metrics registry current when it closes. A phase a crash left open
+        closes here, at the next one."""
+        self._close_phase()
+        sp = self.tracer.span(name, step=self.step_seq)
+        sp.__enter__()
+        self._phase = (sp, time.perf_counter(), observe)
+
+    def _close_phase(self) -> None:
+        if self._phase is not None:
+            sp, t0, observe = self._phase
+            self._phase = None
+            getattr(self.metrics, observe)(time.perf_counter() - t0)
+            sp.__exit__(None, None, None)
+
+    def _dispatch_span(self, kind: str, key, step: Optional[int] = None):
+        """``serve.dispatch``: the ENQUEUE of one compiled program (its
+        inputs' ``device_put`` and the asynchronous launch), not its
+        compute — that is the device's, and shows as ``serve.fetch``."""
+        return self.tracer.span(
+            "serve.dispatch", EventType.COMPUTE,
+            step=self.step_seq if step is None else step, kind=kind,
+            key=key)
 
     @property
     def in_flight(self) -> Optional["StepInFlight"]:
@@ -1020,15 +1057,17 @@ class InferenceEngine:
                                        self.pool.blocks_per_shard)
         return self._put(blk, dtype)
 
-    def _jit_step(self, fn, *, donate_argnums=(), n_outs: int = 4,
+    def _jit_step(self, name: str, fn, *, donate_argnums=(), n_outs: int = 4,
                   pages_argnums=(1, 2), pages_out=None, params_argnum=0,
                   tables_argnum=None):
-        """Compile a step body: plain jit at tp=sp=1 (byte-identical
-        programs to before TP/SP existed), shard_map over the TP or SP mesh
+        """Compile a step body under a stable program ``name`` (the device
+        profile's ``XLA Modules`` line reads ``jit_<name>``): plain jit at
+        tp=sp=1, shard_map over the TP or SP mesh
         otherwise. The extra keyword arguments describe which
         operands/outputs are the sharded page bundles and (under SP) which
         operand is the stacked per-shard block table — plain jit and TP
         ignore ``tables_argnum`` (TP tables are replicated)."""
+        fn.__name__ = name
         if self._sp is not None:
             return self._sp.jit_step(
                 fn, donate_argnums=donate_argnums, n_outs=n_outs,
@@ -1127,9 +1166,11 @@ class InferenceEngine:
         ``fetch-outside-commit`` lint rule pins every ``jax.device_get``
         on the step path to this helper): one batched transfer returns
         every launched program's sampled-token/ok/accepts bundle."""
-        with profiled("serve.fetch", EventType.COMPUTE, self.profiler):
+        with self.tracer.span("serve.fetch", EventType.COMPUTE,
+                              step=self.step_seq):
             out = jax.device_get(tuple(devs))
         self._t_fetch_done = time.perf_counter()
+        self._open_phase("serve.commit", "observe_commit")
         return out
 
     def _commit_rec(self, rec: Dict[str, Any], out, events) -> None:
@@ -1157,10 +1198,13 @@ class InferenceEngine:
         self._abort_batch(rows, error, flight.events)
 
     def _mark_dispatch(self) -> None:
-        """Stamp the step's first jitted launch: the wall gap since the
-        previous bundle fetch is the host gap the overlapped loop exists
-        to close. First launch of a step consumes the stamp; speculative
-        dispatches record a zero gap at adoption instead."""
+        """Stamp the step's first jitted launch: ``serve.build`` ends here,
+        and the wall gap since the previous bundle fetch is the host gap
+        the overlapped loop exists to close (in a profile: what lies
+        between a ``serve.fetch`` and the next ``serve.dispatch``). First
+        launch of a step consumes the stamp; speculative dispatches record
+        a zero gap at adoption instead."""
+        self._close_phase()
         t = self._t_fetch_done
         if t is None:
             return
@@ -1170,9 +1214,6 @@ class InferenceEngine:
         for r in self.scheduler.running:
             if r.state is RequestState.RUNNING:
                 r.host_gap_s += gap
-        if self.tracer.enabled:
-            self.tracer.instant("serve.host_gap", step=self.step_seq,
-                                ms=round(gap * 1e3, 3))
 
     def _step_key(self):
         """The step's PRNG key: normally the next split, but a rebuild
@@ -1256,8 +1297,7 @@ class InferenceEngine:
         prev_tok = rec["dev"][0]     # step N's unfetched sampled tokens
         try:
             with self._sync_guard(), \
-                    profiled("serve.decode_spec", EventType.COMPUTE,
-                             self.profiler):
+                    self._dispatch_span(label, key, self.step_seq + 1):
                 newtok, ok, pk, pv = fn(
                     self.params, self.pool.pages_k, self.pool.pages_v,
                     prev_tok, self._put(offsets), self._put_tables(step.tables),
@@ -1355,9 +1395,8 @@ class InferenceEngine:
                     or req.block_table[:len(snap)] != snap):
                 return
             cache.publish(tokens, snap, clen)
-            if self.tracer.enabled:
-                self.tracer.instant("serve.publish", trace=req.trace_id,
-                                    rid=req.rid, step=step)
+            self.tracer.instant("serve.publish", trace=req.trace_id,
+                                rid=req.rid, step=step)
 
         run.rid = req.rid
         self._deferred.append(run)
@@ -1453,8 +1492,8 @@ class InferenceEngine:
 
         # pool buffers are donated: the scatter updates pages in place
         # instead of copying the whole pool per prefill
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=4,
-                              tables_argnum=5)
+        return self._jit_step("tnn_serve_prefill", fn, donate_argnums=(1, 2),
+                              n_outs=4, tables_argnum=5)
 
     def _prefill_build(self, req: Request, events) -> Optional[Dict[str, Any]]:
         """Legacy whole-prompt prefill, build/dispatch half: allocate the
@@ -1501,8 +1540,7 @@ class InferenceEngine:
             fn = self._jit[key] = self._prefill_fn(padded, nb_bucket)
         try:
             self._mark_dispatch()
-            with profiled("serve.prefill", EventType.COMPUTE,
-                          self.profiler):
+            with self._dispatch_span("prefill", key):
                 tok, ok, pk, pv = fn(
                     self.params, self.pool.pages_k, self.pool.pages_v,
                     self._put(ids), self._put(len(seq), jnp.int32),
@@ -1551,9 +1589,8 @@ class InferenceEngine:
             req.out_tokens.append(tok)
             req.ttft_s = now - req.submit_time
             self.metrics.observe_ttft(req.ttft_s)
-            if self.tracer.enabled:
-                self.tracer.instant("serve.first_token", trace=req.trace_id,
-                                    rid=req.rid, step=self.step_seq)
+            self.tracer.instant("serve.first_token", trace=req.trace_id,
+                                rid=req.rid, step=self.step_seq)
             events["tokens"].append((req.rid, tok))
             self._maybe_finish(req, tok, events)
 
@@ -1592,9 +1629,10 @@ class InferenceEngine:
                 return (kv_pool_lib.copy_blocks(pages_k, src, dst),
                         kv_pool_lib.copy_blocks(pages_v, src, dst))
 
-            return self._jit_step(sp_fn, donate_argnums=(0, 1), n_outs=2,
-                                  pages_argnums=(0, 1), pages_out=(0, 1),
-                                  params_argnum=None, tables_argnum=2)
+            return self._jit_step("tnn_kv_cow", sp_fn, donate_argnums=(0, 1),
+                                  n_outs=2, pages_argnums=(0, 1),
+                                  pages_out=(0, 1), params_argnum=None,
+                                  tables_argnum=2)
 
         def fn(pages_k, pages_v, src, dst):
             # kv_pool.copy_blocks: under int8 the scale sidecar clones with
@@ -1603,8 +1641,8 @@ class InferenceEngine:
                     kv_pool_lib.copy_blocks(pages_v, src, dst))
 
         # donated + traced src/dst: one compile, in-place block copy
-        return self._jit_step(fn, donate_argnums=(0, 1), n_outs=2,
-                              pages_argnums=(0, 1), pages_out=(0, 1),
+        return self._jit_step("tnn_kv_cow", fn, donate_argnums=(0, 1),
+                              n_outs=2, pages_argnums=(0, 1), pages_out=(0, 1),
                               params_argnum=None)
 
     def _demote_blocks(self, blocks: List[int]) -> None:
@@ -1624,7 +1662,7 @@ class InferenceEngine:
             return
         host = self.pool.export_blocks([b for b, _ in pairs])
         for (b, key), leaves in zip(pairs, host):
-            if self.kv_tier.demote(key, leaves) and self.tracer.enabled:
+            if self.kv_tier.demote(key, leaves):
                 self.tracer.instant("tier.demote", block=b,
                                     tier_blocks=len(self.kv_tier),
                                     tier_bytes=self.kv_tier.bytes_used)
@@ -1640,7 +1678,8 @@ class InferenceEngine:
                 return (kv_pool_lib.write_block(pages_k, b, payload_k),
                         kv_pool_lib.write_block(pages_v, b, payload_v))
 
-            return self._jit_step(sp_fn, donate_argnums=(0, 1), n_outs=2,
+            return self._jit_step("tnn_tier_adopt", sp_fn,
+                                  donate_argnums=(0, 1), n_outs=2,
                                   pages_argnums=(0, 1), pages_out=(0, 1),
                                   params_argnum=None, tables_argnum=2)
 
@@ -1651,8 +1690,8 @@ class InferenceEngine:
                     kv_pool_lib.write_block(pages_v, blk, payload_v))
 
         # donated pages + traced block id: one compile serves every readmit
-        return self._jit_step(fn, donate_argnums=(0, 1), n_outs=2,
-                              pages_argnums=(0, 1), pages_out=(0, 1),
+        return self._jit_step("tnn_tier_adopt", fn, donate_argnums=(0, 1),
+                              n_outs=2, pages_argnums=(0, 1), pages_out=(0, 1),
                               params_argnum=None)
 
     def _tier_payload(self, leaves):
@@ -1718,10 +1757,9 @@ class InferenceEngine:
             readmitted += 1
         if readmitted:
             self.metrics.observe_tier_hit(readmitted)
-            if self.tracer.enabled:
-                self.tracer.instant("tier.readmit", blocks=readmitted,
-                                    tier_blocks=len(self.kv_tier),
-                                    tier_bytes=self.kv_tier.bytes_used)
+            self.tracer.instant("tier.readmit", blocks=readmitted,
+                                tier_blocks=len(self.kv_tier),
+                                tier_bytes=self.kv_tier.bytes_used)
 
     # -- cross-replica KV handoff (disaggregated serving) ---------------------
 
@@ -1783,11 +1821,10 @@ class InferenceEngine:
                 exports.append((key, leaves, tier_digest(key, leaves)))
         if exports:
             self.metrics.observe_handoff_export(len(exports))
-            if self.tracer.enabled:
-                self.tracer.instant("handoff.export", blocks=len(exports),
-                                    wire_bytes=sum(
-                                        sum(x.nbytes for x in lv)
-                                        for _, lv, _ in exports))
+            self.tracer.instant("handoff.export", blocks=len(exports),
+                                wire_bytes=sum(
+                                    sum(x.nbytes for x in lv)
+                                    for _, lv, _ in exports))
         return exports
 
     def _wire_leaves_ok(self, leaves) -> bool:
@@ -1877,8 +1914,7 @@ class InferenceEngine:
             resident += 1
         if adopted:
             self.metrics.observe_handoff_adopt(adopted)
-            if self.tracer.enabled:
-                self.tracer.instant("handoff.adopt", blocks=adopted)
+            self.tracer.instant("handoff.adopt", blocks=adopted)
         return resident
 
     def prefix_keys(self) -> List[bytes]:
@@ -2190,8 +2226,8 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with profiled("serve.mixed", EventType.COMPUTE,
-                              self.profiler):
+                with self._dispatch_span("spec" if spec_on else "mixed",
+                                         key):
                     if spec_on:
                         accepts, newtok, ok, pk, pv = fn(
                             self.params, self.pool.pages_k, self.pool.pages_v,
@@ -2296,10 +2332,9 @@ class InferenceEngine:
             self.metrics.observe_prefill_chunk(take)
             if self.faults is not None:
                 self.faults.prefill_delay(take)
-            if self.tracer.enabled:
-                self.tracer.instant("serve.prefill_chunk",
-                                    trace=req.trace_id, rid=req.rid,
-                                    step=self.step_seq, take=take)
+            self.tracer.instant("serve.prefill_chunk",
+                                trace=req.trace_id, rid=req.rid,
+                                step=self.step_seq, take=take)
             if self.prefix_cache is not None:
                 # every block this chunk just FILLED is immutable now —
                 # index it so the next shared-prefix request forks it.
@@ -2327,9 +2362,8 @@ class InferenceEngine:
             req.out_tokens.append(tok)
             req.ttft_s = now - req.submit_time
             self.metrics.observe_ttft(req.ttft_s, under_load=n_dec > 0)
-            if self.tracer.enabled:
-                self.tracer.instant("serve.first_token", trace=req.trace_id,
-                                    rid=req.rid, step=self.step_seq)
+            self.tracer.instant("serve.first_token", trace=req.trace_id,
+                                rid=req.rid, step=self.step_seq)
             events["tokens"].append((req.rid, tok))
             self._maybe_finish(req, tok, events)
         self.metrics.observe_mixed_step(
@@ -2359,8 +2393,8 @@ class InferenceEngine:
             newtok = sampling.sample_ragged(last, key, t, k, p)
             return newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=4,
-                              tables_argnum=6)
+        return self._jit_step(f"tnn_serve_mixed_w{qw}", fn,
+                              donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
 
     def _mixed_standard_fn(self, b: int, qw: int, nb: int):
         model = self._step_model
@@ -2380,19 +2414,23 @@ class InferenceEngine:
             # through scatter_chunk's q_lens mask, so it never leaks
             pad = [(0, 0), (0, 0), (0, 0), (0, qw), (0, 0)]
             kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
-            x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                   toks)                        # (B, qw, D)
-            x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                   x, offset=starts)
+            with jax.named_scope("embed"):
+                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
+                                       toks)                    # (B, qw, D)
+                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
+                                       x, offset=starts)
             rows_k, rows_v = [], []
             idx = (starts[:, None] + jnp.arange(qw))[:, None, :, None]
             for i, block in enumerate(model.blocks):
                 cache = {"k": kf[i], "v": vf[i]}
-                x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                              starts)
+                with jax.named_scope(f"h{i}"):
+                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
+                                                  starts)
                 rows_k.append(jnp.take_along_axis(cache["k"], idx, axis=2))
                 rows_v.append(jnp.take_along_axis(cache["v"], idx, axis=2))
-            x, _ = model.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope("ln_f"):
+                x, _ = model.ln_f.apply(
+                    {"params": params["ln_f"], "state": {}}, x)
             # project only each row's last LIVE position through the head —
             # (B, 1, V) instead of a (B, qw, V) logits cube
             xl = jnp.take_along_axis(
@@ -2408,11 +2446,12 @@ class InferenceEngine:
                                                 rows_v, q_lens)
             return newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=4,
-                              tables_argnum=6)
+        return self._jit_step(f"tnn_serve_mixed_gather_w{qw}", fn,
+                              donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
 
     # -- speculative verification ----------------------------------------------
 
+    @jax.named_scope("sample")
     def _spec_verify(self, logits, toks, q_lens, n_draft, t, k, p, key,
                      poison):
         """Token-exact verification of a ragged speculative step from the
@@ -2484,8 +2523,8 @@ class InferenceEngine:
                                          t, k, p, key, poison)
             return accepts, newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=5,
-                              tables_argnum=6)
+        return self._jit_step(f"tnn_serve_spec_w{qw}", fn,
+                              donate_argnums=(1, 2), n_outs=5, tables_argnum=6)
 
     def _spec_standard_fn(self, b: int, qw: int, nb: int):
         model = self._step_model
@@ -2500,19 +2539,23 @@ class InferenceEngine:
             # same assembly-edge headroom rationale as _mixed_standard_fn
             pad = [(0, 0), (0, 0), (0, 0), (0, qw), (0, 0)]
             kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
-            x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                   toks)
-            x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                   x, offset=starts)
+            with jax.named_scope("embed"):
+                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
+                                       toks)
+                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
+                                       x, offset=starts)
             rows_k, rows_v = [], []
             idx = (starts[:, None] + jnp.arange(qw))[:, None, :, None]
             for i, block in enumerate(model.blocks):
                 cache = {"k": kf[i], "v": vf[i]}
-                x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                              starts)
+                with jax.named_scope(f"h{i}"):
+                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
+                                                  starts)
                 rows_k.append(jnp.take_along_axis(cache["k"], idx, axis=2))
                 rows_v.append(jnp.take_along_axis(cache["v"], idx, axis=2))
-            x, _ = model.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope("ln_f"):
+                x, _ = model.ln_f.apply(
+                    {"params": params["ln_f"], "state": {}}, x)
             # verification needs every position's logits, so the whole row
             # goes through the head — (B, qw, V), the price of lookahead
             logits = model._head(params, x)
@@ -2526,8 +2569,8 @@ class InferenceEngine:
                                                 rows_v, q_lens)
             return accepts, newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=5,
-                              tables_argnum=6)
+        return self._jit_step(f"tnn_serve_spec_gather_w{qw}", fn,
+                              donate_argnums=(1, 2), n_outs=5, tables_argnum=6)
 
     def _preempt(self, req: Request) -> None:
         self._note_leave_running(req, time.perf_counter())
@@ -2536,9 +2579,8 @@ class InferenceEngine:
         req.cache_len = 0
         self.scheduler.requeue(req)
         self.metrics.observe_preemption(req.rid)
-        if self.tracer.enabled:
-            self.tracer.instant("serve.preempt", trace=req.trace_id,
-                                rid=req.rid, step=self.step_seq)
+        self.tracer.instant("serve.preempt", trace=req.trace_id,
+                            rid=req.rid, step=self.step_seq)
 
     def _decode_fn(self, batch: int, nb: int):
         model = self._step_model
@@ -2549,21 +2591,25 @@ class InferenceEngine:
             kf, vf = kv_pool_lib.gather_kv(
                 pages_k, pages_v, tables,
                 out_dtype=model.policy.compute_dtype, axis_name=sp_axis)
-            x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                   toks[:, None])                 # (B, 1, D)
-            x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                   x, offset=offsets)
+            with jax.named_scope("embed"):
+                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
+                                       toks[:, None])           # (B, 1, D)
+                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
+                                       x, offset=offsets)
             rows_k, rows_v = [], []
             idx = offsets[:, None, None, None]
             for i, block in enumerate(model.blocks):
                 cache = {"k": kf[i], "v": vf[i]}
-                x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                              offsets)
+                with jax.named_scope(f"h{i}"):
+                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
+                                                  offsets)
                 rows_k.append(
                     jnp.take_along_axis(cache["k"], idx, axis=2)[:, :, 0])
                 rows_v.append(
                     jnp.take_along_axis(cache["v"], idx, axis=2)[:, :, 0])
-            x, _ = model.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope("ln_f"):
+                x, _ = model.ln_f.apply(
+                    {"params": params["ln_f"], "state": {}}, x)
             logits = model._head(params, x)[:, -1] + poison[:, None]  # (B, V)
             ok = jnp.isfinite(logits).all(axis=-1)                # (B,)
             newtok = sampling.sample_ragged(logits, key, t, k, p)
@@ -2573,8 +2619,8 @@ class InferenceEngine:
                                                 jnp.stack(rows_v))
             return newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=4,
-                              tables_argnum=5)
+        return self._jit_step("tnn_serve_decode_gather", fn,
+                              donate_argnums=(1, 2), n_outs=4, tables_argnum=5)
 
     def _paged_decode_fn(self, batch: int, nb: int):
         model = self._step_model
@@ -2592,8 +2638,8 @@ class InferenceEngine:
             newtok = sampling.sample_ragged(logits, key, t, k, p)
             return newtok, ok, pages_k, pages_v
 
-        return self._jit_step(fn, donate_argnums=(1, 2), n_outs=4,
-                              tables_argnum=5)
+        return self._jit_step("tnn_serve_decode", fn, donate_argnums=(1, 2),
+                              n_outs=4, tables_argnum=5)
 
     def _fused_decode_fn(self, batch: int, nb: int):
         model = self.model
@@ -2612,16 +2658,19 @@ class InferenceEngine:
                 l, b, h, tt, dh = c.shape
                 return c.transpose(0, 1, 3, 2, 4).reshape(l, b, tt, h * dh)
             kc, vc = flat(kf), flat(vf)
-            x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                   toks[:, None])
-            x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                   x, offset=offset)
+            with jax.named_scope("embed"):
+                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
+                                       toks[:, None])
+                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
+                                       x, offset=offset)
             x_out, kc, vc = fused_decode_stack(
                 x[:, 0, :], offset, kc, vc, stacks,
                 num_heads=model.num_heads, chunks=fused["chunks"],
                 interpret=fused["interpret"])
-            xf, _ = model.ln_f.apply({"params": params["ln_f"], "state": {}},
-                                     x_out[:, None, :])
+            with jax.named_scope("ln_f"):
+                xf, _ = model.ln_f.apply(
+                    {"params": params["ln_f"], "state": {}},
+                    x_out[:, None, :])
             logits = model._head(params, xf)[:, -1] + poison[:, None]
             ok = jnp.isfinite(logits).all(axis=-1)
             newtok = sampling.sample_ragged(logits, key, t, k, p)
@@ -2637,6 +2686,7 @@ class InferenceEngine:
                 pages_v, tables, offsets, row_v.reshape(l, b, h, d // h))
             return newtok, ok, pages_k, pages_v
 
+        fn.__name__ = "tnn_serve_decode_fused"
         return jax.jit(fn, donate_argnums=(2, 3))
 
     def _decode_build(self, live: Sequence[Request],
@@ -2654,11 +2704,10 @@ class InferenceEngine:
         poison = step.poison
         if self.faults is not None:
             poison[:len(live)][self.faults.poison_rows(len(live))] = np.nan
-        label = {"pdecode": "serve.decode_paged",
-                 "fdecode": "serve.decode_fused",
-                 "decode": "serve.decode"}[key[0]]
-        self._note_program(label.split(".", 1)[1], key,
-                           [r.rid for r in live], fill=len(live) / b)
+        label = {"pdecode": "decode_paged", "fdecode": "decode_fused",
+                 "decode": "decode"}[key[0]]
+        self._note_program(label, key, [r.rid for r in live],
+                           fill=len(live) / b)
         fn = self._jit.get(key)
         if fn is None:
             fn = self._jit[key] = (
@@ -2673,7 +2722,7 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with profiled(label, EventType.COMPUTE, self.profiler):
+                with self._dispatch_span(label, key):
                     if lockstep:
                         newtok, ok, pk, pv = fn(
                             self.params, self._fused["stacks"],
@@ -2844,9 +2893,8 @@ class InferenceEngine:
             req.cache_len = 0
             self.scheduler.migrate(req)
             self.metrics.observe_migration(len(req.resume_tokens))
-            if self.tracer.enabled:
-                self.tracer.instant("serve.migrate", trace=req.trace_id,
-                                    rid=req.rid, step=self.step_seq)
+            self.tracer.instant("serve.migrate", trace=req.trace_id,
+                                rid=req.rid, step=self.step_seq)
         self.pool.reset_pages()
         if self.prefix_cache is not None:
             self.pool.purge_evictable()
@@ -2872,8 +2920,7 @@ class InferenceEngine:
         req.block_table = []
         self.scheduler.finish(req, reason)
         self.metrics.observe_finish(req.ttft_s)
-        if self.tracer.enabled:
-            self.tracer.instant("serve.finish", trace=req.trace_id,
-                                rid=req.rid, reason=reason,
-                                step=self.step_seq)
+        self.tracer.instant("serve.finish", trace=req.trace_id,
+                            rid=req.rid, reason=reason,
+                            step=self.step_seq)
         events["finished"].append(req.rid)

@@ -634,6 +634,7 @@ class PagedKVPool:
 # -- jit-safe assembly (trace into the engine's compiled steps) ---------------
 
 
+@jax.named_scope("kv_gather")
 def gather_kv(pages_k, pages_v, block_tables, out_dtype=None,
               axis_name=None):
     """Block tables -> contiguous ragged-batch caches.
@@ -684,6 +685,7 @@ def gather_kv(pages_k, pages_v, block_tables, out_dtype=None,
     return g(pages_k), g(pages_v)
 
 
+@jax.named_scope("kv_write")
 def scatter_prefill(pages, blocks, kv):
     """Write one sequence's contiguous prefill cache into its blocks.
 
@@ -706,6 +708,7 @@ def scatter_prefill(pages, blocks, kv):
     return pages.at[:, blocks].set(x)
 
 
+@jax.named_scope("kv_write")
 def scatter_token(pages, block_tables, offsets, rows):
     """Write one new KV row per sequence at its decode position.
 
@@ -732,6 +735,7 @@ def scatter_token(pages, block_tables, offsets, rows):
     return pages.at[:, blk, :, slot, :].set(rows.transpose(1, 0, 2, 3))
 
 
+@jax.named_scope("kv_write")
 def scatter_chunk(pages, block_tables, starts, rows, q_lens):
     """Write a ragged chunk of new KV rows per sequence, all layers at once.
 
@@ -765,6 +769,7 @@ def scatter_chunk(pages, block_tables, starts, rows, q_lens):
     return pages.at[:, blk, :, slot, :].set(rows.transpose(1, 2, 0, 3, 4))
 
 
+@jax.named_scope("kv_write")
 def write_block(pages, block, payload):
     """Write one whole page at ``block`` across every layer (the host-tier
     re-admission's device half). pages: (L, N, H, bs, Dh); block: scalar
@@ -780,6 +785,7 @@ def write_block(pages, block, payload):
     return pages.at[:, block].set(payload)
 
 
+@jax.named_scope("kv_write")
 def copy_blocks(pages, src, dst):
     """Copy whole pages ``src -> dst`` across every layer (the COW split's
     device half). src/dst: (n,) int32 block ids. Under QuantPages the scale

@@ -1,19 +1,14 @@
 """Serving metrics: TTFT, per-token latency, queue depth, pool occupancy,
-throughput — wired into profiling.profiler and a Prometheus exposition.
+throughput — counters and reservoirs where the work happens, and a
+Prometheus exposition. (Spans are ``tracing.Tracer``'s; nothing here touches
+a profiler.)
 
-The engine wraps prefill/decode work in ``profiling.profiled`` spans (visible
-in the Chrome trace alongside training spans) and mirrors the aggregate
-counters into a Profiler via ``tick`` under ``serve.*`` keys, so one merged
-timeline covers both a training job and the serving engine colocated with it.
-
-Three exposition surfaces share one observation path:
+Two exposition surfaces share one observation path:
 
 - ``summary()`` — the flat dict benchmarks and ``GET /v1/stats`` report.
 - ``prometheus_series()`` — counter/gauge/histogram families rendered by
   ``render_prometheus`` into text-format 0.0.4 for ``GET /metrics``; the
   Router merges per-replica families under a ``replica`` label.
-- ``Profiler.tick`` counters (when a profiler is wired) for the merged
-  training+serving timeline.
 
 Every ``_tick`` key MUST be registered in ``EXPOSITION`` (tick key →
 (prometheus name, type, help, summary key)); the ``unregistered-metric-key``
@@ -91,6 +86,21 @@ EXPOSITION: Dict[str, Tuple[str, str, str, str]] = {
         "tnn_serve_host_gap_seconds_total", "counter",
         "Cumulative wall gap between a step's result fetch and the next "
         "dispatch (device idle on host bookkeeping)", "host_gap_ms_p50"),
+    "serve.build_s": (
+        "tnn_serve_build_seconds_total", "counter",
+        "Cumulative host seconds building a step (serve.build: deadline "
+        "expiry, scheduling, admissions, input staging up to the first "
+        "launch)", "build_ms_p50"),
+    "serve.commit_s": (
+        "tnn_serve_commit_seconds_total", "counter",
+        "Cumulative host seconds committing a step after its fetch "
+        "(serve.commit: pool and scheduler state, stop checks, events)",
+        "commit_ms_p50"),
+    "serve.emit_delay_s": (
+        "tnn_serve_emit_delay_seconds_total", "counter",
+        "Cumulative seconds token events waited between their step's "
+        "commit and the front end's flush that wrote them",
+        "emit_delay_ms_p50"),
     "serve.overlap_rebuild": (
         "tnn_serve_overlap_rebuilds_total", "counter",
         "Speculatively dispatched steps rolled back on misprediction",
@@ -399,6 +409,8 @@ class ServingMetrics:
 
     Latency samples are wall-clock seconds; throughput is generated tokens
     over the span from the first observation to the latest one.
+    ``profiler`` is only remembered (callers that swap in a fresh registry
+    pass the engine's): counters are not mirrored into it.
     """
 
     def __init__(self, profiler: Optional[Profiler] = None, *,
@@ -418,6 +430,9 @@ class ServingMetrics:
         self.token_latency_s = res("token_latency_s")
         self.decode_stall_s = res("decode_stall_s")
         self.host_gap_s = res("host_gap_s")
+        self.build_s = res("build_s")
+        self.commit_s = res("commit_s")
+        self.emit_delay_s = res("emit_delay_s")
         self.step_latency_s = res("step_latency_s")
         self.queue_wait_s = res("queue_wait_s")
         self.queue_depth = res("queue_depth")
@@ -426,8 +441,7 @@ class ServingMetrics:
         self.mixed_step_fill = res("mixed_step_fill")
         self.finished_ttft_s = res("finished_ttft_s")  # TTFT of *finished*
         #: cumulative sum of every value ever ticked, by tick key — the
-        #: counter surface behind the Prometheus exposition (kept even when
-        #: no profiler is wired)
+        #: counter surface behind the Prometheus exposition
         self.counters: Dict[str, float] = {}
         #: fixed-bucket histograms for the EXPOSITION "histogram" families
         self.histograms: Dict[str, Histogram] = {
@@ -504,8 +518,6 @@ class ServingMetrics:
         hist = self.histograms.get(metric)
         if hist is not None:
             hist.observe(value)
-        if self.profiler is not None:
-            self.profiler.tick(metric, value)
 
     def observe_ttft(self, seconds: float, under_load: bool = False) -> None:
         """``under_load`` marks a first token produced while OTHER requests
@@ -566,6 +578,26 @@ class ServingMetrics:
         loop exists to drive this toward zero."""
         self.host_gap_s.append(seconds)
         self._tick("serve.host_gap_s", seconds)
+
+    def observe_build(self, seconds: float) -> None:
+        """One ``serve.build`` phase: ``begin_step`` up to its first launch
+        (deadline expiry, scheduling, admissions, input staging)."""
+        self.build_s.append(seconds)
+        self._tick("serve.build_s", seconds)
+
+    def observe_commit(self, seconds: float) -> None:
+        """One ``serve.commit`` phase: ``finish_step`` after its fetch (pool
+        and scheduler state, stop checks, event buckets)."""
+        self.commit_s.append(seconds)
+        self._tick("serve.commit_s", seconds)
+
+    def observe_emit_delay(self, seconds: float) -> None:
+        """One ``token`` event's wait between its step's commit and the
+        front end's flush that wrote it out: the front-end layer seen from
+        inside (half the stdin poll, on average, while a step is long).
+        Called from the front end's thread."""
+        self.emit_delay_s.append(seconds)
+        self._tick("serve.emit_delay_s", seconds)
 
     def observe_overlap_rebuild(self) -> None:
         """A speculatively dispatched step N+1 was rolled back because step
@@ -901,6 +933,12 @@ class ServingMetrics:
             "host_gap_ms_mean": ms(_mean(self.host_gap_s)),
             "host_gap_ms_p50": ms(_percentile(self.host_gap_s, 50)),
             "host_gap_ms_p99": ms(_percentile(self.host_gap_s, 99)),
+            "build_ms_p50": ms(_percentile(self.build_s, 50)),
+            "build_ms_p99": ms(_percentile(self.build_s, 99)),
+            "commit_ms_p50": ms(_percentile(self.commit_s, 50)),
+            "commit_ms_p99": ms(_percentile(self.commit_s, 99)),
+            "emit_delay_ms_p50": ms(_percentile(self.emit_delay_s, 50)),
+            "emit_delay_ms_p99": ms(_percentile(self.emit_delay_s, 99)),
             "overlap_rebuilds": self.overlap_rebuilds,
             "step_latency_ms_p50": ms(_percentile(self.step_latency_s, 50)),
             "step_latency_ms_p99": ms(_percentile(self.step_latency_s, 99)),

@@ -851,9 +851,8 @@ class Router:
         if self._thread is not None:
             sup.start()
         self.metrics.observe_replicas(self.num_active_replicas())
-        if self.tracer.enabled:
-            self.tracer.instant("scale.up", replica=idx,
-                                replicas=self.num_active_replicas())
+        self.tracer.instant("scale.up", replica=idx,
+                            replicas=self.num_active_replicas())
         self._wake.set()
         return idx
 
@@ -886,10 +885,9 @@ class Router:
         except Exception:  # noqa: BLE001 — a dying replica drains itself
             pass
         self.metrics.observe_replicas(self.num_active_replicas())
-        if self.tracer.enabled:
-            self.tracer.instant("scale.down", replica=idx, reason=reason,
-                                migrated=len(victims),
-                                replicas=self.num_active_replicas())
+        self.tracer.instant("scale.down", replica=idx, reason=reason,
+                            migrated=len(victims),
+                            replicas=self.num_active_replicas())
         self._wake.set()
         return True
 
@@ -1028,10 +1026,9 @@ class Router:
         while attempt <= self.max_retries:   # explicit retry budget
             if attempt:
                 self.metrics.observe_router_retry()
-                if self.tracer.enabled:
-                    self.tracer.instant(
-                        "router.retry", trace=rec.kwargs.get("trace_id"),
-                        gid=rec.gid, attempt=attempt)
+                self.tracer.instant(
+                    "router.retry", trace=rec.kwargs.get("trace_id"),
+                    gid=rec.gid, attempt=attempt)
                 delay = min(self.retry_backoff_s * (2 ** (attempt - 1)),
                             self.retry_backoff_max_s)
                 delay += float(self._rng.random()) * self.retry_jitter_s
@@ -1091,10 +1088,9 @@ class Router:
                 h.breaker.record_success()
                 h.health.observe_dispatch(rec.t_dispatch - t_call)
                 h.health.observe_outcome(True)
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "router.dispatch", trace=rec.kwargs.get("trace_id"),
-                    gid=rec.gid, replica=h.idx, rid=lrid)
+            self.tracer.instant(
+                "router.dispatch", trace=rec.kwargs.get("trace_id"),
+                gid=rec.gid, replica=h.idx, rid=lrid)
             return
         if raising and last is not None:
             raise last
@@ -1291,11 +1287,10 @@ class Router:
                 out = None
             remaining = rec.max_new - len(rec.emitted)
         if promoted_to is not None:
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "router.migrate", trace=rec.kwargs.get("trace_id"),
-                    gid=rec.gid, from_replica=h.idx,
-                    promoted_hedge=True, to_replica=promoted_to)
+            self.tracer.instant(
+                "router.migrate", trace=rec.kwargs.get("trace_id"),
+                gid=rec.gid, from_replica=h.idx,
+                promoted_hedge=True, to_replica=promoted_to)
             return
         if out is not None:
             self._emit(rec, out)
@@ -1315,11 +1310,10 @@ class Router:
             self._emit(rec, out)
             return
         self.metrics.observe_migration(len(rec.prompt) + len(rec.emitted))
-        if self.tracer.enabled:
-            self.tracer.instant(
-                "router.migrate", trace=rec.kwargs.get("trace_id"),
-                gid=rec.gid, from_replica=h.idx,
-                emitted=len(rec.emitted))
+        self.tracer.instant(
+            "router.migrate", trace=rec.kwargs.get("trace_id"),
+            gid=rec.gid, from_replica=h.idx,
+            emitted=len(rec.emitted))
         self._dispatch(rec)   # failure here emits the terminal error event
 
     def _finish_failed(self, rec: _Routed, kind: str, reason: str) -> None:
@@ -1414,11 +1408,10 @@ class Router:
                     if med > 0 and sc > self.degrade_factor * med:
                         if h.suspect_since is None:
                             h.suspect_since = now
-                            if self.tracer.enabled:
-                                self.tracer.instant(
-                                    "router.degrade", replica=h.idx,
-                                    score=round(sc, 4),
-                                    median=round(med, 4))
+                            self.tracer.instant(
+                                "router.degrade", replica=h.idx,
+                                score=round(sc, 4),
+                                median=round(med, 4))
                         elif (now - h.suspect_since
                               >= self.degrade_window_s
                               and non_degraded > 1):
@@ -1438,10 +1431,9 @@ class Router:
                             h.readmit_since = None
                             h.degraded_at = None
                             h.recovery_probing = False
-                            if self.tracer.enabled:
-                                self.tracer.instant(
-                                    "router.readmit", replica=h.idx,
-                                    score=round(sc, 4))
+                            self.tracer.instant(
+                                "router.readmit", replica=h.idx,
+                                score=round(sc, 4))
                     else:
                         h.readmit_since = None
                     if not h.live:
@@ -1460,11 +1452,10 @@ class Router:
         h.readmit_since = None
         h.recovery_probing = False
         self.metrics.observe_ejection()
-        if self.tracer.enabled:
-            self.tracer.instant("router.eject", replica=h.idx,
-                                score=round(score, 4),
-                                median=round(median, 4),
-                                live=len(h.live))
+        self.tracer.instant("router.eject", replica=h.idx,
+                            score=round(score, 4),
+                            median=round(median, 4),
+                            live=len(h.live))
         return [(self._open[gid], self._open[gid].epoch, h)
                 for gid in list(h.live) if gid in self._open
                 and self._open[gid].replica == h.idx]
@@ -1494,11 +1485,10 @@ class Router:
             self._cancel_quiet(h, old_lrid)
         self.metrics.observe_migration(len(rec.prompt) + len(rec.emitted))
         self.metrics.observe_proactive_migration()
-        if self.tracer.enabled:
-            self.tracer.instant(
-                "router.migrate", trace=rec.kwargs.get("trace_id"),
-                gid=rec.gid, from_replica=h.idx, proactive=True,
-                emitted=len(rec.emitted))
+        self.tracer.instant(
+            "router.migrate", trace=rec.kwargs.get("trace_id"),
+            gid=rec.gid, from_replica=h.idx, proactive=True,
+            emitted=len(rec.emitted))
         self._dispatch(rec)   # failure here emits the terminal error event
 
     # -- disaggregated serving: boundary handoff / fleet prefix cache ----------
@@ -1559,11 +1549,10 @@ class Router:
         if self.handoff_kv and handed == 0:
             self.metrics.observe_handoff_fallback()
         self.metrics.observe_migration(len(rec.prompt) + len(rec.emitted))
-        if self.tracer.enabled:
-            self.tracer.instant(
-                "router.handoff", trace=rec.kwargs.get("trace_id"),
-                gid=rec.gid, from_replica=h.idx, to_replica=target.idx,
-                adopted_blocks=handed, kv=self.handoff_kv)
+        self.tracer.instant(
+            "router.handoff", trace=rec.kwargs.get("trace_id"),
+            gid=rec.gid, from_replica=h.idx, to_replica=target.idx,
+            adopted_blocks=handed, kv=self.handoff_kv)
         self._dispatch(rec)   # failure here emits the terminal error event
 
     def _fleet_prefix_pull(self, rec: _Routed, h: _Replica) -> None:
@@ -1616,10 +1605,9 @@ class Router:
             with self._lock:
                 self._replica_keys.setdefault(h.idx, set()).update(
                     k for k, _, _ in exports)
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "router.prefix_pull", gid=rec.gid, source=best.idx,
-                    target=h.idx, blocks=adopted)
+            self.tracer.instant(
+                "router.prefix_pull", gid=rec.gid, source=best.idx,
+                target=h.idx, blocks=adopted)
 
     def _refresh_prefix_dir(self) -> None:
         """Probe-loop refresh of the fleet prefix directory: which replica
@@ -1660,9 +1648,8 @@ class Router:
                 want = "decode" if i < n_decode else "prefill"
                 if h.role != want:
                     h.role = want
-                    if self.tracer.enabled:
-                        self.tracer.instant("router.role", replica=h.idx,
-                                            role=want)
+                    self.tracer.instant("router.role", replica=h.idx,
+                                        role=want)
 
     def _hedge_threshold_locked(self) -> Optional[float]:
         """The TTFT past which a request gets hedged (caller holds the
@@ -1747,10 +1734,9 @@ class Router:
         if stale:
             self._cancel_quiet(hh, lrid)
             return False
-        if self.tracer.enabled:
-            self.tracer.instant(
-                "router.hedge", trace=rec.kwargs.get("trace_id"),
-                gid=rec.gid, replica=hh.idx, primary=primary)
+        self.tracer.instant(
+            "router.hedge", trace=rec.kwargs.get("trace_id"),
+            gid=rec.gid, replica=hh.idx, primary=primary)
         return True
 
     # -- health probe / lifecycle convergence ----------------------------------

@@ -350,8 +350,11 @@ class EngineSupervisor:
 
     def _run_commands(self, block: bool) -> None:
         try:
-            item = self._cmds.get(timeout=self.idle_wait_s) if block \
-                else self._cmds.get_nowait()
+            if block:       # nothing runnable: wait for a request
+                with self.tracer.span("serve.wait"):
+                    item = self._cmds.get(timeout=self.idle_wait_s)
+            else:
+                item = self._cmds.get_nowait()
         except queue.Empty:
             return
         ran = False
@@ -405,8 +408,7 @@ class EngineSupervisor:
         self._open[rid] = req
         if listener is not None:
             self._listeners[rid] = listener
-        if self.tracer.enabled:
-            self.tracer.instant("sup.admit", trace=req.trace_id, rid=rid)
+        self.tracer.instant("sup.admit", trace=req.trace_id, rid=rid)
         self._refresh_health()
         return rid
 
@@ -499,6 +501,19 @@ class EngineSupervisor:
         for rid, tok in events["tokens"]:
             self._emit(rid, {"event": "token", "id": rid, "token": int(tok)})
 
+    def _emit_step(self, events: Dict[str, List],
+                   record: Optional[Dict[str, Any]]) -> None:
+        """What follows a committed step on the worker: its flight record,
+        its token events and terminal events handed to the sinks, the
+        health snapshot. ``serve.emit`` is the phase span between the
+        step's ``serve.commit`` and the next ``serve.build``."""
+        with self.tracer.span("serve.emit",
+                              step=(record or {}).get("step_seq")):
+            self.flight.record(record)
+            self._dispatch_tokens(events)
+            self._sweep_terminals()
+            self._refresh_health()
+
     def _sweep_terminals(self) -> None:
         """The single emitter of terminal events: any open request observed
         in a terminal state gets exactly one structured event, no matter
@@ -534,8 +549,7 @@ class EngineSupervisor:
         self.restarts += 1
         self._wake.clear()
         self.engine.metrics.observe_restart()
-        if self.tracer.enabled:
-            self.tracer.instant("sup.restart", n=self.restarts)
+        self.tracer.instant("sup.restart", n=self.restarts)
         if self.restarts > self.max_restarts:
             self._dump_flight("restart_budget")
             self.engine.abort_all(
@@ -623,10 +637,7 @@ class EngineSupervisor:
             self._restart(f"engine step crashed: {type(e).__name__}: {e}")
             return
         dt = time.perf_counter() - t0
-        self.flight.record(self._last_step_record())
-        self._dispatch_tokens(events)
-        self._sweep_terminals()
-        self._refresh_health()
+        self._emit_step(events, self._last_step_record())
         if self.watchdog_step_s is not None and dt > self.watchdog_step_s:
             self._dump_flight("watchdog")
             self._restart(
@@ -688,10 +699,7 @@ class EngineSupervisor:
         dt = time.perf_counter() - t0
         # the engine's CURRENT note may belong to a speculative step N+1
         # already in flight — record the step that just committed instead
-        self.flight.record(eng.last_finished_record())
-        self._dispatch_tokens(events)
-        self._sweep_terminals()
-        self._refresh_health()
+        self._emit_step(events, eng.last_finished_record())
         if self.watchdog_step_s is not None and dt > self.watchdog_step_s:
             self._dump_flight("watchdog")
             self._restart(

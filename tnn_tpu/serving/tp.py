@@ -238,29 +238,36 @@ class TPModel:
         x, _ = self._trunk(params, ids, False, None, offset=offset)
         new_caches = []
         for i, block in enumerate(self.blocks):
-            x, c = block.apply_cached(params[f"h{i}"], x, caches[i], offset)
+            with jax.named_scope(f"h{i}"):
+                x, c = block.apply_cached(params[f"h{i}"], x, caches[i],
+                                          offset)
             new_caches.append(c)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+        with jax.named_scope("ln_f"):
+            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
         return self._head(params, x), new_caches
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):
         x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
         for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope(f"h{i}"):
+                x, pages_k, pages_v = block.apply_paged(
+                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
+                    offsets, layer=i)
+        with jax.named_scope("ln_f"):
+            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
         return self._head(params, x)[:, -1], pages_k, pages_v
 
     def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
                     offsets, q_lens):
         x, _ = self._trunk(params, toks, False, None, offset=offsets)
         for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i, q_lens=q_lens)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
+            with jax.named_scope(f"h{i}"):
+                x, pages_k, pages_v = block.apply_paged(
+                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
+                    offsets, layer=i, q_lens=q_lens)
+        with jax.named_scope("ln_f"):
+            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
         return self._head(params, x), pages_k, pages_v
 
 

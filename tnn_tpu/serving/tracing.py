@@ -4,13 +4,18 @@ Two small, host-side-only observability primitives (neither ever touches a
 device array, so the ``TNN_DEBUG_SYNC`` transfer guard and the
 host-sync-in-step-path lint stay clean with tracing enabled):
 
-- ``Tracer`` — a thin span/instant recorder over the existing
-  ``profiling.Profiler``. Engine, supervisor, and router each hold one;
-  spans carry ``(trace_id, rid, step_seq)`` encoded into the event name so
+- ``Tracer`` — the one span/instant API of the serving stack, with two
+  sinks. (1) The JAX profiler's trace, always: every span enters a
+  ``jax.profiler.TraceAnnotation`` (``profiling.span``) whose attributes are
+  the event's stats, so whenever somebody records a profile
+  (``jax.profiler.start_trace``, the benchmark's ``--trace 1``) the engine's
+  phases sit on one clock with the device ops. Outside a session that costs
+  an atomic load: no flag guards it. (2) A ``profiling.Profiler``, only when
+  one is wired (``trace=True``): there the attributes ``(trace_id, rid,
+  step_seq)`` are encoded into the event NAME so
   ``Profiler.to_chrome_trace`` yields one Perfetto view across
   router → replicas → engine steps (one track per profiler ``source``).
-  A ``Tracer(None)`` is a complete no-op: tracing off must cost nothing
-  and change nothing (tracing on ≡ off token-exact is a standing gate).
+  Tracing on must change nothing (on ≡ off token-exact is a standing gate).
 
 - ``FlightRecorder`` — a bounded ring buffer of recent engine step
   records (step kind + compile key, batch rids, fill, pool occupancy,
@@ -29,18 +34,17 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Deque, Dict, Iterator, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
-from ..profiling.profiler import EventType, Profiler
+from ..profiling.profiler import EventType, Profiler, span
 
 
 def span_name(base: str, **attrs: Any) -> str:
-    """Encode span attributes into the event name (``base k=v k=v``).
-
-    Chrome-trace ``args`` would be richer, but the profiler's event model
-    is (type, start, end, name, source) — flat names keep the span usable
-    by both ``to_chrome_trace`` and ``tools/visualize_profiler``.
+    """Encode span attributes into the event name (``base k=v k=v``) for the
+    ``Profiler`` sink, whose event model is (type, start, end, name, source):
+    flat names keep the span usable by both ``to_chrome_trace`` and
+    ``tools/visualize_profiler``. The profiler-trace sink carries the same
+    attributes as stats and leaves the name alone.
     """
     if not attrs:
         return base
@@ -48,38 +52,59 @@ def span_name(base: str, **attrs: Any) -> str:
     return base + (" " + " ".join(parts) if parts else "")
 
 
+class _Span:
+    """One open span in both sinks; ``Tracer.span`` returns it entered-able
+    (``with``) and the engine's phases close it non-lexically (``close``)."""
+
+    __slots__ = ("_profiler", "_type", "_name", "_ann", "_t0")
+
+    def __init__(self, profiler, type, name, ann):
+        self._profiler, self._type, self._name = profiler, type, name
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._profiler.add_event(self._type, self._t0, time.perf_counter(),
+                                 self._name)
+        self._ann.__exit__(*exc)
+        return False
+
+
 class Tracer:
-    """Span/instant recorder over a ``Profiler`` (no-op when profiler is
-    None). All methods are safe from any thread — the profiler locks."""
+    """Span/instant recorder: always into the JAX profiler's trace, and into
+    a ``Profiler`` when one is wired. All methods are safe from any thread —
+    the profiler locks, and a ``TraceAnnotation`` is per-thread."""
 
     def __init__(self, profiler: Optional[Profiler] = None):
         self.profiler = profiler
 
     @property
     def enabled(self) -> bool:
+        """Whether the ``Profiler`` sink is wired (the annotation sink is
+        always on and needs no guard)."""
         return self.profiler is not None
 
-    @contextmanager
     def span(self, base: str, type: EventType = EventType.OTHER,
-             **attrs: Any) -> Iterator[None]:
-        """Timed span: records ``base k=v ...`` over the body's duration."""
+             **attrs: Any):
+        """Timed span over a ``with`` body: ``base`` with ``attrs`` as stats
+        in the profiler trace, ``base k=v ...`` in the ``Profiler``."""
+        ann = span(base, **attrs)
         if self.profiler is None:
-            yield
-            return
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.profiler.add_event(type, start, time.perf_counter(),
-                                    span_name(base, **attrs))
+            return ann
+        return _Span(self.profiler, type, span_name(base, **attrs), ann)
 
     def instant(self, base: str, type: EventType = EventType.OTHER,
                 **attrs: Any) -> None:
-        """Zero-duration marker (dispatch, retry, preemption, publish...)."""
-        if self.profiler is None:
-            return
-        now = time.perf_counter()
-        self.profiler.add_event(type, now, now, span_name(base, **attrs))
+        """Zero-duration marker (submit, retry, preemption, publish...)."""
+        with span(base, **{k: v for k, v in attrs.items()
+                           if v is not None}):
+            pass
+        if self.profiler is not None:
+            now = time.perf_counter()
+            self.profiler.add_event(type, now, now, span_name(base, **attrs))
 
 
 class FlightRecorder:

@@ -303,8 +303,8 @@ def train_model(
                                                 reset=not continue_epoch,
                                                 limit=config.max_steps,
                                                 place=place_batch):
-                # host-side span = dispatch of one compiled step (device runs async; use
-                # profiling.device_trace for per-HLO timing). CUMULATIVE keeps only
+                # host-side span = dispatch of one compiled step (device runs async; record
+                # with jax.profiler.start_trace for per-HLO timing). CUMULATIVE keeps only
                 # constant-memory counters; NORMAL records one event per step.
                 if cumulative_prof:
                     t_step = time.perf_counter()

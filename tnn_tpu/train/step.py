@@ -22,6 +22,7 @@ from ..nn import losses as losses_lib
 from ..nn import metrics as metrics_lib
 from ..nn.optimizers import Optimizer
 from ..nn.schedulers import Scheduler, NoOp
+from ..profiling.profiler import span
 
 
 class TrainState(NamedTuple):
@@ -165,14 +166,15 @@ def make_train_step(
 
     def compute_loss(params, net_state, data, labels, sub):
         out, new_net_state = apply_model(params, net_state, data, sub)
-        if lm_head_chunk is not None:
-            from ..nn.lm_loss import lm_head_loss
+        with jax.named_scope("loss"):
+            if lm_head_chunk is not None:
+                from ..nn.lm_loss import lm_head_loss
 
-            loss = lm_head_loss(out, model.head_table(params), labels,
-                                lm_head_chunk)
-        else:
-            loss = loss_fn(out, labels)
-        loss = loss + aux_loss_sum(new_net_state)
+                loss = lm_head_loss(out, model.head_table(params), labels,
+                                    lm_head_chunk)
+            else:
+                loss = loss_fn(out, labels)
+            loss = loss + aux_loss_sum(new_net_state)
         return loss, (out, new_net_state)
 
     def step(state: TrainState, data, labels, lr_scale):
@@ -184,7 +186,9 @@ def make_train_step(
         if grad_accum == 1:
             (loss, (out, new_net_state)), grads = grad_fn(
                 state.params, state.net_state, data, labels, sub)
-            acc = metrics_lib.accuracy(out, labels) if compute_accuracy else None
+            with jax.named_scope("metrics"):
+                acc = (metrics_lib.accuracy(out, labels)
+                       if compute_accuracy else None)
         else:
             if data.shape[0] % grad_accum:
                 raise ValueError(
@@ -201,8 +205,9 @@ def make_train_step(
                 (loss, (out, net_state)), grads = grad_fn(
                     state.params, net_state, d, l, k)
                 grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
-                acc_inc = (metrics_lib.accuracy(out, l)
-                           if compute_accuracy else jnp.zeros((), jnp.float32))
+                with jax.named_scope("metrics"):
+                    acc_inc = (metrics_lib.accuracy(out, l) if compute_accuracy
+                               else jnp.zeros((), jnp.float32))
                 return (grads_acc, net_state, loss_acc + loss, acc_acc + acc_inc), None
 
             zeros = jax.tree_util.tree_map(
@@ -217,8 +222,9 @@ def make_train_step(
 
         if not host_driven:
             lr_scale = scheduler.scale(state.step)
-        new_params, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params, lr_scale=lr_scale)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params, lr_scale=lr_scale)
         metrics = {"loss": loss, "lr_scale": lr_scale}
         if compute_accuracy:
             metrics["accuracy"] = acc
@@ -240,19 +246,25 @@ def make_train_step(
             return state, metrics
 
     donate_argnums = (0,) if donate else ()
+    step.__name__ = "tnn_train_step"    # the profile's module: jit_tnn_train_step
     jitted = jax.jit(step, donate_argnums=donate_argnums)
 
+    # ``train.dispatch`` is the ENQUEUE of one compiled call (the device runs
+    # it asynchronously), on the JAX profiler's clock: docs/observability.md
     if host_driven:
         # Host-driven schedulers (ReduceLROnPlateau) feed their factor in as a runtime
         # operand — tracing scheduler.scale() would constant-fold it into the program.
         def wrapped(state, data, labels):
-            return jitted(state, data, labels,
-                          jnp.asarray(scheduler.current_scale(), jnp.float32))
+            with span("train.dispatch"):
+                return jitted(state, data, labels,
+                              jnp.asarray(scheduler.current_scale(),
+                                          jnp.float32))
     else:
         one = jnp.ones((), jnp.float32)  # hoisted: no per-step H2D transfer
 
         def wrapped(state, data, labels):
-            return jitted(state, data, labels, one)
+            with span("train.dispatch"):
+                return jitted(state, data, labels, one)
 
     return wrapped
 
