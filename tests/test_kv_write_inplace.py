@@ -1,0 +1,212 @@
+"""The paged KV write stays in place: what the compiled step programs hold,
+and the engine invariant the whole-page write relies on.
+
+``scatter_kv_rows`` / ``scatter_kv_chunk`` (ops/pallas/paged_attention.py)
+rewrite whole pages of the donated pool in the layout the paged kernel reads.
+Two things can silently undo that, and neither shows on the CPU:
+
+* a write whose lowering makes the TPU compiler re-lay the WHOLE pool out
+  around every layer's scatter (it did: 72 pool copies a decode step, 81% of
+  the step). ``test_step_program_has_no_pool_copy_per_layer`` AOT-compiles
+  the step programs for the compile-only v5e target and counts them.
+* a step that writes one non-scratch page from two rows, or a page someone
+  else still reads: the whole-page write would then lose a row. The engine
+  never builds such a step (``PagedKVPool.check_step_writes``); the tests at
+  the bottom drive it through the copy-on-write case and check every step.
+
+Parity of the write itself is in tests/test_paged_attention.py.
+"""
+import re
+import signal
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tnn_tpu.ops.pallas import paged_attention as pa
+from tnn_tpu.serving import InferenceEngine
+from tnn_tpu.serving.kv_pool import PagedKVPool
+from tnn_tpu.utils import compile_cache
+
+# gpt2-large as published, and the benchmark's pool (chipbench/configs/
+# gpt2-large-serve.json): 20 heads of 64, pages of 16, 704 blocks, 8 rows
+_WIDTHS = dict(vocab_size=50257, max_len=1024, d_model=1280, num_heads=20)
+_HEADS, _HEAD_DIM, _BLOCK, _BLOCKS, _ROWS = 20, 64, 16, 704, 8
+# what the program's entry and exit may cost: the pool rests in another
+# layout than the kernel reads (PERF.md section 7), 2 copies in + 2 out
+_ENTRY_EXIT_COPIES = 6
+_COMPILE_TIMEOUT_S = 240
+
+
+class _Timeout(Exception):
+    pass
+
+
+@pytest.fixture
+def alarm():
+    """The compile-only target loads libtpu; a second process that holds it
+    can make that wait. Fail after a bound instead of hanging a worker."""
+    def on_alarm(signum, frame):
+        raise _Timeout(f"no result in {_COMPILE_TIMEOUT_S} s")
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(_COMPILE_TIMEOUT_S)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    can never be read back here; conftest re-arms the cache for the next
+    test."""
+    compile_cache.disable()
+    yield
+
+
+def _pool_copies(one_chip, form, dtype, num_layers):
+    from tnn_tpu.models.gpt2 import GPT2
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    model = GPT2(num_layers=num_layers, **_WIDTHS)
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    shape = (num_layers, _BLOCKS, _HEADS, _BLOCK, _HEAD_DIM)
+    if dtype == "int8":
+        pages = pa.QuantPages(spec(shape, jnp.int8),
+                              spec(shape[:-1] + (1,), jnp.float32))
+    else:
+        pages = spec(shape, jnp.bfloat16)
+    tables = spec((_ROWS, _WIDTHS["max_len"] // _BLOCK), jnp.int32)
+    lens = spec((_ROWS,), jnp.int32)
+    # the "auto" routes ask jax.default_backend(): say what the target is
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(model.apply_decode_paged,
+                              donate_argnums=(2, 3)).lower(
+                params, spec((_ROWS,), jnp.int32), pages, pages, tables, lens)
+        else:
+            lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
+                params, spec((_ROWS, 64), jnp.int32), pages, pages, tables,
+                lens, lens)
+        text = lowered.compile().as_text()
+    assert "tnn_paged_attention" in text, "the Pallas kernel is not in it"
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\("
+                      % ",".join(map(str, shape)))
+    return [line.strip()[:160] for line in text.splitlines()
+            if pool.search(line)]
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("form", ["decode", "chunk64"])
+def test_step_program_has_no_pool_copy_per_layer(form, dtype, one_chip,
+                                                 no_compile_cache, alarm):
+    """Pool-shaped ``copy`` instructions in the step program compiled for the
+    v5e: as many at 4 layers as at 2 (none belongs to a layer), and no more
+    than the program's entry and exit cost. (Of an int8 pool this counts the
+    int8 data array; its f32 scale sidecar has another shape and its own
+    copies: PERF.md section 7.)"""
+    two = _pool_copies(one_chip, form, dtype, 2)
+    four = _pool_copies(one_chip, form, dtype, 4)
+    assert len(two) == len(four), (two, four)
+    assert len(four) <= _ENTRY_EXIT_COPIES, four
+
+
+# -- the one-writer invariant --------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    from tnn_tpu.models.gpt2 import GPT2
+
+    model = GPT2(vocab_size=128, max_len=64, num_layers=2, d_model=32,
+                 num_heads=2)
+    return model, model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+
+
+@pytest.mark.parametrize("mode", ["sync", "overlap", "spec"])
+def test_every_step_writes_a_page_from_one_row(tiny_lm, mode):
+    """A shared-prefix fork followed by writes: twins of a published prompt
+    (full-cover hits, whose last block is cloned before its first write) and
+    sharers of its prefix run in ONE batch with the publisher's pages
+    forked into their tables. Every packed step is held to the invariant:
+    each non-scratch page it writes has refcount 1 and one writing row."""
+    model, params = tiny_lm
+    kw = dict(spec="ngram", spec_k=3) if mode == "spec" else {}
+    eng = InferenceEngine(model, params, num_blocks=40, block_size=4,
+                          max_batch_size=4, max_seq_len=32, prefix_cache=True,
+                          decode_path="paged", overlap=mode != "sync", **kw)
+    eng.pool.debug = True       # what TNN_POOL_DEBUG=1 sets
+    steps, shared, refused = [], [], []
+    inner = eng.pool.check_step_writes
+
+    def checked(tables, starts, q_lens):
+        try:
+            inner(tables, starts, q_lens)
+        except ValueError as e:     # the engine isolates a failed step
+            refused.append(str(e))
+            raise
+        steps.append(int(np.sum(np.asarray(q_lens) > 0)))
+        shared.append(sum(r > 1 for r in eng.pool._ref.values()))
+
+    eng.pool.check_step_writes = checked
+    rng = np.random.default_rng(5)
+    base = np.tile(rng.integers(0, 128, 4), 2).astype(np.int32)  # 2 blocks
+    first = eng.submit(base, 6)
+    for _ in range(2):          # the publisher's prefill ends and publishes
+        eng.step()
+    tails = [rng.integers(0, 128, 3).astype(np.int32) for _ in range(2)]
+    prompts = [base, base] + [np.concatenate([base, t]) for t in tails]
+    rids = [eng.submit(p, 6) for p in prompts]
+    out = eng.run_until_complete()
+    eng.check_invariants()
+    assert not refused, refused
+    assert eng.metrics.prefix_cows >= 1, "no full-cover hit was cloned"
+    assert len(steps) > 4 and max(steps) > 1
+    assert max(shared) > 0, "no page was shared while steps wrote"
+    assert out[rids[0]] == out[rids[1]] == out[first]
+
+
+def test_check_step_writes_refuses_two_writers_and_shared_pages():
+    pool = PagedKVPool(num_layers=1, num_blocks=8, num_kv_heads=1, block_size=4,
+                       head_dim=8)
+    a, b = pool.alloc(2), pool.alloc(1)
+    scratch = PagedKVPool.SCRATCH
+    tables = np.array([a, b + [scratch], [scratch, scratch]], np.int32)
+    ones = np.ones(3, np.int32)
+    pool.check_step_writes(tables, np.array([5, 2, 0]), ones)
+    # rows 0 and 1 both write page a[1]
+    twice = np.array([a, [b[0], a[1]], [scratch, scratch]], np.int32)
+    with pytest.raises(ValueError, match="rows 0 and 1"):
+        pool.check_step_writes(twice, np.array([5, 5, 0]), ones)
+    # ... but only if both WRITE it: row 1 reading it is a shared prefix
+    pool.fork([a[1]])
+    pool.check_step_writes(twice, np.array([2, 0, 0]), ones)
+    with pytest.raises(ValueError, match="refcount 2"):
+        pool.check_step_writes(twice, np.array([5, 0, 0]), ones)
+    # a chunk is held to every page it straddles; absent rows to none
+    with pytest.raises(ValueError, match="refcount 2"):
+        pool.check_step_writes(twice, np.array([2, 0, 0]),
+                               np.array([4, 1, 0]))
+    pool.check_step_writes(twice, np.array([5, 0, 0]), np.array([0, 1, 0]))

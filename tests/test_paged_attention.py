@@ -414,3 +414,119 @@ def test_int8_mixed_kind_rejected():
     q, pk, pv, tables, lens = _random_case(47)
     with pytest.raises(ValueError, match="both"):
         pa.paged_attention(q, _quantize(pk), pv, tables, lens)
+
+
+# -- the whole-page write against the per-row scatter it replaced -------------
+
+
+def _oracle_rows(pages, tables, offsets, rows, layer=None):
+    """The write as it was before the whole-page form: one scatter with
+    indices on the layer, page and SLOT dims."""
+    if isinstance(pages, pa.QuantPages):
+        q, s = pa.quantize_kv_rows(rows)
+        return pa.QuantPages(_oracle_rows(pages.data, tables, offsets, q, layer),
+                             _oracle_rows(pages.scale, tables, offsets, s,
+                                          layer))
+    bs = pages.shape[-2]
+    blk = jnp.take_along_axis(tables, (offsets // bs)[:, None], axis=1)[:, 0]
+    blk, slot = jnp.maximum(blk, 0), offsets % bs
+    if pages.ndim == 5:
+        return pages.at[layer, blk, :, slot, :].set(rows)
+    return pages.at[blk, :, slot, :].set(rows)
+
+
+def _oracle_chunk(pages, tables, starts, rows, q_lens, layer=None):
+    if isinstance(pages, pa.QuantPages):
+        q, s = pa.quantize_kv_rows(rows)
+        return pa.QuantPages(
+            _oracle_chunk(pages.data, tables, starts, q, q_lens, layer),
+            _oracle_chunk(pages.scale, tables, starts, s, q_lens, layer))
+    bs, qw, nbt = pages.shape[-2], rows.shape[1], tables.shape[1]
+    pos = starts[:, None] + jnp.arange(qw)
+    live = jnp.arange(qw)[None, :] < q_lens[:, None]
+    blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, nbt - 1), axis=1)
+    blk, slot = jnp.maximum(jnp.where(live, blk, 0), 0), pos % bs
+    if pages.ndim == 5:
+        return pages.at[layer, blk, :, slot, :].set(rows)
+    return pages.at[blk, :, slot, :].set(rows)
+
+
+def _write_case(seed, *, qw, block_size=4, batch=5, blocks_per_row=6,
+                holes=False, all_scratch=False):
+    """A pool, tables that keep the engine's one-writer invariant (every
+    non-scratch page in one row's table only, scratch page 0 as padding) and
+    a ragged chunk per row: row 0 absent (q_lens 0), row 1 a full chunk that
+    starts on a page's last slot (the most pages a chunk can straddle), the
+    last row padding whose table is all scratch."""
+    rng = np.random.default_rng(seed)
+    num_blocks = 1 + batch * blocks_per_row
+    shape = (2, num_blocks, 2, block_size, 16)
+    pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    tables = rng.permutation(np.arange(1, num_blocks)).reshape(
+        batch, blocks_per_row).astype(np.int32)
+    room = blocks_per_row * block_size - qw
+    starts = rng.integers(0, room + 1, size=batch).astype(np.int32)
+    q_lens = rng.integers(1, qw + 1, size=batch).astype(np.int32)
+    q_lens[0] = 0
+    starts[1], q_lens[1] = block_size - 1, qw
+    tables[-1] = 0
+    if all_scratch:
+        tables[:] = 0
+    if holes:   # pages another SP shard owns: every other table entry
+        tables[:, 1::2] = -1
+    rows = jnp.asarray(rng.normal(size=(batch, qw, 2, 16)), jnp.float32)
+    return (pages, jnp.asarray(tables), jnp.asarray(starts), rows,
+            jnp.asarray(q_lens))
+
+
+def _assert_same_but_scratch(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = np.asarray(g), np.asarray(w)
+        if g.ndim == 5:     # (L, N, ...): page 0 of every layer is scratch
+            g, w = g[:, 1:], w[:, 1:]
+        else:
+            g, w = g[1:], w[1:]
+        np.testing.assert_array_equal(g, w)
+
+
+_WRITE_CASES = {
+    # chunk widths that straddle at most 1, 2, 3, 4 and 5 pages of 4 slots
+    "pages1": dict(qw=1), "pages2": dict(qw=3), "pages3": dict(qw=6),
+    "pages4": dict(qw=10), "pages5": dict(qw=16),
+    "holes": dict(qw=6, holes=True),
+    "all_scratch": dict(qw=6, all_scratch=True),
+    "one_layer": dict(qw=6), "traced_layer": dict(qw=6),
+    "int8": dict(qw=6), "int8_holes": dict(qw=10, holes=True),
+}
+
+
+@pytest.mark.parametrize("form", ["rows", "chunk"])
+@pytest.mark.parametrize("case", list(_WRITE_CASES))
+def test_page_write_matches_per_row_scatter(case, form):
+    """scatter_kv_rows / scatter_kv_chunk (whole pages, in place) against the
+    per-row scatter formula they replaced: bit-exact on every non-scratch
+    page, whatever lands in the scratch page."""
+    pages, tables, starts, rows, q_lens = _write_case(
+        sum(map(ord, case)), **_WRITE_CASES[case])
+    if case.startswith("int8"):
+        pages = _quantize(pages)
+    layer = 1
+    if case == "one_layer":
+        pages, layer = pages[1], None
+    if form == "rows":
+        # the decode form: every row writes one position (a padding row's
+        # table is all scratch)
+        args, new, old = (starts, rows[:, 0]), pa.scatter_kv_rows, _oracle_rows
+    else:
+        args, new, old = (starts, rows, q_lens), pa.scatter_kv_chunk, \
+            _oracle_chunk
+    if case == "traced_layer":
+        got = jax.jit(lambda p, ly: new(p, tables, *args, layer=ly))(
+            pages, jnp.asarray(layer, jnp.int32))
+    else:
+        got = new(pages, tables, *args, layer=layer)
+    _assert_same_but_scratch(got, old(pages, tables, *args, layer=layer))
+    if case == "all_scratch":   # and nothing but the scratch page changed
+        _assert_same_but_scratch(got, pages)

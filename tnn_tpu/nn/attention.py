@@ -456,8 +456,11 @@ class MultiHeadAttention(Module):
             garbage the caller must ignore.
 
         Returns (out (B, Q, D), pages_k, pages_v) — pages updated only at the
-        written rows, so with the pool buffers donated through jit the update
-        is in place.
+        written rows. The write rewrites the whole pages those rows land in
+        (``scatter_kv_rows`` / ``scatter_kv_chunk``), so with the pool
+        buffers donated through jit it is in place in the layout the kernel
+        reads; it needs the engine's one-writer invariant (a step writes a
+        non-scratch page from one row only).
         """
         if self.kv_cache_dtype == "int8":
             raise NotImplementedError(

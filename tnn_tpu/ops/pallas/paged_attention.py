@@ -66,6 +66,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import interpret_default
@@ -541,7 +542,6 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
     return out[:, 0] if was_3d else out
 
 
-@jax.named_scope("kv_write")
 def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):
     """Write one new KV row per sequence at its decode position.
 
@@ -549,34 +549,12 @@ def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):
     ``layer`` naming the layer (or a single layer's (N, H, bs, Dh));
     ``block_tables`` (B, nb); ``offsets`` (B,) the position each row writes;
     ``rows`` (B, H, Dh). Rows whose table points at the pool's scratch page
-    land there harmlessly. Returns the updated pages — under jit with the
-    pool buffers donated this lowers to an in-place dynamic-update-scatter.
-
-    QuantPages: rows are quantized HERE (write time) and the int8 data and
-    f32 scale scatter through the same block-table math, so a row's scale
-    can never drift from its page slot.
+    land there harmlessly. Returns the updated pages. The one-token case of
+    ``scatter_kv_chunk``, which says how the write is made (and opens the
+    ``kv_write`` scope: one level in an op's path, whichever form is called).
     """
-    if isinstance(pages, QuantPages):
-        qrows, srows = quantize_kv_rows(rows)
-        return QuantPages(
-            scatter_kv_rows(pages.data, block_tables, offsets, qrows,
-                            layer=layer),
-            scatter_kv_rows(pages.scale, block_tables, offsets, srows,
-                            layer=layer))
-    bs = pages.shape[-2]
-    blk = jnp.take_along_axis(block_tables, (offsets // bs)[:, None],
-                              axis=1)[:, 0]
-    # -1 holes (positions another SP shard owns) divert to the scratch page
-    # instead of wrapping to the LAST page and corrupting live KV
-    blk = jnp.maximum(blk, 0)
-    slot = offsets % bs
-    # two advanced indices (blk, slot) around the sliced head axis put the
-    # batch dim first in the update operand: rows is already (B, H, Dh)
-    if pages.ndim == 5:
-        if layer is None:
-            raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
-        return pages.at[layer, blk, :, slot, :].set(rows)
-    return pages.at[blk, :, slot, :].set(rows)
+    return scatter_kv_chunk(pages, block_tables, offsets, rows[:, None],
+                            jnp.ones_like(offsets), layer=layer)
 
 
 @jax.named_scope("kv_write")
@@ -587,8 +565,29 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
     ``rows`` is (B, Q, H, Dh): row b's tokens t < q_lens[b] land at positions
     ``starts[b] + t`` through its block table; padding tokens (and whole rows
     with q_lens == 0) are redirected to the pool's scratch page 0, which is
-    never allocated to a request, so they can't corrupt live KV. Same layer /
-    donation / write-time-quantization semantics as ``scatter_kv_rows``.
+    never allocated to a request, so they can't corrupt live KV. Same layer
+    semantics as ``scatter_kv_rows``.
+
+    The write moves WHOLE PAGES: Q consecutive positions touch at most
+    ``(Q + bs - 2) // bs + 1`` pages of a row's table; each is read, the new
+    rows put in their slots, and the page written back. Gather and scatter
+    index the pool's LEADING dims only ([layer, blk]) and move whole
+    (H, bs, Dh) pages, so both run in the pool's own layout — the one the
+    paged kernel reads — and with the pool buffers donated through jit the
+    update is in place. (Indices on the slot axis, ``.at[layer, blk, :,
+    slot]``, made the TPU scatter want another layout, and the compiler
+    re-laid the WHOLE pool out and back around every layer's write.)
+
+    That is exact under the ONE-WRITER invariant the engine keeps
+    (``PagedKVPool.check_step_writes``): a step writes a non-scratch page
+    from one row only — a shared prefix page is cloned before its first
+    write. Pages of a chunk that hold no live token, and -1 table holes
+    (positions another SP shard owns), divert to the scratch page, where
+    rows may overwrite each other: nothing reads it.
+
+    QuantPages: rows are quantized HERE (write time) and the int8 data and
+    f32 scale scatter through the same block-table math, so a row's scale
+    can never drift from its page slot.
     """
     if isinstance(pages, QuantPages):
         qrows, srows = quantize_kv_rows(rows)
@@ -597,21 +596,28 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
                              layer=layer),
             scatter_kv_chunk(pages.scale, block_tables, starts, srows, q_lens,
                              layer=layer))
+    if pages.ndim == 5 and layer is None:
+        raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
     bs = pages.shape[-2]
-    qw = rows.shape[1]
+    b, qw = rows.shape[:2]
     nbt = block_tables.shape[1]
-    pos = starts[:, None] + jnp.arange(qw)                # (B, Q)
-    live = jnp.arange(qw)[None, :] < q_lens[:, None]      # (B, Q)
-    blk = jnp.take_along_axis(block_tables,
-                              jnp.clip(pos // bs, 0, nbt - 1), axis=1)
-    # dead tokens AND -1 table holes (positions another SP shard owns) land
-    # in the scratch page — a raw -1 would wrap to the last page
-    blk = jnp.maximum(jnp.where(live, blk, 0), 0)
-    slot = pos % bs
-    # advanced (blk, slot) indices around the sliced head axis broadcast to
-    # (B, Q) and lead the update operand: rows is already (B, Q, H, Dh)
-    if pages.ndim == 5:
-        if layer is None:
-            raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
-        return pages.at[layer, blk, :, slot, :].set(rows)
-    return pages.at[blk, :, slot, :].set(rows)
+    npg = (qw + bs - 2) // bs + 1
+    entry = (starts // bs)[:, None] + jnp.arange(npg)     # (B, P) table slots
+    # the chunk token that lands in each slot of each touched page
+    tok = (entry * bs - starts[:, None])[:, :, None] + jnp.arange(bs)
+    live = (tok >= 0) & (tok < q_lens[:, None, None])     # (B, P, bs)
+    blk = jnp.take_along_axis(block_tables, jnp.clip(entry, 0, nbt - 1),
+                              axis=1)
+    # a raw -1 would wrap to the LAST page and corrupt live KV
+    blk = jnp.where(live.any(-1) & (entry < nbt), jnp.maximum(blk, 0), 0)
+    new = jnp.take_along_axis(
+        rows, jnp.clip(tok, 0, qw - 1).reshape(b, npg * bs, 1, 1), axis=1)
+    new = new.reshape(b * npg, bs, *rows.shape[2:]).swapaxes(1, 2)
+    here = live.reshape(b * npg, 1, bs, 1)
+    # the first read of a program's pool argument has no producer to take its
+    # layout from; left free, the compiler re-lays the pool out once more for
+    # that one gather
+    pages = with_layout_constraint(
+        pages, Layout(major_to_minor=tuple(range(pages.ndim))))
+    at = (layer, blk.reshape(-1)) if pages.ndim == 5 else blk.reshape(-1)
+    return pages.at[at].set(jnp.where(here, new, pages[at]))

@@ -1286,6 +1286,7 @@ class InferenceEngine:
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
             paged=self._paged, fused_available=False, speculative=True)
+        self._check_step_writes(step, step.offsets)
         b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
         label = "decode_paged" if self._paged else "decode"
         fn = self._jit.get(key)
@@ -2185,6 +2186,7 @@ class InferenceEngine:
             b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, spec_on=spec_on,
             kv_key=self._kv_key)
+        self._check_step_writes(step, step.starts, step.q_lens)
         b, qw, poison = step.b, step.qw, step.poison
         if self.faults is not None:
             if dec:
@@ -2689,6 +2691,15 @@ class InferenceEngine:
         fn.__name__ = "tnn_serve_decode_fused"
         return jax.jit(fn, donate_argnums=(2, 3))
 
+    def _check_step_writes(self, step, starts, q_lens=None) -> None:
+        """TNN_POOL_DEBUG=1: hold every packed step to the one-writer
+        invariant the in-place page write relies on (``q_lens`` None: the
+        decode form, one token a row)."""
+        if self.pool.debug:
+            self.pool.check_step_writes(
+                step.tables, starts,
+                np.ones_like(starts) if q_lens is None else q_lens)
+
     def _decode_build(self, live: Sequence[Request],
                       events) -> Optional[Dict[str, Any]]:
         """Pure-decode build/dispatch half: stage the batch, launch the
@@ -2700,6 +2711,7 @@ class InferenceEngine:
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
             paged=self._paged, fused_available=self._fused is not None)
+        self._check_step_writes(step, step.offsets)
         b, nb, key, lockstep = step.b, step.nb, step.key, step.lockstep
         poison = step.poison
         if self.faults is not None:

@@ -544,6 +544,42 @@ class PagedKVPool:
                     f"unallocated-in-tables={sorted(unknown)} "
                     f"(table_uses, refcount)={counts}")
 
+    def check_step_writes(self, tables, starts, q_lens) -> None:
+        """Verify the ONE-WRITER invariant for one packed step; raises
+        ValueError on violation.
+
+        ``tables`` (B, nb) are the step's GLOBAL block tables, row i writes
+        positions ``starts[i] .. starts[i] + q_lens[i] - 1``. The paged write
+        (``ops.pallas.paged_attention.scatter_kv_rows`` / ``scatter_kv_chunk``)
+        rewrites whole pages, which is exact only while every non-scratch
+        page a step writes belongs to one row alone: refcount 1 (a shared
+        prefix page is cloned before its first write, ``_match_prefix``) and
+        no second row of the batch writing it. Scratch pages take any number
+        of writers — nothing reads them. The engine runs this on every
+        packed step under TNN_POOL_DEBUG=1.
+        """
+        bs = self.block_size
+        writer: Dict[int, int] = {}
+        for i, (table, start, n) in enumerate(zip(tables, starts, q_lens)):
+            if n <= 0:
+                continue
+            first, last = int(start) // bs, (int(start) + int(n) - 1) // bs
+            for blk in table[first:last + 1]:
+                blk = int(blk)
+                if blk in self._scratch:
+                    continue
+                if blk in writer:
+                    raise ValueError(
+                        f"block {blk} written by rows {writer[blk]} and {i} "
+                        "of one step — the whole-page write needs one "
+                        "writer per page")
+                writer[blk] = i
+                if self._ref.get(blk) != 1:
+                    raise ValueError(
+                        f"row {i} writes block {blk} with refcount "
+                        f"{self._ref.get(blk)} — a shared or unowned page "
+                        "must be cloned before its first write")
+
     # -- device pages ---------------------------------------------------------
 
     def update_pages(self, pages_k, pages_v) -> None:
