@@ -8,6 +8,16 @@ benchmark makes from the seed and the program takes in place of its own
 random initialisation, and (2) a subclass of ``InferenceEngine`` that only
 remembers the engine, so that the window can be marked on its counters.
 (Copied in shape from ``chip_smoke.drive_serve``, proven on the chip in PR 21.)
+
+The driver names no family of models. Everything it knows of the model comes
+from the configuration's reference module (``chipbench/reference/<family>.py``):
+``sizes_of(config) -> sz`` (whatever the family needs, handed on to the
+reference's ``Forward`` and to every ``opcount``), ``make_params(sz, seed)``
+(in the type the program keeps its weights in) and ``check_program(model, sz,
+name)`` (refuses a program whose model is not the configuration's). The
+harness itself reads two sizes, each under one name: ``sz["vocab_size"]``,
+which ids are drawn from and checked against, and ``sz["positions"]``, the
+most a request may take, prompt and output together.
 """
 from __future__ import annotations
 
@@ -45,9 +55,10 @@ class Client(io.TextIOBase):
     """The client side of the pipe: what a traffic generator drives, and the
     stand-in for the server's stdout that stamps every event as it arrives."""
 
-    def __init__(self, wfd, passthrough):
+    def __init__(self, wfd, passthrough, positions):
         self._w = os.fdopen(wfd, "w")
         self._passthrough = passthrough
+        self._positions = positions
         self._part = ""
         self.cv = threading.Condition()
         self.reqs = {}
@@ -105,6 +116,11 @@ class Client(io.TextIOBase):
     def send(self, req: Req):
         """Write one request. ``req.due`` is when it was due; the generator
         calls this as close to that instant as it can."""
+        if len(req.tokens) + req.max_new > self._positions:
+            raise ValueError(
+                f"request {req.id}: {len(req.tokens)} + {req.max_new} tokens "
+                f"are more than the {self._positions} positions of the "
+                "configuration: the mix does not fit it")
         req.sent = time.perf_counter()
         if req.due is None:
             req.due = req.sent
@@ -162,10 +178,6 @@ def _flags(config, ctx):
     return flags + ["--seed", str(ctx.seed % (2 ** 31 - 1))]
 
 
-def _model_sizes(config, ctx):
-    return config["rehearsal"] if ctx.rehearse else config
-
-
 def run(ctx):
     """Set the server up, let the cell's generator drive it through the
     window, shut it down; return the observations (``obs``)."""
@@ -176,7 +188,8 @@ def run(ctx):
 
     config, traffic = ctx.config, ctx.traffic
     reference = spec.plugin("reference", config["reference"])
-    sz = reference.sizes_of(_model_sizes(config, ctx))
+    sz = ctx.sizes = reference.sizes_of(
+        config["rehearsal"] if ctx.rehearse else config)
     params = reference.make_params(sz, ctx.seed)
     jax.block_until_ready(params)
     ctx.note(f"weights made from the seed on {jax.devices()[0].platform}: "
@@ -193,17 +206,12 @@ def run(ctx):
 
     def create(name, **kw):
         model = real_create(name, **kw)
-        got = dict(n_layer=model.num_layers, n_embd=model.d_model,
-                   n_head=model.num_heads, vocab_size=model.vocab_size,
-                   n_positions=model.max_len)
-        if got != sz:
-            raise SystemExit(f"the program's {name} has sizes {got}, the "
-                             f"configuration file says {sz}")
+        reference.check_program(model, sz, name)
         model.init = lambda *a, **k: {"params": params, "state": {}}
         return model
 
     rfd, wfd = os.pipe()
-    client = Client(wfd, passthrough=sys.stderr)
+    client = Client(wfd, passthrough=sys.stderr, positions=sz["positions"])
     err = io.StringIO()
     obs = {"kind": "serve", "client": client, "traffic": traffic,
            "sizes": sz, "params": params, "reference": reference}
@@ -305,7 +313,7 @@ def _warm_up(client, config, ctx):
 
     part = config["rehearsal"] if ctx.rehearse else config
     rng = np.random.default_rng([ctx.seed % (2 ** 63), 7])
-    vocab = _model_sizes(config, ctx)["vocab_size"]
+    vocab = ctx.sizes["vocab_size"]
     t0 = time.perf_counter()
     prompts = [[int(x) for x in rng.integers(0, vocab, n)]
                for n in part["warmup_prompt_lens"]]

@@ -43,7 +43,10 @@ def gap_readings(obs, sample, control=None):
     below the configuration's), the tokens judged are not the served ones but
     those the reference computed in THAT precision puts first."""
     reference, sz = obs["reference"], obs["sizes"]
-    length = sz["n_positions"]
+    # built for the longest sampled request, rounded up as the reference
+    # asks, not for the most positions the configuration declares
+    length = reference.forward_length(sz, max(
+        (len(r.tokens) + len(r.streamed) for r in sample), default=1))
     fwd = reference.Forward(obs["params"], sz, length)
     low = reference.Forward(obs["params"], sz, length, quant=control) \
         if control else None
@@ -68,13 +71,7 @@ def judge(obs):
     ctx, client, traffic = obs["ctx"], obs["client"], obs["traffic"]
     config, summary, eng = ctx.config, obs["summary"], obs["engine"]
     marks = obs["marks"]
-    bad = []
-
-    def hold(name, got, limit, ok):
-        ctx.note(f"check {name}: {got} (limit {limit}) "
-                 f"{'ok' if ok else 'FAIL'}")
-        if not ok:
-            bad.append(name)
+    hold = ctx.hold
 
     measured = [r for r in client.reqs.values() if r.measured]
     errored = [r for r in measured if r.end not in ("done", "cancelled", None)]
@@ -85,49 +82,51 @@ def judge(obs):
         if "grace_s" in traffic else []
     failed = len({r.id for r in errored + late})
     vocab = obs["sizes"]["vocab_size"]
-    hold("server exit code", obs["rc"], 0, obs["rc"] == 0)
-    hold("requests ended in error", len(errored), 0, not errored)
-    hold("error events of no request", len(client.stray_errors), 0,
-         not client.stray_errors)
+    hold("server_rc", obs["rc"], 0, obs["rc"] == 0, "server exit code")
+    hold("errored", len(errored), 0, not errored, "requests ended in error")
+    hold("stray_errors", len(client.stray_errors), 0,
+         not client.stray_errors, "error events of no request")
     for key in ("engine_restarts", "step_retries", "failed"):
-        hold(f"window summary {key}", summary.get(key, "missing"), 0,
-             summary.get(key, "missing") == 0)
-    hold("decode path", f"{eng['decode_path']!r} fallback "
+        hold(f"summary_{key}", summary.get(key, "missing"), 0,
+             summary.get(key, "missing") == 0, "the window's own counter")
+    hold("decode_path", f"{eng['decode_path']!r} fallback "
          f"{eng['paged_fallback_reason']!r}", "'paged' None",
          eng["decode_path"] == "paged"
          and eng["paged_fallback_reason"] is None)
     if not ctx.rehearse:
         from tnn_tpu.ops.pallas.runtime import interpret_default
 
-        hold("kernels interpreted", interpret_default(), False,
+        hold("kernels_interpreted", interpret_default(), False,
              not interpret_default())
-        hold("pool pages on", eng["pool_platforms"], ["tpu"],
-             eng["pool_platforms"] == ["tpu"])
+        hold("pool_on", eng["pool_platforms"], ["tpu"],
+             eng["pool_platforms"] == ["tpu"], "where the pool's pages are")
     new_keys = marks["keys_close"] - marks["keys_open"]
-    hold("programs compiled inside the window", sorted(map(str, new_keys)),
-         [], not new_keys)
+    hold("compiled_in_window", sorted(map(str, new_keys)), [], not new_keys,
+         "programs compiled inside the window")
     wrong = [r.id for r in measured
              if len(r.streamed) > r.max_new
              or (r.end == "done" and len(r.streamed) != r.max_new)
              or any(not 0 <= t < vocab for t in r.streamed)]
-    hold("requests with a wrong count of tokens or one outside the "
-         "vocabulary", wrong, [], not wrong)
+    hold("wrong_streams", wrong, [], not wrong, "requests with a wrong "
+         "count of tokens or one outside the vocabulary")
     n_tok = sum(len(r.streamed) for r in measured)
-    hold("tokens streamed by measured requests", n_tok, ">= 1", n_tok >= 1)
+    hold("tokens_streamed", n_tok, ">= 1", n_tok >= 1,
+         "by measured requests")
 
     sample = sample_requests(obs, config["check"]["sample_requests"])
     gmax, gmean, n = gap_readings(obs, sample)
     limits = (config["rehearsal"] if ctx.rehearse else config)["limits"]
     ctx.note(f"reference sample: {len(sample)} requests, {n} served tokens, "
              f"longest {max((len(r.tokens) + len(r.streamed) for r in sample), default=0)}")
-    hold("gap_max (widest gap of a served token's reference logit below "
-         "the reference's best)", gmax, limits["gap_max"],
-         gmax <= limits["gap_max"])
-    hold("gap_mean (mean of those gaps)", gmean, limits["gap_mean"],
-         gmean <= limits["gap_mean"])
+    hold("gap_max", gmax, limits["gap_max"], gmax <= limits["gap_max"],
+         "widest gap of a served token's reference logit below the "
+         "reference's best")
+    hold("gap_mean", gmean, limits["gap_mean"], gmean <= limits["gap_mean"],
+         "mean of those gaps")
     obs["readings"] = {"gap_max": gmax, "gap_mean": gmean, "tokens": n}
     obs["facts"] = facts(obs)
-    return not bad, len(measured), failed
+    correct = all(c["ok"] for c in ctx.checks.values())
+    return correct, len(measured), failed
 
 
 def facts(obs):

@@ -58,7 +58,7 @@ def run(ctx):
     part = config["rehearsal"] if ctx.rehearse else config
     tr = part["train"]
     reference = spec.plugin("reference", config["reference"])
-    sz = reference.sizes_of(part)
+    sz = ctx.sizes = reference.sizes_of(part)
     batch, seq = tr["batch"], tr["seq"]
 
     compile_cache.enable()

@@ -101,20 +101,14 @@ def gaps(got, want):
 
 def judge(obs):
     ctx = obs["ctx"]
-    bad = []
+    hold = ctx.hold
 
-    def hold(name, got, limit, ok):
-        ctx.note(f"check {name}: {got} (limit {limit}) "
-                 f"{'ok' if ok else 'FAIL'}")
-        if not ok:
-            bad.append(name)
-
-    hold("steps finished in the window", obs["steps_in_window"], ">= 1",
-         obs["steps_in_window"] >= 1)
-    hold("non-finite losses fetched in the window", obs["nonfinite"], 0,
-         obs["nonfinite"] == 0)
-    hold("loss finite after the window", obs["final_loss"], "finite",
-         bool(np.isfinite(obs["final_loss"])))
+    hold("steps", obs["steps_in_window"], ">= 1",
+         obs["steps_in_window"] >= 1, "steps finished in the window")
+    hold("nonfinite_losses", obs["nonfinite"], 0, obs["nonfinite"] == 0,
+         "of those fetched in the window")
+    hold("final_loss", obs["final_loss"], "finite",
+         bool(np.isfinite(obs["final_loss"])), "after the window")
     want = reference_steps(obs)
     ctx.note(f"reference losses {want['loss']}; program's {obs['got']['loss']}")
     obs["readings"] = gaps(obs["got"], want)
@@ -128,7 +122,8 @@ def judge(obs):
     rate = obs["steps_in_window"] * obs["batch"] * obs["seq"] / obs["window_s"]
     obs["facts"] = {"model_flops_per_s":
                     rate * lm_train.flops_per_token(obs["sizes"], obs["seq"])}
-    return not bad, obs["steps_in_window"], obs["nonfinite"]
+    correct = all(c["ok"] for c in ctx.checks.values())
+    return correct, obs["steps_in_window"], obs["nonfinite"]
 
 
 def also_worth_reading(obs):

@@ -5,50 +5,49 @@ has streamed its first token, so every row decodes from the first instant,
 and closes ``--seconds`` later by the clock. Parameters, all data in the
 traffic file:
 
-  outstanding         requests always in flight
-  wave                rows of the batch: the window opens when this many
-                      have a first token
-  prompt_len, output_len   {"min", "max"}: uniform
-  pool                requests prepared per run, a multiple of ``wave``
-  vocab_size
+  outstanding   requests always in flight
+  wave          rows of the batch: the window opens when this many have a
+                first token
+  requests      [[prompt length, output length], ...] in the order they are
+                sent; a run that needs more starts the list again
 
-Every ``wave`` requests in a row hold the same lengths, evenly spaced over
-the range, in an order drawn from ``--seed``, so the rows that decode through
-a window hold the same contexts under every seed. A decode step's time grows
-with the pages its rows fill (634 ms at 27% of the pool, 653 ms at 41%;
-PERF.md, PR 23): lengths drawn freely over the pool would let the seed change
-the work.
+``--seed`` gives every request its token ids and nothing else: which lengths
+there are, how they are paired and in which order they arrive is the file's.
+A closed loop's work follows that order (which request ends when, so how
+many steps carry a replacement's prefill and how long the rows' contexts are):
+with the order drawn from the seed, six seeds read ``out_tok_s`` 51.7 to 54.0
+over 2,212 to 2,816 prefill tokens (PERF.md, PR 25). The ids come from the
+configuration's vocabulary (``sizes["vocab_size"]``), so one mix serves
+configurations of any vocabulary.
 """
 from __future__ import annotations
+
+import itertools
 
 import numpy as np
 
 from chipbench.drivers.serve_stdin import Req
 
 
-def plan(traffic, seed, scale=1.0):
-    """[(prompt, max_new)] in the order they will be sent."""
+def requests(traffic, seed, vocab, scale=1.0):
+    """(prompt, max_new) without end, in the order they will be sent."""
     rng = np.random.default_rng([int(seed) % (2 ** 63), 2])
-    wave, waves = traffic["wave"], traffic["pool"] // traffic["wave"]
-
-    def lengths(span):
-        lo, hi = (max(1, round(span[k] * scale)) for k in ("min", "max"))
-        even = [round(lo + (hi - lo) * (i + 0.5) / wave) for i in range(wave)]
-        return [even[i] for _ in range(waves) for i in rng.permutation(wave)]
-
-    prompts = lengths(traffic["prompt_len"])
-    outs = lengths(traffic["output_len"])
-    return [([int(x) for x in rng.integers(0, traffic["vocab_size"], p)], o)
-            for p, o in zip(prompts, outs)]
+    for p, o in itertools.cycle(traffic["requests"]):
+        p, o = (max(1, round(n * scale)) for n in (p, o))
+        yield [int(x) for x in rng.integers(0, vocab, p)], o
 
 
 def drive(client, traffic, ctx):
-    todo = list(enumerate(plan(traffic, ctx.seed, ctx.scale)))
+    todo = enumerate(requests(traffic, ctx.seed, ctx.sizes["vocab_size"],
+                              ctx.scale))
     sent = []
 
     def top_up():
-        while todo and len(client.outstanding()) < traffic["outstanding"]:
-            i, (prompt, max_new) = todo.pop(0)
+        # a request that ends in an error is not replaced: the run is not
+        # correct, and a server that refuses everything is not fed for ever
+        while len(client.outstanding()) < traffic["outstanding"] and all(
+                r.end in (None, "done") for r in sent):
+            i, (prompt, max_new) = next(todo)
             req = Req(f"r{i}", prompt, max_new, None)
             req.measured = True
             sent.append(req)
@@ -67,6 +66,3 @@ def drive(client, traffic, ctx):
                     min(0.05, max(0.0, t_end - client.now())))
         top_up()
     client.close_window()
-    if not todo:
-        ctx.note("closed_backlog: the prepared pool of requests ran out "
-                 "inside the window; raise 'pool' in the traffic file")

@@ -23,11 +23,11 @@ import numpy as np
 from chipbench.drivers.serve_stdin import Req
 
 
-def plan(traffic, seed, seconds, scale=1.0):
-    """The run's requests, [(due offset from window open, prompt, max_new)].
+def plan(traffic, seed, seconds, vocab, scale=1.0):
+    """The run's requests, [(due offset from window open, prompt, max_new)],
+    their ids drawn from the configuration's vocabulary of ``vocab`` tokens.
     ``scale`` shrinks every length (the rehearsal's tiny model)."""
     rng = np.random.default_rng([int(seed) % (2 ** 63), 1])
-    vocab = traffic["vocab_size"]
 
     def sc(x):
         return max(1, int(round(x * scale)))
@@ -57,7 +57,8 @@ def plan(traffic, seed, seconds, scale=1.0):
 
 
 def drive(client, traffic, ctx):
-    reqs = plan(traffic, ctx.seed, ctx.seconds, ctx.scale)
+    reqs = plan(traffic, ctx.seed, ctx.seconds, ctx.sizes["vocab_size"],
+                ctx.scale)
     t0 = client.now() + ctx.seconds * traffic["lead_in_share"]
     measured = []
     for i, (off, prompt, max_new) in enumerate(reqs):

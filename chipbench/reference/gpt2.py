@@ -51,11 +51,40 @@ TWIN_STD = 5.6e-4
 WPE_STD = 1.0
 
 
+# The keys of the published config that are widths: ``reduced`` may name none
+# of them (``spec.check_cut``). The family has no key for a head's size, which
+# is n_embd / n_head, so the number of heads counts as one.
+WIDTH_KEYS = ("n_embd", "n_inner", "n_head")
+LENGTH_STEP = 128       # a Forward is built for a multiple of this
+
+
 def sizes_of(cfg: dict) -> dict:
-    """The sizes the reference needs, by the published config's key names."""
+    """The sizes the reference needs, by the published config's key names,
+    and the two the harness reads, under its own names: ``vocab_size`` (ids
+    are drawn from and checked against it) and ``positions`` (the most a
+    request may take, prompt and output together)."""
     return dict(n_layer=int(cfg["n_layer"]), n_embd=int(cfg["n_embd"]),
                 n_head=int(cfg["n_head"]), vocab_size=int(cfg["vocab_size"]),
-                n_positions=int(cfg["n_positions"]))
+                n_positions=int(cfg["n_positions"]),
+                positions=int(cfg["n_positions"]))
+
+
+def check_program(model, sz: dict, name: str):
+    """Refuse a program whose model is not the configuration's."""
+    got = dict(n_layer=model.num_layers, n_embd=model.d_model,
+               n_head=model.num_heads, vocab_size=model.vocab_size,
+               n_positions=model.max_len)
+    want = {k: sz[k] for k in got}
+    if got != want:
+        raise SystemExit(f"the program's {name} has sizes {got}, the "
+                         f"configuration file says {want}")
+
+
+def forward_length(sz: dict, longest: int) -> int:
+    """The length to build a :class:`Forward` for, given the longest sequence
+    it will be asked: the next multiple of ``LENGTH_STEP``, so that runs whose
+    longest request differs by a few tokens share one compiled program."""
+    return min(sz["n_positions"], -(-longest // LENGTH_STEP) * LENGTH_STEP)
 
 
 def param_shapes(sz: dict) -> dict:
@@ -79,8 +108,10 @@ def _is_shape(x):
 
 
 def make_params(sz: dict, seed: int):
-    """The whole f32 parameter tree from ``seed`` in ONE jitted call on the
-    default device: normal, mean 0, with
+    """The whole parameter tree from ``seed`` in ONE jitted call on the
+    default device, in the type the program keeps it in (here float32
+    masters: ``tnn_tpu.models.create`` computes in bf16 over them): normal,
+    mean 0, with
 
       wte 0.02 (GPT-2's own), wpe ``WPE_STD``
       every matmul kernel 1/sqrt(fan_in), and the two residual projections

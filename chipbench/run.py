@@ -4,9 +4,11 @@
 
 from the root of a checkout, on a machine that holds the chips the cell asks
 for. The last line of standard output is one JSON object (``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``);
-everything else worth reading goes on earlier lines. With no TPU, or fewer
-chips than the cell asks for, the command exits 2 and prints no result.
+``attempted``, ``failed``, ``metrics``, ``device``, traced ``breakdown``, and
+last ``checks``: every number that decided ``correct``, by a short name, with
+its limit); the same checks are the last lines of standard error. Everything
+else worth reading goes on earlier lines. With no TPU, or fewer chips than
+the cell asks for, the command exits 2 and prints no result.
 
 ``--rehearse`` runs the same control flow on whatever backend JAX has, at the
 configuration's tiny ``rehearsal`` sizes, to debug the harness in a sandbox
@@ -21,6 +23,7 @@ T_START = time.perf_counter()       # set-up counts from process start
 
 import argparse     # noqa: E402
 import json         # noqa: E402
+import math         # noqa: E402
 import os           # noqa: E402
 import shutil       # noqa: E402
 import sys          # noqa: E402
@@ -33,7 +36,9 @@ TRACE_SECONDS = 3.0     # ... and this long: traces are large
 
 class Ctx:
     """What a driver and a generator get: the cell's data, the run's
-    arguments, and the window's marks (memory peak, the traced slice)."""
+    arguments, the window's marks (memory peak, the traced slice), and
+    where what decides ``correct`` is written down (``hold``). A driver
+    leaves the sizes its reference module gave it in ``sizes``."""
 
     def __init__(self, workload, config, traffic, args):
         self.workload, self.config, self.traffic = workload, config, traffic
@@ -45,6 +50,19 @@ class Ctx:
         self.memory_peak_bytes = None
         self.trace_dir = None
         self._tracer = None
+        self.sizes = None
+        self.checks, self.check_lines = {}, []
+
+    def hold(self, name, got, limit, ok, what=""):
+        """One number compared: ``name`` is short and plain, ``what`` says
+        it in words on the run's own line."""
+        if isinstance(got, float) and not math.isfinite(got):
+            got = repr(got)             # the result line stays plain JSON
+        self.checks[name] = {"value": got, "limit": limit, "ok": bool(ok)}
+        self.check_lines.append(
+            f"check {name}: {got} (limit {limit}) "
+            f"{'ok' if ok else 'FAIL'}{'  -- ' + what if what else ''}")
+        self.note(self.check_lines[-1])
 
     def note(self, msg):
         tag = "[REHEARSAL, not a chip run] " if self.rehearse else ""
@@ -123,6 +141,9 @@ def run_cell(args):
     """One run of one cell: (the result line's object, the observations)."""
     bench = spec.benchmark()
     workload, config, traffic = spec.cell(bench, args.workload)
+    spec.check_cut(spec.by_name(bench["configs"], workload["config"],
+                                "configuration"), config,
+                   spec.plugin("reference", config["reference"]))
     traffic.update(getattr(args, "traffic_override", {}))   # control.py's
     config.update(getattr(args, "config_override", {}))
     import tnn_tpu  # noqa: F401  the system under test: absent -> no result
@@ -171,6 +192,8 @@ def run_cell(args):
             {k: v["value"] for k, v in result["metrics"].items()}))
         result["metrics"] = {}
         result["rehearsal"] = True
+    # last: of a line that is not correct the driver's record keeps the END
+    result["checks"] = ctx.checks
     return result, obs
 
 
@@ -186,8 +209,10 @@ def parse(argv=None):
 
 
 def main(argv=None):
-    result, _ = run_cell(parse(argv))
+    result, obs = run_cell(parse(argv))
     sys.stdout.flush()
+    print("\n".join("chipbench: " + line for line in obs["ctx"].check_lines),
+          file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -1,5 +1,6 @@
 """The benchmark's own arithmetic, on the CPU: no chip, no network, no
 topology call. (tests/chipbench/ is one of BENCHMARK.json's ``paths``.)"""
+import itertools
 import os
 import re
 import statistics
@@ -117,17 +118,20 @@ def _chat():
     return spec.load_json("chipbench", "traffic", "chat.json")
 
 
+VOCAB = 50257
+
+
 def test_open_loop_is_deterministic_in_the_seed():
-    a = open_loop.plan(_chat(), 2 ** 31 + 11, 51.0)
-    b = open_loop.plan(_chat(), 2 ** 31 + 11, 51.0)
-    c = open_loop.plan(_chat(), 12, 51.0)
+    a = open_loop.plan(_chat(), 2 ** 31 + 11, 51.0, VOCAB)
+    b = open_loop.plan(_chat(), 2 ** 31 + 11, 51.0, VOCAB)
+    c = open_loop.plan(_chat(), 12, 51.0, VOCAB)
     assert a == b and a != c
 
 
 def test_open_loop_draws_the_distributions_the_file_names():
     tr = _chat()
     tr["rate_per_s"] = 20.0             # enough requests to see shares
-    reqs = open_loop.plan(tr, 2 ** 31 + 5, 51.0)
+    reqs = open_loop.plan(tr, 2 ** 31 + 5, 51.0, VOCAB)
     n = len(reqs)
     assert n == pytest.approx(20.0 * 51.0 * 1.25, rel=0.1)
     lens = sorted(len(p) for _, p, _ in reqs)
@@ -148,7 +152,7 @@ def test_open_loop_draws_the_distributions_the_file_names():
     assert gaps.mean() == pytest.approx(1 / 20.0, rel=0.1)
     assert gaps.std() == pytest.approx(gaps.mean(), rel=0.15)
     # another seed: other lengths at other instants
-    other = open_loop.plan(tr, 6, 51.0)
+    other = open_loop.plan(tr, 6, 51.0, VOCAB)
     assert [len(p) for _, p, _ in other[:50]] != lens[:50]
     assert [t for t, _, _ in other[:5]] != due[:5]
 
@@ -156,7 +160,7 @@ def test_open_loop_draws_the_distributions_the_file_names():
 def test_open_loop_shares_the_prefixes_the_file_names():
     tr = _chat()
     tr["rate_per_s"] = 20.0
-    reqs = open_loop.plan(tr, 3, 51.0)
+    reqs = open_loop.plan(tr, 3, 51.0, VOCAB)
     heads = {}
     for k in tr["prefixes"]:            # a head that several prompts start with
         seen = {}
@@ -177,25 +181,54 @@ def test_open_loop_shares_the_prefixes_the_file_names():
                for (_, p, _), n in zip(reqs, shared) if n)
 
 
+def _once(tr, seed, vocab, scale=1.0):
+    """The file's list gone through once: [(prompt, max_new)]."""
+    return list(itertools.islice(
+        closed_backlog.requests(tr, seed, vocab, scale), len(tr["requests"])))
+
+
 def test_closed_backlog_plan():
+    """Two seeds send the same sequence of (prompt length, output length)
+    with other ids: the order and the pairing are the file's, not the
+    seed's."""
     tr = spec.load_json("chipbench", "traffic", "decode.json")
-    a, b = closed_backlog.plan(tr, 2 ** 31 + 5), closed_backlog.plan(tr, 7)
-    assert a == closed_backlog.plan(tr, 2 ** 31 + 5) and a != b
-    assert len(a) == tr["pool"] and tr["pool"] % tr["wave"] == 0
-    w = tr["wave"]
-    waves = [a[i:i + w] for i in range(0, len(a), w)] + [b[:w]]
+    a = _once(tr, 2 ** 31 + 5, VOCAB)
+    b = _once(tr, 7, VOCAB)
+    assert a == _once(tr, 2 ** 31 + 5, VOCAB) and a != b
+    assert [(len(p), o) for p, o in a] == [(len(p), o) for p, o in b] \
+        == [tuple(r) for r in tr["requests"]]
+    assert all(p != q for (p, _), (q, _) in zip(a, b))
+    assert all(0 <= t < VOCAB for p, _ in a[:4] for t in p)
+    assert max(t for p, _ in a for t in p) > VOCAB // 2
+    small = _once(tr, 7, 1000)
+    assert max(t for p, _ in small for t in p) < 1000
+    # a run that needs more than the list holds starts it again, new ids
+    more = list(itertools.islice(
+        closed_backlog.requests(tr, 7, VOCAB), 2 * len(a)))
+    assert more[:len(a)] == b
+    assert [(len(p), o) for p, o in more[len(a):]] == \
+        [(len(p), o) for p, o in b] and more[len(a)][0] != b[0][0]
+    # the rehearsal's scale shrinks every length alike
+    tiny = _once(tr, 7, VOCAB, 0.125)
+    assert [(len(p), o) for p, o in tiny] == [
+        (max(1, round(p * 0.125)), max(1, round(o * 0.125)))
+        for p, o in tr["requests"]]
+
+
+def test_closed_backlog_file_holds_the_same_lengths_in_every_wave():
+    tr = spec.load_json("chipbench", "traffic", "decode.json")
+    w, reqs = tr["wave"], tr["requests"]
+    assert tr["outstanding"] == 2 * w and len(reqs) % w == 0
+    waves = [reqs[i:i + w] for i in range(0, len(reqs), w)]
     # every wave holds the same lengths, spread evenly over the range, in
-    # another order: the rows of a window hold the same contexts by any seed
-    assert len({tuple(sorted(len(p) for p, _ in x)) for x in waves}) == 1
-    assert len({tuple(sorted(o for _, o in x)) for x in waves}) == 1
-    assert [len(p) for p, _ in a[:w]] != [len(p) for p, _ in b[:w]]
-    lens = sorted(len(p) for p, _ in a[:w])
-    assert lens == [100, 172, 244, 316, 388, 460, 532, 604]
-    assert sum(lens) / w == (64 + 640) / 2
-    assert sorted(o for _, o in a[:w]) == [144, 176, 208, 240, 272, 304,
-                                           336, 368]
-    assert max(len(p) + o for p, o in a) <= 1024
-    assert all(0 <= t < tr["vocab_size"] for p, _ in a[:4] for t in p)
+    # another order and pairing: the rows of a window hold the same contexts
+    even = lambda lo, hi: [round(lo + (hi - lo) * (i + 0.5) / w)     # noqa
+                           for i in range(w)]
+    assert all(sorted(p for p, _ in x) == even(64, 640) for x in waves)
+    assert all(sorted(o for _, o in x) == even(128, 384) for x in waves)
+    assert len({tuple(map(tuple, x)) for x in waves}) == len(waves) >= 4
+    assert sum(p for p, _ in waves[0]) / w == (64 + 640) / 2
+    assert max(p + o for p, o in reqs) <= 1024
 
 
 # --------------------------------------------------------------- opcount ----
@@ -241,7 +274,7 @@ def test_xplane_reduction_of_the_recorded_trace():
     """chipbench/reduce/sample_v5e.xplane.pb: three calls of a small jitted
     function (the paged kernel at toy shapes, then a matmul + tanh) recorded
     on a TPU v5 lite in PR 23, 10 ms of host sleep between them."""
-    from chipbench.readers import trace_idle, trace_kernel_share
+    from chipbench.readers import trace_idle, trace_roofline
     from chipbench.reduce import xplane
 
     tr = xplane.reduce_file(os.path.join(
@@ -253,10 +286,11 @@ def test_xplane_reduction_of_the_recorded_trace():
     assert tr["top_ops"][0][1] == pytest.approx(20.0e-6, rel=0.02)
     assert tr["top_gaps"][0][0] == "$time sleep"
     obs = {"trace": tr}
-    assert trace_kernel_share.read(
-        obs, pattern='custom_call_target="tpu_custom_call"') == \
-        pytest.approx(85.8, abs=1.0)
-    assert trace_kernel_share.read(obs, pattern="no such kernel") is None
+    # a kernel is found by its instruction's name, not by the text after it
+    assert 100 * trace_roofline.kernel_seconds(tr, "^tiny") / tr["busy_s"] \
+        == pytest.approx(85.8, abs=1.0)
+    assert trace_roofline.kernel_seconds(tr, "tpu_custom_call") == 0
+    assert trace_roofline.kernel_seconds(tr, "no such kernel") == 0
     assert trace_idle.read(obs) == pytest.approx(99.89, abs=0.05)
 
 
@@ -335,10 +369,10 @@ def test_benchmark_json_names_units_and_files():
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
         assert any(c["file"].startswith(p + "/") for p in b["paths"])
         cfg = spec.load_json(c["file"])
-        assert cfg["reduced"] == c["reduced"] == []
+        assert cfg["reduced"] == c["reduced"]       # empty or not:
+        spec.check_cut(c, cfg, spec.plugin("reference", cfg["reference"]))
         spec.plugin("drivers", cfg["driver"])
         spec.plugin("drivers", cfg["driver"] + "_check")
-        spec.plugin("reference", cfg["reference"])
         names.append(c["name"])
     cells = {}
     for w in b["workloads"]:
