@@ -31,9 +31,9 @@ from tnn_tpu.serving.kv_pool import PagedKVPool
 from tnn_tpu.utils import compile_cache
 
 # gpt2-large as published, and the benchmark's pool (chipbench/configs/
-# gpt2-large-serve.json): 20 heads of 64, pages of 16, 704 blocks, 8 rows
+# gpt2-large-serve.json): 20 heads of 64, pages of 16, 1,024 blocks, 16 rows
 _WIDTHS = dict(vocab_size=50257, max_len=1024, d_model=1280, num_heads=20)
-_HEADS, _HEAD_DIM, _BLOCK, _BLOCKS, _ROWS = 20, 64, 16, 704, 8
+_HEADS, _HEAD_DIM, _BLOCK, _BLOCKS, _ROWS = 20, 64, 16, 1024, 16
 # what the program's entry and exit may cost: the pool rests in another
 # layout than the kernel reads (PERF.md section 7), 2 copies in + 2 out
 _ENTRY_EXIT_COPIES = 6
@@ -131,6 +131,54 @@ def test_step_program_has_no_pool_copy_per_layer(form, dtype, one_chip,
     four = _pool_copies(one_chip, form, dtype, 4)
     assert len(two) == len(four), (two, four)
     assert len(four) <= _ENTRY_EXIT_COPIES, four
+
+
+# -- the windowed model's step (PR 28): the same question at its widths -------
+
+
+def _eva_program(one_chip, form, num_layers, blocks=64):
+    """EvaByte's block as published (32 heads of 128, window 2,048, chunk
+    16), pages of 128 as the benchmark serves it, 8 rows, compiled for the
+    v5e: (the program's text, its pool's shape)."""
+    from tnn_tpu import models
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    model = models.create("evabyte", num_layers=num_layers)
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    shape = (num_layers, blocks, 32, 128, 128)
+    pages = spec(shape, jnp.bfloat16)
+    tables, lens = spec((8, 32), jnp.int32), spec((8,), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(model.apply_decode_paged,
+                              donate_argnums=(2, 3)).lower(
+                params, spec((8,), jnp.int32), pages, pages, tables, lens)
+        else:
+            lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
+                params, spec((8, 256), jnp.int32), pages, pages, tables,
+                lens, lens)
+        return lowered.compile().as_text(), shape
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk256"])
+def test_windowed_step_compiles_for_the_chip_with_no_pool_copy(
+        form, one_chip, no_compile_cache, alarm):
+    """The kernel ``tnn_eva_attention`` at heads of 128 is accepted by the
+    chip's compiler in its decode and its chunk form, and the step around it
+    (exact rows written, summaries read back and written, both under
+    donation) makes NO pool-shaped copy: a row of 128 fills the lanes, so
+    this pool needs no conversion at entry or exit either."""
+    text, shape = _eva_program(one_chip, form, 2)
+    assert "tnn_eva_attention" in text and "tnn_paged_attention" not in text
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\("
+                      % ",".join(map(str, shape)))
+    assert not [line for line in text.splitlines() if pool.search(line)]
 
 
 # -- the one-writer invariant --------------------------------------------------

@@ -60,6 +60,7 @@ from tnn_tpu import checkpoint as ckpt_lib
 from tnn_tpu import models
 from tnn_tpu.data.tokenizer import Tokenizer
 from tnn_tpu.profiling.profiler import Profiler, span
+from tnn_tpu.serving.engine import refuse_windowed
 from tnn_tpu.serving import (AdmissionRejected, EngineSupervisor,
                              InferenceEngine, Router, ShuttingDown,
                              run_server)
@@ -340,6 +341,18 @@ def main(argv=None):
         # building random gpt2_small weights takes seconds, and a config
         # error should die before that, not after
         params = None
+
+    # a model whose state is not K/V blocks (EVA) says in one sentence what
+    # it does not serve with, before any weights are made
+    refusal = refuse_windowed(
+        model, prefix_cache=not (args.no_prefix_cache
+                                 or args.no_chunked_prefill),
+        spec=args.spec != "off", tp=args.tp, sp=args.sp,
+        host_tier_bytes=args.host_tier_bytes, kv_dtype=args.kv_dtype,
+        chunked_prefill=not args.no_chunked_prefill,
+        decode_path=args.decode_path)
+    if refusal:
+        ap.error(refusal)
 
     # fail fast on an impossible TP config BEFORE touching model weights:
     # the engine would reject it anyway, but a clear one-line error beats

@@ -20,12 +20,13 @@ from ..core.module import Module, register_module
 from ..nn.embedding import Embedding, PositionalEmbedding
 from ..nn.layers import Dense, Dropout
 from ..nn.norms import LayerNorm
-from ..nn.transformer import GPTBlock
+from ..nn.transformer import GPTBlock, PagedDecoder
 
 
 @register_module("gpt2")
-class GPT2(Module):
-    """Decoder-only LM: wte + wpe -> n_layer x GPTBlock -> ln_f -> logits (tied head)."""
+class GPT2(PagedDecoder, Module):
+    """Decoder-only LM: wte + wpe -> n_layer x GPTBlock -> ln_f -> logits (tied head).
+    Serves against the paged pool through ``PagedDecoder``."""
 
     def __init__(self, vocab_size: int = 50257, max_len: int = 1024, num_layers: int = 12,
                  d_model: int = 768, num_heads: int = 12, dropout: float = 0.0,
@@ -156,45 +157,9 @@ class GPT2(Module):
             new_caches.append(c)
         return self._head(params, self._ln_f(params, x)), new_caches
 
-    def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
-                           offsets):
-        """One decode step straight against the paged KV pool (serving).
-
-        toks (B,) this step's token per row; pages_k/pages_v the pool's
-        (L, N, H_kv, bs, Dh) arrays with L == num_layers; block_tables (B, nb)
-        page ids; offsets (B,) each row's position (kv length before this
-        token). Every layer scatters its new K/V row into its page and
-        attends over the tables (GPTBlock.apply_paged) — no contiguous cache
-        is ever assembled. Returns (last-position logits (B, V), pages_k,
-        pages_v); donate the pages through jit for in-place pool updates.
-        """
-        x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            with jax.named_scope(f"h{i}"):
-                x, pages_k, pages_v = block.apply_paged(
-                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
-                    offsets, layer=i)
-        x = self._ln_f(params, x)
-        return self._head(params, x)[:, -1], pages_k, pages_v
-
-    def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
-                    offsets, q_lens):
-        """Ragged multi-token step against the paged KV pool (serving).
-
-        The mixed prefill+decode form of ``apply_decode_paged``: toks is
-        (B, Q) with row b carrying ``q_lens[b]`` live new tokens starting at
-        position ``offsets[b]`` (the rest padding — their KV lands in the
-        pool's scratch page, their logits are garbage). Returns (full logits
-        (B, Q, V), pages_k, pages_v); the caller reads row b's next-token
-        logits at q position ``q_lens[b] - 1``.
-        """
-        x, _ = self._trunk(params, toks, False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            with jax.named_scope(f"h{i}"):
-                x, pages_k, pages_v = block.apply_paged(
-                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
-                    offsets, layer=i, q_lens=q_lens)
-        return self._head(params, self._ln_f(params, x)), pages_k, pages_v
+    def _embed(self, params, toks, offsets):
+        """PagedDecoder's hook: token + learned position embeddings."""
+        return self._trunk(params, toks, False, None, offset=offsets)[0]
 
     def _config(self):
         cfg = {"vocab_size": self.vocab_size, "max_len": self.max_len,

@@ -21,6 +21,7 @@ from ..nn.attention import MultiHeadAttention
 from ..nn.embedding import Embedding
 from ..nn.layers import Dense
 from ..nn.norms import RMSNorm
+from ..nn.transformer import PagedDecoder
 
 
 @register_module("llama_block")
@@ -34,7 +35,10 @@ class LlamaBlock(Module):
     def __init__(self, num_heads: int, mlp_hidden: int,
                  num_kv_heads: Optional[int] = None,
                  rope_theta: float = 10000.0, backend: str = "xla",
-                 kv_cache_dtype: Optional[str] = None, name=None, policy=None):
+                 kv_cache_dtype: Optional[str] = None,
+                 norm_eps: float = 1e-6, norm_unit_offset: bool = False,
+                 residual_f32: bool = False, window: Optional[int] = None,
+                 chunk: Optional[int] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
         self.mlp_hidden = int(mlp_hidden)
@@ -42,13 +46,22 @@ class LlamaBlock(Module):
         self.rope_theta = float(rope_theta)
         self.backend = backend
         self.kv_cache_dtype = kv_cache_dtype
+        self.norm_eps = float(norm_eps)
+        self.norm_unit_offset = bool(norm_unit_offset)
+        # the residual sum kept in float32 while the sublayers compute in
+        # the policy's dtype (EvaByte's ``fp32_skip_add``)
+        self.residual_f32 = bool(residual_f32)
+        self.window, self.chunk = window, chunk
         p = self.policy
-        self.ln1 = RMSNorm(policy=p)
+        norm = dict(eps=self.norm_eps, unit_offset=self.norm_unit_offset,
+                    policy=p)
+        self.ln1 = RMSNorm(**norm)
         self.attn = MultiHeadAttention(
             num_heads, causal=True, backend=backend,
             num_kv_heads=self.num_kv_heads, rope_theta=self.rope_theta,
-            use_bias=False, kv_cache_dtype=kv_cache_dtype, policy=p)
-        self.ln2 = RMSNorm(policy=p)
+            use_bias=False, kv_cache_dtype=kv_cache_dtype, window=window,
+            chunk=chunk, policy=p)
+        self.ln2 = RMSNorm(**norm)
         self.gate = Dense(self.mlp_hidden, use_bias=False, policy=p)
         self.up = Dense(self.mlp_hidden, use_bias=False, policy=p)
         # the down projection needs the model dim, known only at init —
@@ -81,14 +94,27 @@ class LlamaBlock(Module):
                             * u, train=train)
         return out
 
+    # Layer scopes of the device profile as GPTBlock names them
+    # (docs/observability.md): the first norm counts as ``attn_qkv``, each
+    # residual add as the sublayer it closes.
+
+    @jax.named_scope("attn_qkv")
+    def _ln1(self, params, x):
+        return self.ln1.apply({"params": params["ln1"], "state": {}}, x)[0]
+
+    def _add(self, x, h):
+        return x + h.astype(x.dtype)
+
+    def _mlp_residual(self, params, x, train=False):
+        with jax.named_scope("mlp"):
+            h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
+            return self._add(x, self._swiglu(params, h, train))
+
     def _apply(self, params, state, x, *, train, rng):
         k1 = rnglib.split_for(rng, 1)[0]
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
-        h, _ = self.attn.apply({"params": params["attn"], "state": {}}, h,
-                               train=train, rng=k1)
-        x = x + h
-        h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        return x + self._swiglu(params, h, train), state
+        h, _ = self.attn.apply({"params": params["attn"], "state": {}},
+                               self._ln1(params, x), train=train, rng=k1)
+        return self._mlp_residual(params, self._add(x, h), train), state
 
     # -- cached decode --------------------------------------------------------
 
@@ -96,12 +122,20 @@ class LlamaBlock(Module):
         return self.attn.init_cache(batch, max_len, d_model)
 
     def apply_cached(self, params, x, cache, offset):
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
-        h, new_cache = self.attn.apply_cached({"params": params["attn"]}, h,
-                                              cache, offset)
-        x = x + h
-        h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        return x + self._swiglu(params, h, False), new_cache
+        h, new_cache = self.attn.apply_cached(
+            {"params": params["attn"]}, self._ln1(params, x), cache, offset)
+        return self._mlp_residual(params, self._add(x, h)), new_cache
+
+    def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
+                    layer, q_lens=None):
+        """apply_cached against the paged KV pool (see
+        MultiHeadAttention.apply_paged for the contract)."""
+        h, pages_k, pages_v = self.attn.apply_paged(
+            {"params": params["attn"]}, self._ln1(params, x), pages_k,
+            pages_v, block_tables, offsets, layer=layer, q_lens=q_lens)
+        with jax.named_scope("attn_out"):
+            x = self._add(x, h)
+        return self._mlp_residual(params, x), pages_k, pages_v
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
@@ -113,16 +147,35 @@ class LlamaBlock(Module):
             cfg["num_kv_heads"] = self.num_kv_heads
         if self.kv_cache_dtype:
             cfg["kv_cache_dtype"] = self.kv_cache_dtype
+        cfg.update(_block_options(self))
         return cfg
 
 
+_BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
+                   "residual_f32": False, "window": None, "chunk": None}
+
+
+def _block_options(m):
+    """The block's options that differ from Llama's own, for ``_config``."""
+    return {k: getattr(m, k) for k, v in _BLOCK_DEFAULTS.items()
+            if getattr(m, k) != v}
+
+
 @register_module("llama")
-class Llama(Module):
+class Llama(PagedDecoder, Module):
     """Decoder-only LM: wte -> n x LlamaBlock -> RMSNorm -> head.
 
     No positional-embedding table — positions enter through RoPE inside
     attention, so ``max_len`` bounds only the decode cache, not a learned
-    parameter."""
+    parameter.
+
+    The family's other members are configurations of this class and its
+    block, not classes of their own. EvaByte (:func:`evabyte`): RMSNorm with
+    a unit offset, the residual sum in float32, an untied head of
+    ``num_pred_heads`` x vocab columns of which the first vocab (the next
+    token's) are served, and EVA attention (``window``, ``chunk``:
+    nn/attention.py). Every member serves against the paged pool through
+    ``PagedDecoder``."""
 
     def __init__(self, vocab_size: int = 32000, max_len: int = 2048,
                  num_layers: int = 12, d_model: int = 768, num_heads: int = 12,
@@ -130,8 +183,20 @@ class Llama(Module):
                  mlp_hidden: Optional[int] = None,
                  rope_theta: float = 10000.0, backend: str = "xla",
                  tie_embeddings: bool = True,
-                 kv_cache_dtype: Optional[str] = None, name=None, policy=None):
+                 kv_cache_dtype: Optional[str] = None,
+                 norm_eps: float = 1e-6, norm_unit_offset: bool = False,
+                 residual_f32: bool = False, window: Optional[int] = None,
+                 chunk: Optional[int] = None, num_pred_heads: int = 1,
+                 name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        self.norm_eps = float(norm_eps)
+        self.norm_unit_offset = bool(norm_unit_offset)
+        self.residual_f32 = bool(residual_f32)
+        self.window = int(window) if window else None
+        self.chunk = int(chunk) if chunk else None
+        self.num_pred_heads = int(num_pred_heads)
+        if self.num_pred_heads > 1 and tie_embeddings:
+            raise ValueError("more than one output head needs an untied head")
         self.vocab_size = int(vocab_size)
         self.max_len = int(max_len)
         self.num_layers = int(num_layers)
@@ -150,9 +215,14 @@ class Llama(Module):
         self.blocks = [LlamaBlock(num_heads, self.mlp_hidden,
                                   num_kv_heads=self.num_kv_heads,
                                   rope_theta=rope_theta, backend=backend,
-                                  kv_cache_dtype=kv_cache_dtype, policy=p)
+                                  kv_cache_dtype=kv_cache_dtype,
+                                  norm_eps=norm_eps,
+                                  norm_unit_offset=norm_unit_offset,
+                                  residual_f32=residual_f32, window=window,
+                                  chunk=chunk, policy=p)
                        for _ in range(num_layers)]
-        self.ln_f = RMSNorm(policy=p)
+        self.ln_f = RMSNorm(eps=norm_eps, unit_offset=norm_unit_offset,
+                            policy=p)
 
     def _init(self, rng, input_shape):
         n, s = input_shape[:2]
@@ -165,7 +235,8 @@ class Llama(Module):
         for i, block in enumerate(self.blocks):
             params[f"h{i}"] = block.init(keys[3 + i], emb_shape)["params"]
         if not self.tie_embeddings:
-            head = Dense(self.vocab_size, use_bias=False, policy=self.policy)
+            head = Dense(self.vocab_size * self.num_pred_heads,
+                         use_bias=False, policy=self.policy)
             params["head"] = head.init(keys[2], emb_shape)["params"]
         return params, {}
 
@@ -175,21 +246,31 @@ class Llama(Module):
             return self.wte.attend(params["wte"], x)
         from ..ops.pallas.quant_matmul import qmatmul
 
-        w = self.policy.cast_param(params["head"]["kernel"])
-        return qmatmul(x, w, out_dtype=jnp.float32)
+        # of several output heads the first (the next token's) is served
+        w = self.policy.cast_param(
+            params["head"]["kernel"])[:, :self.vocab_size]
+        return qmatmul(self.policy.cast_in(x), w, out_dtype=jnp.float32)
+
+    @jax.named_scope("embed")
+    def _embed(self, params, ids, offsets=None):
+        """Token embeddings (positions enter through RoPE, not here); the
+        residual stream starts in float32 where the model keeps it so."""
+        x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
+        return x.astype(jnp.float32) if self.residual_f32 else x
+
+    @jax.named_scope("ln_f")
+    def _ln_f(self, params, x):
+        return self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)[0]
 
     def _hidden(self, params, ids, train, rng):
         keys = rnglib.split_for(rng, self.num_layers)
-        with jax.named_scope("embed"):
-            x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
+        x = self._embed(params, ids)
         for i, block in enumerate(self.blocks):
             with jax.named_scope(f"h{i}"):
                 x, _ = block.apply(
                     {"params": params[f"h{i}"], "state": {}}, x,
                     train=train, rng=keys[i])
-        with jax.named_scope("ln_f"):
-            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return x
+        return self._ln_f(params, x)
 
     def _apply(self, params, state, ids, *, train, rng):
         return self._head(params, self._hidden(params, ids, train, rng)), state
@@ -213,17 +294,14 @@ class Llama(Module):
         return [b.init_cache(batch, max_len, self.d_model) for b in self.blocks]
 
     def apply_cached(self, params, ids, caches, offset):
-        with jax.named_scope("embed"):
-            x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
+        x = self._embed(params, ids)
         new_caches = []
         for i, block in enumerate(self.blocks):
             with jax.named_scope(f"h{i}"):
                 x, c = block.apply_cached(params[f"h{i}"], x, caches[i],
                                           offset)
             new_caches.append(c)
-        with jax.named_scope("ln_f"):
-            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), new_caches
+        return self._head(params, self._ln_f(params, x)), new_caches
 
     def _config(self):
         cfg = {"vocab_size": self.vocab_size, "max_len": self.max_len,
@@ -235,7 +313,38 @@ class Llama(Module):
             cfg["num_kv_heads"] = self.num_kv_heads
         if self.kv_cache_dtype:
             cfg["kv_cache_dtype"] = self.kv_cache_dtype
+        cfg.update(_block_options(self))
+        if self.num_pred_heads != 1:
+            cfg["num_pred_heads"] = self.num_pred_heads
         return cfg
+
+
+def evabyte(num_layers: int = 16, **kw):
+    """EvaByte (https://huggingface.co/EvaByte/EvaByte, config.json) as one
+    chip of a two-stage pipeline serves it: 16 of the 32 layers, every width
+    as published (4,096 wide, 32 heads of 128, feed-forward 11,008, the 320
+    bytes, 32,768 positions, window 2,048, chunk 16, 8 output heads), bf16
+    weights. ``num_layers=32`` is the whole model (13 GB: no room for a
+    batch on a 16 GB chip)."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    cfg = dict(vocab_size=320, max_len=32768, d_model=4096, num_heads=32,
+               mlp_hidden=11008, window=2048, chunk=16, num_pred_heads=8)
+    cfg.update(kw)
+    return Llama(num_layers=num_layers, rope_theta=100000.0,
+                 tie_embeddings=False, norm_eps=1e-5, norm_unit_offset=True,
+                 residual_f32=True, **cfg)
+
+
+def evabyte_tiny(**kw):
+    """EvaByte's block at test sizes: 2 layers, 64 wide, 4 heads of 16,
+    window 32, chunk 4, 2 output heads, 256 positions."""
+    cfg = dict(num_layers=2, d_model=64, num_heads=4, mlp_hidden=128,
+               window=32, chunk=4, num_pred_heads=2, max_len=256)
+    cfg.update(kw)
+    return evabyte(**cfg)
 
 
 def llama_small(**kw):

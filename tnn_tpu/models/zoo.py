@@ -72,6 +72,8 @@ register("llama_small")(lambda **kw: llama_lib.llama_small(**kw))
 register("flash_llama_small")(
     lambda **kw: llama_lib.llama_small(backend="pallas", **kw))
 register("llama_1b")(lambda **kw: llama_lib.llama_1b(**kw))
+register("evabyte")(lambda **kw: llama_lib.evabyte(**kw))
+register("evabyte_tiny")(lambda **kw: llama_lib.evabyte_tiny(**kw))
 register("gpt2_medium")(lambda **kw: gpt2_lib.gpt2_medium(**kw))
 register("gpt2_large")(lambda **kw: gpt2_lib.gpt2_large(**kw))
 register("flash_gpt2_small")(lambda **kw: gpt2_lib.gpt2_small(backend="pallas", **kw))

@@ -259,9 +259,20 @@ class MultiHeadAttention(Module):
                  num_kv_heads: Optional[int] = None,
                  kv_cache_dtype: Optional[str] = None,
                  rope_theta: Optional[float] = None, use_bias: bool = True,
+                 window: Optional[int] = None, chunk: Optional[int] = None,
                  name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
+        # EVA attention (ops/pallas/eva_attention.py): exact keys of the
+        # current ``window`` positions beside one learned summary (``phi``,
+        # ``mu`` a head) for every ``chunk`` earlier tokens. None = every
+        # position exact, the plain softmax attention.
+        self.window = int(window) if window else None
+        self.chunk = int(chunk) if chunk else None
+        if (self.window is None) != (self.chunk is None) or (
+                self.window and self.window % self.chunk):
+            raise ValueError(f"window {window} and chunk {chunk} come "
+                             "together, the window a multiple of the chunk")
         # grouped-query attention (beyond reference): H_kv < H shares each
         # kv head across a group of query heads, shrinking the decode KV
         # cache (the decode bandwidth floor) by H/H_kv
@@ -304,6 +315,13 @@ class MultiHeadAttention(Module):
         if self.use_bias:
             params["qkv_bias"] = jnp.zeros((d + 2 * kv_d,), pd)
             params["out_bias"] = jnp.zeros((d,), pd)
+        if self.window:
+            dh = d // self.num_heads
+            k3, k4 = jax.random.split(k2)
+            for name, key in (("phi", k3), ("mu", k4)):
+                params[name] = (jnp.clip(jax.random.normal(
+                    key, (self.num_kv_heads, dh)), -1.0, 1.0)
+                    / math.sqrt(dh)).astype(pd)
         return params, {}
 
     def _split_heads(self, x, h=None):
@@ -343,6 +361,11 @@ class MultiHeadAttention(Module):
         return self.policy.cast_out(y)
 
     def _apply(self, params, state, x, *, train, rng):
+        if self.window and x.shape[1] > self.window:
+            raise NotImplementedError(
+                f"a sequence of {x.shape[1]} positions is longer than the "
+                f"window of {self.window}: EVA attention past one window "
+                "runs against the paged pool (apply_paged)")
         q, k, v = self._project_qkv(params, x)
         if self.rope_theta:
             with jax.named_scope("attn_qkv"):
@@ -474,6 +497,12 @@ class MultiHeadAttention(Module):
                 k_new = apply_rope(k_new, offsets, self.rope_theta)
         from ..ops.pallas import paged_attention as pa
 
+        if self.window:
+            out, pages_k, pages_v = self._eva_paged(
+                params, q, k_new, v_new, pages_k, pages_v, block_tables,
+                offsets, layer, q_lens)
+            y = self._project_out(params, out, False, None)
+            return y, pages_k, pages_v
         quant_pool = isinstance(pages_k, pa.QuantPages)
         if q_lens is None and x.shape[1] == 1:
             # decode form, kept verbatim: the pure-decode compiled step must
@@ -511,6 +540,46 @@ class MultiHeadAttention(Module):
         y = self._project_out(params, out.transpose(0, 2, 1, 3), False, None)
         return y, pages_k, pages_v
 
+    def _eva_paged(self, params, q, k_new, v_new, pages_k, pages_v,
+                   block_tables, offsets, layer, q_lens):
+        """The EVA step, one function for a decode row, a prompt chunk and a
+        mixed batch of both: the (rotated) new rows go into the window's
+        exact pages at their window-relative positions, every chunk they
+        complete gets its summary row, and one softmax runs over the
+        window's exact rows and the earlier windows' summaries. A step's
+        tokens lie in one window (the scheduler ends a grant there).
+        q, k_new, v_new: (B, H, Q, Dh); returns (out (B, H, Q, Dh), pages)."""
+        from ..ops.pallas import eva_attention as eva
+        from ..ops.pallas import paged_attention as pa
+
+        if isinstance(pages_k, pa.QuantPages):
+            raise NotImplementedError(
+                "EVA summaries are written in the pool's compute dtype; "
+                "int8 pages are refused for a windowed model")
+        b, _, qw, _ = q.shape
+        if q_lens is None:
+            q_lens = jnp.full((b,), qw, jnp.int32)
+        bs = pages_k.shape[-2]
+        n_exact = self.window // bs
+        rel = offsets % self.window
+        exact = block_tables[:, :n_exact]
+        pages_k = pa.scatter_kv_chunk(
+            pages_k, exact, rel, k_new.transpose(0, 2, 1, 3).astype(
+                pages_k.dtype), q_lens, layer=layer)
+        pages_v = pa.scatter_kv_chunk(
+            pages_v, exact, rel, v_new.transpose(0, 2, 1, 3).astype(
+                pages_v.dtype), q_lens, layer=layer)
+        pages_k, pages_v = eva.write_summaries(
+            pages_k, pages_v, block_tables, offsets, q_lens, params["phi"],
+            params["mu"], n_exact=n_exact, window=self.window,
+            chunk=self.chunk, layer=layer, qw=qw)
+        out = eva.eva_attention(
+            q.transpose(0, 2, 1, 3), pages_k, pages_v, block_tables,
+            rel + q_lens, (offsets // self.window) * (self.window
+                                                      // self.chunk),
+            n_exact=n_exact, q_lens=q_lens, layer=layer)
+        return out.transpose(0, 2, 1, 3), pages_k, pages_v
+
     def output_shape(self, input_shape):
         return tuple(input_shape)
 
@@ -525,4 +594,6 @@ class MultiHeadAttention(Module):
             cfg["rope_theta"] = self.rope_theta
         if not self.use_bias:
             cfg["use_bias"] = False
+        if self.window:
+            cfg["window"], cfg["chunk"] = self.window, self.chunk
         return cfg

@@ -147,24 +147,36 @@ class GroupNorm(Module):
 
 @register_module("rmsnorm")
 class RMSNorm(Module):
-    """RMS norm (no reference equivalent — modern LLM addition beyond parity)."""
+    """RMS norm (no reference equivalent — modern LLM addition beyond parity).
 
-    def __init__(self, eps: float = 1e-6, name=None, policy=None):
+    ``unit_offset``: the gain is ``1 + scale`` and ``scale`` starts at zero
+    (EvaByte's ``norm_add_unit_offset``; Gemma's norm too)."""
+
+    def __init__(self, eps: float = 1e-6, unit_offset: bool = False,
+                 name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.eps = float(eps)
+        self.unit_offset = bool(unit_offset)
 
     def _init(self, rng, input_shape):
         c = input_shape[-1]
-        return {"scale": jnp.ones((c,), self.policy.param_dtype)}, {}
+        fill = jnp.zeros if self.unit_offset else jnp.ones
+        return {"scale": fill((c,), self.policy.param_dtype)}, {}
 
     def _apply(self, params, state, x, *, train, rng):
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jnp.reciprocal(jnp.sqrt(ms + self.eps)) * params["scale"].astype(jnp.float32)
+        gain = params["scale"].astype(jnp.float32)
+        if self.unit_offset:
+            gain = 1.0 + gain
+        y = xf * jnp.reciprocal(jnp.sqrt(ms + self.eps)) * gain
         return y.astype(x.dtype), state
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
 
     def _config(self):
-        return {"eps": self.eps}
+        cfg = {"eps": self.eps}
+        if self.unit_offset:
+            cfg["unit_offset"] = True
+        return cfg

@@ -24,6 +24,46 @@ from .layers import Dense, Dropout
 from .norms import LayerNorm
 
 
+class PagedDecoder:
+    """The serving step of a decoder-only LM against the paged KV pool, for
+    any model made of ``self.blocks`` whose blocks have ``apply_paged``:
+    ``_embed(params, toks, offsets)``, the blocks, ``_ln_f``, ``_head``. One
+    loop for every family (GPT-2's block, the Llama block and its EvaByte
+    configuration), so the engine's normal path takes each of them."""
+
+    def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
+                    offsets, q_lens=None):
+        """Ragged multi-token step against the paged KV pool.
+
+        toks is (B, Q) with row b carrying ``q_lens[b]`` live new tokens
+        starting at position ``offsets[b]`` (the rest padding: their KV lands
+        in the pool's scratch page, their logits are garbage); pages_k /
+        pages_v the pool's (L, N, H_kv, bs, Dh) arrays with L == num_layers;
+        block_tables (B, nb) page ids. Every layer writes its new K/V rows
+        into their pages and attends over the tables (the block's
+        ``apply_paged``): no contiguous cache is ever assembled. Returns
+        (logits (B, Q, V), pages_k, pages_v); the caller reads row b's
+        next-token logits at q position ``q_lens[b] - 1``, and donates the
+        pages through jit for in-place pool updates. ``q_lens`` None is the
+        decode form (Q == 1)."""
+        x = self._embed(params, toks, offsets)
+        for i, block in enumerate(self.blocks):
+            with jax.named_scope(f"h{i}"):
+                x, pages_k, pages_v = block.apply_paged(
+                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
+                    offsets, layer=i, q_lens=q_lens)
+        return self._head(params, self._ln_f(params, x)), pages_k, pages_v
+
+    def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
+                           offsets):
+        """One decode step: toks (B,) this step's token per row, offsets (B,)
+        each row's position (kv length before this token). Returns
+        (last-position logits (B, V), pages_k, pages_v)."""
+        logits, pages_k, pages_v = self.apply_paged(
+            params, toks[:, None], pages_k, pages_v, block_tables, offsets)
+        return logits[:, -1], pages_k, pages_v
+
+
 @register_module("gpt_block")
 class GPTBlock(Module):
     """Pre-LN transformer decoder block (parity: gpt_block, layer_builder.hpp:531)."""

@@ -589,13 +589,19 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
     f32 scale scatter through the same block-table math, so a row's scale
     can never drift from its page slot.
     """
+    return write_rows(pages, block_tables, starts, rows, q_lens, layer=layer)
+
+
+def write_rows(pages, block_tables, starts, rows, q_lens, *, layer=None):
+    """``scatter_kv_chunk`` under the caller's scope: the whole-page write
+    for rows that are not a step's K/V (EVA's chunk summaries)."""
     if isinstance(pages, QuantPages):
         qrows, srows = quantize_kv_rows(rows)
         return QuantPages(
-            scatter_kv_chunk(pages.data, block_tables, starts, qrows, q_lens,
-                             layer=layer),
-            scatter_kv_chunk(pages.scale, block_tables, starts, srows, q_lens,
-                             layer=layer))
+            write_rows(pages.data, block_tables, starts, qrows, q_lens,
+                       layer=layer),
+            write_rows(pages.scale, block_tables, starts, srows, q_lens,
+                       layer=layer))
     if pages.ndim == 5 and layer is None:
         raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
     bs = pages.shape[-2]

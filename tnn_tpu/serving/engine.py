@@ -127,6 +127,45 @@ def _splice_draft_row(toks, draft, row):
     return jax.lax.dynamic_update_slice(toks, draft, (row, jnp.int32(1)))
 
 
+def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
+                    host_tier_bytes=0, kv_dtype="f32", chunked_prefill=True,
+                    decode_path="auto") -> Optional[str]:
+    """One sentence saying why ``model`` does not serve with the first of
+    these options that is on, or None. A model whose state is not "K and V
+    of every position" (EVA: an exact window beside chunk summaries) runs on
+    the normal path over a pool of two kinds of page; what assumes K/V blocks
+    is refused at start-up, by the engine and by ``tnn-serve`` before it
+    makes any weights."""
+    window = getattr(model, "window", None)
+    if not window:
+        return None
+    for on, what in (
+            (prefix_cache, "prefix sharing (the prefix cache; "
+             "--no-prefix-cache): a cached block would have to carry the "
+             "summaries of everything before it"),
+            (spec, "speculative decoding (spec): a rejected draft may have "
+             "ended a window or written a summary, and neither rolls back"),
+            (tp > 1, "tensor parallelism (tp): the summary write and the "
+             "two-segment kernel are not head-sharded"),
+            (sp > 1, "sequence parallelism (sp): exact and summary pages "
+             "are not block-sharded"),
+            (bool(host_tier_bytes), "the host KV tier: it demotes "
+             "prefix-cache blocks"),
+            (kv_dtype == "int8", "int8 pages (kv_dtype): summaries are "
+             "written in the compute dtype"),
+            (not chunked_prefill, "whole-prompt prefill: the window and "
+             "its summaries are built chunk by chunk"),
+            (decode_path not in ("auto", "paged"),
+             f"decode_path={decode_path!r}: there is no assembled cache of "
+             "two kinds of state")):
+        if on:
+            return (f"{type(model).__name__} keeps an exact window of "
+                    f"{window} positions beside chunk summaries, not K/V "
+                    f"blocks of every position: it does not serve with "
+                    f"{what}")
+    return None
+
+
 class StepInFlight:
     """Handle for one dispatched-but-uncommitted engine step.
 
@@ -335,6 +374,17 @@ class InferenceEngine:
         self.model = model
         self.kv_dtype = kv_dtype
         self.quant_weights = bool(quant_weights)
+        # a model whose state is not "K and V of every position" (EVA: an
+        # exact window beside chunk summaries) runs on the same path, with a
+        # pool of two kinds of page; what assumes K/V blocks is refused
+        window = getattr(model, "window", None)
+        refusal = refuse_windowed(
+            model, prefix_cache=prefix_cache and chunked_prefill,
+            spec=self.drafter is not None, tp=tp, sp=sp,
+            host_tier_bytes=host_tier_bytes, kv_dtype=kv_dtype,
+            chunked_prefill=chunked_prefill, decode_path=decode_path)
+        if refusal:
+            raise ValueError(refusal)
         # tensor parallelism: tp > 1 shards attention heads and the paged
         # pool's head axis over a mesh of tp devices; all host-side
         # bookkeeping stays replicated (serving/tp.py). _tp is None at
@@ -416,7 +466,8 @@ class InferenceEngine:
             num_layers=model.num_layers, num_kv_heads=model.num_kv_heads,
             head_dim=self.head_dim, num_blocks=num_blocks,
             block_size=block_size, dtype=model.policy.compute_dtype,
-            kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp)
+            kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp,
+            window=window, chunk=getattr(model, "chunk", None))
         self.pool.fault_plan = faults
         # static gauge extras spliced into every _health_gauges refresh:
         # lets operators spot a misconfigured replica from /healthz alone
@@ -436,11 +487,11 @@ class InferenceEngine:
             "pool_blocks_per_shard": self.pool.blocks_per_shard,
             "host_tier_max_bytes": int(host_tier_bytes),
         }
-        cap = min(model.max_len, self.pool.capacity * block_size)
+        cap = min(model.max_len, self.pool.token_capacity)
         self.max_seq_len = min(max_seq_len or cap, cap)
         # fixed assembly width: every decode step gathers this many blocks per
         # row (padded with scratch), so ONE compile covers all batch states
-        self.blocks_per_seq = self.pool.blocks_for(self.max_seq_len)
+        self.blocks_per_seq = self.pool.table_width(self.max_seq_len)
         if self.sp > 1 and self.blocks_per_seq % self.sp:
             raise ValueError(
                 f"assembly width blocks_per_seq={self.blocks_per_seq} does "
@@ -639,10 +690,10 @@ class InferenceEngine:
             raise ValueError(
                 f"prompt {prompt.size} + max_new_tokens {max_new_tokens} "
                 f"exceeds max_seq_len {self.max_seq_len}")
-        if self.pool.blocks_for(total) > self.pool.capacity:
+        if self.pool.lifetime_blocks(total) > self.pool.capacity:
             raise ValueError(
-                f"request needs {self.pool.blocks_for(total)} blocks but the "
-                f"pool only has {self.pool.capacity} — it could never run")
+                f"request needs {self.pool.lifetime_blocks(total)} blocks but "
+                f"the pool only has {self.pool.capacity} — it could never run")
         if self.max_queue_depth and \
                 self.scheduler.queue_depth >= self.max_queue_depth:
             if self.admission_policy == "reject":
@@ -760,10 +811,11 @@ class InferenceEngine:
         """Pool bookkeeping + full block accounting against every running
         request's live table (only running requests hold blocks). Raises
         ValueError on any violation — the chaos suite's leak detector."""
-        pairs = [(r.block_table, r.cache_len)
-                 for r in self.scheduler.running if r.block_table]
-        self.pool.check_invariants([t for t, _ in pairs],
-                                   [n for _, n in pairs])
+        rows = [r for r in self.scheduler.running
+                if r.block_table or r.summary_table]
+        self.pool.check_invariants([r.block_table for r in rows],
+                                   [r.cache_len for r in rows],
+                                   [r.summary_table for r in rows])
         if self.kv_tier is not None:
             self.kv_tier.check_invariants()
 
@@ -778,9 +830,7 @@ class InferenceEngine:
             req.queued_s += max(0.0, now - req.queued_time)
         else:
             self._note_leave_running(req, now)
-        if req.block_table:
-            self.pool.free(req.block_table)
-            req.block_table = []
+        self._free_blocks(req)
         self.scheduler.terminate(req, state, error)
         self.tracer.instant("serve.terminal", trace=req.trace_id,
                             rid=req.rid, state=state.value,
@@ -793,6 +843,69 @@ class InferenceEngine:
             self.metrics.observe_timeout()
         if events is not None and bucket is not None:
             events[bucket].append((req.rid, error))
+
+    def _free_blocks(self, req: Request) -> None:
+        """Give back everything a request holds: its exact pages and, in a
+        windowed pool, its summary pages."""
+        if req.block_table or req.summary_table:
+            self.pool.free(req.block_table + req.summary_table)
+            req.block_table, req.summary_table = [], []
+
+    def _grow_need(self, req: Request, cache_len: int, new_tokens: int):
+        """(exact, summary) blocks ``req`` lacks to write ``new_tokens``
+        positions from ``cache_len``."""
+        need_e, need_s = self.pool.table_need(cache_len, new_tokens)
+        return (max(0, need_e - len(req.block_table)),
+                max(0, need_s - len(req.summary_table)))
+
+    def _extend(self, req: Request, grow) -> List[Any]:
+        """Allocate ``grow`` = (exact, summary) blocks onto the request's
+        tables; returns what a roll-back undoes: (req, table's attribute,
+        length before, the new blocks)."""
+        done = []
+        for attr, n in zip(("block_table", "summary_table"), grow):
+            if n:
+                table = getattr(req, attr)
+                ext = self.pool.alloc(n, start=len(table))
+                done.append((req, attr, len(table), ext))
+                table.extend(ext)
+        return done
+
+    def _unextend(self, done, *, only_intact: bool = False) -> None:
+        for req, attr, orig, ext in reversed(done):
+            table = getattr(req, attr)
+            if only_intact and table[orig:orig + len(ext)] != ext:
+                # a terminated/preempted row already freed its whole table
+                # (extension included); only intact tables still own it
+                continue
+            self.pool.free(ext)
+            del table[orig:]
+
+    def _end_window(self, req: Request) -> None:
+        """A windowed request whose committed length reached a window's end
+        gives its exact pages back: the next window starts with none, and
+        reads this one through its summaries only."""
+        if self.pool.window and req.block_table \
+                and req.cache_len % self.pool.window == 0:
+            self.pool.free(req.block_table)
+            req.block_table = []
+            self.metrics.observe_eva_roll()
+            self.tracer.instant("serve.eva_roll", trace=req.trace_id,
+                                rid=req.rid, step=self.step_seq,
+                                at=req.cache_len)
+
+    def _observe_eva(self, rows, ends) -> None:
+        """A windowed step's counters: the share of the window's exact
+        positions each row attends over (row i's tokens end just before
+        position ``ends[i]``), and the pool's rows that hold summaries."""
+        pool = self.pool
+        if not pool.window:
+            return
+        self.metrics.observe_eva_step(
+            [((int(e) - 1) % pool.window + 1) / pool.window
+             for _, e in zip(rows, ends)],
+            sum(r.cache_len // pool.chunk for r in self.scheduler.running)
+            / (pool.capacity * pool.block_size))
 
     # -- per-request latency breakdown (host-side clocks only) ----------------
 
@@ -1262,30 +1375,29 @@ class InferenceEngine:
                     or req.stop_token is not None
                     or req.deadline_s is not None
                     or req.num_generated + 1 >= req.max_new_tokens
-                    or req.cache_len + 2 > self.max_seq_len):
+                    or req.cache_len + 2 > self.max_seq_len
+                    # step N ends this row's window: its commit gives the
+                    # exact pages back, which no prediction packs
+                    or self.pool.room_in_window(req.cache_len) == 1):
                 return False
-            grows.append(max(0, self.pool.blocks_for(req.cache_len + 2)
-                             - len(req.block_table)))
-        if sum(grows) and not self.pool.can_alloc(sum(grows)):
+            grows.append(self._grow_need(req, req.cache_len + 1, 1))
+        total = sum(map(sum, grows))
+        if total and not self.pool.can_alloc(total):
             return False
         rollback: List[Any] = []
         try:
             for req, g in zip(live, grows):
-                if g:
-                    ext = self.pool.alloc(g, start=len(req.block_table))
-                    rollback.append((req, len(req.block_table), ext))
-                    req.block_table.extend(ext)
+                rollback.extend(self._extend(req, g))
         except PoolExhausted:
-            for req, orig, ext in rollback:
-                self.pool.free(ext)
-                del req.block_table[orig:]
+            self._unextend(rollback)
             return False
         # speculative=True packs the predicted row state: each offset
         # assumes exactly one token committed at step N
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            paged=self._paged, fused_available=False, speculative=True)
+            paged=self._paged, fused_available=False, speculative=True,
+            sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.offsets)
         b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
         label = "decode_paged" if self._paged else "decode"
@@ -1305,13 +1417,12 @@ class InferenceEngine:
                     self._put(step.temps), self._put(step.topks),
                     self._put(step.topps), step_key, self._put(step.poison))
         except Exception:  # noqa: BLE001 — speculation must never hurt
-            for req, orig, ext in rollback:
-                self.pool.free(ext)
-                del req.block_table[orig:]
+            self._unextend(rollback)
             self._reuse_key = step_key
             self._recover_pages_if_dead(flight.events)
             return False
         self.pool.update_pages(pk, pv)
+        self._observe_eva(live, offsets + 1)
         flight.spec = {
             "rec": {"kind": "decode", "dev": (newtok, ok),
                     "live": list(live), "t0": t0, "b": b},
@@ -1343,13 +1454,7 @@ class InferenceEngine:
             and all(req.cache_len == spec["offsets"][req.rid]
                     for req in live))
         if not predicted:
-            for req, orig, ext in spec["rollback"]:
-                if req.block_table[orig:orig + len(ext)] == ext:
-                    # a terminated/preempted row already freed its whole
-                    # table (extension included); only intact tables still
-                    # own the speculative growth
-                    self.pool.free(ext)
-                    del req.block_table[orig:]
+            self._unextend(spec["rollback"], only_intact=True)
             self._reuse_key = spec["key"]
             self.metrics.observe_overlap_rebuild()
             return
@@ -1601,7 +1706,7 @@ class InferenceEngine:
         mixed step (blocks are allocated per chunk, not up front). With the
         prefix cache on, the cached prefix is forked first — those
         positions are already-resident KV and are never prefilled."""
-        nb_total = self.pool.blocks_for(req.prefill_len)
+        nb_total = self.pool.table_width(req.prefill_len)
         if nb_total > self.blocks_per_seq:
             # unreachable via submit()'s validation (resume <= prompt +
             # max_new), but a corrupted resume must not poison the batch
@@ -2004,8 +2109,8 @@ class InferenceEngine:
         budget-FAILed, or hit an allocation fault — a chunk-boundary alloc
         failure fails ONLY this request (``chunk=True`` also routes the
         prefill fault-injection site at the boundary)."""
-        needed = self.pool.blocks_for(req.cache_len + new_tokens)
-        grow = max(0, needed - len(req.block_table))
+        need = self._grow_need(req, req.cache_len, new_tokens)
+        grow = sum(need)
         while grow and not self.pool.can_alloc(
                 grow, start=len(req.block_table)):
             victim = self.scheduler.preempt_victim()
@@ -2032,9 +2137,7 @@ class InferenceEngine:
         try:
             if chunk and self.faults is not None:
                 self.faults.on_prefill()
-            if grow:
-                req.block_table.extend(
-                    self.pool.alloc(grow, start=len(req.block_table)))
+            self._extend(req, need)
         except (PoolExhausted, FaultInjected) as e:
             where = "at chunk boundary" if chunk else "mid-decode"
             self._terminate(req, RequestState.FAILED,
@@ -2185,7 +2288,7 @@ class InferenceEngine:
             rows, len(dec), drafts, takes,
             b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, spec_on=spec_on,
-            kv_key=self._kv_key)
+            kv_key=self._kv_key, sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.starts, step.q_lens)
         b, qw, poison = step.b, step.qw, step.poison
         if self.faults is not None:
@@ -2258,6 +2361,7 @@ class InferenceEngine:
                 self._abort_batch(rows, f"decode step failed: {e}", events)
                 return
         self.pool.update_pages(pk, pv)
+        self._observe_eva(rows, step.starts + step.q_lens)
         flight.recs.append({
             "kind": "spec" if spec_on else "mixed",
             "dev": ((accepts, newtok, ok, toks_in) if spec_on
@@ -2296,6 +2400,7 @@ class InferenceEngine:
                 if not spec_on:
                     tok = int(newtok[i])
                     req.cache_len += 1
+                    self._end_window(req)
                     req.next_token = tok
                     req.out_tokens.append(tok)
                     events["tokens"].append((req.rid, tok))
@@ -2331,6 +2436,7 @@ class InferenceEngine:
                 continue
             take = takes[req.rid]
             req.cache_len += take
+            self._end_window(req)
             self.metrics.observe_prefill_chunk(take)
             if self.faults is not None:
                 self.faults.prefill_delay(take)
@@ -2576,8 +2682,7 @@ class InferenceEngine:
 
     def _preempt(self, req: Request) -> None:
         self._note_leave_running(req, time.perf_counter())
-        self.pool.free(req.block_table)
-        req.block_table = []
+        self._free_blocks(req)
         req.cache_len = 0
         self.scheduler.requeue(req)
         self.metrics.observe_preemption(req.rid)
@@ -2710,7 +2815,8 @@ class InferenceEngine:
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            paged=self._paged, fused_available=self._fused is not None)
+            paged=self._paged, fused_available=self._fused is not None,
+            sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.offsets)
         b, nb, key, lockstep = step.b, step.nb, step.key, step.lockstep
         poison = step.poison
@@ -2765,6 +2871,7 @@ class InferenceEngine:
                 self._abort_batch(live, f"decode step failed: {e}", events)
                 return None
         self.pool.update_pages(pk, pv)
+        self._observe_eva(live, step.offsets + 1)
         return {"kind": "decode", "dev": (newtok, ok), "live": list(live),
                 "t0": t0, "b": b}
 
@@ -2787,6 +2894,7 @@ class InferenceEngine:
                 continue
             tok = int(newtok[i])
             req.cache_len += 1
+            self._end_window(req)
             req.next_token = tok
             req.out_tokens.append(tok)
             events["tokens"].append((req.rid, tok))
@@ -2900,8 +3008,7 @@ class InferenceEngine:
                     f"last failure: {reason}", events, "failed")
                 continue
             self._note_leave_running(req, now)
-            self.pool.free(req.block_table)
-            req.block_table = []
+            self._free_blocks(req)
             req.cache_len = 0
             self.scheduler.migrate(req)
             self.metrics.observe_migration(len(req.resume_tokens))
@@ -2928,8 +3035,7 @@ class InferenceEngine:
         self._note_leave_running(req, time.perf_counter())
         if self._deferred:
             self._flush_deferred_for(req)
-        self.pool.free(req.block_table)
-        req.block_table = []
+        self._free_blocks(req)
         self.scheduler.finish(req, reason)
         self.metrics.observe_finish(req.ttft_s)
         self.tracer.instant("serve.finish", trace=req.trace_id,

@@ -47,6 +47,27 @@ contract, not an implementation detail:
   their pages or vice versa. Rows are quantized at scatter time and
   dequantized at the attention read; the block-table math is identical,
   so fork/COW/truncate/eviction never look inside the bundle.
+
+Two kinds of page
+-----------------
+A model whose attention keeps an exact window beside learned summaries (EVA:
+``ops/pallas/eva_attention.py``) holds two kinds of state, and the pool holds
+both in the SAME arrays, from ONE allocator and under ONE occupancy. With
+``window`` and ``chunk`` set, a request has two tables:
+
+- its *exact* table (``Request.block_table``): the pages of the positions of
+  its CURRENT window only, position ``p`` at window-relative position
+  ``p % window``. It grows as the window fills and is released whole when the
+  window ends (``cache_len`` reaches a multiple of ``window``);
+- its *summary* table (``Request.summary_table``): row ``c`` holds the
+  summary ``(k~, v~)`` of tokens ``chunk * c .. chunk * c + chunk - 1``. It
+  only grows, and goes when the request does.
+
+A step's packed table is ``window // block_size`` exact entries, then the
+summary entries (``table_width``). ``window = None`` is the case "every
+position exact, no summaries": GPT-2's, with the exact table the whole
+table. ``table_need`` / ``lifetime_blocks`` answer for both kinds, and
+``check_invariants`` / ``check_step_writes`` know both.
 """
 from __future__ import annotations
 
@@ -71,7 +92,21 @@ class PagedKVPool:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int,
                  num_blocks: int, block_size: int = 16, dtype=jnp.float32,
-                 kv_dtype: str = "f32", sharding=None, sp: int = 1):
+                 kv_dtype: str = "f32", sharding=None, sp: int = 1,
+                 window: Optional[int] = None, chunk: Optional[int] = None):
+        if (window is None) != (chunk is None):
+            raise ValueError("window and chunk come together")
+        if window is not None:
+            if window % block_size or window % chunk or chunk < 1:
+                raise ValueError(
+                    f"window {window} must be a multiple of block_size "
+                    f"{block_size} and of chunk {chunk}")
+            if sp > 1 or kv_dtype != "f32":
+                raise ValueError(
+                    "a windowed pool (exact pages beside summary pages) is "
+                    "neither block-sharded (sp) nor int8")
+        self.window = window
+        self.chunk = chunk
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved scratch)")
         if block_size < 1:
@@ -224,6 +259,64 @@ class PagedKVPool:
     def blocks_for(self, num_tokens: int) -> int:
         """Blocks needed to hold ``num_tokens`` cache positions."""
         return max(1, math.ceil(num_tokens / self.block_size))
+
+    # -- two kinds of page: what a request needs of each ------------------
+
+    @property
+    def exact_width(self) -> int:
+        """Entries of a packed step table that are exact pages (0: all)."""
+        return self.window // self.block_size if self.window else 0
+
+    def table_need(self, cache_len: int, new_tokens: int = 0):
+        """(exact, summary) table lengths a request needs to hold
+        ``cache_len`` resident positions and write ``new_tokens`` more (which
+        lie in one window: ``room_in_window``)."""
+        if self.window is None:
+            return self.blocks_for(cache_len + new_tokens), 0
+        end = cache_len + new_tokens
+        in_window = end - (cache_len // self.window) * self.window
+        return (math.ceil(in_window / self.block_size),
+                math.ceil((end // self.chunk) / self.block_size))
+
+    def room_in_window(self, cache_len: int) -> int:
+        """Tokens a step may write from ``cache_len`` before the window
+        ends (a step never crosses a window's end: the pages of the old
+        window are released between two steps)."""
+        if self.window is None:
+            return 1 << 62
+        return self.window - cache_len % self.window
+
+    def lifetime_blocks(self, total_tokens: int) -> int:
+        """The most blocks a request of ``total_tokens`` positions (prompt
+        and output) ever holds: will it fit to its last token."""
+        if self.window is None:
+            return self.blocks_for(total_tokens)
+        return (math.ceil(min(total_tokens, self.window) / self.block_size)
+                + math.ceil((total_tokens // self.chunk) / self.block_size))
+
+    def admission_blocks(self, first_tokens: int, total_tokens: int) -> int:
+        """Blocks an admission is planned against: what the request's first
+        step needs, or, in a windowed pool (whose pages come and go, so that
+        "fits now" says nothing), what it needs to its last token."""
+        if self.window is None:
+            return self.blocks_for(first_tokens)
+        return self.lifetime_blocks(total_tokens)
+
+    def table_width(self, total_tokens: int) -> int:
+        """Entries of the packed step table a request of ``total_tokens``
+        positions needs: the exact segment whole, then its summaries."""
+        if self.window is None:
+            return self.blocks_for(total_tokens)
+        return self.exact_width + max(1, math.ceil(
+            (total_tokens // self.chunk) / self.block_size))
+
+    @property
+    def token_capacity(self) -> int:
+        """The longest request the pool could hold alone."""
+        if self.window is None:
+            return self.capacity * self.block_size
+        rest = self.capacity - self.exact_width
+        return max(0, rest) * self.block_size * self.chunk
 
     def owner(self, block: int) -> int:
         """Sequence-parallel shard a global block id lives on."""
@@ -428,7 +521,8 @@ class PagedKVPool:
     def check_invariants(
             self,
             block_tables: Optional[Iterable[Sequence[int]]] = None,
-            seq_lens: Optional[Sequence[int]] = None) -> None:
+            seq_lens: Optional[Sequence[int]] = None,
+            summary_tables: Optional[Iterable[Sequence[int]]] = None) -> None:
         """Verify the pool's bookkeeping; raises ValueError on violation.
 
         Always checked: free + allocated + evictable == capacity (a strict
@@ -454,6 +548,12 @@ class PagedKVPool:
         (a full-cover prefix hit re-derives its last token copy-on-write and
         briefly holds that one extra block). A rejected draft suffix whose
         blocks were never truncated shows up here as a longer tail.
+
+        With ``summary_tables`` (parallel too: a windowed pool's second kind
+        of page) both of a row's tables are held to ``table_need``: the exact
+        table covers the current window's resident positions and no more
+        than the next token's, the summary table one row a finished chunk;
+        and the full accounting counts the blocks of both.
         """
         if self.kv_dtype == "int8":
             # scale/page agreement: both sides must still be the bundled
@@ -507,12 +607,28 @@ class PagedKVPool:
             raise ValueError(f"refcount < 1: {self._ref}")
         if block_tables is not None:
             block_tables = [list(t) for t in block_tables]
+            sums = [list(t) for t in summary_tables] \
+                if summary_tables is not None else [[]] * len(block_tables)
+            if len(sums) != len(block_tables):
+                raise ValueError("summary_tables not parallel to "
+                                 "block_tables")
             if seq_lens is not None:
                 if len(list(seq_lens)) != len(block_tables):
                     raise ValueError(
                         f"seq_lens ({len(list(seq_lens))}) not parallel to "
                         f"block_tables ({len(block_tables)})")
                 for i, (table, n) in enumerate(zip(block_tables, seq_lens)):
+                    if self.window is not None:
+                        (lo_e, lo_s), (hi_e, hi_s) = \
+                            self.table_need(n), self.table_need(n, 1)
+                        if not (lo_e <= len(table) <= hi_e
+                                and lo_s <= len(sums[i]) <= hi_s):
+                            raise ValueError(
+                                f"row {i}: {n} resident tokens hold "
+                                f"{len(table)} exact and {len(sums[i])} "
+                                f"summary blocks, want {lo_e}..{hi_e} and "
+                                f"{lo_s}..{hi_s}")
+                        continue
                     if n > len(table) * self.block_size:
                         raise ValueError(
                             f"row {i}: {n} resident tokens exceed table "
@@ -525,7 +641,7 @@ class PagedKVPool:
                             f"{self.blocks_for(n + 1)}); a rejected draft "
                             f"suffix was not truncated")
             usage: Counter = Counter()
-            for table in block_tables:
+            for table in block_tables + sums:
                 usage.update(table)
             for sc in self._scratch:        # padded entries are legal
                 usage.pop(sc, None)
@@ -563,8 +679,22 @@ class PagedKVPool:
         for i, (table, start, n) in enumerate(zip(tables, starts, q_lens)):
             if n <= 0:
                 continue
-            first, last = int(start) // bs, (int(start) + int(n) - 1) // bs
-            for blk in table[first:last + 1]:
+            start, end = int(start), int(start) + int(n)
+            if self.window is None:
+                written = table[start // bs:(end - 1) // bs + 1]
+            else:
+                # the window's exact pages at window-relative positions,
+                # then the summary page of every chunk the tokens complete
+                rel, w = start % self.window, self.exact_width
+                if rel + int(n) > self.window:
+                    raise ValueError(
+                        f"row {i} writes {start}..{end - 1} across the end "
+                        f"of a window of {self.window}")
+                written = list(table[rel // bs:(rel + int(n) - 1) // bs + 1])
+                c0, c1 = start // self.chunk, end // self.chunk
+                if c1 > c0:
+                    written += list(table[w + c0 // bs:w + (c1 - 1) // bs + 1])
+            for blk in written:
                 blk = int(blk)
                 if blk in self._scratch:
                     continue

@@ -475,6 +475,13 @@ class ServingMetrics:
         # overlapped loop: speculatively dispatched steps torn down because
         # step N's outcome invalidated the predicted row set
         self.overlap_rebuilds = 0
+        # a windowed model (EVA: exact window beside chunk summaries): the
+        # share of the window's exact positions in use, a row and a step;
+        # the pool's rows that hold summaries; windows ended
+        self.eva_window_fill_sum = 0.0      # over eva_row_steps
+        self.eva_row_steps = 0
+        self.eva_summary_rows_max = 0.0
+        self.eva_windows_rolled = 0
         # runtime-resilience counters (supervisor / overload degradation)
         self.shed = 0                 # queued requests displaced by priority
         self.engine_restarts = 0      # supervisor-driven engine recoveries
@@ -604,6 +611,20 @@ class ServingMetrics:
         N's committed outcome invalidated its predicted row set."""
         self.overlap_rebuilds += 1
         self._tick("serve.overlap_rebuild", 1)
+
+    def observe_eva_step(self, window_fills, summary_share: float) -> None:
+        """One step of a windowed model: ``window_fills`` the share of the
+        window's exact positions each of its rows attends over,
+        ``summary_share`` the summary rows held by all running requests over
+        the rows of the whole pool."""
+        self.eva_window_fill_sum += sum(window_fills)
+        self.eva_row_steps += len(window_fills)
+        self.eva_summary_rows_max = max(self.eva_summary_rows_max,
+                                        summary_share)
+
+    def observe_eva_roll(self) -> None:
+        """A request's window ended: its exact pages went back to the pool."""
+        self.eva_windows_rolled += 1
 
     def observe_decode(self, num_tokens: int, seconds: float,
                        batch_width: int) -> None:
@@ -872,7 +893,7 @@ class ServingMetrics:
         def ms(x):
             return x * 1e3
 
-        return {
+        out = {
             "requests_finished": self.finished,
             "decode_tokens": self.decode_tokens,
             "prefill_tokens": self.prefill_tokens,
@@ -965,6 +986,15 @@ class ServingMetrics:
             "tier_bytes": getattr(self, "_last_tier_bytes", 0.0),
             "replicas": getattr(self, "_last_replicas", 0.0),
         }
+        if self.eva_row_steps:
+            # only a windowed model has these: a reader of a model with
+            # every position exact finds nothing, not a zero
+            out.update(
+                eva_window_fill_mean=(self.eva_window_fill_sum
+                                      / self.eva_row_steps),
+                eva_summary_rows_max=self.eva_summary_rows_max,
+                eva_windows_rolled=self.eva_windows_rolled)
+        return out
 
     # -- Prometheus exposition ------------------------------------------------
 

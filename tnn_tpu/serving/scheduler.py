@@ -83,6 +83,9 @@ class Request:
     # -- engine-managed --
     state: RequestState = RequestState.QUEUED
     block_table: List[int] = field(default_factory=list)
+    summary_table: List[int] = field(default_factory=list)  # a windowed
+    #                                     pool's second kind of page: one row
+    #                                     a finished chunk (kv_pool.py)
     cache_len: int = 0                  # tokens resident in the KV pool
     prefill_len: int = 0                # total tokens the current (re-)
     #                                     prefill must push; while cache_len
@@ -224,6 +227,20 @@ class Scheduler:
 
     # -- planning -------------------------------------------------------------
 
+    def _headroom(self, pool) -> int:
+        """Blocks an admission may plan against. A windowed pool admits a
+        request only if it fits TO ITS LAST TOKEN beside what the running
+        requests may still take (their exact window whole, a summary row for
+        every chunk to come): exact pages come and go with the windows, so
+        "fits now" says nothing, and a steady run never preempts."""
+        if pool.window is None:
+            return pool.num_allocatable
+        owed = sum(
+            pool.lifetime_blocks(len(r.prompt) + r.max_new_tokens)
+            - len(r.block_table) - len(r.summary_table)
+            for r in self.running)
+        return pool.num_allocatable - owed
+
     def schedule(self, pool) -> StepPlan:
         """Plan one engine step: which queued requests to admit, and the
         running set to decode. Admission is strictly FCFS — a blocked
@@ -282,7 +299,8 @@ class Scheduler:
         for i, req in enumerate(prefilling):
             rem = req.prefill_len - req.cache_len
             avail = budget if budget >= 1 else (1 if i == 0 else 0)
-            take = min(self.chunk_size, rem, avail)
+            take = min(self.chunk_size, rem, avail,
+                       pool.room_in_window(req.cache_len))
             if take <= 0:
                 continue
             chunks[req.rid] = take
@@ -306,9 +324,11 @@ class Scheduler:
                     shared = mb[:-1] if cow else mb
                     forked = len(shared)
                     revive = sum(1 for b in shared if pool.is_evictable(b))
-            take = min(self.chunk_size, total - cached, max(budget, 1))
-            nb = pool.blocks_for(cached + take) - forked
-            if planned_blocks + nb + revive > pool.num_allocatable:
+            take = min(self.chunk_size, total - cached, max(budget, 1),
+                       pool.room_in_window(cached))
+            nb = pool.admission_blocks(
+                cached + take, len(req.prompt) + req.max_new_tokens) - forked
+            if planned_blocks + nb + revive > self._headroom(pool):
                 break
             req.prefill_len = total
             chunks[req.rid] = take

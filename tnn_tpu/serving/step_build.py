@@ -97,8 +97,13 @@ def shard_tables(tables: np.ndarray, sp: int,
                     np.int32(-1))
 
 
-def _fill_row(step: PackedStep, i: int, req) -> None:
+def _fill_row(step: PackedStep, i: int, req, sum_at: int = 0) -> None:
+    """``sum_at``: where a windowed pool's summary pages start in the packed
+    table (its exact segment's width, ``PagedKVPool.exact_width``)."""
     step.tables[i, :len(req.block_table)] = req.block_table
+    if req.summary_table:
+        step.tables[i, sum_at:sum_at + len(req.summary_table)] = \
+            req.summary_table
     step.temps[i] = req.temperature
     step.topks[i] = req.top_k
     step.topps[i] = req.top_p
@@ -115,7 +120,8 @@ def _alloc_common(b: int, nb: int, scratch: int):
 
 def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
                takes: Dict[int, int], *, b: int, nb: int, scratch: int,
-               spec_on: bool, kv_key: Tuple[Any, ...]) -> MixedStep:
+               spec_on: bool, kv_key: Tuple[Any, ...],
+               sum_at: int = 0) -> MixedStep:
     """Pack decode rows (first ``n_dec`` of ``rows``, each 1 token +
     optional draft) and prompt-chunk rows (the rest, ``takes[rid]`` tokens
     each) into one ragged batch. Host drafts land in the token matrix here;
@@ -135,7 +141,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(rows):
         step.starts[i] = req.cache_len
-        _fill_row(step, i, req)
+        _fill_row(step, i, req, sum_at)
         if i < n_dec:
             d = drafts.get(req.rid, []) if spec_on else []
             step.toks[i, 0] = req.next_token
@@ -156,7 +162,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
                 kv_key: Tuple[Any, ...], paged: bool,
                 fused_available: bool,
-                speculative: bool = False) -> DecodeStep:
+                speculative: bool = False, sum_at: int = 0) -> DecodeStep:
     """Pack the pure-decode batch. ``speculative=True`` packs the
     overlapped engine's predicted step N+1: each row's offset assumes
     exactly one more token committed, and the token column is left zero —
@@ -171,7 +177,7 @@ def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
         if not speculative:
             step.toks[i] = req.next_token
         step.offsets[i] = req.cache_len + (1 if speculative else 0)
-        _fill_row(step, i, req)
+        _fill_row(step, i, req, sum_at)
     step.lockstep = (not paged and fused_available and not speculative
                      and len(set(step.offsets[:len(live)].tolist())) == 1)
     if step.lockstep:
