@@ -133,6 +133,33 @@ def test_step_program_has_no_pool_copy_per_layer(form, dtype, one_chip,
     assert len(four) <= _ENTRY_EXIT_COPIES, four
 
 
+@pytest.mark.parametrize("qw", [1, 64])
+def test_paged_kernel_compiles_at_plain_llama_shapes(qw, one_chip,
+                                                     no_compile_cache, alarm):
+    """The kernel alone at widths no cell runs it at: heads of 128 and
+    grouped queries (32 query heads over 8 KV heads, ``g`` = 4), the decode
+    form and a 64-token chunk. The chip's compiler accepts the grouped grid
+    step there: 8 pages of all 8 heads, ``Q * g`` = 4 and 256 query rows."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, heads, kv_heads, head_dim, blocks = 8, 32, 8, 128, 256
+    pages = spec((2, blocks, kv_heads, _BLOCK, head_dim), jnp.bfloat16)
+    tables, lens = spec((rows, 128), jnp.int32), spec((rows,), jnp.int32)
+    assert pa.fetch_group(bs=_BLOCK, dh=head_dim, hkv=kv_heads,
+                          qg=qw * heads // kv_heads,
+                          page_dtype=jnp.bfloat16, nb=128) == (8, kv_heads)
+
+    def attend(q, pk, pv, tbl, kv_lens, q_lens):
+        return pa.paged_attention(q, pk, pv, tbl, kv_lens, q_lens=q_lens,
+                                  layer=1, backend="pallas", interpret=False)
+
+    text = jax.jit(attend).lower(
+        spec((rows, qw, heads, head_dim), jnp.bfloat16), pages, pages,
+        tables, lens, lens).compile().as_text()
+    assert "tnn_paged_attention" in text
+
+
 # -- the windowed model's step (PR 28): the same question at its widths -------
 
 

@@ -446,6 +446,12 @@ class TestProfilerSink:
             assert by_name.get(name), f"no {name} event in the host plane"
             assert all("step" in ev[4] for ev in by_name[name]), name
         assert {"kind", "key"} <= set(by_name["serve.dispatch"][0][4])
+        # a paged step program says what a grid step of its kernel fetches:
+        # 12 table entries (all of a row's 48 positions) of both heads
+        paged = [ev[4] for ev in by_name["serve.dispatch"]
+                 if ev[4]["kind"] == "decode_paged"]
+        assert paged and all((int(a["attn_pages"]), int(a["attn_heads"]))
+                             == (12, 2) for a in paged)
         assert not any(" " in ev[1] or "=" in ev[1] for ev in evs)
         assert len({ev[0] for ev in evs if ev[1] in PHASES}) == 1
         # the phases tile the worker's time: no two of them overlap, and
@@ -464,6 +470,7 @@ class TestProfilerSink:
         # and the reservoirs were fed where the work happened
         s = eng.metrics.summary()
         assert s["build_ms_p50"] > 0 and s["commit_ms_p50"] > 0
+        assert 0 < s["attn_fetch_fill_mean"] <= 3 / 12  # <= 11 positions
         assert "serve.host_gap" not in by_name      # the gap is between spans
 
     def test_training_spans_in_the_host_plane(self, tmp_path):

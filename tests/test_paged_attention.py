@@ -530,3 +530,199 @@ def test_page_write_matches_per_row_scatter(case, form):
     _assert_same_but_scratch(got, old(pages, tables, *args, layer=layer))
     if case == "all_scratch":   # and nothing but the scratch page changed
         _assert_same_but_scratch(got, pages)
+
+
+# -- the grouped grid step: several pages and every head a step ---------------
+#
+# A grid step fetches ``pages`` consecutive table entries of ``heads`` heads
+# (``pa.fetch_group``) and makes ONE softmax update over the group. The cases
+# above run it too (at their sizes one group holds a whole table); these put
+# group boundaries, padding, holes and dead rows where the grouping can go
+# wrong: pages of 16, so a group is 8 pages = 128 positions.
+
+_GROUP_BS = 16
+
+
+def _group_case(seed, kv_lens, *, heads=(4, 4), head_dim=64, qw=None,
+                q_lens=None, nb=20, num_layers=2, dtype=jnp.float32,
+                holes=()):
+    """Pool + tables for rows of the given lengths (table entries past a
+    row's live pages point at scratch page 0). ``qw`` None is the decode
+    form; ``holes`` are (row, entry) table slots stamped -1."""
+    rng = np.random.default_rng(seed)
+    h, hkv = heads
+    b = len(kv_lens)
+    live = [-(-int(n) // _GROUP_BS) for n in kv_lens]
+    num_blocks = sum(live) + 1
+    shape = (num_layers, num_blocks, hkv, _GROUP_BS, head_dim)
+    pk = jnp.asarray(rng.normal(size=shape), dtype)
+    pv = jnp.asarray(rng.normal(size=shape), dtype)
+    perm = rng.permutation(np.arange(1, num_blocks))
+    tables = np.zeros((b, nb), np.int32)
+    at = 0
+    for i, n in enumerate(live):
+        tables[i, :n] = perm[at:at + n]
+        at += n
+    for row, entry in holes:
+        tables[row, entry] = -1
+    qshape = (b, h, head_dim) if qw is None else (b, qw, h, head_dim)
+    q = jnp.asarray(rng.normal(size=qshape), dtype)
+    if q_lens is not None:
+        q_lens = jnp.asarray(q_lens, jnp.int32)
+    return (q, pk, pv, jnp.asarray(tables),
+            jnp.asarray(kv_lens, jnp.int32), q_lens)
+
+
+def _assert_matches_reference(case, *, layer=1, atol=2e-5, stats=False):
+    q, pk, pv, tables, kv_lens, q_lens = case
+    kw = dict(q_lens=q_lens, layer=layer, return_stats=stats)
+    out = pa.paged_attention(q, pk, pv, tables, kv_lens, backend="pallas",
+                             **kw)
+    ref = pa.paged_attention(q, pk, pv, tables, kv_lens, backend="xla", **kw)
+    for got, want in zip(out if stats else (out,), ref if stats else (ref,)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=atol,
+                                   rtol=atol)
+    return out[0] if stats else out
+
+
+# lengths that end inside a group, at its end and one past it, a whole table
+# (20 entries: no multiple of the group's 8, so the table is padded), one
+# position, and a row of length 0
+_GROUP_LENS = [0, 1, 100, 128, 129, 256, 257, 320]
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("heads", [(4, 4), (4, 2), (4, 1)],
+                         ids=["g1", "g2", "g4"])
+def test_grouped_decode_lengths_around_group_ends(heads, head_dim):
+    case = _group_case(head_dim + heads[1], _GROUP_LENS, heads=heads,
+                       head_dim=head_dim)
+    assert pa.fetch_group(bs=_GROUP_BS, dh=head_dim, hkv=heads[1],
+                          qg=heads[0] // heads[1], page_dtype=jnp.float32,
+                          nb=20) == (8, heads[1])
+    out = _assert_matches_reference(case)
+    assert np.all(np.asarray(out[0]) == 0)      # the row of length 0
+
+
+@pytest.mark.parametrize("heads", [(4, 4), (4, 1)], ids=["g1", "g4"])
+@pytest.mark.parametrize("qw", [1, 8, 64])
+def test_grouped_chunks_with_dead_rows(qw, heads):
+    """Ragged chunks across group ends: a row with no query token (its
+    context still live) and a row of length 0 beside live rows, a decode
+    row, chunks that start before a group's end and end after it."""
+    kv_lens = [200, 0, 129, 128 + qw // 2, 320, 77]
+    q_lens = [0, 0, 1, min(qw, 128 + qw // 2), qw, min(qw, 77)]
+    case = _group_case(qw * 10 + heads[1], kv_lens, heads=heads, qw=qw,
+                       q_lens=q_lens)
+    out = np.asarray(_assert_matches_reference(case))
+    for i, n in enumerate(q_lens):
+        assert np.all(out[i, n:] == 0), i
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk8"])
+def test_grouped_holes_inside_a_live_group_with_stats(form):
+    """-1 table entries (pages another sequence-parallel shard owns) in the
+    middle of live groups, a group that holds nothing but holes, and a row
+    whose every page is a hole: out, m and l all match the reference's."""
+    holes = [(0, 1), (0, 2), (0, 9), (1, 0), (2, 3)] \
+        + [(3, e) for e in range(8, 16)] + [(4, e) for e in range(5)]
+    qw = None if form == "decode" else 8
+    q_lens = None if qw is None else [8, 1, 5, 8, 8, 0]
+    case = _group_case(71, [300, 129, 64, 320, 80, 40], qw=qw, q_lens=q_lens,
+                       heads=(4, 2), holes=holes)
+    _assert_matches_reference(case, stats=True)
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk8"])
+def test_grouped_int8_pages(form):
+    qw = None if form == "decode" else 8
+    q_lens = None if qw is None else [8, 1, 0, 4, 8]
+    q, pk, pv, tables, kv_lens, q_lens = _group_case(
+        83, [300, 129, 50, 128, 16], qw=qw, q_lens=q_lens, heads=(4, 2))
+    case = (q, _quantize(pk), _quantize(pv), tables, kv_lens, q_lens)
+    _assert_matches_reference(case)
+
+
+@pytest.mark.parametrize("pages", ["traced-layer", "one-layer", "bf16"])
+def test_grouped_layer_forms(pages):
+    """A traced ``layer`` (the scalar-prefetch operand), one layer's 4-D
+    pages, and bf16 pages at the serving cell's page shape."""
+    dtype = jnp.bfloat16 if pages == "bf16" else jnp.float32
+    q, pk, pv, tables, kv_lens, _ = _group_case(
+        97, [300, 129, 7], heads=(4, 2), dtype=dtype)
+    if pages == "one-layer":
+        out = pa.paged_attention(q, pk[1], pv[1], tables, kv_lens,
+                                 backend="pallas")
+    else:
+        run = jax.jit(lambda layer: pa.paged_attention(
+            q, pk, pv, tables, kv_lens, layer=layer, backend="pallas"))
+        out = run(jnp.asarray(1, jnp.int32))
+    ref = pa.paged_attention_reference(q, pk, pv, tables, kv_lens, layer=1)
+    atol = 3e-2 if pages == "bf16" else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
+
+
+# (bs, dh, hkv, qg, page dtype, nb) -> the (pages, heads) it must give
+_FETCH_GROUPS = {
+    "gpt2-large-decode": ((16, 64, 20, 1, jnp.bfloat16, 64), (8, 20)),
+    "gpt2-large-chunk64": ((16, 64, 20, 64, jnp.bfloat16, 64), (8, 20)),
+    "gpt2-large-tp4-shard": ((16, 64, 5, 64, jnp.bfloat16, 64), (8, 5)),
+    "gpt2-large-int8": ((16, 64, 20, 64, jnp.int8, 64), None),
+    "llama-dh128-g4": ((16, 128, 8, 256, jnp.bfloat16, 128), None),
+    "evabyte-width-heads": ((16, 128, 32, 64, jnp.bfloat16, 128), None),
+    "pages-of-128": ((128, 128, 32, 256, jnp.bfloat16, 32), None),
+    "short-table": ((16, 64, 20, 1, jnp.bfloat16, 3), (3, 20)),
+}
+
+
+@pytest.mark.parametrize("shape", list(_FETCH_GROUPS))
+def test_fetch_group_stays_inside_its_vmem_budget(shape):
+    (bs, dh, hkv, qg, dtype, nb), want = _FETCH_GROUPS[shape]
+    pages, heads = pa.fetch_group(bs=bs, dh=dh, hkv=hkv, qg=qg,
+                                  page_dtype=dtype, nb=nb)
+    assert 1 <= pages <= nb and hkv % heads == 0
+    assert pages * bs <= max(bs, 128)
+    assert pa.group_vmem_bytes(pages, heads, bs=bs, dh=dh, qg=qg,
+                               page_dtype=dtype) <= pa._VMEM_BUDGET
+    if heads < hkv:     # the next divisor up would not have fitted
+        up = min(h for h in range(heads + 1, hkv + 1) if hkv % h == 0)
+        assert pa.group_vmem_bytes(pages, up, bs=bs, dh=dh, qg=qg,
+                                   page_dtype=dtype) > pa._VMEM_BUDGET
+    if want is not None:
+        assert (pages, heads) == want
+
+
+def test_attn_fetch_fill_mean_is_a_hand_count():
+    """``summary()["attn_fetch_fill_mean"]``: a row's live pages over the
+    page slots of the groups the kernel fetches for them, mean over rows
+    and steps, with the group the kernel's own launch derives."""
+    from tnn_tpu.models.gpt2 import GPT2
+    from tnn_tpu.serving import InferenceEngine
+
+    model = GPT2(vocab_size=128, max_len=512, num_layers=1, d_model=32,
+                 num_heads=2)
+    params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+    eng = InferenceEngine(model, params, num_blocks=40, block_size=16,
+                          max_batch_size=4, max_seq_len=512,
+                          decode_path="paged")
+    assert "attn_fetch_fill_mean" not in eng.metrics.summary()
+    assert eng._attn_group(1) == pa.fetch_group(
+        bs=16, dh=16, hkv=2, qg=1, page_dtype=eng.pool.dtype, nb=32) == (8, 2)
+    # rows of 1, 128, 129 and 300 positions hold 1, 8, 9 and 19 pages, in
+    # 1, 1, 2 and 3 groups of 8 slots; the fifth entry is not a live row
+    eng._observe_attention([None] * 4, np.array([1, 128, 129, 300, 77]), 1)
+    want = (1 / 8 + 8 / 8 + 9 / 16 + 19 / 24) / 4
+    assert eng.metrics.summary()["attn_fetch_fill_mean"] \
+        == pytest.approx(want)
+    # a step whose rows hold nothing yet adds nothing
+    eng._observe_attention([None] * 2, np.array([0, 0]), 64)
+    assert eng.metrics.summary()["attn_fetch_fill_mean"] \
+        == pytest.approx(want)
+    # and the engine feeds it: a 20-token prompt decoding 3 tokens never
+    # holds more than 2 of a group's 8 slots
+    eng.submit(np.arange(20, dtype=np.int32), 3)
+    eng.run_until_complete()
+    assert eng.metrics.attn_fetch_row_steps >= 4 + 3
+    assert eng.metrics.summary()["attn_fetch_fill_mean"] < want

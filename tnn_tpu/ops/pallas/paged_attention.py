@@ -22,23 +22,37 @@ its own chunk plus every previously written position. ``q_lens = 1``
 reproduces the PR 2 decode kernel exactly; this is what lets the engine pack
 decode rows and prefill chunks into ONE compiled mixed step.
 
-Grid: ``(B, H_kv, num_table_entries)`` — the innermost axis sweeps one row's
-block table; the (m, l, acc) scratch carries the online softmax across it.
-Because the grid's head axis never mixes heads, tensor-parallel serving
+Grid: ``(B, H_kv / heads, ceil(num_table_entries / pages))``. A grid step
+fetches ``pages`` CONSECUTIVE table entries of row b, each as one whole-page
+block ``(heads, bs, Dh)`` (the pool is handed to ``pallas_call`` once per page
+slot, each with its own index map into the block table; the ``heads`` heads
+of a page are contiguous in the pool, so one DMA a page), and makes ONE
+online-softmax update per head over the group's ``pages * bs`` positions:
+one batched score matmul, one mask, one ``m / l / acc`` update, one
+``p @ V``. The innermost axis sweeps the row's table a group at a time and
+the (m, l, acc) scratch carries the softmax across it. ``fetch_group`` gives
+``(pages, heads)`` from what a call can see: ``pages`` so that a group is
+about 128 positions (no more than the table is wide), ``heads`` the largest
+divisor of ``H_kv`` whose blocks, scratch and values fit a VMEM budget at the
+page's shape and dtype and the query tile's ``Q * g`` rows. GPT-2 large
+(pages of 16 x 64 bf16, 20 heads) runs 8 pages of all 20 heads a grid step:
+a grid step costs ~0.3 us whatever it holds, so it has to hold a lot.
+Because a head block never mixes heads, tensor-parallel serving
 (``serving/tp.py``) runs this kernel UNMODIFIED per shard: each shard's pool
 slice holds ``H_kv/tp`` heads of every page, the kernel sweeps it with the
-same block tables (replicated host-side), and the head axis of q/out is just
-locally smaller.
+same block tables (replicated host-side), and ``heads`` follows the shape.
 Grouped-query attention shares each fetched kv page across its query group:
 the wrapper lays q (and out, and the stats) out HEAD-MAJOR, (B, H_kv, Q * G,
-Dh) with row ``t * G + i`` = token t, group member i, so a grid step's block
-is already the 2-D (Q * G, Dh) tile the body multiplies — the v5e Mosaic
+Dh) with row ``t * G + i`` = token t, group member i, so a head's block is
+already the 2-D (Q * G, Dh) tile the body multiplies — the v5e Mosaic
 refuses in-kernel shape casts between (Q, G, Dh) and (Q * G, Dh). For the
 decode form (Q = 1) that layout is a pure reshape; a multi-token step pays
-one transpose of the small q/out tensors per call, never of KV. Pages past
-a row's live length clamp their fetch index to the last live page, so the Pallas pipeline elides the dead DMAs (same trick as
-flash_attention's causal dead-block clamp), and ``pl.when`` skips their
-compute.
+one transpose of the small q/out tensors per call, never of KV. A group with
+no live position is skipped whole (``pl.when``) and its fetch indices repeat
+the row's last live group's, so the Pallas pipeline elides the dead DMAs
+(same trick as flash_attention's causal dead-block clamp); dead pages INSIDE
+a live group (past the row's length, or ``-1`` holes) fetch the row's last
+live page (or page 0) and the mask keeps them out of the softmax.
 
 ``paged_attention_reference`` is the same math in plain lax (gather the tables
 into a contiguous cache, masked softmax) — the parity oracle for the kernel
@@ -65,6 +79,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
@@ -101,49 +116,81 @@ def quantize_kv_rows(x):
     return q, scale
 
 
-def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, k_ref,
-                 v_ref, *refs, scale: float, bs: int, g: int, qw: int,
-                 stats: bool = False):
+# a grid step's group: about this many key positions (the width of the MXU and
+# of a vector register), every KV head of the pool where VMEM allows
+_GROUP_POSITIONS = 128
+# what a grid step's blocks (two buffers each), scratch and the body's values
+# may take of the 16 MiB a kernel gets on the v5e by default
+_VMEM_BUDGET = 10 * 2 ** 20
+
+
+def _tile_bytes(rows, cols, dtype):
+    """VMEM bytes of a (rows, cols) tile: lanes padded to 128, sublanes to
+    the dtype's packing (8 rows of 4 bytes, 16 of 2, 32 of 1)."""
+    size = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // size)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * size
+
+
+def group_vmem_bytes(pages, heads, *, bs, dh, qg, page_dtype):
+    """VMEM one grid step holds for a group of ``pages`` pages of ``heads``
+    heads: the K/V blocks (and an int8 pool's scale sidecars) and the q/out
+    blocks twice each (the pipeline's two buffers), the m/l/acc scratch, and
+    the body's group-wide K, V, scores and probabilities. q is counted at
+    the pages' dtype (float32 beside int8 pages)."""
+    quant = jnp.dtype(page_dtype) == jnp.int8
+    dtype = jnp.float32 if quant else page_dtype
+    page = _tile_bytes(bs, dh, page_dtype)
+    if quant:
+        page += _tile_bytes(bs, 1, jnp.float32)
+    t = pages * bs
+    blocks = 2 * 2 * pages * page + 2 * 2 * _tile_bytes(qg, dh, dtype)
+    scratch = _tile_bytes(qg, dh, jnp.float32) \
+        + 2 * _tile_bytes(qg, 1, jnp.float32)
+    body = 2 * _tile_bytes(t, dh, dtype) \
+        + 2 * _tile_bytes(qg, t, jnp.float32) \
+        + _tile_bytes(qg, dh, jnp.float32)
+    return heads * (blocks + scratch + body)
+
+
+def fetch_group(*, bs, dh, hkv, qg, page_dtype, nb):
+    """``(pages, heads)`` of one grid step, from what a call can see: the
+    page's shape ``(bs, dh)`` and dtype (int8 means ``QuantPages``), the
+    pool's (or the shard's) ``hkv`` heads, the query tile's ``qg = Q * g``
+    rows and the table's ``nb`` entries.
+
+    ``pages`` consecutive table entries make a group of about
+    ``_GROUP_POSITIONS`` key positions (a whole table if it is shorter);
+    ``heads`` is the largest divisor of
+    ``hkv`` whose group fits ``_VMEM_BUDGET`` (``group_vmem_bytes``), and
+    only a group too large with ONE head gives pages up. The launch and the
+    engine's ``attn_fetch_fill_mean`` both ask this function."""
+    pages = max(1, min(_GROUP_POSITIONS // bs, nb))
+    size = functools.partial(group_vmem_bytes, bs=bs, dh=dh, qg=qg,
+                             page_dtype=page_dtype)
+    while pages > 1 and size(pages, 1) > _VMEM_BUDGET:
+        pages //= 2
+    heads = max(h for h in range(1, hkv + 1) if hkv % h == 0
+                and (h == 1 or size(pages, h) <= _VMEM_BUDGET))
+    return pages, heads
+
+
+def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
+                 scale: float, bs: int, g: int, qw: int, pages: int,
+                 quant: bool, stats: bool):
+    """One grid step: ``pages`` consecutive table entries of row b, every
+    head of the step's head block, ONE online-softmax update per head over
+    the group's ``pages * bs`` positions.
+
+    ``refs``: per page slot its K and V block ``(heads, bs, Dh)`` (then the
+    two ``(heads, bs, 1)`` scales of an int8 pool), the output, with
+    ``stats`` the m and l outputs (the running max and normalizer a
+    sequence-parallel shard hands ``ops.softmax_merge.merge_psum``), then
+    the m / l / acc scratch, all ``(heads, Q*g, .)``. bf16 and int8 pages
+    differ ONLY in how a page's K/V reaches the MXU (``load``)."""
     del layer_ref  # consumed by the index maps, not the body
-
-    def load_kv():
-        return k_ref[...], v_ref[...]    # (bs, Dh) — one page
-
-    _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs,
-               scale=scale, bs=bs, g=g, qw=qw, stats=stats)
-
-
-def _attn_kernel_int8(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref,
-                      k_ref, v_ref, ks_ref, vs_ref, *refs, scale: float,
-                      bs: int, g: int, qw: int, stats: bool = False):
-    del layer_ref
-
-    def load_kv():
-        # in-VMEM dequant inside the online-softmax sweep: the page arrives
-        # as int8 + one f32 scale per row, so HBM traffic is int8 bytes on
-        # this backend too (the load runs under the same pl.when as the
-        # block's compute — dead pages fetch nothing extra). NOTE: int8's
-        # minimum TPU tile is (32, 128) sublane x lane; blocks smaller than
-        # that lean on Mosaic's relayout and lose part of the traffic win.
-        k = k_ref[...].astype(jnp.float32) * ks_ref[...]
-        v = v_ref[...].astype(jnp.float32) * vs_ref[...]
-        return k, v
-
-    _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs,
-               scale=scale, bs=bs, g=g, qw=qw, stats=stats)
-
-
-def _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs, *,
-               scale: float, bs: int, g: int, qw: int, stats: bool):
-    """Shared online-softmax body: the f32 and int8 kernels differ ONLY in
-    how a page's K/V reaches the MXU (``load_kv``), keeping the two in
-    lockstep by construction.
-
-    ``refs`` is (o_ref, [m_ref, l_ref when stats], m_scr, l_scr, acc_scr) —
-    with ``stats`` the kernel also emits its per-row online-softmax state
-    (running max ``m``, normalizer ``l``), which is exactly the partial a
-    sequence-parallel shard needs for ``ops.softmax_merge.merge_psum``.
-    """
+    per = 4 if quant else 2
+    kv, refs = refs[:per * pages], refs[per * pages:]
     if stats:
         o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -151,133 +198,176 @@ def _attn_step(tables_ref, lens_ref, qlens_ref, q_ref, load_kv, refs, *,
     b = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
+    t = pages * bs
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)   # (Q*g, 1) running max
-        l_scr[:] = jnp.zeros_like(l_scr)            # (Q*g, 1) running denom
-        acc_scr[:] = jnp.zeros_like(acc_scr)        # (Q*g, Dh) output acc
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)   # running max
+        l_scr[...] = jnp.zeros_like(l_scr)            # running denominator
+        acc_scr[...] = jnp.zeros_like(acc_scr)        # output accumulator
 
     kv_len = lens_ref[b]
     q_live = qlens_ref[b]
 
-    # a NEGATIVE table entry is a dead hole — sequence-parallel serving
-    # stamps -1 at positions another shard owns; the fetch index map clamps
-    # it to page 0 and this predicate skips the block entirely
-    @pl.when((j * bs < kv_len) & (tables_ref[b, j] >= 0))
-    def _block():
-        # the whole ragged query chunk, (Q*g, Dh) with row = t*g + group
-        # member: the WRAPPER lays q/out/stats out that way, because the v5e
-        # Mosaic refuses the (Q, g, Dh) <-> (Q*g, Dh) shape casts in-kernel
+    def load(i):
+        k_ref, v_ref = kv[per * i], kv[per * i + 1]
+        if not quant:
+            return k_ref[...], v_ref[...]
+        # dequantized in VMEM: the page arrives as int8 + one f32 scale per
+        # row, so HBM traffic is int8 bytes. (int8's minimum TPU tile is
+        # (32, 128); smaller pages lean on Mosaic's relayout.)
+        return (k_ref[...].astype(jnp.float32) * kv[per * i + 2][...],
+                v_ref[...].astype(jnp.float32) * kv[per * i + 3][...])
+
+    # a group with no live position is skipped whole; dead pages INSIDE a
+    # live group (past kv_len, or -1 holes) are fetched (_fetch_table says
+    # what for them) and masked out of the softmax
+    @pl.when(j * t < kv_len)
+    def _group():
+        # (heads, Q*g, Dh), row t*g + i = token t, group member i: the
+        # WRAPPER lays q/out/stats out that way, because the v5e Mosaic
+        # refuses the (Q, g, Dh) <-> (Q*g, Dh) shape casts in-kernel
         q = q_ref[...]
-        k, v = load_kv()
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        ks, vs = zip(*(load(i) for i in range(pages)))
+        k = ks[0] if pages == 1 else jnp.concatenate(ks, axis=1)
+        v = vs[0] if pages == 1 else jnp.concatenate(vs, axis=1)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (qw * g, bs), 1)
+        # the key position of each of the group's slots. A NEGATIVE table
+        # entry is a dead hole (sequence-parallel serving stamps -1 on the
+        # pages another shard owns): its keys go past every query's limit
+        slot = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        entry = tables_ref[b, j * pages]
+        for i in range(1, pages):
+            entry = jnp.where(slot >= i * bs, tables_ref[b, j * pages + i],
+                              entry)
+        kpos = jnp.where(entry < 0, 2 ** 30, j * t + slot)
+        # query token t sits at absolute position start + t with
+        # start = kv_len - q_live: causal over its own chunk AND over every
+        # previously written position (q_live = 1 degenerates to the decode
+        # mask kpos < kv_len); rows past q_live see no key at all
         trow = jax.lax.broadcasted_iota(jnp.int32, (qw * g, 1), 0)
         if g > 1:
             trow = jax.lax.div(trow, jnp.int32(g))
-        # query token t sits at absolute position start + t with
-        # start = kv_len - q_live: causal over its own chunk AND over every
-        # previously written position; rows past q_live are fully masked
-        # (q_live = 1 degenerates to the decode mask kpos < kv_len)
-        mask = (kpos <= kv_len - q_live + trow) & (trow < q_live)
+        limit = jnp.where(trow < q_live, kv_len - q_live + trow, -1)
+        mask = (kpos <= limit)[None]                    # (1, Q*g, t)
         s = jnp.where(mask, s, _NEG_INF)
-        m_prev, l_prev = m_scr[:], l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        m_scr[...] = m_new
 
     @pl.when(j == nj - 1)
     def _final():
-        l = l_scr[:]
+        l = l_scr[...]  # noqa: E741
         lsafe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> exactly 0
-        o_ref[...] = (acc_scr[:] / lsafe).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / lsafe).astype(o_ref.dtype)
         if stats:
-            m_ref[...] = m_scr[:]
+            m_ref[...] = m_scr[...]
             l_ref[...] = l
 
 
+def _fetch_table(tables, lens, bs, pages):
+    """The table the kernel walks, ``pages`` entries a grid step: padded to
+    whole groups, and every dead entry replaced by the one its DMA should
+    repeat. Dead trailing groups repeat the row's last live group and dead
+    pages inside it the row's last live page: a block index that repeats lets
+    the pipeline elide the DMA. ``max(len, 1)`` keeps fully-dead rows at
+    entry 0. Live entries, ``-1`` holes among them, stay as they are.
+    (Computed here, once a step program, and not by every page slot's index
+    map at every grid step: the layers' equal computations merge into one.)"""
+    nb = tables.shape[1]
+    width = -(-nb // pages) * pages
+    last = jnp.clip(jax.lax.div(lens + (bs - 1), bs), 1, nb)[:, None] - 1
+    e = np.arange(width, dtype=np.int32)[None, :]
+    e = jnp.minimum(e // pages, jax.lax.div(last, pages)) * pages + e % pages
+    return jnp.take_along_axis(tables, jnp.minimum(e, last), axis=1)
+
+
+# An INLINED jit: a step program calls this once a layer with the same shapes
+# (the layer is an operand), so the launch (a dozen and a half index maps and
+# the body) is traced once a program and not once a layer, and the equal
+# ``pallas_call`` equations are lowered once; every call keeps its own scope.
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("scale", "interpret", "stats"))
 def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
-                            q_lens, layer, scale, interpret, stats=False):
+                            q_lens, layer, *, scale, interpret, stats):
     quant = isinstance(pages_k, QuantPages)
     b, qw, h, dh = q.shape
-    _, _, hkv, bs, _ = (pages_k.data if quant else pages_k).shape
+    data = pages_k.data if quant else pages_k
+    _, _, hkv, bs, _ = data.shape
     g = h // hkv
-    nb = block_tables.shape[1]
-    # head-major query rows: (B, H_kv, Q*g, Dh), so one grid step's block is
-    # already the 2-D (Q*g, Dh) tile the body works on (no in-kernel reshape)
+    pages, heads = fetch_group(bs=bs, dh=dh, hkv=hkv, qg=qw * g,
+                               page_dtype=data.dtype,
+                               nb=block_tables.shape[1])
+    # head-major query rows: (B, H_kv, Q*g, Dh), so a grid step's block is
+    # (heads, Q*g, Dh), one 2-D tile a head (no in-kernel reshape)
     qg = _to_head_major(q, hkv)
-    tables = block_tables.astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
     qlens = q_lens.astype(jnp.int32)
-    layer_arr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    layer_arr = jnp.reshape(layer, (1,))
+    tables = _fetch_table(block_tables.astype(jnp.int32), lens, bs, pages)
+    nb = tables.shape[1]
 
-    def kv_index(bi, hi, j, tbl, ln, qln, ly):
-        # clamp dead trailing pages to the row's last live page: the repeated
-        # block index lets the pipeline elide the DMA (compute is pl.when-
-        # skipped); max(len, 1) keeps fully-dead rows fetching page 0, and
-        # the outer max clamps -1 holes (pages another SP shard owns — their
-        # compute is pl.when-skipped on the table-entry sign) to page 0 too
-        nlive = (jnp.maximum(ln[bi], 1) + bs - 1) // bs
-        return (ly[0], jnp.maximum(tbl[bi, jnp.minimum(j, nlive - 1)], 0),
-                hi, 0, 0)
+    def kv_index(i):
+        def index(bi, hi, j, tbl, ln, qln, ly):
+            # -1 holes (the body masks them on the entry's sign) fetch page 0
+            return (ly[0], jnp.maximum(tbl[bi, j * pages + i], 0), hi, 0, 0)
+        return index
 
     def q_index(bi, hi, j, tbl, ln, qln, ly):
         return (bi, hi, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((None, None, qw * g, dh), q_index),
-        pl.BlockSpec((None, None, None, bs, dh), kv_index),
-        pl.BlockSpec((None, None, None, bs, dh), kv_index),
-    ]
+    in_specs = [pl.BlockSpec((None, heads, qw * g, dh), q_index)]
     operands = [qg]
-    if quant:
-        # the scale sidecars chase the SAME block-table index maps as their
-        # pages, so a clamped dead-page fetch elides both DMAs together
-        in_specs += [pl.BlockSpec((None, None, None, bs, 1), kv_index),
-                     pl.BlockSpec((None, None, None, bs, 1), kv_index)]
-        operands += [pages_k.data, pages_v.data, pages_k.scale,
-                     pages_v.scale]
-        kernel = _attn_kernel_int8
-    else:
-        operands += [pages_k, pages_v]
-        kernel = _attn_kernel
+    for i in range(pages):
+        # the pool once per page slot, each with its own walk of the table
+        spec = pl.BlockSpec((None, None, heads, bs, dh), kv_index(i))
+        in_specs += [spec, spec]
+        if quant:
+            # the scale sidecars chase the SAME index maps as their pages,
+            # so a clamped dead-page fetch elides both DMAs together
+            sspec = pl.BlockSpec((None, None, heads, bs, 1), kv_index(i))
+            in_specs += [sspec, sspec]
+            operands += [pages_k.data, pages_v.data, pages_k.scale,
+                         pages_v.scale]
+        else:
+            operands += [pages_k, pages_v]
 
-    out_specs = pl.BlockSpec((None, None, qw * g, dh), q_index)
+    out_specs = pl.BlockSpec((None, heads, qw * g, dh), q_index)
     out_shape = jax.ShapeDtypeStruct((b, hkv, qw * g, dh), q.dtype)
     if stats:
         # per-row online-softmax state rides along as two extra outputs —
         # the sequence-parallel merge's inputs (ops.softmax_merge)
-        stat_spec = pl.BlockSpec((None, None, qw * g, 1), q_index)
+        stat_spec = pl.BlockSpec((None, heads, qw * g, 1), q_index)
         stat_shape = jax.ShapeDtypeStruct((b, hkv, qw * g, 1), jnp.float32)
         out_specs = (out_specs, stat_spec, stat_spec)
         out_shape = (out_shape, stat_shape, stat_shape)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hkv, nb),
+        grid=(b, hkv // heads, nb // pages),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((qw * g, 1), jnp.float32),
-            pltpu.VMEM((qw * g, 1), jnp.float32),
-            pltpu.VMEM((qw * g, dh), jnp.float32),
+            pltpu.VMEM((heads, qw * g, 1), jnp.float32),
+            pltpu.VMEM((heads, qw * g, 1), jnp.float32),
+            pltpu.VMEM((heads, qw * g, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, bs=bs, g=g, qw=qw,
-                          stats=stats),
+        functools.partial(_attn_kernel, scale=scale, bs=bs, g=g, qw=qw,
+                          pages=pages, quant=quant, stats=stats),
         # the name the device profile shows; the variants are other kernels
         name="tnn_paged_attention" + ("_int8" if quant else "")
         + ("_stats" if stats else ""),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        # scratch carries only along the innermost (page) sweep
+        # scratch carries only along the innermost (group) sweep
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -534,8 +624,9 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
     if interpret is None:
         interpret = interpret_default()
     out = _paged_attention_pallas(q, pages_k, pages_v, block_tables,
-                                  kv_lens, q_lens, layer, scale, interpret,
-                                  stats=return_stats)
+                                  kv_lens, q_lens,
+                                  jnp.asarray(layer, jnp.int32), scale=scale,
+                                  interpret=interpret, stats=return_stats)
     if return_stats:
         o, m, l = out  # noqa: E741
         return (o[:, 0], m[:, 0], l[:, 0]) if was_3d else (o, m, l)

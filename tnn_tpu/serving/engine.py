@@ -568,6 +568,7 @@ class InferenceEngine:
         # stays there instead of hopping over from the default device
         self._key = jax.device_put(jax.random.PRNGKey(seed), device)
         self._jit: Dict[Any, Any] = {}
+        self._attn_groups: Dict[int, Any] = {}   # step width -> _attn_group
         # TNN_DEBUG_SYNC=1: run every step under jax.transfer_guard
         # ("disallow") — the dynamic complement to tnnlint's static
         # host-sync-in-step-path rule. All intentional step inputs go
@@ -894,11 +895,40 @@ class InferenceEngine:
                                 rid=req.rid, step=self.step_seq,
                                 at=req.cache_len)
 
-    def _observe_eva(self, rows, ends) -> None:
-        """A windowed step's counters: the share of the window's exact
-        positions each row attends over (row i's tokens end just before
-        position ``ends[i]``), and the pool's rows that hold summaries."""
+    def _attn_group(self, qw: int):
+        """``(pages, heads)`` of a grid step of the paged kernel in the step
+        program ``qw`` tokens wide: what the kernel's own launch derives
+        from the same shapes (a TP shard's pool holds ``1 / tp`` of the
+        heads). None where that kernel is not the step's attention (the
+        gather paths, a windowed model)."""
+        if not self._paged or self.pool.window:
+            return None
+        group = self._attn_groups.get(qw)
+        if group is None:
+            from ..ops.pallas.paged_attention import fetch_group
+            pool, model = self.pool, self.model
+            group = self._attn_groups[qw] = fetch_group(
+                bs=pool.block_size, dh=pool.head_dim,
+                hkv=pool.num_kv_heads // self.tp,
+                qg=qw * (model.num_heads // model.num_kv_heads),
+                page_dtype=(jnp.int8 if pool.kv_dtype == "int8"
+                            else pool.dtype),
+                nb=self.blocks_per_seq)
+        return group
+
+    def _observe_attention(self, rows, ends, qw: int) -> None:
+        """The attention counters of one paged step ``qw`` tokens wide whose
+        row i's tokens end just before position ``ends[i]``. Plain paged
+        attention: the fill of the page slots the kernel fetches in groups.
+        A windowed model: the share of the window's exact positions each row
+        attends over, and the pool's rows that hold summaries."""
         pool = self.pool
+        group = self._attn_group(qw)
+        if group is not None:
+            pages, _ = group
+            live = -(-np.asarray(ends[:len(rows)]) // pool.block_size)
+            live = live[live > 0]
+            self.metrics.observe_attn_fetch(live / (-(-live // pages) * pages))
         if not pool.window:
             return
         self.metrics.observe_eva_step(
@@ -1076,14 +1106,20 @@ class InferenceEngine:
             getattr(self.metrics, observe)(time.perf_counter() - t0)
             sp.__exit__(None, None, None)
 
-    def _dispatch_span(self, kind: str, key, step: Optional[int] = None):
+    def _dispatch_span(self, kind: str, key, step: Optional[int] = None,
+                       qw: Optional[int] = None):
         """``serve.dispatch``: the ENQUEUE of one compiled program (its
         inputs' ``device_put`` and the asynchronous launch), not its
-        compute — that is the device's, and shows as ``serve.fetch``."""
+        compute — that is the device's, and shows as ``serve.fetch``. A
+        paged step program ``qw`` tokens wide also says what a grid step of
+        its attention kernel fetches (``attn_pages``, ``attn_heads``)."""
+        group = None if qw is None else self._attn_group(qw)
+        attrs = {} if group is None else dict(attn_pages=group[0],
+                                              attn_heads=group[1])
         return self.tracer.span(
             "serve.dispatch", EventType.COMPUTE,
             step=self.step_seq if step is None else step, kind=kind,
-            key=key)
+            key=key, **attrs)
 
     @property
     def in_flight(self) -> Optional["StepInFlight"]:
@@ -1410,7 +1446,8 @@ class InferenceEngine:
         prev_tok = rec["dev"][0]     # step N's unfetched sampled tokens
         try:
             with self._sync_guard(), \
-                    self._dispatch_span(label, key, self.step_seq + 1):
+                    self._dispatch_span(label, key, self.step_seq + 1,
+                                        qw=1):
                 newtok, ok, pk, pv = fn(
                     self.params, self.pool.pages_k, self.pool.pages_v,
                     prev_tok, self._put(offsets), self._put_tables(step.tables),
@@ -1422,7 +1459,7 @@ class InferenceEngine:
             self._recover_pages_if_dead(flight.events)
             return False
         self.pool.update_pages(pk, pv)
-        self._observe_eva(live, offsets + 1)
+        self._observe_attention(live, offsets + 1, 1)
         flight.spec = {
             "rec": {"kind": "decode", "dev": (newtok, ok),
                     "live": list(live), "t0": t0, "b": b},
@@ -2332,7 +2369,7 @@ class InferenceEngine:
                 if self.faults is not None:
                     self.faults.on_decode()
                 with self._dispatch_span("spec" if spec_on else "mixed",
-                                         key):
+                                         key, qw=qw):
                     if spec_on:
                         accepts, newtok, ok, pk, pv = fn(
                             self.params, self.pool.pages_k, self.pool.pages_v,
@@ -2361,7 +2398,7 @@ class InferenceEngine:
                 self._abort_batch(rows, f"decode step failed: {e}", events)
                 return
         self.pool.update_pages(pk, pv)
-        self._observe_eva(rows, step.starts + step.q_lens)
+        self._observe_attention(rows, step.starts + step.q_lens, qw)
         flight.recs.append({
             "kind": "spec" if spec_on else "mixed",
             "dev": ((accepts, newtok, ok, toks_in) if spec_on
@@ -2840,7 +2877,7 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with self._dispatch_span(label, key):
+                with self._dispatch_span(label, key, qw=1):
                     if lockstep:
                         newtok, ok, pk, pv = fn(
                             self.params, self._fused["stacks"],
@@ -2871,7 +2908,7 @@ class InferenceEngine:
                 self._abort_batch(live, f"decode step failed: {e}", events)
                 return None
         self.pool.update_pages(pk, pv)
-        self._observe_eva(live, step.offsets + 1)
+        self._observe_attention(live, step.offsets + 1, 1)
         return {"kind": "decode", "dev": (newtok, ok), "live": list(live),
                 "t0": t0, "b": b}
 

@@ -482,6 +482,8 @@ class ServingMetrics:
         self.eva_row_steps = 0
         self.eva_summary_rows_max = 0.0
         self.eva_windows_rolled = 0
+        self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
+        self.attn_fetch_row_steps = 0
         # runtime-resilience counters (supervisor / overload degradation)
         self.shed = 0                 # queued requests displaced by priority
         self.engine_restarts = 0      # supervisor-driven engine recoveries
@@ -621,6 +623,13 @@ class ServingMetrics:
         self.eva_row_steps += len(window_fills)
         self.eva_summary_rows_max = max(self.eva_summary_rows_max,
                                         summary_share)
+
+    def observe_attn_fetch(self, fills) -> None:
+        """One paged step: ``fills`` each live row's live pages over the
+        page slots of the groups the paged kernel fetches for them
+        (``paged_attention.fetch_group``)."""
+        self.attn_fetch_fill_sum += float(sum(fills))
+        self.attn_fetch_row_steps += len(fills)
 
     def observe_eva_roll(self) -> None:
         """A request's window ended: its exact pages went back to the pool."""
@@ -986,6 +995,10 @@ class ServingMetrics:
             "tier_bytes": getattr(self, "_last_tier_bytes", 0.0),
             "replicas": getattr(self, "_last_replicas", 0.0),
         }
+        if self.attn_fetch_row_steps:
+            # only the paged decode path fetches pages in groups
+            out["attn_fetch_fill_mean"] = (self.attn_fetch_fill_sum
+                                           / self.attn_fetch_row_steps)
         if self.eva_row_steps:
             # only a windowed model has these: a reader of a model with
             # every position exact finds nothing, not a zero
