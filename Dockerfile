@@ -3,7 +3,7 @@
 #
 #   docker build -t tnn-tpu .
 #   docker run --rm tnn-tpu python -m pytest tests/ -x -q          # CPU suite
-#   docker run --rm --privileged tnn-tpu python bench.py           # on a TPU VM
+#   docker run --rm --privileged tnn-tpu python chip_smoke.py      # on a TPU VM
 #
 # On Cloud TPU VMs pass through /dev/accel* and install the libtpu wheel that
 # matches the runtime; on CPU the suite runs on a virtual 8-device mesh.

@@ -415,10 +415,6 @@ def check_engine(engine, *, want_platform, want_devices=1, interpret) -> list:
     from tnn_tpu.ops.pallas.runtime import interpret_default
 
     failures = []
-    path = engine.stats()["decode_path"]
-    if path != "paged" or engine.paged_fallback_reason is not None:
-        failures.append(f"decode path {path!r} (fallback reason: "
-                        f"{engine.paged_fallback_reason}), want 'paged'")
     if interpret_default() != interpret:
         failures.append(f"interpret_default() is {interpret_default()}, "
                         f"want {interpret}")

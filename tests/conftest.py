@@ -92,6 +92,34 @@ def sp():
     return 2
 
 
+# -- the serving suites' second model family ----------------------------------
+
+
+@pytest.fixture(scope="session")
+def llama_lm():
+    """The block ``evabyte-pp2.decode-docs`` runs, without its window:
+    rotary positions, RMSNorm, gated feed-forward, grouped heads (4 query
+    heads over 2 KV heads), float32. Same vocabulary and length as the
+    suites' ``tiny_lm`` (a 2-layer GPT-2), so prompts and drafters carry
+    over."""
+    from tnn_tpu.core.dtypes import DTypePolicy
+    from tnn_tpu.models.llama import Llama
+
+    model = Llama(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+                  vocab_size=128, max_len=64, policy=DTypePolicy(
+                      io="float32", param="float32", compute="float32"))
+    params = model.init(jax.random.PRNGKey(1), (1, 8))["params"]
+    return model, params
+
+
+@pytest.fixture
+def lm(request, family):
+    """The model of a test's ``family`` case ("gpt2" | "llama"): the test
+    module's own ``tiny_lm``, or ``llama_lm``."""
+    return request.getfixturevalue(
+        {"gpt2": "tiny_lm", "llama": "llama_lm"}[family])
+
+
 # -- test tiers ---------------------------------------------------------------
 # Measured-slow tests (>15s on a 1-CPU host, mostly multi-minute mesh/pipeline
 # XLA compiles) are auto-marked so `pytest -m "not slow"` is a fast dev tier;
@@ -116,7 +144,6 @@ _SLOW_TESTS = {
     "test_mid_epoch_resume_continues_cursor",
     "test_tp_sharding_rules", "test_train_step_fused_head_matches_standard",
     "test_sort_dispatch_matches_einsum",
-    "test_fused_generate_matches_logits_teacher_forced",
     "test_resnet18_trains_one_step", "test_mesh_axes_dp_matches_single_device",
     "test_topk_routing_and_capacity",
     "test_worker_death_detected_and_rank_rejoins",
@@ -124,22 +151,20 @@ _SLOW_TESTS = {
     "test_loss_decreases_and_checkpoints",
     "test_nested_blocks_config_roundtrip", "test_wrn16_8_param_count",
     "test_gpt2_param_count_small",
-    "test_serve_bench_smoke", "test_serve_bench_chaos",
     "test_tp_llama_matches_single_device",
     # TP-serving composition/failure tests: each builds several tp=2
     # shard_map engines (multi-second compiles on the 1-CPU host); the
-    # cheap TP gates — tp=2 vs tp=1 parity on both decode paths,
-    # validation, observability, the serve_bench --tp capacity gate —
+    # cheap TP gates — tp=2 vs tp=1 parity, validation, observability —
     # stay tier-1, these deeper compositions ride the full CI tier to
     # keep tier-1 inside its 870 s budget
     "test_full_composition_exact", "test_preemption_parity",
     "test_sampled_rows_deterministic", "test_debug_sync_clean",
     "test_supervisor_crash_restart_exact", "test_chaos_gate_per_shard",
     # disaggregation: the composed-chaos PR gate runs two full 3-replica
-    # fleets per decode path (~25 s each); the per-mechanism handoff
+    # fleets per model family (~25 s each); the per-mechanism handoff
     # tests (boundary exactness, corrupt/slow/pressure degradation,
     # receiver death, fleet pulls) stay tier-1
-    "test_disagg_composed_chaos_token_exact", "test_serve_bench_disagg",
+    "test_disagg_composed_chaos_token_exact",
 }
 
 

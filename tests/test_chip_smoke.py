@@ -121,7 +121,7 @@ def test_importing_the_package_touches_no_device():
     """A process that imports the serving stack must not take the chip: the
     one that runs it may be a child."""
     code = ("import tnn_tpu, tnn_tpu.serving, tnn_tpu.cli.serve, "
-            "tnn_tpu.cli.trainer, benchmarks.serve_bench, chip_smoke\n"
+            "tnn_tpu.cli.trainer, chip_smoke\n"
             "from jax._src import xla_bridge\n"
             "assert not xla_bridge._backends, xla_bridge._backends\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=REPO,

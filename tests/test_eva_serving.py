@@ -481,9 +481,7 @@ def test_scheduler_ends_a_grant_at_a_windows_end():
     (dict(tp=2), "tensor parallelism"),
     (dict(sp=2), "sequence parallelism"),
     (dict(prefix_cache=True, host_tier_bytes=1 << 20), "prefix sharing"),
-    (dict(kv_dtype="int8"), "int8 pages"),
-    (dict(chunked_prefill=False), "whole-prompt prefill"),
-    (dict(decode_path="standard"), "decode_path='standard'")])
+    (dict(kv_dtype="int8"), "int8 pages")])
 def test_the_engine_refuses_what_assumes_kv_blocks(model, weights, kw, what):
     with pytest.raises(ValueError, match="exact window of 32") as e:
         engine(model, weights[1], **kw)

@@ -231,7 +231,7 @@ def test_every_step_writes_a_page_from_one_row(tiny_lm, mode):
     kw = dict(spec="ngram", spec_k=3) if mode == "spec" else {}
     eng = InferenceEngine(model, params, num_blocks=40, block_size=4,
                           max_batch_size=4, max_seq_len=32, prefix_cache=True,
-                          decode_path="paged", overlap=mode != "sync", **kw)
+                          overlap=mode != "sync", **kw)
     eng.pool.debug = True       # what TNN_POOL_DEBUG=1 sets
     steps, shared, refused = [], [], []
     inner = eng.pool.check_step_writes

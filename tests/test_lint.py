@@ -224,7 +224,7 @@ class InferenceEngine:
         assert _rules('''
 class InferenceEngine:
     def step(self):
-        out = self._decode_fn(self.params)
+        out = self._paged_decode_fn(self.params)
         if out:
             return 1
 ''', "host-sync-in-step-path") == ["host-sync-in-step-path"]
@@ -245,7 +245,7 @@ class InferenceEngine:
         assert _rules('''
 class Offline:
     def generate(self):
-        tok = self._decode_fn(self.params)
+        tok = self._paged_decode_fn(self.params)
         return int(tok)
 ''', "host-sync-in-step-path") == []
 

@@ -54,17 +54,21 @@ def tiny_lm():
     return model, params
 
 
-def _spec_run(model, params, *, trace, decode_path="auto"):
+def _spec_prompts():
+    rng = np.random.default_rng(11)
+    prefix = rng.integers(0, 128, 12).astype(np.int32)
+    return [np.concatenate([prefix, rng.integers(0, 128, n).astype(
+        np.int32)]) for n in (3, 5, 2, 4)]
+
+
+def _spec_run(model, params, *, trace):
     """Spec-decode + prefix-cache workload: shared 12-token prefix so the
     second wave forks cached KV, ngram drafting so the mixed step runs the
     verify path — the two features whose step shapes tracing must not
     perturb."""
     eng = InferenceEngine(model, params, spec="ngram", spec_k=3,
-                          decode_path=decode_path, trace=trace, **KW)
-    rng = np.random.default_rng(11)
-    prefix = rng.integers(0, 128, 12).astype(np.int32)
-    prompts = [np.concatenate([prefix, rng.integers(0, 128, n).astype(
-        np.int32)]) for n in (3, 5, 2, 4)]
+                          trace=trace, **KW)
+    prompts = _spec_prompts()
     rids = [eng.submit(p, 8) for p in prompts[:2]]
     eng.run_until_complete()                  # publishes the prefix
     rids += [eng.submit(p, 8) for p in prompts[2:]]
@@ -111,21 +115,14 @@ class TestTracer:
         assert inst[0].duration == 0.0
 
 
-@pytest.fixture(scope="module")
-def spec_ref(tiny_lm):
-    """Untraced reference outputs per decode path, computed once — every
-    traced run in this module diffs against these (an engine build + spec
-    workload is the expensive part of this file; don't repeat it)."""
-    cache = {}
+def _spec_ref(model, params):
+    """What the spec workload must emit, traced or not: the offline greedy
+    reference (``models.gpt2.generate``) of each prompt."""
+    from tnn_tpu.models.gpt2 import generate
 
-    def get(path):
-        if path not in cache:
-            model, params = tiny_lm
-            cache[path] = _spec_run(model, params, trace=False,
-                                    decode_path=path)[0]
-        return cache[path]
-
-    return get
+    return [np.asarray(generate(model, params, p[None], 8,
+                                max_len=KW["max_seq_len"]))[0].tolist()
+            for p in _spec_prompts()]
 
 
 @pytest.fixture(scope="module")
@@ -151,29 +148,25 @@ def flight_run(tiny_lm, tmp_path_factory):
 
 
 class TestTracedTokenExact:
-    # the standard path rides slow: paged is the default/production path
-    # and the tier-1 budget is tight; `-m slow` covers the matrix
-    @pytest.mark.parametrize("path", [
-        pytest.param("standard", marks=pytest.mark.slow), "paged"])
-    def test_traced_equals_untraced(self, tiny_lm, spec_ref, path):
-        model, params = tiny_lm
-        ref = spec_ref(path)
-        got, eng = _spec_run(model, params, trace=True, decode_path=path)
-        assert got == ref, f"tracing changed tokens on {path} decode"
+    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    def test_traced_equals_untraced(self, lm, family):
+        model, params = lm
+        got, eng = _spec_run(model, params, trace=True)
+        assert got == _spec_ref(model, params), \
+            f"tracing changed tokens of the {family} model"
         # and the trace is real: request-scoped events with trace ids
         names = [ev.name for ev in eng.profiler.events]
         assert any(n.startswith("serve.submit") for n in names)
         assert any(n.startswith("serve.finish") for n in names)
         assert any("trace=t0" in n for n in names)
 
-    def test_traced_clean_under_debug_sync(self, tiny_lm, spec_ref,
-                                           monkeypatch):
+    def test_traced_clean_under_debug_sync(self, tiny_lm, monkeypatch):
         """Tracing instants/spans are host-side bookkeeping: a traced step
         under jax.transfer_guard('disallow') neither syncs nor diverges."""
         model, params = tiny_lm
-        ref = spec_ref("paged")
+        ref = _spec_ref(model, params)
         monkeypatch.setenv("TNN_DEBUG_SYNC", "1")
-        got, eng = _spec_run(model, params, trace=True, decode_path="paged")
+        got, eng = _spec_run(model, params, trace=True)
         assert eng.debug_sync
         assert got == ref
 
@@ -574,7 +567,7 @@ class TestStableNames:
     def test_serving_steps_carry_the_catalog(self, tiny_lm, as_on_the_chip):
         jnp = jax.numpy
         model, params = tiny_lm
-        eng = InferenceEngine(model, params, decode_path="paged", **KW)
+        eng = InferenceEngine(model, params, **KW)
         b, nb = KW["max_batch_size"], eng.blocks_per_seq
         row = dict(t=jnp.zeros(b), k=jnp.zeros(b, jnp.int32), p=jnp.zeros(b))
         tables = jnp.zeros((b, nb), jnp.int32)

@@ -1,5 +1,5 @@
 """Overlapped engine loop (PR: async overlap): the double-buffered loop is
-token-exact against the synchronous loop on every decode path, survives
+token-exact against the synchronous loop for both model families, survives
 staggered arrivals / preemption / mid-run crashes, never publishes prefix
 blocks for a terminated request, and stays clean under TNN_DEBUG_SYNC=1.
 
@@ -57,32 +57,33 @@ def _run(model, params, overlap, prompts=None, max_new=8, **kw):
 
 
 class TestOverlapTokenExact:
-    @pytest.mark.parametrize("path,spec", [
-        ("paged", "off"),
-        ("paged", "ngram"),
-        ("standard", "off"),
-        pytest.param("standard", "ngram", marks=pytest.mark.slow),
-        pytest.param("standard", "draft", marks=pytest.mark.slow),
-        pytest.param("paged", "draft", marks=pytest.mark.slow),
+    @pytest.mark.parametrize("family,spec", [
+        ("gpt2", "off"),
+        ("gpt2", "ngram"),
+        ("llama", "off"),
+        pytest.param("llama", "ngram", marks=pytest.mark.slow),
+        pytest.param("llama", "draft", marks=pytest.mark.slow),
+        pytest.param("gpt2", "draft", marks=pytest.mark.slow),
     ])
-    def test_matrix(self, tiny_lm, draft_lm, path, spec):
-        model, params = tiny_lm
-        kw = dict(decode_path=path, prefix_cache=True)
+    def test_matrix(self, lm, draft_lm, family, spec):
+        model, params = lm
+        kw = dict(prefix_cache=True)
         if spec != "off":
             kw["spec"] = spec
         if spec == "draft":
             kw["draft_model"], kw["draft_params"] = draft_lm
         off, _ = _run(model, params, overlap=False, **kw)
         on, eng = _run(model, params, overlap=True, **kw)
-        assert on == off, f"overlap changed tokens on {path}/{spec}"
+        assert on == off, f"overlap changed tokens on {family}/{spec}"
         # the loop actually overlapped: the fetch->dispatch gap was measured
         assert len(eng.metrics.host_gap_s) > 0
         assert eng.in_flight is None and not eng._deferred
 
-    def test_staggered_preempted_exact(self, tiny_lm):
+    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    def test_staggered_preempted_exact(self, lm, family):
         """Arrivals landing WHILE a step is in flight, on a pool small
         enough to preempt, still commit the synchronous loop's tokens."""
-        model, params = tiny_lm
+        model, params = lm
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
@@ -246,10 +247,8 @@ class TestDebugSyncOverlap:
         build, speculative dispatch, and the single bundle fetch are all
         explicit, so the guarded run neither raises nor diverges."""
         model, params = tiny_lm
-        ref, _ = _run(model, params, overlap=True, spec="ngram",
-                      decode_path="paged")
+        ref, _ = _run(model, params, overlap=True, spec="ngram")
         monkeypatch.setenv("TNN_DEBUG_SYNC", "1")
-        got, eng = _run(model, params, overlap=True, spec="ngram",
-                        decode_path="paged")
+        got, eng = _run(model, params, overlap=True, spec="ngram")
         assert eng.debug_sync
         assert got == ref

@@ -705,8 +705,7 @@ def test_attn_fetch_fill_mean_is_a_hand_count():
                  num_heads=2)
     params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
     eng = InferenceEngine(model, params, num_blocks=40, block_size=16,
-                          max_batch_size=4, max_seq_len=512,
-                          decode_path="paged")
+                          max_batch_size=4, max_seq_len=512)
     assert "attn_fetch_fill_mean" not in eng.metrics.summary()
     assert eng._attn_group(1) == pa.fetch_group(
         bs=16, dh=16, hkv=2, qg=1, page_dtype=eng.pool.dtype, nb=32) == (8, 2)

@@ -2,10 +2,12 @@
 end-to-end continuous batching with token-for-token parity against
 models.gpt2.generate (the offline single-sequence reference path).
 
-Parity methodology: the engine assembles per-request caches at the pool's
-fixed width (blocks_per_seq * block_size) and generate() is run with
-``max_len`` equal to that width, so both paths softmax over identically
-shaped (masked) caches — greedy outputs must then match exactly.
+Parity methodology: the engine attends the pool's pages through block
+tables of a fixed width (blocks_per_seq * block_size positions) and the
+reference, which assembles a contiguous cache, is run with ``max_len`` equal
+to that width; greedy outputs must match exactly. Feature suites run on two
+model families (``FAMILIES``): the GPT-2 block and the rotary / RMSNorm /
+gated / grouped-head block the EvaByte cell runs.
 """
 import time
 
@@ -20,8 +22,8 @@ from tnn_tpu.serving import (TERMINAL_STATES, AdmissionRejected, Autoscaler,
                              EngineSupervisor, FaultPlan, HostKVTier,
                              InferenceEngine, PagedKVPool, PoolExhausted,
                              PrefixCache, Request, RequestState, Router,
-                             Scheduler, ShuttingDown, SupervisorState,
-                             gather_kv, scatter_prefill, scatter_token)
+                             Scheduler, ShuttingDown, SupervisorState)
+from tnn_tpu.ops.pallas.paged_attention import scatter_kv_chunk
 
 
 # -- pool bookkeeping ---------------------------------------------------------
@@ -74,9 +76,11 @@ class TestPagedKVPool:
         assert pool.blocks_for(4) == 1
         assert pool.blocks_for(5) == 2
 
-    def test_gather_after_fragmentation(self):
+    def test_write_after_fragmentation(self):
         """Logical order must follow the block TABLE, not block-id order —
-        tables acquired after frees interleave arbitrarily in the pool."""
+        tables acquired after frees interleave arbitrarily in the pool. The
+        system's own write (``scatter_kv_chunk``) lands position p in page
+        ``table[p // bs]``, slot ``p % bs``."""
         pool = self._pool(num_layers=1, num_kv_heads=1, head_dim=2,
                           num_blocks=8, block_size=2)
         a = pool.alloc(2)
@@ -85,28 +89,36 @@ class TestPagedKVPool:
         c = pool.alloc(3)  # reuses a's blocks (LIFO) + one fresh: fragmented
         assert set(a) & set(c), "expected block reuse to fragment the table"
         seq = jnp.broadcast_to(
-            jnp.arange(6, dtype=jnp.float32)[None, None, :, None],
-            (1, 1, 6, 2))
-        pool.update_pages(
-            scatter_prefill(pool.pages_k, jnp.asarray(c), seq),
-            scatter_prefill(pool.pages_v, jnp.asarray(c), -seq))
+            jnp.arange(6, dtype=jnp.float32)[None, :, None, None],
+            (1, 6, 1, 2))                                   # (B, Q, H, Dh)
         table = jnp.asarray([pool.padded_table(c, 4)])
-        kf, vf = gather_kv(pool.pages_k, pool.pages_v, table)
-        got = np.asarray(kf)[0, 0, 0, :6, 0]
+        zero, six = jnp.asarray([0]), jnp.asarray([6])
+        pool.update_pages(
+            scatter_kv_chunk(pool.pages_k, table, zero, seq, six, layer=0),
+            scatter_kv_chunk(pool.pages_v, table, zero, -seq, six, layer=0))
+        pk = np.asarray(pool.pages_k)[0, :, 0, :, 0]        # (N, bs)
+        got = np.concatenate([pk[blk] for blk in c])
         np.testing.assert_array_equal(got, np.arange(6, dtype=np.float32))
-        np.testing.assert_array_equal(np.asarray(vf)[0, 0, 0, :6, 0], -got)
-        del b
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(pool.pages_v)[0, blk, 0, :, 0]
+                            for blk in c]), -got)
+        # nobody else's page was touched
+        for blk in b:
+            assert not pk[blk].any()
 
-    def test_scatter_token_lands_in_right_slot(self):
+    def test_write_lands_in_right_slot(self):
         pool = self._pool(num_layers=1, num_kv_heads=1, head_dim=2,
                           num_blocks=8, block_size=4)
         blocks = pool.alloc(2)
         tables = jnp.asarray([pool.padded_table(blocks, 2)])
         # position 5 = second block, slot 1
-        rows = jnp.full((1, 1, 1, 2), 7.0)
-        pages = scatter_token(pool.pages_k, tables, jnp.asarray([5]), rows)
-        got = np.asarray(pages)[0, blocks[1], 0, 1]
-        np.testing.assert_array_equal(got, [7.0, 7.0])
+        rows = jnp.full((1, 1, 1, 2), 7.0)                  # (B, Q, H, Dh)
+        pages = scatter_kv_chunk(pool.pages_k, tables, jnp.asarray([5]),
+                                 rows, jnp.asarray([1]), layer=0)
+        got = np.asarray(pages)[0, blocks[1], 0]            # (bs, Dh)
+        np.testing.assert_array_equal(got[1], [7.0, 7.0])
+        assert not got[[0, 2, 3]].any()
+        assert not np.asarray(pages)[0, blocks[0]].any()
 
 
 # -- scheduler policy ---------------------------------------------------------
@@ -147,14 +159,20 @@ class TestScheduler:
     def test_token_budget_defers_prefill(self):
         sched = Scheduler(max_batch_size=4, token_budget=10)
         pool = self._pool()
-        sched.submit(_req(0, 8))
-        sched.submit(_req(1, 8))            # 16 > budget: second waits
+        for i in range(3):
+            sched.submit(_req(i, 8))
         plan = sched.schedule(pool)
-        assert [r.rid for r in plan.prefills] == [0]
-        # an over-budget prompt still runs when it is the ONLY work
-        sched2 = Scheduler(max_batch_size=4, token_budget=4)
+        # 8 of the budget go to the head, the 2 left to the next one's first
+        # chunk; the third finds no budget and waits
+        assert [r.rid for r in plan.prefills] == [0, 1]
+        assert plan.chunks == {0: 8, 1: 2}
+        # with no budget at all a request still starts when it is the ONLY
+        # work
+        sched2 = Scheduler(max_batch_size=4, token_budget=0)
         sched2.submit(_req(9, 8))
-        assert [r.rid for r in sched2.schedule(pool).prefills] == [9]
+        plan2 = sched2.schedule(pool)
+        assert [r.rid for r in plan2.prefills] == [9]
+        assert plan2.chunks == {9: 1}
 
     def test_requeue_goes_to_front(self):
         sched = Scheduler(max_batch_size=4, token_budget=100)
@@ -189,6 +207,11 @@ def tiny_lm():
     return model, params
 
 
+# the ``family`` axis of the feature suites; ``lm`` (conftest) resolves a case
+# to ``tiny_lm`` or ``llama_lm``
+FAMILIES = ["gpt2", "llama"]
+
+
 def _greedy_ref(model, params, prompt, max_new, max_len):
     from tnn_tpu.models.gpt2 import generate
 
@@ -197,8 +220,9 @@ def _greedy_ref(model, params, prompt, max_new, max_len):
 
 
 class TestEngineTiny:
-    def test_staggered_parity(self, tiny_lm):
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_staggered_parity(self, lm, family):
+        model, params = lm
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
@@ -212,10 +236,11 @@ class TestEngineTiny:
             assert out[rid] == _greedy_ref(model, params, p, 10,
                                            eng.assembly_len)
 
-    def test_preemption_recovers_exactly(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_preemption_recovers_exactly(self, lm, family):
         """A pool too small for all requests must preempt (recompute-requeue)
         and still produce byte-identical greedy outputs, ending drained."""
-        model, params = tiny_lm
+        model, params = lm
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
@@ -232,10 +257,11 @@ class TestEngineTiny:
         # drained: only free + prefix-cache-evictable blocks remain
         assert eng.pool.num_free + eng.pool.num_evictable == eng.pool.capacity
 
-    def test_mixed_sampling_params(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_mixed_sampling_params(self, lm, family):
         """Greedy and stochastic requests share one decode batch; stochastic
         rows stay in-vocab and the run terminates."""
-        model, params = tiny_lm
+        model, params = lm
         eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
                               max_batch_size=4, max_seq_len=32, seed=3)
         p = np.arange(6, dtype=np.int32)
@@ -246,8 +272,9 @@ class TestEngineTiny:
         assert len(out[s]) == 8
         assert all(0 <= t < model.vocab_size for t in out[s])
 
-    def test_stop_token_frees_early(self, tiny_lm):
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_stop_token_frees_early(self, lm, family):
+        model, params = lm
         eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
                               max_batch_size=2, max_seq_len=32)
         p = np.arange(5, dtype=np.int32)
@@ -259,109 +286,75 @@ class TestEngineTiny:
         assert eng.result(rid).finish_reason == "stop_token"
         assert eng.pool.num_allocated == 0
 
-    def test_paged_parity_staggered(self, tiny_lm):
-        """decode_path="paged" (no gather_kv, pages attended via block
-        tables) must match "standard" token-for-token AND the offline
-        reference, under staggered admission (ragged offsets)."""
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_paged_parity_staggered(self, lm, family):
+        """The step programs attend the pool's pages through block tables
+        and never assemble a cache: under staggered admission (ragged
+        offsets) every stream equals the offline reference, which does."""
+        model, params = lm
         rng = np.random.default_rng(5)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
+        eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
+                              max_batch_size=4, max_seq_len=32)
+        rids = [eng.submit(prompts[0], 10)]
+        eng.step(); eng.step()
+        rids += [eng.submit(p, 10) for p in prompts[1:]]
+        out = eng.run_until_complete()
+        assert eng.stats()["decode_path"] == "paged"
+        assert eng.paged_fallback_reason is None
+        assert {k[0] for k in eng._jit} == {"pdecode", "mixed"}
+        for rid, p in zip(rids, prompts):
+            assert out[rid] == _greedy_ref(model, params, p, 10,
+                                           eng.assembly_len)
 
-        def run(path):
-            eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
-                                  max_batch_size=4, max_seq_len=32,
-                                  decode_path=path)
-            rids = [eng.submit(prompts[0], 10)]
-            eng.step(); eng.step()
-            rids += [eng.submit(p, 10) for p in prompts[1:]]
-            out = eng.run_until_complete()
-            return eng, [out[r] for r in rids]
-
-        eng, paged = run("paged")
-        assert eng._paged and eng.paged_fallback_reason is None
-        assert eng.fused_fallback_reason == \
-            "unused (paged decode path selected)"
-        _, std = run("standard")
-        assert paged == std
-        for toks, p in zip(paged, prompts):
-            assert toks == _greedy_ref(model, params, p, 10,
-                                       eng.assembly_len)
-
-    def test_paged_preemption_parity(self, tiny_lm):
-        """Preemption-recovery (recompute-requeue) must be byte-identical
-        between the paged and standard decode paths."""
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_paged_preemption_parity(self, lm, family):
+        """Preemption-recovery (recompute-requeue) leaves every stream
+        byte-identical to the offline reference, with the prefix cache off:
+        every resumed request re-prefills its whole extended prompt."""
+        model, params = lm
         rng = np.random.default_rng(6)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
+        eng = InferenceEngine(model, params, num_blocks=9, block_size=4,
+                              max_batch_size=4, max_seq_len=32,
+                              prefix_cache=False)
+        for p in prompts:
+            eng.submit(p, 10)
+        out = eng.run_until_complete()
+        assert eng.metrics.preemptions > 0, "pool was never exhausted"
+        for rid, p in enumerate(prompts):
+            assert out[rid] == _greedy_ref(model, params, p, 10,
+                                           eng.assembly_len)
+        assert eng.pool.num_allocated == 0
+        assert eng.pool.num_free == eng.pool.capacity
 
-        def run(path):
-            eng = InferenceEngine(model, params, num_blocks=9, block_size=4,
-                                  max_batch_size=4, max_seq_len=32,
-                                  decode_path=path)
-            for p in prompts:
-                eng.submit(p, 10)
-            return eng, eng.run_until_complete()
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_paged_mixed_sampling(self, lm, family):
+        """Stochastic rows ride the paged step too: the same engine seed
+        gives the same stream twice, and every sampled token lies in the
+        top-k of the reference forward's distribution at its position."""
+        model, params = lm
+        p = np.arange(6, dtype=np.int32)
 
-        eng_p, out_p = run("paged")
-        eng_s, out_s = run("standard")
-        assert eng_p.metrics.preemptions > 0, "pool was never exhausted"
-        assert out_p == out_s
-        assert eng_p.pool.num_allocated == 0
-
-    def test_paged_mixed_sampling(self, tiny_lm):
-        """Stochastic rows ride the paged step too: same engine seed =>
-        identical streams vs the standard path (same sampling draws over
-        identical logits)."""
-        model, params = tiny_lm
-
-        def run(path):
+        def run():
             eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
-                                  max_batch_size=4, max_seq_len=32, seed=3,
-                                  decode_path=path)
-            p = np.arange(6, dtype=np.int32)
+                                  max_batch_size=4, max_seq_len=32, seed=3)
             g = eng.submit(p, 8)
             s = eng.submit(p, 8, temperature=0.9, top_k=16, top_p=0.9)
             out = eng.run_until_complete()
             return out[g], out[s]
 
-        assert run("paged") == run("standard")
-
-    def test_paged_probe_fallback(self, tiny_lm):
-        """A model without apply_decode_paged falls back under auto (reason
-        recorded); decode_path="paged" makes the failure fatal."""
-        model, params = tiny_lm
-        plain = type("NoPaged", (), {})()
-        for attr in ("kv_cache_dtype", "max_len", "d_model", "num_heads",
-                     "num_kv_heads", "num_layers", "policy", "moe_experts"):
-            setattr(plain, attr, getattr(model, attr, None))
-        eng = InferenceEngine.__new__(InferenceEngine)
-        # probe in isolation: the full engine needs a real model elsewhere
-        eng.model = plain
-        with pytest.raises(ValueError, match="apply_decode_paged"):
-            eng._probe_paged()
-        eng2 = InferenceEngine(model, params, num_blocks=8, block_size=4,
-                               max_batch_size=2, max_seq_len=16,
-                               decode_path="standard")
-        assert not eng2._paged
-        assert "decode_path" in eng2.paged_fallback_reason
-
-    def test_prefill_bucketing_bounds_compiles(self, tiny_lm):
-        """Prompt lengths quantize to power-of-two block buckets: many
-        distinct lengths share O(log) compiled prefill programs (legacy
-        whole-prompt path; the chunked default compiles NO prefill programs
-        — see TestChunkedPrefill.test_mixed_bucketing_bounds_compiles)."""
-        model, params = tiny_lm
-        eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
-                              max_batch_size=4, max_seq_len=32,
-                              chunked_prefill=False)
-        for n in (1, 2, 3, 4, 5, 7, 9, 11, 13, 15):
-            eng.submit(np.arange(n, dtype=np.int32) % 128, 2)
-        eng.run_until_complete()
-        buckets = sorted(k[1] for k in eng._jit if k[0] == "prefill")
-        # nb 1,2,3,4 -> buckets 1,2,4 -> padded 4,8,16 (cap: blocks_per_seq 8)
-        assert buckets == [4, 8, 16]
+        first = run()
+        assert first == run()
+        greedy, sampled = first
+        assert greedy == _greedy_ref(model, params, p, 8, 32)
+        ids = jnp.asarray(list(p) + sampled)[None]
+        logits, _ = model.apply({"params": params, "state": {}}, ids)
+        rows = np.asarray(logits[0, len(p) - 1:-1])
+        for tok, row in zip(sampled, rows):
+            assert tok in np.argsort(row)[-16:]
 
     def test_submit_validation(self, tiny_lm):
         model, params = tiny_lm
@@ -381,11 +374,12 @@ class TestEngineTiny:
 class TestChunkedPrefill:
     """The PR 4 tentpole: prompts advance chunk_size tokens per step inside
     the SAME compiled program as the decode rows. Every schedule must stay
-    token-exact against the retired whole-prompt path, on both decode paths,
-    with and without preemption."""
+    token-exact against the offline reference (``models.gpt2.generate``,
+    which pushes the whole prompt through one forward), with and without
+    preemption."""
 
-    def _run(self, tiny_lm, prompts, *, stagger=True, **kw):
-        model, params = tiny_lm
+    def _run(self, lm, prompts, *, stagger=True, **kw):
+        model, params = lm
         merged = dict(num_blocks=32, block_size=4, max_batch_size=4,
                       max_seq_len=32)
         merged.update(kw)
@@ -397,71 +391,61 @@ class TestChunkedPrefill:
         out = eng.run_until_complete()
         return eng, [out[r] for r in rids]
 
-    def test_chunked_matches_whole_staggered(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_chunked_matches_whole_staggered(self, lm, family):
         """chunk_size=4 splits the 9/16-token prompts across several mixed
-        steps; outputs must equal the whole-prompt path AND the offline
-        reference, on the standard and paged decode paths alike."""
-        model, params = tiny_lm
+        steps; outputs must equal the offline reference, whose prefill is
+        one whole-prompt forward."""
+        model, params = lm
         rng = np.random.default_rng(7)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
-        eng_c, chunked = self._run(tiny_lm, prompts, chunk_size=4)
-        _, whole = self._run(tiny_lm, prompts, chunked_prefill=False)
-        eng_p, chunked_paged = self._run(tiny_lm, prompts, chunk_size=4,
-                                         decode_path="paged")
-        _, whole_paged = self._run(tiny_lm, prompts, chunked_prefill=False,
-                                   decode_path="paged")
-        assert chunked == whole == chunked_paged == whole_paged
+        eng_c, chunked = self._run(lm, prompts, chunk_size=4)
         for toks, p in zip(chunked, prompts):
             assert toks == _greedy_ref(model, params, p, 10,
                                        eng_c.assembly_len)
-        # the 16-token prompt really took several chunks, and no legacy
-        # prefill program was ever compiled
+        # the 16-token prompt really took several chunks, and the only
+        # programs are the decode step and the mixed steps
         assert eng_c.metrics.prefill_chunks >= 4 + 3 + 2 + 2
-        assert not any(k[0] == "prefill" for k in eng_c._jit)
-        assert eng_p._paged and not any(k[0] == "prefill" for k in eng_p._jit)
+        assert {k[0] for k in eng_c._jit} == {"pdecode", "mixed"}
         _assert_drained(eng_c)
 
-    def test_chunked_preemption_recovers_exactly(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_chunked_preemption_recovers_exactly(self, lm, family):
         """A starved pool preempts mid-stream; partially-prefilled work is
         re-chunked on resume and every stream stays byte-identical."""
-        model, params = tiny_lm
+        model, params = lm
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, 128, p).astype(np.int32)
                    for p in (5, 9, 16, 7)]
-        for path in ("standard", "paged"):
-            eng, outs = self._run(tiny_lm, prompts, stagger=False,
-                                  num_blocks=9, chunk_size=4,
-                                  decode_path=path)
-            assert eng.metrics.preemptions > 0, "pool was never exhausted"
-            for toks, p in zip(outs, prompts):
-                assert toks == _greedy_ref(model, params, p, 10,
-                                           eng.assembly_len)
-            _assert_drained(eng)
+        eng, outs = self._run(lm, prompts, stagger=False,
+                              num_blocks=9, chunk_size=4)
+        assert eng.metrics.preemptions > 0, "pool was never exhausted"
+        for toks, p in zip(outs, prompts):
+            assert toks == _greedy_ref(model, params, p, 10,
+                                       eng.assembly_len)
+        _assert_drained(eng)
 
     def test_mixed_bucketing_bounds_compiles(self, tiny_lm):
         """Chunk takes quantize to power-of-two query widths: many distinct
-        prompt lengths share O(log chunk_size) compiled mixed programs, and
-        the legacy prefill program is never built."""
+        prompt lengths share O(log chunk_size) compiled mixed programs."""
         model, params = tiny_lm
         eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
                               max_batch_size=4, max_seq_len=32, chunk_size=8)
         for n in (1, 2, 3, 4, 5, 7, 9, 11, 13, 15):
             eng.submit(np.arange(n, dtype=np.int32) % 128, 2)
         eng.run_until_complete()
-        assert not any(k[0] == "prefill" for k in eng._jit)
         widths = {k[2] for k in eng._jit if k[0] == "mixed"}
         assert widths, "mixed step never ran"
         assert widths <= {1, 2, 4, 8}      # pow2 buckets, capped by chunk_size
         _assert_drained(eng)
 
-    def test_mixed_sampling_in_chunked_steps(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_mixed_sampling_in_chunked_steps(self, lm, family):
         """Greedy and stochastic rows share mixed steps with in-flight prompt
         chunks; the greedy stream stays exact and stochastic rows stay
-        in-vocab. (Cross-schedule stochastic equality vs the whole-prompt
-        path is NOT asserted: the two paths draw step keys at different
-        points of the stream, so the draws legitimately differ.)"""
-        model, params = tiny_lm
+        in-vocab."""
+        model, params = lm
         eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
                               max_batch_size=4, max_seq_len=32, seed=3,
                               chunk_size=4)
@@ -476,6 +460,31 @@ class TestChunkedPrefill:
 
 
 # -- acceptance: gpt2_small, 8 staggered requests ----------------------------
+
+
+def _assert_teacher_forced(model, params, prompts, outs):
+    """Feed each prompt plus the engine's output through one plain reference
+    forward and require every engine token to be the argmax there (a handful
+    of fp near-ties allowed)."""
+    seqs = np.stack([np.concatenate([p, o]) for p, o in zip(prompts, outs)])
+    caches = model.init_cache(len(outs), seqs.shape[1])
+    logits, _ = model.apply_cached(params, jnp.asarray(seqs), caches, 0)
+    logits = np.asarray(logits, np.float64)
+    plen, max_new = prompts.shape[1], len(outs[0])
+    exact, ties = 0, []
+    for i in range(len(outs)):
+        for j in range(max_new):
+            row = logits[i, plen + j - 1]
+            chosen = seqs[i, plen + j]
+            if chosen == row.argmax():
+                exact += 1
+            else:
+                ties.append(float(row.max() - row[chosen]))
+    total = len(outs) * max_new
+    # measured: 124/128 exact, worst near-tie margin 0.0088 — far under the
+    # ~0.01+ top-2 gaps a non-greedy bug would violate
+    assert exact >= 0.9 * total, f"only {exact}/{total} tokens were argmax"
+    assert all(m < 0.05 for m in ties), f"non-tie divergence: {ties}"
 
 
 @pytest.mark.slow
@@ -516,39 +525,17 @@ def test_gpt2_small_staggered_greedy():
     assert eng.pool.num_allocated == 0
     assert all(len(out[rid]) == max_new for rid in rids)
 
-    seqs = np.stack([np.concatenate([prompts[i], out[rids[i]]])
-                     for i in range(len(rids))])
-    caches = model.init_cache(len(rids), seqs.shape[1])
-    logits, _ = model.apply_cached(params, jnp.asarray(seqs), caches, 0)
-    logits = np.asarray(logits, np.float64)
-    plen = prompts.shape[1]
-    exact, ties = 0, []
-    for i in range(len(rids)):
-        for j in range(max_new):
-            row = logits[i, plen + j - 1]
-            chosen = seqs[i, plen + j]
-            if chosen == row.argmax():
-                exact += 1
-            else:
-                ties.append(float(row.max() - row[chosen]))
-    total = len(rids) * max_new
-    # measured: 124/128 exact, worst near-tie margin 0.0088 — far under the
-    # ~0.01+ top-2 gaps a non-greedy bug would violate
-    assert exact >= 0.9 * total, f"only {exact}/{total} tokens were argmax"
-    assert all(m < 0.05 for m in ties), f"non-tie divergence: {ties}"
+    _assert_teacher_forced(model, params, prompts, [out[r] for r in rids])
 
 
 @pytest.mark.slow
 def test_gpt2_small_paged_matches_standard():
-    """Acceptance bar for the paged decode path: on gpt2_small, staggered
-    submissions with preemption, decode_path="paged" must produce
-    TOKEN-IDENTICAL streams to "standard".
-
-    Unlike the teacher-forced test above, exact equality is well-posed here:
-    both engines run the same schedule over the same weights, so every
-    near-tie must resolve the same way — any divergence is a real paged-path
-    bug (wrong page read/write, off-by-one kv length, table mix-up), not fp
-    noise."""
+    """Acceptance bar for the serving path with the prefix cache off: on
+    gpt2_small, staggered submissions with preemption, every resumed request
+    re-prefills its whole extended prompt, and every token is the argmax of
+    the plain reference forward at its position (teacher forcing, as above:
+    a wrong page read or write, an off-by-one kv length or a table mix-up
+    shows as a non-tie divergence)."""
     from tnn_tpu.models.zoo import create
 
     model = create("gpt2_small")
@@ -556,37 +543,27 @@ def test_gpt2_small_paged_matches_standard():
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, model.vocab_size, (8, 12)).astype(np.int32)
     max_new = 16
-
-    def run(path):
-        eng = InferenceEngine(model, params, num_blocks=14, block_size=16,
-                              max_batch_size=8, max_seq_len=32,
-                              decode_path=path)
-        rids = []
-        for i, p in enumerate(prompts):
-            rids.append(eng.submit(p, max_new))
-            if i % 3 == 2:
-                eng.step()
-        out = eng.run_until_complete()
-        return eng, [out[r] for r in rids]
-
-    eng_p, paged = run("paged")
-    eng_s, std = run("standard")
-    assert eng_p.metrics.preemptions > 0, "pool was never exhausted"
-    assert eng_s.metrics.preemptions > 0
-    assert paged == std
-    assert eng_p.pool.num_allocated == 0
+    eng = InferenceEngine(model, params, num_blocks=14, block_size=16,
+                          max_batch_size=8, max_seq_len=32,
+                          prefix_cache=False)
+    rids = []
+    for i, p in enumerate(prompts):
+        rids.append(eng.submit(p, max_new))
+        if i % 3 == 2:
+            eng.step()
+    out = eng.run_until_complete()
+    assert eng.metrics.preemptions > 0, "pool was never exhausted"
+    _assert_teacher_forced(model, params, prompts, [out[r] for r in rids])
+    assert eng.pool.num_allocated == 0
 
 
 @pytest.mark.slow
 def test_gpt2_small_chunked_paged_matches_standard():
     """Chunked-prefill acceptance on gpt2_small: chunk_size=8 splits every
     12-token prompt across two mixed steps, the pool preempts under load,
-    and the paged path must stay TOKEN-IDENTICAL to the standard path.
-
-    As above, exact equality is well-posed because both engines run the same
-    schedule over the same weights — identical near-tie resolution — so any
-    divergence is a real mixed-step bug (ragged query gather, chunk scatter,
-    per-row kv length), not fp noise."""
+    and every token is the argmax of the plain reference forward at its
+    position (a wrong ragged query gather, chunk write or per-row kv length
+    shows as a non-tie divergence)."""
     from tnn_tpu.models.zoo import create
 
     model = create("gpt2_small")
@@ -594,27 +571,19 @@ def test_gpt2_small_chunked_paged_matches_standard():
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, model.vocab_size, (8, 12)).astype(np.int32)
     max_new = 16
-
-    def run(path):
-        eng = InferenceEngine(model, params, num_blocks=14, block_size=16,
-                              max_batch_size=8, max_seq_len=32,
-                              decode_path=path, chunk_size=8)
-        rids = []
-        for i, p in enumerate(prompts):
-            rids.append(eng.submit(p, max_new))
-            if i % 3 == 2:
-                eng.step()
-        out = eng.run_until_complete()
-        return eng, [out[r] for r in rids]
-
-    eng_p, paged = run("paged")
-    eng_s, std = run("standard")
-    assert eng_p.metrics.preemptions > 0, "pool was never exhausted"
-    assert eng_p.metrics.prefill_chunks > len(prompts), "prompts never split"
-    assert paged == std
-    assert eng_p.pool.num_allocated == 0
-    assert eng_p.pool.num_free + eng_p.pool.num_evictable == \
-        eng_p.pool.capacity
+    eng = InferenceEngine(model, params, num_blocks=14, block_size=16,
+                          max_batch_size=8, max_seq_len=32, chunk_size=8)
+    rids = []
+    for i, p in enumerate(prompts):
+        rids.append(eng.submit(p, max_new))
+        if i % 3 == 2:
+            eng.step()
+    out = eng.run_until_complete()
+    assert eng.metrics.preemptions > 0, "pool was never exhausted"
+    assert eng.metrics.prefill_chunks > len(prompts), "prompts never split"
+    _assert_teacher_forced(model, params, prompts, [out[r] for r in rids])
+    assert eng.pool.num_allocated == 0
+    assert eng.pool.num_free + eng.pool.num_evictable == eng.pool.capacity
 
 
 # -- fault tolerance: invariants, lifecycle, backpressure, chaos --------------
@@ -977,7 +946,7 @@ class TestLifecycle:
         assert s["cancelled"] == 1
         assert s["pool_allocated_blocks"] == 0
         assert s["queue_depth"] == 0 and s["num_running"] == 0
-        assert s["decode_path"] in ("paged", "fused", "standard")
+        assert s["decode_path"] == "paged"
 
 
 class TestChaos:
@@ -1139,13 +1108,14 @@ class TestChaos:
                 assert out[rid] == ref[ref_rid], f"survivor {rid} diverged"
         _assert_drained(eng)
 
-    def test_chaos_gate_paged_path(self, tiny_lm):
-        """Same gate over the paged decode path (its own compiled step and
-        KV plumbing must honor the same isolation)."""
-        model, params = tiny_lm
+    def test_chaos_gate_llama(self, llama_lm):
+        """Same gate over the other family's block (rotary, RMSNorm, gated
+        feed-forward, grouped heads): its compiled step and KV plumbing must
+        honor the same isolation."""
+        model, params = llama_lm
         prompts = self._prompts(6, seed=7)
         kw = dict(num_blocks=16, block_size=4, max_batch_size=4,
-                  max_seq_len=32, decode_path="paged")
+                  max_seq_len=32)
         ref_eng, ref_rids = self._run(model, params, prompts, **kw)
         plan = FaultPlan(seed=13, alloc_fail_prob=0.12, nan_logit_calls=(4,))
         eng, rids = self._run(model, params, prompts, plan=plan, **kw)
@@ -1373,25 +1343,29 @@ class TestPrefixCacheEngine:
         _assert_drained(eng_on)
         _assert_drained(eng_off)
 
-    def test_cache_on_equals_cache_off_paged(self, tiny_lm):
-        """Same A/B over the paged decode path: forked tables must read
-        identically through the ragged paged-attention kernel."""
-        model, params = tiny_lm
+    def test_cache_on_equals_cache_off_llama(self, llama_lm):
+        """Same A/B over the other family's block: forked tables of grouped
+        KV heads must read identically through the ragged paged-attention
+        kernel, and rotary positions must survive a prefix hit."""
+        model, params = llama_lm
         prompts = self._shared_prompts(seed=1)
-        eng_on, on = self._run(model, params, prompts, stagger=1,
-                               decode_path="paged")
+        eng_on, on = self._run(model, params, prompts, stagger=1)
         eng_off, off = self._run(model, params, prompts, stagger=1,
-                                 decode_path="paged", prefix_cache=False)
+                                 prefix_cache=False)
         assert on == off
         assert eng_on.metrics.prefill_tokens_saved > 0, "cache never hit"
+        for p, toks in zip(prompts, on):
+            assert toks == _greedy_ref(model, params, p, 8,
+                                       eng_on.assembly_len)
         _assert_drained(eng_on)
         _assert_drained(eng_off)
 
-    def test_cache_on_equals_cache_off_under_preemption(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_cache_on_equals_cache_off_under_preemption(self, lm, family):
         """A pool too small for the shared-prefix batch: preemption churns
         tables through free -> evictable -> revived, and outputs must stay
         token-exact against cache-off AND the offline reference."""
-        model, params = tiny_lm
+        model, params = lm
         prompts = self._shared_prompts(seed=2)
         kw = dict(num_blocks=9, block_size=4, max_batch_size=4,
                   max_seq_len=32)
@@ -1405,13 +1379,14 @@ class TestPrefixCacheEngine:
         _assert_drained(eng_on)
         _assert_drained(eng_off)
 
-    def test_cow_at_partial_block_boundary(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_cow_at_partial_block_boundary(self, lm, family):
         """Resubmitting an identical prompt is a FULL-COVER hit: every full
         block matches, so the matcher's first KV write (its recomputed last
         token) would land inside the last matched block. The engine must
         give it a private copy — and the published original must survive
         intact for the next twin."""
-        model, params = tiny_lm
+        model, params = lm
         p = np.arange(8, dtype=np.int32)   # exactly 2 full blocks
         eng = InferenceEngine(model, params, **self.KW)
         ref = _greedy_ref(model, params, p, 8, eng.assembly_len)
@@ -1859,18 +1834,19 @@ class TestCrashResumeExactness:
     mid-decode, mid-spec-draft — loses KV pages but never committed
     tokens. After the supervisor restart, every request migrates through
     the recompute-resume path and both the final output and the streamed
-    token sequence are byte-identical to an uninterrupted run, across
-    decode paths and with the prefix cache on or off."""
+    token sequence are byte-identical to an uninterrupted run (the offline
+    reference), across model families and with the prefix cache on or
+    off."""
 
     @pytest.mark.parametrize("cache", [True, False],
                              ids=["cache", "nocache"])
-    @pytest.mark.parametrize("path", ["standard", "paged"])
+    @pytest.mark.parametrize("family", FAMILIES)
     @pytest.mark.parametrize(
         "site", ["prefill_chunk", "decode", "spec_draft"])
-    def test_crash_resume_token_exact(self, tiny_lm, site, path, cache):
-        model, params = tiny_lm
+    def test_crash_resume_token_exact(self, lm, site, family, cache):
+        model, params = lm
         kw = dict(num_blocks=32, block_size=4, max_batch_size=4,
-                  max_seq_len=32, decode_path=path, prefix_cache=cache)
+                  max_seq_len=32, prefix_cache=cache)
         if site == "spec_draft":
             kw.update(spec="ngram", spec_k=3)
             prompts = _cyclic_prompts(2, seed=3)
@@ -3193,8 +3169,8 @@ class TestSpecDecode:
 
     KW = dict(num_blocks=32, block_size=4, max_batch_size=4, max_seq_len=32)
 
-    def _eng(self, tiny_lm, draft_lm=None, spec="ngram", **kw):
-        model, params = tiny_lm
+    def _eng(self, lm, draft_lm=None, spec="ngram", **kw):
+        model, params = lm
         merged = dict(self.KW)
         merged.update(kw)
         if spec == "draft":
@@ -3209,12 +3185,11 @@ class TestSpecDecode:
         out = eng.run_until_complete()
         return [out[r] for r in rids]
 
-    @pytest.mark.parametrize(
-        "path", [pytest.param("standard", marks=pytest.mark.slow), "paged"])
-    def test_ngram_staggered_parity(self, tiny_lm, path):
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_ngram_staggered_parity(self, lm, family):
+        model, params = lm
         prompts = _cyclic_prompts(4, seed=0)
-        eng = self._eng(tiny_lm, decode_path=path)
+        eng = self._eng(lm)
         outs = self._staggered(eng, prompts)
         for toks, p in zip(outs, prompts):
             assert toks == _greedy_ref(model, params, p, 10,
@@ -3232,11 +3207,11 @@ class TestSpecDecode:
     # both variants re-pay the draft-model jit cache; the draft axis keeps
     # a tier-1 gate via the spec_draft crash-resume matrix entry
     @pytest.mark.slow
-    @pytest.mark.parametrize("path", ["standard", "paged"])
-    def test_draft_model_staggered_parity(self, tiny_lm, draft_lm, path):
-        model, params = tiny_lm
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_draft_model_staggered_parity(self, lm, draft_lm, family):
+        model, params = lm
         prompts = _cyclic_prompts(4, seed=1)
-        eng = self._eng(tiny_lm, draft_lm, spec="draft", decode_path=path)
+        eng = self._eng(lm, draft_lm, spec="draft")
         outs = self._staggered(eng, prompts)
         for toks, p in zip(outs, prompts):
             assert toks == _greedy_ref(model, params, p, 10,
@@ -3246,7 +3221,7 @@ class TestSpecDecode:
 
     def test_spec_off_engine_is_untouched(self, tiny_lm):
         """spec="off" must not even build spec programs: every mixed compile
-        key keeps its legacy 4-tuple shape, and the gauges say so."""
+        key keeps its 4-tuple shape, and the gauges say so."""
         eng = self._eng(tiny_lm, spec="off")
         self._staggered(eng, _cyclic_prompts(4, seed=0))
         assert all(len(k) == 4 for k in eng._jit if k[0] == "mixed")
@@ -3270,15 +3245,16 @@ class TestSpecDecode:
                                            eng.assembly_len)
         _assert_drained(eng)
 
-    def test_prefix_cache_hits_stay_exact(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_prefix_cache_hits_stay_exact(self, lm, family):
         """Shared-prefix admission (forked tables, COW) composes with
         speculation: cached rows still verify token-exact."""
-        model, params = tiny_lm
+        model, params = lm
         rng = np.random.default_rng(3)
         prefix = np.tile(rng.integers(0, 128, 3), 4).astype(np.int32)
         prompts = [np.concatenate([prefix, rng.integers(0, 128, 4)
                                    .astype(np.int32)]) for _ in range(4)]
-        eng = self._eng(tiny_lm)
+        eng = self._eng(lm)
         rids = []
         for p in prompts:
             rids.append(eng.submit(p, 8))
@@ -3290,13 +3266,14 @@ class TestSpecDecode:
                                            eng.assembly_len)
         _assert_drained(eng)
 
-    def test_stop_token_mid_draft_clips_commit(self, tiny_lm):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_stop_token_mid_draft_clips_commit(self, lm, family):
         """A stop token inside an accepted draft run clips the commit at the
         stop position — trailing accepted tokens are discarded, exactly as
         sequential decode would never have produced them."""
-        model, params = tiny_lm
+        model, params = lm
         p = _cyclic_prompts(1, seed=4)[0]
-        eng = self._eng(tiny_lm)
+        eng = self._eng(lm)
         ref = _greedy_ref(model, params, p, 10, eng.assembly_len)
         stop = ref[3]
         rid = eng.submit(p, 10, stop_token=stop)
@@ -3372,9 +3349,12 @@ class TestSpecDecode:
         with pytest.raises(ValueError, match="spec_k"):
             InferenceEngine(model, params, spec="ngram", spec_k=0,
                             **self.KW)
-        with pytest.raises(ValueError, match="chunked_prefill"):
-            InferenceEngine(model, params, spec="ngram",
-                            chunked_prefill=False, **self.KW)
+        # the one serving path needs the model's paged methods: a model
+        # without them is refused at start-up, in one sentence
+        plain = type("NoPaged", (), {"kv_cache_dtype": None})()
+        with pytest.raises(ValueError, match="NoPaged has no "
+                                             "apply_decode_paged"):
+            InferenceEngine(plain, params, **self.KW)
         from tnn_tpu.models.gpt2 import gpt2_tiny
 
         wrong = gpt2_tiny(vocab_size=64, max_len=64)
@@ -3722,8 +3702,8 @@ class TestTierEngine:
                                    .astype(np.int32)])
                 for _ in range(n)]
 
-    def _engine(self, tiny_lm, *, tier_bytes, **kw):
-        model, params = tiny_lm
+    def _engine(self, lm, *, tier_bytes, **kw):
+        model, params = lm
         merged = dict(num_blocks=10, block_size=4, max_batch_size=2,
                       max_seq_len=32, chunk_size=8,
                       host_tier_bytes=tier_bytes)
@@ -3743,18 +3723,17 @@ class TestTierEngine:
         return out
 
     @pytest.mark.slow
-    @pytest.mark.parametrize("path", ["standard", "paged"])
-    def test_tier_token_exact_composed(self, tiny_lm, path):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_tier_token_exact_composed(self, lm, family):
         """The acceptance gate: tier-on output must equal tier-off output
         token-for-token with prefix cache + ngram speculation + overlap +
-        int8 KV all composed, on both decode paths — and the tier must
+        int8 KV all composed, for both model families — and the tier must
         have genuinely carried traffic (demotions and readmits observed),
         while the tier-off twin saw none."""
         prompts = self._prompts()
-        compose = dict(decode_path=path, spec="ngram", spec_k=3,
-                       overlap=True, kv_dtype="int8")
-        on = self._engine(tiny_lm, tier_bytes=1 << 20, **compose)
-        off = self._engine(tiny_lm, tier_bytes=0, **compose)
+        compose = dict(spec="ngram", spec_k=3, overlap=True, kv_dtype="int8")
+        on = self._engine(lm, tier_bytes=1 << 20, **compose)
+        off = self._engine(lm, tier_bytes=0, **compose)
         # two passes: the first populates device cache + tier, the second
         # readmits what pool pressure demoted
         on_toks = [self._serve_serially(on, prompts) for _ in range(2)][1]
@@ -4236,13 +4215,12 @@ class TestDisagg:
     fleet-wide shared prefix cache."""
 
     KW = dict(num_blocks=64, block_size=4, max_batch_size=4, max_seq_len=64,
-              chunk_size=8, chunked_prefill=True, prefix_cache=True,
-              decode_path="paged")
+              chunk_size=8, prefix_cache=True)
     THRESH = 16
 
-    def _fleet(self, tiny_lm, n=2, *, plans=None, router_kw=None,
+    def _fleet(self, lm, n=2, *, plans=None, router_kw=None,
                engine_kw=None, sup_kw=None):
-        model, params = tiny_lm
+        model, params = lm
         ekw = dict(self.KW)
         ekw.update(engine_kw or {})
         skw = dict(restart_backoff_s=0.0)
@@ -4284,17 +4262,16 @@ class TestDisagg:
         with pytest.raises(ValueError, match="at least one decode"):
             Router(sups, roles=["prefill", "prefill"])
 
-    @pytest.mark.parametrize("path", ["standard", "paged"])
+    @pytest.mark.parametrize("family", FAMILIES)
     @pytest.mark.parametrize("kv", [True, False])
-    def test_boundary_handoff_token_exact(self, tiny_lm, path, kv):
-        """The tentpole, both decode paths: a long prompt lands on the
+    def test_boundary_handoff_token_exact(self, lm, family, kv):
+        """The tentpole, both model families: a long prompt lands on the
         prefill replica, crosses to the decode replica at the first-token
         boundary (KV wire transfer or recompute-resume), and the client
         sees one uninterrupted token-exact stream."""
-        model, params = tiny_lm
+        model, params = lm
         router, sups, events = self._fleet(
-            tiny_lm, router_kw=dict(handoff_kv=kv),
-            engine_kw=dict(decode_path=path))
+            lm, router_kw=dict(handoff_kv=kv))
         rng = np.random.default_rng(11)
         lp, ln = self._long(rng)
         sp = rng.integers(0, 128, 6).astype(np.int32)
@@ -4561,16 +4538,14 @@ class TestDisagg:
         assert router.stats()["boundary_handoffs"] == 1
         self._no_leaks(router)
 
-    @pytest.mark.parametrize("path", ["standard", "paged"])
-    def test_disagg_composed_chaos_token_exact(self, tiny_lm, path):
-        """The PR gate, both decode paths: disagg-on vs disagg-off with
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_disagg_composed_chaos_token_exact(self, lm, family):
+        """The PR gate, both model families: disagg-on vs disagg-off with
         prefix cache + ngram spec + overlap + int8 KV composed, under
         handoff chaos (seeded corrupt + slow wire blocks, one decode
         replica killed mid-run) — every stream token-exact against the
         greedy reference, zero leaked blocks on the survivors."""
-        model, params = tiny_lm
-        ekw = dict(decode_path=path, kv_dtype="int8", spec="ngram",
-                   spec_k=3, overlap=True)
+        ekw = dict(kv_dtype="int8", spec="ngram", spec_k=3, overlap=True)
         rng = np.random.default_rng(19)
         prefix = rng.integers(0, 128, 8).astype(np.int32)
         prompts = [rng.integers(0, 128, self.THRESH + 4 + i).astype(np.int32)
@@ -4592,7 +4567,7 @@ class TestDisagg:
                    if disagg else dict(roles=None,
                                        disagg_prompt_threshold=0))
             router, sups, events = self._fleet(
-                tiny_lm, n=3, plans=plans, router_kw=rkw, engine_kw=ekw)
+                lm, n=3, plans=plans, router_kw=rkw, engine_kw=ekw)
             gids = [router.submit(p, max_new) for p in prompts]
             router.pump(2)
             router.kill_replica(2)       # a receiver dies mid-fleet
@@ -4654,7 +4629,7 @@ class TestHostTierTPExclusion:
         with pytest.raises(ValueError, match="tp>1 is unsupported"):
             InferenceEngine(model, params, num_blocks=8, block_size=4,
                             max_batch_size=2, max_seq_len=16,
-                            chunked_prefill=True, prefix_cache=True,
+                            prefix_cache=True,
                             host_tier_bytes=1 << 20, tp=2)
 
     def test_cli_rejects_tier_with_tp(self, capsys):

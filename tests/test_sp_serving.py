@@ -5,9 +5,9 @@ every shard sweeps its own pages with the ragged paged kernel and the
 per-shard partials merge through one online-softmax psum per layer
 (ops/softmax_merge.py). The merge itself is exact to float tolerance, so
 — exactly like the TP lane — the gate here is byte-exactness of sampled
-token streams on fixed seeds: every composition that works at sp=1 (both
-decode paths, spec decode, prefix cache, the overlapped loop, int8 KV)
-must emit identical tokens at sp=2, through preemption and a mid-run
+token streams on fixed seeds: every composition that works at sp=1 (spec
+decode, prefix cache, the overlapped loop, int8 KV) must emit identical
+tokens at sp=2, through preemption and a mid-run
 supervisor crash. The headline capability gate is the long-context one:
 a prompt whose KV exceeds a single chip's pool must SERVE at sp=2 and
 fail cleanly at sp=1.
@@ -128,15 +128,6 @@ class TestSPValidation:
         with pytest.raises(ValueError, match="blocks_per_seq"):
             InferenceEngine(model, params, sp=sp, **kw)
 
-    def test_fused_decode_gated_off(self, tiny_lm, sp):
-        """Explicit fused selection errors (like TP); auto falls back."""
-        model, params = tiny_lm
-        with pytest.raises(ValueError, match="fused"):
-            InferenceEngine(model, params, sp=sp, decode_path="fused", **KW)
-        eng = InferenceEngine(model, params, sp=sp, decode_path="standard",
-                              **KW)
-        assert eng._fused is None
-
     def test_cli_preflight_rejects_sp_with_tp(self, sp, capsys):
         """tnn-serve dies with a pointed one-liner BEFORE touching model
         weights, not a shard_map traceback out of engine construction."""
@@ -211,17 +202,16 @@ class TestSPPool:
 
 
 class TestSPExactness:
-    @pytest.mark.parametrize("path", ["paged", "standard"])
-    def test_staggered_parity_both_paths(self, tiny_lm, sp, path):
-        """Staggered admission (ragged offsets) on both decode paths:
-        sp=2 streams must equal sp=1 streams AND the offline greedy
-        reference, token for token. seed=13 is a checked tie-free seed on
+    def test_staggered_parity(self, tiny_lm, sp):
+        """Staggered admission (ragged offsets): sp=2 streams must equal
+        sp=1 streams AND the offline greedy reference, token for token.
+        seed=13 is a checked tie-free seed on
         jax 0.9: the merge is exact to float tolerance, but the bf16
         per-shard partials can flip a greedy near-tie of this random
         model."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=13)
-        kw = dict(decode_path=path, stagger=2)
+        kw = dict(stagger=2)
         eng1, base = _run(model, params, prompts, **kw)
         eng2, sharded = _run(model, params, prompts, sp=sp, **kw)
         assert sharded == base
@@ -239,7 +229,7 @@ class TestSPExactness:
         before sharding, so even the closeness-gated lane is parity)."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=7) + _prompts(2, seed=7)[:1]  # a repeat
-        kw = dict(decode_path="paged", kv_dtype="int8", spec="ngram",
+        kw = dict(kv_dtype="int8", spec="ngram",
                   prefix_cache=True, overlap=True)
         eng1, base = _run(model, params, prompts, **kw)
         eng2, sharded = _run(model, params, prompts, sp=sp, **kw)
@@ -253,7 +243,7 @@ class TestSPExactness:
         no shard leaks a block."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=1)
-        kw = dict(num_blocks=10, decode_path="paged")
+        kw = dict(num_blocks=10)
         eng1, base = _run(model, params, prompts, max_new=10, **kw)
         eng2, sharded = _run(model, params, prompts, max_new=10, sp=sp, **kw)
         assert eng2.metrics.preemptions > 0, "pool was never exhausted"
@@ -321,7 +311,7 @@ class TestSPFailures:
         model, params = tiny_lm
         plan = FaultPlan(step_crash_calls=(2,))
         eng = InferenceEngine(model, params, sp=sp, faults=plan,
-                              decode_path="paged", num_blocks=32,
+                              num_blocks=32,
                               block_size=4, max_batch_size=2, max_seq_len=32)
         events = []
         sup = EngineSupervisor(eng, event_sink=events.append,
@@ -349,7 +339,7 @@ class TestSPFailures:
         model, params = tiny_lm
         prompts = _prompts(6, seed=6)
         kw = dict(num_blocks=16, block_size=4, max_batch_size=4,
-                  max_seq_len=32, decode_path="paged", sp=sp)
+                  max_seq_len=32, sp=sp)
 
         def run(plan=None):
             eng = InferenceEngine(model, params, faults=plan, **kw)
@@ -454,8 +444,7 @@ class TestCompileCache:
 class TestSPObservability:
     def test_gauges_and_exposition(self, tiny_lm, sp):
         model, params = tiny_lm
-        eng, _ = _run(model, params, _prompts(2, seed=3), sp=sp,
-                      decode_path="paged")
+        eng, _ = _run(model, params, _prompts(2, seed=3), sp=sp)
         s = eng.stats()
         assert s["sp_degree"] == sp
         assert s["pool_blocks_per_shard"] == eng.pool.blocks_per_shard

@@ -3,8 +3,8 @@
 Unlike the int8 lane (closeness-gated), TP changes nothing numerically
 except the all-reduce order of two matmul partial sums per layer — on the
 fixed-seed tiny model that drift never flips a sampled token, so the gate
-here is byte-exactness: every composition that works at tp=1 (both decode
-paths, spec decode, prefix cache, the overlapped loop, int8 KV) must emit
+here is byte-exactness: every composition that works at tp=1 (spec
+decode, prefix cache, the overlapped loop, int8 KV) must emit
 identical token streams at tp=2, through staggered arrivals, preemption,
 and a mid-run supervisor crash (whose pool reset must purge EVERY shard).
 
@@ -100,28 +100,17 @@ class TestTPValidation:
         with pytest.raises(ValueError, match="quant"):
             InferenceEngine(model, params, tp=tp, quant_weights=True, **KW)
 
-    def test_fused_decode_gated_off(self, tiny_lm, tp):
-        """Explicit fused selection errors (like int8); auto falls back."""
-        model, params = tiny_lm
-        with pytest.raises(ValueError, match="fused"):
-            InferenceEngine(model, params, tp=tp, decode_path="fused", **KW)
-        eng = InferenceEngine(model, params, tp=tp, decode_path="standard",
-                              **KW)
-        assert eng._fused is None
-
 
 # -- exactness: tp=2 == tp=1 == offline reference -----------------------------
 
 
 class TestTPExactness:
-    @pytest.mark.parametrize("path", ["paged", "standard"])
-    def test_staggered_parity_both_paths(self, tiny_lm, tp, path):
-        """Staggered admission (ragged offsets) on both decode paths:
-        tp=2 streams must equal tp=1 streams AND the offline greedy
-        reference, token for token."""
+    def test_staggered_parity(self, tiny_lm, tp):
+        """Staggered admission (ragged offsets): tp=2 streams must equal
+        tp=1 streams AND the offline greedy reference, token for token."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=5)
-        kw = dict(decode_path=path, stagger=2)
+        kw = dict(stagger=2)
         eng1, base = _run(model, params, prompts, **kw)
         eng2, sharded = _run(model, params, prompts, tp=tp, **kw)
         assert sharded == base
@@ -139,7 +128,7 @@ class TestTPExactness:
         shard, so even the closeness-gated lane becomes parity here)."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=7) + _prompts(2, seed=7)[:1]  # a repeat
-        kw = dict(decode_path="paged", kv_dtype="int8", spec="ngram",
+        kw = dict(kv_dtype="int8", spec="ngram",
                   prefix_cache=True, overlap=True)
         eng1, base = _run(model, params, prompts, **kw)
         eng2, sharded = _run(model, params, prompts, tp=tp, **kw)
@@ -152,7 +141,7 @@ class TestTPExactness:
         produces byte-identical output and no shard leaks a block."""
         model, params = tiny_lm
         prompts = _prompts(4, seed=1)
-        kw = dict(num_blocks=9, decode_path="paged")
+        kw = dict(num_blocks=9)
         eng1, base = _run(model, params, prompts, max_new=10, **kw)
         eng2, sharded = _run(model, params, prompts, max_new=10, tp=tp, **kw)
         assert eng2.metrics.preemptions > 0, "pool was never exhausted"
@@ -187,7 +176,7 @@ class TestTPExactness:
         monkeypatch.setenv("TNN_DEBUG_SYNC", "1")
         model, params = tiny_lm
         prompts = _prompts(3, seed=2)
-        eng, out = _run(model, params, prompts, tp=tp, decode_path="paged",
+        eng, out = _run(model, params, prompts, tp=tp,
                         spec="ngram", overlap=True)
         for toks, p in zip(out, prompts):
             assert toks == _greedy_ref(model, params, p, 8,
@@ -207,7 +196,7 @@ class TestTPFailures:
         model, params = tiny_lm
         plan = FaultPlan(step_crash_calls=(2,))
         eng = InferenceEngine(model, params, tp=tp, faults=plan,
-                              decode_path="paged", num_blocks=32,
+                              num_blocks=32,
                               block_size=4, max_batch_size=2, max_seq_len=32)
         events = []
         sup = EngineSupervisor(eng, event_sink=events.append,
@@ -233,7 +222,7 @@ class TestTPFailures:
         model, params = tiny_lm
         prompts = _prompts(8, seed=6)
         kw = dict(num_blocks=16, block_size=4, max_batch_size=4,
-                  max_seq_len=32, decode_path="paged", tp=tp)
+                  max_seq_len=32, tp=tp)
 
         def run(plan=None):
             eng = InferenceEngine(model, params, faults=plan, **kw)
@@ -260,7 +249,7 @@ class TestTPObservability:
     def test_gauges_and_exposition(self, tiny_lm, tp):
         model, params = tiny_lm
         eng, _ = _run(model, params, _prompts(2, seed=3), tp=tp,
-                      kv_dtype="int8", decode_path="paged")
+                      kv_dtype="int8")
         s = eng.stats()
         assert s["tp_degree"] == tp
         per_tok = eng.pool.kv_bytes_per_token + \

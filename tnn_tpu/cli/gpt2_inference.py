@@ -44,14 +44,8 @@ def main(argv=None):
     ap.add_argument("--int8", action="store_true",
                     help="weight-only int8 decode (in-VMEM-dequant Pallas "
                          "matmul; ~2x fewer weight bytes per token)")
-    ap.add_argument("--fused", action="store_true",
-                    help="whole-stack fused decode kernel (one Pallas launch "
-                         "per token, ops/pallas/decode_stack.py); implies "
-                         "--int8")
     args = ap.parse_args(argv)
     compile_cache.enable()
-    if args.fused:
-        args.int8 = True
     if (args.top_k or args.top_p) and args.temperature <= 0:
         # top-k/top-p only shape a STOCHASTIC distribution; under greedy
         # (temperature 0) they would be silently ignored
@@ -86,19 +80,15 @@ def main(argv=None):
         prompt_ids = np.frombuffer(args.prompt.encode(), np.uint8).astype(
             np.int32)[None] % model.vocab_size
 
-    gen_fn = generate
-    if args.fused:
-        from tnn_tpu.models.fused_decode import fused_generate as gen_fn
-
     # generate twice: first call compiles, second measures steady-state decode.
     # np.asarray forces completion — without it the device would still be
     # running the first call when the timer starts.
     kw = dict(temperature=args.temperature, top_k=args.top_k,
               top_p=args.top_p, rng=jax.random.PRNGKey(args.seed))
-    out = gen_fn(model, params, prompt_ids, args.max_new_tokens, **kw)
+    out = generate(model, params, prompt_ids, args.max_new_tokens, **kw)
     np.asarray(out)
     t0 = time.perf_counter()
-    out = gen_fn(model, params, prompt_ids, args.max_new_tokens, **kw)
+    out = generate(model, params, prompt_ids, args.max_new_tokens, **kw)
     new_tokens = np.asarray(out)[0]  # generate returns only the new tokens
     dt = time.perf_counter() - t0
 

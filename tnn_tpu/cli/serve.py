@@ -111,10 +111,6 @@ def main(argv=None):
                     help="prompt tokens per mixed-step prefill chunk "
                          "(chunked prefill co-schedules prompt chunks with "
                          "decode rows in one compiled step)")
-    ap.add_argument("--no-chunked-prefill", action="store_true",
-                    help="restore the legacy whole-prompt prefill path "
-                         "(one bucketed prefill program per admitted prompt; "
-                         "also disables the prefix cache)")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable automatic prefix caching (content-"
                          "addressed KV block reuse across requests)")
@@ -123,8 +119,6 @@ def main(argv=None):
                          "many full KV blocks")
     ap.add_argument("--max-seq-len", type=int, default=0,
                     help="per-request position cap (0 = model/pool limit)")
-    ap.add_argument("--decode-path", default="auto",
-                    choices=("auto", "standard", "fused", "paged"))
     ap.add_argument("--kv-dtype", default="f32", choices=("f32", "int8"),
                     help="KV pool page dtype: int8 halves resident KV and "
                          "decode page traffic (per-row f32 scale sidecar; "
@@ -163,8 +157,8 @@ def main(argv=None):
                          "demoted to host memory and re-admitted on a later "
                          "prefix hit after a rolling-hash digest check (a "
                          "corrupt or torn block degrades to an uncached "
-                         "miss, never wrong KV); requires the prefix cache "
-                         "and chunked prefill, incompatible with --tp > 1")
+                         "miss, never wrong KV); requires the prefix cache, "
+                         "incompatible with --tp > 1")
     ap.add_argument("--autoscale", default="",
                     help="MIN:MAX — run a load-driven autoscaler over the "
                          "replica fleet: scale up under queue pressure via "
@@ -293,10 +287,10 @@ def main(argv=None):
         ap.error(f"--host-tier-bytes must be >= 0, got "
                  f"{args.host_tier_bytes}")
     if args.host_tier_bytes:
-        if args.no_prefix_cache or args.no_chunked_prefill:
+        if args.no_prefix_cache:
             ap.error("--host-tier-bytes needs the prefix cache (the tier "
                      "is keyed by its rolling-hash chain) — drop "
-                     "--no-prefix-cache/--no-chunked-prefill or the tier")
+                     "--no-prefix-cache or the tier")
         if args.tp > 1:
             ap.error("--host-tier-bytes is incompatible with --tp > 1 "
                      "(demoted page slices would need a cross-shard "
@@ -322,11 +316,10 @@ def main(argv=None):
     if args.disagg_prompt_threshold < 1:
         ap.error(f"--disagg-prompt-threshold must be >= 1, got "
                  f"{args.disagg_prompt_threshold}")
-    if args.fleet_prefix and (args.no_prefix_cache
-                              or args.no_chunked_prefill):
+    if args.fleet_prefix and args.no_prefix_cache:
         ap.error("--fleet-prefix needs the prefix cache (pulled blocks "
                  "are keyed by its rolling-hash chain) — drop "
-                 "--no-prefix-cache/--no-chunked-prefill")
+                 "--no-prefix-cache")
 
     tokenizer = None
     if args.vocab:
@@ -345,12 +338,9 @@ def main(argv=None):
     # a model whose state is not K/V blocks (EVA) says in one sentence what
     # it does not serve with, before any weights are made
     refusal = refuse_windowed(
-        model, prefix_cache=not (args.no_prefix_cache
-                                 or args.no_chunked_prefill),
+        model, prefix_cache=not args.no_prefix_cache,
         spec=args.spec != "off", tp=args.tp, sp=args.sp,
-        host_tier_bytes=args.host_tier_bytes, kv_dtype=args.kv_dtype,
-        chunked_prefill=not args.no_chunked_prefill,
-        decode_path=args.decode_path)
+        host_tier_bytes=args.host_tier_bytes, kv_dtype=args.kv_dtype)
     if refusal:
         ap.error(refusal)
 
@@ -371,10 +361,6 @@ def main(argv=None):
         if args.quant_weights:
             ap.error("--quant-weights is incompatible with --tp > 1 "
                      "(int8 weight leaves don't column-shard)")
-        if args.decode_path == "fused":
-            ap.error("--decode-path fused is incompatible with --tp > 1 "
-                     "(the fused kernel stacks whole-model weights; use "
-                     "auto, paged, or standard)")
 
     # same fail-fast treatment for an impossible SP (context mesh) config
     if args.sp > 1:
@@ -398,10 +384,6 @@ def main(argv=None):
         if args.quant_weights:
             ap.error("--quant-weights is incompatible with --sp > 1 "
                      "(int8 weight leaves re-materialize off-mesh)")
-        if args.decode_path == "fused":
-            ap.error("--decode-path fused is incompatible with --sp > 1 "
-                     "(the fused kernel assembles one chip's contiguous "
-                     "cache; use auto, paged, or standard)")
         # mirror the engine's assembly-width computation so a bad
         # max_seq_len dies here as a one-liner, not a ctor traceback
         cap = min(model.max_len, (args.num_blocks - args.sp)
@@ -452,11 +434,9 @@ def main(argv=None):
             model, params, num_blocks=args.num_blocks,
             block_size=args.block_size,
             max_batch_size=args.max_batch_size, chunk_size=args.chunk_size,
-            chunked_prefill=not args.no_chunked_prefill,
             prefix_cache=not args.no_prefix_cache,
             prefix_cache_min_hit_blocks=args.prefix_cache_min_hit_blocks,
             max_seq_len=args.max_seq_len or None,
-            decode_path=args.decode_path,
             max_queue_depth=args.max_queue_depth,
             preemption_budget=(None if args.preemption_budget < 0
                                else args.preemption_budget),
@@ -484,11 +464,9 @@ def main(argv=None):
             flight_dir=flight_dir)
 
     engine = build_engine()
-    # one line saying where this process really runs and which decode path
-    # the engine resolved: a server that landed on the CPU, interprets its
-    # kernels or fell off the paged path must not look like one that did not
-    print(f"tnn-serve: {device_line()} "
-          f"decode_path={engine.stats()['decode_path']}", file=sys.stderr)
+    # one line saying where this process really runs: a server that landed
+    # on the CPU or interprets its kernels must not look like one that did not
+    print(f"tnn-serve: {device_line()}", file=sys.stderr)
     if args.host_tier_bytes:
         print(f"host KV tier: {args.host_tier_bytes} bytes, verified "
               "re-admission (corrupt blocks degrade to uncached misses)",
@@ -502,12 +480,6 @@ def main(argv=None):
         print(f"sequence parallel: sp={args.sp}, "
               f"{engine.pool.blocks_per_shard} block(s)/shard, max context "
               f"{engine.max_seq_len} tokens over the context mesh",
-              file=sys.stderr)
-    if not engine._paged and engine.paged_fallback_reason:
-        print(f"paged decode unavailable: {engine.paged_fallback_reason}",
-              file=sys.stderr)
-    if not engine._paged and engine.fused_fallback_reason:
-        print(f"standard decode path: {engine.fused_fallback_reason}",
               file=sys.stderr)
 
     scaler = None

@@ -1,7 +1,7 @@
 """Token-sampling strategies for autoregressive generation.
 
 Greedy, temperature, top-k, and nucleus (top-p) sampling behind one factory —
-shared by models.gpt2.generate, models.fused_decode.fused_generate, and the
+shared by models.gpt2.generate and the
 serving engine (tnn_tpu/serving/engine.py). Exceeds the reference, whose
 inference loop is greedy argmax only (examples/gpt2_inference.cpp:107-119).
 

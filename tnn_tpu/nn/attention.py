@@ -461,8 +461,8 @@ class MultiHeadAttention(Module):
                     offsets, layer=0, q_lens=None):
         """One step straight against the paged KV pool.
 
-        The serving hot path (docs/serving.md): instead of assembling a
-        contiguous cache (``apply_cached`` over ``kv_pool.gather_kv``), the
+        The serving hot path (docs/serving.md): no contiguous cache is
+        assembled (that is ``apply_cached``, the offline ``generate``'s); the
         new tokens' K/V rows are scattered into their pages and attention
         streams the pages the block table names
         (``ops.pallas.paged_attention``).

@@ -5,10 +5,8 @@ each request's KV cache lives in fixed-size pages of the pool arrays
 
     pages_k, pages_v : (L, num_blocks, H_kv, block_size, head_dim)
 
-and a per-request *block table* names its pages in logical order. The old
-decode step materialized every live request's full cache contiguously
-(``serving.kv_pool.gather_kv``) before attending — O(B * T_max) HBM copies per
-token. This kernel consumes the pages DIRECTLY: the block tables and per-row
+and a per-request *block table* names its pages in logical order. This
+kernel consumes the pages DIRECTLY: the block tables and per-row
 kv lengths are scalar-prefetched, the BlockSpec index maps chase the tables,
 and flash-style online softmax accumulates over the streamed pages — so the
 only KV traffic per step is the KV actually attended over, and no contiguous

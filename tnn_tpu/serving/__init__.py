@@ -18,8 +18,7 @@ from ..utils import compile_cache
 from .autoscaler import Autoscaler
 from .engine import InferenceEngine
 from .faults import EngineCrash, FaultInjected, FaultPlan
-from .kv_pool import (PagedKVPool, PoolExhausted, gather_kv, scatter_prefill,
-                      scatter_token)
+from .kv_pool import PagedKVPool, PoolExhausted
 from .kv_tier import HostKVTier
 from .metrics import (ServingMetrics, label_series, merge_series,
                       render_prometheus)
@@ -34,8 +33,8 @@ from .supervisor import EngineSupervisor, ShuttingDown, SupervisorState
 from .tracing import FlightRecorder, Tracer, span_name
 
 __all__ = [
-    "InferenceEngine", "PagedKVPool", "PoolExhausted", "gather_kv",
-    "scatter_prefill", "scatter_token", "ServingMetrics", "PrefixCache",
+    "InferenceEngine", "PagedKVPool", "PoolExhausted", "ServingMetrics",
+    "PrefixCache",
     "Request", "RequestState", "Scheduler", "StepPlan", "AdmissionRejected",
     "TERMINAL_STATES", "FaultPlan", "FaultInjected", "EngineCrash",
     "EngineSupervisor", "SupervisorState", "ShuttingDown",

@@ -7,42 +7,32 @@ Ties the subsystem together:
         (up to chunk_size prompt tokens each) into a single compiled program
       --> streamed tokens / finished requests
 
-Chunked prefill (the default; ``chunked_prefill=False`` restores the retired
-whole-prompt path): prompts are pushed ``chunk_size`` tokens at a time,
+Chunked prefill: prompts are pushed ``chunk_size`` tokens at a time,
 co-scheduled with the decode rows inside the same ``token_budget``, so a long
 prompt arriving mid-stream never stalls decoding requests for a whole
 prompt-length forward pass — the dominant TTFT/latency tail under mixed load.
 Partially-prefilled requests persist their progress in pool blocks and take
 their next chunk on later steps without recompute. Steps with no chunk work
-delegate to the SAME pure-decode program as before chunking existed, so
-decode streams are bit-identical.
+run the pure-decode program.
 
 Static-shape discipline (the whole point on XLA backends): the mixed step is
 compiled per (max_batch_size, chunk-width bucket, assembly_width) with chunk
 widths bucketed to powers of two — requests joining or leaving the batch
 never retrace; absent rows are padded onto the pool's scratch block and
 masked by the per-row q_lens/offsets (padding tokens write their KV to the
-scratch page and output garbage that is never read). In legacy whole-prompt
-mode, prefill pads prompts up to a block multiple, so prompt-length buckets
-(not exact lengths) key its jit cache; either way N distinct prompt lengths
-cost O(log N) compiles.
+scratch page and output garbage that is never read), so N distinct prompt
+lengths cost O(log N) compiles.
 
-Decode-path selection: ``decode_path="auto"`` probes the PAGED path first —
-``model.apply_decode_paged`` over the ragged paged-attention kernel
-(ops/pallas/paged_attention.py), which consumes the pool's pages + block
-tables directly with no assembled cache and no ``gather_kv`` in the step
-trace. When the model can't take it (reason in ``paged_fallback_reason``),
-auto falls back to probing the fused one-launch Pallas kernel
-(models.fused_decode) — needs decode-quantized params, no MoE/GQA/int8-cache,
-a VMEM-fitting geometry, and a lockstep batch (all rows at one offset; ragged
-steps drop to standard within the same run, reason in
-``fused_fallback_reason``) — and finally to the standard assembled-cache
-path. All paths read and write the same paged pool; the pool buffers are
-DONATED through every jitted step (prefill and all decode paths), so XLA
-updates pages in place instead of copying the pool each token. See
-docs/serving.md for the full decode-path matrix.
+One serving path: every step program (``tnn_serve_decode``,
+``tnn_serve_mixed_w<w>``, ``tnn_serve_spec_w<w>``) runs the model's
+``apply_decode_paged`` / ``apply_paged`` over the ragged paged-attention
+kernel (ops/pallas/paged_attention.py), which consumes the pool's pages and
+block tables directly: no cache is ever assembled. A model without those
+methods is refused at start-up. The pool buffers are DONATED through every
+jitted step, so XLA updates pages in place instead of copying the pool each
+token.
 
-Automatic prefix caching (default on with chunked prefill; docs/serving.md
+Automatic prefix caching (default on; docs/serving.md
 has the full design): admission probes a content-addressed block index
 (serving/prefix_cache.py) with the request's prompt; matched blocks are
 ``fork``ed into its block table — their tokens are already-resident KV and
@@ -53,9 +43,8 @@ LRU instead of the free list and are reclaimed on demand, so the cache never
 reduces effective capacity. A fully-cached prompt keeps its last token out of
 the match (the recomputed tail produces the first-token logits) and takes a
 copy-on-write clone of the block that token writes into — indexed blocks are
-immutable. With the cache off (or legacy whole-prompt mode, which scatters
-whole prefills over its table and so cannot share blocks) behaviour and
-output streams are unchanged; with it on, outputs stay token-exact because
+immutable. With the cache off behaviour and output streams are unchanged;
+with it on, outputs stay token-exact because
 matched KV is bit-identical to what the skipped prefill would have written.
 
 Speculative decoding (``spec="ngram"`` / ``"draft"`` / a custom
@@ -66,7 +55,7 @@ scoring primitive chunked prefill already compiled — and one forward
 verifies all of them. Greedy rows accept the longest draft prefix matching
 the per-position argmax; stochastic rows run standard rejection sampling
 against the filtered target distribution. Accepted tokens commit through the
-existing chunk scatter; rejected tails roll back by truncating the row's
+mixed step's page write; rejected tails roll back by truncating the row's
 block table to its verified length (``pool.truncate``). Greedy output
 streams are token-exact vs spec-off by construction — every committed token
 is one the sequential decode would have produced — and unverified draft KV
@@ -104,7 +93,6 @@ import numpy as np
 
 from ..models import sampling
 from ..profiling.profiler import EventType, Profiler
-from ..utils.bucketing import pow2_bucket
 from . import kv_pool as kv_pool_lib
 from . import spec_decode
 from . import step_build
@@ -128,8 +116,7 @@ def _splice_draft_row(toks, draft, row):
 
 
 def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
-                    host_tier_bytes=0, kv_dtype="f32", chunked_prefill=True,
-                    decode_path="auto") -> Optional[str]:
+                    host_tier_bytes=0, kv_dtype="f32") -> Optional[str]:
     """One sentence saying why ``model`` does not serve with the first of
     these options that is on, or None. A model whose state is not "K and V
     of every position" (EVA: an exact window beside chunk summaries) runs on
@@ -152,12 +139,7 @@ def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
             (bool(host_tier_bytes), "the host KV tier: it demotes "
              "prefix-cache blocks"),
             (kv_dtype == "int8", "int8 pages (kv_dtype): summaries are "
-             "written in the compute dtype"),
-            (not chunked_prefill, "whole-prompt prefill: the window and "
-             "its summaries are built chunk by chunk"),
-            (decode_path not in ("auto", "paged"),
-             f"decode_path={decode_path!r}: there is no assembled cache of "
-             "two kinds of state")):
+             "written in the compute dtype")):
         if on:
             return (f"{type(model).__name__} keeps an exact window of "
                     f"{window} positions beside chunk summaries, not K/V "
@@ -205,18 +187,12 @@ class InferenceEngine:
     token_budget : per-step cap on model tokens (decodes + prompt chunks).
     chunk_size : prompt tokens a request may push per mixed step (chunk
         widths are bucketed to powers of two for compile-cache boundedness).
-    chunked_prefill : False restores the legacy whole-prompt prefill path
-        (one bucketed prefill program per admitted prompt, decode separate).
-    prefix_cache : automatic prefix caching (requires chunked prefill; the
-        legacy path scatters whole prefills over its table, so it cannot
-        share blocks and silently runs uncached). False disables matching,
+    prefix_cache : automatic prefix caching. False disables matching,
         publishing, and the evictable pool entirely.
     prefix_cache_min_hit_blocks : ignore cache matches shorter than this
         many full blocks (a tiny hit still costs a fork + index churn).
     max_seq_len : per-request position cap (prompt + generated); defaults to
         the smaller of model.max_len and the pool's whole capacity.
-    decode_path : "auto" | "standard" | "fused" | "paged" (see module
-        docstring and docs/serving.md).
     max_queue_depth : bounded admission — waiting requests beyond this make
         ``submit`` apply backpressure (0 = unbounded).
     admission_policy : "reject" (submit raises ``AdmissionRejected``) or
@@ -230,8 +206,8 @@ class InferenceEngine:
     spec : speculative decoding — "off", "ngram" (self-speculative n-gram
         lookup over each row's own context), "draft" (a small stand-in model
         proposes; needs ``draft_model``/``draft_params``), or any
-        ``spec_decode.Drafter`` instance. Requires chunked prefill (the
-        mixed step is the verification primitive).
+        ``spec_decode.Drafter`` instance (the mixed step is the
+        verification primitive).
     spec_k : max drafted tokens per decode row per step (the verified step
         scores ``k+1`` positions).
     draft_model, draft_params : the stand-in model for ``spec="draft"``;
@@ -262,7 +238,7 @@ class InferenceEngine:
         begin/finish around the deferred work and may speculatively
         dispatch step N+1 from predicted row states before step N commits
         (``try_speculate``; mispredictions roll back and rebuild).
-        Token-exact vs overlap-off on every decode path — a direct
+        Token-exact vs overlap-off — a direct
         ``step()`` call stays fully synchronous either way. Default off;
         ``tnn-serve`` turns it on (``--no-overlap`` opts out).
     device : the one device this engine's params, pool and step inputs live
@@ -273,10 +249,10 @@ class InferenceEngine:
     def __init__(self, model, params, *, num_blocks: int = 64,
                  block_size: int = 16, max_batch_size: int = 8,
                  token_budget: int = 2048, chunk_size: int = 64,
-                 chunked_prefill: bool = True, prefix_cache: bool = True,
+                 prefix_cache: bool = True,
                  prefix_cache_min_hit_blocks: int = 1,
                  max_seq_len: Optional[int] = None,
-                 decode_path: str = "auto", max_queue_depth: int = 0,
+                 max_queue_depth: int = 0,
                  admission_policy: str = "reject",
                  preemption_budget: Optional[int] = 16,
                  migration_budget: Optional[int] = 3,
@@ -296,8 +272,7 @@ class InferenceEngine:
         if kv_dtype not in ("f32", "int8"):
             raise ValueError(f"kv_dtype must be 'f32' or 'int8', "
                              f"got {kv_dtype!r}")
-        if decode_path not in ("auto", "standard", "fused", "paged"):
-            raise ValueError(f"unknown decode_path {decode_path!r}")
+        self._probe_paged(model)
         if admission_policy not in ("reject", "block"):
             raise ValueError(
                 f"unknown admission_policy {admission_policy!r}")
@@ -313,11 +288,11 @@ class InferenceEngine:
             raise ValueError("prefix_cache_min_hit_blocks must be >= 1")
         if host_tier_bytes < 0:
             raise ValueError("host_tier_bytes must be >= 0 (0 = no tier)")
-        if host_tier_bytes and not (prefix_cache and chunked_prefill):
+        if host_tier_bytes and not prefix_cache:
             raise ValueError(
                 "host_tier_bytes requires the prefix cache (tier entries "
-                "are addressed by its chain keys) — enable prefix_cache "
-                "and chunked_prefill, or set host_tier_bytes=0")
+                "are addressed by its chain keys) — enable prefix_cache, or "
+                "set host_tier_bytes=0")
         if host_tier_bytes and tp > 1:
             raise ValueError(
                 "host_tier_bytes with tp>1 is unsupported — demoted page "
@@ -358,13 +333,8 @@ class InferenceEngine:
         elif spec != "off":
             raise ValueError(f"unknown spec {spec!r} (off | ngram | draft | "
                              "a spec_decode.Drafter)")
-        if self.drafter is not None:
-            if not chunked_prefill:
-                raise ValueError(
-                    "speculative decoding requires chunked_prefill — the "
-                    "ragged mixed step is its verification primitive")
-            if self.spec_k < 1:
-                raise ValueError("spec_k must be >= 1")
+        if self.drafter is not None and self.spec_k < 1:
+            raise ValueError("spec_k must be >= 1")
         self.max_queue_depth = int(max_queue_depth)
         self.admission_policy = admission_policy
         self.preemption_budget = preemption_budget
@@ -379,10 +349,9 @@ class InferenceEngine:
         # pool of two kinds of page; what assumes K/V blocks is refused
         window = getattr(model, "window", None)
         refusal = refuse_windowed(
-            model, prefix_cache=prefix_cache and chunked_prefill,
+            model, prefix_cache=prefix_cache,
             spec=self.drafter is not None, tp=tp, sp=sp,
-            host_tier_bytes=host_tier_bytes, kv_dtype=kv_dtype,
-            chunked_prefill=chunked_prefill, decode_path=decode_path)
+            host_tier_bytes=host_tier_bytes, kv_dtype=kv_dtype)
         if refusal:
             raise ValueError(refusal)
         # tensor parallelism: tp > 1 shards attention heads and the paged
@@ -489,8 +458,8 @@ class InferenceEngine:
         }
         cap = min(model.max_len, self.pool.token_capacity)
         self.max_seq_len = min(max_seq_len or cap, cap)
-        # fixed assembly width: every decode step gathers this many blocks per
-        # row (padded with scratch), so ONE compile covers all batch states
+        # fixed table width: every step's block table has this many entries
+        # per row (padded with scratch), so ONE compile covers all batch states
         self.blocks_per_seq = self.pool.table_width(self.max_seq_len)
         if self.sp > 1 and self.blocks_per_seq % self.sp:
             raise ValueError(
@@ -501,13 +470,12 @@ class InferenceEngine:
                 f"ceil(max_seq_len / block_size) is a multiple of sp")
         self.assembly_len = self.blocks_per_seq * block_size
         self.chunk_size = int(chunk_size)
-        self.chunked_prefill = bool(chunked_prefill)
         self.scheduler = Scheduler(
             max_batch_size=max_batch_size, token_budget=token_budget,
-            chunk_size=self.chunk_size if self.chunked_prefill else 0,
+            chunk_size=self.chunk_size,
             spec_tokens=self.spec_k if self.drafter is not None else 0)
         self.prefix_cache: Optional[PrefixCache] = None
-        if prefix_cache and self.chunked_prefill:
+        if prefix_cache:
             self.prefix_cache = PrefixCache(
                 block_size=block_size,
                 min_hit_blocks=prefix_cache_min_hit_blocks)
@@ -576,76 +544,20 @@ class InferenceEngine:
         # jax.device_get, so any implicit transfer left on the step path
         # raises instead of silently stalling the pipeline.
         self.debug_sync = os.environ.get("TNN_DEBUG_SYNC", "") == "1"
+        # always None: there is one serving path and nothing to fall back
+        # from. Kept because the benchmark reads it
+        # (chipbench/drivers/serve_stdin.py:277-278).
         self.paged_fallback_reason: Optional[str] = None
-        self.fused_fallback_reason: Optional[str] = None
-        self._paged = False
-        self._fused: Optional[Dict[str, Any]] = None
-        # auto probes paged first: it handles ragged batches natively (the
-        # common continuous-batching state) and never assembles a cache
-        if decode_path in ("auto", "paged"):
-            try:
-                self._probe_paged()
-                self._paged = True
-            except ValueError as e:
-                if decode_path == "paged":
-                    raise
-                self.paged_fallback_reason = str(e)
-        else:
-            self.paged_fallback_reason = f"disabled (decode_path={decode_path!r})"
-        if self._paged:
-            self.fused_fallback_reason = "unused (paged decode path selected)"
-        elif decode_path in ("auto", "fused"):
-            try:
-                self._fused = self._probe_fused(max_batch_size)
-            except ValueError as e:
-                if decode_path == "fused":
-                    raise
-                self.fused_fallback_reason = str(e)
-        else:
-            self.fused_fallback_reason = f"disabled (decode_path={decode_path!r})"
 
-    # -- decode-path probes ---------------------------------------------------
-
-    def _probe_paged(self) -> None:
-        """Validate the paged decode path against this model; raises
-        ValueError (with the reason) when auto must fall back."""
-        if not hasattr(self.model, "apply_decode_paged"):
+    @staticmethod
+    def _probe_paged(model) -> None:
+        """The start-up check of the one serving path: the step programs
+        call the model's paged methods and nothing else."""
+        if not hasattr(model, "apply_decode_paged"):
             raise ValueError(
-                f"{type(self.model).__name__} has no apply_decode_paged — "
-                "the paged path needs the model to decode straight against "
-                "pool pages (see GPT2.apply_decode_paged)")
-
-    def _probe_fused(self, batch: int) -> Dict[str, Any]:
-        """Validate the fused decode kernel against this model/params; raises
-        ValueError (with the reason) when the standard path must be used."""
-        if self.kv_dtype == "int8":
-            raise ValueError(
-                "fused decode assembles a contiguous compute-dtype cache — "
-                "int8 pages would dequantize outside the kernel with no "
-                "bandwidth win; int8 pools use the paged or standard path")
-        if self.tp > 1:
-            raise ValueError(
-                "fused decode stacks whole-model weights into one kernel "
-                "invocation — head-sharded TP params cannot stack; tp>1 "
-                "serves the paged or standard path")
-        if self.sp > 1:
-            raise ValueError(
-                "fused decode assembles one chip's contiguous cache — a "
-                "block-sharded SP pool has no single-chip cache to "
-                "assemble; sp>1 serves the paged or standard path")
-        from ..models import fused_decode
-
-        chunks = fused_decode.pick_chunks(
-            self.model.d_model, 4 * self.model.d_model, batch,
-            self.assembly_len)
-        if chunks is None:
-            raise ValueError("model too large for the fused kernel's VMEM "
-                             "budget at this batch/assembly geometry")
-        from ..ops.pallas.runtime import interpret_default
-
-        stacks = fused_decode.stack_decode_weights(self.model, self.params)
-        return {"stacks": stacks, "chunks": chunks,
-                "interpret": interpret_default()}
+                f"{type(model).__name__} has no apply_decode_paged: serving "
+                "decodes straight against pool pages (see "
+                "nn/transformer.PagedDecoder)")
 
     # -- request lifecycle ----------------------------------------------------
 
@@ -781,9 +693,9 @@ class InferenceEngine:
             "prefix_publish_suspended_now": (
                 self.prefix_cache is not None
                 and self.pool.occupancy > self.prefix_publish_max_occupancy),
-            "decode_path": ("paged" if self._paged
-                            else "fused" if self._fused is not None
-                            else "standard"),
+            # the one serving path; the key stays because the benchmark
+            # reads it (chipbench/drivers/serve_stdin.py:277-278)
+            "decode_path": "paged",
             "compiled_step_signatures": len(self._jit),
             "step_seq": self.step_seq,
             "spec": self.spec_mode,
@@ -899,9 +811,9 @@ class InferenceEngine:
         """``(pages, heads)`` of a grid step of the paged kernel in the step
         program ``qw`` tokens wide: what the kernel's own launch derives
         from the same shapes (a TP shard's pool holds ``1 / tp`` of the
-        heads). None where that kernel is not the step's attention (the
-        gather paths, a windowed model)."""
-        if not self._paged or self.pool.window:
+        heads). None where that kernel is not the step's attention (a
+        windowed model)."""
+        if self.pool.window:
             return None
         group = self._attn_groups.get(qw)
         if group is None:
@@ -971,8 +883,7 @@ class InferenceEngine:
 
     def step(self) -> Dict[str, List]:
         """Run one serving step: expire deadlines, admit, then one mixed
-        prefill+decode step (or, in legacy whole-prompt mode, per-prompt
-        prefills followed by one batched decode).
+        prefill+decode step.
 
         Returns the streamed increment this step produced::
 
@@ -1063,7 +974,7 @@ class InferenceEngine:
         self._last_step_latency_s = flight.latency_s
         # per-request stall attribution: a decode-phase row that survived the
         # step without committing a token spent the whole step stalled
-        # (behind peer prefills in legacy mode, a retried fault, ...)
+        # (a retried fault, ...)
         for r in self.scheduler.running:
             if r.state is RequestState.RUNNING and \
                     r.num_generated == flight.gen_before.get(r.rid, -1):
@@ -1160,8 +1071,7 @@ class InferenceEngine:
     def _note_program(self, kind: str, key, rids: List[int],
                       fill: float) -> None:
         """Attach one launched compiled program to the current step's
-        flight record (a legacy step may launch several prefills + a
-        decode; a mixed step launches exactly one)."""
+        flight record."""
         if self._step_note is not None:
             self._step_note["programs"].append(
                 {"kind": kind, "compile_key": list(key), "rids": list(rids),
@@ -1239,35 +1149,17 @@ class InferenceEngine:
             self.faults.on_step()
         self._enforce_deadlines(events)
         plan = self.scheduler.schedule(self.pool)
-        if self.scheduler.chunk_size:
-            chunks = dict(plan.chunks)
-            for req in plan.prefills:
-                if not self._admit_chunked(req, events):
-                    chunks.pop(req.rid, None)
-                elif req.rid in chunks:
-                    # the grant was budgeted against the scheduler's cache
-                    # probe; clamp to the tail actually left after the fork
-                    # (a COW alloc fault may have fallen back to uncached)
-                    chunks[req.rid] = min(chunks[req.rid],
-                                          req.prefill_len - req.cache_len)
-            self._mixed_build(chunks, flight)
-        else:
-            # legacy whole-prompt mode: prefills dispatch alongside the
-            # decode launch and commit from the same fetch bundle — a row
-            # admitted this step takes its first decode token NEXT step
-            # (final outputs are unchanged; only step attribution moves)
-            for req in plan.prefills:
-                rec = self._prefill_build(req, events)
-                if rec is not None:
-                    flight.recs.append(rec)
-            self._ensure_decode_capacity(events)
-            live = [r for r in self.scheduler.running
-                    if r.state is RequestState.RUNNING
-                    and r.cache_len >= r.prefill_len]
-            if live:
-                rec = self._decode_build(live, events)
-                if rec is not None:
-                    flight.recs.append(rec)
+        chunks = dict(plan.chunks)
+        for req in plan.prefills:
+            if not self._admit_chunked(req, events):
+                chunks.pop(req.rid, None)
+            elif req.rid in chunks:
+                # the grant was budgeted against the scheduler's cache
+                # probe; clamp to the tail actually left after the fork
+                # (a COW alloc fault may have fallen back to uncached)
+                chunks[req.rid] = min(chunks[req.rid],
+                                      req.prefill_len - req.cache_len)
+        self._mixed_build(chunks, flight)
 
     def _commit_step(self, flight: "StepInFlight") -> None:
         """The commit phase: ONE batched fetch of the step's small
@@ -1323,27 +1215,17 @@ class InferenceEngine:
         return out
 
     def _commit_rec(self, rec: Dict[str, Any], out, events) -> None:
-        kind = rec["kind"]
-        if kind == "prefill":
-            self._prefill_commit(rec, out, events)
-        elif kind == "decode":
+        if rec["kind"] == "decode":
             self._decode_commit(rec, out, events)
         else:
             self._mixed_commit(rec, out, events)
 
     def _abort_flight(self, flight: "StepInFlight", error: str) -> None:
         """Bundle-fetch failure: unattributable to one row, so every row
-        the flight touched fails (legacy prefill rows not yet admitted
-        included) and the pool pages are recovered."""
+        the flight touched fails and the pool pages are recovered."""
         rows: List[Request] = []
         for rec in flight.recs:
-            if rec["kind"] == "prefill":
-                req = rec["req"]
-                if req.state not in TERMINAL_STATES:
-                    self._terminate(req, RequestState.FAILED, error,
-                                    flight.events, "failed")
-            else:
-                rows.extend(rec.get("live") or rec.get("rows") or [])
+            rows.extend(rec.get("live") or rec.get("rows") or [])
         self._abort_batch(rows, error, flight.events)
 
     def _mark_dispatch(self) -> None:
@@ -1396,8 +1278,7 @@ class InferenceEngine:
                 or flight.spec is not None or self.faults is not None
                 or self.drafter is not None or self.scheduler.waiting
                 or len(flight.recs) != 1
-                or flight.recs[0]["kind"] != "decode"
-                or not (self._paged or self._fused is None)):
+                or flight.recs[0]["kind"] != "decode"):
             return False
         rec = flight.recs[0]
         live = rec["live"]
@@ -1432,15 +1313,13 @@ class InferenceEngine:
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            paged=self._paged, fused_available=False, speculative=True,
-            sum_at=self.pool.exact_width)
+            speculative=True, sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.offsets)
         b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
-        label = "decode_paged" if self._paged else "decode"
+        label = "decode_paged"
         fn = self._jit.get(key)
         if fn is None:
-            fn = self._jit[key] = (self._paged_decode_fn(b, nb)
-                                   if self._paged else self._decode_fn(b, nb))
+            fn = self._jit[key] = self._paged_decode_fn(b, nb)
         step_key = self._step_key()
         t0 = time.perf_counter()
         prev_tok = rec["dev"][0]     # step N's unfetched sampled tokens
@@ -1604,7 +1483,7 @@ class InferenceEngine:
         return {rid: list(r.out_tokens) for rid, r in self.requests.items()
                 if r.state is RequestState.FINISHED}
 
-    # -- prefill --------------------------------------------------------------
+    # -- admission ------------------------------------------------------------
 
     def _next_key(self):
         self._key, sub = jax.random.split(self._key)
@@ -1615,127 +1494,6 @@ class InferenceEngine:
         elif self._sp is not None:
             sub = self._sp.put_replicated(sub)
         return sub
-
-    def _prefill_fn(self, padded_len: int, nb: int):
-        model = self._step_model
-
-        def fn(params, pages_k, pages_v, ids, length, blocks, t, k, p, key,
-               poison):
-            caches = model.init_cache(1, padded_len)
-            logits, caches = model.apply_cached(params, ids, caches, 0)
-            last = jnp.take(logits[0], length - 1, axis=0) + poison  # (V,)
-            ok = jnp.isfinite(last).all()
-            tok = sampling.sample_ragged(last[None], key, t[None], k[None],
-                                         p[None])[0]
-            k_all = jnp.stack([c["k"][0] for c in caches])      # (L, H, P, Dh)
-            v_all = jnp.stack([c["v"][0] for c in caches])
-            pages_k = kv_pool_lib.scatter_prefill(pages_k, blocks, k_all)
-            pages_v = kv_pool_lib.scatter_prefill(pages_v, blocks, v_all)
-            return tok, ok, pages_k, pages_v
-
-        # pool buffers are donated: the scatter updates pages in place
-        # instead of copying the whole pool per prefill
-        return self._jit_step("tnn_serve_prefill", fn, donate_argnums=(1, 2),
-                              n_outs=4, tables_argnum=5)
-
-    def _prefill_build(self, req: Request, events) -> Optional[Dict[str, Any]]:
-        """Legacy whole-prompt prefill, build/dispatch half: allocate the
-        prompt's blocks, launch the bucketed prefill program, adopt its
-        pages. Returns the flight record whose device refs
-        ``_prefill_commit`` consumes — or None when the row failed."""
-        t0 = time.perf_counter()
-        seq = req.resume_tokens
-        bs = self.pool.block_size
-        nb = self.pool.blocks_for(len(seq))
-        if nb > self.blocks_per_seq:
-            # unreachable via submit()'s validation (resume <= prompt +
-            # max_new), but a corrupted resume must not poison the batch
-            self._terminate(
-                req, RequestState.FAILED,
-                f"oversized resume: {len(seq)} tokens need {nb} blocks > "
-                f"assembly capacity {self.blocks_per_seq}", events, "failed")
-            return None
-        try:
-            if self.faults is not None:
-                self.faults.on_prefill()
-            req.block_table = self.pool.alloc(nb)
-        except (PoolExhausted, FaultInjected) as e:
-            self._terminate(req, RequestState.FAILED,
-                            f"prefill failed: {e}", events, "failed")
-            return None
-        # bucket the COMPILED width to the next power of two (capped at the
-        # assembly width) so N distinct prompt lengths cost O(log N) compiles,
-        # not one each; only the nb real blocks are allocated — the bucket's
-        # tail rows scatter into the reserved scratch block and vanish
-        nb_bucket = pow2_bucket(nb, cap=self.blocks_per_seq)
-        padded = nb_bucket * bs
-        blocks = req.block_table
-        ids = np.zeros((1, padded), np.int32)
-        ids[0, :len(seq)] = seq
-        poison = np.float32("nan") if (
-            self.faults is not None and self.faults.poison_prefill()
-        ) else np.float32(0.0)
-        key = ("prefill", padded) + self._kv_key
-        self._note_program("prefill", key, [req.rid],
-                           fill=len(seq) / padded)
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = self._jit[key] = self._prefill_fn(padded, nb_bucket)
-        try:
-            self._mark_dispatch()
-            with self._dispatch_span("prefill", key):
-                tok, ok, pk, pv = fn(
-                    self.params, self.pool.pages_k, self.pool.pages_v,
-                    self._put(ids), self._put(len(seq), jnp.int32),
-                    self._put_tables(self.pool.padded_table(blocks,
-                                                            nb_bucket)),
-                    self._put(req.temperature, jnp.float32),
-                    self._put(req.top_k, jnp.int32),
-                    self._put(req.top_p, jnp.float32), self._step_key(),
-                    self._put(poison))
-        except Exception as e:  # noqa: BLE001 — isolate, don't crash serving
-            self._terminate(req, RequestState.FAILED,
-                            f"prefill step failed: {e}", events, "failed")
-            self._recover_pages_if_dead(events)
-            return None
-        # pages adopted at dispatch: the decode launch sharing this step's
-        # fetch bundle consumes them next in the donation chain
-        self.pool.update_pages(pk, pv)
-        return {"kind": "prefill", "dev": (tok, ok), "req": req, "t0": t0,
-                "seq_len": len(seq)}
-
-    def _prefill_commit(self, rec: Dict[str, Any], out, events) -> None:
-        """Legacy prefill commit half: consumes the fetched (token, ok)
-        pair, admits the row, and emits its first token."""
-        req = rec["req"]
-        if req.state in TERMINAL_STATES:
-            return                      # cancelled/expired while in flight
-        tok, ok = int(out[0]), bool(out[1])
-        if self.logit_guard and not ok:
-            self._terminate(req, RequestState.FAILED,
-                            "non-finite logits in prefill", events, "failed")
-            return
-        req.cache_len = rec["seq_len"]
-        # queue wait closes at t0 (prefill launch), so the whole-prompt
-        # forward lands in prefill_s, not queued_s
-        self._note_admit(req, rec["t0"])
-        self.scheduler.admit(req)
-        now = time.perf_counter()
-        self._note_prefill_done(req, now)
-        self.metrics.observe_prefill(rec["seq_len"], now - rec["t0"])
-        if req.out_tokens:
-            # preemption recovery: the pending next_token survives; the
-            # prefill's own sample is redundant (greedy: identical) — drop it
-            pass
-        else:
-            req.next_token = tok
-            req.out_tokens.append(tok)
-            req.ttft_s = now - req.submit_time
-            self.metrics.observe_ttft(req.ttft_s)
-            self.tracer.instant("serve.first_token", trace=req.trace_id,
-                                rid=req.rid, step=self.step_seq)
-            events["tokens"].append((req.rid, tok))
-            self._maybe_finish(req, tok, events)
 
     def _admit_chunked(self, req: Request, events) -> bool:
         """Chunked admission: no device work — the request joins the running
@@ -2246,9 +2004,8 @@ class InferenceEngine:
         row takes 1 token and every mid-prefill row with a chunk grant
         pushes its next prompt chunk, all inside ONE compiled program keyed
         on the power-of-two bucket of the widest chunk. Steps with no chunk
-        work delegate to the legacy pure-decode program, so decode streams
-        are bit-identical to the pre-chunking engine. ``_mixed_commit``
-        consumes the launch's fetched bundle.
+        work run the pure-decode program. ``_mixed_commit`` consumes the
+        launch's fetched bundle.
 
         With a drafter installed, decode rows additionally carry their
         speculative lookahead as extra ragged positions (``q_len = 1 + k``)
@@ -2306,7 +2063,7 @@ class InferenceEngine:
                if r.cache_len < r.prefill_len and r.rid in chunks]
         n_spec = sum(len(drafts.get(r.rid, ())) for r in dec)
         if not chk and not n_spec:
-            # nothing ragged this step: the legacy pure-decode program is
+            # nothing ragged this step: the pure-decode program is
             # bit-identical and cheaper. Zero-draft rows still count in the
             # spec denominator so acceptance stats stay honest.
             if dec:
@@ -2339,14 +2096,9 @@ class InferenceEngine:
                            [r.rid for r in rows], fill=len(rows) / b)
         fn = self._jit.get(key)
         if fn is None:
-            if spec_on:
-                fn = self._jit[key] = (
-                    self._spec_paged_fn(b, qw, step.nb) if self._paged
-                    else self._spec_standard_fn(b, qw, step.nb))
-            else:
-                fn = self._jit[key] = (
-                    self._mixed_paged_fn(b, qw, step.nb) if self._paged
-                    else self._mixed_standard_fn(b, qw, step.nb))
+            fn = self._jit[key] = (
+                self._spec_paged_fn(b, qw, step.nb) if spec_on
+                else self._mixed_paged_fn(b, qw, step.nb))
         toks_in = self._put(step.toks)
         for i, dd in step.dev_drafts:
             # splice device-resident drafts into the token matrix without
@@ -2541,59 +2293,6 @@ class InferenceEngine:
         return self._jit_step(f"tnn_serve_mixed_w{qw}", fn,
                               donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
 
-    def _mixed_standard_fn(self, b: int, qw: int, nb: int):
-        model = self._step_model
-        # SP assembled-cache path: each shard gathers the positions it owns
-        # and a psum over the context mesh rebuilds the full replicated
-        # cache, so the cached-attention body below runs unchanged
-        sp_axis = self._step_model.sp_axis if self._sp is not None else None
-
-        def fn(params, pages_k, pages_v, toks, starts, q_lens, tables,
-               t, k, p, key, poison):
-            kf, vf = kv_pool_lib.gather_kv(
-                pages_k, pages_v, tables,
-                out_dtype=model.policy.compute_dtype, axis_name=sp_axis)
-            # pad the time axis by qw: apply_cached's per-row cache write
-            # CLAMPS its start, so a chunk ending at the assembly edge must
-            # have headroom — the padded tail is gathered back below only
-            # through scatter_chunk's q_lens mask, so it never leaks
-            pad = [(0, 0), (0, 0), (0, 0), (0, qw), (0, 0)]
-            kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
-            with jax.named_scope("embed"):
-                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                       toks)                    # (B, qw, D)
-                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                       x, offset=starts)
-            rows_k, rows_v = [], []
-            idx = (starts[:, None] + jnp.arange(qw))[:, None, :, None]
-            for i, block in enumerate(model.blocks):
-                cache = {"k": kf[i], "v": vf[i]}
-                with jax.named_scope(f"h{i}"):
-                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                                  starts)
-                rows_k.append(jnp.take_along_axis(cache["k"], idx, axis=2))
-                rows_v.append(jnp.take_along_axis(cache["v"], idx, axis=2))
-            with jax.named_scope("ln_f"):
-                x, _ = model.ln_f.apply(
-                    {"params": params["ln_f"], "state": {}}, x)
-            # project only each row's last LIVE position through the head —
-            # (B, 1, V) instead of a (B, qw, V) logits cube
-            xl = jnp.take_along_axis(
-                x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)
-            logits = model._head(params, xl)[:, 0] + poison[:, None]
-            ok = jnp.isfinite(logits).all(axis=-1)
-            newtok = sampling.sample_ragged(logits, key, t, k, p)
-            rows_k = jnp.stack(rows_k).transpose(0, 1, 3, 2, 4)  # (L,B,Q,H,Dh)
-            rows_v = jnp.stack(rows_v).transpose(0, 1, 3, 2, 4)
-            pages_k = kv_pool_lib.scatter_chunk(pages_k, tables, starts,
-                                                rows_k, q_lens)
-            pages_v = kv_pool_lib.scatter_chunk(pages_v, tables, starts,
-                                                rows_v, q_lens)
-            return newtok, ok, pages_k, pages_v
-
-        return self._jit_step(f"tnn_serve_mixed_gather_w{qw}", fn,
-                              donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
-
     # -- speculative verification ----------------------------------------------
 
     @jax.named_scope("sample")
@@ -2671,52 +2370,6 @@ class InferenceEngine:
         return self._jit_step(f"tnn_serve_spec_w{qw}", fn,
                               donate_argnums=(1, 2), n_outs=5, tables_argnum=6)
 
-    def _spec_standard_fn(self, b: int, qw: int, nb: int):
-        model = self._step_model
-        verify = self._spec_verify
-        sp_axis = self._step_model.sp_axis if self._sp is not None else None
-
-        def fn(params, pages_k, pages_v, toks, starts, q_lens, tables,
-               n_draft, t, k, p, key, poison):
-            kf, vf = kv_pool_lib.gather_kv(
-                pages_k, pages_v, tables,
-                out_dtype=model.policy.compute_dtype, axis_name=sp_axis)
-            # same assembly-edge headroom rationale as _mixed_standard_fn
-            pad = [(0, 0), (0, 0), (0, 0), (0, qw), (0, 0)]
-            kf, vf = jnp.pad(kf, pad), jnp.pad(vf, pad)
-            with jax.named_scope("embed"):
-                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                       toks)
-                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                       x, offset=starts)
-            rows_k, rows_v = [], []
-            idx = (starts[:, None] + jnp.arange(qw))[:, None, :, None]
-            for i, block in enumerate(model.blocks):
-                cache = {"k": kf[i], "v": vf[i]}
-                with jax.named_scope(f"h{i}"):
-                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                                  starts)
-                rows_k.append(jnp.take_along_axis(cache["k"], idx, axis=2))
-                rows_v.append(jnp.take_along_axis(cache["v"], idx, axis=2))
-            with jax.named_scope("ln_f"):
-                x, _ = model.ln_f.apply(
-                    {"params": params["ln_f"], "state": {}}, x)
-            # verification needs every position's logits, so the whole row
-            # goes through the head — (B, qw, V), the price of lookahead
-            logits = model._head(params, x)
-            accepts, newtok, ok = verify(logits, toks, q_lens, n_draft,
-                                         t, k, p, key, poison)
-            rows_k = jnp.stack(rows_k).transpose(0, 1, 3, 2, 4)  # (L,B,Q,H,Dh)
-            rows_v = jnp.stack(rows_v).transpose(0, 1, 3, 2, 4)
-            pages_k = kv_pool_lib.scatter_chunk(pages_k, tables, starts,
-                                                rows_k, q_lens)
-            pages_v = kv_pool_lib.scatter_chunk(pages_v, tables, starts,
-                                                rows_v, q_lens)
-            return accepts, newtok, ok, pages_k, pages_v
-
-        return self._jit_step(f"tnn_serve_spec_gather_w{qw}", fn,
-                              donate_argnums=(1, 2), n_outs=5, tables_argnum=6)
-
     def _preempt(self, req: Request) -> None:
         self._note_leave_running(req, time.perf_counter())
         self._free_blocks(req)
@@ -2726,52 +2379,12 @@ class InferenceEngine:
         self.tracer.instant("serve.preempt", trace=req.trace_id,
                             rid=req.rid, step=self.step_seq)
 
-    def _decode_fn(self, batch: int, nb: int):
-        model = self._step_model
-        sp_axis = self._step_model.sp_axis if self._sp is not None else None
-
-        def fn(params, pages_k, pages_v, toks, offsets, tables, t, k, p, key,
-               poison):
-            kf, vf = kv_pool_lib.gather_kv(
-                pages_k, pages_v, tables,
-                out_dtype=model.policy.compute_dtype, axis_name=sp_axis)
-            with jax.named_scope("embed"):
-                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                       toks[:, None])           # (B, 1, D)
-                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                       x, offset=offsets)
-            rows_k, rows_v = [], []
-            idx = offsets[:, None, None, None]
-            for i, block in enumerate(model.blocks):
-                cache = {"k": kf[i], "v": vf[i]}
-                with jax.named_scope(f"h{i}"):
-                    x, cache = block.apply_cached(params[f"h{i}"], x, cache,
-                                                  offsets)
-                rows_k.append(
-                    jnp.take_along_axis(cache["k"], idx, axis=2)[:, :, 0])
-                rows_v.append(
-                    jnp.take_along_axis(cache["v"], idx, axis=2)[:, :, 0])
-            with jax.named_scope("ln_f"):
-                x, _ = model.ln_f.apply(
-                    {"params": params["ln_f"], "state": {}}, x)
-            logits = model._head(params, x)[:, -1] + poison[:, None]  # (B, V)
-            ok = jnp.isfinite(logits).all(axis=-1)                # (B,)
-            newtok = sampling.sample_ragged(logits, key, t, k, p)
-            pages_k = kv_pool_lib.scatter_token(pages_k, tables, offsets,
-                                                jnp.stack(rows_k))
-            pages_v = kv_pool_lib.scatter_token(pages_v, tables, offsets,
-                                                jnp.stack(rows_v))
-            return newtok, ok, pages_k, pages_v
-
-        return self._jit_step("tnn_serve_decode_gather", fn,
-                              donate_argnums=(1, 2), n_outs=4, tables_argnum=5)
-
     def _paged_decode_fn(self, batch: int, nb: int):
         model = self._step_model
 
         def fn(params, pages_k, pages_v, toks, offsets, tables, t, k, p, key,
                poison):
-            # no gather_kv, no assembled cache: the model scatters each
+            # no assembled cache: the model scatters each
             # layer's new row into its page and the paged-attention kernel
             # streams KV via the block tables — per-step pool traffic is B
             # row writes plus the KV actually attended over
@@ -2785,54 +2398,6 @@ class InferenceEngine:
         return self._jit_step("tnn_serve_decode", fn, donate_argnums=(1, 2),
                               n_outs=4, tables_argnum=5)
 
-    def _fused_decode_fn(self, batch: int, nb: int):
-        model = self.model
-        fused = self._fused
-        bs = self.pool.block_size
-
-        def fn(params, stacks, pages_k, pages_v, toks, offset, tables,
-               t, k, p, key, poison):
-            from ..ops.pallas.decode_stack import fused_decode_stack
-
-            kf, vf = kv_pool_lib.gather_kv(
-                pages_k, pages_v, tables,
-                out_dtype=model.policy.compute_dtype)
-            # (L, B, H, T, Dh) -> the kernel's flat (L, B, T, D) layout
-            def flat(c):
-                l, b, h, tt, dh = c.shape
-                return c.transpose(0, 1, 3, 2, 4).reshape(l, b, tt, h * dh)
-            kc, vc = flat(kf), flat(vf)
-            with jax.named_scope("embed"):
-                x, _ = model.wte.apply({"params": params["wte"], "state": {}},
-                                       toks[:, None])
-                x, _ = model.wpe.apply({"params": params["wpe"], "state": {}},
-                                       x, offset=offset)
-            x_out, kc, vc = fused_decode_stack(
-                x[:, 0, :], offset, kc, vc, stacks,
-                num_heads=model.num_heads, chunks=fused["chunks"],
-                interpret=fused["interpret"])
-            with jax.named_scope("ln_f"):
-                xf, _ = model.ln_f.apply(
-                    {"params": params["ln_f"], "state": {}},
-                    x_out[:, None, :])
-            logits = model._head(params, xf)[:, -1] + poison[:, None]
-            ok = jnp.isfinite(logits).all(axis=-1)
-            newtok = sampling.sample_ragged(logits, key, t, k, p)
-            # extract the one new row per layer and page it back in
-            row_k = jax.lax.dynamic_slice_in_dim(kc, offset, 1, axis=2)[:, :, 0]
-            row_v = jax.lax.dynamic_slice_in_dim(vc, offset, 1, axis=2)[:, :, 0]
-            l, b, d = row_k.shape
-            h = model.num_kv_heads
-            offsets = jnp.full((b,), offset, jnp.int32)
-            pages_k = kv_pool_lib.scatter_token(
-                pages_k, tables, offsets, row_k.reshape(l, b, h, d // h))
-            pages_v = kv_pool_lib.scatter_token(
-                pages_v, tables, offsets, row_v.reshape(l, b, h, d // h))
-            return newtok, ok, pages_k, pages_v
-
-        fn.__name__ = "tnn_serve_decode_fused"
-        return jax.jit(fn, donate_argnums=(2, 3))
-
     def _check_step_writes(self, step, starts, q_lens=None) -> None:
         """TNN_POOL_DEBUG=1: hold every packed step to the one-writer
         invariant the in-place page write relies on (``q_lens`` None: the
@@ -2845,30 +2410,25 @@ class InferenceEngine:
     def _decode_build(self, live: Sequence[Request],
                       events) -> Optional[Dict[str, Any]]:
         """Pure-decode build/dispatch half: stage the batch, launch the
-        selected decode program, adopt its pages. Returns the flight
+        decode program, adopt its pages. Returns the flight
         record ``_decode_commit`` consumes — or None when the batch
         aborted."""
         t0 = time.perf_counter()
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            paged=self._paged, fused_available=self._fused is not None,
             sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.offsets)
-        b, nb, key, lockstep = step.b, step.nb, step.key, step.lockstep
+        b, nb, key = step.b, step.nb, step.key
         poison = step.poison
         if self.faults is not None:
             poison[:len(live)][self.faults.poison_rows(len(live))] = np.nan
-        label = {"pdecode": "decode_paged", "fdecode": "decode_fused",
-                 "decode": "decode"}[key[0]]
+        label = "decode_paged"
         self._note_program(label, key, [r.rid for r in live],
                            fill=len(live) / b)
         fn = self._jit.get(key)
         if fn is None:
-            fn = self._jit[key] = (
-                self._paged_decode_fn(b, nb) if self._paged
-                else self._fused_decode_fn(b, nb) if lockstep
-                else self._decode_fn(b, nb))
+            fn = self._jit[key] = self._paged_decode_fn(b, nb)
         # one key per STEP (held across the retry): a transient fault retried
         # with the same key reproduces the fault-free step bit-for-bit
         step_key = self._step_key()
@@ -2878,22 +2438,12 @@ class InferenceEngine:
                 if self.faults is not None:
                     self.faults.on_decode()
                 with self._dispatch_span(label, key, qw=1):
-                    if lockstep:
-                        newtok, ok, pk, pv = fn(
-                            self.params, self._fused["stacks"],
-                            self.pool.pages_k, self.pool.pages_v,
-                            self._put(step.toks),
-                            self._put(int(step.offsets[0]), jnp.int32),
-                            self._put_tables(step.tables), self._put(step.temps),
-                            self._put(step.topks), self._put(step.topps),
-                            step_key, self._put(poison))
-                    else:
-                        newtok, ok, pk, pv = fn(
-                            self.params, self.pool.pages_k, self.pool.pages_v,
-                            self._put(step.toks), self._put(step.offsets),
-                            self._put_tables(step.tables), self._put(step.temps),
-                            self._put(step.topks), self._put(step.topps),
-                            step_key, self._put(poison))
+                    newtok, ok, pk, pv = fn(
+                        self.params, self.pool.pages_k, self.pool.pages_v,
+                        self._put(step.toks), self._put(step.offsets),
+                        self._put_tables(step.tables), self._put(step.temps),
+                        self._put(step.topks), self._put(step.topps),
+                        step_key, self._put(poison))
                 break
             except FaultInjected as e:
                 # injected pre-call: donated buffers untouched, retryable

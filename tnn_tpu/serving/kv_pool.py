@@ -7,18 +7,17 @@ The pool owns two device arrays of fixed-size token pages per layer,
 
 plus host-side bookkeeping: a free list, and a per-block refcount so a shared
 prompt prefix can be forked (``fork``) instead of copied. Sequences hold a
-*block table* — an ordered list of block ids — and the assembly helpers below
-turn a batch of block tables into the contiguous ``(B, H, T, Dh)`` caches that
-``nn.attention.MultiHeadAttention.apply_cached`` / ``GPT2.apply_cached``
-consume, so the whole model stack is reused unchanged.
+*block table* — an ordered list of block ids — which the paged-attention
+kernel and the page write (``ops/pallas/paged_attention.py``) take as it is:
+nothing ever assembles a contiguous cache.
 
 Block 0 is RESERVED as scratch: padded rows of a ragged batch (the engine
 always decodes at a fixed batch width) point their block tables at it, so
 their garbage reads/writes land somewhere harmless instead of in live blocks.
 
-The gather/scatter helpers are pure jnp functions — they trace into the
-engine's jitted prefill/decode steps, keeping the pool device-resident; only
-the alloc/free bookkeeping lives on the host.
+The pages stay device-resident; only the alloc/free bookkeeping lives on the
+host. The whole-page helpers at the bottom (``write_block``, ``copy_blocks``)
+are pure jnp functions that trace into the engine's COW and adopt steps.
 
 Page layout contract
 --------------------
@@ -80,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.pallas.paged_attention import QuantPages, quantize_kv_rows
+from ..ops.pallas.paged_attention import QuantPages
 
 
 class PoolExhausted(RuntimeError):
@@ -797,142 +796,7 @@ class PagedKVPool:
         return list(block_table) + [self.SCRATCH] * (width - len(block_table))
 
 
-# -- jit-safe assembly (trace into the engine's compiled steps) ---------------
-
-
-@jax.named_scope("kv_gather")
-def gather_kv(pages_k, pages_v, block_tables, out_dtype=None,
-              axis_name=None):
-    """Block tables -> contiguous ragged-batch caches.
-
-    pages_*: (L, N, H, bs, Dh); block_tables: (B, nb) int32.
-    Returns two (L, B, H, nb*bs, Dh) arrays — per layer, exactly the cache
-    layout ``MultiHeadAttention.apply_cached`` reads. Positions past a row's
-    true length hold garbage; the ragged causal mask (per-row kv_offset) keeps
-    them out of the softmax.
-
-    ``out_dtype`` applies only to QuantPages: the dequantized cache is cast
-    to it (default f32) so it matches the compute dtype the downstream
-    cached-attention writes its new rows in. Plain pages ignore it — they
-    already ARE the pool dtype.
-
-    ``axis_name`` (sequence-parallel path, inside shard_map over the context
-    mesh): ``block_tables`` is this shard's LOCAL table — positions owned by
-    other shards hold ``-1``. Each shard gathers the positions it owns,
-    zeros the holes, and a ``psum`` over the mesh assembles the full
-    replicated cache: every shard ends up with the complete (L, B, H, T, Dh)
-    arrays, so the standard (assembled-cache) decode path runs unchanged
-    under SP.
-    """
-    def g(pages):
-        tbl = jnp.maximum(block_tables, 0) if axis_name is not None \
-            else block_tables
-        if isinstance(pages, QuantPages):
-            # dequant at the gather: the assembled cache is compute-dtype,
-            # so the cached-attention consumers downstream are untouched
-            l, _, h, bs, dh = pages.data.shape
-            b, nb = block_tables.shape
-            x = pages.data[:, tbl].astype(jnp.float32) \
-                * pages.scale[:, tbl]
-            x = x.astype(out_dtype or jnp.float32)
-            x = x.transpose(0, 1, 3, 2, 4, 5)
-            x = x.reshape(l, b, h, nb * bs, dh)
-        else:
-            l, _, h, bs, dh = pages.shape
-            b, nb = block_tables.shape
-            x = pages[:, tbl]                    # (L, B, nb, H, bs, Dh)
-            x = x.transpose(0, 1, 3, 2, 4, 5)    # (L, B, H, nb, bs, Dh)
-            x = x.reshape(l, b, h, nb * bs, dh)
-        if axis_name is not None:
-            dead = jnp.repeat(block_tables < 0, bs, axis=1)  # (B, nb*bs)
-            x = jnp.where(dead[None, :, None, :, None], 0, x)
-            x = jax.lax.psum(x, axis_name)
-        return x
-    return g(pages_k), g(pages_v)
-
-
-@jax.named_scope("kv_write")
-def scatter_prefill(pages, blocks, kv):
-    """Write one sequence's contiguous prefill cache into its blocks.
-
-    pages: (L, N, H, bs, Dh); blocks: (nb,) int32; kv: (L, H, nb*bs, Dh).
-    Returns the updated pages. QuantPages: rows quantize at write time;
-    data and scale scatter through identical index math. Under SP the
-    per-shard ``blocks`` carries ``-1`` for positions another shard owns;
-    those chunks are redirected to the shard's scratch page (local row 0).
-    """
-    if isinstance(pages, QuantPages):
-        qkv, skv = quantize_kv_rows(kv)
-        return QuantPages(scatter_prefill(pages.data, blocks, qkv),
-                          scatter_prefill(pages.scale, blocks, skv))
-    l, _, h, bs, dh = pages.shape
-    nb = blocks.shape[0]
-    blocks = jnp.maximum(blocks, 0)
-    x = kv.transpose(0, 2, 1, 3)                 # (L, P, H, Dh)
-    x = x.reshape(l, nb, bs, h, dh)              # (L, nb, bs, H, Dh)
-    x = x.transpose(0, 1, 3, 2, 4)               # (L, nb, H, bs, Dh)
-    return pages.at[:, blocks].set(x)
-
-
-@jax.named_scope("kv_write")
-def scatter_token(pages, block_tables, offsets, rows):
-    """Write one new KV row per sequence at its decode position.
-
-    pages: (L, N, H, bs, Dh); block_tables: (B, nb); offsets: (B,) the
-    position each row just wrote; rows: (L, B, H, Dh). Padded rows point
-    their table at SCRATCH, so their writes land in the scratch block.
-    QuantPages: rows quantize at write time.
-    """
-    if isinstance(pages, QuantPages):
-        qrows, srows = quantize_kv_rows(rows)
-        return QuantPages(scatter_token(pages.data, block_tables, offsets,
-                                        qrows),
-                          scatter_token(pages.scale, block_tables, offsets,
-                                        srows))
-    bs = pages.shape[3]
-    blk = jnp.take_along_axis(block_tables, (offsets // bs)[:, None],
-                              axis=1)[:, 0]
-    # SP: a -1 hole (position owned by another shard) lands in this shard's
-    # scratch page instead of wrapping to the pool's last block
-    blk = jnp.maximum(blk, 0)
-    slot = offsets % bs
-    # the two advanced indices (blk, slot) around sliced axes put the batch
-    # dim first in the update operand: (B, L, H, Dh)
-    return pages.at[:, blk, :, slot, :].set(rows.transpose(1, 0, 2, 3))
-
-
-@jax.named_scope("kv_write")
-def scatter_chunk(pages, block_tables, starts, rows, q_lens):
-    """Write a ragged chunk of new KV rows per sequence, all layers at once.
-
-    pages: (L, N, H, bs, Dh); block_tables: (B, nb); starts: (B,) the first
-    position each row writes; rows: (L, B, Q, H, Dh); q_lens: (B,) live
-    tokens per row. Row b's tokens t < q_lens[b] land at starts[b] + t;
-    padding tokens (t >= q_lens[b], and whole rows with q_lens == 0) are
-    redirected to SCRATCH, which is never allocated to a request. The mixed
-    prefill+decode step uses this to persist each prefill chunk's KV.
-    QuantPages: rows quantize at write time.
-    """
-    if isinstance(pages, QuantPages):
-        qrows, srows = quantize_kv_rows(rows)
-        return QuantPages(scatter_chunk(pages.data, block_tables, starts,
-                                        qrows, q_lens),
-                          scatter_chunk(pages.scale, block_tables, starts,
-                                        srows, q_lens))
-    bs = pages.shape[3]
-    qw = rows.shape[2]
-    nbt = block_tables.shape[1]
-    pos = starts[:, None] + jnp.arange(qw)                # (B, Q)
-    live = jnp.arange(qw)[None, :] < q_lens[:, None]      # (B, Q)
-    blk = jnp.take_along_axis(block_tables,
-                              jnp.clip(pos // bs, 0, nbt - 1), axis=1)
-    # dead tokens AND -1 holes (SP positions owned by another shard) are
-    # both redirected to the scratch page
-    blk = jnp.maximum(jnp.where(live, blk, PagedKVPool.SCRATCH), 0)
-    slot = pos % bs
-    # advanced (blk, slot) indices broadcast to (B, Q) and lead the update
-    # operand: (B, Q, L, H, Dh)
-    return pages.at[:, blk, :, slot, :].set(rows.transpose(1, 2, 0, 3, 4))
+# -- whole-page writes (trace into the engine's compiled COW/adopt steps) ----
 
 
 @jax.named_scope("kv_write")

@@ -5,7 +5,8 @@ a profiler.)
 
 Two exposition surfaces share one observation path:
 
-- ``summary()`` — the flat dict benchmarks and ``GET /v1/stats`` report.
+- ``summary()`` — the flat dict ``engine.stats()`` and ``GET /v1/stats``
+  report.
 - ``prometheus_series()`` — counter/gauge/histogram families rendered by
   ``render_prometheus`` into text-format 0.0.4 for ``GET /metrics``; the
   Router merges per-replica families under a ``replica`` label.
@@ -60,9 +61,6 @@ EXPOSITION: Dict[str, Tuple[str, str, str, str]] = {
     "serve.queue_wait_s": (
         "tnn_serve_queue_wait_seconds", "histogram",
         "Time spent QUEUED before (each) admission", "queue_wait_ms_p50"),
-    "serve.prefill_s": (
-        "tnn_serve_prefill_seconds_total", "counter",
-        "Cumulative prefill wall seconds", "prefill_tokens"),
     "serve.prefill_chunks": (
         "tnn_serve_prefill_chunks_total", "counter",
         "Prompt chunks pushed inside mixed steps", "prefill_chunks"),
@@ -538,11 +536,6 @@ class ServingMetrics:
             self.ttft_under_load_s.append(seconds)
         self._tick("serve.ttft_s", seconds)
 
-    def observe_prefill(self, num_tokens: int, seconds: float) -> None:
-        self._mark()
-        self.prefill_tokens += num_tokens
-        self._tick("serve.prefill_s", seconds)
-
     def observe_prefill_chunk(self, num_tokens: int) -> None:
         """One prompt chunk pushed inside a mixed step."""
         self._mark()
@@ -577,7 +570,7 @@ class ServingMetrics:
 
     def observe_decode_stall(self, seconds: float) -> None:
         """Wall gap between consecutive steps that emitted decode-phase
-        tokens — what a whole-prompt prefill inflates and chunking bounds."""
+        tokens — what chunked prefill bounds."""
         self.decode_stall_s.append(seconds)
         self._tick("serve.decode_stall_s", seconds)
 
@@ -884,7 +877,8 @@ class ServingMetrics:
                    if s > self.slo_stall_s)
 
     def summary(self) -> Dict[str, float]:
-        """One flat dict — the shape benchmarks/serve_bench.py reports.
+        """One flat dict: what ``engine.stats()`` and the ``tnn-serve``
+        summary line report.
 
         Every aggregate is NaN-safe and defined on empty series (0.0), so a
         run with zero decode steps — e.g. every prompt fully served from the

@@ -160,26 +160,27 @@ class Request:
 
 @dataclass
 class StepPlan:
+    #: requests admitted this step (they join the running set at once and
+    #: push their first chunk in the same step)
     prefills: List[Request]
     decodes: List[Request]
     #: rid -> live tokens to push this step for rows still mid-prefill
-    #: (empty in legacy whole-prompt mode)
     chunks: Dict[int, int] = field(default_factory=dict)
 
 
 class Scheduler:
     """FCFS continuous batching over a PagedKVPool.
 
-    ``token_budget`` caps the model tokens processed per step (decode steps
-    cost 1 per running request and take priority; prefills fill the rest).
-    A prompt longer than the whole budget is still admitted when it is the
-    only work — otherwise it could never start.
+    ``token_budget`` caps the model tokens processed per step (a decode row
+    costs 1 and takes priority; prompt chunks fill the rest). A request is
+    still admitted with no budget left when it is the only work — otherwise
+    it could never start.
 
-    ``chunk_size`` > 0 switches to Sarathi-style chunked prefill: prompts
-    enter the running set immediately and push at most ``chunk_size`` prompt
-    tokens per step, co-scheduled with the decode rows inside the same token
-    budget, so a long prompt never stalls the decode stream for a whole
-    prompt-length forward pass. 0 keeps the legacy whole-prompt admission.
+    ``chunk_size``: Sarathi-style chunked prefill. Prompts enter the running
+    set immediately and push at most ``chunk_size`` prompt tokens per step,
+    co-scheduled with the decode rows inside the same token budget, so a
+    long prompt never stalls the decode stream for a whole prompt-length
+    forward pass.
 
     ``spec_tokens`` > 0 (the engine sets it when speculative decoding is on)
     charges each decode-phase row ``1 + spec_tokens`` budget per step: a
@@ -190,11 +191,11 @@ class Scheduler:
     """
 
     def __init__(self, max_batch_size: int = 8, token_budget: int = 2048,
-                 chunk_size: int = 0, spec_tokens: int = 0):
+                 chunk_size: int = 64, spec_tokens: int = 0):
         if max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
-        if chunk_size < 0:
-            raise ValueError("chunk_size must be >= 0")
+        if chunk_size < 1:
+            raise ValueError("chunk_size must be >= 1")
         if spec_tokens < 0:
             raise ValueError("spec_tokens must be >= 0")
         self.max_batch_size = int(max_batch_size)
@@ -250,35 +251,15 @@ class Scheduler:
         Called only from the engine's build phase against COMMITTED state:
         under the overlapped loop every prior step's commit has already
         adopted its pool pages and scheduler transitions before the next
-        ``schedule`` runs, so planning never sees a half-applied step."""
-        if self.chunk_size:
-            return self._schedule_chunked(pool)
-        budget = self.token_budget - len(self.running)
-        prefills: List[Request] = []
-        planned_blocks = 0
-        while self.waiting and \
-                len(self.running) + len(prefills) < self.max_batch_size:
-            req = self.waiting[0]
-            need = len(req.resume_tokens)
-            nb = pool.blocks_for(need)
-            if planned_blocks + nb > pool.num_allocatable:
-                break
-            if need > budget and (prefills or self.running):
-                break  # over budget — admissible only as the sole work
-            budget -= need
-            planned_blocks += nb
-            req.prefill_len = need
-            prefills.append(self.waiting.popleft())
-        return StepPlan(prefills=prefills, decodes=list(self.running))
+        ``schedule`` runs, so planning never sees a half-applied step.
 
-    def _schedule_chunked(self, pool) -> StepPlan:
-        """Sarathi-style step packing: each decode-phase running row costs 1
+        Sarathi-style step packing: each decode-phase running row costs 1
         budget token; running rows still mid-prefill take up to chunk_size
         more of their prompt; what's left admits queued requests at chunk
         granularity (FCFS). The oldest mid-prefill row always advances at
         least one token, so held blocks are never idle; a sole request is
         always admitted even with budget < 1 (it could never start
-        otherwise, mirroring the legacy over-budget rule).
+        otherwise).
 
         With a prefix cache, admission probes the index first: cached
         prompt positions cost no chunk budget (their KV is already

@@ -57,8 +57,8 @@ PAGE_SPEC = P(None, "seq", None, None, None)
 
 # Stacked per-shard block tables: leading axis one entry per shard. A
 # partial spec (trailing dims replicated) so the SAME spec covers the
-# (sp, B, nb) step tables, the (sp, nb) legacy-prefill table, and the
-# (sp, 1, k) block-id arguments of the COW/adopt steps.
+# (sp, B, nb) step tables and the (sp, 1, k) block-id arguments of the
+# COW/adopt steps.
 TABLE_SPEC = P("seq")
 
 
@@ -161,8 +161,8 @@ class SPContext:
         return jax.device_put(x, self.replicated)
 
     def put_tables(self, tables: np.ndarray, blocks_per_shard: int):
-        """Stage GLOBAL block tables (any rank — step tables, the legacy
-        prefill table, COW/adopt block-id pairs) as the stacked per-shard
+        """Stage GLOBAL block tables (any rank — step tables, COW/adopt
+        block-id pairs) as the stacked per-shard
         (sp, ...) device array ``jit_step``'s ``tables_argnum`` consumes:
         shard s's slice holds LOCAL row ids for the positions it owns and
         ``-1`` holes for everyone else's."""
@@ -177,14 +177,11 @@ class SPModel:
     Presents the SAME interface and dimensions as the base model — every
     parameter and every matmul is replicated, so most methods delegate
     verbatim. Only the paged-attention call differs: each shard sweeps its
-    own pages and the partials merge across the mesh (``SPAttention``).
-    ``sp_axis`` names the mesh axis; the engine's assembled-cache step
-    bodies read it to psum their ``gather_kv``."""
+    own pages and the partials merge across the mesh (``SPAttention``)."""
 
     def __init__(self, base, sp: int):
         self.base = base
         self.sp = int(sp)
-        self.sp_axis = "seq"
         self.vocab_size = base.vocab_size
         self.max_len = base.max_len
         self.num_layers = base.num_layers
@@ -205,15 +202,6 @@ class SPModel:
 
     def _head(self, params, x):
         return self.base._head(params, x)
-
-    def init_cache(self, batch: int, max_len: Optional[int] = None):
-        return self.base.init_cache(batch, max_len)
-
-    def apply_cached(self, params, ids, caches, offset):
-        # assembled-cache path: the engine's step body already psum-gathered
-        # the full replicated cache (kv_pool.gather_kv(axis_name=sp_axis)),
-        # so the base model runs unchanged on every shard
-        return self.base.apply_cached(params, ids, caches, offset)
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):
@@ -247,12 +235,6 @@ class SPBlock:
         self.sp = int(sp)
         self.attn = SPAttention(base.attn, sp)
 
-    def init_cache(self, batch: int, max_len: int, d_model: int):
-        return self.base.init_cache(batch, max_len, d_model)
-
-    def apply_cached(self, params, x, cache, offset):
-        return self.base.apply_cached(params, x, cache, offset)
-
     def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
                     layer, q_lens=None):
         base = self.base
@@ -280,12 +262,6 @@ class SPAttention:
     def __init__(self, base, sp: int):
         self.base = base
         self.sp = int(sp)
-
-    def apply_cached(self, variables, x, cache, offset):
-        return self.base.apply_cached(variables, x, cache, offset)
-
-    def init_cache(self, batch: int, max_len: int, d_model: int):
-        return self.base.init_cache(batch, max_len, d_model)
 
     def apply_paged(self, variables, x, pages_k, pages_v, block_tables,
                     offsets, layer=0, q_lens=None):

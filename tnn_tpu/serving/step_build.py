@@ -21,7 +21,7 @@ engine's step-program notes record, so they double as the replay surface.
 Row layout contract (mirrored by the commit halves in engine.py):
 ``pack_mixed`` puts decode-phase rows first (each carrying 1 committed
 token plus optional speculative draft positions), then mid-prefill chunk
-rows; ``pack_decode`` is the legacy pure-decode batch, one token per row.
+rows; ``pack_decode`` is the pure-decode batch, one token per row.
 Padding rows point their tables at the pool's scratch block.
 """
 from __future__ import annotations
@@ -65,10 +65,9 @@ class MixedStep(PackedStep):
 
 @dataclasses.dataclass
 class DecodeStep(PackedStep):
-    """The legacy pure-decode batch: one committed token per row."""
+    """The pure-decode batch: one committed token per row."""
     toks: np.ndarray = None         # (B,) this step's token per row
     offsets: np.ndarray = None      # (B,) kv length before this token
-    lockstep: bool = False          # uniform offsets (fused-kernel eligible)
 
 
 def shard_tables(tables: np.ndarray, sp: int,
@@ -77,17 +76,16 @@ def shard_tables(tables: np.ndarray, sp: int,
     parallelism. Pure host math — the dispatch side stages the result over
     the context mesh with ``P("seq", None, None)``.
 
-    ``tables``: global ids of any rank — (B, nb) step tables, the (nb,)
-    legacy-prefill table, the (1, k) block-id pairs of the COW/adopt steps.
+    ``tables``: global ids of any rank — (B, nb) step tables, the (1, k)
+    block-id pairs of the COW/adopt steps.
     Position j's block was allocated from shard ``j % sp``
     (``PagedKVPool.alloc(..., start=)``) but this function derives
     ownership from the ID RANGE, ``g // blocks_per_shard``, so COW-forked
     and handoff-adopted blocks land on whichever shard actually holds
     their pages. Returns (sp, *tables.shape) int32 where shard s's entry
     is the LOCAL row ``g % blocks_per_shard`` if shard s owns ``g``, else
-    ``-1``: the paged kernel skips -1 blocks, the scatters redirect them to
-    the shard's scratch page, and ``gather_kv``'s psum reassembles the full
-    cache from the ownership partition.
+    ``-1``: the paged kernel skips -1 blocks and the page write redirects
+    them to the shard's scratch page.
     """
     owner = tables // blocks_per_shard
     local = (tables % blocks_per_shard).astype(np.int32)
@@ -160,16 +158,15 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
 
 
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
-                kv_key: Tuple[Any, ...], paged: bool,
-                fused_available: bool,
-                speculative: bool = False, sum_at: int = 0) -> DecodeStep:
+                kv_key: Tuple[Any, ...], speculative: bool = False,
+                sum_at: int = 0) -> DecodeStep:
     """Pack the pure-decode batch. ``speculative=True`` packs the
     overlapped engine's predicted step N+1: each row's offset assumes
     exactly one more token committed, and the token column is left zero —
     the dispatched program reads step N's unfetched sampled tokens
     directly as its device-resident input."""
     step = DecodeStep(
-        key=(), b=b, nb=nb,
+        key=("pdecode", b, nb) + kv_key, b=b, nb=nb,
         toks=np.zeros((b,), np.int32),
         offsets=np.zeros((b,), np.int32),
         **_alloc_common(b, nb, scratch))
@@ -178,13 +175,4 @@ def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
             step.toks[i] = req.next_token
         step.offsets[i] = req.cache_len + (1 if speculative else 0)
         _fill_row(step, i, req, sum_at)
-    step.lockstep = (not paged and fused_available and not speculative
-                     and len(set(step.offsets[:len(live)].tolist())) == 1)
-    if step.lockstep:
-        # padded rows share the live offset: their scratch-block writes
-        # stay harmless and the kernel's scalar position is uniform
-        step.offsets[len(live):] = step.offsets[0]
-    step.key = (("pdecode", b, nb) if paged
-                else ("fdecode", b, nb) if step.lockstep
-                else ("decode", b, nb)) + kv_key
     return step

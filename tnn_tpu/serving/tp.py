@@ -229,23 +229,6 @@ class TPModel:
     def _head(self, params, x):
         return self.base._head(params, x)
 
-    def init_cache(self, batch: int, max_len: Optional[int] = None):
-        max_len = max_len or self.max_len
-        return [b.init_cache(batch, max_len, self.d_model)
-                for b in self.blocks]
-
-    def apply_cached(self, params, ids, caches, offset):
-        x, _ = self._trunk(params, ids, False, None, offset=offset)
-        new_caches = []
-        for i, block in enumerate(self.blocks):
-            with jax.named_scope(f"h{i}"):
-                x, c = block.apply_cached(params[f"h{i}"], x, caches[i],
-                                          offset)
-            new_caches.append(c)
-        with jax.named_scope("ln_f"):
-            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), new_caches
-
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):
         x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
@@ -287,9 +270,6 @@ class TPBlock:
         self.mlp_ratio = base.mlp_ratio
         self.activation = base.activation
 
-    def init_cache(self, batch: int, max_len: int, d_model: int):
-        return self.attn.init_cache(batch, max_len, d_model)
-
     def _mlp(self, params, h):
         # Dense._apply twice, with the contraction split: fc's kernel/bias
         # are column-sharded (activation applies elementwise to local
@@ -312,15 +292,6 @@ class TPBlock:
         h = jax.lax.psum(h, "model")
         h = h + params["proj"]["bias"].astype(jnp.float32)
         return policy.cast_out(h)
-
-    def apply_cached(self, params, x, cache, offset):
-        h, _ = self.ln1.apply({"params": params["ln1"], "state": {}}, x)
-        h, new_cache = self.attn.apply_cached({"params": params["attn"]}, h,
-                                              cache, offset)
-        x = x + h
-        h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-        h = self._mlp(params, h)
-        return x + h, new_cache
 
     def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
                     layer, q_lens=None):
@@ -390,53 +361,6 @@ class TPAttention:
             y = y + params["out_bias"].astype(y.dtype)
         # dropout is decode-only here (train=False) — a no-op, omitted
         return policy.cast_out(y)
-
-    def init_cache(self, batch: int, max_len: int, d_model: int):
-        base = self.base
-        dh = d_model // base.num_heads
-        if base.kv_cache_dtype == "int8":
-            z8 = jnp.zeros((batch, self.kl, max_len, dh), jnp.int8)
-            zs = jnp.zeros((batch, self.kl, max_len, 1), jnp.float32)
-            return {"k": z8, "v": z8, "k_scale": zs, "v_scale": zs}
-        dtype = base.policy.compute_dtype
-        return {
-            "k": jnp.zeros((batch, self.kl, max_len, dh), dtype),
-            "v": jnp.zeros((batch, self.kl, max_len, dh), dtype),
-        }
-
-    def apply_cached(self, variables, x, cache, offset):
-        from ..nn.attention import apply_rope, sdpa
-
-        base = self.base
-        params = variables["params"]
-        q, k_new, v_new = self._project_qkv(params, x)
-        if base.rope_theta:
-            # rotation is per-head independent — exact under head sharding
-            q = apply_rope(q, offset, base.rope_theta)
-            k_new = apply_rope(k_new, offset, base.rope_theta)
-        if getattr(offset, "ndim", 0):  # per-row write positions
-            upd = lambda buf, new: jax.vmap(  # noqa: E731
-                lambda b, n, o: jax.lax.dynamic_update_slice_in_dim(
-                    b, n, o, axis=1))(buf, new, offset)
-        else:
-            upd = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
-                buf, new, offset, axis=2)
-        if base.kv_cache_dtype == "int8":
-            kq, ks = base._quant_rows(k_new)
-            vq, vs = base._quant_rows(v_new)
-            cache = {"k": upd(cache["k"], kq), "v": upd(cache["v"], vq),
-                     "k_scale": upd(cache["k_scale"], ks),
-                     "v_scale": upd(cache["v_scale"], vs)}
-            cd = base.policy.compute_dtype
-            k = (cache["k"].astype(jnp.float32) * cache["k_scale"]).astype(cd)
-            v = (cache["v"].astype(jnp.float32) * cache["v_scale"]).astype(cd)
-        else:
-            cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
-            k, v = cache["k"], cache["v"]
-        out = sdpa(q, k, v, causal=True, kv_offset=offset,
-                   backend=base.backend if base.backend != "ring" else "xla")
-        y = self._project_out(params, out)
-        return y, cache
 
     def apply_paged(self, variables, x, pages_k, pages_v, block_tables,
                     offsets, layer=0, q_lens=None):

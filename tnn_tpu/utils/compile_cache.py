@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache, placed from outside the program.
 
 Every entry point (``tnn-serve``, ``tnn-trainer``, ``tnn-train-gpt2``,
-``tnn-gpt2-inference``, ``bench.py``, ``chip_smoke.py``) calls
+``tnn-gpt2-inference``, ``chip_smoke.py``) calls
 :func:`enable` once before its first compile. Where the cache lives:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads the variable itself and this

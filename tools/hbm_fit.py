@@ -22,8 +22,8 @@ def row(size: str, batch: int, seq: int):
     from tnn_tpu import models, nn
     from tnn_tpu.train.step import create_train_state
 
-    # same convention as benchmarks/model_bench.py: a size starting with
-    # "llama" names the Llama family directly, anything else is gpt2_<size>
+    # a size starting with "llama" names the Llama family directly, anything
+    # else is gpt2_<size>
     name = size if size.startswith("llama") else f"gpt2_{size}"
     model = models.create(name, max_len=seq)
     opt = nn.AdamW(lr=1e-4)
