@@ -14,7 +14,8 @@ comes out by the repo's own means. Phases, each printing its name and result:
              lives, and whether ``block_until_ready`` blocks here.
   kernel     ``paged_attention(backend="pallas", interpret=False)`` against
              the XLA gather reference at gpt2_small geometry: decode form,
-             chunk widths 8 and 64, spec width 5, bf16 and int8 pages, stats
+             chunk widths 8 and 64, spec width 5, bf16 pages (plain, and two
+             heads a page row as the pool holds them) and int8 pages, stats
              on and off, -1-holed tables. Max abs error per row of the table.
   serve      nine token-id requests (prompts of 5..700 tokens, two sharing a
              96-token prefix) through ``tnn-serve --model gpt2_small
@@ -72,7 +73,7 @@ PHASES = ("device", "kernel", "serve", "train", "four_chip")
 # Tolerances, stated before the chip was asked. bf16 has 8 bits of mantissa
 # (eps 2^-8 = 3.9e-3); attention outputs are O(1) averages of unit-variance
 # values and both sides accumulate in f32, so a few eps bounds the difference.
-KERNEL_TOL = {"bf16": 2e-2, "int8": 3e-2}
+KERNEL_TOL = {"bf16": 2e-2, "pack2": 2e-2, "int8": 3e-2}
 # gpt2_small's random-init logits have a spread of ~0.5 and a top-1/top-2 gap
 # of a few hundredths; a token drawn from a WRONG distribution sits ~2 below
 # the arg-max. 0.25 separates "bf16 reordering moved a near-tie" from "wrong".
@@ -168,9 +169,17 @@ def phase_kernel(cfg) -> list:
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
 
     pk, pv = rand((L, N, Hkv, bs, dh)), rand((L, N, Hkv, bs, dh))
-    pages = {"bf16": (pk, pv),
-             "int8": (QuantPages(*quantize_kv_rows(pk)),
-                      QuantPages(*quantize_kv_rows(pv)))}
+
+    def pack2(x):   # two heads side by side in a page row, as the pool
+        # holds heads of 64 (``PagedKVPool.page_shape``)
+        return x.reshape(L, N, Hkv // 2, 2, bs, dh).swapaxes(3, 4) \
+            .reshape(L, N, Hkv // 2, bs, 2 * dh)
+
+    # name -> the pages the kernel reads, the pages the XLA path reads
+    pages = {"bf16": ((pk, pv),) * 2,
+             "pack2": ((pack2(pk), pack2(pv)), (pk, pv)),
+             "int8": ((QuantPages(*quantize_kv_rows(pk)),
+                       QuantPages(*quantize_kv_rows(pv))),) * 2}
     cap = nb * bs
     kv_lens = np.array([1, bs, bs + 1, 100, cap // 2 - 1, 700, cap - 24,
                         cap])[:B].clip(1, cap).astype(np.int32)
@@ -186,7 +195,7 @@ def phase_kernel(cfg) -> list:
     log(f"{'form':8s} {'pages':5s} {'stats':5s} {'holes':5s} "
         f"{'max|out|err':>11s} {'max|m|err':>10s} {'max l relerr':>12s} "
         f"{'tol':>6s}")
-    for pname, (pgk, pgv) in pages.items():
+    for pname, ((pgk, pgv), (rfk, rfv)) in pages.items():
         tol = KERNEL_TOL[pname]
         for fname, qw in forms:
             for stats in (False, True):
@@ -203,7 +212,7 @@ def phase_kernel(cfg) -> list:
                         q, pgk, pgv, jnp.asarray(tbl), jnp.asarray(kv_lens),
                         backend="pallas", interpret=interpret, **kw)
                     want = paged_attention(
-                        q, pgk, pgv, jnp.asarray(tbl), jnp.asarray(kv_lens),
+                        q, rfk, rfv, jnp.asarray(tbl), jnp.asarray(kv_lens),
                         backend="xla", **kw)
                     got, want = (got, want) if stats else ((got,), (want,))
                     g = [np.asarray(a, np.float32) for a in got]

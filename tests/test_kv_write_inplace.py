@@ -34,9 +34,12 @@ from tnn_tpu.utils import compile_cache
 # gpt2-large-serve.json): 20 heads of 64, pages of 16, 1,024 blocks, 16 rows
 _WIDTHS = dict(vocab_size=50257, max_len=1024, d_model=1280, num_heads=20)
 _HEADS, _HEAD_DIM, _BLOCK, _BLOCKS, _ROWS = 20, 64, 16, 1024, 16
-# what the program's entry and exit may cost: the pool rests in another
-# layout than the kernel reads (PERF.md section 7), 2 copies in + 2 out
-_ENTRY_EXIT_COPIES = 6
+# what the entry and exit of an int8 pool's program may cost: its rows of
+# 64 one-byte values rest in another layout than the kernel reads (2 copies
+# in + 2 out). A bf16 pool packs two heads a row (``pa.lane_pack``), rests
+# in the kernel's layout, and may cost NOTHING
+_ENTRY_EXIT_COPIES = {"bf16": 0, "int8": 6}
+_KERNEL_LAYOUT = "{4,3,2,1,0"
 _COMPILE_TIMEOUT_S = 240
 
 
@@ -91,11 +94,16 @@ def _pool_copies(one_chip, form, dtype, num_layers):
         lambda x: spec(x.shape, x.dtype),
         jax.eval_shape(
             lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
-    shape = (num_layers, _BLOCKS, _HEADS, _BLOCK, _HEAD_DIM)
+    # the shape is the pool's own (a pool of two blocks says what a page is)
+    shape = PagedKVPool(
+        num_layers, _HEADS, _HEAD_DIM, 2, _BLOCK, dtype=jnp.bfloat16,
+        kv_dtype="int8" if dtype == "int8" else "f32").page_shape
+    shape = shape[:1] + (_BLOCKS,) + shape[2:]
     if dtype == "int8":
         pages = pa.QuantPages(spec(shape, jnp.int8),
                               spec(shape[:-1] + (1,), jnp.float32))
     else:
+        assert shape[2:] == (_HEADS // 2, _BLOCK, 2 * _HEAD_DIM)
         pages = spec(shape, jnp.bfloat16)
     tables = spec((_ROWS, _WIDTHS["max_len"] // _BLOCK), jnp.int32)
     lens = spec((_ROWS,), jnp.int32)
@@ -112,10 +120,14 @@ def _pool_copies(one_chip, form, dtype, num_layers):
                 lens, lens)
         text = lowered.compile().as_text()
     assert "tnn_paged_attention" in text, "the Pallas kernel is not in it"
-    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\("
-                      % ",".join(map(str, shape)))
+    dims = ",".join(map(str, shape))
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+    # the layouts the program's pool ARGUMENTS rest in (K and V): the
+    # module's first line, ``entry_computation_layout={(args)->(results)}``
+    layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
+                         text.split("\n", 1)[0].split(")->(")[0])
     return [line.strip()[:160] for line in text.splitlines()
-            if pool.search(line)]
+            if pool.search(line)], layouts
 
 
 @pytest.mark.parametrize("dtype", ["bf16", "int8"])
@@ -124,13 +136,17 @@ def test_step_program_has_no_pool_copy_per_layer(form, dtype, one_chip,
                                                  no_compile_cache, alarm):
     """Pool-shaped ``copy`` instructions in the step program compiled for the
     v5e: as many at 4 layers as at 2 (none belongs to a layer), and no more
-    than the program's entry and exit cost. (Of an int8 pool this counts the
-    int8 data array; its f32 scale sidecar has another shape and its own
-    copies: PERF.md section 7.)"""
-    two = _pool_copies(one_chip, form, dtype, 2)
-    four = _pool_copies(one_chip, form, dtype, 4)
+    than the program's entry and exit cost: NONE for the bf16 pool, whose
+    arguments rest in the layout the kernel reads. (Of an int8 pool this
+    counts the int8 data array; its f32 scale sidecar has another shape and
+    its own copies: PERF.md section 7.)"""
+    two, _ = _pool_copies(one_chip, form, dtype, 2)
+    four, layouts = _pool_copies(one_chip, form, dtype, 4)
     assert len(two) == len(four), (two, four)
-    assert len(four) <= _ENTRY_EXIT_COPIES, four
+    assert len(four) <= _ENTRY_EXIT_COPIES[dtype], four
+    if dtype == "bf16":
+        assert len(layouts) == 2, layouts
+        assert all(x.startswith(_KERNEL_LAYOUT) for x in layouts), layouts
 
 
 @pytest.mark.parametrize("qw", [1, 64])

@@ -440,11 +440,14 @@ class TestProfilerSink:
             assert all("step" in ev[4] for ev in by_name[name]), name
         assert {"kind", "key"} <= set(by_name["serve.dispatch"][0][4])
         # a paged step program says what a grid step of its kernel fetches:
-        # 12 table entries (all of a row's 48 positions) of both heads
+        # 12 table entries (all of a row's 48 positions) of the ONE page row
+        # that holds both heads of 16 side by side (``kv_lane_pack`` 2)
         paged = [ev[4] for ev in by_name["serve.dispatch"]
                  if ev[4]["kind"] == "decode_paged"]
-        assert paged and all((int(a["attn_pages"]), int(a["attn_heads"]))
-                             == (12, 2) for a in paged)
+        assert paged and all(
+            (int(a["attn_pages"]), int(a["attn_heads"]),
+             int(a["kv_lane_pack"])) == (12, 1, 2) for a in paged)
+        assert eng.stats()["kv_lane_pack"] == 2
         assert not any(" " in ev[1] or "=" in ev[1] for ev in evs)
         assert len({ev[0] for ev in evs if ev[1] in PHASES}) == 1
         # the phases tile the worker's time: no two of them overlap, and

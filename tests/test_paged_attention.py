@@ -5,7 +5,10 @@ CPU (forced by the ``kernel`` marker's conftest fixture), checked against the
 XLA-lax reference in the same module; the reference itself is checked against
 a dense softmax-attention oracle built here. Covers ragged lengths, block
 sizes, GQA head ratios, layer selection, zero-length rows, and the
-``scatter_kv_rows`` write half of the page contract.
+``scatter_kv_rows`` write half of the page contract. PACKED pages (``p`` KV
+heads side by side in a page row, ``pa.lane_pack``) are cases of the same
+tests: the pool is made unpacked, ``_pack`` lays it out as ``PagedKVPool``
+would, and every oracle reads the unpacked one.
 """
 import math
 
@@ -17,6 +20,14 @@ import pytest
 from tnn_tpu.ops.pallas import paged_attention as pa
 
 pytestmark = pytest.mark.kernel
+
+
+def _pack(pages, p):
+    """(..., H_kv, bs, Dh) as a pool rests with ``p`` heads a row:
+    (..., H_kv / p, bs, p * Dh), heads ``p j .. p j + p - 1`` side by side."""
+    *lead, hkv, bs, dh = pages.shape
+    return pages.reshape(*lead, hkv // p, p, bs, dh).swapaxes(-3, -2) \
+        .reshape(*lead, hkv // p, bs, p * dh)
 
 
 def _random_case(seed, *, num_layers=2, num_blocks=12, block_size=8,
@@ -255,25 +266,34 @@ def _dense_oracle_mq(q, pages_k, pages_v, tables, kv_lens, q_lens, layer):
 
 
 @pytest.mark.parametrize("block_size", [4, 8])
-@pytest.mark.parametrize("heads", [(4, 4), (4, 2), (4, 1)],
-                         ids=["mha", "gqa2", "mqa"])
+@pytest.mark.parametrize(
+    "heads", [(4, 4, 1), (4, 2, 1), (4, 1, 1), (4, 4, 2), (4, 4, 4),
+              (4, 2, 2)],
+    ids=["mha", "gqa2", "mqa", "mha-p2", "mha-p4", "gqa2-p2"])
 @pytest.mark.parametrize("qw", [4, 8])
 def test_multitoken_kernel_matches_oracle(block_size, heads, qw):
-    """Ragged q chunks x GQA ratios x block sizes: the kernel, the XLA
-    reference, and the dense oracle agree; scatter_kv_chunk writes the
-    chunk's KV where attention then reads it."""
-    h, hkv = heads
+    """Ragged q chunks x GQA ratios x block sizes x heads a page row: the
+    kernel, the XLA reference, and the dense oracle agree; scatter_kv_chunk
+    writes the chunk's KV where attention then reads it, and into a packed
+    page exactly what it writes into an unpacked one."""
+    h, hkv, p = heads
     q, pk, pv, tables, starts, q_lens, rows_k, rows_v = _random_chunk_case(
         block_size * 100 + h * 10 + qw, block_size=block_size, num_heads=h,
         num_kv_heads=hkv, qw=qw)
     kv_lens = starts + q_lens
-    pk = pa.scatter_kv_chunk(pk, tables, starts, rows_k, q_lens, layer=1)
-    pv = pa.scatter_kv_chunk(pv, tables, starts, rows_v, q_lens, layer=1)
+    flat_k = pa.scatter_kv_chunk(pk, tables, starts, rows_k, q_lens, layer=1)
+    flat_v = pa.scatter_kv_chunk(pv, tables, starts, rows_v, q_lens, layer=1)
+    pk = pa.scatter_kv_chunk(_pack(pk, p), tables, starts, rows_k, q_lens,
+                             layer=1)
+    pv = pa.scatter_kv_chunk(_pack(pv, p), tables, starts, rows_v, q_lens,
+                             layer=1)
+    assert pk.shape[2:] == (hkv // p, block_size, p * q.shape[-1])
+    _assert_same_but_scratch(pk, _pack(flat_k, p))
     ref = pa.paged_attention_reference(q, pk, pv, tables, kv_lens,
                                        q_lens=q_lens, layer=1)
     out = pa.paged_attention(q, pk, pv, tables, kv_lens, q_lens=q_lens,
                              layer=1, backend="pallas")
-    oracle = _dense_oracle_mq(q, pk, pv, tables, kv_lens, q_lens, 1)
+    oracle = _dense_oracle_mq(q, flat_k, flat_v, tables, kv_lens, q_lens, 1)
     np.testing.assert_allclose(np.asarray(ref), oracle, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
@@ -452,12 +472,13 @@ def _oracle_chunk(pages, tables, starts, rows, q_lens, layer=None):
 
 
 def _write_case(seed, *, qw, block_size=4, batch=5, blocks_per_row=6,
-                holes=False, all_scratch=False):
+                holes=False, all_scratch=False, pack=1):
     """A pool, tables that keep the engine's one-writer invariant (every
     non-scratch page in one row's table only, scratch page 0 as padding) and
     a ragged chunk per row: row 0 absent (q_lens 0), row 1 a full chunk that
     starts on a page's last slot (the most pages a chunk can straddle), the
     last row padding whose table is all scratch."""
+    del pack    # the test packs the pool after the oracle has written it
     rng = np.random.default_rng(seed)
     num_blocks = 1 + batch * blocks_per_row
     shape = (2, num_blocks, 2, block_size, 16)
@@ -499,6 +520,10 @@ _WRITE_CASES = {
     "all_scratch": dict(qw=6, all_scratch=True),
     "one_layer": dict(qw=6), "traced_layer": dict(qw=6),
     "int8": dict(qw=6), "int8_holes": dict(qw=10, holes=True),
+    # both heads of 16 in one page row of 32 (``pa.lane_pack``)
+    "packed": dict(qw=6, pack=2), "packed_pages5": dict(qw=16, pack=2),
+    "packed_holes": dict(qw=10, holes=True, pack=2),
+    "packed_one_layer": dict(qw=6, pack=2),
 }
 
 
@@ -507,13 +532,15 @@ _WRITE_CASES = {
 def test_page_write_matches_per_row_scatter(case, form):
     """scatter_kv_rows / scatter_kv_chunk (whole pages, in place) against the
     per-row scatter formula they replaced: bit-exact on every non-scratch
-    page, whatever lands in the scratch page."""
+    page, whatever lands in the scratch page. A packed page takes the
+    packed form of what the formula writes into the unpacked one."""
     pages, tables, starts, rows, q_lens = _write_case(
         sum(map(ord, case)), **_WRITE_CASES[case])
+    pack = _WRITE_CASES[case].get("pack", 1)
     if case.startswith("int8"):
         pages = _quantize(pages)
     layer = 1
-    if case == "one_layer":
+    if case.endswith("one_layer"):
         pages, layer = pages[1], None
     if form == "rows":
         # the decode form: every row writes one position (a padding row's
@@ -522,12 +549,15 @@ def test_page_write_matches_per_row_scatter(case, form):
     else:
         args, new, old = (starts, rows, q_lens), pa.scatter_kv_chunk, \
             _oracle_chunk
+    want = old(pages, tables, *args, layer=layer)
+    if pack > 1:
+        pages, want = _pack(pages, pack), _pack(want, pack)
     if case == "traced_layer":
         got = jax.jit(lambda p, ly: new(p, tables, *args, layer=ly))(
             pages, jnp.asarray(layer, jnp.int32))
     else:
         got = new(pages, tables, *args, layer=layer)
-    _assert_same_but_scratch(got, old(pages, tables, *args, layer=layer))
+    _assert_same_but_scratch(got, want)
     if case == "all_scratch":   # and nothing but the scratch page changed
         _assert_same_but_scratch(got, pages)
 
@@ -573,17 +603,27 @@ def _group_case(seed, kv_lens, *, heads=(4, 4), head_dim=64, qw=None,
             jnp.asarray(kv_lens, jnp.int32), q_lens)
 
 
-def _assert_matches_reference(case, *, layer=1, atol=2e-5, stats=False):
+def _assert_matches_reference(case, *, layer=1, atol=2e-5, stats=False,
+                              pack=1):
+    """The kernel (interpret mode) against the XLA path. With ``pack`` both
+    read the pool packed, and are held to the XLA path over the UNPACKED
+    pool (a query head's zero lanes add exact zeros: same tolerance)."""
     q, pk, pv, tables, kv_lens, q_lens = case
     kw = dict(q_lens=q_lens, layer=layer, return_stats=stats)
-    out = pa.paged_attention(q, pk, pv, tables, kv_lens, backend="pallas",
-                             **kw)
     ref = pa.paged_attention(q, pk, pv, tables, kv_lens, backend="xla", **kw)
-    for got, want in zip(out if stats else (out,), ref if stats else (ref,)):
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32), atol=atol,
-                                   rtol=atol)
-    return out[0] if stats else out
+    if pack > 1:
+        pk, pv = _pack(pk, pack), _pack(pv, pack)
+    outs = [pa.paged_attention(q, pk, pv, tables, kv_lens, backend=backend,
+                               **kw)
+            for backend in (("pallas", "xla") if pack > 1 else ("pallas",))]
+    for out in outs:
+        for got, want in zip(out if stats else (out,),
+                             ref if stats else (ref,)):
+            assert got.shape == want.shape
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       atol=atol, rtol=atol)
+    return outs[0][0] if stats else outs[0]
 
 
 # lengths that end inside a group, at its end and one past it, a whole table
@@ -592,46 +632,66 @@ def _assert_matches_reference(case, *, layer=1, atol=2e-5, stats=False):
 _GROUP_LENS = [0, 1, 100, 128, 129, 256, 257, 320]
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("heads", [(4, 4), (4, 2), (4, 1)],
-                         ids=["g1", "g2", "g4"])
-def test_grouped_decode_lengths_around_group_ends(heads, head_dim):
+# (query heads, KV heads), head dim, heads a page row
+_PACKED = {"g1-p2": ((4, 4), 64, 2), "g2-p2": ((4, 2), 64, 2),
+           "g4-p2": ((8, 2), 64, 2), "g1-p4": ((4, 4), 32, 4),
+           "g2-p4": ((8, 4), 32, 4)}
+_DECODE_SHAPES = {f"{g}-dh{dh}": (heads, dh, 1)
+                  for dh in (64, 128)
+                  for g, heads in (("g1", (4, 4)), ("g2", (4, 2)),
+                                   ("g4", (4, 1)))}
+_DECODE_SHAPES.update(_PACKED)
+
+
+@pytest.mark.parametrize("shape", list(_DECODE_SHAPES))
+def test_grouped_decode_lengths_around_group_ends(shape):
+    heads, head_dim, pack = _DECODE_SHAPES[shape]
     case = _group_case(head_dim + heads[1], _GROUP_LENS, heads=heads,
                        head_dim=head_dim)
-    assert pa.fetch_group(bs=_GROUP_BS, dh=head_dim, hkv=heads[1],
-                          qg=heads[0] // heads[1], page_dtype=jnp.float32,
-                          nb=20) == (8, heads[1])
-    out = _assert_matches_reference(case)
+    # to the kernel a packed row is one KV head of ``pack * head_dim`` with
+    # ``pack`` query groups: every such head of 8 pages a grid step
+    assert pa.fetch_group(bs=_GROUP_BS, dh=pack * head_dim,
+                          hkv=heads[1] // pack,
+                          qg=pack * heads[0] // heads[1],
+                          page_dtype=jnp.float32,
+                          nb=20) == (8, heads[1] // pack)
+    out = _assert_matches_reference(case, pack=pack)
     assert np.all(np.asarray(out[0]) == 0)      # the row of length 0
 
 
-@pytest.mark.parametrize("heads", [(4, 4), (4, 1)], ids=["g1", "g4"])
+_CHUNK_SHAPES = {"g1": ((4, 4), 64, 1), "g4": ((4, 1), 64, 1), **_PACKED}
+
+
+@pytest.mark.parametrize("shape", list(_CHUNK_SHAPES))
 @pytest.mark.parametrize("qw", [1, 8, 64])
-def test_grouped_chunks_with_dead_rows(qw, heads):
+def test_grouped_chunks_with_dead_rows(qw, shape):
     """Ragged chunks across group ends: a row with no query token (its
     context still live) and a row of length 0 beside live rows, a decode
     row, chunks that start before a group's end and end after it."""
+    heads, head_dim, pack = _CHUNK_SHAPES[shape]
     kv_lens = [200, 0, 129, 128 + qw // 2, 320, 77]
     q_lens = [0, 0, 1, min(qw, 128 + qw // 2), qw, min(qw, 77)]
     case = _group_case(qw * 10 + heads[1], kv_lens, heads=heads, qw=qw,
-                       q_lens=q_lens)
-    out = np.asarray(_assert_matches_reference(case))
+                       q_lens=q_lens, head_dim=head_dim)
+    out = np.asarray(_assert_matches_reference(case, pack=pack))
     for i, n in enumerate(q_lens):
         assert np.all(out[i, n:] == 0), i
 
 
+@pytest.mark.parametrize("pack", [1, 2], ids=["flat", "p2"])
 @pytest.mark.parametrize("form", ["decode", "chunk8"])
-def test_grouped_holes_inside_a_live_group_with_stats(form):
+def test_grouped_holes_inside_a_live_group_with_stats(form, pack):
     """-1 table entries (pages another sequence-parallel shard owns) in the
     middle of live groups, a group that holds nothing but holes, and a row
-    whose every page is a hole: out, m and l all match the reference's."""
+    whose every page is a hole: out, m and l all match the reference's
+    (m and l are a query head's, whatever row its KV head lies in)."""
     holes = [(0, 1), (0, 2), (0, 9), (1, 0), (2, 3)] \
         + [(3, e) for e in range(8, 16)] + [(4, e) for e in range(5)]
     qw = None if form == "decode" else 8
     q_lens = None if qw is None else [8, 1, 5, 8, 8, 0]
     case = _group_case(71, [300, 129, 64, 320, 80, 40], qw=qw, q_lens=q_lens,
                        heads=(4, 2), holes=holes)
-    _assert_matches_reference(case, stats=True)
+    _assert_matches_reference(case, stats=True, pack=pack)
 
 
 @pytest.mark.parametrize("form", ["decode", "chunk8"])
@@ -694,6 +754,82 @@ def test_fetch_group_stays_inside_its_vmem_budget(shape):
         assert (pages, heads) == want
 
 
+# (head dim, KV heads one device holds, page dtype) -> heads a page row
+_LANE_PACKS = {
+    "gpt2-large": ((64, 20, jnp.bfloat16), 2),
+    "dh128": ((128, 32, jnp.bfloat16), 1),
+    "gpt2-large-tp4-shard": ((64, 5, jnp.bfloat16), 1),
+    "one-kv-head": ((64, 1, jnp.bfloat16), 1),
+    "dh32": ((32, 8, jnp.bfloat16), 4),
+    "dh32-6-heads": ((32, 6, jnp.float32), 3),
+    "tiny-test-model": ((16, 2, jnp.float32), 2),
+    "dh256": ((256, 8, jnp.bfloat16), 1),
+    "int8": ((64, 20, jnp.int8), 1),
+}
+
+
+@pytest.mark.parametrize("shape", list(_LANE_PACKS))
+def test_lane_pack_rule(shape):
+    """The largest divisor of the device's KV heads at most ``128 // Dh``;
+    1 for int8 pages. ``PagedKVPool`` builds what it says."""
+    from tnn_tpu.serving.kv_pool import PagedKVPool
+
+    (dh, hkv, dtype), want = _LANE_PACKS[shape]
+    assert pa.lane_pack(hkv, dh, dtype) == want
+    int8 = jnp.dtype(dtype) == jnp.int8
+    pool = PagedKVPool(2, hkv, dh, 3, 4,
+                       dtype=jnp.float32 if int8 else dtype,
+                       kv_dtype="int8" if int8 else "f32")
+    assert pool.lane_pack == want
+    assert pool.page_shape == (2, 3, hkv // want, 4, want * dh)
+    data = pool.pages_k.data if int8 else pool.pages_k
+    assert data.shape == pool.page_shape
+    assert pool.kv_bytes_per_token == 2 * 2 * hkv * dh * data.dtype.itemsize
+
+
+# heads, head dim -> heads a page row at tp = 1 and at tp = 2
+_TP_POOLS = {"whole-groups": (4, 8, 4, 2), "odd-shard": (6, 64, 2, 1)}
+
+
+@pytest.mark.parametrize("shape", list(_TP_POOLS))
+def test_tp_shard_owns_whole_page_rows(shape):
+    """``tp`` = 2 on the CPU mesh. Four heads of 8: a shard holds 2, the pool
+    packs them into one row and each device holds whole rows. Six heads of
+    64: a shard's 3 heads are odd, so its pool stays unpacked (today's cost,
+    not an error) where ``tp`` = 1 packs pairs. Both token-exact against
+    ``tp`` = 1 and the offline reference."""
+    from tnn_tpu.models.gpt2 import GPT2, generate
+    from tnn_tpu.serving import InferenceEngine
+
+    heads, dh, pack1, pack2 = _TP_POOLS[shape]
+    model = GPT2(vocab_size=128, max_len=64, num_layers=2,
+                 d_model=dh * heads, num_heads=heads)
+    params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+    rng = np.random.default_rng(heads)
+    prompts = [rng.integers(0, 128, int(n)).astype(np.int32)
+               for n in (5, 11, 7, 13)]
+
+    def run(tp):
+        eng = InferenceEngine(model, params, num_blocks=32, block_size=4,
+                              max_batch_size=4, max_seq_len=32, tp=tp)
+        rids = [eng.submit(p, 8) for p in prompts]
+        out = eng.run_until_complete()
+        eng.check_invariants()
+        return eng, [out[r] for r in rids]
+
+    one, want = run(1)
+    two, got = run(2)
+    assert one.pool.lane_pack == pack1
+    assert two.pool.lane_pack == two.stats()["kv_lane_pack"] == pack2
+    assert two.pool.page_shape == (2, 32, heads // pack2, 4, pack2 * dh)
+    assert two.pool.pages_k.sharding.shard_shape(two.pool.page_shape) \
+        == (2, 32, heads // pack2 // 2, 4, pack2 * dh)
+    assert got == want
+    for p, toks in zip(prompts, got):
+        ref = np.asarray(generate(model, params, p[None], 8, max_len=32))[0]
+        assert ref.tolist() == toks
+
+
 def test_attn_fetch_fill_mean_is_a_hand_count():
     """``summary()["attn_fetch_fill_mean"]``: a row's live pages over the
     page slots of the groups the kernel fetches for them, mean over rows
@@ -707,8 +843,11 @@ def test_attn_fetch_fill_mean_is_a_hand_count():
     eng = InferenceEngine(model, params, num_blocks=40, block_size=16,
                           max_batch_size=4, max_seq_len=512)
     assert "attn_fetch_fill_mean" not in eng.metrics.summary()
+    # the pool holds both heads of 16 in ONE row of 32: to the kernel one KV
+    # head of 32 with a query group of 2
+    assert eng.pool.page_shape == (1, 40, 1, 16, 32)
     assert eng._attn_group(1) == pa.fetch_group(
-        bs=16, dh=16, hkv=2, qg=1, page_dtype=eng.pool.dtype, nb=32) == (8, 2)
+        bs=16, dh=32, hkv=1, qg=2, page_dtype=eng.pool.dtype, nb=32) == (8, 1)
     # rows of 1, 128, 129 and 300 positions hold 1, 8, 9 and 19 pages, in
     # 1, 1, 2 and 3 groups of 8 slots; the fifth entry is not a live row
     eng._observe_attention([None] * 4, np.array([1, 128, 129, 300, 77]), 1)

@@ -469,8 +469,8 @@ class MultiHeadAttention(Module):
 
         x : (B, Q, D) — this step's new tokens per row (Q = 1 for pure
             decode; Q > 1 for ragged prefill chunks).
-        pages_k / pages_v : the pool's (L, N, H_kv, bs, Dh) arrays; ``layer``
-            selects this block's slice without copying it.
+        pages_k / pages_v : the pool's (L, N, H_kv / p, bs, p * Dh) arrays;
+            ``layer`` selects this block's slice without copying it.
         block_tables : (B, nb) page ids; offsets : (B,) the position each row
             writes first (its kv length BEFORE this step's tokens).
         q_lens : (B,) live tokens per row this step, or None for the decode

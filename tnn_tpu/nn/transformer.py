@@ -38,7 +38,7 @@ class PagedDecoder:
         toks is (B, Q) with row b carrying ``q_lens[b]`` live new tokens
         starting at position ``offsets[b]`` (the rest padding: their KV lands
         in the pool's scratch page, their logits are garbage); pages_k /
-        pages_v the pool's (L, N, H_kv, bs, Dh) arrays with L == num_layers;
+        pages_v the pool's (L, N, H_kv / p, bs, p * Dh) arrays, L == num_layers;
         block_tables (B, nb) page ids. Every layer writes its new K/V rows
         into their pages and attends over the tables (the block's
         ``apply_paged``): no contiguous cache is ever assembled. Returns

@@ -3,8 +3,12 @@
 The serving engine's attention hot path (arXiv:2604.15464's storage model):
 each request's KV cache lives in fixed-size pages of the pool arrays
 
-    pages_k, pages_v : (L, num_blocks, H_kv, block_size, head_dim)
+    pages_k, pages_v : (L, num_blocks, H_kv / p, block_size, p * head_dim)
 
+(``p`` KV heads side by side in a page row so that a row fills the 128
+lanes, ``lane_pack``; ``p`` = 1 is the plain ``(L, N, H_kv, bs, Dh)``, and
+"head" below means a page row: to the kernel a packed pool is a
+grouped-query pool of ``H_kv / p`` heads of ``p * Dh``, ``paged_attention``)
 and a per-request *block table* names its pages in logical order. This
 kernel consumes the pages DIRECTLY: the block tables and per-row
 kv lengths are scalar-prefetched, the BlockSpec index maps chase the tables,
@@ -173,6 +177,70 @@ def fetch_group(*, bs, dh, hkv, qg, page_dtype, nb):
     return pages, heads
 
 
+def lane_pack(hkv, dh, page_dtype):
+    """How many KV heads of ``dh`` lie side by side in one page row: the
+    largest divisor of ``hkv`` (the heads ONE device holds: a tensor-parallel
+    shard's, so that a shard owns whole groups) that is at most ``128 // dh``.
+    A row of ``lane_pack * dh`` then fills the 128 lanes of a vector
+    register, and the pool rests in the layout the kernel and the whole-page
+    write read: at ``dh`` = 64 a row of one head is half a register, the
+    compiler keeps such a pool in another layout, and every step program
+    converted it in and out (4 whole-pool copies, three quarters of GPT-2
+    large's decode step). 1 at ``dh`` >= 128, for an odd head count, and for
+    int8 pages, whose f32 scale sidecar is one value a (position, head).
+    From shapes and the page dtype alone: ``PagedKVPool`` asks it once, and
+    the write and the read see it in ``pages.shape[-1] // rows.shape[-1]``."""
+    if jnp.dtype(page_dtype) == jnp.int8:
+        return 1
+    return max(p for p in range(1, max(128 // dh, 1) + 1) if hkv % p == 0)
+
+
+def _head_slot(h, p, g):
+    """(H, 1): the slot of a packed row that query head ``h``'s KV head
+    ``h // g`` lies in."""
+    return ((np.arange(h) // g) % p)[:, None]
+
+
+def _lay_in_lanes(q, p, g):
+    """(..., H, Dh) -> (..., H, p * Dh): query head ``h`` of KV head
+    ``h // g`` keeps its values in the lanes of that head's slot
+    ``(h // g) % p`` of a packed row, zeros in the others. The packed pool
+    is then a grouped-query pool of ``H_kv / p`` heads of ``p * Dh`` with a
+    group of ``p * g``: a score against a packed key row is head ``h``'s own
+    (the other heads' lanes meet exact zeros in a float32 accumulation)."""
+    slot = _head_slot(q.shape[-2], p, g)
+    return jnp.concatenate([jnp.where(slot == s, q, 0) for s in range(p)],
+                           axis=-1)
+
+
+def _own_lanes(out, p, g):
+    """Inverse of ``_lay_in_lanes`` for the output: (..., H, p * Dh) ->
+    (..., H, Dh), each head's own slot of its ``p @ V`` row (the other
+    lanes hold its probabilities over the OTHER heads' values)."""
+    dh = out.shape[-1] // p
+    slot = _head_slot(out.shape[-2], p, g)
+    own = out[..., :dh]
+    for s in range(1, p):
+        own = jnp.where(slot == s, out[..., s * dh:(s + 1) * dh], own)
+    return own
+
+
+def _over_packed_rows(attend, q, pages, stats):
+    """``attend(q)`` over pages that hold ``p`` KV heads a row (``p`` read
+    off the last dims): the queries laid into their lanes, each head's own
+    lanes of the output kept (``m`` and ``l`` are a query head's: no lanes
+    to choose). At ``p`` = 1 it is ``attend(q)``."""
+    rows, width = _pages_shape(pages)[2::2]
+    p = width // q.shape[-1]
+    if p == 1:
+        return attend(q)
+    g = q.shape[-2] // (rows * p)
+    out = attend(_lay_in_lanes(q, p, g))
+    if stats:
+        return (_own_lanes(out[0], p, g),) + tuple(out[1:])
+    return _own_lanes(out, p, g)
+
+
 def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
                  scale: float, bs: int, g: int, qw: int, pages: int,
                  quant: bool, stats: bool):
@@ -295,6 +363,19 @@ def _fetch_table(tables, lens, bs, pages):
                    static_argnames=("scale", "interpret", "stats"))
 def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
                             q_lens, layer, *, scale, interpret, stats):
+    """The kernel's launch for queries ``(B, Q, H, Dh)`` over pages of
+    ``p`` heads a row (the lane juggling is in here, so that it too is
+    traced once a program)."""
+    return _over_packed_rows(
+        functools.partial(_launch, pages_k=pages_k, pages_v=pages_v,
+                          block_tables=block_tables, kv_lens=kv_lens,
+                          q_lens=q_lens, layer=layer, scale=scale,
+                          interpret=interpret, stats=stats),
+        q, pages_k, stats)
+
+
+def _launch(q, *, pages_k, pages_v, block_tables, kv_lens, q_lens, layer,
+            scale, interpret, stats):
     quant = isinstance(pages_k, QuantPages)
     b, qw, h, dh = q.shape
     data = pages_k.data if quant else pages_k
@@ -505,13 +586,9 @@ def paged_attention_reference(q, pages_k, pages_v, block_tables, kv_lens, *,
     Same signature/semantics as ``paged_attention`` — the parity oracle for
     the kernel and the off-TPU fallback (it IS a gather, which is exactly
     what the kernel exists to avoid on TPU)."""
-    q, was_3d, q_lens, pages_k, pages_v, scale = _check_args(
-        q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale)
-    if was_3d:
-        return _paged_attention_xla(q[:, 0], pages_k, pages_v, block_tables,
-                                    kv_lens, layer, scale)
-    return _paged_attention_xla_mq(q, pages_k, pages_v, block_tables,
-                                   kv_lens, q_lens, layer, scale)
+    return paged_attention(q, pages_k, pages_v, block_tables, kv_lens,
+                           q_lens=q_lens, layer=layer, scale=scale,
+                           backend="xla")
 
 
 def _check_args(q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale):
@@ -534,8 +611,8 @@ def _check_args(q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale):
             pages_k, pages_v = pages_k[None], pages_v[None]
         pk, pv = pages_k, pages_v
     if pk.shape != pv.shape or pk.ndim != 5:
-        raise ValueError(f"pages must both be (L, N, H_kv, bs, Dh); got "
-                         f"{pk.shape} / {pv.shape}")
+        raise ValueError(f"pages must both be (L, N, H_kv / p, bs, p * Dh); "
+                         f"got {pk.shape} / {pv.shape}")
     was_3d = q.ndim == 3
     if was_3d:
         if q_lens is not None:
@@ -546,11 +623,13 @@ def _check_args(q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale):
         raise ValueError(f"q must be (B, H, Dh) or (B, Q, H, Dh); "
                          f"got {q.shape}")
     b, qw, h, dh = q.shape
-    hkv = pk.shape[2]
-    if h % hkv or pk.shape[4] != dh:
+    pack = max(pk.shape[4] // dh, 1)    # heads side by side in a page row
+    hkv = pk.shape[2] * pack
+    if h % hkv or pk.shape[4] != pack * dh:
         raise ValueError(f"q has {h} heads / Dh {dh} but pages carry "
-                         f"{hkv} kv heads / Dh {pk.shape[4]}; "
-                         "need H % H_kv == 0 and equal head dims")
+                         f"{pk.shape[2]} rows of {pk.shape[4]} a position "
+                         f"({hkv} kv heads); need rows of p * Dh and "
+                         "H % H_kv == 0")
     if block_tables.shape[0] != b or kv_lens.shape != (b,):
         raise ValueError(f"block_tables {block_tables.shape} / kv_lens "
                          f"{kv_lens.shape} do not match batch {b}")
@@ -574,9 +653,14 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
     q : (B, H, Dh) — decode form, one token per sequence — or (B, Q, H, Dh)
         for ragged multi-token chunks (``q_lens[b]`` live tokens per row,
         left-aligned; the rest is padding and outputs exactly 0).
-    pages_k / pages_v : (L, N, H_kv, bs, Dh) pool pages (or a single layer's
-        (N, H_kv, bs, Dh); ``layer`` then ignored). Never copied: the kernel
-        fetches only the pages the tables name.
+    pages_k / pages_v : (L, N, H_kv / p, bs, p * Dh) pool pages (or a single
+        layer's 4-D slice; ``layer`` then ignored). Never copied: the kernel
+        fetches only the pages the tables name. ``p`` (``lane_pack``) is
+        read off the last dims: with ``p`` > 1 each query head is laid into
+        the lanes of its KV head's slot of a row (zeros elsewhere), the same
+        launch runs over ``H_kv / p`` heads of ``p * Dh`` with a group of
+        ``p * g``, and each head keeps its own lanes of the output. ``scale``
+        comes from the true ``Dh``; at ``p`` = 1 nothing is added.
     block_tables : (B, nb) int32 — page ids in logical order; entries past a
         row's live pages may be anything in-range (the pool pads with its
         scratch page 0).
@@ -607,35 +691,33 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
         q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale)
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if backend == "xla":
-        if was_3d:
-            out = _paged_attention_xla(q[:, 0], pages_k, pages_v,
-                                       block_tables, kv_lens, layer, scale,
-                                       stats=return_stats)
-        else:
-            out = _paged_attention_xla_mq(q, pages_k, pages_v, block_tables,
-                                          kv_lens, q_lens, layer, scale,
-                                          stats=return_stats)
-        return out
-    if backend != "pallas":
+    if backend not in ("pallas", "xla"):
         raise ValueError(f"unknown paged-attention backend {backend!r}")
-    if interpret is None:
-        interpret = interpret_default()
-    out = _paged_attention_pallas(q, pages_k, pages_v, block_tables,
-                                  kv_lens, q_lens,
-                                  jnp.asarray(layer, jnp.int32), scale=scale,
-                                  interpret=interpret, stats=return_stats)
-    if return_stats:
-        o, m, l = out  # noqa: E741
-        return (o[:, 0], m[:, 0], l[:, 0]) if was_3d else (o, m, l)
-    return out[:, 0] if was_3d else out
+    if backend == "pallas":
+        if interpret is None:
+            interpret = interpret_default()
+        out = _paged_attention_pallas(
+            q, pages_k, pages_v, block_tables, kv_lens, q_lens,
+            jnp.asarray(layer, jnp.int32), scale=scale, interpret=interpret,
+            stats=return_stats)
+        if was_3d:
+            out = jax.tree_util.tree_map(lambda x: x[:, 0], out)
+        return out
+    kw = dict(pages_k=pages_k, pages_v=pages_v, block_tables=block_tables,
+              kv_lens=kv_lens, layer=layer, scale=scale, stats=return_stats)
+    if was_3d:
+        attend, q = functools.partial(_paged_attention_xla, **kw), q[:, 0]
+    else:
+        attend = functools.partial(_paged_attention_xla_mq, q_lens=q_lens,
+                                   **kw)
+    return _over_packed_rows(attend, q, pages_k, return_stats)
 
 
 def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):
     """Write one new KV row per sequence at its decode position.
 
-    The write half of the page contract: ``pages`` is (L, N, H, bs, Dh) with
-    ``layer`` naming the layer (or a single layer's (N, H, bs, Dh));
+    The write half of the page contract: ``pages`` is (L, N, H / p, bs,
+    p * Dh) with ``layer`` naming the layer (or a single layer's 4-D slice);
     ``block_tables`` (B, nb); ``offsets`` (B,) the position each row writes;
     ``rows`` (B, H, Dh). Rows whose table points at the pool's scratch page
     land there harmlessly. Returns the updated pages. The one-token case of
@@ -651,11 +733,12 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
                      layer=None):
     """Write a ragged chunk of new KV rows per sequence.
 
-    ``rows`` is (B, Q, H, Dh): row b's tokens t < q_lens[b] land at positions
-    ``starts[b] + t`` through its block table; padding tokens (and whole rows
-    with q_lens == 0) are redirected to the pool's scratch page 0, which is
-    never allocated to a request, so they can't corrupt live KV. Same layer
-    semantics as ``scatter_kv_rows``.
+    ``rows`` is (B, Q, H, Dh) (regrouped to the pages' (H / p, p * Dh):
+    adjacent heads, no data moves): row b's tokens t < q_lens[b] land at
+    positions ``starts[b] + t`` through its block table; padding tokens (and
+    whole rows with q_lens == 0) are redirected to the pool's scratch page
+    0, which is never allocated to a request, so they can't corrupt live KV.
+    Same layer semantics as ``scatter_kv_rows``.
 
     The write moves WHOLE PAGES: Q consecutive positions touch at most
     ``(Q + bs - 2) // bs + 1`` pages of a row's table; each is read, the new
@@ -695,6 +778,9 @@ def write_rows(pages, block_tables, starts, rows, q_lens, *, layer=None):
         raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
     bs = pages.shape[-2]
     b, qw = rows.shape[:2]
+    # a packed page row holds ``pages.shape[-1] // Dh`` adjacent heads side
+    # by side (``lane_pack``): the same bytes as the new rows, regrouped
+    rows = rows.reshape(b, qw, pages.shape[-3], pages.shape[-1])
     nbt = block_tables.shape[1]
     npg = (qw + bs - 2) // bs + 1
     entry = (starts // bs)[:, None] + jnp.arange(npg)     # (B, P) table slots

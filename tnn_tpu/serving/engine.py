@@ -703,6 +703,7 @@ class InferenceEngine:
             "kv_dtype": self.kv_dtype,
             "kv_bytes_per_token": self.pool.kv_bytes_per_token,
             "kv_scale_bytes_per_token": self.pool.kv_scale_bytes_per_token,
+            "kv_lane_pack": self.pool.lane_pack,
             "quant_weights": self.quant_weights,
             "tp_degree": self.tp,
             "kv_bytes_per_token_per_shard":
@@ -811,18 +812,19 @@ class InferenceEngine:
         """``(pages, heads)`` of a grid step of the paged kernel in the step
         program ``qw`` tokens wide: what the kernel's own launch derives
         from the same shapes (a TP shard's pool holds ``1 / tp`` of the
-        heads). None where that kernel is not the step's attention (a
-        windowed model)."""
+        heads; the kernel's "head" is a page row, ``kv_lane_pack`` KV heads
+        side by side, attended by that many query groups). None where that
+        kernel is not the step's attention (a windowed model)."""
         if self.pool.window:
             return None
         group = self._attn_groups.get(qw)
         if group is None:
             from ..ops.pallas.paged_attention import fetch_group
             pool, model = self.pool, self.model
+            _, _, rows, bs, width = pool.page_shape
             group = self._attn_groups[qw] = fetch_group(
-                bs=pool.block_size, dh=pool.head_dim,
-                hkv=pool.num_kv_heads // self.tp,
-                qg=qw * (model.num_heads // model.num_kv_heads),
+                bs=bs, dh=width, hkv=rows // self.tp,
+                qg=qw * (model.num_heads // rows),
                 page_dtype=(jnp.int8 if pool.kv_dtype == "int8"
                             else pool.dtype),
                 nb=self.blocks_per_seq)
@@ -1023,10 +1025,13 @@ class InferenceEngine:
         inputs' ``device_put`` and the asynchronous launch), not its
         compute — that is the device's, and shows as ``serve.fetch``. A
         paged step program ``qw`` tokens wide also says what a grid step of
-        its attention kernel fetches (``attn_pages``, ``attn_heads``)."""
+        its attention kernel fetches (``attn_pages``, ``attn_heads``) and
+        how many KV heads lie side by side in a page row of the pool
+        (``kv_lane_pack``)."""
         group = None if qw is None else self._attn_group(qw)
-        attrs = {} if group is None else dict(attn_pages=group[0],
-                                              attn_heads=group[1])
+        attrs = {} if group is None else dict(
+            attn_pages=group[0], attn_heads=group[1],
+            kv_lane_pack=self.pool.lane_pack)
         return self.tracer.span(
             "serve.dispatch", EventType.COMPUTE,
             step=self.step_seq if step is None else step, kind=kind,
@@ -1733,8 +1738,7 @@ class InferenceEngine:
         match what the SENDER exported — a sender with a different pool
         geometry/dtype would still verify, then crash the adopt write. A
         mismatch degrades to recompute-resume, never an error."""
-        shape = (self.pool.num_layers, self.pool.num_kv_heads,
-                 self.pool.block_size, self.pool.head_dim)
+        shape = self.pool.page_shape[:1] + self.pool.page_shape[2:]
         if self.pool.kv_dtype == "int8":
             return (len(leaves) == 4
                     and leaves[0].shape == shape

@@ -3,8 +3,9 @@ model) for continuous-batching inference.
 
 The pool owns two device arrays of fixed-size token pages per layer,
 
-    pages_k, pages_v : (L, num_blocks, H_kv, block_size, head_dim)
+    pages_k, pages_v : (L, num_blocks, H_kv / p, block_size, p * head_dim)
 
+(``page_shape``; ``p`` = ``lane_pack`` KV heads side by side in a page row)
 plus host-side bookkeeping: a free list, and a per-block refcount so a shared
 prompt prefix can be forked (``fork``) instead of copied. Sequences hold a
 *block table* — an ordered list of block ids — which the paged-attention
@@ -25,10 +26,20 @@ The ragged paged-attention kernel (``ops/pallas/paged_attention.py``) reads
 the pages *directly* — no gather — so the layout below is a cross-module
 contract, not an implementation detail:
 
-- A sequence's cache position ``p`` lives at
-  ``pages_*[layer, table[p // block_size], kv_head, p % block_size, :]``:
+- A sequence's cache position ``t`` lives at
+  ``pages_*[layer, table[t // block_size], kv_head // p, t % block_size,
+  (kv_head % p) * Dh : (kv_head % p + 1) * Dh]``:
   positions are contiguous within a block and ordered across the block
   table, while the blocks themselves may sit anywhere in the pool.
+- ``p`` (``lane_pack``, from ``ops.pallas.paged_attention.lane_pack``) is
+  the largest divisor of the KV heads one device holds that is at most
+  ``128 // Dh``: a page row then fills the 128 lanes and the pool rests in
+  the layout the kernel and the whole-page write read (at ``Dh`` = 64 an
+  unpacked pool was converted in and out by every step program). 1 at
+  ``Dh`` >= 128, for an odd head count, for int8 pages and for a windowed
+  pool, which is the plain ``(L, N, H_kv, bs, Dh)``. Whoever needs the
+  shape asks ``page_shape``; whole-page payloads (``export_blocks``,
+  ``write_block``, ``copy_blocks``) move ``(L, H_kv / p, bs, p * Dh)``.
 - Block tables handed to the kernel are right-padded with ``SCRATCH``
   (``padded_table``); the kernel clamps its page fetches to each row's last
   live block, so padding entries are never DMA'd on TPU.
@@ -39,8 +50,8 @@ contract, not an implementation detail:
   engine donates them through every jitted step, so after a step the
   previously-held arrays are invalid — always re-read ``pool.pages_*``.
 - With ``kv_dtype="int8"`` each ``pages_*`` is a ``QuantPages`` bundle:
-  int8 ``data`` in the layout above plus a per-(position, head) f32
-  ``scale`` sidecar of shape ``(L, N, H_kv, bs, 1)``. The bundle is a
+  int8 ``data`` in the layout above (``p`` = 1) plus a per-(position, head)
+  f32 ``scale`` sidecar of shape ``(L, N, H_kv, bs, 1)``. The bundle is a
   pytree, so it rides through every jitted step, donation, and
   ``update_pages`` as one value — scales can never be re-adopted without
   their pages or vice versa. Rows are quantized at scatter time and
@@ -79,7 +90,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.pallas.paged_attention import QuantPages
+from ..ops.pallas.paged_attention import QuantPages, lane_pack
 
 
 class PoolExhausted(RuntimeError):
@@ -148,6 +159,22 @@ class PagedKVPool:
         # only page creation here and in reset_pages cares; one sharding
         # covers both QuantPages leaves.
         self.sharding = sharding
+        # heads side by side in a page row (``lane_pack``'s rule), from the
+        # heads ONE device holds: a tensor-parallel shard then owns whole
+        # groups. A windowed pool stays unpacked: its readers
+        # (``tnn_eva_attention``, ``write_summaries``) take rows of ``Dh``.
+        flat = (self.num_layers, self.num_blocks, self.num_kv_heads,
+                self.block_size, self.head_dim)
+        local = sharding.shard_shape(flat)[2] if sharding is not None \
+            else self.num_kv_heads
+        self.lane_pack = 1 if window else lane_pack(
+            local, self.head_dim,
+            jnp.int8 if kv_dtype == "int8" else self.dtype)
+        # THE shape of ``pages_k`` / ``pages_v`` (an int8 pool's ``data``;
+        # its ``scale`` has the last axis 1): (L, N, H_kv / p, bs, p * Dh)
+        self.page_shape = flat[:2] + (
+            self.num_kv_heads // self.lane_pack, self.block_size,
+            self.lane_pack * self.head_dim)
         self.reset_pages()
         # LIFO free list: freshly freed blocks are reused first (their pages
         # are warmest); scratch blocks never enter it
@@ -725,8 +752,7 @@ class PagedKVPool:
         zeroed pages must never be matchable. Under tensor parallelism the
         puts honor ``self.sharding``, so a crash reset purges EVERY shard's
         pages, not just the default device's."""
-        shape = (self.num_layers, self.num_blocks, self.num_kv_heads,
-                 self.block_size, self.head_dim)
+        shape = self.page_shape
 
         # explicit puts, not jnp.zeros: recovery runs inside the step's
         # TNN_DEBUG_SYNC transfer guard, where eager jnp ops (which commit
@@ -802,12 +828,12 @@ class PagedKVPool:
 @jax.named_scope("kv_write")
 def write_block(pages, block, payload):
     """Write one whole page at ``block`` across every layer (the host-tier
-    re-admission's device half). pages: (L, N, H, bs, Dh); block: scalar
-    int32 (traced — one compiled fn serves every block id); payload:
-    (L, H, bs, Dh). Under QuantPages the payload is itself a QuantPages of
-    slices, so the int8 data and its f32 scale sidecar are re-adopted
-    together — a readmitted block can never dequantize against stale
-    scales.
+    re-admission's device half). pages: the pool's (L, N, H / p, bs,
+    p * Dh); block: scalar int32 (traced — one compiled fn serves every
+    block id); payload: (L, H / p, bs, p * Dh). Under QuantPages the
+    payload is itself a QuantPages of slices, so the int8 data and its f32
+    scale sidecar are re-adopted together — a readmitted block can never
+    dequantize against stale scales.
     """
     if isinstance(pages, QuantPages):
         return QuantPages(write_block(pages.data, block, payload.data),

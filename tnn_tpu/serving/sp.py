@@ -22,7 +22,7 @@ engine (``sp``×``tp`` is rejected at construction).
 Layout (shard s of sp):
 
     every param leaf                                     -> P() (replicated)
-    pages_k / pages_v  (L, N, H_kv, bs, Dh)  axis 1      -> P(None, "seq")
+    pages_k / pages_v  (L, N, H_kv/p, bs, p*Dh) axis 1   -> P(None, "seq")
     block tables       (sp, B, nb) stacked per-shard     -> P("seq")
     tokens / offsets / kv_lens / sampling params         -> P() (replicated)
 
@@ -49,8 +49,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..parallel import mesh as mesh_lib
 from . import step_build
 
-# The pool's (L, N, H_kv, bs, Dh) arrays split on the BLOCK axis. Used as a
-# pytree prefix, so an int8 pool's QuantPages (data + scale sidecar, both
+# The pool's (L, N, H_kv / p, bs, p * Dh) arrays split on the BLOCK axis. Used
+# as a pytree prefix, so an int8 pool's QuantPages (data + scale sidecar, both
 # rank 5 with blocks on axis 1) shard as one unit — scales travel with
 # their pages.
 PAGE_SPEC = P(None, "seq", None, None, None)

@@ -20,7 +20,13 @@ Layout (shard s of tp):
     fc/bias     (4D,)            columns                  -> P(model)
     proj/kernel (4D, D)          rows                     -> P(model, None)
     out_bias / proj/bias / ln* / wte / wpe                -> replicated
-    pages_k / pages_v  (L, N, H_kv, bs, Dh)  axis 2       -> P(None, None, model)
+    pages_k / pages_v  (L, N, H_kv/p, bs, p*Dh)  axis 2  -> P(None, None, model)
+
+Axis 2 of the pool holds page ROWS, ``p`` KV heads side by side
+(``kv_pool.PagedKVPool.lane_pack``). The pool picks ``p`` from the heads ONE
+shard holds (``sharding.shard_shape``), so a shard always owns whole rows:
+2 of 4 heads a shard pack in pairs, an odd 5 of GPT-2 large's 20 at ``tp`` =
+4 stay unpacked (the cost of a pool of rows of 64, not an error).
 
 The fused qkv kernel's columns are laid out ``[q | k | v]`` with heads
 contiguous inside each section, so a flat column split would hand shard 0 a
@@ -48,10 +54,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel import mesh as mesh_lib
 
-# The pool's (L, N, H_kv, bs, Dh) arrays split on the head axis. Used as a
-# pytree prefix, so an int8 pool's QuantPages (data + scale sidecar, both
-# rank 5 with heads on axis 2) shard as one unit — scales travel with their
-# heads.
+# The pool's (L, N, H_kv / p, bs, p * Dh) arrays split on axis 2, the page
+# rows (whole groups of p heads). Used as a pytree prefix, so an int8 pool's
+# QuantPages (data + scale sidecar, both rank 5 with heads on axis 2, p = 1)
+# shard as one unit — scales travel with their heads.
 PAGE_SPEC = P(None, None, "model", None, None)
 
 
