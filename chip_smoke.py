@@ -85,6 +85,11 @@ CHIP = dict(
     sharer_lens=(700, 130),
     kernel=dict(layers=2, blocks=512, heads=12, kv_heads=12, block_size=16,
                 head_dim=64, batch=8, table=64),
+    # the latent kernel and the grouped expert product at Mistral Small 4's
+    # widths: pages of 128 rows of 384, 32 heads; experts of 2,048 x 4,096
+    latent=dict(blocks=256, block_size=128, row=384, value=256, heads=32,
+                batch=8, table=24, chunk=64),
+    experts=dict(held=8, hidden=2048, width=4096, tokens=(32, 2048)),
     train_model="cifar100_wrn16_8", train_batch=256, train_classes=100,
     degree=4, mesh_steps=5)
 REHEARSAL = dict(
@@ -92,6 +97,9 @@ REHEARSAL = dict(
     max_new=4, prompt_lens=(5, 17, 40), prefix_len=32, sharer_lens=(70, 45),
     kernel=dict(layers=1, blocks=16, heads=2, kv_heads=2, block_size=16,
                 head_dim=64, batch=3, table=8),
+    latent=dict(blocks=16, block_size=8, row=128, value=32, heads=4,
+                batch=3, table=6, chunk=8),
+    experts=dict(held=4, hidden=256, width=128, tokens=(8, 64)),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
 
@@ -233,6 +241,70 @@ def phase_kernel(cfg) -> list:
                         failures.append(
                             f"kernel {fname}/{pname}/stats={stats}/"
                             f"holes={tname}: error above {tol}")
+    return failures + _latent_and_expert_kernels(cfg, rand, rng)
+
+
+def _latent_and_expert_kernels(cfg, rand, rng) -> list:
+    """``tnn_mla_attention`` and ``tnn_expert_gmm`` against their XLA forms
+    (the gather and one dense product an expert), decode and chunk shapes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas.expert_gmm import expert_gmm, row_tile
+    from tnn_tpu.ops.pallas.mla_attention import mla_attention
+
+    interpret, failures = cfg["rehearse"], []
+
+    def check(name, got, want, tol):
+        g, w = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        err = float(np.max(np.abs(g - w)))
+        ok = bool(np.isfinite(g).all()) and err <= tol
+        log(f"{name:28s} max|err| {err:9.2e} tol {tol:6.0e} "
+            f"{'ok' if ok else 'FAIL'}")
+        if not ok:
+            failures.append(f"kernel {name}: error above {tol}")
+
+    k = cfg["latent"]
+    B, nb, bs, h = k["batch"], k["table"], k["block_size"], k["heads"]
+    pages = rand((2, k["blocks"], 1, bs, k["row"]))
+    tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
+    cap = nb * bs
+    kv_lens = jnp.asarray(np.array([1, bs, bs + 1, cap // 3, cap // 2 - 1,
+                                    cap - bs - 5, cap - 24, cap])[:B]
+                          .clip(1, cap), jnp.int32)
+    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
+        q = rand((B, qw, h, k["row"]))
+        q_lens = jnp.minimum(jnp.asarray(
+            np.array([qw, 1, qw // 2 + 1] * B)[:B], jnp.int32), kv_lens)
+        kw = dict(value_dim=k["value"], q_lens=q_lens, layer=1,
+                  scale=k["row"] ** -0.5)
+        check(f"mla {fname}",
+              mla_attention(q, pages, tables, kv_lens, backend="pallas",
+                            interpret=interpret, **kw),
+              mla_attention(q, pages, tables, kv_lens, backend="xla", **kw),
+              KERNEL_TOL["bf16"])
+    e = cfg["experts"]
+    n, f, d = e["held"], e["hidden"], e["width"]
+    gate, up, down = (rand((n, f, d)) * (d ** -0.5) for _ in range(3))
+    for tokens in e["tokens"]:
+        tile = row_tile(4 * tokens)
+        # every expert but the last gets rows (uneven), two tiles are dead
+        sizes = rng.multinomial(tokens, np.ones(n - 1) / (n - 1))
+        tile_expert = np.repeat(np.arange(n - 1), -(-sizes // tile))
+        live = len(tile_expert)
+        tile_expert = np.concatenate([tile_expert, [tile_expert[-1]] * 2])
+        x = np.zeros((len(tile_expert) * tile, d), np.float32)
+        at = 0
+        for cnt in sizes:
+            x[at:at + cnt] = rng.standard_normal((cnt, d))
+            at += -(-cnt // tile) * tile
+        args = (jnp.asarray(x, jnp.bfloat16), gate, up, down,
+                jnp.asarray(tile_expert, jnp.int32), jnp.int32(live))
+        got = expert_gmm(*args, tile=tile, backend="pallas",
+                         interpret=interpret)
+        want = expert_gmm(*args, tile=tile, backend="xla")
+        check(f"expert_gmm {tokens} rows, tile {tile}", got[:live * tile],
+              want[:live * tile], 4 * KERNEL_TOL["bf16"])
     return failures
 
 

@@ -112,12 +112,30 @@ def llama_lm():
     return model, params
 
 
+@pytest.fixture(scope="session")
+def mistral_lm():
+    """The block ``mistral-small4-ep4.decode-long`` runs, at test sizes:
+    latent (MLA) attention over latent pages, 8 of 16 experts held beside a
+    shared one, float32, the suites' vocabulary and length. It refuses the
+    prefix cache, speculation, ``tp``, ``sp`` and int8 pages: the suites take
+    it where they pass none of them."""
+    from tnn_tpu import models
+    from tnn_tpu.core.dtypes import DTypePolicy
+
+    model = models.create("mistral_small4_tiny", vocab_size=128, max_len=64,
+                          policy=DTypePolicy(io="float32", param="float32",
+                                             compute="float32"))
+    params = model.init(jax.random.PRNGKey(2), (1, 8))["params"]
+    return model, params
+
+
 @pytest.fixture
 def lm(request, family):
-    """The model of a test's ``family`` case ("gpt2" | "llama"): the test
-    module's own ``tiny_lm``, or ``llama_lm``."""
+    """The model of a test's ``family`` case ("gpt2" | "llama" | "mistral"):
+    the test module's own ``tiny_lm``, ``llama_lm`` or ``mistral_lm``."""
     return request.getfixturevalue(
-        {"gpt2": "tiny_lm", "llama": "llama_lm"}[family])
+        {"gpt2": "tiny_lm", "llama": "llama_lm",
+         "mistral": "mistral_lm"}[family])
 
 
 # -- test tiers ---------------------------------------------------------------

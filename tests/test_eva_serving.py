@@ -178,12 +178,18 @@ def test_the_engine_serves_the_references_tokens(model, weights, forward,
         assert len(out[rid]) == 60 and gap.max() < TOL
 
 
+@pytest.mark.parametrize("ramp", [8, 1])
 def test_the_overlapped_loop_serves_the_same_tokens(model, weights,
-                                                    monkeypatch):
-    """``tnn-serve``'s default loop (a step dispatched before the last one is
-    fetched, never over a window's end) against the synchronous one, with
-    every packed step held to the one-writer invariant and the pool's
-    bookkeeping checked at every mutation (TNN_POOL_DEBUG)."""
+                                                    monkeypatch, ramp):
+    """``tnn-serve``'s default loop (steps dispatched before the last one is
+    fetched, never over a window's end; ``ramp`` 1: a step deeper with every
+    adoption, so a dozen are queued between two windows' ends) against the
+    synchronous one, with every packed step held to the one-writer invariant
+    and the pool's bookkeeping checked at every mutation (TNN_POOL_DEBUG)."""
+    from tnn_tpu.serving import engine as engine_lib
+
+    monkeypatch.setattr(engine_lib, "SPECULATE_RAMP", ramp)
+    monkeypatch.setattr(engine_lib, "SPECULATE_AHEAD_S", 3600.0)
     monkeypatch.setenv("TNN_POOL_DEBUG", "1")
     rng = np.random.default_rng(11)
     ps = [rng.integers(0, 320, n).astype(np.int32) for n in (29, 50, 64)]

@@ -224,6 +224,57 @@ def test_windowed_step_compiles_for_the_chip_with_no_pool_copy(
     assert not [line for line in text.splitlines() if pool.search(line)]
 
 
+# -- the latent model's step (PR 32): the same question at its widths ---------
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk64"])
+def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
+        form, one_chip, no_compile_cache, alarm):
+    """Mistral Small 4's block as published (32 heads over latent rows of 256
+    + 64 in 384 lanes, experts of 2,048 x 4,096; 4 of them held here so that
+    the compile is quick), pages of 128, 32 rows, 2 layers: the chip's
+    compiler accepts ``tnn_mla_attention`` and ``tnn_expert_gmm`` in the
+    decode and the chunk form, and the step around them makes NO pool-shaped
+    copy: a latent row fills whole lanes, so the ONE pool array rests in the
+    layout its write and its kernel read, and the value stub rides along."""
+    from tnn_tpu import models
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    model = models.create("mistral_small4", num_layers=2, held_experts=4)
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    shape = PagedKVPool(2, 1, model.latent_row, 2, 128, dtype=jnp.bfloat16,
+                        latent=True).page_shape
+    shape = shape[:1] + (512,) + shape[2:]
+    assert shape == (2, 512, 1, 128, 384)
+    pages, stub = spec(shape, jnp.bfloat16), spec((2, 1, 1, 8, 128),
+                                                   jnp.bfloat16)
+    tables, lens = spec((32, 256), jnp.int32), spec((32,), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(model.apply_decode_paged,
+                              donate_argnums=(2, 3)).lower(
+                params, spec((32,), jnp.int32), pages, stub, tables, lens)
+        else:
+            lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
+                params, spec((32, 64), jnp.int32), pages, stub, tables, lens,
+                lens)
+        text = lowered.compile().as_text()
+    assert "tnn_mla_attention" in text and "tnn_expert_gmm" in text
+    assert "tnn_paged_attention" not in text
+    dims = ",".join(map(str, shape))
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+    assert not [line for line in text.splitlines() if pool.search(line)]
+    layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
+                         text.split("\n", 1)[0].split(")->(")[0])
+    assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
+
+
 # -- the one-writer invariant --------------------------------------------------
 
 

@@ -182,6 +182,120 @@ class TestSpeculativeSteps:
             assert on.get(rid, list(eng.requests[rid].out_tokens)) == want
 
 
+class TestSpeculationDepth:
+    """A batch that has been closed for a while queues several steps ahead
+    (``engine.SPECULATE_*``): a host that stands still for a moment then
+    leaves the device busy. Here the ramp is a step an adoption and the
+    time limit is out of the way, so a dozen steps are enough to go deep."""
+
+    KW = dict(num_blocks=64, block_size=4, max_batch_size=4, max_seq_len=64)
+
+    @pytest.fixture(autouse=True)
+    def fast_ramp(self, monkeypatch):
+        from tnn_tpu.serving import engine
+
+        monkeypatch.setattr(engine, "SPECULATE_RAMP", 1)
+        monkeypatch.setattr(engine, "SPECULATE_AHEAD_S", 3600.0)
+
+    @staticmethod
+    def _turn(eng):
+        """One turn of the overlapped drive loop; how deep it queued."""
+        if eng.in_flight is None:
+            eng.begin_step()
+        while eng.try_speculate():
+            pass
+        depth = len(eng.in_flight.ahead)
+        eng.run_deferred()
+        eng.finish_step()
+        return depth
+
+    @pytest.mark.parametrize("family,temperature", [
+        ("gpt2", 0.0), ("gpt2", 0.8), ("llama", 0.8), ("mistral", 0.0)])
+    def test_deep_chain_is_exact(self, lm, family, temperature):
+        """Rows of three lengths decode 40 tokens each: the chain reaches
+        its cap, shortens as the rows near their last token, and serves
+        the synchronous loop's tokens (sampled ones too: every step draws
+        the key it would have drawn)."""
+        from tnn_tpu.serving import engine
+
+        model, params = lm
+        kw = dict(self.KW, prefix_cache=False)
+
+        def submit(eng):
+            return [eng.submit(p, 40, temperature=temperature)
+                    for p in _prompts()]
+
+        sync = InferenceEngine(model, params, **kw, overlap=False)
+        rids = submit(sync)
+        want = sync.run_until_complete()
+        eng = InferenceEngine(model, params, **kw, overlap=True)
+        assert submit(eng) == rids
+        depths = []
+        while eng.has_work or eng.in_flight is not None:
+            depths.append(self._turn(eng))
+        assert max(depths) == engine.SPECULATE_MAX
+        assert depths[-1] == 0, "a step was queued behind a row's last"
+        assert {r: list(eng.requests[r].out_tokens) for r in rids} == want
+        assert eng.metrics.overlap_rebuilds == 0
+        assert eng.pool.num_allocated == 0 and eng.in_flight is None
+
+    @pytest.mark.parametrize("family", ["gpt2", "mistral"])
+    def test_an_arrival_rolls_the_whole_chain_back(self, lm, family):
+        """An arrival while five steps are queued: all are rolled back,
+        their blocks freed and their keys drawn again in order, so every
+        request (sampled at temperature) reads as in the synchronous loop
+        with the same arrival."""
+        model, params = lm
+        kw = dict(self.KW, prefix_cache=False)
+        prompts = _prompts()
+
+        sync = InferenceEngine(model, params, **kw, overlap=False)
+        rids = [sync.submit(p, 30, temperature=0.7) for p in prompts[:2]]
+        for _ in range(11):
+            sync.step()
+        rids.append(sync.submit(prompts[2], 30, temperature=0.7))
+        want = sync.run_until_complete()
+
+        eng = InferenceEngine(model, params, **kw, overlap=True)
+        assert [eng.submit(p, 30, temperature=0.7)
+                for p in prompts[:2]] == rids[:2]
+        for _ in range(10):
+            self._turn(eng)
+        if eng.in_flight is None:
+            eng.begin_step()
+        while eng.try_speculate():
+            pass
+        assert len(eng.in_flight.ahead) >= 5
+        held = eng.pool.num_allocated
+        assert eng.submit(prompts[2], 30, temperature=0.7) == rids[2]
+        eng.finish_step()
+        assert eng.metrics.overlap_rebuilds == 1 and eng.in_flight is None
+        assert eng.pool.num_allocated <= held
+        assert len(eng._reuse_keys) >= 5
+        got = eng.run_until_complete()
+        assert got == want
+        assert eng.pool.num_allocated == 0 and not eng._reuse_keys
+
+    def test_the_chain_deepens_a_step_at_a_time(self, tiny_lm, monkeypatch):
+        """With the ramp at 4 adoptions a step and a time limit of three
+        of the last step's lengths, the depth reads 1, 1, 1, 1, 2, ... and
+        stops at 3."""
+        from tnn_tpu.serving import engine
+
+        monkeypatch.setattr(engine, "SPECULATE_RAMP", 4)
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        eng.submit(_prompts()[0], 40)
+        self._turn(eng)                     # the prompt's mixed step
+        depths = []
+        for _ in range(16):
+            monkeypatch.setattr(engine, "SPECULATE_AHEAD_S",
+                                2.5 * eng._last_step_latency_s)
+            depths.append(self._turn(eng))
+        assert depths[:9] == [1, 1, 1, 1, 2, 2, 2, 2, 3]
+        assert max(depths) == 3
+
+
 class TestDeferredPhase:
     def test_publish_never_lands_for_terminated(self, tiny_lm):
         """A deferred prefix publish queued at commit is guarded at RUN

@@ -308,7 +308,7 @@ class TestEngineTiny:
             assert out[rid] == _greedy_ref(model, params, p, 10,
                                            eng.assembly_len)
 
-    @pytest.mark.parametrize("family", FAMILIES)
+    @pytest.mark.parametrize("family", FAMILIES + ["mistral"])
     def test_paged_preemption_parity(self, lm, family):
         """Preemption-recovery (recompute-requeue) leaves every stream
         byte-identical to the offline reference, with the prefix cache off:

@@ -335,8 +335,9 @@ def main(argv=None):
         # error should die before that, not after
         params = None
 
-    # a model whose state is not K/V blocks (EVA) says in one sentence what
-    # it does not serve with, before any weights are made
+    # a model whose state is not K/V blocks of heads (EVA's window and
+    # summaries, latent rows) says in one sentence what it does not serve
+    # with, before any weights are made
     refusal = refuse_windowed(
         model, prefix_cache=not args.no_prefix_cache,
         spec=args.spec != "off", tp=args.tp, sp=args.sp,

@@ -17,9 +17,10 @@ import jax.numpy as jnp
 
 from ..core import rng as rnglib
 from ..core.module import Module, register_module
-from ..nn.attention import MultiHeadAttention
+from ..nn.attention import LatentAttention, MultiHeadAttention
 from ..nn.embedding import Embedding
 from ..nn.layers import Dense
+from ..nn.moe import ExpertShare
 from ..nn.norms import RMSNorm
 from ..nn.transformer import PagedDecoder
 
@@ -38,10 +39,17 @@ class LlamaBlock(Module):
                  kv_cache_dtype: Optional[str] = None,
                  norm_eps: float = 1e-6, norm_unit_offset: bool = False,
                  residual_f32: bool = False, window: Optional[int] = None,
-                 chunk: Optional[int] = None, name=None, policy=None):
+                 chunk: Optional[int] = None, latent: Optional[dict] = None,
+                 experts: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
         self.mlp_hidden = int(mlp_hidden)
+        # the block is GIVEN its attention (heads: K/V of every position;
+        # eva: ``window`` and ``chunk``; latent: ``latent``, the keywords of
+        # ``nn.attention.LatentAttention``) and its feed-forward (gated, or
+        # ``experts``: the keywords of ``nn.moe.ExpertShare``)
+        self.latent = dict(latent) if latent else None
+        self.experts = dict(experts) if experts else None
         self.num_kv_heads = int(num_kv_heads) if num_kv_heads else self.num_heads
         self.rope_theta = float(rope_theta)
         self.backend = backend
@@ -56,12 +64,19 @@ class LlamaBlock(Module):
         norm = dict(eps=self.norm_eps, unit_offset=self.norm_unit_offset,
                     policy=p)
         self.ln1 = RMSNorm(**norm)
-        self.attn = MultiHeadAttention(
-            num_heads, causal=True, backend=backend,
-            num_kv_heads=self.num_kv_heads, rope_theta=self.rope_theta,
-            use_bias=False, kv_cache_dtype=kv_cache_dtype, window=window,
-            chunk=chunk, policy=p)
+        if self.latent:
+            self.attn = LatentAttention(num_heads, norm_eps=self.norm_eps,
+                                        backend=backend, policy=p,
+                                        **self.latent)
+        else:
+            self.attn = MultiHeadAttention(
+                num_heads, causal=True, backend=backend,
+                num_kv_heads=self.num_kv_heads, rope_theta=self.rope_theta,
+                use_bias=False, kv_cache_dtype=kv_cache_dtype, window=window,
+                chunk=chunk, policy=p)
         self.ln2 = RMSNorm(**norm)
+        self.moe = ExpertShare(policy=p, **self.experts) \
+            if self.experts else None
         self.gate = Dense(self.mlp_hidden, use_bias=False, policy=p)
         self.up = Dense(self.mlp_hidden, use_bias=False, policy=p)
         # the down projection needs the model dim, known only at init —
@@ -72,14 +87,19 @@ class LlamaBlock(Module):
         k1, k2, k3, k4, k5, k6 = jax.random.split(rng, 6)
         down = Dense(d, use_bias=False, policy=self.policy)
         hidden_shape = tuple(input_shape[:-1]) + (self.mlp_hidden,)
-        return {
+        params = {
             "ln1": self.ln1.init(k1, input_shape)["params"],
             "attn": self.attn.init(k2, input_shape)["params"],
             "ln2": self.ln2.init(k3, input_shape)["params"],
-            "gate": self.gate.init(k4, input_shape)["params"],
-            "up": self.up.init(k5, input_shape)["params"],
-            "down": down.init(k6, hidden_shape)["params"],
-        }, {}
+        }
+        if self.moe is not None:
+            params["moe"] = self.moe.init(k4, input_shape)["params"]
+        else:
+            params.update(
+                gate=self.gate.init(k4, input_shape)["params"],
+                up=self.up.init(k5, input_shape)["params"],
+                down=down.init(k6, hidden_shape)["params"])
+        return params, {}
 
     @jax.named_scope("mlp")
     def _swiglu(self, params, h, train):
@@ -105,10 +125,23 @@ class LlamaBlock(Module):
     def _add(self, x, h):
         return x + h.astype(x.dtype)
 
-    def _mlp_residual(self, params, x, train=False):
+    def _mlp_residual(self, params, x, train=False, live=None):
+        if self.moe is not None:
+            return self._experts_residual(params, x, live)
         with jax.named_scope("mlp"):
             h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
             return self._add(x, self._swiglu(params, h, train))
+
+    def _experts_residual(self, params, x, live):
+        """x + experts(rms(x)): the norm (on the float32 residual, so the
+        router sees float32) counts as ``moe_route``; ``live`` (B, Q) marks
+        a step's tokens that are no padding."""
+        with jax.named_scope("moe_route"):
+            h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
+        y, _ = self.moe.apply({"params": params["moe"], "state": {}}, h,
+                              live=live)
+        with jax.named_scope("moe_shared"):
+            return self._add(x, y)
 
     def _apply(self, params, state, x, *, train, rng):
         k1 = rnglib.split_for(rng, 1)[0]
@@ -135,7 +168,13 @@ class LlamaBlock(Module):
             pages_v, block_tables, offsets, layer=layer, q_lens=q_lens)
         with jax.named_scope("attn_out"):
             x = self._add(x, h)
-        return self._mlp_residual(params, x), pages_k, pages_v
+        live = None
+        if self.moe is not None:
+            # a padding position takes no expert: past a row's live tokens,
+            # or (the decode form) a row whose table is the scratch page
+            live = (jnp.arange(x.shape[1])[None, :] < q_lens[:, None]
+                    if q_lens is not None else block_tables[:, :1] > 0)
+        return self._mlp_residual(params, x, live=live), pages_k, pages_v
 
     def output_shape(self, input_shape):
         return tuple(input_shape)
@@ -152,7 +191,8 @@ class LlamaBlock(Module):
 
 
 _BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
-                   "residual_f32": False, "window": None, "chunk": None}
+                   "residual_f32": False, "window": None, "chunk": None,
+                   "latent": None, "experts": None}
 
 
 def _block_options(m):
@@ -187,8 +227,12 @@ class Llama(PagedDecoder, Module):
                  norm_eps: float = 1e-6, norm_unit_offset: bool = False,
                  residual_f32: bool = False, window: Optional[int] = None,
                  chunk: Optional[int] = None, num_pred_heads: int = 1,
-                 name=None, policy=None):
+                 latent: Optional[dict] = None,
+                 experts: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        self.latent = dict(latent) if latent else None
+        self.experts = dict(experts, held=list(experts["held"])) \
+            if experts else None
         self.norm_eps = float(norm_eps)
         self.norm_unit_offset = bool(norm_unit_offset)
         self.residual_f32 = bool(residual_f32)
@@ -219,8 +263,12 @@ class Llama(PagedDecoder, Module):
                                   norm_eps=norm_eps,
                                   norm_unit_offset=norm_unit_offset,
                                   residual_f32=residual_f32, window=window,
-                                  chunk=chunk, policy=p)
+                                  chunk=chunk, latent=latent, experts=experts,
+                                  policy=p)
                        for _ in range(num_layers)]
+        if self.latent:     # one cached row a token, no head axis
+            self.num_kv_heads = 1
+            self.latent_row = self.blocks[0].attn.latent_row
         self.ln_f = RMSNorm(eps=norm_eps, unit_offset=norm_unit_offset,
                             policy=p)
 
@@ -345,6 +393,63 @@ def evabyte_tiny(**kw):
                window=32, chunk=4, num_pred_heads=2, max_len=256)
     cfg.update(kw)
     return evabyte(**cfg)
+
+
+def mistral_small4(num_layers: int = 6, held_experts: int = 32,
+                   vocab: int = 32768, **kw):
+    """Mistral-Small-4-119B-2603 (https://huggingface.co/mistralai/
+    Mistral-Small-4-119B-2603, config.json) as ONE chip of four that share
+    each layer serves it: 6 of the 36 layers, every width as published
+    (4,096 wide, 32 heads, latent attention with ``q_lora_rank`` 1,024,
+    ``kv_lora_rank`` 256, head dims 64 + 64 and 128, YaRN rotary), the
+    router over all 128 experts and 4 a token, experts 0 .. ``held_experts``
+    - 1 of width 2,048 held here beside the shared one, rows 0 .. ``vocab``
+    - 1 of the 131,072-token vocabulary, bf16 weights. The vision tower is
+    not served."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    cfg = dict(
+        vocab_size=vocab, max_len=1048576, d_model=4096, num_heads=32,
+        mlp_hidden=2048,
+        latent=dict(q_rank=1024, kv_rank=256, nope_dim=64, rope_dim=64,
+                    v_dim=128, rope=dict(
+                        rope_theta=10000.0, factor=128.0,
+                        original_max_position_embeddings=8192, beta_fast=32,
+                        beta_slow=1, mscale=1, mscale_all_dim=1,
+                        llama_4_scaling_beta=0.1)),
+        experts=dict(num_experts=128, held=range(held_experts), top_k=4,
+                     hidden=2048, shared=1))
+    cfg.update(kw)
+    return Llama(num_layers=num_layers, tie_embeddings=False, norm_eps=1e-6,
+                 residual_f32=True, **cfg)
+
+
+def mistral_small4_tiny(**kw):
+    """Mistral Small 4's block at test sizes: 2 layers, 64 wide, 4 heads,
+    latent 32 + 16 (query rank 48), 8 of 16 experts of width 32 held, 4 a
+    token, one shared expert; YaRN over 32 original positions. Float32
+    unless told otherwise: at 64 wide and 16 experts a bf16 hidden state
+    breaks a near-tie of the router the other way every few dozen tokens,
+    and one flipped expert of four is a quarter of a layer's routed output,
+    so a CPU rehearsal's comparison would hang on the seed."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="float32", param="float32",
+                                        compute="float32"))
+    cfg = dict(
+        num_layers=2, vocab=256, max_len=256, d_model=64, num_heads=4,
+        mlp_hidden=32,
+        latent=dict(q_rank=48, kv_rank=32, nope_dim=16, rope_dim=16, v_dim=16,
+                    rope=dict(rope_theta=10000.0, factor=4.0,
+                              original_max_position_embeddings=32,
+                              beta_fast=32, beta_slow=1, mscale=1,
+                              mscale_all_dim=1, llama_4_scaling_beta=0.1)),
+        experts=dict(num_experts=16, held=range(8), top_k=4, hidden=32,
+                     shared=1))
+    cfg.update(kw)
+    return mistral_small4(**cfg)
 
 
 def llama_small(**kw):

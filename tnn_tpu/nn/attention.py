@@ -597,3 +597,269 @@ class MultiHeadAttention(Module):
         if self.window:
             cfg["window"], cfg["chunk"] = self.window, self.chunk
         return cfg
+
+
+# -- latent (MLA) attention -----------------------------------------------
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's ``dim / 2`` rotary frequencies (numpy, float32): the plain ones
+    ``theta^(-2i/dim)`` for the pairs that turn more than ``beta_fast`` times
+    over the ``original`` positions, those over ``factor`` for the pairs that
+    turn less than ``beta_slow`` times, a linear ramp between the two pair
+    indices in between."""
+    import numpy as np
+
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def apply_rope_pairs(x, positions, inv_freq):
+    """Rotary embedding over ADJACENT pairs ``(2i, 2i + 1)`` of the last dim
+    (``rope_interleave``): x (..., S, d), positions (..., S) broadcastable
+    against x's leading dims, ``inv_freq`` (d / 2,)."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _positions(offset, b, s):
+    """(B, S) absolute positions of a step's tokens: ``offset`` a scalar or
+    a (B,) array of each row's first position."""
+    if getattr(offset, "ndim", 0):
+        return offset[:, None] + jnp.arange(s)
+    return jnp.broadcast_to(offset + jnp.arange(s), (b, s))
+
+
+@register_module("latent_attention")
+class LatentAttention(Module):
+    """Multi-head latent attention (MLA) over (N, S, D): queries through a
+    low-rank bottleneck, and ONE cached row a token, ``[c_kv | k_rope]``
+    (``kv_rank + rope_dim`` values, no head axis), that every head's key and
+    value are made from.
+
+    ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` -> heads of ``[q_nope | q_rope]``;
+    ``[c_kv | k_r] = x W_kva``, ``c_kv = norm(c_kv)``, ``k_rope = rope(k_r)``
+    (one for all heads); ``[k_nope | v] = c_kv W_kvb`` a head. Rotary over
+    adjacent pairs at YaRN frequencies; the softmax scale is
+    ``(nope + rope)^-1/2 * m^2`` with ``m = 0.1 * mscale_all_dim * ln(factor)
+    + 1``, and the query of position ``t`` is first scaled by ``1 +
+    scaling_beta * ln(1 + floor(t / original))``.
+
+    ``_apply`` / ``apply_cached`` are the plain EXPANDED form (keys and
+    values of every head made from ``c_kv``). ``apply_paged`` is the
+    ABSORBED form, for a decode row and a prompt chunk alike: ``W_kvb``'s key
+    half is folded into the query and its value half into the output, so
+    that a cached row is key (all of it) and value (its first ``kv_rank``
+    values) at once, and ``ops.pallas.mla_attention`` reads each page ONCE.
+    """
+
+    def __init__(self, num_heads: int, q_rank: int, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int, rope: dict,
+                 norm_eps: float = 1e-6, backend: str = "xla", name=None,
+                 policy=None):
+        super().__init__(name=name, policy=policy)
+        self.num_heads, self.q_rank = int(num_heads), int(q_rank)
+        self.kv_rank, self.nope_dim = int(kv_rank), int(nope_dim)
+        self.rope_dim, self.v_dim = int(rope_dim), int(v_dim)
+        self.rope = dict(rope)
+        self.norm_eps = float(norm_eps)
+        self.backend = backend
+        r = self.rope
+        self.original = int(r.get("original_max_position_embeddings", 1 << 30))
+        self.scaling_beta = float(r.get("llama_4_scaling_beta", 0.0))
+        factor = float(r.get("factor", 1.0))
+        m = 0.1 * float(r.get("mscale_all_dim", 0.0)) * math.log(factor) + 1.0
+        self.scale = (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+        self.inv_freq = yarn_inv_freq(
+            self.rope_dim, float(r["rope_theta"]), factor, self.original,
+            float(r.get("beta_fast", 32)), float(r.get("beta_slow", 1)))
+
+    # what one cached row holds, and its width in the pool (whole lanes)
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        from ..ops.pallas.mla_attention import row_width
+
+        return row_width(self.latent_dim)
+
+    def _init(self, rng, input_shape):
+        d, h = input_shape[-1], self.num_heads
+        init = initializers.get("xavier_uniform")
+        ks = jax.random.split(rng, 5)
+        pd = self.policy.param_dtype
+        return {
+            "q_a_kernel": init(ks[0], (d, self.q_rank), pd),
+            "q_norm": jnp.ones((self.q_rank,), pd),
+            "q_b_kernel": init(
+                ks[1], (self.q_rank, h * (self.nope_dim + self.rope_dim)), pd),
+            "kv_a_kernel": init(ks[2], (d, self.latent_dim), pd),
+            "kv_norm": jnp.ones((self.kv_rank,), pd),
+            "kv_b_kernel": init(
+                ks[3], (self.kv_rank, h * (self.nope_dim + self.v_dim)), pd),
+            "out_kernel": init(ks[4], (h * self.v_dim, d), pd),
+        }, {}
+
+    def _mm(self, x, w):
+        from ..ops.pallas.quant_matmul import qmatmul
+
+        return qmatmul(x, self.policy.cast_param(w)).astype(x.dtype)
+
+    def _norm(self, x, gain):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(ms + self.norm_eps)
+                * gain.astype(jnp.float32)).astype(x.dtype)
+
+    @jax.named_scope("attn_qkv")
+    def _latents(self, params, x, offset):
+        """The two low-rank halves of a step's tokens at their positions:
+        q (B, S, H, nope + rope), rotated and scaled by position; the cached
+        row's two parts c_kv (B, S, kv_rank) and k_rope (B, S, rope)."""
+        x = self.policy.cast_in(x)
+        b, s, _ = x.shape
+        pos = _positions(offset, b, s)
+        q = self._mm(self._norm(self._mm(x, params["q_a_kernel"]),
+                                params["q_norm"]), params["q_b_kernel"])
+        q = q.reshape(b, s, self.num_heads, self.nope_dim + self.rope_dim)
+        q_rope = apply_rope_pairs(q[..., self.nope_dim:], pos[:, :, None],
+                                  self.inv_freq)
+        q = jnp.concatenate([q[..., :self.nope_dim], q_rope], axis=-1)
+        if self.scaling_beta:
+            q = (q.astype(jnp.float32) * (1.0 + self.scaling_beta * jnp.log1p(
+                (pos // self.original).astype(jnp.float32)))[:, :, None, None]
+                 ).astype(x.dtype)
+        kv = self._mm(x, params["kv_a_kernel"])
+        c_kv = self._norm(kv[..., :self.kv_rank], params["kv_norm"])
+        k_rope = apply_rope_pairs(kv[..., self.kv_rank:], pos, self.inv_freq)
+        return q, c_kv, k_rope
+
+    def _kv_b(self, params):
+        """``W_kvb`` by head: key half (kv_rank, H, nope), value half
+        (kv_rank, H, v)."""
+        w = self.policy.cast_param(params["kv_b_kernel"]).reshape(
+            self.kv_rank, self.num_heads, self.nope_dim + self.v_dim)
+        return w[..., :self.nope_dim], w[..., self.nope_dim:]
+
+    @jax.named_scope("attn_qkv")
+    def _expand(self, params, c_kv, k_rope):
+        """Keys and values of every head from the cached row's parts:
+        k (B, H, S, nope + rope), v (B, H, S, v)."""
+        w_k, w_v = self._kv_b(params)
+        k_nope = jnp.einsum("bsc,chd->bhsd", c_kv, w_k,
+                            preferred_element_type=jnp.float32)
+        v = jnp.einsum("bsc,chd->bhsd", c_kv, w_v,
+                       preferred_element_type=jnp.float32)
+        k_rope = jnp.broadcast_to(k_rope[:, None].astype(jnp.float32),
+                                  k_nope.shape[:3] + (self.rope_dim,))
+        k = jnp.concatenate([k_nope, k_rope], axis=-1)
+        return k.astype(c_kv.dtype), v.astype(c_kv.dtype)
+
+    @jax.named_scope("attn_out")
+    def _project_out(self, params, heads):
+        """heads (B, S, H, v) -> (B, S, D)."""
+        b, s = heads.shape[:2]
+        y = self._mm(heads.reshape(b, s, self.num_heads * self.v_dim),
+                     params["out_kernel"])
+        return self.policy.cast_out(y)
+
+    def _apply(self, params, state, x, *, train, rng):
+        q, c_kv, k_rope = self._latents(params, x, 0)
+        k, v = self._expand(params, c_kv, k_rope)
+        out = sdpa(q.transpose(0, 2, 1, 3), k, v, causal=True,
+                   scale=self.scale, backend="xla")
+        return self._project_out(params, out.transpose(0, 2, 1, 3)), state
+
+    # -- cached decode, expanded (the offline ``generate``) ----------------
+
+    def init_cache(self, batch: int, max_len: int, d_model: int):
+        dtype = self.policy.compute_dtype
+        h = self.num_heads
+        return {"k": jnp.zeros((batch, h, max_len,
+                                self.nope_dim + self.rope_dim), dtype),
+                "v": jnp.zeros((batch, h, max_len, self.v_dim), dtype)}
+
+    def apply_cached(self, variables, x, cache, offset):
+        params = variables["params"]
+        q, c_kv, k_rope = self._latents(params, x, offset)
+        k_new, v_new = self._expand(params, c_kv, k_rope)
+        if getattr(offset, "ndim", 0):
+            upd = lambda buf, new: jax.vmap(  # noqa: E731
+                lambda b, n, o: jax.lax.dynamic_update_slice_in_dim(
+                    b, n, o, axis=1))(buf, new, offset)
+        else:
+            upd = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
+                buf, new, offset, axis=2)
+        with jax.named_scope("kv_write"):
+            cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
+        out = sdpa(q.transpose(0, 2, 1, 3), cache["k"], cache["v"],
+                   causal=True, scale=self.scale, kv_offset=offset,
+                   backend="xla")
+        return self._project_out(params, out.transpose(0, 2, 1, 3)), cache
+
+    # -- the serving step, absorbed -----------------------------------------
+
+    def apply_paged(self, variables, x, pages_k, pages_v, block_tables,
+                    offsets, layer=0, q_lens=None):
+        """One step against the pool's latent pages ``(L, N, 1, bs,
+        latent_row)``: x (B, Q, D) with ``q_lens[b]`` live tokens a row
+        (None: the decode form, every row one token). The new rows ``[c_kv |
+        k_rope | 0]`` are written into their pages, then every head's
+        absorbed query ``[q_nope W_uk^T | q_rope | 0]`` attends over the
+        row's pages, whose first ``kv_rank`` values are the values too; ``W_uv``
+        and ``W_o`` follow. ``pages_v`` is the pool's unallocated stub and
+        passes through. Returns (out (B, Q, D), pages_k, pages_v)."""
+        from ..ops.pallas import mla_attention as mla
+        from ..ops.pallas import paged_attention as pa
+
+        params = variables["params"]
+        b, qw, _ = x.shape
+        if q_lens is None:
+            q_lens = jnp.ones((b,), jnp.int32)
+        q, c_kv, k_rope = self._latents(params, x, offsets)
+        w_k, w_v = self._kv_b(params)
+        pad = pages_k.shape[-1] - self.latent_dim
+        with jax.named_scope("attn_qkv"):
+            rows = jnp.concatenate(
+                [c_kv, k_rope, jnp.zeros((b, qw, pad), c_kv.dtype)], axis=-1)
+            q_abs = jnp.einsum("bqhd,chd->bqhc", q[..., :self.nope_dim], w_k,
+                               preferred_element_type=jnp.float32)
+            q_lat = jnp.concatenate(
+                [q_abs.astype(q.dtype), q[..., self.nope_dim:],
+                 jnp.zeros((b, qw, self.num_heads, pad), q.dtype)], axis=-1)
+        pages_k = pa.scatter_kv_chunk(
+            pages_k, block_tables, offsets,
+            rows[:, :, None].astype(pages_k.dtype), q_lens, layer=layer)
+        out = mla.mla_attention(
+            q_lat.astype(pages_k.dtype), pages_k, block_tables,
+            offsets + q_lens, q_lens=q_lens, layer=layer, scale=self.scale,
+            value_dim=self.kv_rank)
+        with jax.named_scope("attn_out"):
+            heads = jnp.einsum("bqhc,chd->bqhd", out.astype(w_v.dtype), w_v,
+                               preferred_element_type=jnp.float32)
+        y = self._project_out(params, heads.astype(c_kv.dtype))
+        return y, pages_k, pages_v
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def _config(self):
+        return {"num_heads": self.num_heads, "q_rank": self.q_rank,
+                "kv_rank": self.kv_rank, "nope_dim": self.nope_dim,
+                "rope_dim": self.rope_dim, "v_dim": self.v_dim,
+                "rope": self.rope, "norm_eps": self.norm_eps,
+                "backend": self.backend}

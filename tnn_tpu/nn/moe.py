@@ -21,11 +21,14 @@ tests/test_parallel.py).
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.module import Module, register_module
 from . import activations as act_lib
@@ -231,3 +234,186 @@ def shard_params_ep(params, mesh, axis: str = "expert"):
     from ..parallel.tensor_parallel import shard_params_tp
 
     return shard_params_tp(params, mesh, rules=ep_rules(axis))
+
+
+# -- one chip's share of a dropless expert layer (serving) ------------------
+
+_COUNTS = threading.local()
+
+
+@contextlib.contextmanager
+def collect_counts():
+    """Inside this context every ``ExpertShare`` applied (traced) appends its
+    ``(held,)`` count of assignments, in layer order, to the list it yields:
+    how a serving step program returns its expert counters beside its tokens
+    without the model's ``apply_paged`` changing its signature."""
+    prev = getattr(_COUNTS, "sink", None)
+    _COUNTS.sink = sink = []
+    try:
+        yield sink
+    finally:
+        _COUNTS.sink = prev
+
+
+@register_module("expert_share")
+class ExpertShare(Module):
+    """The experts ONE chip holds of a routed layer of ``num_experts``,
+    beside ``shared`` shared experts (one gated feed-forward of ``shared *
+    hidden``, added once): the share of an expert-parallel deployment that
+    divides each layer over several chips.
+
+    The router is whole: float32 logits over all ``num_experts``, softmax,
+    the ``top_k`` largest renormalised to sum 1. Of a step's assignments
+    those that fall on a HELD expert are sorted by expert, each expert's
+    group padded to the kernel's row tile, and ``ops.pallas.expert_gmm``
+    walks the groups: an expert with no token is never fetched, and no token
+    is dropped at any imbalance (the sorted buffer holds every assignment
+    and a tile of padding an expert). What absent experts would add is left
+    out; nothing here stands in for the other chips or their exchange.
+
+    Leaves: ``router`` (D, num_experts); ``gate``, ``up``, ``down``
+    (held, hidden, D): "out x in" for gate and up, "in x out" for down, so a
+    block of hidden units is contiguous rows in each; the shared expert's
+    ``shared_gate`` / ``shared_up`` (D, shared * hidden) and ``shared_down``
+    (shared * hidden, D)."""
+
+    def __init__(self, num_experts: int, held, top_k: int, hidden: int,
+                 shared: int = 0, name=None, policy=None):
+        super().__init__(name=name, policy=policy)
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.held = tuple(int(e) for e in held)
+        self.hidden, self.shared = int(hidden), int(shared)
+        if not self.held or len(set(self.held)) != len(self.held) or not all(
+                0 <= e < self.num_experts for e in self.held):
+            raise ValueError(f"held experts {self.held} are distinct ids "
+                             f"below {self.num_experts}")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k {top_k} not in [1, {num_experts}]")
+        slot = np.full((self.num_experts,), -1, np.int32)
+        slot[list(self.held)] = np.arange(len(self.held))
+        self._slot = slot           # expert id -> held slot, -1: elsewhere
+
+    def _init(self, rng, input_shape):
+        d, f, n = input_shape[-1], self.hidden, len(self.held)
+        pd = self.policy.param_dtype
+        ks = jax.random.split(rng, 7)
+
+        def normal(key, shape, fan_in):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    / math.sqrt(fan_in)).astype(pd)
+
+        params = {"router": normal(ks[0], (d, self.num_experts), d),
+                  "gate": normal(ks[1], (n, f, d), d),
+                  "up": normal(ks[2], (n, f, d), d),
+                  "down": normal(ks[3], (n, f, d), f)}
+        if self.shared:
+            fs = self.shared * f
+            params.update(shared_gate=normal(ks[4], (d, fs), d),
+                          shared_up=normal(ks[5], (d, fs), d),
+                          shared_down=normal(ks[6], (fs, d), fs))
+        return params, {}
+
+    @jax.named_scope("moe_route")
+    def route(self, params, x):
+        """x (T, D) -> (ids (T, k) int32, weights (T, k) float32): the
+        ``top_k`` of a float32 softmax over ALL experts, renormalised."""
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            params["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        return ids.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+    @jax.named_scope("moe_route")
+    def _sort(self, ids, live, tile):
+        """Lay the held assignments out by expert. ids (T, k), live (T,) ->
+        ``src`` (M,) the token of each row of the sorted buffer (-1: padding),
+        ``dest`` (T, k) the row of each assignment (-1: not held, or a dead
+        token), ``tile_expert`` (M / tile,), ``live_tiles``, ``counts``
+        (held,)."""
+        t, k = ids.shape
+        n = len(self.held)
+        a = t * k
+        # room for every assignment and a tile's padding an expert
+        tiles = -(-a // tile) + n
+        slot = jnp.asarray(self._slot)[ids]
+        key = jnp.where((slot >= 0) & live[:, None], slot, n).reshape(a)
+        order = jnp.argsort(key, stable=True)
+        skey = key[order]
+        counts = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                         dtype=jnp.int32)
+        first = jnp.cumsum(counts) - counts             # group starts, sorted
+        padded = -(-counts // tile) * tile
+        start = jnp.cumsum(padded) - padded             # group starts, padded
+        held = skey < n
+        e = jnp.minimum(skey, n - 1)
+        row = jnp.where(held, start[e] + jnp.arange(a) - first[e],
+                        tiles * tile)                   # past the end: dropped
+        src = jnp.full((tiles * tile,), -1, jnp.int32).at[row].set(
+            (order // k).astype(jnp.int32), mode="drop")
+        dest = jnp.full((a,), -1, jnp.int32).at[order].set(
+            jnp.where(held, row, -1).astype(jnp.int32)).reshape(t, k)
+        ends = jnp.cumsum(padded)
+        live_tiles = ends[-1] // tile
+        at = jnp.minimum(jnp.arange(tiles), jnp.maximum(live_tiles - 1, 0))
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(ends, at * tile, side="right"), n - 1)
+        return src, dest, tile_expert.astype(jnp.int32), live_tiles, counts
+
+    def routed(self, params, x, live=None):
+        """What the HELD experts add for x (T, D): (y (T, D) float32, counts
+        (held,) int32 of the assignments each held expert took). ``live``
+        (T,) bool: a dead token takes no expert and counts nowhere."""
+        from ..ops.pallas.expert_gmm import expert_gmm, row_tile
+
+        t = x.shape[0]
+        if live is None:
+            live = jnp.ones((t,), bool)
+        ids, w = self.route(params, x)
+        tile = row_tile(t * self.top_k)
+        src, dest, tile_expert, live_tiles, counts = self._sort(
+            ids, live, tile)
+        cast = self.policy.cast_param
+        with jax.named_scope("moe_experts"):
+            xc = self.policy.cast_in(x)
+            rows = jnp.where((src >= 0)[:, None], xc[jnp.maximum(src, 0)], 0)
+            ys = expert_gmm(rows, cast(params["gate"]), cast(params["up"]),
+                            cast(params["down"]), tile_expert, live_tiles,
+                            tile=tile)
+            got = jnp.where((dest >= 0)[..., None],
+                            ys[jnp.maximum(dest, 0)].astype(jnp.float32), 0.0)
+            y = jnp.sum(got * w[..., None], axis=1)
+        return y, counts
+
+    @jax.named_scope("moe_shared")
+    def shared_out(self, params, x):
+        from ..ops.pallas.quant_matmul import qmatmul
+
+        cast = self.policy.cast_param
+        xc = self.policy.cast_in(x)
+        g = qmatmul(xc, cast(params["shared_gate"]))
+        u = qmatmul(xc, cast(params["shared_up"])).astype(xc.dtype)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(xc.dtype) * u
+        return qmatmul(h, cast(params["shared_down"]))
+
+    def _apply(self, params, state, x, *, train, rng, live=None):
+        """x (..., D) -> the held experts' share plus the shared expert, in
+        x's dtype. Inside ``collect_counts`` the layer's counts go to its
+        list."""
+        lead, d = x.shape[:-1], x.shape[-1]
+        flat = x.reshape(-1, d)
+        y, counts = self.routed(
+            params, flat, None if live is None else live.reshape(-1))
+        if self.shared:
+            y = y + self.shared_out(params, flat).astype(jnp.float32)
+        sink = getattr(_COUNTS, "sink", None)
+        if sink is not None:
+            sink.append(counts)
+        return y.astype(x.dtype).reshape(lead + (d,)), state
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def _config(self):
+        return {"num_experts": self.num_experts, "held": list(self.held),
+                "top_k": self.top_k, "hidden": self.hidden,
+                "shared": self.shared}

@@ -155,19 +155,22 @@ def group_vmem_bytes(pages, heads, *, bs, dh, qg, page_dtype):
     return heads * (blocks + scratch + body)
 
 
-def fetch_group(*, bs, dh, hkv, qg, page_dtype, nb):
+def fetch_group(*, bs, dh, hkv, qg, page_dtype, nb,
+                positions=_GROUP_POSITIONS):
     """``(pages, heads)`` of one grid step, from what a call can see: the
     page's shape ``(bs, dh)`` and dtype (int8 means ``QuantPages``), the
     pool's (or the shard's) ``hkv`` heads, the query tile's ``qg = Q * g``
     rows and the table's ``nb`` entries.
 
     ``pages`` consecutive table entries make a group of about
-    ``_GROUP_POSITIONS`` key positions (a whole table if it is shorter);
+    ``positions`` key positions (a whole table if it is shorter; a pool of
+    ONE head, ``mla_attention``'s, asks for more than the default: it has no
+    further heads to fill a grid step with);
     ``heads`` is the largest divisor of
     ``hkv`` whose group fits ``_VMEM_BUDGET`` (``group_vmem_bytes``), and
     only a group too large with ONE head gives pages up. The launch and the
     engine's ``attn_fetch_fill_mean`` both ask this function."""
-    pages = max(1, min(_GROUP_POSITIONS // bs, nb))
+    pages = max(1, min(positions // bs, nb))
     size = functools.partial(group_vmem_bytes, bs=bs, dh=dh, qg=qg,
                              page_dtype=page_dtype)
     while pages > 1 and size(pages, 1) > _VMEM_BUDGET:

@@ -93,6 +93,7 @@ import numpy as np
 
 from ..models import sampling
 from ..profiling.profiler import EventType, Profiler
+from ..nn import moe as moe_lib
 from . import kv_pool as kv_pool_lib
 from . import spec_decode
 from . import step_build
@@ -106,6 +107,18 @@ from .scheduler import (TERMINAL_STATES, AdmissionRejected, Request,
 from .tracing import Tracer
 
 
+# How far ``try_speculate`` dispatches ahead of the step in flight: enough
+# steps to keep the device busy for SPECULATE_AHEAD_S while the host stands
+# still (a shared machine stops every process for 80-140 ms now and then:
+# PERF.md section 2; the device goes on with what is queued), at most
+# SPECULATE_MAX, and one step more for every SPECULATE_RAMP steps
+# adopted in a row, so that only a batch that has been closed for a while
+# queues deep: what an arrival finds queued it waits out.
+SPECULATE_AHEAD_S = 0.2
+SPECULATE_MAX = 12
+SPECULATE_RAMP = 8
+
+
 @jax.jit
 def _splice_draft_row(toks, draft, row):
     """Write a device-resident draft into row ``row`` of the step's token
@@ -115,36 +128,59 @@ def _splice_draft_row(toks, draft, row):
     return jax.lax.dynamic_update_slice(toks, draft, (row, jnp.int32(1)))
 
 
+def _stacked(counts) -> tuple:
+    """A step program's expert counters as one more output, ``(layers,
+    held)``, or none: a model with no expert layer returns what it did."""
+    return (jnp.stack(counts),) if counts else ()
+
+
 def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
                     host_tier_bytes=0, kv_dtype="f32") -> Optional[str]:
     """One sentence saying why ``model`` does not serve with the first of
     these options that is on, or None. A model whose state is not "K and V
-    of every position" (EVA: an exact window beside chunk summaries) runs on
-    the normal path over a pool of two kinds of page; what assumes K/V blocks
-    is refused at start-up, by the engine and by ``tnn-serve`` before it
-    makes any weights."""
+    blocks of heads for every position" runs on the normal path over pages
+    of its own kind: EVA (an exact window beside chunk summaries: two kinds
+    of page) and latent attention (one row a token that is key and value at
+    once, no head axis: latent pages). What assumes K/V blocks of heads is
+    refused at start-up, by the engine and by ``tnn-serve`` before it makes
+    any weights."""
     window = getattr(model, "window", None)
-    if not window:
+    latent = getattr(model, "latent", None)
+    if not window and not latent:
         return None
-    for on, what in (
+    if window:
+        state = (f"an exact window of {window} positions beside chunk "
+                 "summaries, not K/V blocks of every position")
+        whys = ("a cached block would have to carry the summaries of "
+                "everything before it",
+                "a rejected draft may have ended a window or written a "
+                "summary, and neither rolls back",
+                "the summary write and the two-segment kernel are not "
+                "head-sharded",
+                "exact and summary pages are not block-sharded",
+                "summaries are written in the compute dtype")
+    else:
+        state = ("one latent row a token that is key and value at once, "
+                 "not K/V blocks of heads")
+        whys = ("the cache's copy-on-write and export move K and V pages",
+                "the verify step returns no expert counters and is not "
+                "held against the reference over latent pages",
+                "a latent row has no head axis to shard",
+                "latent pages are not block-sharded, and the softmax merge "
+                "takes K/V heads",
+                "a latent row's two parts would need two scales")
+    for on, what, why in (
             (prefix_cache, "prefix sharing (the prefix cache; "
-             "--no-prefix-cache): a cached block would have to carry the "
-             "summaries of everything before it"),
-            (spec, "speculative decoding (spec): a rejected draft may have "
-             "ended a window or written a summary, and neither rolls back"),
-            (tp > 1, "tensor parallelism (tp): the summary write and the "
-             "two-segment kernel are not head-sharded"),
-            (sp > 1, "sequence parallelism (sp): exact and summary pages "
-             "are not block-sharded"),
-            (bool(host_tier_bytes), "the host KV tier: it demotes "
-             "prefix-cache blocks"),
-            (kv_dtype == "int8", "int8 pages (kv_dtype): summaries are "
-             "written in the compute dtype")):
+             "--no-prefix-cache)", whys[0]),
+            (spec, "speculative decoding (spec)", whys[1]),
+            (tp > 1, "tensor parallelism (tp)", whys[2]),
+            (sp > 1, "sequence parallelism (sp)", whys[3]),
+            (bool(host_tier_bytes), "the host KV tier",
+             "it demotes prefix-cache blocks"),
+            (kv_dtype == "int8", "int8 pages (kv_dtype)", whys[4])):
         if on:
-            return (f"{type(model).__name__} keeps an exact window of "
-                    f"{window} positions beside chunk summaries, not K/V "
-                    f"blocks of every position: it does not serve with "
-                    f"{what}")
+            return (f"{type(model).__name__} keeps {state}: it does not "
+                    f"serve with {what}: {why}")
     return None
 
 
@@ -154,12 +190,12 @@ class StepInFlight:
     ``begin_step`` fills it with the step's flight-recorder note and one
     record per launched program (device references only — nothing is
     fetched at build time); ``finish_step`` fetches the single result
-    bundle and runs the commit phase against it. ``spec`` optionally holds
-    a speculatively dispatched successor step (see
+    bundle and runs the commit phase against it. ``ahead`` holds the
+    speculatively dispatched successor steps, oldest first (see
     ``InferenceEngine.try_speculate``)."""
 
     __slots__ = ("step_seq", "note", "fired_before", "t0", "gen_before",
-                 "events", "recs", "done", "spec", "latency_s")
+                 "events", "recs", "done", "ahead", "latency_s")
 
     def __init__(self, step_seq: int, note: Dict[str, Any],
                  fired_before: Optional[Counter], t0: float):
@@ -172,7 +208,7 @@ class StepInFlight:
                                         "failed": [], "timed_out": []}
         self.recs: List[Dict[str, Any]] = []
         self.done = False
-        self.spec: Optional[Dict[str, Any]] = None
+        self.ahead: List[Dict[str, Any]] = []
         self.latency_s = 0.0
 
 
@@ -236,8 +272,9 @@ class InferenceEngine:
         (``run_deferred``) drained while the next step runs on-device.
         The drive loops (``run_until_complete``, the supervisor tick) pair
         begin/finish around the deferred work and may speculatively
-        dispatch step N+1 from predicted row states before step N commits
-        (``try_speculate``; mispredictions roll back and rebuild).
+        dispatch steps N+1, N+2, ... from predicted row states before step
+        N commits (``try_speculate``, as deep as ``_speculate_depth`` says;
+        mispredictions roll back and rebuild).
         Token-exact vs overlap-off — a direct
         ``step()`` call stays fully synchronous either way. Default off;
         ``tnn-serve`` turns it on (``--no-overlap`` opts out).
@@ -422,7 +459,9 @@ class InferenceEngine:
             from ..nn import quant as _quant
             params = _quant.quantize_for_decode(params)
         self.params = params
-        self.head_dim = model.d_model // model.num_heads
+        # a latent model's "head" is its one cached row (whole lanes)
+        latent_row = getattr(model, "latent_row", None)
+        self.head_dim = latent_row or model.d_model // model.num_heads
         if self._tp is not None:
             page_sharding = self._tp.page_sharding
         elif self._sp is not None:
@@ -436,7 +475,8 @@ class InferenceEngine:
             head_dim=self.head_dim, num_blocks=num_blocks,
             block_size=block_size, dtype=model.policy.compute_dtype,
             kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp,
-            window=window, chunk=getattr(model, "chunk", None))
+            window=window, chunk=getattr(model, "chunk", None),
+            latent=bool(latent_row))
         self.pool.fault_plan = faults
         # static gauge extras spliced into every _health_gauges refresh:
         # lets operators spot a misconfigured replica from /healthz alone
@@ -516,9 +556,11 @@ class InferenceEngine:
         self.overlap = bool(overlap)
         self._flight: Optional[StepInFlight] = None
         self._deferred: List[Callable[[], None]] = []
-        # PRNG key stashed by an abandoned speculative dispatch; the rebuild
-        # reuses it so the key-consumption sequence matches overlap-off
-        self._reuse_key = None
+        # PRNG keys of abandoned speculative dispatches, oldest first; the
+        # rebuilds reuse them so the key-consumption sequence matches
+        # overlap-off
+        self._reuse_keys: List[Any] = []
+        self._adopted_run = 0       # speculative steps adopted in a row
         self._t_fetch_done: Optional[float] = None
         # the open phase of the worker's time (``_open_phase``): (span,
         # start, observer) of a serve.build or serve.commit in progress
@@ -822,12 +864,17 @@ class InferenceEngine:
             from ..ops.pallas.paged_attention import fetch_group
             pool, model = self.pool, self.model
             _, _, rows, bs, width = pool.page_shape
+            tile, kw = qw, {}
+            if pool.latent:     # the latent kernel's query tile and group
+                from ..ops.pallas import mla_attention as mla
+                tile = mla.query_tile(qw, model.num_heads)
+                kw["positions"] = mla.GROUP_POSITIONS
             group = self._attn_groups[qw] = fetch_group(
                 bs=bs, dh=width, hkv=rows // self.tp,
-                qg=qw * (model.num_heads // rows),
+                qg=tile * (model.num_heads // rows),
                 page_dtype=(jnp.int8 if pool.kv_dtype == "int8"
                             else pool.dtype),
-                nb=self.blocks_per_seq)
+                nb=self.blocks_per_seq, **kw)
         return group
 
     def _observe_attention(self, rows, ends, qw: int) -> None:
@@ -850,6 +897,15 @@ class InferenceEngine:
              for _, e in zip(rows, ends)],
             sum(r.cache_len // pool.chunk for r in self.scheduler.running)
             / (pool.capacity * pool.block_size))
+
+    def _observe_experts(self, experts, tokens: int) -> None:
+        """A step's ``(layers, held)`` expert counters, fetched with its
+        ``tokens`` live tokens' samples (nothing for a model with no expert
+        layer): held over all assignments, the held experts hit, the load's
+        imbalance."""
+        if experts:
+            self.metrics.observe_experts(
+                np.asarray(experts[0]), tokens * self.model.experts["top_k"])
 
     # -- per-request latency breakdown (host-side clocks only) ----------------
 
@@ -925,6 +981,7 @@ class InferenceEngine:
             raise RuntimeError(
                 "a step is already in flight — finish_step() first")
         self.step_seq += 1
+        self._adopted_run = 0       # a step built anew: no run of adoptions
         fired_before = (Counter(self.faults.fired)
                         if self.faults is not None else None)
         # built BEFORE the step body runs: a crash fired at the very top of
@@ -1032,6 +1089,12 @@ class InferenceEngine:
         attrs = {} if group is None else dict(
             attn_pages=group[0], attn_heads=group[1],
             kv_lane_pack=self.pool.lane_pack)
+        if group is not None and self.pool.latent:
+            # ONE page array, read once for keys and values
+            attrs = dict(latent_pages=group[0])
+        experts = getattr(self.model, "experts", None)
+        if qw is not None and experts:
+            attrs["experts_held"] = len(experts["held"])
         return self.tracer.span(
             "serve.dispatch", EventType.COMPUTE,
             step=self.step_seq if step is None else step, kind=kind,
@@ -1256,36 +1319,40 @@ class InferenceEngine:
         after an abandoned speculative dispatch REUSES the abandoned
         step's key, so the engine's key-consumption sequence (and thus
         every stochastic sample) matches the overlap-off engine exactly."""
-        if self._reuse_key is not None:
-            key, self._reuse_key = self._reuse_key, None
-            return key
+        if self._reuse_keys:
+            return self._reuse_keys.pop(0)
         return self._next_key()
 
     # -- speculative step pipelining ------------------------------------------
 
     def try_speculate(self) -> bool:
-        """Speculatively build and dispatch step N+1 while step N is still
-        in flight. Legal only when N+1's build is fully determined by
-        committed state plus N's (unfetched) sampled tokens: a pure decode
-        batch whose every row must survive the commit — no stop tokens, no
-        deadlines, headroom for two more tokens — with KV growth that fits
-        the pool without preemption, no drafter, no fault plan, and an
-        empty wait queue. The dispatched program reads step N's sampled
-        tokens DIRECTLY as its device-resident inputs, so nothing syncs;
-        ``finish_step`` validates the prediction and either adopts the
-        dispatch as the next in-flight step or rolls it back
-        (``_resolve_speculation``). Returns True when a step was
-        dispatched. Abandoned KV writes are harmless: they land at
-        positions at or past every surviving row's committed length, or in
-        blocks the rollback frees — always overwritten before attended."""
+        """Speculatively build and dispatch one more step behind the step in
+        flight (N) and the successors already dispatched: step N+j. Legal
+        only when its build is fully determined by committed state plus the
+        (unfetched) sampled tokens of the step before it: a pure decode
+        batch whose every row must survive the j commits before it — no
+        stop tokens, no deadlines, headroom for j+1 more tokens — with KV
+        growth that fits the pool without preemption, no drafter, no fault
+        plan, and an empty wait queue. The dispatched program reads its
+        predecessor's sampled tokens DIRECTLY as its device-resident
+        inputs, so nothing syncs; ``finish_step`` validates the prediction
+        and either adopts the oldest successor as the next in-flight step
+        or rolls every one back (``_resolve_speculation``). Returns True
+        when a step was dispatched: the drive loops call it until it says
+        False (``_speculate_depth`` successors are dispatched). Abandoned
+        KV writes are harmless: they land at positions at or past every
+        surviving row's committed length, or in blocks the rollback frees
+        — always overwritten before attended."""
         flight = self._flight
         if (not self.overlap or flight is None or flight.done
-                or flight.spec is not None or self.faults is not None
+                or self.faults is not None
                 or self.drafter is not None or self.scheduler.waiting
                 or len(flight.recs) != 1
-                or flight.recs[0]["kind"] != "decode"):
+                or flight.recs[0]["kind"] != "decode"
+                or len(flight.ahead) >= self._speculate_depth()):
             return False
-        rec = flight.recs[0]
+        j = len(flight.ahead) + 1       # every row is j tokens on by then
+        rec = flight.ahead[-1]["rec"] if flight.ahead else flight.recs[0]
         live = rec["live"]
         if live != [r for r in self.scheduler.running
                     if r.state is RequestState.RUNNING]:
@@ -1296,13 +1363,14 @@ class InferenceEngine:
                     or req.cache_len < req.prefill_len
                     or req.stop_token is not None
                     or req.deadline_s is not None
-                    or req.num_generated + 1 >= req.max_new_tokens
-                    or req.cache_len + 2 > self.max_seq_len
-                    # step N ends this row's window: its commit gives the
-                    # exact pages back, which no prediction packs
-                    or self.pool.room_in_window(req.cache_len) == 1):
+                    or req.num_generated + j >= req.max_new_tokens
+                    or req.cache_len + j + 1 > self.max_seq_len
+                    # a step before this one ends the row's window: its
+                    # commit gives the exact pages back, which no
+                    # prediction packs
+                    or self.pool.room_in_window(req.cache_len) <= j):
                 return False
-            grows.append(self._grow_need(req, req.cache_len + 1, 1))
+            grows.append(self._grow_need(req, req.cache_len + j, 1))
         total = sum(map(sum, grows))
         if total and not self.pool.can_alloc(total):
             return False
@@ -1313,12 +1381,12 @@ class InferenceEngine:
         except PoolExhausted:
             self._unextend(rollback)
             return False
-        # speculative=True packs the predicted row state: each offset
-        # assumes exactly one token committed at step N
+        # ahead=j packs the predicted row state: each offset assumes
+        # exactly one token committed by each of the j steps before
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            speculative=True, sum_at=self.pool.exact_width)
+            ahead=j, sum_at=self.pool.exact_width)
         self._check_step_writes(step, step.offsets)
         b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
         label = "decode_paged"
@@ -1327,25 +1395,25 @@ class InferenceEngine:
             fn = self._jit[key] = self._paged_decode_fn(b, nb)
         step_key = self._step_key()
         t0 = time.perf_counter()
-        prev_tok = rec["dev"][0]     # step N's unfetched sampled tokens
+        prev_tok = rec["dev"][0]    # its predecessor's unfetched samples
         try:
             with self._sync_guard(), \
-                    self._dispatch_span(label, key, self.step_seq + 1,
+                    self._dispatch_span(label, key, self.step_seq + j,
                                         qw=1):
-                newtok, ok, pk, pv = fn(
+                newtok, ok, pk, pv, *experts = fn(
                     self.params, self.pool.pages_k, self.pool.pages_v,
                     prev_tok, self._put(offsets), self._put_tables(step.tables),
                     self._put(step.temps), self._put(step.topks),
                     self._put(step.topps), step_key, self._put(step.poison))
         except Exception:  # noqa: BLE001 — speculation must never hurt
             self._unextend(rollback)
-            self._reuse_key = step_key
+            self._reuse_keys.insert(0, step_key)
             self._recover_pages_if_dead(flight.events)
             return False
         self.pool.update_pages(pk, pv)
         self._observe_attention(live, offsets + 1, 1)
-        flight.spec = {
-            "rec": {"kind": "decode", "dev": (newtok, ok),
+        flight.ahead.append({
+            "rec": {"kind": "decode", "dev": (newtok, ok, *experts),
                     "live": list(live), "t0": t0, "b": b},
             "rollback": rollback, "key": step_key,
             "offsets": {r.rid: int(offsets[i])
@@ -1353,19 +1421,29 @@ class InferenceEngine:
             "prog": {"kind": label, "compile_key": list(key),
                      "rids": [r.rid for r in live],
                      "fill": round(len(live) / b, 4)},
-        }
+        })
         return True
 
+    def _speculate_depth(self) -> int:
+        """Successor steps ``try_speculate`` keeps dispatched behind the
+        step in flight: SPECULATE_AHEAD_S of device time at the last
+        step's length, reached a step at a time while adoptions go on."""
+        by_time = (SPECULATE_MAX if self._last_step_latency_s <= 0.0 else
+                   -int(-SPECULATE_AHEAD_S // self._last_step_latency_s))
+        return max(1, min(SPECULATE_MAX, by_time,
+                          1 + self._adopted_run // SPECULATE_RAMP))
+
     def _resolve_speculation(self, flight: "StepInFlight") -> None:
-        """After ``flight`` committed: adopt its speculative successor when
-        the prediction held (the same rows, each exactly one token longer,
-        still running, queue still empty), else roll the dispatch back —
-        free the pre-grown blocks, stash the PRNG key for reuse, and let
-        the next ``begin_step`` rebuild from committed state."""
-        spec = flight.spec
-        if spec is None:
+        """After ``flight`` committed: adopt its oldest speculative
+        successor when the prediction held (the same rows, each exactly
+        one token longer, still running, queue still empty) and hand it
+        the younger ones, else roll them all back — free the pre-grown
+        blocks, stash the PRNG keys for reuse in order, and let the next
+        ``begin_step`` rebuild from committed state."""
+        ahead, flight.ahead = flight.ahead, []
+        if not ahead:
             return
-        flight.spec = None
+        spec = ahead[0]
         rec = spec["rec"]
         live = rec["live"]
         predicted = (
@@ -1375,12 +1453,16 @@ class InferenceEngine:
             and all(req.cache_len == spec["offsets"][req.rid]
                     for req in live))
         if not predicted:
-            self._unextend(spec["rollback"], only_intact=True)
-            self._reuse_key = spec["key"]
+            for s in reversed(ahead):
+                self._unextend(s["rollback"], only_intact=True)
+            self._reuse_keys[:0] = [s["key"] for s in ahead]
+            self._adopted_run = 0
             self.metrics.observe_overlap_rebuild()
             return
         # prediction held: the dispatched step IS the next step — give it
-        # its step_seq and flight-recorder note at adoption time
+        # its step_seq and flight-recorder note at adoption time. Its clock
+        # starts here too: until now it waited behind its predecessor
+        self._adopted_run += 1
         self.step_seq += 1
         note: Dict[str, Any] = {
             "step_seq": self.step_seq,
@@ -1390,9 +1472,11 @@ class InferenceEngine:
             "speculative": True,
         }
         self._step_note = note
+        rec["t0"] = time.perf_counter()
         nxt = StepInFlight(self.step_seq, note, None, rec["t0"])
         nxt.gen_before = {r.rid: r.num_generated for r in live}
         nxt.recs.append(rec)
+        nxt.ahead = ahead[1:]
         self._flight = nxt
         # the dispatch preceded the fetch it would have waited for: the
         # adopted step's host gap is zero by construction
@@ -1476,7 +1560,8 @@ class InferenceEngine:
             if self.overlap:
                 if self._flight is None:
                     self.begin_step()
-                self.try_speculate()
+                while self.try_speculate():
+                    pass
                 self.run_deferred()
                 self.finish_step()
             else:
@@ -2120,6 +2205,7 @@ class InferenceEngine:
         # with the same key reproduces the fault-free step bit-for-bit
         step_key = self._step_key()
         self._mark_dispatch()
+        experts = ()
         for attempt in (0, 1):
             try:
                 if self.faults is not None:
@@ -2135,7 +2221,7 @@ class InferenceEngine:
                             self._put(step.topks), self._put(step.topps),
                             step_key, self._put(poison))
                     else:
-                        newtok, ok, pk, pv = fn(
+                        newtok, ok, pk, pv, *experts = fn(
                             self.params, self.pool.pages_k, self.pool.pages_v,
                             toks_in, self._put(step.starts),
                             self._put(step.q_lens), self._put_tables(step.tables),
@@ -2158,7 +2244,7 @@ class InferenceEngine:
         flight.recs.append({
             "kind": "spec" if spec_on else "mixed",
             "dev": ((accepts, newtok, ok, toks_in) if spec_on
-                    else (newtok, ok)),
+                    else (newtok, ok, *experts)),
             "rows": rows, "n_dec": len(dec), "takes": takes,
             "n_draft": step.n_draft, "n_spec": n_spec, "t0": t0, "b": b,
             "qw": qw})
@@ -2172,9 +2258,12 @@ class InferenceEngine:
         if spec_on:
             accepts, newtok, ok, toks_f = out
         else:
-            newtok, ok = out
+            newtok, ok, *experts = out
         rows = rec["rows"]
         takes = rec["takes"]
+        if not spec_on:
+            self._observe_experts(experts,
+                                  rec["n_dec"] + sum(takes.values()))
         n_draft = rec["n_draft"]
         now = time.perf_counter()
         n_dec = rec["n_dec"]
@@ -2284,15 +2373,16 @@ class InferenceEngine:
             # the ragged paged-attention kernel takes decode rows (q_len 1)
             # and prompt chunks (q_len up to qw) in the same launch; dead
             # tokens scatter their KV to the scratch page and are masked
-            logits, pages_k, pages_v = model.apply_paged(
-                params, toks, pages_k, pages_v, tables, starts, q_lens)
+            with moe_lib.collect_counts() as counts:
+                logits, pages_k, pages_v = model.apply_paged(
+                    params, toks, pages_k, pages_v, tables, starts, q_lens)
             last = jnp.take_along_axis(
                 logits, jnp.maximum(q_lens - 1, 0)[:, None, None],
                 axis=1)[:, 0]                                   # (B, V)
             last = last + poison[:, None]
             ok = jnp.isfinite(last).all(axis=-1)
             newtok = sampling.sample_ragged(last, key, t, k, p)
-            return newtok, ok, pages_k, pages_v
+            return (newtok, ok, pages_k, pages_v) + _stacked(counts)
 
         return self._jit_step(f"tnn_serve_mixed_w{qw}", fn,
                               donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
@@ -2392,12 +2482,13 @@ class InferenceEngine:
             # layer's new row into its page and the paged-attention kernel
             # streams KV via the block tables — per-step pool traffic is B
             # row writes plus the KV actually attended over
-            logits, pages_k, pages_v = model.apply_decode_paged(
-                params, toks, pages_k, pages_v, tables, offsets)
+            with moe_lib.collect_counts() as counts:
+                logits, pages_k, pages_v = model.apply_decode_paged(
+                    params, toks, pages_k, pages_v, tables, offsets)
             logits = logits + poison[:, None]
             ok = jnp.isfinite(logits).all(axis=-1)
             newtok = sampling.sample_ragged(logits, key, t, k, p)
-            return newtok, ok, pages_k, pages_v
+            return (newtok, ok, pages_k, pages_v) + _stacked(counts)
 
         return self._jit_step("tnn_serve_decode", fn, donate_argnums=(1, 2),
                               n_outs=4, tables_argnum=5)
@@ -2442,7 +2533,7 @@ class InferenceEngine:
                 if self.faults is not None:
                     self.faults.on_decode()
                 with self._dispatch_span(label, key, qw=1):
-                    newtok, ok, pk, pv = fn(
+                    newtok, ok, pk, pv, *experts = fn(
                         self.params, self.pool.pages_k, self.pool.pages_v,
                         self._put(step.toks), self._put(step.offsets),
                         self._put_tables(step.tables), self._put(step.temps),
@@ -2463,14 +2554,15 @@ class InferenceEngine:
                 return None
         self.pool.update_pages(pk, pv)
         self._observe_attention(live, step.offsets + 1, 1)
-        return {"kind": "decode", "dev": (newtok, ok), "live": list(live),
-                "t0": t0, "b": b}
+        return {"kind": "decode", "dev": (newtok, ok, *experts),
+                "live": list(live), "t0": t0, "b": b}
 
     def _decode_commit(self, rec: Dict[str, Any], out, events) -> None:
         """Pure-decode commit half: consumes the fetched (tokens, ok)
         pair and replays the per-row token commit."""
-        newtok, ok = out
+        newtok, ok, *experts = out
         live = rec["live"]
+        self._observe_experts(experts, len(live))
         emitted = 0
         for i, req in enumerate(live):
             if req.state in TERMINAL_STATES:
@@ -2544,7 +2636,7 @@ class InferenceEngine:
         # and deferred publishes must never index reclaimed blocks
         self._flight = None
         self._deferred.clear()
-        self._reuse_key = None
+        self._reuse_keys.clear()
         events: Dict[str, List] = {"tokens": [], "finished": [],
                                    "failed": [], "timed_out": []}
         bucket = "timed_out" if state is RequestState.TIMED_OUT else "failed"
@@ -2586,7 +2678,7 @@ class InferenceEngine:
         # same in-flight/deferred reset rationale as abort_all
         self._flight = None
         self._deferred.clear()
-        self._reuse_key = None
+        self._reuse_keys.clear()
         events: Dict[str, List] = {"tokens": [], "finished": [],
                                    "failed": [], "timed_out": []}
         now = time.perf_counter()

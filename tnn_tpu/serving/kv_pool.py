@@ -78,6 +78,17 @@ summary entries (``table_width``). ``window = None`` is the case "every
 position exact, no summaries": GPT-2's, with the exact table the whole
 table. ``table_need`` / ``lifetime_blocks`` answer for both kinds, and
 ``check_invariants`` / ``check_step_writes`` know both.
+
+Latent pages
+------------
+A model with latent (MLA) attention caches ONE row a token a layer, ``[c_kv |
+k_rope | 0]``, that is key and value at once and has no head axis
+(``ops/pallas/mla_attention.py``). With ``latent=True`` the pool is built
+for ``num_kv_heads`` = 1 and ``head_dim`` = the row's width (whole 128-lane
+registers, so that the pool rests in the layout its readers take):
+``pages_k`` is ``(L, N, 1, bs, row)`` from the same allocator and tables,
+and the value pool is NOT allocated: ``pages_v`` is a stub of one register
+that rides through the step programs untouched.
 """
 from __future__ import annotations
 
@@ -103,7 +114,15 @@ class PagedKVPool:
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int,
                  num_blocks: int, block_size: int = 16, dtype=jnp.float32,
                  kv_dtype: str = "f32", sharding=None, sp: int = 1,
-                 window: Optional[int] = None, chunk: Optional[int] = None):
+                 window: Optional[int] = None, chunk: Optional[int] = None,
+                 latent: bool = False):
+        if latent and (window is not None or sp > 1 or kv_dtype != "f32"
+                       or num_kv_heads != 1 or head_dim % 128):
+            raise ValueError(
+                "a latent pool holds one row of whole 128-lane registers a "
+                "token (num_kv_heads 1) and is neither windowed, "
+                "block-sharded (sp) nor int8")
+        self.latent = bool(latent)
         if (window is None) != (chunk is None):
             raise ValueError("window and chunk come together")
         if window is not None:
@@ -261,9 +280,9 @@ class PagedKVPool:
 
         Counts the page data only — the int8 scale sidecar is reported
         separately (``kv_scale_bytes_per_token``) because it is the part
-        that does NOT shrink with the page dtype."""
-        return 2 * self.num_layers * self.num_kv_heads * self.head_dim \
-            * self.page_itemsize
+        that does NOT shrink with the page dtype. A latent pool has no V."""
+        return (1 if self.latent else 2) * self.num_layers \
+            * self.num_kv_heads * self.head_dim * self.page_itemsize
 
     @property
     def kv_scale_bytes_per_token(self) -> int:
@@ -771,7 +790,10 @@ class PagedKVPool:
             self.pages_v = fresh()
         else:
             self.pages_k = put(np.zeros(shape, np.dtype(self.dtype)))
-            self.pages_v = put(np.zeros(shape, np.dtype(self.dtype)))
+            # a latent row is key and value at once: no value pool, a stub
+            self.pages_v = put(np.zeros(
+                (self.num_layers, 1, 1, 8, 128) if self.latent else shape,
+                np.dtype(self.dtype)))
 
     def export_blocks(self, blocks: Sequence[int]) \
             -> List[tuple]:

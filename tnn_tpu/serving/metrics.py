@@ -482,6 +482,15 @@ class ServingMetrics:
         self.eva_windows_rolled = 0
         self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
         self.attn_fetch_row_steps = 0
+        # a model with an expert layer of which this chip holds a share
+        # (nn.moe.ExpertShare): assignments that fell on held experts and
+        # all assignments; over layer-steps, the share of held experts with
+        # a token and the busiest held expert's load over the mean
+        self.expert_held_assignments = 0
+        self.expert_assignments = 0
+        self.expert_layer_steps = 0
+        self.expert_hit_sum = 0.0
+        self.expert_imbalance_sum = 0.0
         # runtime-resilience counters (supervisor / overload degradation)
         self.shed = 0                 # queued requests displaced by priority
         self.engine_restarts = 0      # supervisor-driven engine recoveries
@@ -623,6 +632,21 @@ class ServingMetrics:
         (``paged_attention.fetch_group``)."""
         self.attn_fetch_fill_sum += float(sum(fills))
         self.attn_fetch_row_steps += len(fills)
+
+    def observe_experts(self, counts, assignments: int) -> None:
+        """One step of a model with an expert layer: ``counts`` (layers,
+        held) the assignments each held expert took in each layer,
+        ``assignments`` all the step's (live token, expert) pairs a layer,
+        those that fell on experts held elsewhere too."""
+        layers = counts.shape[0]
+        self.expert_held_assignments += int(counts.sum())
+        self.expert_assignments += int(assignments) * layers
+        self.expert_layer_steps += layers
+        self.expert_hit_sum += float((counts > 0).mean(axis=1).sum())
+        mean = counts.mean(axis=1)
+        # a layer no held expert of which took a token reads 0, not 0 / 0
+        self.expert_imbalance_sum += float(
+            (counts.max(axis=1) / (mean + (mean == 0))).sum())
 
     def observe_eva_roll(self) -> None:
         """A request's window ended: its exact pages went back to the pool."""
@@ -1001,6 +1025,15 @@ class ServingMetrics:
                                       / self.eva_row_steps),
                 eva_summary_rows_max=self.eva_summary_rows_max,
                 eva_windows_rolled=self.eva_windows_rolled)
+        if self.expert_layer_steps:
+            # only a model with an expert layer has these
+            out.update(
+                expert_held_share=(self.expert_held_assignments
+                                   / max(self.expert_assignments, 1)),
+                experts_hit_share=(self.expert_hit_sum
+                                   / self.expert_layer_steps),
+                expert_load_max_over_mean=(self.expert_imbalance_sum
+                                           / self.expert_layer_steps))
         return out
 
     # -- Prometheus exposition ------------------------------------------------

@@ -158,21 +158,21 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
 
 
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
-                kv_key: Tuple[Any, ...], speculative: bool = False,
+                kv_key: Tuple[Any, ...], ahead: int = 0,
                 sum_at: int = 0) -> DecodeStep:
-    """Pack the pure-decode batch. ``speculative=True`` packs the
-    overlapped engine's predicted step N+1: each row's offset assumes
-    exactly one more token committed, and the token column is left zero —
-    the dispatched program reads step N's unfetched sampled tokens
-    directly as its device-resident input."""
+    """Pack the pure-decode batch. ``ahead`` = j > 0 packs the overlapped
+    engine's predicted step N+j: each row's offset assumes exactly j more
+    tokens committed, and the token column is left zero — the dispatched
+    program reads its predecessor's unfetched sampled tokens directly as
+    its device-resident input."""
     step = DecodeStep(
         key=("pdecode", b, nb) + kv_key, b=b, nb=nb,
         toks=np.zeros((b,), np.int32),
         offsets=np.zeros((b,), np.int32),
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(live):
-        if not speculative:
+        if not ahead:
             step.toks[i] = req.next_token
-        step.offsets[i] = req.cache_len + (1 if speculative else 0)
+        step.offsets[i] = req.cache_len + ahead
         _fill_row(step, i, req, sum_at)
     return step

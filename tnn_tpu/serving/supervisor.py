@@ -684,7 +684,8 @@ class EngineSupervisor:
             if eng.in_flight is None:
                 eng.begin_step()
             # host work below overlaps the dispatched step's device time
-            eng.try_speculate()
+            while eng.try_speculate():
+                pass
             eng.run_deferred()
             events = eng.finish_step()
         except Exception as e:  # noqa: BLE001 — crash recovery is the point
