@@ -90,6 +90,9 @@ CHIP = dict(
     latent=dict(blocks=256, block_size=128, row=384, value=256, heads=32,
                 batch=8, table=24, chunk=64),
     experts=dict(held=8, hidden=2048, width=4096, tokens=(32, 2048)),
+    # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
+    # 32 rows of Mistral Small 4's slice
+    sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
     train_model="cifar100_wrn16_8", train_batch=256, train_classes=100,
     degree=4, mesh_steps=5)
 REHEARSAL = dict(
@@ -100,6 +103,7 @@ REHEARSAL = dict(
     latent=dict(blocks=16, block_size=8, row=128, value=32, heads=4,
                 batch=3, table=6, chunk=8),
     experts=dict(held=4, hidden=256, width=128, tokens=(8, 64)),
+    sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
 
@@ -241,7 +245,8 @@ def phase_kernel(cfg) -> list:
                         failures.append(
                             f"kernel {fname}/{pname}/stats={stats}/"
                             f"holes={tname}: error above {tol}")
-    return failures + _latent_and_expert_kernels(cfg, rand, rng)
+    return failures + _latent_and_expert_kernels(cfg, rand, rng) \
+        + _sampler_steps(cfg, rng)
 
 
 def _latent_and_expert_kernels(cfg, rand, rng) -> list:
@@ -305,6 +310,49 @@ def _latent_and_expert_kernels(cfg, rand, rng) -> list:
         want = expert_gmm(*args, tile=tile, backend="xla")
         check(f"expert_gmm {tokens} rows, tile {tile}", got[:live * tile],
               want[:live * tile], 4 * KERNEL_TOL["bf16"])
+    return failures
+
+
+def _sampler_steps(cfg, rng) -> list:
+    """``sampling.sample_ragged`` under jit at the served vocabularies: a
+    step of greedy rows (the argmax alone) and a step with ONE sampled row
+    (one sort of the vocabulary, the softmax, the draw), timed back to back
+    from the host around ``block_until_ready``; both hold every greedy row to
+    the argmax."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.models.sampling import sample_ragged
+
+    failures = []
+    step = jax.jit(sample_ragged)
+    for rows, v in cfg["sampler"]["shapes"]:
+        logits = jnp.asarray(rng.standard_normal((rows, v)), jnp.float32)
+        best = np.asarray(jnp.argmax(logits, axis=-1))
+        k = jnp.full((rows,), 40, jnp.int32)
+        p = jnp.full((rows,), 0.9, jnp.float32)
+        greedy = jnp.zeros((rows,), jnp.float32)
+        kinds = {"greedy": greedy, "one_sampled": greedy.at[0].set(0.8)}
+        keys = list(jax.random.split(jax.random.PRNGKey(1),
+                                     cfg["sampler"]["iters"]))
+        took = {}
+        for kind, t in kinds.items():
+            got = np.asarray(step(logits, keys[0], t, k, p))     # compiles
+            first = 0 if kind == "greedy" else 1    # row 0 is the drawn one
+            if not ((got[first:] == best[first:]).all()
+                    and 0 <= got[0] < v):
+                failures.append(f"sampler {kind} ({rows}, {v}): a greedy "
+                                f"row is not the argmax")
+            t0 = time.perf_counter()
+            for key in keys:
+                out = step(logits, key, t, k, p)
+            jax.block_until_ready(out)
+            took[kind] = (time.perf_counter() - t0) / len(keys) * 1e3
+        log(f"sample_ragged ({rows}, {v}): greedy step "
+            f"{took['greedy']:.3f} ms, step with one sampled row "
+            f"{took['one_sampled']:.3f} ms (host clock, "
+            f"{cfg['sampler']['iters']} calls each)")
     return failures
 
 

@@ -275,6 +275,38 @@ def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
     assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
 
 
+# -- the sampler's conditional (PR 35): the chip's compiler keeps it ----------
+
+
+def test_sampler_sort_stands_inside_the_conditional(one_chip,
+                                                    no_compile_cache, alarm):
+    """``sampling.sample_ragged`` at a served vocabulary (Mistral Small 4's
+    slice, 32 rows; the sort takes the compiler ~25 s, so one shape),
+    compiled for the v5e: ONE ``conditional`` at the entry, which the
+    compiler has not flattened into a ``select``, and the ONE sort of the
+    vocabulary inside a branch of it: a step of greedy rows runs no sort."""
+    from tnn_tpu.models.sampling import sample_ragged
+
+    rows, vocab = 32, 32768
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = jax.jit(sample_ragged).lower(
+        spec((rows, vocab), jnp.float32), spec((2,), jnp.uint32),
+        spec((rows,), jnp.float32), spec((rows,), jnp.int32),
+        spec((rows,), jnp.float32)).compile().as_text()
+    blocks = [b.strip() for b in text.split("\n\n")
+              if "{" in b.strip().split("\n", 1)[0]]
+    entry = [b for b in blocks if b.startswith("ENTRY")]
+    assert len(entry) == 1
+    assert len(re.findall(r" conditional\(", entry[0])) == 1
+    assert " sort(" not in entry[0]
+    assert len(re.findall(r" sort\(", text)) == 1
+    assert f"f32[{rows},{vocab}]" in re.search(r"[^\n]* sort\(",
+                                               text).group(0)
+
+
 # -- the one-writer invariant --------------------------------------------------
 
 

@@ -12,7 +12,9 @@ Two entry points:
   * ``sample_ragged(logits, key, t, k, p)`` — the fully vectorized kernel the
     serving engine calls with TRACED per-request parameter arrays, so one
     compiled decode step serves any mix of greedy/temperature/top-k/top-p
-    requests.
+    requests. It runs what the step's rows ask for: the filter, its sort of
+    the whole vocabulary and the draw stand in one ``lax.cond`` on "any row
+    has a temperature", so a step of greedy rows runs an argmax.
 """
 from __future__ import annotations
 
@@ -30,13 +32,19 @@ def _is_perrow(x) -> bool:
     return getattr(x, "ndim", 0) > 0
 
 
-def _top_p_filter(x, p):
+def _sort_down(x):
+    return jnp.flip(jnp.sort(x, axis=-1), axis=-1)
+
+
+def _top_p_filter(x, p, down=None):
     """Nucleus filter over already-scaled logits: a token survives if the
     probability mass BEFORE it is still below ``p`` — the highest-probability
     token always survives. ``p`` is a python float (scalar path) or an array
     broadcastable to x.shape[:-1] + (1,) (ragged path); values outside (0, 1)
-    must already be mapped to keep-all by the caller."""
-    down = jnp.flip(jnp.sort(x, axis=-1), axis=-1)
+    must already be mapped to keep-all by the caller. ``down``: ``x``'s rows
+    in descending order, where the caller has them already."""
+    if down is None:
+        down = _sort_down(x)
     probs = jax.nn.softmax(down, axis=-1)
     csum = jnp.cumsum(probs, axis=-1)
     keep = (csum - probs) < p
@@ -72,12 +80,15 @@ def filter_logits(logits, temperature, top_k, top_p):
     # top-k: the kth-largest value is the row's cutoff; k outside [1, V)
     # degrades to keep-all (cutoff = the minimum)
     k_eff = jnp.where((k > 0) & (k < v), k, v)
-    down = jnp.flip(jnp.sort(x, axis=-1), axis=-1)
+    down = _sort_down(x)
     kth = jnp.take_along_axis(down, k_eff - 1, axis=-1)
     x = jnp.where(x < kth, NEG_INF, x)
-    # top-p over the top-k survivors
+    # top-p over the top-k survivors. The one sort serves both filters: the
+    # same cut of the sorted row IS the sort of the cut row (ties at the
+    # k-th value survive in both; what falls becomes NEG_INF at the tail)
+    down = jnp.where(down < kth, NEG_INF, down)
     p_eff = jnp.where((p > 0.0) & (p < 1.0), p, 1.0)
-    return _top_p_filter(x, p_eff)
+    return _top_p_filter(x, p_eff, down)
 
 
 @jax.named_scope("sample")
@@ -88,15 +99,25 @@ def sample_ragged(logits, key, temperature, top_k, top_p):
     to logits.shape[:-1]. Per row: temperature<=0 -> greedy argmax; top_k<=0
     or >=V -> keep-all; top_p outside (0, 1) -> keep-all. Filters compose as
     in the scalar path (top-k first, then top-p over the survivors).
+
+    The filter (a sort of the whole vocabulary), the softmax and the draw run
+    only in a step that holds a row with a temperature: what decides is the
+    step's own input, so one compiled program serves both kinds of step. A
+    step with such a row returns what the straight-line form returns, bit for
+    bit (the key is consumed the same way).
     """
     logits = logits.astype(jnp.float32)
     rows = logits.shape[:-1]
     t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), rows)
 
     greedy = jnp.argmax(logits, axis=-1)
-    x = filter_logits(logits, temperature, top_k, top_p)
-    sampled = jax.random.categorical(key, x, axis=-1)
-    return jnp.where(t > 0.0, sampled, greedy)
+
+    def draw():
+        x = filter_logits(logits, temperature, top_k, top_p)
+        sampled = jax.random.categorical(key, x, axis=-1)
+        return jnp.where(t > 0.0, sampled, greedy)
+
+    return jax.lax.cond(jnp.any(t > 0.0), draw, lambda: greedy)
 
 
 def make_sampler(temperature=0.0, top_k=0, top_p=0.0):

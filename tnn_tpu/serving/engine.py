@@ -1076,15 +1076,19 @@ class InferenceEngine:
             getattr(self.metrics, observe)(time.perf_counter() - t0)
             sp.__exit__(None, None, None)
 
-    def _dispatch_span(self, kind: str, key, step: Optional[int] = None,
-                       qw: Optional[int] = None):
+    def _dispatch_span(self, kind: str, key, temps: np.ndarray,
+                       step: Optional[int] = None, qw: Optional[int] = None):
         """``serve.dispatch``: the ENQUEUE of one compiled program (its
         inputs' ``device_put`` and the asynchronous launch), not its
         compute — that is the device's, and shows as ``serve.fetch``. A
         paged step program ``qw`` tokens wide also says what a grid step of
         its attention kernel fetches (``attn_pages``, ``attn_heads``) and
         how many KV heads lie side by side in a page row of the pool
-        (``kv_lane_pack``)."""
+        (``kv_lane_pack``). ``temps`` are the step's temperatures as packed:
+        ``sampled_rows`` of them ask for a draw, and a step with none runs
+        the sampler's argmax alone (``sampling.sample_ragged``)."""
+        sampled = int(np.count_nonzero(temps > 0.0))
+        self.metrics.observe_step_dispatch(sampled)
         group = None if qw is None else self._attn_group(qw)
         attrs = {} if group is None else dict(
             attn_pages=group[0], attn_heads=group[1],
@@ -1098,7 +1102,7 @@ class InferenceEngine:
         return self.tracer.span(
             "serve.dispatch", EventType.COMPUTE,
             step=self.step_seq if step is None else step, kind=kind,
-            key=key, **attrs)
+            key=key, sampled_rows=sampled, **attrs)
 
     @property
     def in_flight(self) -> Optional["StepInFlight"]:
@@ -1398,8 +1402,8 @@ class InferenceEngine:
         prev_tok = rec["dev"][0]    # its predecessor's unfetched samples
         try:
             with self._sync_guard(), \
-                    self._dispatch_span(label, key, self.step_seq + j,
-                                        qw=1):
+                    self._dispatch_span(label, key, step.temps,
+                                        self.step_seq + j, qw=1):
                 newtok, ok, pk, pv, *experts = fn(
                     self.params, self.pool.pages_k, self.pool.pages_v,
                     prev_tok, self._put(offsets), self._put_tables(step.tables),
@@ -2211,7 +2215,7 @@ class InferenceEngine:
                 if self.faults is not None:
                     self.faults.on_decode()
                 with self._dispatch_span("spec" if spec_on else "mixed",
-                                         key, qw=qw):
+                                         key, step.temps, qw=qw):
                     if spec_on:
                         accepts, newtok, ok, pk, pv = fn(
                             self.params, self.pool.pages_k, self.pool.pages_v,
@@ -2532,7 +2536,7 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with self._dispatch_span(label, key, qw=1):
+                with self._dispatch_span(label, key, step.temps, qw=1):
                     newtok, ok, pk, pv, *experts = fn(
                         self.params, self.pool.pages_k, self.pool.pages_v,
                         self._put(step.toks), self._put(step.offsets),

@@ -482,6 +482,10 @@ class ServingMetrics:
         self.eva_windows_rolled = 0
         self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
         self.attn_fetch_row_steps = 0
+        # step programs dispatched, and those with a row that asks for a
+        # draw: the others run the sampler's argmax alone
+        self.dispatched_steps = 0
+        self.sampled_steps = 0
         # a model with an expert layer of which this chip holds a share
         # (nn.moe.ExpertShare): assignments that fell on held experts and
         # all assignments; over layer-steps, the share of held experts with
@@ -632,6 +636,12 @@ class ServingMetrics:
         (``paged_attention.fetch_group``)."""
         self.attn_fetch_fill_sum += float(sum(fills))
         self.attn_fetch_row_steps += len(fills)
+
+    def observe_step_dispatch(self, sampled_rows: int) -> None:
+        """One step program dispatched, ``sampled_rows`` of whose rows have
+        a temperature above 0."""
+        self.dispatched_steps += 1
+        self.sampled_steps += int(sampled_rows > 0)
 
     def observe_experts(self, counts, assignments: int) -> None:
         """One step of a model with an expert layer: ``counts`` (layers,
@@ -988,6 +998,8 @@ class ServingMetrics:
             "emit_delay_ms_p50": ms(_percentile(self.emit_delay_s, 50)),
             "emit_delay_ms_p99": ms(_percentile(self.emit_delay_s, 99)),
             "overlap_rebuilds": self.overlap_rebuilds,
+            "sampled_step_share": (self.sampled_steps / self.dispatched_steps)
+            if self.dispatched_steps else 0.0,
             "step_latency_ms_p50": ms(_percentile(self.step_latency_s, 50)),
             "step_latency_ms_p99": ms(_percentile(self.step_latency_s, 99)),
             "queue_wait_ms_p50": ms(_percentile(self.queue_wait_s, 50)),
