@@ -90,6 +90,11 @@ CHIP = dict(
     latent=dict(blocks=256, block_size=128, row=384, value=256, heads=32,
                 batch=8, table=24, chunk=64),
     experts=dict(held=8, hidden=2048, width=4096, tokens=(32, 2048)),
+    # the paged kernel under a sliding window at Trinity Large's widths: 48
+    # query heads over 8 KV heads of 128, pages of 128, a window of 4,096
+    # read from a table that lists only the pages a row still holds
+    window=dict(blocks=288, block_size=128, heads=48, kv_heads=8,
+                head_dim=128, batch=8, window=4096, chunk=64),
     # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
     # 32 rows of Mistral Small 4's slice
     sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
@@ -103,6 +108,8 @@ REHEARSAL = dict(
     latent=dict(blocks=16, block_size=8, row=128, value=32, heads=4,
                 batch=3, table=6, chunk=8),
     experts=dict(held=4, hidden=256, width=128, tokens=(8, 64)),
+    window=dict(blocks=32, block_size=8, heads=4, kv_heads=2, head_dim=32,
+                batch=3, window=16, chunk=8),
     sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
@@ -246,7 +253,50 @@ def phase_kernel(cfg) -> list:
                             f"kernel {fname}/{pname}/stats={stats}/"
                             f"holes={tname}: error above {tol}")
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
-        + _sampler_steps(cfg, rng)
+        + _window_kernel(cfg, rand, rng) + _sampler_steps(cfg, rng)
+
+
+def _window_kernel(cfg, rand, rng) -> list:
+    """``tnn_paged_attention_win`` against its XLA form: a lower bound a
+    query, a walk that starts at the first query's bound, tables that list a
+    row's pages from ``table_base`` on (one of them a page behind: given
+    back only at the next commit), decode and chunk shapes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                    window_table_pages)
+
+    k = cfg["window"]
+    bs, B, W = k["block_size"], k["batch"], k["window"]
+    nb = window_table_pages(W, bs)
+    pk, pv = (rand((1, k["blocks"], k["kv_heads"], bs, k["head_dim"]))
+              for _ in range(2))
+    tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
+    failures = []
+    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
+        kv_lens = np.array([qw, W // 2, W, W + 1, W + bs - 1, 3 * W + 5,
+                            7 * W + bs // 2, 9 * W])[:B].astype(np.int32)
+        q_lens = np.minimum(np.array([qw, 1, qw // 2 + 1] * B)[:B], kv_lens)
+        base = np.maximum(kv_lens - q_lens - W + 1, 0) // bs
+        base[::3] = np.maximum(base[::3] - 1, 0)
+        q = rand((B, qw, k["heads"], k["head_dim"]))
+        kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32), window=W,
+                  table_base=jnp.asarray(base, jnp.int32))
+        args = (q, pk, pv, tables, jnp.asarray(kv_lens))
+        got = np.asarray(paged_attention(
+            *args, backend="pallas", interpret=cfg["rehearse"],
+            group_positions=4 * bs, **kw), np.float32)
+        want = np.asarray(paged_attention(*args, backend="xla", **kw),
+                          np.float32)
+        err = float(np.max(np.abs(got - want)))
+        ok = bool(np.isfinite(got).all()) and err <= KERNEL_TOL["bf16"]
+        log(f"{'window ' + fname:28s} max|err| {err:9.2e} tol "
+            f"{KERNEL_TOL['bf16']:6.0e} {'ok' if ok else 'FAIL'}")
+        if not ok:
+            failures.append(f"kernel window {fname}: error above "
+                            f"{KERNEL_TOL['bf16']}")
+    return failures
 
 
 def _latent_and_expert_kernels(cfg, rand, rng) -> list:

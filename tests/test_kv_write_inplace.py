@@ -275,6 +275,62 @@ def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
     assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
 
 
+# -- the two-group model's step (PR 37): both kinds of layer in one program -----
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk64"])
+def test_two_group_step_compiles_for_the_chip_with_no_pool_copy(
+        form, one_chip, no_compile_cache, alarm):
+    """Trinity Large's block as published (48 query heads over 8 KV heads of
+    128, window 4,096, dense layer of 12,288, experts of 3,072 x 3,072; 4 of
+    them held here so that the compile is quick), its five layers (a dense
+    sliding layer, sliding, sliding, full, sliding), pages of 128, 32 rows,
+    the cell's table (289 global entries, 4 x 34 window entries, the base):
+    the chip's compiler accepts the window kernel beside the plain one and
+    the grouped expert product in the decode and the chunk form, and the
+    step around them makes NO pool-shaped copy: ONE layer of pages serves
+    both groups in the layout its write and its kernels read."""
+    from tnn_tpu import models
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    model = models.create("trinity_large_ep8", held_experts=4)
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    pool = PagedKVPool(1, 8, 128, 2, 128, dtype=jnp.bfloat16,
+                       groups=model.page_groups)
+    shape = pool.page_shape[:1] + (1024,) + pool.page_shape[2:]
+    assert shape == (1, 1024, 8, 128, 128)
+    pages = spec(shape, jnp.bfloat16)
+    width = pool.table_width(36992)
+    assert width == 289 + 4 * 34 + 1
+    tables, lens = spec((32, width), jnp.int32), spec((32,), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(model.apply_decode_paged,
+                              donate_argnums=(2, 3)).lower(
+                params, spec((32,), jnp.int32), pages, pages, tables, lens)
+        else:
+            lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
+                params, spec((32, 64), jnp.int32), pages, pages, tables, lens,
+                lens)
+        text = lowered.compile().as_text()
+    names = set(re.findall(r"tnn_[a-z_]+[a-z]", text))
+    assert {"tnn_paged_attention", "tnn_paged_attention_win",
+            "tnn_expert_gmm"} <= names
+    assert "tnn_mla_attention" not in names
+    dims = ",".join(map(str, shape))
+    pool_copy = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+    assert not [line for line in text.splitlines() if pool_copy.search(line)]
+    layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
+                         text.split("\n", 1)[0].split(")->(")[0])
+    assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
+
+
 # -- the sampler's conditional (PR 35): the chip's compiler keeps it ----------
 
 

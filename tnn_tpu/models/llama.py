@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from ..core import rng as rnglib
 from ..core.module import Module, register_module
-from ..nn.attention import LatentAttention, MultiHeadAttention
+from ..nn.attention import (GatedAttention, LatentAttention,
+                            MultiHeadAttention)
 from ..nn.embedding import Embedding
 from ..nn.layers import Dense
 from ..nn.moe import ExpertShare
@@ -40,16 +41,25 @@ class LlamaBlock(Module):
                  norm_eps: float = 1e-6, norm_unit_offset: bool = False,
                  residual_f32: bool = False, window: Optional[int] = None,
                  chunk: Optional[int] = None, latent: Optional[dict] = None,
-                 experts: Optional[dict] = None, name=None, policy=None):
+                 experts: Optional[dict] = None,
+                 gated: Optional[dict] = None, sandwich: bool = False,
+                 name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
         self.mlp_hidden = int(mlp_hidden)
         # the block is GIVEN its attention (heads: K/V of every position;
         # eva: ``window`` and ``chunk``; latent: ``latent``, the keywords of
-        # ``nn.attention.LatentAttention``) and its feed-forward (gated, or
-        # ``experts``: the keywords of ``nn.moe.ExpertShare``)
+        # ``nn.attention.LatentAttention``; gated: ``gated``, those of
+        # ``nn.attention.GatedAttention``, THIS layer's: sliding with its
+        # window and rotary base, or global with neither) and its
+        # feed-forward (gated, or ``experts``: the keywords of
+        # ``nn.moe.ExpertShare``)
         self.latent = dict(latent) if latent else None
         self.experts = dict(experts) if experts else None
+        self.gated = dict(gated) if gated else None
+        # ``sandwich``: a norm on each sublayer's OUTPUT too, before it is
+        # added (four norms a block)
+        self.sandwich = bool(sandwich)
         self.num_kv_heads = int(num_kv_heads) if num_kv_heads else self.num_heads
         self.rope_theta = float(rope_theta)
         self.backend = backend
@@ -68,6 +78,11 @@ class LlamaBlock(Module):
             self.attn = LatentAttention(num_heads, norm_eps=self.norm_eps,
                                         backend=backend, policy=p,
                                         **self.latent)
+        elif self.gated:
+            self.attn = GatedAttention(num_heads, self.num_kv_heads,
+                                       norm_eps=self.norm_eps,
+                                       backend=backend, policy=p,
+                                       **self.gated)
         else:
             self.attn = MultiHeadAttention(
                 num_heads, causal=True, backend=backend,
@@ -75,6 +90,7 @@ class LlamaBlock(Module):
                 use_bias=False, kv_cache_dtype=kv_cache_dtype, window=window,
                 chunk=chunk, policy=p)
         self.ln2 = RMSNorm(**norm)
+        self.post = RMSNorm(**norm) if self.sandwich else None
         self.moe = ExpertShare(policy=p, **self.experts) \
             if self.experts else None
         self.gate = Dense(self.mlp_hidden, use_bias=False, policy=p)
@@ -84,7 +100,10 @@ class LlamaBlock(Module):
 
     def _init(self, rng, input_shape):
         d = input_shape[-1]
-        k1, k2, k3, k4, k5, k6 = jax.random.split(rng, 6)
+        # the sandwich's two norms draw keys of their own; a block without
+        # them splits as it always did (seeded initialisations stay put)
+        keys = jax.random.split(rng, 8 if self.sandwich else 6)
+        k1, k2, k3, k4, k5, k6 = keys[:6]
         down = Dense(d, use_bias=False, policy=self.policy)
         hidden_shape = tuple(input_shape[:-1]) + (self.mlp_hidden,)
         params = {
@@ -92,6 +111,9 @@ class LlamaBlock(Module):
             "attn": self.attn.init(k2, input_shape)["params"],
             "ln2": self.ln2.init(k3, input_shape)["params"],
         }
+        if self.sandwich:
+            for name, key in (("ln1_post", keys[6]), ("ln2_post", keys[7])):
+                params[name] = self.post.init(key, input_shape)["params"]
         if self.moe is not None:
             params["moe"] = self.moe.init(k4, input_shape)["params"]
         else:
@@ -122,15 +144,21 @@ class LlamaBlock(Module):
     def _ln1(self, params, x):
         return self.ln1.apply({"params": params["ln1"], "state": {}}, x)[0]
 
-    def _add(self, x, h):
-        return x + h.astype(x.dtype)
+    def _add(self, x, h, post=None):
+        """x + h, ``h`` first through the sandwich's norm ``post`` of the
+        sublayer it closes (in the residual's dtype)."""
+        h = h.astype(x.dtype)
+        if self.sandwich:
+            h = self.post.apply({"params": post, "state": {}}, h)[0]
+        return x + h
 
     def _mlp_residual(self, params, x, train=False, live=None):
         if self.moe is not None:
             return self._experts_residual(params, x, live)
         with jax.named_scope("mlp"):
             h, _ = self.ln2.apply({"params": params["ln2"], "state": {}}, x)
-            return self._add(x, self._swiglu(params, h, train))
+            return self._add(x, self._swiglu(params, h, train),
+                             params.get("ln2_post"))
 
     def _experts_residual(self, params, x, live):
         """x + experts(rms(x)): the norm (on the float32 residual, so the
@@ -141,13 +169,18 @@ class LlamaBlock(Module):
         y, _ = self.moe.apply({"params": params["moe"], "state": {}}, h,
                               live=live)
         with jax.named_scope("moe_shared"):
-            return self._add(x, y)
+            return self._add(x, y, params.get("ln2_post"))
+
+    @jax.named_scope("attn_out")
+    def _attn_residual(self, params, x, h):
+        return self._add(x, h, params.get("ln1_post"))
 
     def _apply(self, params, state, x, *, train, rng):
         k1 = rnglib.split_for(rng, 1)[0]
         h, _ = self.attn.apply({"params": params["attn"], "state": {}},
                                self._ln1(params, x), train=train, rng=k1)
-        return self._mlp_residual(params, self._add(x, h), train), state
+        return self._mlp_residual(
+            params, self._attn_residual(params, x, h), train), state
 
     # -- cached decode --------------------------------------------------------
 
@@ -157,17 +190,20 @@ class LlamaBlock(Module):
     def apply_cached(self, params, x, cache, offset):
         h, new_cache = self.attn.apply_cached(
             {"params": params["attn"]}, self._ln1(params, x), cache, offset)
-        return self._mlp_residual(params, self._add(x, h)), new_cache
+        return self._mlp_residual(
+            params, self._attn_residual(params, x, h)), new_cache
 
     def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
-                    layer, q_lens=None):
+                    layer, q_lens=None, **where):
         """apply_cached against the paged KV pool (see
-        MultiHeadAttention.apply_paged for the contract)."""
+        MultiHeadAttention.apply_paged for the contract). ``where``: what
+        else says where THIS layer's pages are (a window layer's
+        ``table_base``: ``GatedAttention.apply_paged``)."""
         h, pages_k, pages_v = self.attn.apply_paged(
             {"params": params["attn"]}, self._ln1(params, x), pages_k,
-            pages_v, block_tables, offsets, layer=layer, q_lens=q_lens)
-        with jax.named_scope("attn_out"):
-            x = self._add(x, h)
+            pages_v, block_tables, offsets, layer=layer, q_lens=q_lens,
+            **where)
+        x = self._attn_residual(params, x, h)
         live = None
         if self.moe is not None:
             # a padding position takes no expert: past a row's live tokens,
@@ -192,7 +228,8 @@ class LlamaBlock(Module):
 
 _BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
                    "residual_f32": False, "window": None, "chunk": None,
-                   "latent": None, "experts": None}
+                   "latent": None, "experts": None, "gated": None,
+                   "sandwich": False}
 
 
 def _block_options(m):
@@ -228,11 +265,28 @@ class Llama(PagedDecoder, Module):
                  residual_f32: bool = False, window: Optional[int] = None,
                  chunk: Optional[int] = None, num_pred_heads: int = 1,
                  latent: Optional[dict] = None,
-                 experts: Optional[dict] = None, name=None, policy=None):
+                 experts: Optional[dict] = None,
+                 gated: Optional[dict] = None, sandwich: bool = False,
+                 embed_scale: bool = False, num_dense_layers: int = 0,
+                 dense_hidden: Optional[int] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.latent = dict(latent) if latent else None
         self.experts = dict(experts, held=list(experts["held"])) \
             if experts else None
+        # ``gated``: ``head_dim``, ``window``, ``rope_theta`` and
+        # ``layer_types``, one of "sliding_attention" / "full_attention" a
+        # layer: window layers beside global layers in ONE model
+        # (nn.attention.GatedAttention; the pool then holds two groups of
+        # page, ``page_groups``)
+        self.gated = dict(gated, layer_types=list(gated["layer_types"])) \
+            if gated else None
+        self.sandwich = bool(sandwich)
+        # the embedding times sqrt(d_model) (muP)
+        self.embed_scale = bool(embed_scale)
+        # the first layers' feed-forward is dense, of ``dense_hidden``, in a
+        # model whose others hold ``experts``
+        self.num_dense_layers = int(num_dense_layers)
+        self.dense_hidden = int(dense_hidden) if dense_hidden else None
         self.norm_eps = float(norm_eps)
         self.norm_unit_offset = bool(norm_unit_offset)
         self.residual_f32 = bool(residual_f32)
@@ -256,21 +310,84 @@ class Llama(PagedDecoder, Module):
         self.kv_cache_dtype = kv_cache_dtype
         p = self.policy
         self.wte = Embedding(vocab_size, d_model, policy=p)
-        self.blocks = [LlamaBlock(num_heads, self.mlp_hidden,
-                                  num_kv_heads=self.num_kv_heads,
+        if self.gated and len(self.gated["layer_types"]) != self.num_layers:
+            raise ValueError("layer_types names a kind for each of the "
+                             f"{self.num_layers} layers")
+        self.head_dim = int(self.gated["head_dim"]) if self.gated \
+            else self.d_model // self.num_heads
+        self.blocks = [LlamaBlock(num_heads, num_kv_heads=self.num_kv_heads,
                                   rope_theta=rope_theta, backend=backend,
                                   kv_cache_dtype=kv_cache_dtype,
                                   norm_eps=norm_eps,
                                   norm_unit_offset=norm_unit_offset,
                                   residual_f32=residual_f32, window=window,
-                                  chunk=chunk, latent=latent, experts=experts,
-                                  policy=p)
-                       for _ in range(num_layers)]
+                                  chunk=chunk, latent=latent,
+                                  sandwich=sandwich, policy=p,
+                                  **self._layer_options(i))
+                       for i in range(num_layers)]
         if self.latent:     # one cached row a token, no head axis
             self.num_kv_heads = 1
             self.latent_row = self.blocks[0].attn.latent_row
         self.ln_f = RMSNorm(eps=norm_eps, unit_offset=norm_unit_offset,
                             policy=p)
+
+    def _layer_options(self, i: int) -> dict:
+        """What layer ``i`` is given that its neighbour may not be: its
+        feed-forward (dense of ``dense_hidden`` among the first
+        ``num_dense_layers``, else the model's) and its kind of gated
+        attention."""
+        dense = i < self.num_dense_layers
+        opts = dict(
+            mlp_hidden=self.dense_hidden if dense and self.dense_hidden
+            else self.mlp_hidden,
+            experts=None if dense else self.experts)
+        if self.gated:
+            sliding = self.gated["layer_types"][i] == "sliding_attention"
+            opts["gated"] = dict(
+                head_dim=self.gated["head_dim"],
+                window=self.gated["window"] if sliding else None,
+                rope_theta=self.gated["rope_theta"] if sliding else None)
+        return opts
+
+    @property
+    def page_groups(self) -> Optional[dict]:
+        """What the pool holds for a model of window layers beside global
+        ones (``serving.kv_pool``): the window, and how many layers of each
+        kind share a page of their group. None: one table for every layer."""
+        if not self.gated:
+            return None
+        kinds = self.gated["layer_types"]
+        n_win = kinds.count("sliding_attention")
+        return dict(window=int(self.gated["window"]), window_layers=n_win,
+                    full_layers=len(kinds) - n_win)
+
+    def _paged_layers(self, pages_k, block_tables):
+        """A packed step table of two page groups, by layer: ``[a segment a
+        global layer | a segment a window layer | base]`` (``kv_pool``: Two
+        page groups); every layer's pages lie in the pool's ONE layer."""
+        if not self.gated:
+            return super()._paged_layers(pages_k, block_tables)
+        from ..ops.pallas.paged_attention import (group_segments,
+                                                  window_table_pages)
+
+        groups = self.page_groups
+        ww = window_table_pages(groups["window"], pages_k.shape[-2])
+        wg, at_full, at_win = group_segments(
+            block_tables.shape[1], groups["full_layers"],
+            groups["window_layers"], ww)
+        at_full, at_win = iter(at_full), iter(at_win)
+        base = block_tables[:, -1]
+        out = []
+        for kind in self.gated["layer_types"]:
+            if kind == "sliding_attention":
+                at = next(at_win)
+                out.append(dict(block_tables=block_tables[:, at:at + ww],
+                                layer=0, table_base=base))
+            else:
+                at = next(at_full)
+                out.append(dict(block_tables=block_tables[:, at:at + wg],
+                                layer=0))
+        return out
 
     def _init(self, rng, input_shape):
         n, s = input_shape[:2]
@@ -304,7 +421,10 @@ class Llama(PagedDecoder, Module):
         """Token embeddings (positions enter through RoPE, not here); the
         residual stream starts in float32 where the model keeps it so."""
         x, _ = self.wte.apply({"params": params["wte"], "state": {}}, ids)
-        return x.astype(jnp.float32) if self.residual_f32 else x
+        x = x.astype(jnp.float32) if self.residual_f32 else x
+        if self.embed_scale:
+            x = x * jnp.asarray(self.d_model ** 0.5, x.dtype)
+        return x
 
     @jax.named_scope("ln_f")
     def _ln_f(self, params, x):
@@ -364,6 +484,9 @@ class Llama(PagedDecoder, Module):
         cfg.update(_block_options(self))
         if self.num_pred_heads != 1:
             cfg["num_pred_heads"] = self.num_pred_heads
+        for key in ("embed_scale", "num_dense_layers", "dense_hidden"):
+            if getattr(self, key):
+                cfg[key] = getattr(self, key)
         return cfg
 
 
@@ -450,6 +573,59 @@ def mistral_small4_tiny(**kw):
                      shared=1))
     cfg.update(kw)
     return mistral_small4(**cfg)
+
+
+def trinity_large_ep8(num_layers: int = 5, num_dense_layers: int = 1,
+                      held_experts: int = 32, vocab: int = 25024, **kw):
+    """Trinity-Large-Preview (https://huggingface.co/arcee-ai/
+    Trinity-Large-Preview, config.json, ``model_type: afmoe``) as ONE chip of
+    eight that share each layer serves it: the first 5 of the 60 layers (one
+    of the 6 leading dense layers, then a whole period of the pattern, three
+    sliding-window layers and a global one), every width as published (3,072
+    wide, 48 query heads over 8 KV heads of 128, window 4,096, dense
+    feed-forward 12,288), the router over all 256 experts and 4 a token with
+    sigmoid scores and a selection bias, experts 0 .. ``held_experts`` - 1
+    of width 3,072 held here beside the shared one, rows 0 .. ``vocab`` - 1
+    of the 200,192-token vocabulary, bf16 weights."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    period = ["sliding_attention"] * 3 + ["full_attention"]
+    cfg = dict(
+        vocab_size=vocab, max_len=262144, d_model=3072, num_heads=48,
+        num_kv_heads=8, mlp_hidden=3072, dense_hidden=12288,
+        gated=dict(head_dim=128, window=4096, rope_theta=10000.0,
+                   layer_types=(period * -(-num_layers // 4))[:num_layers]),
+        experts=dict(num_experts=256, held=range(held_experts), top_k=4,
+                     hidden=3072, shared=1, score="sigmoid",
+                     route_scale=2.448))
+    cfg.update(kw)
+    return Llama(num_layers=num_layers, num_dense_layers=num_dense_layers,
+                 tie_embeddings=False, norm_eps=1e-5, residual_f32=True,
+                 sandwich=True, embed_scale=True, **cfg)
+
+
+def trinity_large_tiny(**kw):
+    """Trinity Large's block at test sizes: 5 layers in the published order
+    (a dense sliding layer, then sliding, sliding, full, sliding), 64 wide,
+    4 query heads over 2 KV heads of 32, window 16, dense feed-forward 128,
+    8 of 16 experts of width 32 held, 4 a token, one shared expert.
+    Float32 unless told otherwise (``mistral_small4_tiny`` says why)."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="float32", param="float32",
+                                        compute="float32"))
+    cfg = dict(
+        num_layers=5, vocab=256, max_len=256, d_model=64, num_heads=4,
+        num_kv_heads=2, mlp_hidden=32, dense_hidden=128,
+        gated=dict(head_dim=32, window=16, rope_theta=10000.0,
+                   layer_types=["sliding_attention"] * 3
+                   + ["full_attention", "sliding_attention"]),
+        experts=dict(num_experts=16, held=range(8), top_k=4, hidden=32,
+                     shared=1, score="sigmoid", route_scale=2.448))
+    cfg.update(kw)
+    return trinity_large_ep8(**cfg)
 
 
 def llama_small(**kw):

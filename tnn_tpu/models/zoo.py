@@ -77,6 +77,10 @@ register("evabyte_tiny")(lambda **kw: llama_lib.evabyte_tiny(**kw))
 register("mistral_small4")(lambda **kw: llama_lib.mistral_small4(**kw))
 register("mistral_small4_tiny")(
     lambda **kw: llama_lib.mistral_small4_tiny(**kw))
+register("trinity_large_ep8")(
+    lambda **kw: llama_lib.trinity_large_ep8(**kw))
+register("trinity_large_tiny")(
+    lambda **kw: llama_lib.trinity_large_tiny(**kw))
 register("gpt2_medium")(lambda **kw: gpt2_lib.gpt2_medium(**kw))
 register("gpt2_large")(lambda **kw: gpt2_lib.gpt2_large(**kw))
 register("flash_gpt2_small")(lambda **kw: gpt2_lib.gpt2_small(backend="pallas", **kw))

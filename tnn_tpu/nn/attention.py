@@ -642,8 +642,35 @@ def _positions(offset, b, s):
     return jnp.broadcast_to(offset + jnp.arange(s), (b, s))
 
 
+def _cache_writer(offset):
+    """``upd(buf, new)``: new rows into a (B, H, T, .) cache at ``offset``,
+    a scalar or a (B,) array of each row's own position."""
+    if getattr(offset, "ndim", 0):
+        return lambda buf, new: jax.vmap(
+            lambda b, n, o: jax.lax.dynamic_update_slice_in_dim(
+                b, n, o, axis=1))(buf, new, offset)
+    return lambda buf, new: jax.lax.dynamic_update_slice_in_dim(
+        buf, new, offset, axis=2)
+
+
+class _ProjectAndNorm:
+    """What the latent and the gated attention share: a bias-free product in
+    the policy's dtypes and an RMSNorm with a plain gain (``norm_eps``)."""
+
+    def _mm(self, x, w):
+        from ..ops.pallas.quant_matmul import qmatmul
+
+        return qmatmul(x, self.policy.cast_param(w)).astype(x.dtype)
+
+    def _norm(self, x, gain):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(ms + self.norm_eps)
+                * gain.astype(jnp.float32)).astype(x.dtype)
+
+
 @register_module("latent_attention")
-class LatentAttention(Module):
+class LatentAttention(_ProjectAndNorm, Module):
     """Multi-head latent attention (MLA) over (N, S, D): queries through a
     low-rank bottleneck, and ONE cached row a token, ``[c_kv | k_rope]``
     (``kv_rank + rope_dim`` values, no head axis), that every head's key and
@@ -713,17 +740,6 @@ class LatentAttention(Module):
                 ks[3], (self.kv_rank, h * (self.nope_dim + self.v_dim)), pd),
             "out_kernel": init(ks[4], (h * self.v_dim, d), pd),
         }, {}
-
-    def _mm(self, x, w):
-        from ..ops.pallas.quant_matmul import qmatmul
-
-        return qmatmul(x, self.policy.cast_param(w)).astype(x.dtype)
-
-    def _norm(self, x, gain):
-        xf = x.astype(jnp.float32)
-        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + self.norm_eps)
-                * gain.astype(jnp.float32)).astype(x.dtype)
 
     @jax.named_scope("attn_qkv")
     def _latents(self, params, x, offset):
@@ -797,13 +813,7 @@ class LatentAttention(Module):
         params = variables["params"]
         q, c_kv, k_rope = self._latents(params, x, offset)
         k_new, v_new = self._expand(params, c_kv, k_rope)
-        if getattr(offset, "ndim", 0):
-            upd = lambda buf, new: jax.vmap(  # noqa: E731
-                lambda b, n, o: jax.lax.dynamic_update_slice_in_dim(
-                    b, n, o, axis=1))(buf, new, offset)
-        else:
-            upd = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
-                buf, new, offset, axis=2)
+        upd = _cache_writer(offset)
         with jax.named_scope("kv_write"):
             cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
         out = sdpa(q.transpose(0, 2, 1, 3), cache["k"], cache["v"],
@@ -863,3 +873,164 @@ class LatentAttention(Module):
                 "rope_dim": self.rope_dim, "v_dim": self.v_dim,
                 "rope": self.rope, "norm_eps": self.norm_eps,
                 "backend": self.backend}
+
+
+# -- gated grouped-query attention, sliding or global -----------------------
+
+# key positions a grid step of the paged kernel covers for this attention:
+# its pages hold 128 positions of 128 lanes, and a step that fetched one
+# such page a KV head would spend as long starting as moving (a grid step
+# costs ~0.35 us whatever it holds; ops/pallas/paged_attention.fetch_group)
+GATED_GROUP_POSITIONS = 512
+
+
+@register_module("gated_attention")
+class GatedAttention(_ProjectAndNorm, Module):
+    """Grouped-query attention over (N, S, D) with heads of ``head_dim``
+    (``num_heads * head_dim`` need not be D), an RMSNorm of every query and
+    key head, a sigmoid gate on the output, and ONE of two kinds a layer:
+
+    ``window`` set: each position attends itself and the ``window - 1``
+    before it, and queries and keys are rotated (``rope_theta``, half
+    rotation over the whole head);
+    ``window`` None: every earlier position, and NO positions at all.
+
+    ``[q | k | v | g] = x W_qkvg``; ``q, k = rms_q(q), rms_k(k)`` a head;
+    ``o = softmax(q k^T / sqrt(head_dim)) v * sigmoid(g)``; ``y = o W_o``.
+
+    ``apply_paged`` serves both kinds from the pool's pages through
+    ``ops.pallas.paged_attention`` (its ``window`` and ``table_base``): a
+    window layer's table lists only the pages the row still holds."""
+
+    def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
+                 window: Optional[int] = None,
+                 rope_theta: Optional[float] = None, norm_eps: float = 1e-5,
+                 backend: str = "xla", name=None, policy=None):
+        super().__init__(name=name, policy=policy)
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        if self.num_kv_heads <= 0 or self.num_heads % self.num_kv_heads:
+            raise ValueError(f"num_kv_heads {num_kv_heads} must be a "
+                             f"positive divisor of num_heads {num_heads}")
+        self.window = int(window) if window else None
+        self.rope_theta = float(rope_theta) if rope_theta else None
+        self.norm_eps = float(norm_eps)
+        self.backend = backend
+
+    def _init(self, rng, input_shape):
+        d, dh = input_shape[-1], self.head_dim
+        wide = (2 * self.num_heads + 2 * self.num_kv_heads) * dh
+        init = initializers.get("xavier_uniform")
+        k1, k2 = jax.random.split(rng)
+        pd = self.policy.param_dtype
+        return {"qkvg_kernel": init(k1, (d, wide), pd),
+                "q_norm": jnp.ones((dh,), pd), "k_norm": jnp.ones((dh,), pd),
+                "out_kernel": init(k2, (self.num_heads * dh, d), pd)}, {}
+
+    @jax.named_scope("attn_qkv")
+    def _project(self, params, x, offset):
+        """q (B, H, S, Dh), k, v (B, H_kv, S, Dh), gate (B, S, H * Dh) of a
+        step's tokens, q and k normed and (a window layer's) rotated at
+        their positions."""
+        x = self.policy.cast_in(x)
+        b, s, _ = x.shape
+        h, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
+        q, k, v, gate = jnp.split(
+            self._mm(x, params["qkvg_kernel"]),
+            [h * dh, (h + hkv) * dh, (h + 2 * hkv) * dh], axis=-1)
+        q = self._norm(q.reshape(b, s, h, dh), params["q_norm"])
+        k = self._norm(k.reshape(b, s, hkv, dh), params["k_norm"])
+        q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
+        if self.rope_theta:
+            q = apply_rope(q, offset, self.rope_theta)
+            k = apply_rope(k, offset, self.rope_theta)
+        return q, k, v.reshape(b, s, hkv, dh).transpose(0, 2, 1, 3), gate
+
+    @jax.named_scope("attn_out")
+    def _project_out(self, params, heads, gate):
+        """heads (B, S, H, Dh), gate (B, S, H * Dh) -> (B, S, D)."""
+        b, s = heads.shape[:2]
+        o = heads.reshape(b, s, -1)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+        return self.policy.cast_out(self._mm(o, params["out_kernel"]))
+
+    def _seen(self, q_pos, k_pos):
+        """(.., Sq, Sk) bool: what a query at ``q_pos`` attends."""
+        seen = k_pos[..., None, :] <= q_pos[..., :, None]
+        if self.window:
+            seen &= q_pos[..., :, None] - k_pos[..., None, :] < self.window
+        return seen
+
+    def _apply(self, params, state, x, *, train, rng):
+        q, k, v, gate = self._project(params, x, 0)
+        pos = jnp.arange(x.shape[1])
+        out = sdpa(q, k, v, mask=self._seen(pos, pos)[None, None],
+                   backend="xla")
+        return self._project_out(params, out.transpose(0, 2, 1, 3),
+                                 gate), state
+
+    # -- cached decode (the offline ``generate``) --------------------------
+
+    def init_cache(self, batch: int, max_len: int, d_model: int):
+        z = jnp.zeros((batch, self.num_kv_heads, max_len, self.head_dim),
+                      self.policy.compute_dtype)
+        return {"k": z, "v": z}
+
+    def apply_cached(self, variables, x, cache, offset):
+        params = variables["params"]
+        q, k_new, v_new, gate = self._project(params, x, offset)
+        upd = _cache_writer(offset)
+        with jax.named_scope("kv_write"):
+            cache = {"k": upd(cache["k"], k_new), "v": upd(cache["v"], v_new)}
+        b, s = x.shape[:2]
+        seen = self._seen(_positions(offset, b, s),
+                          jnp.arange(cache["k"].shape[2])[None])
+        out = sdpa(q, cache["k"], cache["v"], mask=seen[:, None],
+                   backend="xla")
+        return self._project_out(params, out.transpose(0, 2, 1, 3),
+                                 gate), cache
+
+    # -- the serving step ----------------------------------------------------
+
+    def apply_paged(self, variables, x, pages_k, pages_v, block_tables,
+                    offsets, layer=0, q_lens=None, table_base=None):
+        """One step against the pool's pages: x (B, Q, D) with ``q_lens[b]``
+        live tokens a row (None: the decode form, every row one token) at
+        positions ``offsets[b] ..``. ``block_tables`` (B, nb) are THIS
+        layer's pages; entry 0 is the row's logical page ``table_base[b]``
+        (None: page 0), so a window layer's rows are written and read
+        relative to the pages they still hold. Returns (out (B, Q, D),
+        pages_k, pages_v)."""
+        from ..ops.pallas import paged_attention as pa
+
+        params = variables["params"]
+        b = x.shape[0]
+        if q_lens is None:
+            q_lens = jnp.ones((b,), jnp.int32)
+        q, k_new, v_new, gate = self._project(params, x, offsets)
+        bs = pages_k.shape[-2]
+        at = offsets if table_base is None else offsets - table_base * bs
+        pages_k = pa.scatter_kv_chunk(
+            pages_k, block_tables, at,
+            k_new.transpose(0, 2, 1, 3).astype(pages_k.dtype), q_lens,
+            layer=layer)
+        pages_v = pa.scatter_kv_chunk(
+            pages_v, block_tables, at,
+            v_new.transpose(0, 2, 1, 3).astype(pages_v.dtype), q_lens,
+            layer=layer)
+        with jax.named_scope("win_attn" if self.window else "full_attn"):
+            out = pa.paged_attention(
+                q.transpose(0, 2, 1, 3), pages_k, pages_v, block_tables,
+                kv_lens=offsets + q_lens, q_lens=q_lens, layer=layer,
+                window=self.window, table_base=table_base,
+                group_positions=GATED_GROUP_POSITIONS)
+        return self._project_out(params, out, gate), pages_k, pages_v
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def _config(self):
+        return {"num_heads": self.num_heads,
+                "num_kv_heads": self.num_kv_heads, "head_dim": self.head_dim,
+                "window": self.window, "rope_theta": self.rope_theta,
+                "norm_eps": self.norm_eps, "backend": self.backend}

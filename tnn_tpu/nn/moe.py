@@ -262,8 +262,12 @@ class ExpertShare(Module):
     hidden``, added once): the share of an expert-parallel deployment that
     divides each layer over several chips.
 
-    The router is whole: float32 logits over all ``num_experts``, softmax,
-    the ``top_k`` largest renormalised to sum 1. Of a step's assignments
+    The router is whole: float32 logits over all ``num_experts``, then
+    ``score`` "softmax": the ``top_k`` largest probabilities renormalised to
+    sum 1; or "sigmoid": scores ``p = sigmoid(logits)``, the ``top_k`` largest
+    of ``p + expert_bias`` (a leaf that only SELECTS: the balancing bias),
+    weighted by ``p`` alone, renormalised. Either times ``route_scale``. Of a
+    step's assignments
     those that fall on a HELD expert are sorted by expert, each expert's
     group padded to the kernel's row tile, and ``ops.pallas.expert_gmm``
     walks the groups: an expert with no token is never fetched, and no token
@@ -271,15 +275,20 @@ class ExpertShare(Module):
     and a tile of padding an expert). What absent experts would add is left
     out; nothing here stands in for the other chips or their exchange.
 
-    Leaves: ``router`` (D, num_experts); ``gate``, ``up``, ``down``
+    Leaves: ``router`` (D, num_experts); with sigmoid scores ``expert_bias``
+    (num_experts,) float32; ``gate``, ``up``, ``down``
     (held, hidden, D): "out x in" for gate and up, "in x out" for down, so a
     block of hidden units is contiguous rows in each; the shared expert's
     ``shared_gate`` / ``shared_up`` (D, shared * hidden) and ``shared_down``
     (shared * hidden, D)."""
 
     def __init__(self, num_experts: int, held, top_k: int, hidden: int,
-                 shared: int = 0, name=None, policy=None):
+                 shared: int = 0, score: str = "softmax",
+                 route_scale: float = 1.0, name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {score!r}: softmax or sigmoid")
+        self.score, self.route_scale = score, float(route_scale)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.held = tuple(int(e) for e in held)
         self.hidden, self.shared = int(hidden), int(shared)
@@ -306,6 +315,9 @@ class ExpertShare(Module):
                   "gate": normal(ks[1], (n, f, d), d),
                   "up": normal(ks[2], (n, f, d), d),
                   "down": normal(ks[3], (n, f, d), f)}
+        if self.score == "sigmoid":
+            params["expert_bias"] = jnp.zeros((self.num_experts,),
+                                              jnp.float32)
         if self.shared:
             fs = self.shared * f
             params.update(shared_gate=normal(ks[4], (d, fs), d),
@@ -316,12 +328,24 @@ class ExpertShare(Module):
     @jax.named_scope("moe_route")
     def route(self, params, x):
         """x (T, D) -> (ids (T, k) int32, weights (T, k) float32): the
-        ``top_k`` of a float32 softmax over ALL experts, renormalised."""
+        ``top_k`` of float32 scores over ALL experts, renormalised (the
+        class says how each ``score`` selects and weighs)."""
         logits = jnp.matmul(x.astype(jnp.float32),
                             params["router"].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
-        return ids.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+        if self.score == "sigmoid":
+            p = jax.nn.sigmoid(logits)
+            _, ids = jax.lax.top_k(
+                p + params["expert_bias"].astype(jnp.float32), self.top_k)
+            w = jnp.take_along_axis(p, ids, axis=-1)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        else:
+            w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                   self.top_k)
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        if self.route_scale != 1.0:
+            w = w * self.route_scale
+        return ids.astype(jnp.int32), w
 
     @jax.named_scope("moe_route")
     def _sort(self, ids, live, tile):
@@ -414,6 +438,11 @@ class ExpertShare(Module):
         return tuple(input_shape)
 
     def _config(self):
-        return {"num_experts": self.num_experts, "held": list(self.held),
-                "top_k": self.top_k, "hidden": self.hidden,
-                "shared": self.shared}
+        cfg = {"num_experts": self.num_experts, "held": list(self.held),
+               "top_k": self.top_k, "hidden": self.hidden,
+               "shared": self.shared}
+        if self.score != "softmax":
+            cfg["score"] = self.score
+        if self.route_scale != 1.0:
+            cfg["route_scale"] = self.route_scale
+        return cfg

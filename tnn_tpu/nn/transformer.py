@@ -47,12 +47,20 @@ class PagedDecoder:
         pages through jit for in-place pool updates. ``q_lens`` None is the
         decode form (Q == 1)."""
         x = self._embed(params, toks, offsets)
+        where = self._paged_layers(pages_k, block_tables)
         for i, block in enumerate(self.blocks):
             with jax.named_scope(f"h{i}"):
                 x, pages_k, pages_v = block.apply_paged(
-                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
-                    offsets, layer=i, q_lens=q_lens)
+                    params[f"h{i}"], x, pages_k, pages_v, offsets=offsets,
+                    q_lens=q_lens, **where[i])
         return self._head(params, self._ln_f(params, x)), pages_k, pages_v
+
+    def _paged_layers(self, pages_k, block_tables):
+        """Per block, the keywords of its ``apply_paged`` that say where its
+        pages are: the step's one table and the block's own layer of the
+        pool, unless the model keeps its layers' pages otherwise."""
+        return [dict(block_tables=block_tables, layer=i)
+                for i in range(len(self.blocks))]
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):

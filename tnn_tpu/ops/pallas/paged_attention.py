@@ -246,7 +246,7 @@ def _over_packed_rows(attend, q, pages, stats):
 
 def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
                  scale: float, bs: int, g: int, qw: int, pages: int,
-                 quant: bool, stats: bool):
+                 quant: bool, stats: bool, window: Optional[int] = None):
     """One grid step: ``pages`` consecutive table entries of row b, every
     head of the step's head block, ONE online-softmax update per head over
     the group's ``pages * bs`` positions.
@@ -256,7 +256,10 @@ def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
     ``stats`` the m and l outputs (the running max and normalizer a
     sequence-parallel shard hands ``ops.softmax_merge.merge_psum``), then
     the m / l / acc scratch, all ``(heads, Q*g, .)``. bf16 and int8 pages
-    differ ONLY in how a page's K/V reaches the MXU (``load``)."""
+    differ ONLY in how a page's K/V reaches the MXU (``load``). With
+    ``window`` a query sees its own position and the ``window - 1`` before
+    it: one more comparison in the mask (positions here are relative to the
+    first page the launch walks, ``_launch``)."""
     del layer_ref  # consumed by the index maps, not the body
     per = 4 if quant else 2
     kv, refs = refs[:per * pages], refs[per * pages:]
@@ -319,7 +322,12 @@ def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
         if g > 1:
             trow = jax.lax.div(trow, jnp.int32(g))
         limit = jnp.where(trow < q_live, kv_len - q_live + trow, -1)
-        mask = (kpos <= limit)[None]                    # (1, Q*g, t)
+        mask = kpos <= limit
+        if window is not None:
+            # the page that straddles a query's lower bound is fetched and
+            # masked; pages wholly before the FIRST query's are never walked
+            mask = mask & (kpos > limit - window)
+        mask = mask[None]                               # (1, Q*g, t)
         s = jnp.where(mask, s, _NEG_INF)
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -341,21 +349,28 @@ def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
             l_ref[...] = l
 
 
-def _fetch_table(tables, lens, bs, pages):
+def _fetch_table(tables, lens, bs, pages, shift=None, width=None):
     """The table the kernel walks, ``pages`` entries a grid step: padded to
     whole groups, and every dead entry replaced by the one its DMA should
     repeat. Dead trailing groups repeat the row's last live group and dead
     pages inside it the row's last live page: a block index that repeats lets
-    the pipeline elide the DMA. ``max(len, 1)`` keeps fully-dead rows at
+    the pipeline elide the DMA. With ``shift`` (B,) the walk starts at that
+    entry of each row's table (``lens`` then counts from there) and is
+    ``width`` entries long: a windowed row's pages wholly before its lower
+    bound are not walked. ``max(len, 1)`` keeps fully-dead rows at
     entry 0. Live entries, ``-1`` holes among them, stay as they are.
     (Computed here, once a step program, and not by every page slot's index
     map at every grid step: the layers' equal computations merge into one.)"""
     nb = tables.shape[1]
-    width = -(-nb // pages) * pages
-    last = jnp.clip(jax.lax.div(lens + (bs - 1), bs), 1, nb)[:, None] - 1
+    walk = nb if width is None else min(width, nb)
+    width = -(-walk // pages) * pages
+    last = jnp.clip(jax.lax.div(lens + (bs - 1), bs), 1, walk)[:, None] - 1
     e = np.arange(width, dtype=np.int32)[None, :]
     e = jnp.minimum(e // pages, jax.lax.div(last, pages)) * pages + e % pages
-    return jnp.take_along_axis(tables, jnp.minimum(e, last), axis=1)
+    e = jnp.minimum(e, last)
+    if shift is not None:
+        e = jnp.minimum(e + shift[:, None], nb - 1)
+    return jnp.take_along_axis(tables, e, axis=1)
 
 
 # An INLINED jit: a step program calls this once a layer with the same shapes
@@ -363,9 +378,11 @@ def _fetch_table(tables, lens, bs, pages):
 # the body) is traced once a program and not once a layer, and the equal
 # ``pallas_call`` equations are lowered once; every call keeps its own scope.
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("scale", "interpret", "stats"))
+                   static_argnames=("scale", "interpret", "stats", "window",
+                                    "positions"))
 def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
-                            q_lens, layer, *, scale, interpret, stats):
+                            q_lens, layer, table_base=None, *, scale,
+                            interpret, stats, window=None, positions=None):
     """The kernel's launch for queries ``(B, Q, H, Dh)`` over pages of
     ``p`` heads a row (the lane juggling is in here, so that it too is
     traced once a program)."""
@@ -373,27 +390,78 @@ def _paged_attention_pallas(q, pages_k, pages_v, block_tables, kv_lens,
         functools.partial(_launch, pages_k=pages_k, pages_v=pages_v,
                           block_tables=block_tables, kv_lens=kv_lens,
                           q_lens=q_lens, layer=layer, scale=scale,
-                          interpret=interpret, stats=stats),
+                          interpret=interpret, stats=stats, window=window,
+                          table_base=table_base, positions=positions),
         q, pages_k, stats)
 
 
+def window_table_pages(window, bs):
+    """Entries of a window layer's table: the pages a row holds at most,
+    the window's own and two more (the page its lower bound straddles and the
+    one being written: room for ``bs + 2`` new positions before the pages
+    behind the window go back to the pool). The pool, the scheduler's grants
+    and the model's split of a packed table all ask here."""
+    if window % bs:
+        raise ValueError(f"a sliding window of {window} is whole pages of "
+                         f"{bs} positions")
+    return window // bs + 2
+
+
+def group_segments(width, n_full, n_win, win_pages):
+    """Where each layer's segment starts in a packed step table of two page
+    groups, ``width`` entries wide: ``n_full`` global segments of ``full_pages``
+    entries, ``n_win`` window segments of ``win_pages``, then the window
+    tables' base in the last entry. Returns (full_pages, global starts,
+    window starts): ``step_build._fill_row`` packs by it, the model's
+    ``_paged_layers`` splits by it, ``PagedKVPool.check_step_writes`` reads
+    by it."""
+    full_pages = (width - 1 - n_win * win_pages) // n_full
+    return (full_pages, [j * full_pages for j in range(n_full)],
+            [n_full * full_pages + j * win_pages for j in range(n_win)])
+
+
+def window_walk(window, qw, bs, nb):
+    """Table entries a launch walks for a row of ``qw`` queries under a
+    ``window``: the pages that positions ``first query - window + 1 .. last
+    query`` can lie in, no more than the table has."""
+    return min(nb, (window + qw - 2) // bs + 2)
+
+
 def _launch(q, *, pages_k, pages_v, block_tables, kv_lens, q_lens, layer,
-            scale, interpret, stats):
+            scale, interpret, stats, window=None, table_base=None,
+            positions=None):
     quant = isinstance(pages_k, QuantPages)
     b, qw, h, dh = q.shape
     data = pages_k.data if quant else pages_k
     _, _, hkv, bs, _ = data.shape
     g = h // hkv
+    walk = block_tables.shape[1] if window is None \
+        else window_walk(window, qw, bs, block_tables.shape[1])
     pages, heads = fetch_group(bs=bs, dh=dh, hkv=hkv, qg=qw * g,
-                               page_dtype=data.dtype,
-                               nb=block_tables.shape[1])
+                               page_dtype=data.dtype, nb=walk,
+                               **({"positions": positions} if positions
+                                  else {}))
     # head-major query rows: (B, H_kv, Q*g, Dh), so a grid step's block is
     # (heads, Q*g, Dh), one 2-D tile a head (no in-kernel reshape)
     qg = _to_head_major(q, hkv)
     lens = kv_lens.astype(jnp.int32)
     qlens = q_lens.astype(jnp.int32)
     layer_arr = jnp.reshape(layer, (1,))
-    tables = _fetch_table(block_tables.astype(jnp.int32), lens, bs, pages)
+    shift = None
+    if window is not None or table_base is not None:
+        # the walk starts at the page of the first query's lower bound
+        # (entry 0 of the table is page ``table_base``), and every position
+        # the kernel sees counts from that page's first: to the body a
+        # windowed row is a short row
+        base = jnp.zeros_like(lens) if table_base is None \
+            else table_base.astype(jnp.int32)
+        first = jnp.zeros_like(lens) if window is None else jax.lax.div(
+            jnp.maximum(lens - qlens - (window - 1), 0), bs)
+        first = jnp.maximum(first, base)
+        shift = first - base
+        lens = jnp.maximum(lens - first * bs, 0)
+    tables = _fetch_table(block_tables.astype(jnp.int32), lens, bs, pages,
+                          shift, walk)
     nb = tables.shape[1]
 
     def kv_index(i):
@@ -443,10 +511,11 @@ def _launch(q, *, pages_k, pages_v, block_tables, kv_lens, q_lens, layer,
     )
     out = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, bs=bs, g=g, qw=qw,
-                          pages=pages, quant=quant, stats=stats),
+                          pages=pages, quant=quant, stats=stats,
+                          window=window),
         # the name the device profile shows; the variants are other kernels
-        name="tnn_paged_attention" + ("_int8" if quant else "")
-        + ("_stats" if stats else ""),
+        name="tnn_paged_attention" + ("_win" if window else "")
+        + ("_int8" if quant else "") + ("_stats" if stats else ""),
         grid_spec=grid_spec,
         out_shape=out_shape,
         # scratch carries only along the innermost (group) sweep
@@ -536,9 +605,11 @@ def _paged_attention_xla(q, pages_k, pages_v, block_tables, kv_lens, layer,
 
 
 def _paged_attention_xla_mq(q, pages_k, pages_v, block_tables, kv_lens,
-                            q_lens, layer, scale, stats=False):
+                            q_lens, layer, scale, stats=False, window=None,
+                            table_base=None):
     """Multi-token-query reference: same ragged causal mask as the kernel
-    (and the same dead -1 table-entry masking).
+    (and the same dead -1 table-entry masking; ``window`` and ``table_base``
+    as ``paged_attention`` says).
 
     Works in the kernel's head-major row layout (B, H_kv, Q*g, ·): that
     keeps both contractions in the batched-matmul form of the decode
@@ -556,9 +627,13 @@ def _paged_attention_xla_mq(q, pages_k, pages_v, block_tables, kv_lens,
                    preferred_element_type=jnp.float32) * scale
     start = (kv_lens - q_lens)[:, None]                   # (B, 1)
     tpos = jnp.repeat(jnp.arange(qw), g)[None, :]         # (1, Q*g) token/row
-    kpos = jnp.arange(t)
-    live = (kpos[None, None, :] <= (start + tpos)[:, :, None]) \
+    kpos = jnp.arange(t)[None, None, :]                   # absolute positions
+    if table_base is not None:
+        kpos = kpos + (table_base * bs)[:, None, None]
+    live = (kpos <= (start + tpos)[:, :, None]) \
         & (tpos < q_lens[:, None])[:, :, None]            # (B, Q*g, T)
+    if window is not None:
+        live = live & (kpos > (start + tpos)[:, :, None] - window)
     live = live & jnp.repeat(block_tables >= 0, bs, axis=1)[:, None, :]
     s = jnp.where(live[:, None], s, _NEG_INF)
     if stats:
@@ -583,7 +658,8 @@ def _paged_attention_xla_mq(q, pages_k, pages_v, block_tables, kv_lens,
 
 def paged_attention_reference(q, pages_k, pages_v, block_tables, kv_lens, *,
                               q_lens=None, layer=0,
-                              scale: Optional[float] = None):
+                              scale: Optional[float] = None, window=None,
+                              table_base=None):
     """XLA-lax reference: gather the tables contiguous, masked softmax.
 
     Same signature/semantics as ``paged_attention`` — the parity oracle for
@@ -591,7 +667,8 @@ def paged_attention_reference(q, pages_k, pages_v, block_tables, kv_lens, *,
     what the kernel exists to avoid on TPU)."""
     return paged_attention(q, pages_k, pages_v, block_tables, kv_lens,
                            q_lens=q_lens, layer=layer, scale=scale,
-                           backend="xla")
+                           backend="xla", window=window,
+                           table_base=table_base)
 
 
 def _check_args(q, pages_k, pages_v, block_tables, kv_lens, q_lens, scale):
@@ -650,7 +727,9 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
                     q_lens=None, layer=0, scale: Optional[float] = None,
                     backend: str = "auto",
                     interpret: Optional[bool] = None,
-                    return_stats: bool = False):
+                    return_stats: bool = False,
+                    window: Optional[int] = None, table_base=None,
+                    group_positions: Optional[int] = None):
     """Ragged attention for the current step's query rows over paged KV.
 
     q : (B, H, Dh) — decode form, one token per sequence — or (B, Q, H, Dh)
@@ -682,6 +761,18 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
     GQA: H % H_kv == 0; each kv head's page is fetched once and attended by
     its whole query-head group. Returns q's shape.
 
+    window : a SLIDING window (static): each query attends its own position
+        and the ``window - 1`` before it. Pages wholly before the row's first
+        query's lower bound are neither fetched nor walked (the grid is as
+        long as a window's pages, not as the table), the page that straddles
+        a bound is masked. The kernel is then named ``tnn_paged_attention_win``.
+    table_base : (B,) int32 — the logical page that entry 0 of each row's
+        table holds (default 0): a windowed row's table lists only the pages
+        it still has, the ones behind its window given back to the pool.
+        ``kv_lens`` stay absolute positions.
+    group_positions : key positions a grid step covers (``fetch_group``'s
+        ``positions``; default about 128).
+
     Block-table entries may be NEGATIVE: a -1 marks a dead hole (a page
     another sequence-parallel shard owns) whose positions are skipped as if
     masked. With ``return_stats`` the per-row online-softmax state rides
@@ -701,19 +792,25 @@ def paged_attention(q, pages_k, pages_v, block_tables, kv_lens, *,
             interpret = interpret_default()
         out = _paged_attention_pallas(
             q, pages_k, pages_v, block_tables, kv_lens, q_lens,
-            jnp.asarray(layer, jnp.int32), scale=scale, interpret=interpret,
-            stats=return_stats)
+            jnp.asarray(layer, jnp.int32), table_base, scale=scale,
+            interpret=interpret, stats=return_stats, window=window,
+            positions=group_positions)
         if was_3d:
             out = jax.tree_util.tree_map(lambda x: x[:, 0], out)
         return out
     kw = dict(pages_k=pages_k, pages_v=pages_v, block_tables=block_tables,
               kv_lens=kv_lens, layer=layer, scale=scale, stats=return_stats)
-    if was_3d:
+    bounded = window is not None or table_base is not None
+    if was_3d and not bounded:
         attend, q = functools.partial(_paged_attention_xla, **kw), q[:, 0]
     else:
         attend = functools.partial(_paged_attention_xla_mq, q_lens=q_lens,
+                                   window=window, table_base=table_base,
                                    **kw)
-    return _over_packed_rows(attend, q, pages_k, return_stats)
+    out = _over_packed_rows(attend, q, pages_k, return_stats)
+    if was_3d and bounded:
+        out = jax.tree_util.tree_map(lambda x: x[:, 0], out)
+    return out
 
 
 def scatter_kv_rows(pages, block_tables, offsets, rows, *, layer=None):

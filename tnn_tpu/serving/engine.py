@@ -140,15 +140,31 @@ def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
     these options that is on, or None. A model whose state is not "K and V
     blocks of heads for every position" runs on the normal path over pages
     of its own kind: EVA (an exact window beside chunk summaries: two kinds
-    of page) and latent attention (one row a token that is key and value at
-    once, no head axis: latent pages). What assumes K/V blocks of heads is
-    refused at start-up, by the engine and by ``tnn-serve`` before it makes
-    any weights."""
+    of page), latent attention (one row a token that is key and value at
+    once, no head axis: latent pages) and sliding-window layers beside
+    global layers (two groups of page, the window group's given back from
+    behind the window). What assumes K/V blocks of heads, one table a
+    request, is refused at start-up, by the engine and by ``tnn-serve``
+    before it makes any weights."""
     window = getattr(model, "window", None)
     latent = getattr(model, "latent", None)
-    if not window and not latent:
+    groups = getattr(model, "page_groups", None)
+    if not window and not latent and not groups:
         return None
-    if window:
+    if groups:
+        state = (f"sliding-window layers (a window of {groups['window']}) "
+                 "beside global layers over two groups of page, not one "
+                 "table of K/V blocks for every layer")
+        whys = ("a cached block is one layer's page of one group, and the "
+                "window layers give theirs back from behind the window",
+                "a rejected draft may have given window pages back, and a "
+                "release does not roll back",
+                "the split of a packed table by layer kind is not "
+                "head-sharded",
+                "a window table's base and its walk are not block-sharded",
+                "the int8 kernel has not been held against the reference "
+                "under a window")
+    elif window:
         state = (f"an exact window of {window} positions beside chunk "
                  "summaries, not K/V blocks of every position")
         whys = ("a cached block would have to carry the summaries of "
@@ -461,7 +477,11 @@ class InferenceEngine:
         self.params = params
         # a latent model's "head" is its one cached row (whole lanes)
         latent_row = getattr(model, "latent_row", None)
-        self.head_dim = latent_row or model.d_model // model.num_heads
+        self.head_dim = latent_row or getattr(model, "head_dim", None) \
+            or model.d_model // model.num_heads
+        # window layers beside global layers: ONE layer of pages, each a
+        # layer's of one of two groups (kv_pool: Two page groups)
+        groups = getattr(model, "page_groups", None)
         if self._tp is not None:
             page_sharding = self._tp.page_sharding
         elif self._sp is not None:
@@ -471,12 +491,13 @@ class InferenceEngine:
         else:
             page_sharding = None
         self.pool = PagedKVPool(
-            num_layers=model.num_layers, num_kv_heads=model.num_kv_heads,
+            num_layers=1 if groups else model.num_layers,
+            num_kv_heads=model.num_kv_heads,
             head_dim=self.head_dim, num_blocks=num_blocks,
             block_size=block_size, dtype=model.policy.compute_dtype,
             kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp,
             window=window, chunk=getattr(model, "chunk", None),
-            latent=bool(latent_row))
+            latent=bool(latent_row), groups=groups)
         self.pool.fault_plan = faults
         # static gauge extras spliced into every _health_gauges refresh:
         # lets operators spot a misconfigured replica from /healthz alone
@@ -768,10 +789,12 @@ class InferenceEngine:
         request's live table (only running requests hold blocks). Raises
         ValueError on any violation — the chaos suite's leak detector."""
         rows = [r for r in self.scheduler.running
-                if r.block_table or r.summary_table]
+                if r.block_table or r.summary_table or r.window_table]
         self.pool.check_invariants([r.block_table for r in rows],
                                    [r.cache_len for r in rows],
-                                   [r.summary_table for r in rows])
+                                   [r.summary_table for r in rows],
+                                   [r.window_table for r in rows],
+                                   [r.window_base for r in rows])
         if self.kv_tier is not None:
             self.kv_tier.check_invariants()
 
@@ -802,45 +825,74 @@ class InferenceEngine:
 
     def _free_blocks(self, req: Request) -> None:
         """Give back everything a request holds: its exact pages and, in a
-        windowed pool, its summary pages."""
-        if req.block_table or req.summary_table:
-            self.pool.free(req.block_table + req.summary_table)
+        windowed pool, its summary pages; both groups' of a pool of two."""
+        held = req.block_table + req.summary_table + req.window_table
+        if held:
+            self.pool.free(held)
             req.block_table, req.summary_table = [], []
+            req.window_table, req.window_base = [], 0
+
+    # a request's tables, in the order ``_grow_need`` counts and ``_extend``
+    # allocates them: every position's pages (a windowed pool: the current
+    # window's), a windowed pool's summaries, a pool of two groups' window
+    # pages
+    _TABLES = ("block_table", "summary_table", "window_table")
 
     def _grow_need(self, req: Request, cache_len: int, new_tokens: int):
-        """(exact, summary) blocks ``req`` lacks to write ``new_tokens``
-        positions from ``cache_len``."""
+        """(exact, summary, window) blocks ``req`` lacks to write
+        ``new_tokens`` positions from ``cache_len``."""
         need_e, need_s = self.pool.table_need(cache_len, new_tokens)
+        need_w = self.pool.window_need(cache_len, new_tokens,
+                                       req.window_base)
         return (max(0, need_e - len(req.block_table)),
-                max(0, need_s - len(req.summary_table)))
+                max(0, need_s - len(req.summary_table)),
+                max(0, need_w - len(req.window_table)))
 
     def _extend(self, req: Request, grow) -> List[Any]:
-        """Allocate ``grow`` = (exact, summary) blocks onto the request's
-        tables; returns what a roll-back undoes: (req, table's attribute,
-        length before, the new blocks)."""
+        """Allocate ``grow`` = (exact, summary, window) blocks onto the
+        request's tables; returns what a roll-back undoes: (req, table's
+        attribute, the new blocks)."""
         done = []
-        for attr, n in zip(("block_table", "summary_table"), grow):
+        for attr, n in zip(self._TABLES, grow):
             if n:
                 table = getattr(req, attr)
                 ext = self.pool.alloc(n, start=len(table))
-                done.append((req, attr, len(table), ext))
+                done.append((req, attr, ext))
                 table.extend(ext)
         return done
 
     def _unextend(self, done, *, only_intact: bool = False) -> None:
-        for req, attr, orig, ext in reversed(done):
+        """Undo extensions, youngest first: each is its table's TAIL (a
+        window table may meanwhile have given pages back at its head)."""
+        for req, attr, ext in reversed(done):
             table = getattr(req, attr)
-            if only_intact and table[orig:orig + len(ext)] != ext:
+            if table[len(table) - len(ext):] != ext:
                 # a terminated/preempted row already freed its whole table
                 # (extension included); only intact tables still own it
-                continue
+                if only_intact:
+                    continue
+                raise RuntimeError(f"{attr} of request {req.rid} no longer "
+                                   "ends in the extension to roll back")
             self.pool.free(ext)
-            del table[orig:]
+            del table[len(table) - len(ext):]
 
     def _end_window(self, req: Request) -> None:
         """A windowed request whose committed length reached a window's end
         gives its exact pages back: the next window starts with none, and
-        reads this one through its summaries only."""
+        reads this one through its summaries only. In a pool of two page
+        groups, the window layers' pages that now lie wholly behind the
+        window go back."""
+        if self.pool.sliding and req.window_table:
+            drop = self.pool.release_behind(req.cache_len) - req.window_base
+            if drop > 0:
+                n = drop * self.pool.window_layers
+                self.pool.free(req.window_table[:n])
+                del req.window_table[:n]
+                req.window_base += drop
+                self.metrics.observe_window_release(n)
+                self.tracer.instant("serve.win_release", trace=req.trace_id,
+                                    rid=req.rid, step=self.step_seq,
+                                    at=req.cache_len, pages=n)
         if self.pool.window and req.block_table \
                 and req.cache_len % self.pool.window == 0:
             self.pool.free(req.block_table)
@@ -856,8 +908,9 @@ class InferenceEngine:
         from the same shapes (a TP shard's pool holds ``1 / tp`` of the
         heads; the kernel's "head" is a page row, ``kv_lane_pack`` KV heads
         side by side, attended by that many query groups). None where that
-        kernel is not the step's attention (a windowed model)."""
-        if self.pool.window:
+        kernel is not the step's attention (a windowed model), or runs over
+        two groups of page with a walk of its own a kind of layer."""
+        if self.pool.window or self.pool.sliding:
             return None
         group = self._attn_groups.get(qw)
         if group is None:
@@ -890,6 +943,11 @@ class InferenceEngine:
             live = -(-np.asarray(ends[:len(rows)]) // pool.block_size)
             live = live[live > 0]
             self.metrics.observe_attn_fetch(live / (-(-live // pages) * pages))
+        if pool.sliding:
+            held = sum(len(r.window_table) for r in self.scheduler.running)
+            self.metrics.observe_window_step(
+                [min(int(e), pool.sliding) / pool.sliding
+                 for _, e in zip(rows, ends)], held / pool.capacity)
         if not pool.window:
             return
         self.metrics.observe_eva_step(
@@ -1096,6 +1154,13 @@ class InferenceEngine:
         if group is not None and self.pool.latent:
             # ONE page array, read once for keys and values
             attrs = dict(latent_pages=group[0])
+        if qw is not None and self.pool.sliding:
+            # blocks held by kind: every running row's global pages and the
+            # window pages it has not yet given back
+            rows = self.scheduler.running
+            attrs = dict(pages_by_kind="full:%d,window:%d" % (
+                sum(len(r.block_table) for r in rows),
+                sum(len(r.window_table) for r in rows)))
         experts = getattr(self.model, "experts", None)
         if qw is not None and experts:
             attrs["experts_held"] = len(experts["held"])
@@ -1390,7 +1455,8 @@ class InferenceEngine:
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            ahead=j, sum_at=self.pool.exact_width)
+            ahead=j, sum_at=self.pool.exact_width,
+            kinds=self.pool.kinds)
         self._check_step_writes(step, step.offsets)
         b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
         label = "decode_paged"
@@ -2175,7 +2241,8 @@ class InferenceEngine:
             rows, len(dec), drafts, takes,
             b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, spec_on=spec_on,
-            kv_key=self._kv_key, sum_at=self.pool.exact_width)
+            kv_key=self._kv_key, sum_at=self.pool.exact_width,
+            kinds=self.pool.kinds)
         self._check_step_writes(step, step.starts, step.q_lens)
         b, qw, poison = step.b, step.qw, step.poison
         if self.faults is not None:
@@ -2516,7 +2583,8 @@ class InferenceEngine:
         step = step_build.pack_decode(
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            sum_at=self.pool.exact_width)
+            sum_at=self.pool.exact_width,
+            kinds=self.pool.kinds)
         self._check_step_writes(step, step.offsets)
         b, nb, key = step.b, step.nb, step.key
         poison = step.poison

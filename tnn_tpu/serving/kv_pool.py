@@ -79,6 +79,30 @@ position exact, no summaries": GPT-2's, with the exact table the whole
 table. ``table_need`` / ``lifetime_blocks`` answer for both kinds, and
 ``check_invariants`` / ``check_step_writes`` know both.
 
+Two page groups
+---------------
+A model of sliding-window layers beside global layers (``models.llama``'s
+``gated``) keeps two groups of page, from the SAME array, ONE allocator and
+ONE occupancy. With ``groups`` set (``window``, ``full_layers``,
+``window_layers``) the array has one layer, ``(1, N, H_kv / p, bs, p * Dh)``,
+and a block is one LAYER's page of one group; a request has two tables:
+
+- its *global* table (``Request.block_table``): ``full_layers`` blocks for
+  every page of its context, entry ``i * full_layers + j`` global layer
+  ``j``'s page of positions ``i * bs ..``. It only grows;
+- its *window* table (``Request.window_table``): ``window_layers`` blocks for
+  every page it still holds, entry 0 the logical page
+  ``Request.window_base``. A page wholly behind ``position - window`` is
+  given back (``release_behind``), so a row never holds more than
+  ``win_pages`` = ``window / bs + 2`` of them however long it grows.
+
+With one table for all five layers a 37 k context would hold 290 pages in
+each of them; here four of the five hold 34. A step's packed table is a
+segment a global layer, a segment of ``win_pages`` a window layer, then
+``window_base`` (``table_width``; ``step_build._fill_row`` packs it,
+``Llama._paged_layers`` splits it). Admission plans both groups to the
+request's last token (``lifetime_blocks``), as the windowed pool above.
+
 Latent pages
 ------------
 A model with latent (MLA) attention caches ONE row a token a layer, ``[c_kv |
@@ -101,7 +125,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.pallas.paged_attention import QuantPages, lane_pack
+from ..ops.pallas.paged_attention import (QuantPages, group_segments,
+                                           lane_pack, window_table_pages)
 
 
 class PoolExhausted(RuntimeError):
@@ -115,7 +140,22 @@ class PagedKVPool:
                  num_blocks: int, block_size: int = 16, dtype=jnp.float32,
                  kv_dtype: str = "f32", sharding=None, sp: int = 1,
                  window: Optional[int] = None, chunk: Optional[int] = None,
-                 latent: bool = False):
+                 latent: bool = False, groups: Optional[dict] = None):
+        if groups and (window is not None or latent or sp > 1
+                       or kv_dtype != "f32" or num_layers != 1
+                       or min(groups["full_layers"],
+                              groups["window_layers"]) < 1):
+            raise ValueError(
+                "a pool of two page groups (window layers beside global "
+                "ones) is ONE layer of pages, each a layer's of one group, "
+                "and is neither EVA-windowed, latent, block-sharded (sp) "
+                "nor int8")
+        # sliding-window layers beside global layers: see "Two page groups"
+        self.sliding = int(groups["window"]) if groups else None
+        self.full_layers = int(groups["full_layers"]) if groups else 0
+        self.window_layers = int(groups["window_layers"]) if groups else 0
+        self.win_pages = window_table_pages(self.sliding, block_size) \
+            if groups else 0
         if latent and (window is not None or sp > 1 or kv_dtype != "f32"
                        or num_kv_heads != 1 or head_dim % 128):
             raise ValueError(
@@ -302,7 +342,8 @@ class PagedKVPool:
         return getattr(leaf, "is_deleted", lambda: False)()
 
     def blocks_for(self, num_tokens: int) -> int:
-        """Blocks needed to hold ``num_tokens`` cache positions."""
+        """Blocks needed to hold ``num_tokens`` cache positions (in a pool
+        of two page groups: PAGES, each a block a layer of its group)."""
         return max(1, math.ceil(num_tokens / self.block_size))
 
     # -- two kinds of page: what a request needs of each ------------------
@@ -317,7 +358,8 @@ class PagedKVPool:
         ``cache_len`` resident positions and write ``new_tokens`` more (which
         lie in one window: ``room_in_window``)."""
         if self.window is None:
-            return self.blocks_for(cache_len + new_tokens), 0
+            return (max(self.full_layers, 1)
+                    * self.blocks_for(cache_len + new_tokens), 0)
         end = cache_len + new_tokens
         in_window = end - (cache_len // self.window) * self.window
         return (math.ceil(in_window / self.block_size),
@@ -326,14 +368,50 @@ class PagedKVPool:
     def room_in_window(self, cache_len: int) -> int:
         """Tokens a step may write from ``cache_len`` before the window
         ends (a step never crosses a window's end: the pages of the old
-        window are released between two steps)."""
+        window are released between two steps); in a pool of two page
+        groups, before the window table is full (pages are released between
+        two steps there too: at least ``bs + 2``)."""
+        if self.sliding:
+            return (self.release_behind(cache_len) + self.win_pages) \
+                * self.block_size - cache_len
         if self.window is None:
             return 1 << 62
         return self.window - cache_len % self.window
 
+    # -- two page groups: the window group's pages come and go ------------
+
+    def release_behind(self, cache_len: int) -> int:
+        """The first logical page a row of ``cache_len`` resident positions
+        still needs in its window layers: the next query, at ``cache_len``,
+        sees back to ``cache_len - window + 1``; pages wholly before that
+        are no step's to read again."""
+        return max(0, cache_len - self.sliding + 1) // self.block_size
+
+    def window_need(self, cache_len: int, new_tokens: int, base: int) -> int:
+        """Entries of a window table whose entry 0 is logical page ``base``
+        that hold ``cache_len`` positions and ``new_tokens`` more."""
+        if not self.sliding or cache_len + new_tokens <= 0:
+            return 0
+        last = (cache_len + new_tokens - 1) // self.block_size
+        return self.window_layers * (last - base + 1)
+
+    @property
+    def kinds(self):
+        """How ``step_build._fill_row`` lays a request's two tables into a
+        packed row: (global layers, window layers, entries a window layer),
+        ``paged_attention.group_segments``' arguments after the row's width.
+        None: one group."""
+        if not self.sliding:
+            return None
+        return (self.full_layers, self.window_layers, self.win_pages)
+
     def lifetime_blocks(self, total_tokens: int) -> int:
         """The most blocks a request of ``total_tokens`` positions (prompt
         and output) ever holds: will it fit to its last token."""
+        if self.sliding:
+            pages = self.blocks_for(total_tokens)
+            return (self.full_layers * pages
+                    + self.window_layers * min(pages, self.win_pages))
         if self.window is None:
             return self.blocks_for(total_tokens)
         return (math.ceil(min(total_tokens, self.window) / self.block_size)
@@ -343,13 +421,16 @@ class PagedKVPool:
         """Blocks an admission is planned against: what the request's first
         step needs, or, in a windowed pool (whose pages come and go, so that
         "fits now" says nothing), what it needs to its last token."""
-        if self.window is None:
+        if self.window is None and not self.sliding:
             return self.blocks_for(first_tokens)
         return self.lifetime_blocks(total_tokens)
 
     def table_width(self, total_tokens: int) -> int:
         """Entries of the packed step table a request of ``total_tokens``
         positions needs: the exact segment whole, then its summaries."""
+        if self.sliding:    # a segment a layer, then the window's base
+            return (self.full_layers * self.blocks_for(total_tokens)
+                    + self.window_layers * self.win_pages + 1)
         if self.window is None:
             return self.blocks_for(total_tokens)
         return self.exact_width + max(1, math.ceil(
@@ -358,6 +439,9 @@ class PagedKVPool:
     @property
     def token_capacity(self) -> int:
         """The longest request the pool could hold alone."""
+        if self.sliding:
+            rest = self.capacity - self.window_layers * self.win_pages
+            return max(0, rest) // self.full_layers * self.block_size
         if self.window is None:
             return self.capacity * self.block_size
         rest = self.capacity - self.exact_width
@@ -567,7 +651,9 @@ class PagedKVPool:
             self,
             block_tables: Optional[Iterable[Sequence[int]]] = None,
             seq_lens: Optional[Sequence[int]] = None,
-            summary_tables: Optional[Iterable[Sequence[int]]] = None) -> None:
+            summary_tables: Optional[Iterable[Sequence[int]]] = None,
+            window_tables: Optional[Iterable[Sequence[int]]] = None,
+            window_bases: Optional[Sequence[int]] = None) -> None:
         """Verify the pool's bookkeeping; raises ValueError on violation.
 
         Always checked: free + allocated + evictable == capacity (a strict
@@ -599,6 +685,12 @@ class PagedKVPool:
         table covers the current window's resident positions and no more
         than the next token's, the summary table one row a finished chunk;
         and the full accounting counts the blocks of both.
+
+        With ``window_tables`` and ``window_bases`` (parallel too: a pool of
+        two page groups) a row's global table holds ``full_layers`` blocks a
+        page of its context, its window table ``window_layers`` a page from
+        ``window_base``, which is where ``release_behind`` puts it: a page
+        behind the window that was not given back shows up here.
         """
         if self.kv_dtype == "int8":
             # scale/page agreement: both sides must still be the bundled
@@ -654,15 +746,34 @@ class PagedKVPool:
             block_tables = [list(t) for t in block_tables]
             sums = [list(t) for t in summary_tables] \
                 if summary_tables is not None else [[]] * len(block_tables)
-            if len(sums) != len(block_tables):
-                raise ValueError("summary_tables not parallel to "
-                                 "block_tables")
+            wins = [list(t) for t in window_tables] \
+                if window_tables is not None else [[]] * len(block_tables)
+            bases = list(window_bases) if window_bases is not None \
+                else [0] * len(block_tables)
+            if not len(sums) == len(wins) == len(bases) == len(block_tables):
+                raise ValueError("summary_tables, window_tables and "
+                                 "window_bases are parallel to block_tables")
             if seq_lens is not None:
                 if len(list(seq_lens)) != len(block_tables):
                     raise ValueError(
                         f"seq_lens ({len(list(seq_lens))}) not parallel to "
                         f"block_tables ({len(block_tables)})")
                 for i, (table, n) in enumerate(zip(block_tables, seq_lens)):
+                    if self.sliding:
+                        lo, hi = (self.table_need(n)[0] if n else 0,
+                                  self.table_need(n, 1)[0])
+                        wlo, whi = (self.window_need(n, 0, bases[i]),
+                                    self.window_need(n, 1, bases[i]))
+                        if not (lo <= len(table) <= hi
+                                and wlo <= len(wins[i]) <= whi
+                                and bases[i] == self.release_behind(n)):
+                            raise ValueError(
+                                f"row {i}: {n} resident tokens hold "
+                                f"{len(table)} global and {len(wins[i])} "
+                                f"window blocks from page {bases[i]}, want "
+                                f"{lo}..{hi} and {wlo}..{whi} from page "
+                                f"{self.release_behind(n)}")
+                        continue
                     if self.window is not None:
                         (lo_e, lo_s), (hi_e, hi_s) = \
                             self.table_need(n), self.table_need(n, 1)
@@ -686,7 +797,7 @@ class PagedKVPool:
                             f"{self.blocks_for(n + 1)}); a rejected draft "
                             f"suffix was not truncated")
             usage: Counter = Counter()
-            for table in block_tables + sums:
+            for table in block_tables + sums + wins:
                 usage.update(table)
             for sc in self._scratch:        # padded entries are legal
                 usage.pop(sc, None)
@@ -725,7 +836,21 @@ class PagedKVPool:
             if n <= 0:
                 continue
             start, end = int(start), int(start) + int(n)
-            if self.window is None:
+            if self.sliding:
+                # every layer's segment of the packed row: a global layer's
+                # at the position's page, a window layer's from the base
+                ww = self.win_pages
+                _, at_full, at_win = group_segments(len(table), *self.kinds)
+                lo, hi, base = start // bs, (end - 1) // bs + 1, int(table[-1])
+                if hi - base > ww:
+                    raise ValueError(
+                        f"row {i} writes page {hi - 1} of a window table "
+                        f"of {ww} pages from page {base}")
+                written = [blk for at in at_full
+                           for blk in table[at + lo:at + hi]]
+                written += [blk for at in at_win
+                            for blk in table[at + lo - base:at + hi - base]]
+            elif self.window is None:
                 written = table[start // bs:(end - 1) // bs + 1]
             else:
                 # the window's exact pages at window-relative positions,

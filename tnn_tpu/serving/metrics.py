@@ -480,6 +480,14 @@ class ServingMetrics:
         self.eva_row_steps = 0
         self.eva_summary_rows_max = 0.0
         self.eva_windows_rolled = 0
+        # a model of window layers beside global ones (two page groups):
+        # the share of the sliding window each row's context fills, a row
+        # and a step; the window group's blocks in use over the pool's; the
+        # blocks its rows gave back from behind their windows
+        self.win_fill_sum = 0.0             # over win_row_steps
+        self.win_row_steps = 0
+        self.win_pool_occupancy_max = 0.0
+        self.win_pages_released = 0
         self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
         self.attn_fetch_row_steps = 0
         # step programs dispatched, and those with a row that asks for a
@@ -629,6 +637,20 @@ class ServingMetrics:
         self.eva_row_steps += len(window_fills)
         self.eva_summary_rows_max = max(self.eva_summary_rows_max,
                                         summary_share)
+
+    def observe_window_step(self, fills, occupancy: float) -> None:
+        """One step of a model of two page groups: ``fills`` each row's
+        ``min(context, window) / window``, ``occupancy`` the window group's
+        blocks held by all running requests over the pool's."""
+        self.win_fill_sum += sum(fills)
+        self.win_row_steps += len(fills)
+        self.win_pool_occupancy_max = max(self.win_pool_occupancy_max,
+                                          occupancy)
+
+    def observe_window_release(self, blocks: int) -> None:
+        """A row gave ``blocks`` window-group blocks back: the pages now
+        wholly behind its window, in every window layer."""
+        self.win_pages_released += int(blocks)
 
     def observe_attn_fetch(self, fills) -> None:
         """One paged step: ``fills`` each live row's live pages over the
@@ -1037,6 +1059,12 @@ class ServingMetrics:
                                       / self.eva_row_steps),
                 eva_summary_rows_max=self.eva_summary_rows_max,
                 eva_windows_rolled=self.eva_windows_rolled)
+        if self.win_row_steps:
+            # only a model of two page groups has these
+            out.update(
+                win_fill_mean=self.win_fill_sum / self.win_row_steps,
+                win_pool_occupancy_max=self.win_pool_occupancy_max,
+                win_pages_released=self.win_pages_released)
         if self.expert_layer_steps:
             # only a model with an expert layer has these
             out.update(

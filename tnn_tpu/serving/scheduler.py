@@ -86,6 +86,10 @@ class Request:
     summary_table: List[int] = field(default_factory=list)  # a windowed
     #                                     pool's second kind of page: one row
     #                                     a finished chunk (kv_pool.py)
+    window_table: List[int] = field(default_factory=list)  # a pool of two
+    #                                     page groups: the window layers'
+    #                                     pages it still holds, from logical
+    window_base: int = 0                # page ``window_base`` (kv_pool.py)
     cache_len: int = 0                  # tokens resident in the KV pool
     prefill_len: int = 0                # total tokens the current (re-)
     #                                     prefill must push; while cache_len
@@ -233,13 +237,14 @@ class Scheduler:
         request only if it fits TO ITS LAST TOKEN beside what the running
         requests may still take (their exact window whole, a summary row for
         every chunk to come): exact pages come and go with the windows, so
-        "fits now" says nothing, and a steady run never preempts."""
-        if pool.window is None:
+        "fits now" says nothing, and a steady run never preempts. A pool
+        of two page groups likewise: its window pages come and go."""
+        if pool.window is None and not pool.sliding:
             return pool.num_allocatable
         owed = sum(
             pool.lifetime_blocks(len(r.prompt) + r.max_new_tokens)
             - len(r.block_table) - len(r.summary_table)
-            for r in self.running)
+            - len(r.window_table) for r in self.running)
         return pool.num_allocatable - owed
 
     def schedule(self, pool) -> StepPlan:

@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from ..ops.pallas.paged_attention import group_segments
 from ..utils.bucketing import pow2_bucket
 from . import spec_decode
 
@@ -95,10 +96,25 @@ def shard_tables(tables: np.ndarray, sp: int,
                     np.int32(-1))
 
 
-def _fill_row(step: PackedStep, i: int, req, sum_at: int = 0) -> None:
+def _fill_row(step: PackedStep, i: int, req, sum_at: int = 0,
+              kinds=None) -> None:
     """``sum_at``: where a windowed pool's summary pages start in the packed
-    table (its exact segment's width, ``PagedKVPool.exact_width``)."""
-    step.tables[i, :len(req.block_table)] = req.block_table
+    table (its exact segment's width, ``PagedKVPool.exact_width``).
+    ``kinds``: a pool of two page groups (``PagedKVPool.kinds``: global
+    layers, window layers, entries a window layer): the row is a segment a
+    global layer, a segment a window layer, then the window table's base."""
+    if kinds:
+        n_full, n_win, ww = kinds
+        row = step.tables[i]
+        _, at_full, at_win = group_segments(len(row), n_full, n_win, ww)
+        for table, n, starts in ((req.block_table, n_full, at_full),
+                                 (req.window_table, n_win, at_win)):
+            for j, at in enumerate(starts):
+                seg = table[j::n]
+                row[at:at + len(seg)] = seg
+        row[-1] = req.window_base
+    else:
+        step.tables[i, :len(req.block_table)] = req.block_table
     if req.summary_table:
         step.tables[i, sum_at:sum_at + len(req.summary_table)] = \
             req.summary_table
@@ -119,7 +135,7 @@ def _alloc_common(b: int, nb: int, scratch: int):
 def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
                takes: Dict[int, int], *, b: int, nb: int, scratch: int,
                spec_on: bool, kv_key: Tuple[Any, ...],
-               sum_at: int = 0) -> MixedStep:
+               sum_at: int = 0, kinds=None) -> MixedStep:
     """Pack decode rows (first ``n_dec`` of ``rows``, each 1 token +
     optional draft) and prompt-chunk rows (the rest, ``takes[rid]`` tokens
     each) into one ragged batch. Host drafts land in the token matrix here;
@@ -139,7 +155,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(rows):
         step.starts[i] = req.cache_len
-        _fill_row(step, i, req, sum_at)
+        _fill_row(step, i, req, sum_at, kinds)
         if i < n_dec:
             d = drafts.get(req.rid, []) if spec_on else []
             step.toks[i, 0] = req.next_token
@@ -159,7 +175,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
 
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
                 kv_key: Tuple[Any, ...], ahead: int = 0,
-                sum_at: int = 0) -> DecodeStep:
+                sum_at: int = 0, kinds=None) -> DecodeStep:
     """Pack the pure-decode batch. ``ahead`` = j > 0 packs the overlapped
     engine's predicted step N+j: each row's offset assumes exactly j more
     tokens committed, and the token column is left zero — the dispatched
@@ -174,5 +190,5 @@ def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
         if not ahead:
             step.toks[i] = req.next_token
         step.offsets[i] = req.cache_len + ahead
-        _fill_row(step, i, req, sum_at)
+        _fill_row(step, i, req, sum_at, kinds)
     return step
