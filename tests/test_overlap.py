@@ -182,6 +182,17 @@ class TestSpeculativeSteps:
             assert on.get(rid, list(eng.requests[rid].out_tokens)) == want
 
 
+@pytest.fixture
+def fast_ramp(monkeypatch):
+    """The ramp is a step an adoption and the time limit is out of the way,
+    so a dozen steps are enough to queue deep."""
+    from tnn_tpu.serving import engine
+
+    monkeypatch.setattr(engine, "SPECULATE_RAMP", 1)
+    monkeypatch.setattr(engine, "SPECULATE_AHEAD_S", 3600.0)
+
+
+@pytest.mark.usefixtures("fast_ramp")
 class TestSpeculationDepth:
     """A batch that has been closed for a while queues several steps ahead
     (``engine.SPECULATE_*``): a host that stands still for a moment then
@@ -189,13 +200,6 @@ class TestSpeculationDepth:
     time limit is out of the way, so a dozen steps are enough to go deep."""
 
     KW = dict(num_blocks=64, block_size=4, max_batch_size=4, max_seq_len=64)
-
-    @pytest.fixture(autouse=True)
-    def fast_ramp(self, monkeypatch):
-        from tnn_tpu.serving import engine
-
-        monkeypatch.setattr(engine, "SPECULATE_RAMP", 1)
-        monkeypatch.setattr(engine, "SPECULATE_AHEAD_S", 3600.0)
 
     @staticmethod
     def _turn(eng):
@@ -366,3 +370,310 @@ class TestDebugSyncOverlap:
         got, eng = _run(model, params, overlap=True, spec="ngram")
         assert eng.debug_sync
         assert got == ref
+
+
+@pytest.mark.usefixtures("fast_ramp")
+class TestLoadedServer:
+    """A server under load has a queue, and its rows are taken: a request
+    that WAITS changes nothing about the next step, so the chain of steps
+    queued ahead goes on over it (``Scheduler.would_admit``), and every
+    step is still the step the synchronous loop runs: same rows, same
+    tokens, the head of the queue admitted at the same step."""
+
+    KW = dict(num_blocks=64, block_size=4, max_batch_size=2, max_seq_len=64,
+              prefix_cache=False)
+    _turn = staticmethod(TestSpeculationDepth._turn)
+
+    @staticmethod
+    def _load(eng, temperature=0.0):
+        """Five requests over two rows, outputs of five lengths: three wait,
+        and every row's end lets the head of the queue in."""
+        rng = np.random.default_rng(4)
+        return [eng.submit(rng.integers(0, 128, p).astype(np.int32), n,
+                           temperature=temperature)
+                for p, n in ((5, 14), (9, 22), (7, 9), (12, 17), (6, 12))]
+
+    @staticmethod
+    def _sync(eng, before_step=None):
+        """The synchronous loop, a flight record and the events a step."""
+        log = []
+        while eng.has_work:
+            if before_step is not None:
+                before_step(len(log))
+            events = eng.step()
+            log.append((eng.last_finished_record(), events))
+        return log
+
+    def _overlapped(self, eng, in_flight=None):
+        """The overlapped loop; ``in_flight(i)`` runs while step i is."""
+        log, depths = [], []
+        while eng.has_work or eng.in_flight is not None:
+            if eng.in_flight is None:
+                eng.begin_step()
+            while eng.try_speculate():
+                pass
+            depths.append(len(eng.in_flight.ahead))
+            eng.run_deferred()
+            if in_flight is not None:
+                in_flight(len(log))
+            events = eng.finish_step()
+            log.append((eng.last_finished_record(), events))
+        eng.run_deferred()
+        return log, depths
+
+    @staticmethod
+    def _rows(log):
+        return [(rec["running_rids"],
+                 [p["kind"] for p in rec["programs"]]) for rec, _ in log]
+
+    @staticmethod
+    def _tokens(eng, rids):
+        return {r: list(eng.requests[r].out_tokens) for r in rids}
+
+    @pytest.mark.parametrize("family,temperature", [
+        ("gpt2", 0.0), ("gpt2", 0.8), ("llama", 0.0), ("llama", 0.8),
+        ("mistral", 0.0), ("mistral", 0.8)])
+    def test_a_chain_is_adopted_over_a_queue(self, lm, family, temperature):
+        model, params = lm
+        sync = InferenceEngine(model, params, **self.KW, overlap=False)
+        rids = self._load(sync, temperature)
+        want = self._sync(sync)
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        assert self._load(eng, temperature) == rids
+        got, depths = self._overlapped(eng)
+        assert self._tokens(eng, rids) == self._tokens(sync, rids)
+        # every step holds the rows it holds in the synchronous loop: the
+        # head of the queue came in at the same step
+        assert self._rows(got) == self._rows(want)
+        over_a_queue = [rec for rec, _ in got
+                        if rec.get("speculative") and rec["queued"]]
+        assert len(over_a_queue) >= 10, "no chain went on over the queue"
+        assert max(depths) >= 4
+        # a row's last step is known beforehand: nothing is queued behind
+        # it, so nothing is ever rolled back
+        m = eng.metrics
+        assert m.overlap_rebuilds == 0
+        assert m.adopted_steps == sum(
+            1 for rec, _ in got if rec.get("speculative"))
+        assert m.summary()["adopted_step_share"] == pytest.approx(
+            m.adopted_steps / len(got))
+        assert m.summary()["adopted_step_share"] > 0.5
+        assert eng.pool.num_allocated == 0 and not eng._reuse_keys
+
+    def test_an_arrival_into_a_full_batch_rolls_nothing_back(self, tiny_lm):
+        model, params = tiny_lm
+        prompts = _prompts()
+
+        def arrive(eng, at):
+            def hook(i):
+                if i == at:
+                    hook.rid = eng.submit(prompts[2], 12, temperature=0.7)
+            return hook
+
+        sync = InferenceEngine(model, params, **self.KW, overlap=False)
+        rids = [sync.submit(p, 30, temperature=0.7) for p in prompts[:2]]
+        h = arrive(sync, 9)
+        want = self._sync(sync, before_step=h)
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        assert [eng.submit(p, 30, temperature=0.7)
+                for p in prompts[:2]] == rids
+        # ... while step 8 is in flight with a chain behind it
+        h2 = arrive(eng, 8)
+        got, depths = self._overlapped(eng, in_flight=h2)
+        assert depths[8] >= 5 and h2.rid == h.rid
+        assert eng.metrics.overlap_rebuilds == 0
+        assert got[9][0].get("speculative") and got[9][0]["queued"] == 1
+        assert self._tokens(eng, rids + [h.rid]) == \
+            self._tokens(sync, rids + [h.rid])
+        assert self._rows(got) == self._rows(want)
+
+    @pytest.mark.parametrize("limit,clock", [
+        ("max_queue_s", "queued_time"), ("deadline_s", "submit_time")])
+    def test_a_queued_request_times_out_at_its_step(self, tiny_lm, limit,
+                                                    clock):
+        """An adopted step never runs ``begin_step``: the queued requests'
+        deadlines run at its adoption, into its events. The limit passes
+        while step 6 runs; step 7 reports it in both loops, and the request
+        behind it comes in when it would have."""
+        model, params = tiny_lm
+
+        def load(eng):
+            rids = self._load(eng)
+            # the head of the queue and the one behind it may time out
+            for rid in rids[2:4]:
+                setattr(eng.requests[rid], limit, 5e3)
+            return rids
+
+        def expire(eng, rids, at):
+            def hook(i):
+                if i == at:
+                    req = eng.requests[rids[2]]
+                    setattr(req, clock, getattr(req, clock) - 1e4)
+            return hook
+
+        sync = InferenceEngine(model, params, **self.KW, overlap=False)
+        rids = load(sync)
+        # the synchronous step 6 has returned; the overlapped one is flying
+        want = self._sync(sync, before_step=expire(sync, rids, 7))
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        assert load(eng) == rids
+        got, _ = self._overlapped(eng, in_flight=expire(eng, rids, 6))
+        timed = [i for i, (_, ev) in enumerate(got) if ev["timed_out"]]
+        assert timed == [7] == [i for i, (_, ev) in enumerate(want)
+                                if ev["timed_out"]]
+        assert got[7][1]["timed_out"] == want[7][1]["timed_out"]
+        assert got[7][1]["timed_out"][0][0] == rids[2]
+        assert got[7][0].get("speculative"), "step 7 was built, not adopted"
+        assert eng.metrics.overlap_rebuilds == 0
+        assert eng.requests[rids[2]].state.name == "TIMED_OUT"
+        assert self._tokens(eng, rids) == self._tokens(sync, rids)
+        assert self._rows(got) == self._rows(want)
+
+    TIGHT = dict(KW, max_batch_size=3)
+
+    @staticmethod
+    def _tight_work():
+        """Prompts of 20 and 22 tokens (5 and 6 blocks of four, 8 and 11 at
+        their last token), then one of 30 (8 blocks at once), a short one
+        and a later arrival: a pool of 17 to 18 blocks has a free ROW for
+        the third all along and no room until the first ends."""
+        rng = np.random.default_rng(8)
+        return [(rng.integers(0, 128, p).astype(np.int32), n)
+                for p, n in ((20, 12), (22, 22), (30, 8), (5, 6), (9, 6))]
+
+    @pytest.mark.parametrize("num_blocks", [18, 19])
+    def test_a_free_row_and_a_head_the_pool_cannot_hold(self, tiny_lm,
+                                                        num_blocks):
+        """Three rows, two taken, and a head of the queue whose prompt the
+        pool has no room for: the chain goes on beside the free row (the
+        scheduler's own arithmetic says the head does not fit), the head
+        comes in at the synchronous loop's step, and nobody is preempted
+        who is not there."""
+        model, params = tiny_lm
+        kw = dict(self.TIGHT, num_blocks=num_blocks)
+
+        def load(eng):
+            return [eng.submit(p, n, temperature=0.6)
+                    for p, n in self._tight_work()[:4]]
+
+        sync = InferenceEngine(model, params, **kw, overlap=False)
+        rids = load(sync)
+        want = self._sync(sync)
+        eng = InferenceEngine(model, params, **kw, overlap=True)
+        assert load(eng) == rids
+        got, _ = self._overlapped(eng)
+        assert self._rows(got) == self._rows(want)
+        assert self._tokens(eng, rids) == self._tokens(sync, rids)
+        assert eng.metrics.preemptions == sync.metrics.preemptions
+        beside_a_free_row = [rec for rec, _ in got if rec.get("speculative")
+                             and rec["queued"]
+                             and len(rec["running_rids"]) < 3]
+        assert len(beside_a_free_row) >= 5, "the head fitted: nothing shown"
+        assert eng.pool.num_allocated == 0
+
+    @pytest.mark.parametrize("num_blocks,at", [
+        (17, 3), (18, 4), (18, 5), (19, 5), (19, 6), (19, 7)])
+    def test_blocks_taken_ahead_are_free_to_an_arrival(self, tiny_lm,
+                                                       num_blocks, at):
+        """An arrival finds a free row and a pool whose last blocks the
+        chain has taken for its rows' next tokens. The synchronous loop
+        plans a step before its rows grow and admits the arrival; so does
+        this one (``InferenceEngine._grown_ahead``): it rolls the chain
+        back and admits at that step, sampled rows and all."""
+        model, params = tiny_lm
+        kw = dict(self.TIGHT, num_blocks=num_blocks)
+        work = self._tight_work()
+
+        def load(eng):
+            return [eng.submit(p, n, temperature=0.6) for p, n in work[:2]]
+
+        def arrive(eng, at):
+            def hook(i):
+                if i == at:
+                    hook.rid = eng.submit(*work[4], temperature=0.6)
+            return hook
+
+        sync = InferenceEngine(model, params, **kw, overlap=False)
+        rids = load(sync)
+        h = arrive(sync, at + 1)
+        want = self._sync(sync, before_step=h)
+        eng = InferenceEngine(model, params, **kw, overlap=True)
+        assert load(eng) == rids
+        h2 = arrive(eng, at)
+        got, _ = self._overlapped(eng, in_flight=h2)
+        assert h2.rid == h.rid
+        assert self._rows(got) == self._rows(want)
+        assert self._tokens(eng, rids + [h.rid]) == \
+            self._tokens(sync, rids + [h.rid])
+        assert eng.metrics.preemptions == sync.metrics.preemptions
+        assert eng.metrics.overlap_rebuilds == 1
+        assert eng.pool.num_allocated == 0
+
+    def test_each_refusal_counts_under_its_own_name(self, tiny_lm):
+        """A step in flight and depth to spare, and no step dispatched:
+        ONE reason each time, by name. A full batch with a queue behind it
+        is no reason: the chain goes on."""
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        said = eng.metrics.speculate_refusals
+        assert sorted(said) == ["admission", "mixed_step", "other", "pool",
+                                "row_condition", "row_ends"]
+        assert not eng.try_speculate() and not any(said.values())
+        a = eng.submit(_prompts()[0], 20)
+        eng.begin_step()                            # the prompt's chunk
+        assert not eng.try_speculate()
+        assert said["mixed_step"] == 1 and sum(said.values()) == 1
+        eng.finish_step()
+        eng.begin_step()                            # a decode step, alone
+        b = eng.submit(_prompts()[1], 30)           # a row is free for it
+        assert not eng.try_speculate()
+        assert said["admission"] == 1 and sum(said.values()) == 2
+        eng.finish_step()
+        c = eng.submit(_prompts()[2], 6)            # the batch is full: waits
+        self._turn(eng)                             # b's chunk beside a
+        assert said["mixed_step"] == 2 and sum(said.values()) == 3
+        for _ in range(4):
+            assert self._turn(eng) >= 1             # ... over the queue
+        assert sum(said.values()) == 3              # as deep as it may: none
+        while eng.requests[a].state.name == "RUNNING":
+            self._turn(eng)
+        # the chain stopped short of a's last token, once a turn
+        assert said["row_ends"] >= 1
+        assert sum(said.values()) == 3 + said["row_ends"]
+        eng.run_until_complete()
+        s = eng.metrics.summary()
+        assert s["speculate_refused_mixed_step"] == said["mixed_step"]
+        assert s["speculate_refused_row_ends"] == said["row_ends"]
+        assert 0.0 < s["adopted_step_share"] < 1.0
+        fams = {f["name"]: f for f in eng.metrics.prometheus_series()}
+        assert fams["tnn_serve_adopted_steps_total"]["samples"][0][-1] == \
+            eng.metrics.adopted_steps
+        by_reason = {lb["reason"]: v for _, lb, v in
+                     fams["tnn_serve_speculate_refusals_total"]["samples"]}
+        assert by_reason == {k: float(v) for k, v in said.items()}
+        assert eng.requests[c].state.name == "FINISHED"
+
+    def test_a_dry_pool_counts_as_pool(self, tiny_lm):
+        """Two rows of 3 tokens hold a block of four each and the pool has
+        one more: the step in flight writes position 3, the next would need
+        a block for each row, which the synchronous loop gets by a
+        preemption and no prediction packs."""
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **dict(
+            self.KW, num_blocks=4, max_seq_len=12), overlap=True)
+        rids = [eng.submit(np.arange(3, dtype=np.int32) + i, 9)
+                for i in range(2)]
+        self._turn(eng)                             # the prompts' chunks
+        said = eng.metrics.speculate_refusals
+        assert said["mixed_step"] == sum(said.values()) == 1
+        eng.begin_step()
+        assert not eng.try_speculate()
+        assert said["pool"] == 1 and sum(said.values()) == 2
+        assert eng.metrics.preemptions == 0
+        got = eng.run_until_complete()
+        assert eng.metrics.preemptions >= 1
+        sync = InferenceEngine(model, params, **dict(
+            self.KW, num_blocks=4, max_seq_len=12), overlap=False)
+        assert [sync.submit(np.arange(3, dtype=np.int32) + i, 9)
+                for i in range(2)] == rids
+        assert got == sync.run_until_complete()

@@ -194,6 +194,109 @@ class TestScheduler:
         assert resume.tolist() == [0, 0, 0, 11, 12]  # pending 13 excluded
 
 
+def _snapshot(sched, pool, cache):
+    """Everything ``Scheduler.would_admit`` might have moved."""
+    return ([r.rid for r in sched.waiting], [r.rid for r in sched.running],
+            [(r.prefill_len, r.cache_len, r.state) for r in sched.waiting],
+            sorted(pool._free), list(pool._evictable), dict(pool._ref),
+            None if cache is None else dict(cache._index))
+
+
+class TestWouldAdmit:
+    """``Scheduler.would_admit`` is the head of ``schedule``'s own admission
+    loop, asked without moving anything: on drawn states of every kind of
+    pool it says what ``schedule`` then does, and leaves no trace."""
+
+    KINDS = {
+        "plain": dict(),
+        "prefix_cache": dict(),
+        "windowed": dict(window=32, chunk=4),
+        "two_group": dict(groups=dict(window=16, full_layers=1,
+                                      window_layers=4)),
+    }
+
+    @pytest.mark.parametrize("kind", sorted(KINDS))
+    def test_agrees_with_schedule_and_moves_nothing(self, kind):
+        rng = np.random.default_rng(sorted(self.KINDS).index(kind))
+        answers = set()
+        for _ in range(120):
+            pool = PagedKVPool(1, 2, 4, int(rng.integers(12, 48)), 8,
+                               **self.KINDS[kind])
+            cache = None
+            sched = Scheduler(max_batch_size=int(rng.integers(1, 5)),
+                              token_budget=int(rng.integers(0, 40)),
+                              chunk_size=int(rng.integers(4, 24)))
+            if kind == "prefix_cache":
+                cache = sched.prefix_cache = PrefixCache(8)
+                pool.evictable_filter = cache.contains_block
+                pool.reclaim_hook = cache.drop_blocks
+                # a prompt served earlier: its blocks rest evictable
+                shared = rng.integers(0, 50, 24).astype(np.int32)
+                table = pool.alloc(3)
+                cache.publish(shared, table, 24)
+                pool.free(table)
+            for rid in range(int(rng.integers(0, sched.max_batch_size + 1))):
+                req = _req(rid, int(rng.integers(4, 40)),
+                           max_new=int(rng.integers(1, 40)))
+                req.prefill_len = len(req.prompt)
+                req.cache_len = int(rng.integers(0, len(req.prompt) + 1))
+                grow = (*pool.table_need(req.cache_len, 1),
+                        pool.window_need(req.cache_len, 1, 0))
+                if not pool.can_alloc(sum(grow)):
+                    break
+                req.block_table = pool.alloc(grow[0])
+                req.summary_table = pool.alloc(grow[1])
+                req.window_table = pool.alloc(grow[2])
+                sched.admit(req)
+            for rid in range(10, 10 + int(rng.integers(0, 4))):
+                req = _req(rid, int(rng.integers(4, 60)),
+                           max_new=int(rng.integers(1, 40)))
+                if cache is not None and rng.random() < 0.5:
+                    n = min(24, len(req.prompt))
+                    req.prompt[:n] = shared[:n]
+                sched.submit(req)
+            before = _snapshot(sched, pool, cache)
+            said = sched.would_admit(pool)
+            assert _snapshot(sched, pool, cache) == before
+            assert said == bool(sched.schedule(pool).prefills)
+            answers.add(said)
+        assert answers == {True, False}, "the draw never saw both answers"
+
+    def test_blocks_taken_ahead_count_back_in(self):
+        """``spare``: blocks the engine took for the running rows' steps
+        ahead are blocks ``schedule`` would still have found free: a plain
+        pool counts them back in, a pool that admits to the last token
+        already counts them as no longer owed."""
+        pool = PagedKVPool(1, 2, 4, 9, 4)
+        sched = Scheduler(max_batch_size=2, token_budget=100)
+        row = _req(0, 4)
+        row.prefill_len = row.cache_len = 4
+        row.block_table = pool.alloc(1) + pool.alloc(5)   # 5 taken ahead
+        sched.admit(row)
+        sched.submit(_req(1, 12))           # three blocks; two are free
+        assert not sched.would_admit(pool)
+        assert not sched.would_admit(pool, spare=0)
+        assert sched.would_admit(pool, spare=1)
+        windowed = PagedKVPool(1, 2, 4, 12, 8, window=32, chunk=4)
+        sched = Scheduler(max_batch_size=2, token_budget=100)
+        row = _req(0, 8, max_new=24)        # 4 exact pages + 1 of summaries
+        row.prefill_len = row.cache_len = 8
+        row.block_table = windowed.alloc(1)
+        sched.admit(row)
+        sched.submit(_req(1, 8, max_new=24))
+        fits = sched.would_admit(windowed)
+        row.block_table += windowed.alloc(2)            # taken ahead
+        assert sched.would_admit(windowed, spare=2) == fits
+        assert sched.would_admit(windowed) == fits
+
+    def test_a_full_batch_answers_without_the_pool(self):
+        sched = Scheduler(max_batch_size=1, token_budget=100)
+        sched.admit(_req(0, 4))
+        sched.submit(_req(1, 4))
+        assert not sched.would_admit(None)      # the pool is never asked
+        assert not Scheduler().would_admit(None)    # nor with nobody waiting
+
+
 # -- end-to-end on a tiny model ----------------------------------------------
 
 

@@ -112,8 +112,8 @@ from .tracing import Tracer
 # still (a shared machine stops every process for 80-140 ms now and then:
 # PERF.md section 2; the device goes on with what is queued), at most
 # SPECULATE_MAX, and one step more for every SPECULATE_RAMP steps
-# adopted in a row, so that only a batch that has been closed for a while
-# queues deep: what an arrival finds queued it waits out.
+# adopted in a row, so that only a batch whose rows have stayed for a while
+# queues deep: what an arrival into a free row finds queued it waits out.
 SPECULATE_AHEAD_S = 0.2
 SPECULATE_MAX = 12
 SPECULATE_RAMP = 8
@@ -289,7 +289,9 @@ class InferenceEngine:
         The drive loops (``run_until_complete``, the supervisor tick) pair
         begin/finish around the deferred work and may speculatively
         dispatch steps N+1, N+2, ... from predicted row states before step
-        N commits (``try_speculate``, as deep as ``_speculate_depth`` says;
+        N commits (``try_speculate``, as deep as ``_speculate_depth`` says:
+        pure decode steps at which the scheduler would admit nothing, with
+        a queue behind a full batch as well as with none;
         mispredictions roll back and rebuild).
         Token-exact vs overlap-off — a direct
         ``step()`` call stays fully synchronous either way. Default off;
@@ -1402,54 +1404,84 @@ class InferenceEngine:
         batch whose every row must survive the j commits before it — no
         stop tokens, no deadlines, headroom for j+1 more tokens — with KV
         growth that fits the pool without preemption, no drafter, no fault
-        plan, and an empty wait queue. The dispatched program reads its
+        plan, and a scheduler that would admit nothing at that step
+        (``Scheduler.would_admit``: nobody waits, or every row is taken, or
+        the head of the queue does not fit the budget or the pool; a server
+        under load has a queue, and its rows are taken). The dispatched
+        program reads its
         predecessor's sampled tokens DIRECTLY as its device-resident
         inputs, so nothing syncs; ``finish_step`` validates the prediction
         and either adopts the oldest successor as the next in-flight step
         or rolls every one back (``_resolve_speculation``). Returns True
         when a step was dispatched: the drive loops call it until it says
-        False (``_speculate_depth`` successors are dispatched). Abandoned
+        False (``_speculate_depth`` successors are dispatched); a False
+        with a step in flight and depth to spare counts ONE reason
+        (``ServingMetrics.observe_speculate_refusal``). Abandoned
         KV writes are harmless: they land at positions at or past every
         surviving row's committed length, or in blocks the rollback frees
         — always overwritten before attended."""
         flight = self._flight
         if (not self.overlap or flight is None or flight.done
-                or self.faults is not None
-                or self.drafter is not None or self.scheduler.waiting
-                or len(flight.recs) != 1
-                or flight.recs[0]["kind"] != "decode"
                 or len(flight.ahead) >= self._speculate_depth()):
             return False
+        refusal = self._dispatch_ahead(flight)
+        if refusal:
+            self.metrics.observe_speculate_refusal(refusal)
+        return not refusal
+
+    def _grown_ahead(self, ahead) -> int:
+        """Blocks the steps in ``ahead`` took for their rows' next tokens
+        when they were dispatched: ``Scheduler.schedule`` plans a step
+        BEFORE its rows grow, so it would still have found them
+        allocatable. An upper bound under sequence parallelism (a shard's
+        blocks count ``sp`` times in ``num_allocatable``): to expect an
+        admission that does not come costs a rebuild, to miss one would
+        cost the order of the steps."""
+        return self.sp * sum(len(ext) for s in ahead
+                             for _, _, ext in s["rollback"])
+
+    def _dispatch_ahead(self, flight: "StepInFlight") -> str:
+        """``try_speculate``'s body: "" when step N+j went out, else why
+        not, by the name its refusal is counted under."""
+        if self.faults is not None or self.drafter is not None:
+            return "other"
+        if any(rec["kind"] != "decode" for rec in flight.recs):
+            return "mixed_step"
+        if len(flight.recs) != 1:
+            return "other"              # the step launched nothing
         j = len(flight.ahead) + 1       # every row is j tokens on by then
         rec = flight.ahead[-1]["rec"] if flight.ahead else flight.recs[0]
         live = rec["live"]
         if live != [r for r in self.scheduler.running
                     if r.state is RequestState.RUNNING]:
-            return False
+            return "other"              # a row left while the step flew
         grows = []
         for req in live:
-            if (req.state is not RequestState.RUNNING
-                    or req.cache_len < req.prefill_len
+            if (req.cache_len < req.prefill_len
                     or req.stop_token is not None
                     or req.deadline_s is not None
-                    or req.num_generated + j >= req.max_new_tokens
-                    or req.cache_len + j + 1 > self.max_seq_len
                     # a step before this one ends the row's window: its
                     # commit gives the exact pages back, which no
                     # prediction packs
                     or self.pool.room_in_window(req.cache_len) <= j):
-                return False
+                return "row_condition"
+            if (req.num_generated + j >= req.max_new_tokens
+                    or req.cache_len + j + 1 > self.max_seq_len):
+                return "row_ends"
             grows.append(self._grow_need(req, req.cache_len + j, 1))
+        if self.scheduler.would_admit(self.pool,
+                                      self._grown_ahead(flight.ahead)):
+            return "admission"
         total = sum(map(sum, grows))
         if total and not self.pool.can_alloc(total):
-            return False
+            return "pool"
         rollback: List[Any] = []
         try:
             for req, g in zip(live, grows):
                 rollback.extend(self._extend(req, g))
         except PoolExhausted:
             self._unextend(rollback)
-            return False
+            return "pool"
         # ahead=j packs the predicted row state: each offset assumes
         # exactly one token committed by each of the j steps before
         step = step_build.pack_decode(
@@ -1479,7 +1511,7 @@ class InferenceEngine:
             self._unextend(rollback)
             self._reuse_keys.insert(0, step_key)
             self._recover_pages_if_dead(flight.events)
-            return False
+            return "other"
         self.pool.update_pages(pk, pv)
         self._observe_attention(live, offsets + 1, 1)
         flight.ahead.append({
@@ -1492,7 +1524,7 @@ class InferenceEngine:
                      "rids": [r.rid for r in live],
                      "fill": round(len(live) / b, 4)},
         })
-        return True
+        return ""
 
     def _speculate_depth(self) -> int:
         """Successor steps ``try_speculate`` keeps dispatched behind the
@@ -1506,10 +1538,17 @@ class InferenceEngine:
     def _resolve_speculation(self, flight: "StepInFlight") -> None:
         """After ``flight`` committed: adopt its oldest speculative
         successor when the prediction held (the same rows, each exactly
-        one token longer, still running, queue still empty) and hand it
+        one token longer, still running, and a scheduler that would still
+        admit nothing: asked again of the committed state) and hand it
         the younger ones, else roll them all back — free the pre-grown
         blocks, stash the PRNG keys for reuse in order, and let the next
-        ``begin_step`` rebuild from committed state."""
+        ``begin_step`` rebuild from committed state.
+
+        An adopted step never runs ``begin_step``, so what that does for
+        those who WAIT is done here, in its order: the queued requests'
+        deadlines first (``_expire_waiting``, into the adopted step's
+        events, the step boundary they would have had), then the
+        scheduler's answer."""
         ahead, flight.ahead = flight.ahead, []
         if not ahead:
             return
@@ -1517,26 +1556,34 @@ class InferenceEngine:
         rec = spec["rec"]
         live = rec["live"]
         predicted = (
-            not self.scheduler.waiting
-            and live == [r for r in self.scheduler.running
-                         if r.state is RequestState.RUNNING]
+            live == [r for r in self.scheduler.running
+                     if r.state is RequestState.RUNNING]
             and all(req.cache_len == spec["offsets"][req.rid]
                     for req in live))
+        queued = self.scheduler.queue_depth
+        timed_out: List[Any] = []
+        if predicted and queued:
+            self._expire_waiting({"timed_out": timed_out})
+            predicted = not self.scheduler.would_admit(
+                self.pool, self._grown_ahead(ahead))
         if not predicted:
             for s in reversed(ahead):
                 self._unextend(s["rollback"], only_intact=True)
             self._reuse_keys[:0] = [s["key"] for s in ahead]
             self._adopted_run = 0
             self.metrics.observe_overlap_rebuild()
+            # no step to carry them: they end with the step that committed
+            flight.events["timed_out"].extend(timed_out)
             return
         # prediction held: the dispatched step IS the next step — give it
         # its step_seq and flight-recorder note at adoption time. Its clock
         # starts here too: until now it waited behind its predecessor
         self._adopted_run += 1
+        self.metrics.observe_adopted_step()
         self.step_seq += 1
         note: Dict[str, Any] = {
             "step_seq": self.step_seq,
-            "queued": self.scheduler.queue_depth,
+            "queued": queued,
             "running_rids": [r.rid for r in live],
             "programs": [dict(spec["prog"])],
             "speculative": True,
@@ -1545,6 +1592,7 @@ class InferenceEngine:
         rec["t0"] = time.perf_counter()
         nxt = StepInFlight(self.step_seq, note, None, rec["t0"])
         nxt.gen_before = {r.rid: r.num_generated for r in live}
+        nxt.events["timed_out"] = timed_out
         nxt.recs.append(rec)
         nxt.ahead = ahead[1:]
         self._flight = nxt
@@ -1598,6 +1646,20 @@ class InferenceEngine:
         self._deferred = keep
 
     def _enforce_deadlines(self, events: Dict[str, List]) -> None:
+        self._expire_waiting(events)
+        now = time.perf_counter()
+        for req in list(self.scheduler.running):
+            if req.deadline_s is not None and \
+                    now - req.submit_time > req.deadline_s:
+                self._terminate(
+                    req, RequestState.TIMED_OUT,
+                    f"deadline {req.deadline_s}s exceeded after "
+                    f"{req.num_generated} tokens", events, "timed_out")
+
+    def _expire_waiting(self, events: Dict[str, List]) -> None:
+        """The queued requests' half of deadline expiry: it touches the
+        wait queue and ``events["timed_out"]`` alone, so a step adopted
+        without a ``begin_step`` runs it too (``_resolve_speculation``)."""
         now = time.perf_counter()
         for req in list(self.scheduler.waiting):
             if req.deadline_s is not None and \
@@ -1612,13 +1674,6 @@ class InferenceEngine:
                     req, RequestState.TIMED_OUT,
                     f"max_queue_s {req.max_queue_s}s exceeded",
                     events, "timed_out")
-        for req in list(self.scheduler.running):
-            if req.deadline_s is not None and \
-                    now - req.submit_time > req.deadline_s:
-                self._terminate(
-                    req, RequestState.TIMED_OUT,
-                    f"deadline {req.deadline_s}s exceeded after "
-                    f"{req.num_generated} tokens", events, "timed_out")
 
     def run_until_complete(self, max_steps: int = 100_000) -> Dict[int, List[int]]:
         """Drive steps until every submitted request finished; returns

@@ -103,6 +103,10 @@ EXPOSITION: Dict[str, Tuple[str, str, str, str]] = {
         "tnn_serve_overlap_rebuilds_total", "counter",
         "Speculatively dispatched steps rolled back on misprediction",
         "overlap_rebuilds"),
+    "serve.adopted_step": (
+        "tnn_serve_adopted_steps_total", "counter",
+        "Steps dispatched ahead of their predecessor's commit and adopted "
+        "as dispatched (no build, no host gap)", "adopted_step_share"),
     "serve.decode_s": (
         "tnn_serve_decode_seconds_total", "counter",
         "Cumulative decode-step wall seconds", "tok_per_s"),
@@ -473,6 +477,14 @@ class ServingMetrics:
         # overlapped loop: speculatively dispatched steps torn down because
         # step N's outcome invalidated the predicted row set
         self.overlap_rebuilds = 0
+        # ... and those adopted as dispatched, over every step committed;
+        # each time ``try_speculate`` had a step in flight and depth to
+        # spare and dispatched nothing, the ONE reason why, by name
+        self.adopted_steps = 0
+        self.committed_steps = 0
+        self.speculate_refusals: Dict[str, int] = dict.fromkeys(
+            ("mixed_step", "row_ends", "admission", "pool", "row_condition",
+             "other"), 0)
         # a windowed model (EVA: exact window beside chunk summaries): the
         # share of the window's exact positions in use, a row and a step;
         # the pool's rows that hold summaries; windows ended
@@ -628,6 +640,24 @@ class ServingMetrics:
         self.overlap_rebuilds += 1
         self._tick("serve.overlap_rebuild", 1)
 
+    def observe_adopted_step(self) -> None:
+        """A step dispatched behind its predecessor was adopted as the next
+        step: it never ran ``begin_step``, and its host gap is zero."""
+        self.adopted_steps += 1
+        self._tick("serve.adopted_step", 1)
+
+    def observe_speculate_refusal(self, reason: str) -> None:
+        """``try_speculate`` had a step in flight and depth to spare and
+        dispatched nothing. ``reason`` is one of a closed list:
+        ``mixed_step`` (the step in flight pushes a prompt chunk),
+        ``row_ends`` (a row's last token comes before the step would run),
+        ``admission`` (the scheduler would admit the head of the queue at
+        that step), ``pool`` (the rows' next pages do not fit without a
+        preemption), ``row_condition`` (a stop token, a deadline or a
+        window's end on a row), ``other`` (a drafter or a fault plan, a row
+        that left in flight, a dispatch that failed)."""
+        self.speculate_refusals[reason] += 1
+
     def observe_eva_step(self, window_fills, summary_share: float) -> None:
         """One step of a windowed model: ``window_fills`` the share of the
         window's exact positions each of its rows attends over,
@@ -703,6 +733,7 @@ class ServingMetrics:
     def observe_step_latency(self, seconds: float) -> None:
         """Wall time of one whole engine step (any kind) — the flight
         recorder's and the step-latency histogram's shared source."""
+        self.committed_steps += 1
         self.step_latency_s.append(seconds)
         self._tick("serve.step_latency_s", seconds)
 
@@ -1020,6 +1051,10 @@ class ServingMetrics:
             "emit_delay_ms_p50": ms(_percentile(self.emit_delay_s, 50)),
             "emit_delay_ms_p99": ms(_percentile(self.emit_delay_s, 99)),
             "overlap_rebuilds": self.overlap_rebuilds,
+            "adopted_step_share": (self.adopted_steps / self.committed_steps)
+            if self.committed_steps else 0.0,
+            **{f"speculate_refused_{reason}": n
+               for reason, n in self.speculate_refusals.items()},
             "sampled_step_share": (self.sampled_steps / self.dispatched_steps)
             if self.dispatched_steps else 0.0,
             "step_latency_ms_p50": ms(_percentile(self.step_latency_s, 50)),
@@ -1101,6 +1136,12 @@ class ServingMetrics:
             families.append({"name": name, "type": mtype, "help": help_,
                              "samples": [("", {}, float(getattr(self,
                                                                 attr)))]})
+        families.append({
+            "name": "tnn_serve_speculate_refusals_total", "type": "counter",
+            "help": "try_speculate calls with a step in flight and depth to "
+                    "spare that dispatched nothing, by reason",
+            "samples": [("", {"reason": reason}, float(n))
+                        for reason, n in self.speculate_refusals.items()]})
         families.append({
             "name": "tnn_serve_queue_depth", "type": "gauge",
             "help": "Waiting requests at the last engine step",

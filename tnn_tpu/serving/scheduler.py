@@ -232,53 +232,38 @@ class Scheduler:
 
     # -- planning -------------------------------------------------------------
 
-    def _headroom(self, pool) -> int:
+    def _headroom(self, pool, spare: int = 0) -> int:
         """Blocks an admission may plan against. A windowed pool admits a
         request only if it fits TO ITS LAST TOKEN beside what the running
         requests may still take (their exact window whole, a summary row for
         every chunk to come): exact pages come and go with the windows, so
         "fits now" says nothing, and a steady run never preempts. A pool
-        of two page groups likewise: its window pages come and go."""
+        of two page groups likewise: its window pages come and go.
+
+        ``spare``: blocks the caller took for the running rows AHEAD of the
+        step being planned (``would_admit``). A plain pool counts them back
+        in; here a block a row holds early is a block it no longer owes, so
+        the sum stands as it is."""
         if pool.window is None and not pool.sliding:
-            return pool.num_allocatable
+            return pool.num_allocatable + spare
         owed = sum(
             pool.lifetime_blocks(len(r.prompt) + r.max_new_tokens)
             - len(r.block_table) - len(r.summary_table)
             - len(r.window_table) for r in self.running)
         return pool.num_allocatable - owed
 
-    def schedule(self, pool) -> StepPlan:
-        """Plan one engine step: which queued requests to admit, and the
-        running set to decode. Admission is strictly FCFS — a blocked
-        queue head blocks everyone behind it (no out-of-order admission, so
-        no starvation).
-
-        Called only from the engine's build phase against COMMITTED state:
-        under the overlapped loop every prior step's commit has already
-        adopted its pool pages and scheduler transitions before the next
-        ``schedule`` runs, so planning never sees a half-applied step.
-
-        Sarathi-style step packing: each decode-phase running row costs 1
-        budget token; running rows still mid-prefill take up to chunk_size
-        more of their prompt; what's left admits queued requests at chunk
-        granularity (FCFS). The oldest mid-prefill row always advances at
-        least one token, so held blocks are never idle; a sole request is
-        always admitted even with budget < 1 (it could never start
-        otherwise).
-
-        With a prefix cache, admission probes the index first: cached
-        prompt positions cost no chunk budget (their KV is already
-        resident) and matched blocks cost no new allocation — only the
-        uncached tail is budgeted. Reviving an EVICTABLE matched block does
-        consume reclaimable capacity, so it is counted against
-        ``pool.num_allocatable`` alongside fresh blocks."""
+    def _plan_running(self, pool):
+        """The running rows' part of a step: (chunk grants of the rows still
+        mid-prefill, the token budget left for admissions). Each
+        decode-phase row costs 1 budget token (plus ``spec_tokens`` drafted
+        candidates scored alongside it); rows mid-prefill take up to
+        chunk_size more of their prompt, and the oldest always advances at
+        least one token."""
         chunks: Dict[int, int] = {}
         budget = self.token_budget
         prefilling: List[Request] = []
         for req in self.running:
             if req.cache_len >= req.prefill_len:
-                # decode-phase row: one token this step, plus up to
-                # spec_tokens drafted candidates scored alongside it
                 budget -= 1 + self.spec_tokens
             else:
                 prefilling.append(req)
@@ -291,36 +276,99 @@ class Scheduler:
                 continue
             chunks[req.rid] = take
             budget -= take
+        return chunks, budget
+
+    def _has_free_row(self, admitted: int = 0) -> bool:
+        """Somebody waits and a row is free beside the running requests and
+        the ``admitted`` ones of the step being planned."""
+        return bool(self.waiting) and \
+            len(self.running) + admitted < self.max_batch_size
+
+    def _admission(self, pool, admitted: int, budget: int, planned: int,
+                   spare: int = 0):
+        """The admission test for the head of the queue, given a free row:
+        (first chunk, blocks the plan pays) when the step would admit it
+        after ``admitted`` others that took ``planned`` blocks, None when
+        the budget or the pool says no. Reads only: nothing leaves the
+        queue, no request is written to, the prefix cache is probed.
+
+        With a prefix cache, cached prompt positions cost no chunk budget
+        (their KV is already resident) and matched blocks cost no new
+        allocation — only the uncached tail is budgeted. Reviving an
+        EVICTABLE matched block does consume reclaimable capacity, so it is
+        counted against the headroom alongside fresh blocks."""
+        req = self.waiting[0]
+        sole = not self.running and not admitted
+        if budget < 1 and not sole:
+            return None
+        tokens = req.resume_tokens
+        cached = forked = revive = 0
+        if self.prefix_cache is not None:
+            mb, cached, cow = self.prefix_cache.probe(tokens)
+            if mb:
+                # a full-cover hit forks all but the last matched block
+                # (the engine gives that one a fresh COW copy, counted
+                # in nb below via blocks_for - forked)
+                shared = mb[:-1] if cow else mb
+                forked = len(shared)
+                revive = sum(1 for b in shared if pool.is_evictable(b))
+        take = min(self.chunk_size, len(tokens) - cached,
+                   max(budget, 1), pool.room_in_window(cached))
+        nb = pool.admission_blocks(
+            cached + take, len(req.prompt) + req.max_new_tokens) - forked
+        if planned + nb + revive > self._headroom(pool, spare):
+            return None
+        return take, nb + revive
+
+    def would_admit(self, pool, spare: int = 0) -> bool:
+        """Whether ``schedule`` would admit the head of the queue into the
+        step after the one the running rows are in, by ``schedule``'s own
+        arithmetic and moving nothing: what the engine asks before it
+        dispatches that step ahead of time, and again before it adopts it
+        (``InferenceEngine.try_speculate``). An empty queue answers at once,
+        a full batch without touching the pool. ``spare`` are the blocks the
+        engine has already taken for the running rows' steps ahead, which
+        ``schedule`` would still have found free (``_headroom``)."""
+        if not self._has_free_row():
+            return False
+        _, budget = self._plan_running(pool)
+        return self._admission(pool, 0, budget, 0, spare) is not None
+
+    def schedule(self, pool) -> StepPlan:
+        """Plan one engine step: which queued requests to admit, and the
+        running set to decode. Admission is strictly FCFS — a blocked
+        queue head blocks everyone behind it (no out-of-order admission, so
+        no starvation).
+
+        Called only from the engine's build phase against COMMITTED state:
+        under the overlapped loop every prior step's commit has already
+        adopted its pool pages and scheduler transitions before the next
+        ``schedule`` runs, so planning never sees a half-applied step. A
+        step dispatched ahead of its predecessor's commit never comes
+        here: it is legal only while ``would_admit`` says this method
+        would admit nothing, waiting requests or none.
+
+        Sarathi-style step packing (``_plan_running``): each decode-phase
+        running row costs 1 budget token; running rows still mid-prefill
+        take up to chunk_size more of their prompt; what's left admits
+        queued requests at chunk granularity (FCFS, ``_admission``), while
+        a row is free. A sole request is always admitted even with
+        budget < 1 (it could never start otherwise)."""
+        chunks, budget = self._plan_running(pool)
         prefills: List[Request] = []
         planned_blocks = 0
-        while self.waiting and \
-                len(self.running) + len(prefills) < self.max_batch_size:
-            req = self.waiting[0]
-            total = len(req.resume_tokens)
-            sole = not self.running and not prefills
-            if budget < 1 and not sole:
+        while self._has_free_row(len(prefills)):
+            fit = self._admission(pool, len(prefills), budget,
+                                  planned_blocks)
+            if fit is None:
                 break
-            cached = forked = revive = 0
-            if self.prefix_cache is not None:
-                mb, cached, cow = self.prefix_cache.probe(req.resume_tokens)
-                if mb:
-                    # a full-cover hit forks all but the last matched block
-                    # (the engine gives that one a fresh COW copy, counted
-                    # in nb below via blocks_for - forked)
-                    shared = mb[:-1] if cow else mb
-                    forked = len(shared)
-                    revive = sum(1 for b in shared if pool.is_evictable(b))
-            take = min(self.chunk_size, total - cached, max(budget, 1),
-                       pool.room_in_window(cached))
-            nb = pool.admission_blocks(
-                cached + take, len(req.prompt) + req.max_new_tokens) - forked
-            if planned_blocks + nb + revive > self._headroom(pool):
-                break
-            req.prefill_len = total
+            take, cost = fit
+            req = self.waiting.popleft()
+            req.prefill_len = len(req.resume_tokens)
             chunks[req.rid] = take
             budget -= take
-            planned_blocks += nb + revive
-            prefills.append(self.waiting.popleft())
+            planned_blocks += cost
+            prefills.append(req)
         return StepPlan(prefills=prefills, decodes=list(self.running),
                         chunks=chunks)
 
