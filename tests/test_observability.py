@@ -20,12 +20,21 @@ PR 24 adds the second sink and the device-side names: under
 spans land in the profile's host plane with their attributes as stats, and
 the lowered step programs carry every scope and kernel name of
 docs/observability.md's catalog.
+
+PR 39 accounts for the step boundary: the spans it adds (``serve.put`` /
+``serve.launch`` inside ``serve.dispatch``, ``serve.speculate`` round a step
+dispatched ahead) nest and tile the overlapped worker's time, the counters
+beside them are in ``summary()`` and ``EXPOSITION``, the front end's empty
+polls say how late the machine let them be, and what a step pays for all of
+it with no profiler session is measured here.
 """
 import glob
 import json
 import os
 import re
+import statistics
 import threading
+import time
 from unittest import mock
 
 import numpy as np
@@ -438,7 +447,7 @@ class TestProfilerSink:
         for name in PHASES:
             assert by_name.get(name), f"no {name} event in the host plane"
             assert all("step" in ev[4] for ev in by_name[name]), name
-        assert {"kind", "key"} <= set(by_name["serve.dispatch"][0][4])
+        assert {"kind", "program"} <= set(by_name["serve.dispatch"][0][4])
         # a paged step program says what a grid step of its kernel fetches:
         # 12 table entries (all of a row's 48 positions) of the ONE page row
         # that holds both heads of 16 side by side (``kv_lane_pack`` 2)
@@ -545,6 +554,266 @@ class TestProfilerSink:
         n = len(seen["before"].emit_delay_s) + len(seen["swapped"].emit_delay_s)
         assert n == 6 and len(seen["swapped"].emit_delay_s) >= 1
         assert seen["swapped"].summary()["emit_delay_ms_p50"] >= 0.0
+
+
+# ------------------------------------ PR 39: the step boundary's account ----
+
+NEW_KEYS = ("put_ms_p50", "launch_ms_p50", "step_latency_ms_mean",
+            "step_latency_ms_max", "speculate_refused_mixed_step_share",
+            "front_late_ms_total", "front_late_ms_max")
+REFUSALS = ("mixed_step", "row_ends", "admission", "pool", "row_condition",
+            "other")
+
+
+def _overlap_run(model, params, *, trace):
+    """A plain workload through the overlapped loop (every family takes
+    it): prompts of two chunks, outputs that end at different steps, so
+    mixed steps, steps built, steps dispatched ahead and refusals all
+    occur."""
+    eng = InferenceEngine(model, params, overlap=True, prefix_cache=False,
+                          chunk_size=8, trace=trace, **KW)
+    rng = np.random.default_rng(5)
+    rids = [eng.submit(rng.integers(0, 128, n).astype(np.int32), new)
+            for n, new in ((11, 6), (9, 10), (13, 8))]
+    out = eng.run_until_complete()
+    return [out[r] for r in rids], eng
+
+
+def _inside(inner, outer):
+    return outer[2] <= inner[2] and inner[3] <= outer[3]
+
+
+class TestStepBoundary:
+    @pytest.mark.parametrize("family", ["gpt2", "llama", "mistral"])
+    def test_traced_equals_untraced_with_overlap_on(self, lm, family):
+        model, params = lm
+        on, eng = _overlap_run(model, params, trace=True)
+        off, plain = _overlap_run(model, params, trace=False)
+        assert on == off, f"tracing changed tokens of the {family} model"
+        names = [ev.name for ev in eng.profiler.events]
+        for base in ("serve.put", "serve.launch", "serve.speculate"):
+            assert any(n.startswith(base + " ") for n in names), base
+        dispatches = [n for n in names if n.startswith("serve.dispatch ")]
+        assert any(" ahead=1 " in n for n in dispatches)
+        # a dispatch names its program as the device profile will
+        assert any(" program=tnn_serve_decode " in n for n in dispatches)
+        assert any(" program=tnn_serve_mixed_w8 " in n for n in dispatches)
+        assert all(" program=tnn_serve_" in n for n in dispatches)
+        assert plain.metrics.summary()["adopted_step_share"] > 0
+
+    def test_sub_spans_nest_and_tile_the_overlapped_worker(
+            self, tiny_lm, tmp_path):
+        """Under a profiler session the overlapped engine leaves, on one
+        thread: ``serve.put`` / ``serve.launch`` inside ``serve.dispatch``
+        (a mixed step's token matrix: a ``serve.put`` inside
+        ``serve.build``), ``serve.speculate`` round the dispatch of a step
+        that goes out ahead, every one with ``step``; no two spans overlap
+        but one lies inside the other, so the innermost level is
+        disjoint."""
+        model, params = tiny_lm
+        _overlap_run(model, params, trace=False)     # compile outside
+        _start_trace(tmp_path)
+        try:
+            _, eng = _overlap_run(model, params, trace=False)
+        finally:
+            jax.profiler.stop_trace()
+        evs = [ev for ev in _host_events(tmp_path) if ev[3] > ev[2]]
+        by = {}
+        for ev in evs:
+            by.setdefault(ev[1], []).append(ev)
+        for name in PHASES + ("serve.put", "serve.launch",
+                              "serve.speculate"):
+            assert by.get(name), f"no {name} event in the host plane"
+            assert all("step" in ev[4] for ev in by[name]), name
+        assert len({ev[0] for ev in evs if ev[1].startswith("serve.")}) == 1
+
+        def parents(ev, names):
+            return [o for n in names for o in by[n] if _inside(ev, o)
+                    and o[4]["step"] == ev[4]["step"]]
+
+        assert all(len(parents(ev, ["serve.dispatch"])) == 1
+                   for ev in by["serve.launch"])
+        assert len(by["serve.launch"]) == len(by["serve.dispatch"])
+        in_build = [ev for ev in by["serve.put"]
+                    if parents(ev, ["serve.build"])]
+        assert all(len(parents(ev, ["serve.dispatch", "serve.build"])) == 1
+                   for ev in by["serve.put"])
+        mixed = [ev for ev in by["serve.dispatch"]
+                 if ev[4]["kind"] == "mixed"]
+        assert len(in_build) == len(mixed) > 0
+        # serve.dispatch says how far ahead of the committed state it went,
+        # and which program it launched
+        assert all("ahead" in ev[4] for ev in by["serve.dispatch"])
+        assert {ev[4]["program"] for ev in by["serve.dispatch"]} \
+            == {"tnn_serve_decode", "tnn_serve_mixed_w8"}
+        ahead = [ev for ev in by["serve.dispatch"] if int(ev[4]["ahead"])]
+        assert ahead and all(len(parents(ev, ["serve.speculate"])) == 1
+                             for ev in ahead)
+        # a serve.speculate either holds the dispatch of the step it names
+        # or was refused, and the registry counted why
+        went = [ev for ev in by["serve.speculate"]
+                if any(_inside(d, ev) for d in ahead)]
+        assert len(went) == len(ahead)
+        s = eng.metrics.summary()
+        assert len(by["serve.speculate"]) - len(went) == sum(
+            s[f"speculate_refused_{r}"] for r in REFUSALS)
+        assert s["speculate_refused_mixed_step"] > 0
+        # laminar: any two spans of the worker are disjoint or nested
+        spans = sorted((ev for ev in evs if ev[1].startswith("serve.")),
+                       key=lambda ev: (ev[2], -ev[3]))
+        for i, a in enumerate(spans):
+            for b in spans[i + 1:]:
+                if b[2] >= a[3]:
+                    break
+                assert b[3] <= a[3], (a[1], b[1])
+        # between two fetches the worker is in a span most of the time
+        # again: what is left unnamed is the loop's own few lines
+        fetches = sorted(by["serve.fetch"], key=lambda ev: ev[2])
+        top = [ev for ev in spans if not any(
+            _inside(ev, o) and o is not ev for o in spans)]
+        covered = sum(ev[3] - ev[2] for ev in top
+                      if fetches[0][2] <= ev[2] and ev[3] <= fetches[-1][3])
+        assert covered > 0.8 * (fetches[-1][3] - fetches[0][2])
+
+    def test_new_summary_keys_and_their_exposition(self, tiny_lm):
+        fresh = ServingMetrics()
+        s = fresh.summary()
+        assert all(s[k] == 0.0 for k in NEW_KEYS)
+        for key, summary_key in (("serve.put_s", "put_ms_p50"),
+                                 ("serve.launch_s", "launch_ms_p50"),
+                                 ("serve.front_late_s",
+                                  "front_late_ms_total")):
+            assert EXPOSITION[key][3] == summary_key
+        text = render_prometheus(fresh.prometheus_series())
+        for fam in ("tnn_serve_put_seconds_total",
+                    "tnn_serve_launch_seconds_total",
+                    "tnn_serve_front_late_seconds_total",
+                    "tnn_serve_step_latency_max_seconds",
+                    "tnn_serve_front_late_max_seconds"):
+            assert f"# TYPE {fam}" in text, fam
+        # ONE decode step into a fresh registry: its put and its launch are
+        # two halves of its dispatch, whose length the Profiler sink holds
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, trace=True, **KW)
+        eng.submit(np.arange(5, dtype=np.int32), 6)
+        eng.step()
+        eng.step()
+        eng.metrics = ServingMetrics()
+        before = len(eng.profiler.events)
+        eng.step()
+        s = eng.metrics.summary()
+        dispatch = [ev for ev in eng.profiler.events[before:]
+                    if ev.name.startswith("serve.dispatch")]
+        assert len(dispatch) == 1 and "kind=decode_paged" in dispatch[0].name
+        assert 0 < s["put_ms_p50"] and 0 < s["launch_ms_p50"]
+        assert s["put_ms_p50"] + s["launch_ms_p50"] \
+            <= 1e3 * dispatch[0].duration
+        assert s["step_latency_ms_mean"] == pytest.approx(
+            s["step_latency_ms_max"]) and s["step_latency_ms_max"] > 0
+        # the mean is exact over every step, the share is over steps committed
+        m = ServingMetrics(reservoir_size=4)
+        for i in range(100):
+            m.observe_step_latency(0.001 * (1 + i % 10))
+        for _ in range(30):
+            m.observe_speculate_refusal("mixed_step")
+        s = m.summary()
+        assert s["step_latency_ms_mean"] == pytest.approx(5.5)
+        assert s["step_latency_ms_max"] == pytest.approx(10.0)
+        assert s["speculate_refused_mixed_step_share"] == pytest.approx(0.3)
+
+    def test_front_end_counts_how_late_its_empty_polls_came_back(
+            self, tiny_lm, monkeypatch, capsys):
+        """An empty poll of stdin that comes back late counts by how much;
+        one that returned lines counts nothing, however late."""
+        import argparse
+
+        import tnn_tpu.cli.serve as serve_cli
+
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **KW)
+        sup = EngineSupervisor(eng)
+        rfd, wfd = os.pipe()
+        real, seen = serve_cli._read_stdin_lines, {"empty": 0, "late": []}
+
+        def slow(fd, pending, timeout):
+            out = real(fd, pending, timeout)
+            if out[0] or out[2]:
+                time.sleep(0.04)        # lines (or EOF), late: not counted
+            else:
+                seen["empty"] += 1
+                if seen["empty"] == 1:
+                    time.sleep(0.03)    # an empty poll, 30 ms late
+            return out
+
+        observe = ServingMetrics.observe_front_late
+        monkeypatch.setattr(serve_cli, "_read_stdin_lines", slow)
+        monkeypatch.setattr(
+            ServingMetrics, "observe_front_late",
+            lambda self, s: (seen["late"].append(s), observe(self, s))[1])
+
+        def feed():
+            with os.fdopen(wfd, "w") as w:
+                w.write(json.dumps({"id": "a", "tokens": [1, 2, 3],
+                                    "max_new_tokens": 3}) + "\n")
+                w.flush()
+                time.sleep(0.25)        # the front end polls, and finds nothing
+
+        with os.fdopen(rfd, "r") as rd:
+            monkeypatch.setattr("sys.stdin", rd)
+            t = threading.Thread(target=feed)
+            t.start()
+            rc = serve_cli._serve_stdin(
+                sup, model, None,
+                argparse.Namespace(max_new_tokens=3, deadline_s=0.0))
+            t.join(10.0)
+        assert rc == 0 and not t.is_alive()
+        capsys.readouterr()
+        assert seen["empty"] >= 1 and len(seen["late"]) == seen["empty"]
+        assert seen["late"][0] >= 0.03 and min(seen["late"]) >= 0.0
+        s = eng.metrics.summary()
+        assert s["front_late_ms_max"] == pytest.approx(1e3 * max(seen["late"]))
+        assert s["front_late_ms_total"] == pytest.approx(
+            1e3 * sum(seen["late"]))
+
+    def test_what_the_added_spans_cost_a_step_with_no_session(
+            self, tiny_lm, monkeypatch):
+        """With nobody recording, what the engine's own ``_dispatch`` and
+        ``try_speculate`` take around a program and a build that do nothing:
+        the spans (``serve.dispatch`` with ``serve.put`` / ``serve.launch``,
+        ``serve.speculate``), the clock reads, the per-key attributes'
+        lookup, the samples. That is all a step pays for the account, with
+        what ``serve.dispatch`` cost before it; a mixed step adds the one
+        annotation round its token matrix. The bound is one no loaded test
+        machine can miss; the number itself is printed (``pytest -s``) and
+        reported in CHANGES.md (under 20 us on an idle machine)."""
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, overlap=True, **KW)
+        eng.submit(np.arange(5, dtype=np.int32), 12)
+        eng.step()
+        eng.step()
+        eng.begin_step()                # a decode step in flight
+        temps = np.zeros(KW["max_batch_size"], np.float32)
+        refusals = iter(["", "row_ends"] * 200)
+
+        def program(*args):
+            return None
+
+        def a_step_ahead_that_builds_nothing(flight):
+            eng._dispatch(program, "decode_paged", temps, 1, tuple, ahead=1)
+            return next(refusals)
+
+        monkeypatch.setattr(eng, "_dispatch_ahead",
+                            a_step_ahead_that_builds_nothing)
+        samples = []
+        for _ in range(200):
+            t0 = time.perf_counter()
+            eng.try_speculate()
+            samples.append(time.perf_counter() - t0)
+        median_us = 1e6 * statistics.median(samples)
+        print(f"_dispatch inside try_speculate, no session, nothing to put "
+              f"or launch: {median_us:.2f} us a step")
+        assert median_us < 100
+        assert eng.metrics.summary()["speculate_refused_row_ends"] == 100
 
 
 # ------------------------------- PR 24: names that survive a refactor ----

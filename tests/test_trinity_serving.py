@@ -562,15 +562,27 @@ def test_deep_speculation_rides_over_releases(model, weights, monkeypatch):
 
 # -- spans and counters -------------------------------------------------------------------------
 
-def test_dispatch_says_the_pages_by_kind_and_releases_are_instants(
+def test_blocks_held_by_kind_are_the_tables_and_releases_are_instants(
         model, weights):
     eng = engine(model, weights[1], trace=True)
     eng.submit(np.arange(20, dtype=np.int32), 30)
+    for _ in range(6):
+        eng.step()
+    # the blocks held by kind are what the pool and the requests' two tables
+    # say (``win_pool_occupancy_max`` / ``pool_occupancy_max`` count them):
+    # no span walks the rows' tables to say it again at every dispatch
+    rows = eng.scheduler.running
+    full = sum(len(r.block_table) for r in rows)
+    window = sum(len(r.window_table) for r in rows)
+    assert full > 0 and window > 0
+    assert eng.pool.num_allocated == full + window
     eng.run_until_complete()
     names = [ev.name for ev in eng.profiler.events]
     spans = [n for n in names if n.startswith("serve.dispatch")]
-    assert spans and all("pages_by_kind=full:" in n and "experts_held=8" in n
+    assert spans and all("experts_held=8" in n and "pages_by_kind" not in n
                          and "attn_pages" not in n for n in spans)
+    assert eng.metrics.summary()["win_pool_occupancy_max"] \
+        >= window / eng.pool.capacity
     rel = [n for n in names if n.startswith("serve.win_release")]
     assert rel and all("pages=4" in n for n in rel)
     assert eng.metrics.summary()["win_pages_released"] == 4 * len(rel)

@@ -76,6 +76,9 @@ def _emit(obj):
     sys.stdout.flush()
 
 
+POLL_S = 0.05       # one poll of stdin by the JSON-lines front end
+
+
 def _read_stdin_lines(fd: int, pending: bytes, timeout: float):
     """Lines that arrived on ``fd`` within ``timeout``: (lines, pending, eof).
 
@@ -585,7 +588,11 @@ def _serve_stdin(supervisor, model, tokenizer, args):
     the stdin poll, ``front.submit`` one request line, ``front.flush`` the
     events written out), and every ``token`` event is stamped when the
     worker hands it over, right after its step's commit, so a flush can
-    record how long it waited (``observe_emit_delay``)."""
+    record how long it waited (``observe_emit_delay``). A poll of stdin
+    that comes back EMPTY slept in ``select`` for ``POLL_S`` and touched no
+    device: what it took beyond that, the machine took from every thread
+    (``observe_front_late``; long at the same instant as a step is the
+    machine, a long step alone is the device or the runtime)."""
     out_q: "queue.Queue" = queue.Queue()
     supervisor.event_sink = lambda ev: out_q.put((time.perf_counter(), ev))
 
@@ -653,12 +660,15 @@ def _serve_stdin(supervisor, model, tokenizer, args):
             out["text"] = tokenizer.decode(ev["tokens"])
         _emit(out)
 
+    def current_metrics():
+        # the engine's CURRENT registry, looked up at every use: a caller
+        # may swap it to mark a window (never one captured at start-up)
+        return getattr(supervisor, "engine", supervisor).metrics
+
     def flush_events():
         if out_q.empty():
             return
-        # the engine's CURRENT registry, looked up at every flush: a caller
-        # may swap it to mark a window (never one captured at start-up)
-        metrics = getattr(supervisor, "engine", supervisor).metrics
+        metrics = current_metrics()
         n = 0
         with span("front.flush") as flush:
             while True:
@@ -690,8 +700,12 @@ def _serve_stdin(supervisor, model, tokenizer, args):
             if eof or supervisor.draining:
                 supervisor.join(0.05)  # drain in progress: just wait
                 continue
+            t_poll = time.perf_counter()
             with span("front.read"):
-                lines, pending, eof = _read_stdin_lines(fd, pending, 0.05)
+                lines, pending, eof = _read_stdin_lines(fd, pending, POLL_S)
+            if not lines and not eof:
+                current_metrics().observe_front_late(
+                    max(0.0, time.perf_counter() - t_poll - POLL_S))
             for raw in lines:
                 if raw.strip():
                     handle_line(raw.decode(errors="replace"))

@@ -588,6 +588,8 @@ class InferenceEngine:
         # the open phase of the worker's time (``_open_phase``): (span,
         # start, observer) of a serve.build or serve.commit in progress
         self._phase: Optional[tuple] = None
+        # seconds of ``serve.put`` a step spent before its launch's own
+        self._put_pending = 0.0
         # last step's wall time, exposed through the health gauges so the
         # router's health scoring can see a gray-slow replica without ever
         # reaching into the engine
@@ -602,6 +604,10 @@ class InferenceEngine:
         self._key = jax.device_put(jax.random.PRNGKey(seed), device)
         self._jit: Dict[Any, Any] = {}
         self._attn_groups: Dict[int, Any] = {}   # step width -> _attn_group
+        # compiled program -> the name ``_jit_step`` gave it, and what a
+        # serve.dispatch says of it
+        self._program_names: Dict[Any, str] = {}
+        self._dispatch_attrs: Dict[Any, Dict[str, Any]] = {}
         # TNN_DEBUG_SYNC=1: run every step under jax.transfer_guard
         # ("disallow") — the dynamic complement to tnnlint's static
         # host-sync-in-step-path rule. All intentional step inputs go
@@ -1136,40 +1142,66 @@ class InferenceEngine:
             getattr(self.metrics, observe)(time.perf_counter() - t0)
             sp.__exit__(None, None, None)
 
-    def _dispatch_span(self, kind: str, key, temps: np.ndarray,
-                       step: Optional[int] = None, qw: Optional[int] = None):
-        """``serve.dispatch``: the ENQUEUE of one compiled program (its
-        inputs' ``device_put`` and the asynchronous launch), not its
-        compute — that is the device's, and shows as ``serve.fetch``. A
-        paged step program ``qw`` tokens wide also says what a grid step of
-        its attention kernel fetches (``attn_pages``, ``attn_heads``) and
-        how many KV heads lie side by side in a page row of the pool
-        (``kv_lane_pack``). ``temps`` are the step's temperatures as packed:
-        ``sampled_rows`` of them ask for a draw, and a step with none runs
-        the sampler's argmax alone (``sampling.sample_ragged``)."""
+    def _dispatch(self, fn, kind: str, temps: np.ndarray, qw: int,
+                  stage: Callable[[], tuple], ahead: int = 0):
+        """``serve.dispatch``: the ENQUEUE of one compiled step program, not
+        its compute (that is the device's, and shows as ``serve.fetch``), in
+        its two halves: ``serve.put``, every ``device_put`` of the launch
+        (``stage()`` returns the program's arguments behind the parameters
+        and the pool), and ``serve.launch``, the jitted call alone (its
+        arguments' flattening and the asynchronous enqueue). Their lengths
+        feed ``observe_put`` / ``observe_launch``. ``ahead`` is how many
+        uncommitted steps the program was dispatched behind (0: built after
+        its predecessor's fetch); its ``step`` is the one it will commit as.
+        What a span says of the PROGRAM (``_program_attrs``) is worked out
+        once a compiled program. ``temps`` are the step's temperatures
+        as packed: ``sampled_rows`` of them ask for a draw, and a step with
+        none runs the sampler's argmax alone (``sampling.sample_ragged``)."""
+        step = self.step_seq + ahead
+        staged, self._put_pending = self._put_pending, 0.0
         sampled = int(np.count_nonzero(temps > 0.0))
         self.metrics.observe_step_dispatch(sampled)
-        group = None if qw is None else self._attn_group(qw)
-        attrs = {} if group is None else dict(
-            attn_pages=group[0], attn_heads=group[1],
-            kv_lane_pack=self.pool.lane_pack)
+        attrs = self._dispatch_attrs.get(fn)
+        if attrs is None:
+            attrs = self._dispatch_attrs[fn] = self._program_attrs(fn, qw)
+        with self.tracer.span("serve.dispatch", EventType.COMPUTE, step=step,
+                              kind=kind, ahead=ahead, sampled_rows=sampled,
+                              **attrs):
+            t0 = time.perf_counter()
+            with self.tracer.span("serve.put", step=step):
+                args = stage()
+            t1 = time.perf_counter()
+            with self.tracer.span("serve.launch", EventType.COMPUTE,
+                                  step=step):
+                out = fn(self.params, self.pool.pages_k, self.pool.pages_v,
+                         *args)
+            t2 = time.perf_counter()
+        self.metrics.observe_put(staged + t1 - t0)
+        self.metrics.observe_launch(t2 - t1)
+        return out
+
+    def _program_attrs(self, fn, qw: int) -> Dict[str, Any]:
+        """What a paged step program ``qw`` tokens wide says of itself on
+        its ``serve.dispatch``: its name (``program``: the device profile's
+        ``XLA Modules`` line reads ``jit_<program>``, and the step-boundary
+        reader checks its join of a program to the span that launched it
+        against that); what a grid step of its attention kernel fetches
+        (``attn_pages``, ``attn_heads``) and how many KV heads lie side by
+        side in a page row of the pool (``kv_lane_pack``); a latent model's
+        ONE page array, read once for keys and values (``latent_pages``);
+        the routed experts this chip holds of each layer
+        (``experts_held``)."""
+        attrs: Dict[str, Any] = {"program": self._program_names.get(fn, "")}
+        group = self._attn_group(qw)
         if group is not None and self.pool.latent:
-            # ONE page array, read once for keys and values
-            attrs = dict(latent_pages=group[0])
-        if qw is not None and self.pool.sliding:
-            # blocks held by kind: every running row's global pages and the
-            # window pages it has not yet given back
-            rows = self.scheduler.running
-            attrs = dict(pages_by_kind="full:%d,window:%d" % (
-                sum(len(r.block_table) for r in rows),
-                sum(len(r.window_table) for r in rows)))
+            attrs["latent_pages"] = group[0]
+        elif group is not None:
+            attrs.update(attn_pages=group[0], attn_heads=group[1],
+                         kv_lane_pack=self.pool.lane_pack)
         experts = getattr(self.model, "experts", None)
-        if qw is not None and experts:
+        if experts:
             attrs["experts_held"] = len(experts["held"])
-        return self.tracer.span(
-            "serve.dispatch", EventType.COMPUTE,
-            step=self.step_seq if step is None else step, kind=kind,
-            key=key, sampled_rows=sampled, **attrs)
+        return attrs
 
     @property
     def in_flight(self) -> Optional["StepInFlight"]:
@@ -1267,16 +1299,19 @@ class InferenceEngine:
         ignore ``tables_argnum`` (TP tables are replicated)."""
         fn.__name__ = name
         if self._sp is not None:
-            return self._sp.jit_step(
+            jitted = self._sp.jit_step(
                 fn, donate_argnums=donate_argnums, n_outs=n_outs,
                 pages_argnums=pages_argnums, pages_out=pages_out,
                 params_argnum=params_argnum, tables_argnum=tables_argnum)
-        if self._tp is None:
-            return jax.jit(fn, donate_argnums=donate_argnums)
-        return self._tp.jit_step(
-            fn, donate_argnums=donate_argnums, n_outs=n_outs,
-            pages_argnums=pages_argnums, pages_out=pages_out,
-            params_argnum=params_argnum)
+        elif self._tp is None:
+            jitted = jax.jit(fn, donate_argnums=donate_argnums)
+        else:
+            jitted = self._tp.jit_step(
+                fn, donate_argnums=donate_argnums, n_outs=n_outs,
+                pages_argnums=pages_argnums, pages_out=pages_out,
+                params_argnum=params_argnum)
+        self._program_names[jitted] = name
+        return jitted
 
     def _build_step(self, flight: "StepInFlight") -> None:
         """The build/dispatch phase: everything up to and including the
@@ -1424,7 +1459,9 @@ class InferenceEngine:
         if (not self.overlap or flight is None or flight.done
                 or len(flight.ahead) >= self._speculate_depth()):
             return False
-        refusal = self._dispatch_ahead(flight)
+        with self.tracer.span("serve.speculate",
+                              step=self.step_seq + len(flight.ahead) + 1):
+            refusal = self._dispatch_ahead(flight)
         if refusal:
             self.metrics.observe_speculate_refusal(refusal)
         return not refusal
@@ -1499,14 +1536,13 @@ class InferenceEngine:
         t0 = time.perf_counter()
         prev_tok = rec["dev"][0]    # its predecessor's unfetched samples
         try:
-            with self._sync_guard(), \
-                    self._dispatch_span(label, key, step.temps,
-                                        self.step_seq + j, qw=1):
-                newtok, ok, pk, pv, *experts = fn(
-                    self.params, self.pool.pages_k, self.pool.pages_v,
-                    prev_tok, self._put(offsets), self._put_tables(step.tables),
-                    self._put(step.temps), self._put(step.topks),
-                    self._put(step.topps), step_key, self._put(step.poison))
+            with self._sync_guard():
+                newtok, ok, pk, pv, *experts = self._dispatch(
+                    fn, label, step.temps, 1, lambda: (
+                        prev_tok, self._put(offsets),
+                        self._put_tables(step.tables), self._put(step.temps),
+                        self._put(step.topks), self._put(step.topps),
+                        step_key, self._put(step.poison)), ahead=j)
         except Exception:  # noqa: BLE001 — speculation must never hurt
             self._unextend(rollback)
             self._reuse_keys.insert(0, step_key)
@@ -2314,19 +2350,25 @@ class InferenceEngine:
             fn = self._jit[key] = (
                 self._spec_paged_fn(b, qw, step.nb) if spec_on
                 else self._mixed_paged_fn(b, qw, step.nb))
-        toks_in = self._put(step.toks)
-        for i, dd in step.dev_drafts:
-            # splice device-resident drafts into the token matrix without
-            # fetching them. The commit reads draft VALUES back from the
-            # fetched token matrix, so host and device drafts commit
-            # identically. Under TP/SP the draft tensor (produced on the
-            # drafter's single device) replicates onto the mesh first —
-            # a device-to-device transfer, no host sync.
-            mesh = self._tp if self._tp is not None else self._sp
-            draft_toks = dd.toks if mesh is None \
-                else mesh.put_replicated(dd.toks)
-            toks_in = _splice_draft_row(toks_in, draft_toks[None, :],
-                                        self._put(i, jnp.int32))
+        # the token matrix is staged here, inside serve.build, because the
+        # commit wants it back (``rec["dev"]``): a serve.put of its own,
+        # counted into the step's put time at its launch
+        t_put = time.perf_counter()
+        with self.tracer.span("serve.put", step=self.step_seq):
+            toks_in = self._put(step.toks)
+            for i, dd in step.dev_drafts:
+                # splice device-resident drafts into the token matrix
+                # without fetching them. The commit reads draft VALUES back
+                # from the fetched token matrix, so host and device drafts
+                # commit identically. Under TP/SP the draft tensor (produced
+                # on the drafter's single device) replicates onto the mesh
+                # first — a device-to-device transfer, no host sync.
+                mesh = self._tp if self._tp is not None else self._sp
+                draft_toks = dd.toks if mesh is None \
+                    else mesh.put_replicated(dd.toks)
+                toks_in = _splice_draft_row(toks_in, draft_toks[None, :],
+                                            self._put(i, jnp.int32))
+        self._put_pending = time.perf_counter() - t_put
         # one key per STEP (held across the retry): a transient fault retried
         # with the same key reproduces the fault-free step bit-for-bit
         step_key = self._step_key()
@@ -2336,24 +2378,19 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with self._dispatch_span("spec" if spec_on else "mixed",
-                                         key, step.temps, qw=qw):
-                    if spec_on:
-                        accepts, newtok, ok, pk, pv = fn(
-                            self.params, self.pool.pages_k, self.pool.pages_v,
-                            toks_in, self._put(step.starts),
-                            self._put(step.q_lens), self._put_tables(step.tables),
-                            self._put(step.n_draft), self._put(step.temps),
-                            self._put(step.topks), self._put(step.topps),
-                            step_key, self._put(poison))
-                    else:
-                        newtok, ok, pk, pv, *experts = fn(
-                            self.params, self.pool.pages_k, self.pool.pages_v,
-                            toks_in, self._put(step.starts),
-                            self._put(step.q_lens), self._put_tables(step.tables),
-                            self._put(step.temps), self._put(step.topks),
-                            self._put(step.topps), step_key,
-                            self._put(poison))
+                # a speculative program takes its rows' draft counts too
+                out = self._dispatch(
+                    fn, "spec" if spec_on else "mixed", step.temps, qw,
+                    lambda: (
+                        toks_in, self._put(step.starts),
+                        self._put(step.q_lens), self._put_tables(step.tables),
+                        *((self._put(step.n_draft),) if spec_on else ()),
+                        self._put(step.temps), self._put(step.topks),
+                        self._put(step.topps), step_key, self._put(poison)))
+                if spec_on:
+                    accepts, newtok, ok, pk, pv = out
+                else:
+                    newtok, ok, pk, pv, *experts = out
                 break
             except FaultInjected as e:
                 # injected pre-call: donated buffers untouched, retryable
@@ -2659,13 +2696,12 @@ class InferenceEngine:
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                with self._dispatch_span(label, key, step.temps, qw=1):
-                    newtok, ok, pk, pv, *experts = fn(
-                        self.params, self.pool.pages_k, self.pool.pages_v,
+                newtok, ok, pk, pv, *experts = self._dispatch(
+                    fn, label, step.temps, 1, lambda: (
                         self._put(step.toks), self._put(step.offsets),
                         self._put_tables(step.tables), self._put(step.temps),
                         self._put(step.topks), self._put(step.topps),
-                        step_key, self._put(poison))
+                        step_key, self._put(poison)))
                 break
             except FaultInjected as e:
                 # injected pre-call: donated buffers untouched, retryable

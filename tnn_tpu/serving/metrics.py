@@ -94,11 +94,25 @@ EXPOSITION: Dict[str, Tuple[str, str, str, str]] = {
         "Cumulative host seconds committing a step after its fetch "
         "(serve.commit: pool and scheduler state, stop checks, events)",
         "commit_ms_p50"),
+    "serve.put_s": (
+        "tnn_serve_put_seconds_total", "counter",
+        "Cumulative host seconds staging step inputs on the device "
+        "(serve.put: every device_put of one launch)", "put_ms_p50"),
+    "serve.launch_s": (
+        "tnn_serve_launch_seconds_total", "counter",
+        "Cumulative host seconds inside the jitted call of a step program "
+        "(serve.launch: argument flattening and the asynchronous enqueue)",
+        "launch_ms_p50"),
     "serve.emit_delay_s": (
         "tnn_serve_emit_delay_seconds_total", "counter",
         "Cumulative seconds token events waited between their step's "
         "commit and the front end's flush that wrote them",
         "emit_delay_ms_p50"),
+    "serve.front_late_s": (
+        "tnn_serve_front_late_seconds_total", "counter",
+        "Cumulative seconds by which the front end's empty stdin polls "
+        "overran the time they asked for (a thread that touches no device: "
+        "the machine's stalls seen from inside)", "front_late_ms_total"),
     "serve.overlap_rebuild": (
         "tnn_serve_overlap_rebuilds_total", "counter",
         "Speculatively dispatched steps rolled back on misprediction",
@@ -243,6 +257,10 @@ _DIRECT_FAMILIES: Tuple[Tuple[str, str, str, str], ...] = (
      "Prompt tokens pushed through prefill"),
     ("steps", "tnn_serve_steps_total", "counter",
      "Engine steps executed"),
+    ("step_latency_max_s", "tnn_serve_step_latency_max_seconds", "gauge",
+     "Longest engine step wall time since this registry was created"),
+    ("front_late_max_s", "tnn_serve_front_late_max_seconds", "gauge",
+     "Longest overrun of one empty stdin poll of the front end"),
 )
 
 
@@ -434,6 +452,8 @@ class ServingMetrics:
         self.host_gap_s = res("host_gap_s")
         self.build_s = res("build_s")
         self.commit_s = res("commit_s")
+        self.put_s = res("put_s")
+        self.launch_s = res("launch_s")
         self.emit_delay_s = res("emit_delay_s")
         self.step_latency_s = res("step_latency_s")
         self.queue_wait_s = res("queue_wait_s")
@@ -482,6 +502,9 @@ class ServingMetrics:
         # spare and dispatched nothing, the ONE reason why, by name
         self.adopted_steps = 0
         self.committed_steps = 0
+        self.step_latency_max_s = 0.0
+        # the front end's empty stdin polls: how far past their timeout
+        self.front_late_max_s = 0.0
         self.speculate_refusals: Dict[str, int] = dict.fromkeys(
             ("mixed_step", "row_ends", "admission", "pool", "row_condition",
              "other"), 0)
@@ -626,6 +649,27 @@ class ServingMetrics:
         self.commit_s.append(seconds)
         self._tick("serve.commit_s", seconds)
 
+    def observe_put(self, seconds: float) -> None:
+        """The ``serve.put`` spans of one launch: every ``device_put`` of the
+        step's host arrays (a mixed step's token matrix, staged inside
+        ``serve.build``, included)."""
+        self.put_s.append(seconds)
+        self._tick("serve.put_s", seconds)
+
+    def observe_launch(self, seconds: float) -> None:
+        """One ``serve.launch``: the jitted call alone, from its arguments'
+        flattening to the asynchronous enqueue's return."""
+        self.launch_s.append(seconds)
+        self._tick("serve.launch_s", seconds)
+
+    def observe_front_late(self, seconds: float) -> None:
+        """One stdin poll of the front end that came back empty, by how much
+        it overran the time it asked for. That thread touches no device and
+        sleeps in ``select``: what it loses, the machine took. Called from
+        the front end's thread."""
+        self.front_late_max_s = max(self.front_late_max_s, seconds)
+        self._tick("serve.front_late_s", seconds)
+
     def observe_emit_delay(self, seconds: float) -> None:
         """One ``token`` event's wait between its step's commit and the
         front end's flush that wrote it out: the front-end layer seen from
@@ -735,6 +779,7 @@ class ServingMetrics:
         recorder's and the step-latency histogram's shared source."""
         self.committed_steps += 1
         self.step_latency_s.append(seconds)
+        self.step_latency_max_s = max(self.step_latency_max_s, seconds)
         self._tick("serve.step_latency_s", seconds)
 
     def observe_queue_wait(self, seconds: float) -> None:
@@ -1048,17 +1093,30 @@ class ServingMetrics:
             "build_ms_p99": ms(_percentile(self.build_s, 99)),
             "commit_ms_p50": ms(_percentile(self.commit_s, 50)),
             "commit_ms_p99": ms(_percentile(self.commit_s, 99)),
+            "put_ms_p50": ms(_percentile(self.put_s, 50)),
+            "launch_ms_p50": ms(_percentile(self.launch_s, 50)),
             "emit_delay_ms_p50": ms(_percentile(self.emit_delay_s, 50)),
             "emit_delay_ms_p99": ms(_percentile(self.emit_delay_s, 99)),
+            "front_late_ms_total": ms(self.counters.get(
+                "serve.front_late_s", 0.0)),
+            "front_late_ms_max": ms(self.front_late_max_s),
             "overlap_rebuilds": self.overlap_rebuilds,
             "adopted_step_share": (self.adopted_steps / self.committed_steps)
             if self.committed_steps else 0.0,
             **{f"speculate_refused_{reason}": n
                for reason, n in self.speculate_refusals.items()},
+            "speculate_refused_mixed_step_share": (
+                self.speculate_refusals["mixed_step"] / self.committed_steps)
+            if self.committed_steps else 0.0,
             "sampled_step_share": (self.sampled_steps / self.dispatched_steps)
             if self.dispatched_steps else 0.0,
             "step_latency_ms_p50": ms(_percentile(self.step_latency_s, 50)),
             "step_latency_ms_p99": ms(_percentile(self.step_latency_s, 99)),
+            # exact over every step, not over the reservoir's sample
+            "step_latency_ms_mean": ms(
+                self.counters.get("serve.step_latency_s", 0.0)
+                / self.committed_steps) if self.committed_steps else 0.0,
+            "step_latency_ms_max": ms(self.step_latency_max_s),
             "queue_wait_ms_p50": ms(_percentile(self.queue_wait_s, 50)),
             "queue_wait_ms_p99": ms(_percentile(self.queue_wait_s, 99)),
             "prefill_chunks": self.prefill_chunks,
