@@ -290,8 +290,8 @@ class TestSpeculationDepth:
         model, params = tiny_lm
         eng = InferenceEngine(model, params, **self.KW, overlap=True)
         eng.submit(_prompts()[0], 40)
-        self._turn(eng)                     # the prompt's mixed step
-        depths = []
+        # the prompt's mixed step: the first decode step goes out behind it
+        depths = [self._turn(eng)]
         for _ in range(16):
             monkeypatch.setattr(engine, "SPECULATE_AHEAD_S",
                                 2.5 * eng._last_step_latency_s)
@@ -621,33 +621,37 @@ class TestLoadedServer:
         assert not eng.try_speculate() and not any(said.values())
         a = eng.submit(_prompts()[0], 20)
         eng.begin_step()                            # the prompt's chunk
-        assert not eng.try_speculate()
-        assert said["mixed_step"] == 1 and sum(said.values()) == 1
-        eng.finish_step()
-        eng.begin_step()                            # a decode step, alone
+        assert eng.try_speculate()                  # a plain chunk: no reason
+        assert sum(said.values()) == 0
+        eng.finish_step()                           # a decode step, adopted
         b = eng.submit(_prompts()[1], 30)           # a row is free for it
         assert not eng.try_speculate()
-        assert said["admission"] == 1 and sum(said.values()) == 2
+        assert said["admission"] == 1 and sum(said.values()) == 1
         eng.finish_step()
         c = eng.submit(_prompts()[2], 6)            # the batch is full: waits
-        self._turn(eng)                             # b's chunk beside a
-        assert said["mixed_step"] == 2 and sum(said.values()) == 3
+        assert self._turn(eng) == 1                 # b's chunk beside a
+        assert sum(said.values()) == 1
         for _ in range(4):
             assert self._turn(eng) >= 1             # ... over the queue
-        assert sum(said.values()) == 3              # as deep as it may: none
+        assert sum(said.values()) == 1              # as deep as it may: none
         while eng.requests[a].state.name == "RUNNING":
             self._turn(eng)
         # the chain stopped short of a's last token, once a turn
         assert said["row_ends"] >= 1
-        assert sum(said.values()) == 3 + said["row_ends"]
+        assert sum(said.values()) == 1 + said["row_ends"]
         eng.run_until_complete()
         s = eng.metrics.summary()
-        assert s["speculate_refused_mixed_step"] == said["mixed_step"]
+        assert s["speculate_refused_mixed_step"] == said["mixed_step"] == 0
         assert s["speculate_refused_row_ends"] == said["row_ends"]
         assert 0.0 < s["adopted_step_share"] < 1.0
+        m = eng.metrics
+        assert s["adopted_mixed_steps"] == 0 < s["adopted_decode_steps"] \
+            == m.adopted_steps      # no prompt here is longer than a chunk
         fams = {f["name"]: f for f in eng.metrics.prometheus_series()}
         assert fams["tnn_serve_adopted_steps_total"]["samples"][0][-1] == \
             eng.metrics.adopted_steps
+        assert fams["tnn_serve_adopted_decode_steps_total"]["samples"][0][
+            -1] == m.adopted_steps
         by_reason = {lb["reason"]: v for _, lb, v in
                      fams["tnn_serve_speculate_refusals_total"]["samples"]}
         assert by_reason == {k: float(v) for k, v in said.items()}
@@ -655,20 +659,23 @@ class TestLoadedServer:
 
     def test_a_dry_pool_counts_as_pool(self, tiny_lm):
         """Two rows of 3 tokens hold a block of four each and the pool has
-        one more: the step in flight writes position 3, the next would need
-        a block for each row, which the synchronous loop gets by a
-        preemption and no prediction packs."""
+        one more: the step in flight writes position 3 (it went out behind
+        the prompts' chunks), the next would need a block for each row,
+        which the synchronous loop gets by a preemption and no prediction
+        packs."""
         model, params = tiny_lm
         eng = InferenceEngine(model, params, **dict(
             self.KW, num_blocks=4, max_seq_len=12), overlap=True)
         rids = [eng.submit(np.arange(3, dtype=np.int32) + i, 9)
                 for i in range(2)]
-        self._turn(eng)                             # the prompts' chunks
+        # the prompts' chunks; the step that writes position 3 goes out
+        # behind them and is adopted
+        assert self._turn(eng) == 1
         said = eng.metrics.speculate_refusals
-        assert said["mixed_step"] == sum(said.values()) == 1
-        eng.begin_step()
+        assert sum(said.values()) == 0
+        assert eng.in_flight.note["speculative"]
         assert not eng.try_speculate()
-        assert said["pool"] == 1 and sum(said.values()) == 2
+        assert said["pool"] == 1 and sum(said.values()) == 1
         assert eng.metrics.preemptions == 0
         got = eng.run_until_complete()
         assert eng.metrics.preemptions >= 1
@@ -677,3 +684,314 @@ class TestLoadedServer:
         assert [sync.submit(np.arange(3, dtype=np.int32) + i, 9)
                 for i in range(2)] == rids
         assert got == sync.run_until_complete()
+
+
+@pytest.fixture(scope="module", params=["evabyte_tiny", "trinity_large_tiny"])
+def windowed_lm(request):
+    """A pool whose pages come and go: EVA's exact window of 32 beside
+    chunk summaries, and sliding-window layers (a window of 16) beside
+    global ones over two page groups."""
+    from tnn_tpu import models
+    from tnn_tpu.core.dtypes import DTypePolicy
+
+    model = models.create(request.param, policy=DTypePolicy(
+        io="float32", param="float32", compute="float32"))
+    params = model.init(jax.random.PRNGKey(5), (1, 8))["params"]
+    return model, params
+
+
+@pytest.mark.usefixtures("fast_ramp")
+class TestChainsThroughPrompts:
+    """A step goes out behind a MIXED step too: a row pushing its prompt is
+    its chunk on by the next step and takes the chunk the scheduler's own
+    arithmetic grants there; its prompt done, it decodes from its first
+    sample, still on the device. Chunks of four tokens make every prompt a
+    run of mixed steps."""
+
+    KW = dict(num_blocks=64, block_size=4, max_batch_size=4, max_seq_len=64,
+              prefix_cache=False, chunk_size=4)
+    _turn = staticmethod(TestSpeculationDepth._turn)
+    _sync = staticmethod(TestLoadedServer._sync)
+    _overlapped = TestLoadedServer._overlapped
+    _rows = staticmethod(TestLoadedServer._rows)
+    _tokens = staticmethod(TestLoadedServer._tokens)
+
+    @staticmethod
+    def _work(lengths, seed=6):
+        rng = np.random.default_rng(seed)
+        return [(rng.integers(0, 128, p).astype(np.int32), n)
+                for p, n in lengths]
+
+    def _both(self, lm, work, temperature=0.0, in_flight=None,
+              before_step=None, **kw):
+        """The same work through the synchronous loop and the overlapped
+        one: (sync engine, its log, overlapped engine, its log, depths)."""
+        model, params = lm
+        kw = dict(self.KW, **kw)
+        sync = InferenceEngine(model, params, **kw, overlap=False)
+        rids = [sync.submit(p, n, temperature=temperature) for p, n in work]
+        want = self._sync(sync, before_step=before_step and before_step(sync))
+        eng = InferenceEngine(model, params, **kw, overlap=True)
+        assert [eng.submit(p, n, temperature=temperature)
+                for p, n in work] == rids
+        got, depths = self._overlapped(eng, in_flight and in_flight(eng))
+        return sync, want, eng, got, depths
+
+    def _same(self, sync, want, eng, got):
+        rids = sorted(sync.requests)
+        assert sorted(eng.requests) == rids
+        assert self._tokens(eng, rids) == self._tokens(sync, rids)
+        assert [eng.requests[r].state for r in rids] == \
+            [sync.requests[r].state for r in rids]
+        assert self._rows(got) == self._rows(want)
+        assert eng.pool.num_allocated == 0 and not eng._reuse_keys
+        assert eng.in_flight is None
+
+    @pytest.mark.parametrize("family,temperature", [
+        ("gpt2", 0.0), ("gpt2", 0.8), ("llama", 0.0), ("llama", 0.8),
+        ("mistral", 0.0), ("mistral", 0.8)])
+    def test_a_chain_through_a_whole_prompt_is_adopted(self, lm, family,
+                                                       temperature):
+        """One prompt of 22 tokens in chunks of four: the admitting step is
+        built, the five chunks behind it and every decode step are adopted,
+        and the tokens (sampled ones too) are the synchronous loop's."""
+        sync, want, eng, got, depths = self._both(
+            lm, self._work([(22, 12)]), temperature)
+        self._same(sync, want, eng, got)
+        kinds = [(rec.get("speculative", False),
+                  rec["programs"][0]["kind"]) for rec, _ in got]
+        assert kinds == [(False, "mixed")] + [(True, "mixed")] * 5 \
+            + [(True, "decode_paged")] * 11
+        m = eng.metrics
+        assert m.overlap_rebuilds == 0 and max(depths) >= 4
+        s = m.summary()
+        assert (s["adopted_mixed_steps"], s["adopted_decode_steps"]) == (5, 11)
+        assert s["speculate_refused_mixed_step"] == 0
+        fams = {f["name"]: f for f in m.prometheus_series()}
+        assert fams["tnn_serve_adopted_mixed_steps_total"]["samples"][0][
+            -1] == 5
+
+    @pytest.mark.parametrize("family,temperature", [
+        ("gpt2", 0.8), ("llama", 0.0), ("mistral", 0.8)])
+    def test_a_row_moves_to_the_decode_rows_with_its_first_token(
+            self, lm, family, temperature, monkeypatch):
+        """Running order a, b, c with b still pushing its prompt: the step
+        of b's last chunk holds rows (a, c, b), the one behind it (a, b,
+        c), so b's first token is row 2 of the samples on the device and
+        c's row 1: a gather, not the predecessor's order."""
+        from tnn_tpu.serving import engine
+
+        gathers = []
+        real = engine._splice_prev_tokens
+
+        def spy(toks, prev, idx, from_prev):
+            gathers.append((toks.ndim, list(np.asarray(idx)),
+                            list(np.asarray(from_prev))))
+            return real(toks, prev, idx, from_prev)
+
+        monkeypatch.setattr(engine, "_splice_prev_tokens", spy)
+        sync, want, eng, got, _ = self._both(
+            lm, self._work([(3, 30), (18, 12), (2, 30)]), temperature)
+        self._same(sync, want, eng, got)
+        assert eng.metrics.overlap_rebuilds == 0
+        # b's chunks beside a and c decoding: their tokens from rows 0, 1
+        assert (2, [0, 1, 2, 3], [True, True, False, False]) in gathers
+        # ... and the decode step behind b's last chunk
+        assert (1, [0, 2, 1, 3], [True, True, True, False]) in gathers
+        moved = [rec for rec, _ in got if rec.get("speculative")
+                 and rec["programs"][0]["kind"] == "decode_paged"]
+        assert moved and eng.metrics.adopted_by_kind["mixed"] >= 3
+
+    @pytest.mark.parametrize("family,temperature", [
+        ("gpt2", 0.0), ("llama", 0.8), ("mistral", 0.0)])
+    def test_two_rows_push_their_prompts_at_once(self, lm, family,
+                                                 temperature):
+        """Prompts of 19 and 11 tokens admitted into one step beside a row
+        that decodes: both take a chunk a step, the shorter one ends first
+        and decodes beside the longer one's last chunks."""
+        sync, want, eng, got, _ = self._both(
+            lm, self._work([(2, 26), (19, 8), (11, 9)]), temperature)
+        self._same(sync, want, eng, got)
+        m = eng.metrics
+        assert m.overlap_rebuilds == 0
+        assert m.speculate_refusals["mixed_step"] == 0
+        two = [rec for rec, _ in got if rec.get("speculative")
+               and rec["programs"][0]["kind"] == "mixed"]
+        assert len(two) >= 4
+        # built: the admitting step, and the step behind each row's end
+        assert len(got) - m.adopted_steps <= 4
+
+    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    def test_a_resumed_request_keeps_its_token(self, lm, family):
+        """A pool of eight blocks preempts: the victim comes back with its
+        output as more prompt and a token it already drew. The step behind
+        its last chunk takes THAT token from the host, not the sample of
+        the chunk (drawn at temperature: another token)."""
+        from tnn_tpu.serving import engine
+
+        kept = []
+
+        def watch(eng):
+            def hook(i):
+                kept.extend(
+                    row for s in eng.in_flight.ahead
+                    for row in s["rec"]["before"]
+                    if row.src == engine._ON_HOST and row.req.out_tokens
+                    and row.cache_len == row.req.prefill_len)
+            return hook
+
+        sync, want, eng, got, _ = self._both(
+            lm, self._work([(5, 10), (9, 10), (16, 10), (7, 10)], seed=1),
+            temperature=0.7, in_flight=watch, num_blocks=9)
+        assert eng.metrics.preemptions == sync.metrics.preemptions > 0
+        self._same(sync, want, eng, got)
+        assert kept, "no step went out behind a resumed row's last chunk"
+        assert eng.metrics.overlap_rebuilds == 0
+
+    @pytest.mark.parametrize("family", ["gpt2", "mistral"])
+    def test_an_admitted_arrival_rolls_a_chain_of_chunks_back(self, lm,
+                                                             family):
+        """A row decodes, a prompt of 30 tokens is on its way in chunks
+        with four steps queued, and a row is free: an arrival is admitted
+        at the next step, so the chain is rolled back, the blocks taken
+        for its chunks are freed and its keys drawn again in order."""
+        held = {}
+
+        def arrive(at):
+            def make(eng):
+                def hook(i):
+                    if i == at:
+                        ahead = eng.in_flight.ahead if eng.overlap else ()
+                        held.update(
+                            blocks=eng.pool.num_allocated, queued=len(ahead),
+                            kinds=[s["rec"]["kind"] for s in ahead])
+                        hook.rid = eng.submit(*self._work([(6, 9)], 3)[0],
+                                              temperature=0.7)
+                        resolve = eng._resolve_speculation
+
+                        def resolved(flight):   # the next one only
+                            del eng._resolve_speculation
+                            resolve(flight)
+                            held["after"] = eng.pool.num_allocated
+                        eng._resolve_speculation = resolved
+                return hook
+            return make
+
+        sync, want, eng, got, _ = self._both(
+            lm, self._work([(3, 30), (30, 8)]), temperature=0.7,
+            before_step=arrive(5), in_flight=arrive(4))
+        self._same(sync, want, eng, got)
+        # b's last chunks and the decode steps behind them
+        assert held["queued"] >= 3 and held["kinds"][:2] == ["mixed"] * 2
+        assert eng.metrics.overlap_rebuilds == 1
+        # what the rows hold once the chain is gone: what they hold in the
+        # synchronous loop after that step
+        assert held["after"] == want[4][0]["pool_allocated"] < held["blocks"]
+        assert not got[5][0].get("speculative")     # the admitting step
+
+    def test_a_non_finite_chunk_fails_alone_and_the_chain_is_rebuilt(
+            self, tiny_lm, monkeypatch):
+        """The chunk that starts at position 8 of the second request comes
+        back non-finite: that request fails, the steps queued behind the
+        chunk are rolled back and built again without it, and the row
+        beside it reads as in the synchronous loop."""
+        from tnn_tpu.serving import step_build
+
+        real = step_build.pack_mixed
+        ahead_of_commit = []
+
+        def poisoned(rows, n_dec, *a, **kw):
+            step = real(rows, n_dec, *a, **kw)
+            for i, req in enumerate(rows):
+                if i >= n_dec and req.rid == 1 and step.starts[i] == 8:
+                    step.poison[i] = np.nan
+                    ahead_of_commit.append(kw.get("lens") is not None)
+            return step
+
+        monkeypatch.setattr(step_build, "pack_mixed", poisoned)
+        sync, want, eng, got, _ = self._both(
+            tiny_lm, self._work([(3, 24), (21, 8)]), temperature=0.7)
+        assert ahead_of_commit == [False, True]
+        self._same(sync, want, eng, got)
+        assert eng.requests[1].state.name == "FAILED"
+        assert "prefill chunk" in eng.requests[1].error
+        assert eng.metrics.overlap_rebuilds == 1
+        assert len(eng.requests[0].out_tokens) == 24
+
+    def test_a_prompt_the_budget_leaves_no_chunk_counts_as_mixed_step(
+            self, tiny_lm):
+        """Three rows, then a token budget of five: a decode row and one
+        chunk of four use it up, and the second prompt sits in its row with
+        no chunk (a budget that shrinks under a row's feet: a window's end
+        can do that to the rows behind it). What such a row gets next is
+        not worked out ahead: ``mixed_step``, until the first prompt ends."""
+        model, params = tiny_lm
+        work = self._work([(2, 20), (14, 6), (10, 6)])
+
+        def admitted(overlap):
+            eng = InferenceEngine(model, params, **self.KW, overlap=overlap)
+            rids = [eng.submit(p, n) for p, n in work]
+            eng.step()
+            assert len(eng.scheduler.running) == 3
+            eng.scheduler.token_budget = 5
+            return eng, rids
+
+        sync, rids = admitted(False)
+        want = self._sync(sync)
+        eng, _ = admitted(True)
+        got, _ = self._overlapped(eng)
+        self._same(sync, want, eng, got)
+        starved = [rec for rec, _ in got
+                   if len(rec["programs"][0]["rids"]) == 2
+                   and len(rec["running_rids"]) == 3]
+        said = eng.metrics.speculate_refusals
+        assert said["mixed_step"] == len(starved) >= 2
+        assert not any(rec.get("speculative") for rec in starved)
+        assert eng.metrics.overlap_rebuilds == 0
+        assert eng.metrics.adopted_by_kind["mixed"] >= 1
+
+    def test_a_row_that_left_ends_the_chain(self, tiny_lm):
+        """A cancellation while steps are queued: the prediction behind
+        them still holds the row, and nothing more goes out on it
+        (``other``); the commit then rolls the chain back."""
+        model, params = tiny_lm
+        eng = InferenceEngine(model, params, **self.KW, overlap=True)
+        rids = [eng.submit(p, n) for p, n in
+                self._work([(3, 30), (14, 30)])]
+        for _ in range(2):
+            self._turn(eng)
+        if eng.in_flight is None:
+            eng.begin_step()
+        assert eng.try_speculate()                  # one queued, room for more
+        assert eng.cancel(rids[0])
+        held = eng.pool.num_allocated
+        assert not eng.try_speculate()
+        assert eng.metrics.speculate_refusals["other"] == 1
+        assert eng.pool.num_allocated == held
+        eng.finish_step()
+        assert eng.metrics.overlap_rebuilds == 1 and eng.in_flight is None
+        eng.run_until_complete()
+        assert len(eng.requests[rids[1]].out_tokens) == 30
+        assert eng.pool.num_allocated == 0
+
+    def test_a_window_s_end_is_not_guessed(self, windowed_lm):
+        """Prompts of 50 and 21 tokens in chunks of eight through a pool
+        whose pages come and go: the chain stops where a chunk's commit
+        ends a window or gives pages back (``row_condition``), goes on
+        everywhere else, and serves the synchronous loop's tokens with
+        every packed step held to the one-writer invariant."""
+        model, _ = windowed_lm
+        rng = np.random.default_rng(12)
+        work = [(rng.integers(0, model.vocab_size, p).astype(np.int32), n)
+                for p, n in ((50, 24), (21, 30))]
+        sync, want, eng, got, _ = self._both(
+            windowed_lm, work, num_blocks=128, block_size=8,
+            max_seq_len=128, chunk_size=8)
+        self._same(sync, want, eng, got)
+        m = eng.metrics
+        assert m.overlap_rebuilds == 0
+        assert m.speculate_refusals["row_condition"] >= 2
+        assert m.speculate_refusals["mixed_step"] == 0
+        assert m.adopted_by_kind["mixed"] >= 2
+        built = [rec for rec, _ in got if not rec.get("speculative")]
+        assert len(built) < len(got) / 2

@@ -85,7 +85,8 @@ import os
 import time
 from collections import Counter
 from contextlib import nullcontext
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Union)
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +127,39 @@ def _splice_draft_row(toks, draft, row):
     column constant never becomes an eager host->device transfer under
     TNN_DEBUG_SYNC=1."""
     return jax.lax.dynamic_update_slice(toks, draft, (row, jnp.int32(1)))
+
+
+@jax.jit
+def _splice_prev_tokens(toks, prev, idx, from_prev):
+    """Finish a step's token array on the device from ``prev``, the
+    unfetched samples of the step dispatched before it: row i's pending
+    token (a decode step's ``toks[i]``, a mixed step's ``toks[i, 0]``)
+    becomes ``prev[idx[i]]`` where ``from_prev[i]``, and stays what the host
+    packed elsewhere (a chunk row's prompt, a resumed row's kept token,
+    padding). The gather is what lets a row that finished its prompt move
+    from the chunk rows to its place among the decode rows."""
+    col = toks if toks.ndim == 1 else toks[:, 0]
+    col = jnp.where(from_prev, prev[idx].astype(toks.dtype), col)
+    return col if toks.ndim == 1 else toks.at[:, 0].set(col)
+
+
+class _RowAhead(NamedTuple):
+    """One running row as the commit of a dispatched step will leave it
+    (``InferenceEngine._rows_after``): what the overlapped loop predicts
+    the step after from."""
+    req: Request
+    cache_len: int
+    generated: int
+    # where its pending token is: a row of that step's unfetched samples,
+    # ``_ON_HOST`` (``req.next_token``), None while it pushes its prompt
+    src: Optional[int]
+
+    @property
+    def decoding(self) -> bool:
+        return self.src is not None
+
+
+_ON_HOST = -1
 
 
 def _stacked(counts) -> tuple:
@@ -290,9 +324,10 @@ class InferenceEngine:
         begin/finish around the deferred work and may speculatively
         dispatch steps N+1, N+2, ... from predicted row states before step
         N commits (``try_speculate``, as deep as ``_speculate_depth`` says:
-        pure decode steps at which the scheduler would admit nothing, with
-        a queue behind a full batch as well as with none;
-        mispredictions roll back and rebuild).
+        decode steps and the mixed steps that carry a prompt's next chunks,
+        at which the scheduler would admit nothing, with a queue behind a
+        full batch as well as with none; mispredictions roll back and
+        rebuild).
         Token-exact vs overlap-off — a direct
         ``step()`` call stays fully synchronous either way. Default off;
         ``tnn-serve`` turns it on (``--no-overlap`` opts out).
@@ -884,25 +919,38 @@ class InferenceEngine:
             self.pool.free(ext)
             del table[len(table) - len(ext):]
 
+    def _pages_behind(self, req: Request, cache_len: int) -> int:
+        """Logical pages at the head of ``req``'s window table that lie
+        wholly behind the sliding window once ``cache_len`` positions are
+        resident (0: one page group, or nothing to give back)."""
+        if not (self.pool.sliding and req.window_table):
+            return 0
+        return max(0, self.pool.release_behind(cache_len) - req.window_base)
+
+    def _window_ends(self, req: Request, cache_len: int) -> bool:
+        """Whether ``cache_len`` resident positions end an exact window
+        whose pages ``req`` still holds (a windowed pool)."""
+        return bool(self.pool.window and req.block_table
+                    and cache_len % self.pool.window == 0)
+
     def _end_window(self, req: Request) -> None:
         """A windowed request whose committed length reached a window's end
         gives its exact pages back: the next window starts with none, and
         reads this one through its summaries only. In a pool of two page
         groups, the window layers' pages that now lie wholly behind the
-        window go back."""
-        if self.pool.sliding and req.window_table:
-            drop = self.pool.release_behind(req.cache_len) - req.window_base
-            if drop > 0:
-                n = drop * self.pool.window_layers
-                self.pool.free(req.window_table[:n])
-                del req.window_table[:n]
-                req.window_base += drop
-                self.metrics.observe_window_release(n)
-                self.tracer.instant("serve.win_release", trace=req.trace_id,
-                                    rid=req.rid, step=self.step_seq,
-                                    at=req.cache_len, pages=n)
-        if self.pool.window and req.block_table \
-                and req.cache_len % self.pool.window == 0:
+        window go back. (What a step packed ahead of this commit cannot
+        know: ``_dispatch_ahead`` asks the same two questions.)"""
+        drop = self._pages_behind(req, req.cache_len)
+        if drop:
+            n = drop * self.pool.window_layers
+            self.pool.free(req.window_table[:n])
+            del req.window_table[:n]
+            req.window_base += drop
+            self.metrics.observe_window_release(n)
+            self.tracer.instant("serve.win_release", trace=req.trace_id,
+                                rid=req.rid, step=self.step_seq,
+                                at=req.cache_len, pages=n)
+        if self._window_ends(req, req.cache_len):
             self.pool.free(req.block_table)
             req.block_table = []
             self.metrics.observe_eva_roll()
@@ -1435,17 +1483,24 @@ class InferenceEngine:
         """Speculatively build and dispatch one more step behind the step in
         flight (N) and the successors already dispatched: step N+j. Legal
         only when its build is fully determined by committed state plus the
-        (unfetched) sampled tokens of the step before it: a pure decode
-        batch whose every row must survive the j commits before it — no
-        stop tokens, no deadlines, headroom for j+1 more tokens — with KV
-        growth that fits the pool without preemption, no drafter, no fault
-        plan, and a scheduler that would admit nothing at that step
-        (``Scheduler.would_admit``: nobody waits, or every row is taken, or
-        the head of the queue does not fit the budget or the pool; a server
-        under load has a queue, and its rows are taken). The dispatched
-        program reads its
-        predecessor's sampled tokens DIRECTLY as its device-resident
-        inputs, so nothing syncs; ``finish_step`` validates the prediction
+        (unfetched) sampled tokens of the step before it, whatever that
+        step's kind: a decode row is one token on and its next token is the
+        step's sample, still on the device; a row pushing its prompt is its
+        chunk on, and takes the chunk the scheduler's own arithmetic grants
+        at that length (``Scheduler.plan_running``) or, its prompt done,
+        decodes from its first sample (``_rows_after``). Every row must
+        survive the j commits before it — no stop tokens, no deadlines,
+        headroom for its next tokens, no chunk whose commit gives a
+        window's pages back — with KV growth that fits the pool without
+        preemption, no drafter, no fault plan, and a scheduler that would
+        admit nothing at that step (``Scheduler.would_admit``: nobody
+        waits, or every row is taken, or the head of the queue does not fit
+        the budget or the pool; a server under load has a queue, and its
+        rows are taken). The dispatched program reads its predecessor's
+        sampled tokens as device-resident inputs (directly behind a decode
+        step, through ``_splice_prev_tokens`` where rows moved or a prompt
+        is in the step), so nothing syncs; ``finish_step`` validates the
+        prediction
         and either adopts the oldest successor as the next in-flight step
         or rolls every one back (``_resolve_speculation``). Returns True
         when a step was dispatched: the drive loops call it until it says
@@ -1460,11 +1515,20 @@ class InferenceEngine:
                 or len(flight.ahead) >= self._speculate_depth()):
             return False
         with self.tracer.span("serve.speculate",
-                              step=self.step_seq + len(flight.ahead) + 1):
+                              step=self.step_seq + len(flight.ahead) + 1,
+                              kind=self._newest_rec(flight).get("kind", "")):
             refusal = self._dispatch_ahead(flight)
         if refusal:
             self.metrics.observe_speculate_refusal(refusal)
         return not refusal
+
+    @staticmethod
+    def _newest_rec(flight: "StepInFlight") -> Dict[str, Any]:
+        """The record of the newest step dispatched: the predecessor of the
+        step ``try_speculate`` would dispatch ({}: nothing was launched)."""
+        if flight.ahead:
+            return flight.ahead[-1]["rec"]
+        return flight.recs[-1] if flight.recs else {}
 
     def _grown_ahead(self, ahead) -> int:
         """Blocks the steps in ``ahead`` took for their rows' next tokens
@@ -1477,90 +1541,209 @@ class InferenceEngine:
         return self.sp * sum(len(ext) for s in ahead
                              for _, _, ext in s["rollback"])
 
+    def _running_rows(self) -> List[Request]:
+        """The rows a step holds: RUNNING requests, in admission order."""
+        return [r for r in self.scheduler.running
+                if r.state is RequestState.RUNNING]
+
+    def _rows_after(self, rec: Dict[str, Any]) -> Union[List[_RowAhead], str]:
+        """The running rows, in the scheduler's order, as the commit of the
+        dispatched step ``rec`` will leave them, or the refusal that says
+        why that is not known. A step dispatched ahead starts from the
+        prediction it was packed from (``rec["before"]``), a step that
+        ``begin_step`` built from the committed state, which stands until
+        its commit. ``_decode_commit`` / ``_mixed_commit`` restated: a
+        decode row is one token on, its next token row i of the step's
+        samples; a chunk row is its grant on, and at its prompt's end it
+        decodes from row i too, or, resumed after a preemption, from the
+        token it kept (``req.next_token``: that commit ignores the
+        sample)."""
+        after = rec.get("after")
+        if after is not None:
+            return after
+        before = rec.get("before")
+        if before is None:
+            before = [_RowAhead(r, r.cache_len, r.num_generated,
+                                _ON_HOST if r.cache_len >= r.prefill_len
+                                else None) for r in self._running_rows()]
+        rows = rec.get("live") or rec["rows"]
+        n_dec, takes = rec.get("n_dec", len(rows)), rec.get("takes", {})
+        at = {row.req.rid: k for k, row in enumerate(before)}
+        if any(req.rid not in at for req in rows):
+            return "other"              # a row left while the step flew
+        after = list(before)
+        for i, req in enumerate(rows):
+            k = at.pop(req.rid)
+            row = before[k]
+            if i < n_dec:
+                row = row._replace(cache_len=row.cache_len + 1,
+                                   generated=row.generated + 1, src=i)
+            else:
+                row = row._replace(cache_len=row.cache_len + takes[req.rid])
+                if row.cache_len >= req.prefill_len:
+                    row = row._replace(src=_ON_HOST) if req.out_tokens \
+                        else row._replace(generated=1, src=i)
+            after[k] = row
+        if at:
+            # a running row the step does not hold: the budget left its
+            # prompt no chunk, and what it gets next is not worked out here
+            return "other" if any(before[k].decoding for k in at.values()) \
+                else "mixed_step"
+        rec["after"] = after
+        return after
+
     def _dispatch_ahead(self, flight: "StepInFlight") -> str:
         """``try_speculate``'s body: "" when step N+j went out, else why
         not, by the name its refusal is counted under."""
         if self.faults is not None or self.drafter is not None:
             return "other"
-        if any(rec["kind"] != "decode" for rec in flight.recs):
-            return "mixed_step"
         if len(flight.recs) != 1:
             return "other"              # the step launched nothing
-        j = len(flight.ahead) + 1       # every row is j tokens on by then
-        rec = flight.ahead[-1]["rec"] if flight.ahead else flight.recs[0]
-        live = rec["live"]
-        if live != [r for r in self.scheduler.running
-                    if r.state is RequestState.RUNNING]:
-            return "other"              # a row left while the step flew
-        grows = []
-        for req in live:
-            if (req.cache_len < req.prefill_len
-                    or req.stop_token is not None
-                    or req.deadline_s is not None
-                    # a step before this one ends the row's window: its
-                    # commit gives the exact pages back, which no
-                    # prediction packs
-                    or self.pool.room_in_window(req.cache_len) <= j):
+        j = len(flight.ahead) + 1
+        prev = self._newest_rec(flight)
+        after = self._rows_after(prev)
+        if isinstance(after, str):
+            return after
+        if [row.req for row in after] != self._running_rows():
+            return "other"              # a row left while the chain flew
+        pool = self.pool
+        chunked = prev.get("takes", ())     # the rows prev pushes a chunk of
+        lens: Dict[int, int] = {}
+        for row in after:
+            req, at = row.req, row.cache_len
+            # a decode row since ``since``: a step before this one ends its
+            # window, and that commit gives the exact pages back, which no
+            # prediction packs
+            since = max(req.cache_len, req.prefill_len)
+            if (req.stop_token is not None or req.deadline_s is not None
+                    # a chunk whose commit changes the row's tables
+                    # (``_end_window``)
+                    or (req.rid in chunked
+                        and (self._pages_behind(req, at)
+                             or self._window_ends(req, at)))
+                    or (row.decoding
+                        and pool.room_in_window(since) <= at - since)):
                 return "row_condition"
-            if (req.num_generated + j >= req.max_new_tokens
-                    or req.cache_len + j + 1 > self.max_seq_len):
+            if row.decoding and (row.generated >= req.max_new_tokens
+                                 or at + 1 > self.max_seq_len):
                 return "row_ends"
-            grows.append(self._grow_need(req, req.cache_len + j, 1))
-        if self.scheduler.would_admit(self.pool,
-                                      self._grown_ahead(flight.ahead)):
+            lens[req.rid] = at
+        chunks, _ = self.scheduler.plan_running(pool, lens)
+        grows = []
+        for row in after:
+            take = 1 if row.decoding else chunks.get(row.req.rid, 0)
+            if not take:
+                return "mixed_step"     # a prompt the budget leaves no chunk
+            grows.append(self._grow_need(row.req, row.cache_len, take))
+        if self.scheduler.would_admit(pool, self._grown_ahead(flight.ahead),
+                                      lens):
             return "admission"
         total = sum(map(sum, grows))
-        if total and not self.pool.can_alloc(total):
+        if total and not pool.can_alloc(total):
             return "pool"
         rollback: List[Any] = []
         try:
-            for req, g in zip(live, grows):
-                rollback.extend(self._extend(req, g))
+            for row, g in zip(after, grows):
+                rollback.extend(self._extend(row.req, g))
         except PoolExhausted:
             self._unextend(rollback)
             return "pool"
-        # ahead=j packs the predicted row state: each offset assumes
-        # exactly one token committed by each of the j steps before
-        step = step_build.pack_decode(
-            live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
-            scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            ahead=j, sum_at=self.pool.exact_width,
-            kinds=self.pool.kinds)
-        self._check_step_writes(step, step.offsets)
-        b, nb, key, offsets = step.b, step.nb, step.key, step.offsets
-        label = "decode_paged"
+        # the step's rows as ``_mixed_build`` lays them: the decode rows,
+        # then the rows that push a chunk, each in the scheduler's order.
+        # Row i's token is row ``idx[i]`` of the predecessor's samples
+        # where ``from_prev[i]``
+        dec = [row for row in after if row.decoding]
+        rows = [row.req for row in dec] + \
+            [row.req for row in after if not row.decoding]
+        b = self.scheduler.max_batch_size
+        idx, from_prev = np.arange(b, dtype=np.int32), np.zeros(b, bool)
+        for i, row in enumerate(dec):
+            if row.src != _ON_HOST:
+                idx[i], from_prev[i] = row.src, True
+        packed = dict(
+            b=b, nb=self.blocks_per_seq, scratch=PagedKVPool.SCRATCH,
+            kv_key=self._kv_key, sum_at=pool.exact_width, kinds=pool.kinds,
+            lens=lens, on_device={row.req.rid for row in dec
+                                  if row.src != _ON_HOST})
+        t0 = time.perf_counter()
+        if len(dec) < len(rows):
+            takes = {req.rid: chunks[req.rid] for req in rows[len(dec):]}
+            step = step_build.pack_mixed(rows, len(dec), {}, takes,
+                                         spec_on=False, **packed)
+            self._check_step_writes(step, step.starts, step.q_lens)
+            label, qw = "mixed", step.qw
+            where = (step.starts, step.q_lens)
+            ends = step.starts + step.q_lens
+            rec = {"kind": "mixed", "rows": rows, "n_dec": len(dec),
+                   "takes": takes, "n_draft": step.n_draft, "n_spec": 0,
+                   "qw": qw}
+        else:
+            step = step_build.pack_decode(rows, **packed)
+            self._check_step_writes(step, step.offsets)
+            label, qw = "decode_paged", 1
+            where = (step.offsets,)
+            ends = step.offsets + 1
+            rec = {"kind": "decode", "live": rows}
+        key = step.key
         fn = self._jit.get(key)
         if fn is None:
-            fn = self._jit[key] = self._paged_decode_fn(b, nb)
+            fn = self._jit[key] = (
+                self._mixed_paged_fn(b, qw, step.nb) if label == "mixed"
+                else self._paged_decode_fn(b, step.nb))
+            self._warm_splice(step.toks.shape)
+        prev_tok = prev["dev"][0]   # its predecessor's unfetched samples
         step_key = self._step_key()
-        t0 = time.perf_counter()
-        prev_tok = rec["dev"][0]    # its predecessor's unfetched samples
+
+        def stage():
+            if label != "mixed" and from_prev[:len(rows)].all() \
+                    and (idx == np.arange(b)).all():
+                toks = prev_tok     # behind a decode step: its samples, as is
+            else:
+                toks = self._put(step.toks)
+                if from_prev.any():
+                    toks = _splice_prev_tokens(
+                        toks, prev_tok, self._put(idx), self._put(from_prev))
+            return (toks, *map(self._put, where),
+                    self._put_tables(step.tables), self._put(step.temps),
+                    self._put(step.topks), self._put(step.topps), step_key,
+                    self._put(step.poison))
+
         try:
             with self._sync_guard():
                 newtok, ok, pk, pv, *experts = self._dispatch(
-                    fn, label, step.temps, 1, lambda: (
-                        prev_tok, self._put(offsets),
-                        self._put_tables(step.tables), self._put(step.temps),
-                        self._put(step.topks), self._put(step.topps),
-                        step_key, self._put(step.poison)), ahead=j)
+                    fn, label, step.temps, qw, stage, ahead=j)
         except Exception:  # noqa: BLE001 — speculation must never hurt
             self._unextend(rollback)
             self._reuse_keys.insert(0, step_key)
             self._recover_pages_if_dead(flight.events)
             return "other"
-        self.pool.update_pages(pk, pv)
-        self._observe_attention(live, offsets + 1, 1)
+        pool.update_pages(pk, pv)
+        self._observe_attention(rows, ends, qw)
+        rec.update(dev=(newtok, ok, *experts), t0=t0, b=b, before=after)
         flight.ahead.append({
-            "rec": {"kind": "decode", "dev": (newtok, ok, *experts),
-                    "live": list(live), "t0": t0, "b": b},
-            "rollback": rollback, "key": step_key,
-            "offsets": {r.rid: int(offsets[i])
-                        for i, r in enumerate(live)},
+            "rec": rec, "rollback": rollback, "key": step_key,
+            # what its adoption holds the committed rows to: each one's
+            # length, and with it whether it decodes or pushes its prompt
+            "offsets": lens, "decoding": {row.req.rid for row in dec},
             "prog": {"kind": label, "compile_key": list(key),
-                     "rids": [r.rid for r in live],
-                     "fill": round(len(live) / b, 4)},
+                     "rids": [r.rid for r in rows],
+                     "fill": round(len(rows) / b, 4)},
         })
         return ""
+
+    def _warm_splice(self, shape) -> None:
+        """Compile ``_splice_prev_tokens`` for a step program's token array
+        when the program itself is made: a width's first step behind a
+        prompt's chunk must find both compiled (warm-up reaches every
+        width's program, not every width's chunk beside decoding rows)."""
+        if not self.overlap:
+            return
+        b = shape[0]
+        _splice_prev_tokens(
+            self._put(np.zeros(shape, np.int32)),
+            self._put(np.zeros((b,), np.int32)),
+            self._put(np.arange(b, dtype=np.int32)),
+            self._put(np.zeros((b,), bool)))
 
     def _speculate_depth(self) -> int:
         """Successor steps ``try_speculate`` keeps dispatched behind the
@@ -1573,12 +1756,15 @@ class InferenceEngine:
 
     def _resolve_speculation(self, flight: "StepInFlight") -> None:
         """After ``flight`` committed: adopt its oldest speculative
-        successor when the prediction held (the same rows, each exactly
-        one token longer, still running, and a scheduler that would still
-        admit nothing: asked again of the committed state) and hand it
-        the younger ones, else roll them all back — free the pre-grown
-        blocks, stash the PRNG keys for reuse in order, and let the next
-        ``begin_step`` rebuild from committed state.
+        successor when the prediction held (the same rows, each at the
+        length it was packed at and so in the phase it was packed in,
+        pushing its prompt or decoding, still running, and a scheduler that
+        would still admit nothing: asked again of the committed state) and
+        hand it the younger ones, else roll them all back — free the
+        pre-grown blocks, stash the PRNG keys for reuse in order, and let
+        the next ``begin_step`` rebuild from committed state. A row that
+        failed alone, a cancellation, an arrival the scheduler admits: each
+        changes the rows or what the scheduler says, and rolls back.
 
         An adopted step never runs ``begin_step``, so what that does for
         those who WAIT is done here, in its order: the queued requests'
@@ -1590,12 +1776,12 @@ class InferenceEngine:
             return
         spec = ahead[0]
         rec = spec["rec"]
-        live = rec["live"]
+        running = self._running_rows()
         predicted = (
-            live == [r for r in self.scheduler.running
-                     if r.state is RequestState.RUNNING]
+            [r.rid for r in running] == list(spec["offsets"])
             and all(req.cache_len == spec["offsets"][req.rid]
-                    for req in live))
+                    and (req.cache_len >= req.prefill_len)
+                    == (req.rid in spec["decoding"]) for req in running))
         queued = self.scheduler.queue_depth
         timed_out: List[Any] = []
         if predicted and queued:
@@ -1615,19 +1801,20 @@ class InferenceEngine:
         # its step_seq and flight-recorder note at adoption time. Its clock
         # starts here too: until now it waited behind its predecessor
         self._adopted_run += 1
-        self.metrics.observe_adopted_step()
+        self.metrics.observe_adopted_step(rec["kind"])
         self.step_seq += 1
         note: Dict[str, Any] = {
             "step_seq": self.step_seq,
             "queued": queued,
-            "running_rids": [r.rid for r in live],
+            "running_rids": [r.rid for r in running],
             "programs": [dict(spec["prog"])],
             "speculative": True,
         }
         self._step_note = note
         rec["t0"] = time.perf_counter()
         nxt = StepInFlight(self.step_seq, note, None, rec["t0"])
-        nxt.gen_before = {r.rid: r.num_generated for r in live}
+        nxt.gen_before = {r.rid: r.num_generated for r in running
+                          if r.cache_len >= r.prefill_len}
         nxt.events["timed_out"] = timed_out
         nxt.recs.append(rec)
         nxt.ahead = ahead[1:]
@@ -1650,8 +1837,11 @@ class InferenceEngine:
         probe-only, so outputs are unaffected."""
         cache = self.prefix_cache
         tokens = req.resume_tokens
-        snap = list(req.block_table)
         clen = req.cache_len
+        # the blocks the chunks so far FILLED, which is all a publish reads:
+        # what steps dispatched ahead took for the chunks to come is theirs
+        # to give back, and a roll-back must not cost the row its publish
+        snap = req.block_table[:clen // self.pool.block_size]
         step = self.step_seq
 
         def run() -> None:
@@ -2270,8 +2460,7 @@ class InferenceEngine:
             and r.cache_len < r.prefill_len for r in self.scheduler.running)
         if not has_chunks and not spec_on:
             self._ensure_decode_capacity(events)
-            live = [r for r in self.scheduler.running
-                    if r.state is RequestState.RUNNING]
+            live = self._running_rows()
             if live:
                 rec = self._decode_build(live, events)
                 if rec is not None:
@@ -2306,8 +2495,7 @@ class InferenceEngine:
                 if not self._grow_blocks(req, 1 + (len(d) if d else 0),
                                          events, chunk=False):
                     drafts.pop(req.rid, None)
-        live = [r for r in self.scheduler.running
-                if r.state is RequestState.RUNNING]
+        live = self._running_rows()
         dec = [r for r in live if r.cache_len >= r.prefill_len]
         chk = [(r, chunks[r.rid]) for r in live
                if r.cache_len < r.prefill_len and r.rid in chunks]
@@ -2350,6 +2538,8 @@ class InferenceEngine:
             fn = self._jit[key] = (
                 self._spec_paged_fn(b, qw, step.nb) if spec_on
                 else self._mixed_paged_fn(b, qw, step.nb))
+            if not spec_on:
+                self._warm_splice(step.toks.shape)
         # the token matrix is staged here, inside serve.build, because the
         # commit wants it back (``rec["dev"]``): a serve.put of its own,
         # counted into the step's put time at its launch
@@ -2688,6 +2878,7 @@ class InferenceEngine:
         fn = self._jit.get(key)
         if fn is None:
             fn = self._jit[key] = self._paged_decode_fn(b, nb)
+            self._warm_splice(step.toks.shape)
         # one key per STEP (held across the retry): a transient fault retried
         # with the same key reproduces the fault-free step bit-for-bit
         step_key = self._step_key()

@@ -121,6 +121,13 @@ EXPOSITION: Dict[str, Tuple[str, str, str, str]] = {
         "tnn_serve_adopted_steps_total", "counter",
         "Steps dispatched ahead of their predecessor's commit and adopted "
         "as dispatched (no build, no host gap)", "adopted_step_share"),
+    "serve.adopted_mixed_step": (
+        "tnn_serve_adopted_mixed_steps_total", "counter",
+        "Adopted steps that push a prompt chunk (a mixed step dispatched "
+        "behind its predecessor's commit)", "adopted_mixed_steps"),
+    "serve.adopted_decode_step": (
+        "tnn_serve_adopted_decode_steps_total", "counter",
+        "Adopted steps of decode rows alone", "adopted_decode_steps"),
     "serve.decode_s": (
         "tnn_serve_decode_seconds_total", "counter",
         "Cumulative decode-step wall seconds", "tok_per_s"),
@@ -501,6 +508,10 @@ class ServingMetrics:
         # each time ``try_speculate`` had a step in flight and depth to
         # spare and dispatched nothing, the ONE reason why, by name
         self.adopted_steps = 0
+        # ... by the kind of the step adopted ("mixed": it pushes a prompt
+        # chunk; "decode")
+        self.adopted_by_kind: Dict[str, int] = dict.fromkeys(
+            ("mixed", "decode"), 0)
         self.committed_steps = 0
         self.step_latency_max_s = 0.0
         # the front end's empty stdin polls: how far past their timeout
@@ -684,21 +695,29 @@ class ServingMetrics:
         self.overlap_rebuilds += 1
         self._tick("serve.overlap_rebuild", 1)
 
-    def observe_adopted_step(self) -> None:
+    def observe_adopted_step(self, kind: str) -> None:
         """A step dispatched behind its predecessor was adopted as the next
-        step: it never ran ``begin_step``, and its host gap is zero."""
+        step: it never ran ``begin_step``, and its host gap is zero.
+        ``kind``: "mixed" (it pushes a prompt chunk) or "decode"."""
         self.adopted_steps += 1
+        self.adopted_by_kind[kind] += 1
         self._tick("serve.adopted_step", 1)
+        if kind == "mixed":
+            self._tick("serve.adopted_mixed_step", 1)
+        else:
+            self._tick("serve.adopted_decode_step", 1)
 
     def observe_speculate_refusal(self, reason: str) -> None:
         """``try_speculate`` had a step in flight and depth to spare and
         dispatched nothing. ``reason`` is one of a closed list:
-        ``mixed_step`` (the step in flight pushes a prompt chunk),
+        ``mixed_step`` (a row pushing its prompt is left out of the step in
+        flight or of the next: the token budget gave it no chunk),
         ``row_ends`` (a row's last token comes before the step would run),
         ``admission`` (the scheduler would admit the head of the queue at
         that step), ``pool`` (the rows' next pages do not fit without a
-        preemption), ``row_condition`` (a stop token, a deadline or a
-        window's end on a row), ``other`` (a drafter or a fault plan, a row
+        preemption), ``row_condition`` (a stop token or a deadline on a row,
+        a window's end before the step, a chunk whose commit gives a
+        window's pages back), ``other`` (a drafter or a fault plan, a row
         that left in flight, a dispatch that failed)."""
         self.speculate_refusals[reason] += 1
 
@@ -1103,6 +1122,8 @@ class ServingMetrics:
             "overlap_rebuilds": self.overlap_rebuilds,
             "adopted_step_share": (self.adopted_steps / self.committed_steps)
             if self.committed_steps else 0.0,
+            "adopted_mixed_steps": self.adopted_by_kind["mixed"],
+            "adopted_decode_steps": self.adopted_by_kind["decode"],
             **{f"speculate_refused_{reason}": n
                for reason, n in self.speculate_refusals.items()},
             "speculate_refused_mixed_step_share": (

@@ -252,26 +252,34 @@ class Scheduler:
             - len(r.window_table) for r in self.running)
         return pool.num_allocatable - owed
 
-    def _plan_running(self, pool):
+    def plan_running(self, pool, lens: Optional[Dict[int, int]] = None):
         """The running rows' part of a step: (chunk grants of the rows still
         mid-prefill, the token budget left for admissions). Each
         decode-phase row costs 1 budget token (plus ``spec_tokens`` drafted
         candidates scored alongside it); rows mid-prefill take up to
         chunk_size more of their prompt, and the oldest always advances at
-        least one token."""
+        least one token.
+
+        Reads only. ``lens`` (rid -> resident positions) plans the rows at
+        PREDICTED lengths, a row it leaves out at its committed one: the
+        step the engine dispatches ahead of its predecessors' commits
+        (``InferenceEngine.try_speculate``) is the step ``schedule`` plans
+        once they have landed, by this arithmetic and no copy of it."""
+        lens = lens or {}
         chunks: Dict[int, int] = {}
         budget = self.token_budget
-        prefilling: List[Request] = []
+        prefilling: List[tuple] = []
         for req in self.running:
-            if req.cache_len >= req.prefill_len:
+            cache_len = lens.get(req.rid, req.cache_len)
+            if cache_len >= req.prefill_len:
                 budget -= 1 + self.spec_tokens
             else:
-                prefilling.append(req)
-        for i, req in enumerate(prefilling):
-            rem = req.prefill_len - req.cache_len
+                prefilling.append((req, cache_len))
+        for i, (req, cache_len) in enumerate(prefilling):
+            rem = req.prefill_len - cache_len
             avail = budget if budget >= 1 else (1 if i == 0 else 0)
             take = min(self.chunk_size, rem, avail,
-                       pool.room_in_window(req.cache_len))
+                       pool.room_in_window(cache_len))
             if take <= 0:
                 continue
             chunks[req.rid] = take
@@ -320,7 +328,8 @@ class Scheduler:
             return None
         return take, nb + revive
 
-    def would_admit(self, pool, spare: int = 0) -> bool:
+    def would_admit(self, pool, spare: int = 0,
+                    lens: Optional[Dict[int, int]] = None) -> bool:
         """Whether ``schedule`` would admit the head of the queue into the
         step after the one the running rows are in, by ``schedule``'s own
         arithmetic and moving nothing: what the engine asks before it
@@ -328,10 +337,12 @@ class Scheduler:
         (``InferenceEngine.try_speculate``). An empty queue answers at once,
         a full batch without touching the pool. ``spare`` are the blocks the
         engine has already taken for the running rows' steps ahead, which
-        ``schedule`` would still have found free (``_headroom``)."""
+        ``schedule`` would still have found free (``_headroom``); ``lens``
+        the running rows' predicted lengths (``plan_running``): a row still
+        pushing its prompt takes its chunk out of the budget first."""
         if not self._has_free_row():
             return False
-        _, budget = self._plan_running(pool)
+        _, budget = self.plan_running(pool, lens)
         return self._admission(pool, 0, budget, 0, spare) is not None
 
     def schedule(self, pool) -> StepPlan:
@@ -348,13 +359,13 @@ class Scheduler:
         here: it is legal only while ``would_admit`` says this method
         would admit nothing, waiting requests or none.
 
-        Sarathi-style step packing (``_plan_running``): each decode-phase
+        Sarathi-style step packing (``plan_running``): each decode-phase
         running row costs 1 budget token; running rows still mid-prefill
         take up to chunk_size more of their prompt; what's left admits
         queued requests at chunk granularity (FCFS, ``_admission``), while
         a row is free. A sole request is always admitted even with
         budget < 1 (it could never start otherwise)."""
-        chunks, budget = self._plan_running(pool)
+        chunks, budget = self.plan_running(pool)
         prefills: List[Request] = []
         planned_blocks = 0
         while self._has_free_row(len(prefills)):

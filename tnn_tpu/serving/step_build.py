@@ -23,11 +23,17 @@ Row layout contract (mirrored by the commit halves in engine.py):
 token plus optional speculative draft positions), then mid-prefill chunk
 rows; ``pack_decode`` is the pure-decode batch, one token per row.
 Padding rows point their tables at the pool's scratch block.
+
+Both pack a step AHEAD of its predecessors' commits too (the overlapped
+engine's predicted step): ``lens`` gives each row's predicted length in
+place of its committed ``cache_len``, and the rows named in ``on_device``
+leave their pending token zero, because it is an unfetched sample of the
+step before: the engine finishes the token array on the device.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Collection, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -135,12 +141,16 @@ def _alloc_common(b: int, nb: int, scratch: int):
 def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
                takes: Dict[int, int], *, b: int, nb: int, scratch: int,
                spec_on: bool, kv_key: Tuple[Any, ...],
-               sum_at: int = 0, kinds=None) -> MixedStep:
+               sum_at: int = 0, kinds=None,
+               lens: Optional[Dict[int, int]] = None,
+               on_device: Collection[int] = ()) -> MixedStep:
     """Pack decode rows (first ``n_dec`` of ``rows``, each 1 token +
     optional draft) and prompt-chunk rows (the rest, ``takes[rid]`` tokens
     each) into one ragged batch. Host drafts land in the token matrix here;
     ``DeviceDraft`` rows are recorded in ``dev_drafts`` for the engine to
-    splice on-device (their values never touch the host)."""
+    splice on-device (their values never touch the host). ``lens`` /
+    ``on_device``: the predicted step (module docstring); a chunk row's
+    chunk starts at its predicted length."""
     widest = max([takes[r.rid] for r in rows[n_dec:]]
                  + [1 + len(drafts.get(r.rid, ())) for r in rows[:n_dec]])
     qw = pow2_bucket(widest)
@@ -154,11 +164,12 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
         n_draft=np.zeros((b,), np.int32),
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(rows):
-        step.starts[i] = req.cache_len
+        start = step.starts[i] = lens[req.rid] if lens else req.cache_len
         _fill_row(step, i, req, sum_at, kinds)
         if i < n_dec:
             d = drafts.get(req.rid, []) if spec_on else []
-            step.toks[i, 0] = req.next_token
+            if req.rid not in on_device:
+                step.toks[i, 0] = req.next_token
             if isinstance(d, spec_decode.DeviceDraft):
                 step.dev_drafts.append((i, d))
             elif d:
@@ -168,27 +179,28 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
         else:
             take = takes[req.rid]
             seq = req.resume_tokens
-            step.toks[i, :take] = seq[req.cache_len:req.cache_len + take]
+            step.toks[i, :take] = seq[start:start + take]
             step.q_lens[i] = take
     return step
 
 
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
-                kv_key: Tuple[Any, ...], ahead: int = 0,
-                sum_at: int = 0, kinds=None) -> DecodeStep:
-    """Pack the pure-decode batch. ``ahead`` = j > 0 packs the overlapped
-    engine's predicted step N+j: each row's offset assumes exactly j more
-    tokens committed, and the token column is left zero — the dispatched
-    program reads its predecessor's unfetched sampled tokens directly as
-    its device-resident input."""
+                kv_key: Tuple[Any, ...], sum_at: int = 0, kinds=None,
+                lens: Optional[Dict[int, int]] = None,
+                on_device: Collection[int] = ()) -> DecodeStep:
+    """Pack the pure-decode batch. ``lens`` / ``on_device``: the predicted
+    step (module docstring): each row's offset is its predicted length, and
+    a row whose token is on the device leaves it zero. Where that is every
+    row, in their predecessor's order, the dispatched program reads its
+    predecessor's unfetched sampled tokens directly as its input."""
     step = DecodeStep(
         key=("pdecode", b, nb) + kv_key, b=b, nb=nb,
         toks=np.zeros((b,), np.int32),
         offsets=np.zeros((b,), np.int32),
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(live):
-        if not ahead:
+        if req.rid not in on_device:
             step.toks[i] = req.next_token
-        step.offsets[i] = req.cache_len + ahead
+        step.offsets[i] = lens[req.rid] if lens else req.cache_len
         _fill_row(step, i, req, sum_at, kinds)
     return step
