@@ -90,6 +90,11 @@ CHIP = dict(
     latent=dict(blocks=256, block_size=128, row=384, value=256, heads=32,
                 batch=8, table=24, chunk=64),
     experts=dict(held=8, hidden=2048, width=4096, tokens=(32, 2048)),
+    # ... and at LongCat-Flash's: rows of 640 (value 512) under 64 heads, the
+    # decode form; 16 held experts of 2,048 x 6,144 at a step's 64 rows
+    latent_wide=dict(blocks=64, block_size=128, row=640, value=512, heads=64,
+                     batch=8, table=8, chunk=None),
+    experts_wide=dict(held=16, hidden=2048, width=6144, tokens=(64,)),
     # the paged kernel under a sliding window at Trinity Large's widths: 48
     # query heads over 8 KV heads of 128, pages of 128, a window of 4,096
     # read from a table that lists only the pages a row still holds
@@ -108,6 +113,9 @@ REHEARSAL = dict(
     latent=dict(blocks=16, block_size=8, row=128, value=32, heads=4,
                 batch=3, table=6, chunk=8),
     experts=dict(held=4, hidden=256, width=128, tokens=(8, 64)),
+    latent_wide=dict(blocks=16, block_size=8, row=256, value=128, heads=8,
+                     batch=3, table=6, chunk=None),
+    experts_wide=dict(held=6, hidden=128, width=256, tokens=(8,)),
     window=dict(blocks=32, block_size=8, heads=4, kv_heads=2, head_dim=32,
                 batch=3, window=16, chunk=8),
     sampler=dict(shapes=((4, 320),), iters=2),
@@ -319,47 +327,51 @@ def _latent_and_expert_kernels(cfg, rand, rng) -> list:
         if not ok:
             failures.append(f"kernel {name}: error above {tol}")
 
-    k = cfg["latent"]
-    B, nb, bs, h = k["batch"], k["table"], k["block_size"], k["heads"]
-    pages = rand((2, k["blocks"], 1, bs, k["row"]))
-    tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
-    cap = nb * bs
-    kv_lens = jnp.asarray(np.array([1, bs, bs + 1, cap // 3, cap // 2 - 1,
-                                    cap - bs - 5, cap - 24, cap])[:B]
-                          .clip(1, cap), jnp.int32)
-    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
-        q = rand((B, qw, h, k["row"]))
-        q_lens = jnp.minimum(jnp.asarray(
-            np.array([qw, 1, qw // 2 + 1] * B)[:B], jnp.int32), kv_lens)
-        kw = dict(value_dim=k["value"], q_lens=q_lens, layer=1,
-                  scale=k["row"] ** -0.5)
-        check(f"mla {fname}",
-              mla_attention(q, pages, tables, kv_lens, backend="pallas",
-                            interpret=interpret, **kw),
-              mla_attention(q, pages, tables, kv_lens, backend="xla", **kw),
-              KERNEL_TOL["bf16"])
-    e = cfg["experts"]
-    n, f, d = e["held"], e["hidden"], e["width"]
-    gate, up, down = (rand((n, f, d)) * (d ** -0.5) for _ in range(3))
-    for tokens in e["tokens"]:
-        tile = row_tile(4 * tokens)
-        # every expert but the last gets rows (uneven), two tiles are dead
-        sizes = rng.multinomial(tokens, np.ones(n - 1) / (n - 1))
-        tile_expert = np.repeat(np.arange(n - 1), -(-sizes // tile))
-        live = len(tile_expert)
-        tile_expert = np.concatenate([tile_expert, [tile_expert[-1]] * 2])
-        x = np.zeros((len(tile_expert) * tile, d), np.float32)
-        at = 0
-        for cnt in sizes:
-            x[at:at + cnt] = rng.standard_normal((cnt, d))
-            at += -(-cnt // tile) * tile
-        args = (jnp.asarray(x, jnp.bfloat16), gate, up, down,
-                jnp.asarray(tile_expert, jnp.int32), jnp.int32(live))
-        got = expert_gmm(*args, tile=tile, backend="pallas",
-                         interpret=interpret)
-        want = expert_gmm(*args, tile=tile, backend="xla")
-        check(f"expert_gmm {tokens} rows, tile {tile}", got[:live * tile],
-              want[:live * tile], 4 * KERNEL_TOL["bf16"])
+    for k in (cfg["latent"], cfg["latent_wide"]):
+        B, nb, bs, h = k["batch"], k["table"], k["block_size"], k["heads"]
+        pages = rand((2, k["blocks"], 1, bs, k["row"]))
+        tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)),
+                             jnp.int32)
+        cap = nb * bs
+        kv_lens = jnp.asarray(np.array(
+            [1, bs, bs + 1, cap // 3, cap // 2 - 1, cap - bs - 5, cap - 24,
+             cap])[:B].clip(1, cap), jnp.int32)
+        for qw in filter(None, (1, k["chunk"])):
+            q = rand((B, qw, h, k["row"]))
+            q_lens = jnp.minimum(jnp.asarray(
+                np.array([qw, 1, qw // 2 + 1] * B)[:B], jnp.int32), kv_lens)
+            kw = dict(value_dim=k["value"], q_lens=q_lens, layer=1,
+                      scale=k["row"] ** -0.5)
+            check(f"mla {'decode' if qw == 1 else f'chunk{qw}'} "
+                  f"{h} heads x {k['row']}",
+                  mla_attention(q, pages, tables, kv_lens, backend="pallas",
+                                interpret=interpret, **kw),
+                  mla_attention(q, pages, tables, kv_lens, backend="xla",
+                                **kw), KERNEL_TOL["bf16"])
+    for e in (cfg["experts"], cfg["experts_wide"]):
+        n, f, d = e["held"], e["hidden"], e["width"]
+        gate, up, down = (rand((n, f, d)) * (d ** -0.5) for _ in range(3))
+        for tokens in e["tokens"]:
+            tile = row_tile(4 * tokens)
+            # every expert but the last gets rows (uneven), two tiles are
+            # dead
+            sizes = rng.multinomial(tokens, np.ones(n - 1) / (n - 1))
+            tile_expert = np.repeat(np.arange(n - 1), -(-sizes // tile))
+            live = len(tile_expert)
+            tile_expert = np.concatenate([tile_expert, [tile_expert[-1]] * 2])
+            x = np.zeros((len(tile_expert) * tile, d), np.float32)
+            at = 0
+            for cnt in sizes:
+                x[at:at + cnt] = rng.standard_normal((cnt, d))
+                at += -(-cnt // tile) * tile
+            args = (jnp.asarray(x, jnp.bfloat16), gate, up, down,
+                    jnp.asarray(tile_expert, jnp.int32), jnp.int32(live))
+            got = expert_gmm(*args, tile=tile, backend="pallas",
+                             interpret=interpret)
+            want = expert_gmm(*args, tile=tile, backend="xla")
+            check(f"expert_gmm {tokens} rows x {d}, tile {tile}",
+                  got[:live * tile], want[:live * tile],
+                  4 * KERNEL_TOL["bf16"])
     return failures
 
 

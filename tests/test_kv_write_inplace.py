@@ -275,6 +275,65 @@ def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
     assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
 
 
+# -- the shortcut block's step (PR 41): two cache layers a block --------------------
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk32"])
+def test_shortcut_step_compiles_for_the_chip_with_no_pool_copy(
+        form, one_chip, no_compile_cache, alarm):
+    """LongCat-Flash's block as published (64 heads over latent rows of 512 +
+    64 in 640 lanes, both rank scales, two dense feed-forwards of 12,288, a
+    router of 768 with 12 a token, experts of 2,048 x 6,144; 4 of them held
+    here and ONE block so that the compile is quick), pages of 128, 64 rows:
+    the chip's compiler accepts ``tnn_mla_attention`` at 64 heads and a row of
+    640 (a chunk of 32 is four query tiles of 512 rows) and
+    ``tnn_expert_gmm`` at this width, in the decode and the 64 x 32 mixed
+    form, and the step around them makes NO pool-shaped copy of the pool's
+    TWO layers, written one after the other by the block's two attentions.
+    The mixed step's 2,048 tokens x 12 picks go through the held experts in
+    8 slices of 256 tokens (a sorted buffer of 3,072 + 4 x 128 rows, not of
+    24,576 + 4 x 128: ``nn.moe.SORTED_BYTES``)."""
+    from tnn_tpu import models
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    model = models.create("longcat_flash_ep32", num_layers=1, held_experts=4)
+    assert model.cache_layers == 2 and model.latent_row == 640
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    shape = PagedKVPool(2, 1, model.latent_row, 2, 128, dtype=jnp.bfloat16,
+                        latent=True).page_shape
+    shape = shape[:1] + (256,) + shape[2:]
+    assert shape == (2, 256, 1, 128, 640)
+    pages, stub = spec(shape, jnp.bfloat16), spec((2, 1, 1, 8, 128),
+                                                   jnp.bfloat16)
+    tables, lens = spec((64, 64), jnp.int32), spec((64,), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(model.apply_decode_paged,
+                              donate_argnums=(2, 3)).lower(
+                params, spec((64,), jnp.int32), pages, stub, tables, lens)
+        else:
+            lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
+                params, spec((64, 32), jnp.int32), pages, stub, tables, lens,
+                lens)
+        text = lowered.compile().as_text()
+    assert "tnn_mla_attention" in text and "tnn_expert_gmm" in text
+    assert "tnn_paged_attention" not in text
+    assert ("bf16[3584,6144]" in text) == (form == "chunk32") \
+        and "bf16[25088,6144]" not in text
+    dims = ",".join(map(str, shape))
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+    assert not [line for line in text.splitlines() if pool.search(line)]
+    layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
+                         text.split("\n", 1)[0].split(")->(")[0])
+    assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
+
+
 # -- the two-group model's step (PR 37): both kinds of layer in one program -----
 
 

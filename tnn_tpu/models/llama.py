@@ -204,12 +204,8 @@ class LlamaBlock(Module):
             pages_v, block_tables, offsets, layer=layer, q_lens=q_lens,
             **where)
         x = self._attn_residual(params, x, h)
-        live = None
-        if self.moe is not None:
-            # a padding position takes no expert: past a row's live tokens,
-            # or (the decode form) a row whose table is the scratch page
-            live = (jnp.arange(x.shape[1])[None, :] < q_lens[:, None]
-                    if q_lens is not None else block_tables[:, :1] > 0)
+        live = _live_tokens(x, block_tables, q_lens) \
+            if self.moe is not None else None
         return self._mlp_residual(params, x, live=live), pages_k, pages_v
 
     def output_shape(self, input_shape):
@@ -224,6 +220,119 @@ class LlamaBlock(Module):
             cfg["kv_cache_dtype"] = self.kv_cache_dtype
         cfg.update(_block_options(self))
         return cfg
+
+
+def _live_tokens(x, block_tables, q_lens):
+    """(B, Q) bool: a step's tokens that are no padding, which take no
+    expert: past a row's live tokens, or (the decode form) a row whose table
+    is the scratch page."""
+    if q_lens is not None:
+        return jnp.arange(x.shape[1])[None, :] < q_lens[:, None]
+    return block_tables[:, :1] > 0
+
+
+@register_module("shortcut_block")
+class ShortcutBlock(Module):
+    """A block of TWO attention sublayers and two dense feed-forwards with
+    ONE expert layer across them (shortcut-connected experts): the experts
+    read the first sublayer's post-attention norm and their output is added
+    at the END of the second, so that (on a deployment that divides the
+    experts over chips) their exchange overlaps the first dense feed-forward
+    and the whole second attention.
+
+        a0 = x  + attn_0(rms(x));   h0 = rms(a0);   s = experts(h0)
+        b0 = a0 + mlp_0(h0)
+        a1 = b0 + attn_1(rms(b0))
+        y  = a1 + mlp_1(rms(a1)) + s
+
+    The block is GIVEN its parts: ``a0`` and ``a1`` are two dense
+    ``LlamaBlock`` s of the keywords ``block`` (leaves and scopes under
+    ``a0`` / ``a1`` as a Llama block has them), ``moe`` an ``ExpertShare``
+    of the keywords ``experts``. Each attention keeps cache rows of its own:
+    the block takes TWO layers of the pool (``cache_layers``)."""
+
+    cache_layers = 2
+
+    def __init__(self, experts: dict, name=None, policy=None, **block):
+        super().__init__(name=name, policy=policy)
+        self.experts, self.block = dict(experts), dict(block)
+        self.halves = [LlamaBlock(policy=self.policy, **block)
+                       for _ in range(self.cache_layers)]
+        self.moe = ExpertShare(policy=self.policy, **self.experts)
+
+    def _init(self, rng, input_shape):
+        keys = jax.random.split(rng, 3)
+        params = {f"a{j}": half.init(keys[j], input_shape)["params"]
+                  for j, half in enumerate(self.halves)}
+        params["moe"] = self.moe.init(keys[2], input_shape)["params"]
+        return params, {}
+
+    def _block(self, params, x, attention, live=None):
+        """The block's equations; ``attention(j, variables, h)`` is
+        sublayer ``j``'s attention of its normed input (plain, cached or
+        paged: the caller's)."""
+        (first, second), (p0, p1) = self.halves, (params["a0"], params["a1"])
+
+        def attend(j, half, p, x):
+            h = attention(j, {"params": p["attn"], "state": {}},
+                          half._ln1(p, x))
+            return half._attn_residual(p, x, h)
+
+        with jax.named_scope("a0"):
+            a0 = attend(0, first, p0, x)
+            with jax.named_scope("mlp"):
+                h0 = first.ln2.apply({"params": p0["ln2"], "state": {}},
+                                     a0)[0]
+            s, _ = self.moe.apply({"params": params["moe"], "state": {}}, h0,
+                                  live=live)
+            with jax.named_scope("mlp"):
+                b0 = first._add(a0, first._swiglu(p0, h0, False))
+        with jax.named_scope("a1"):
+            y = second._mlp_residual(p1, attend(1, second, p1, b0))
+            with jax.named_scope("moe_shortcut"):
+                return y + s.astype(y.dtype)
+
+    def _apply(self, params, state, x, *, train, rng):
+        return self._block(
+            params, x, lambda j, v, h: self.halves[j].attn.apply(
+                v, h, train=train, rng=None)[0]), state
+
+    def init_cache(self, batch: int, max_len: int, d_model: int):
+        return [half.init_cache(batch, max_len, d_model)
+                for half in self.halves]
+
+    def apply_cached(self, params, x, cache, offset):
+        new = list(cache)
+
+        def attention(j, v, h):
+            out, new[j] = self.halves[j].attn.apply_cached(
+                v, h, cache[j], offset)
+            return out
+
+        return self._block(params, x, attention), new
+
+    def apply_paged(self, params, x, pages_k, pages_v, block_tables, offsets,
+                    layer, q_lens=None):
+        """``layer``: the block's two layers of the pool, the first
+        attention's and the second's."""
+        pages = [pages_k, pages_v]
+
+        def attention(j, v, h):
+            out, pages[0], pages[1] = self.halves[j].attn.apply_paged(
+                v, h, pages[0], pages[1], block_tables, offsets,
+                layer=layer[j], q_lens=q_lens)
+            return out
+
+        y = self._block(params, x, attention,
+                        _live_tokens(x, block_tables, q_lens))
+        return y, pages[0], pages[1]
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def _config(self):
+        return dict(self.block, experts=dict(
+            self.experts, held=list(self.experts["held"])))
 
 
 _BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
@@ -268,8 +377,16 @@ class Llama(PagedDecoder, Module):
                  experts: Optional[dict] = None,
                  gated: Optional[dict] = None, sandwich: bool = False,
                  embed_scale: bool = False, num_dense_layers: int = 0,
-                 dense_hidden: Optional[int] = None, name=None, policy=None):
+                 dense_hidden: Optional[int] = None, shortcut: bool = False,
+                 name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        # ``shortcut``: every block is a ``ShortcutBlock``: two attention
+        # sublayers and two dense feed-forwards of ``mlp_hidden`` with the
+        # model's ``experts`` across them; ``num_layers`` counts BLOCKS, the
+        # pool's layers are ``cache_layers``
+        self.shortcut = bool(shortcut)
+        if self.shortcut and not experts:
+            raise ValueError("a shortcut block is given its experts")
         self.latent = dict(latent) if latent else None
         self.experts = dict(experts, held=list(experts["held"])) \
             if experts else None
@@ -315,28 +432,32 @@ class Llama(PagedDecoder, Module):
                              f"{self.num_layers} layers")
         self.head_dim = int(self.gated["head_dim"]) if self.gated \
             else self.d_model // self.num_heads
-        self.blocks = [LlamaBlock(num_heads, num_kv_heads=self.num_kv_heads,
-                                  rope_theta=rope_theta, backend=backend,
-                                  kv_cache_dtype=kv_cache_dtype,
-                                  norm_eps=norm_eps,
-                                  norm_unit_offset=norm_unit_offset,
-                                  residual_f32=residual_f32, window=window,
-                                  chunk=chunk, latent=latent,
-                                  sandwich=sandwich, policy=p,
-                                  **self._layer_options(i))
+        kind = ShortcutBlock if self.shortcut else LlamaBlock
+        self.blocks = [kind(num_heads=num_heads,
+                            num_kv_heads=self.num_kv_heads,
+                            rope_theta=rope_theta, backend=backend,
+                            kv_cache_dtype=kv_cache_dtype, norm_eps=norm_eps,
+                            norm_unit_offset=norm_unit_offset,
+                            residual_f32=residual_f32, window=window,
+                            chunk=chunk, latent=latent, sandwich=sandwich,
+                            policy=p, **self._layer_options(i))
                        for i in range(num_layers)]
         if self.latent:     # one cached row a token, no head axis
+            from ..ops.pallas.mla_attention import row_width
+
             self.num_kv_heads = 1
-            self.latent_row = self.blocks[0].attn.latent_row
+            self.latent_row = row_width(self.latent["kv_rank"]
+                                        + self.latent["rope_dim"])
         self.ln_f = RMSNorm(eps=norm_eps, unit_offset=norm_unit_offset,
                             policy=p)
 
     def _layer_options(self, i: int) -> dict:
         """What layer ``i`` is given that its neighbour may not be: its
         feed-forward (dense of ``dense_hidden`` among the first
-        ``num_dense_layers``, else the model's) and its kind of gated
-        attention."""
-        dense = i < self.num_dense_layers
+        ``num_dense_layers``, else the model's; a shortcut block's dense
+        feed-forwards are the model's and its experts go across them) and
+        its kind of gated attention."""
+        dense = i < self.num_dense_layers and not self.shortcut
         opts = dict(
             mlp_hidden=self.dense_hidden if dense and self.dense_hidden
             else self.mlp_hidden,
@@ -484,7 +605,8 @@ class Llama(PagedDecoder, Module):
         cfg.update(_block_options(self))
         if self.num_pred_heads != 1:
             cfg["num_pred_heads"] = self.num_pred_heads
-        for key in ("embed_scale", "num_dense_layers", "dense_hidden"):
+        for key in ("embed_scale", "num_dense_layers", "dense_hidden",
+                    "shortcut"):
             if getattr(self, key):
                 cfg[key] = getattr(self, key)
         return cfg
@@ -626,6 +748,63 @@ def trinity_large_tiny(**kw):
                      shared=1, score="sigmoid", route_scale=2.448))
     cfg.update(kw)
     return trinity_large_ep8(**cfg)
+
+
+def longcat_flash_ep32(num_layers: int = 4, held_experts: int = 16,
+                       vocab: int = 16384, **kw):
+    """LongCat-Flash-Omni's language model (https://huggingface.co/
+    meituan-longcat/LongCat-Flash-Omni, config.json) as ONE chip of 32 that
+    share each layer serves it: 4 of the 28 blocks, every width as published
+    (6,144 wide, 64 heads; a block of two latent attentions, ``q_lora_rank``
+    1,536, ``kv_lora_rank`` 512, head dims 128 + 64 and 128, both rank
+    scales, plain rotary at theta 1e7, and two dense feed-forwards of
+    12,288 with one shortcut expert layer across them), the softmax router
+    over all 768 ids (512 experts + 256 zero-compute identity experts) and
+    12 a token, selected with the bias, weighted without it, not
+    renormalised, times 6; experts 0 .. ``held_experts`` - 1 of width 2,048
+    held here, rows 0 .. ``vocab`` - 1 of the 131,072-token vocabulary, bf16
+    weights. The audio and vision towers and the codec decoder are not
+    served."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    cfg = dict(
+        vocab_size=vocab, max_len=131072, d_model=6144, num_heads=64,
+        mlp_hidden=12288,
+        latent=dict(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                    v_dim=128, rope=dict(rope_theta=10000000.0)),
+        experts=dict(num_experts=512, zero_experts=256,
+                     held=range(held_experts), top_k=12, hidden=2048,
+                     score="softmax_raw", route_scale=6.0))
+    cfg.update(kw)
+    d, lat = cfg["d_model"], cfg["latent"]
+    # mla_scale_q_lora / mla_scale_kv_lora
+    cfg["latent"] = dict(lat, q_scale=(d / lat["q_rank"]) ** 0.5,
+                         kv_scale=(d / lat["kv_rank"]) ** 0.5)
+    return Llama(num_layers=num_layers, tie_embeddings=False, norm_eps=1e-5,
+                 residual_f32=True, shortcut=True, **cfg)
+
+
+def longcat_flash_tiny(**kw):
+    """LongCat-Flash's block at test sizes: 2 blocks (4 cache layers), 64
+    wide, 4 heads, latent 32 + 16 (query rank 48: rank scales 1.155 and
+    1.414), dense feed-forwards of 128, a router of 24 (16 experts + 8
+    zero-compute) and 6 a token, 8 experts of width 32 held. Float32 unless
+    told otherwise (``mistral_small4_tiny`` says why)."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="float32", param="float32",
+                                        compute="float32"))
+    cfg = dict(
+        num_layers=2, vocab=256, max_len=256, d_model=64, num_heads=4,
+        mlp_hidden=128,
+        latent=dict(q_rank=48, kv_rank=32, nope_dim=16, rope_dim=16, v_dim=16,
+                    rope=dict(rope_theta=10000.0)),
+        experts=dict(num_experts=16, zero_experts=8, held=range(8), top_k=6,
+                     hidden=32, score="softmax_raw", route_scale=6.0))
+    cfg.update(kw)
+    return longcat_flash_ep32(**cfg)
 
 
 def llama_small(**kw):

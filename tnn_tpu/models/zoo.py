@@ -81,6 +81,10 @@ register("trinity_large_ep8")(
     lambda **kw: llama_lib.trinity_large_ep8(**kw))
 register("trinity_large_tiny")(
     lambda **kw: llama_lib.trinity_large_tiny(**kw))
+register("longcat_flash_ep32")(
+    lambda **kw: llama_lib.longcat_flash_ep32(**kw))
+register("longcat_flash_tiny")(
+    lambda **kw: llama_lib.longcat_flash_tiny(**kw))
 register("gpt2_medium")(lambda **kw: gpt2_lib.gpt2_medium(**kw))
 register("gpt2_large")(lambda **kw: gpt2_lib.gpt2_large(**kw))
 register("flash_gpt2_small")(lambda **kw: gpt2_lib.gpt2_small(backend="pallas", **kw))

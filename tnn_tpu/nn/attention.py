@@ -662,11 +662,12 @@ class _ProjectAndNorm:
 
         return qmatmul(x, self.policy.cast_param(w)).astype(x.dtype)
 
-    def _norm(self, x, gain):
+    def _norm(self, x, gain, scale: float = 1.0):
+        """RMSNorm in float32, times ``scale`` before the cast back."""
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + self.norm_eps)
-                * gain.astype(jnp.float32)).astype(x.dtype)
+        y = xf * jax.lax.rsqrt(ms + self.norm_eps) * gain.astype(jnp.float32)
+        return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
 @register_module("latent_attention")
@@ -679,10 +680,18 @@ class LatentAttention(_ProjectAndNorm, Module):
     ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` -> heads of ``[q_nope | q_rope]``;
     ``[c_kv | k_r] = x W_kva``, ``c_kv = norm(c_kv)``, ``k_rope = rope(k_r)``
     (one for all heads); ``[k_nope | v] = c_kv W_kvb`` a head. Rotary over
-    adjacent pairs at YaRN frequencies; the softmax scale is
+    adjacent pairs at YaRN frequencies (plain ``theta^(-2i/d)`` where the
+    ``rope`` dict carries no ``factor``); the softmax scale is
     ``(nope + rope)^-1/2 * m^2`` with ``m = 0.1 * mscale_all_dim * ln(factor)
     + 1``, and the query of position ``t`` is first scaled by ``1 +
     scaling_beta * ln(1 + floor(t / original))``.
+
+    ``q_scale`` / ``kv_scale``: the rank scales of a model that multiplies
+    every query head by ``(d_model / q_rank)^1/2`` and the normed ``c_kv`` by
+    ``(d_model / kv_rank)^1/2``. Both are folded into the float32 norm of
+    their latent (``W_qb`` is linear, so scaling ``c_q`` scales the heads):
+    the row that is CACHED is ``kv_scale * norm(c_kv)``, and ``W_kvb``, the
+    absorbed one too, is the checkpoint's.
 
     ``_apply`` / ``apply_cached`` are the plain EXPANDED form (keys and
     values of every head made from ``c_kv``). ``apply_paged`` is the
@@ -694,9 +703,11 @@ class LatentAttention(_ProjectAndNorm, Module):
 
     def __init__(self, num_heads: int, q_rank: int, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int, rope: dict,
-                 norm_eps: float = 1e-6, backend: str = "xla", name=None,
+                 norm_eps: float = 1e-6, backend: str = "xla",
+                 q_scale: float = 1.0, kv_scale: float = 1.0, name=None,
                  policy=None):
         super().__init__(name=name, policy=policy)
+        self.q_scale, self.kv_scale = float(q_scale), float(kv_scale)
         self.num_heads, self.q_rank = int(num_heads), int(q_rank)
         self.kv_rank, self.nope_dim = int(kv_rank), int(nope_dim)
         self.rope_dim, self.v_dim = int(rope_dim), int(v_dim)
@@ -709,9 +720,16 @@ class LatentAttention(_ProjectAndNorm, Module):
         factor = float(r.get("factor", 1.0))
         m = 0.1 * float(r.get("mscale_all_dim", 0.0)) * math.log(factor) + 1.0
         self.scale = (self.nope_dim + self.rope_dim) ** -0.5 * m * m
-        self.inv_freq = yarn_inv_freq(
-            self.rope_dim, float(r["rope_theta"]), factor, self.original,
-            float(r.get("beta_fast", 32)), float(r.get("beta_slow", 1)))
+        if "factor" in r:
+            self.inv_freq = yarn_inv_freq(
+                self.rope_dim, float(r["rope_theta"]), factor, self.original,
+                float(r.get("beta_fast", 32)), float(r.get("beta_slow", 1)))
+        else:       # plain rotary: no ramp to take a frequency through
+            import numpy as np
+
+            self.inv_freq = (float(r["rope_theta"]) ** (-np.arange(
+                0, self.rope_dim, 2, dtype=np.float64) / self.rope_dim)
+                ).astype(np.float32)
 
     # what one cached row holds, and its width in the pool (whole lanes)
     @property
@@ -750,7 +768,8 @@ class LatentAttention(_ProjectAndNorm, Module):
         b, s, _ = x.shape
         pos = _positions(offset, b, s)
         q = self._mm(self._norm(self._mm(x, params["q_a_kernel"]),
-                                params["q_norm"]), params["q_b_kernel"])
+                                params["q_norm"], self.q_scale),
+                     params["q_b_kernel"])
         q = q.reshape(b, s, self.num_heads, self.nope_dim + self.rope_dim)
         q_rope = apply_rope_pairs(q[..., self.nope_dim:], pos[:, :, None],
                                   self.inv_freq)
@@ -760,7 +779,8 @@ class LatentAttention(_ProjectAndNorm, Module):
                 (pos // self.original).astype(jnp.float32)))[:, :, None, None]
                  ).astype(x.dtype)
         kv = self._mm(x, params["kv_a_kernel"])
-        c_kv = self._norm(kv[..., :self.kv_rank], params["kv_norm"])
+        c_kv = self._norm(kv[..., :self.kv_rank], params["kv_norm"],
+                          self.kv_scale)
         k_rope = apply_rope_pairs(kv[..., self.kv_rank:], pos, self.inv_freq)
         return q, c_kv, k_rope
 
@@ -868,11 +888,15 @@ class LatentAttention(_ProjectAndNorm, Module):
         return tuple(input_shape)
 
     def _config(self):
-        return {"num_heads": self.num_heads, "q_rank": self.q_rank,
-                "kv_rank": self.kv_rank, "nope_dim": self.nope_dim,
-                "rope_dim": self.rope_dim, "v_dim": self.v_dim,
-                "rope": self.rope, "norm_eps": self.norm_eps,
-                "backend": self.backend}
+        cfg = {"num_heads": self.num_heads, "q_rank": self.q_rank,
+               "kv_rank": self.kv_rank, "nope_dim": self.nope_dim,
+               "rope_dim": self.rope_dim, "v_dim": self.v_dim,
+               "rope": self.rope, "norm_eps": self.norm_eps,
+               "backend": self.backend}
+        for key in ("q_scale", "kv_scale"):
+            if getattr(self, key) != 1.0:
+                cfg[key] = getattr(self, key)
+        return cfg
 
 
 # -- gated grouped-query attention, sliding or global -----------------------

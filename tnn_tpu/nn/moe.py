@@ -239,6 +239,20 @@ def shard_params_ep(params, mesh, axis: str = "expert"):
 # -- one chip's share of a dropless expert layer (serving) ------------------
 
 _COUNTS = threading.local()
+# the most bytes ``ExpertShare``'s sorted buffer takes before a step's tokens
+# go through the held experts in slices (64 MiB: 8,192 assignments of 4,096
+# values in bfloat16)
+SORTED_BYTES = 64 << 20
+
+
+class _Counts(list):
+    """What ``collect_counts`` yields: the layers' ``(held,)`` counts, with
+    the ``(2,)`` counters of the layers that have zero-compute experts
+    (``ExpertShare._identity``) in a list of their own, ``zero``."""
+
+    def __init__(self):
+        super().__init__()
+        self.zero = []
 
 
 @contextlib.contextmanager
@@ -246,9 +260,11 @@ def collect_counts():
     """Inside this context every ``ExpertShare`` applied (traced) appends its
     ``(held,)`` count of assignments, in layer order, to the list it yields:
     how a serving step program returns its expert counters beside its tokens
-    without the model's ``apply_paged`` changing its signature."""
+    without the model's ``apply_paged`` changing its signature. A layer with
+    zero-compute experts appends its two counters of them to the list's
+    ``zero``."""
     prev = getattr(_COUNTS, "sink", None)
-    _COUNTS.sink = sink = []
+    _COUNTS.sink = sink = _Counts()
     try:
         yield sink
     finally:
@@ -262,12 +278,14 @@ class ExpertShare(Module):
     hidden``, added once): the share of an expert-parallel deployment that
     divides each layer over several chips.
 
-    The router is whole: float32 logits over all ``num_experts``, then
-    ``score`` "softmax": the ``top_k`` largest probabilities renormalised to
-    sum 1; or "sigmoid": scores ``p = sigmoid(logits)``, the ``top_k`` largest
-    of ``p + expert_bias`` (a leaf that only SELECTS: the balancing bias),
-    weighted by ``p`` alone, renormalised. Either times ``route_scale``. Of a
-    step's assignments
+    The router is whole: float32 logits over all ``num_experts`` (and the
+    ``zero_experts`` behind them), then ``score`` "softmax": the ``top_k``
+    largest probabilities renormalised to sum 1; or "sigmoid": scores ``p =
+    sigmoid(logits)``, the ``top_k`` largest of ``p + expert_bias`` (a leaf
+    that only SELECTS: the balancing bias), weighted by ``p`` alone,
+    renormalised; or "softmax_raw": ``p = softmax(logits)``, selected by ``p
+    + expert_bias`` likewise, weighted by ``p`` as it is, NOT renormalised.
+    Each times ``route_scale``. Of a step's assignments
     those that fall on a HELD expert are sorted by expert, each expert's
     group padded to the kernel's row tile, and ``ops.pallas.expert_gmm``
     walks the groups: an expert with no token is never fetched, and no token
@@ -275,8 +293,15 @@ class ExpertShare(Module):
     and a tile of padding an expert). What absent experts would add is left
     out; nothing here stands in for the other chips or their exchange.
 
-    Leaves: ``router`` (D, num_experts); with sigmoid scores ``expert_bias``
-    (num_experts,) float32; ``gate``, ``up``, ``down``
+    ``zero_experts``: ids ``num_experts ..`` of the router are zero-compute
+    IDENTITY experts. They have no weights and live on no chip: a token's
+    picks among them add the sum of their weights times the layer's input,
+    computed here for this chip's own tokens, never sorted into the grouped
+    product and never counted as held or absent.
+
+    Leaves: ``router`` (D, num_experts + zero_experts); unless the score is
+    "softmax", ``expert_bias`` (the router's width,) float32; ``gate``,
+    ``up``, ``down``
     (held, hidden, D): "out x in" for gate and up, "in x out" for down, so a
     block of hidden units is contiguous rows in each; the shared expert's
     ``shared_gate`` / ``shared_up`` (D, shared * hidden) and ``shared_down``
@@ -284,21 +309,25 @@ class ExpertShare(Module):
 
     def __init__(self, num_experts: int, held, top_k: int, hidden: int,
                  shared: int = 0, score: str = "softmax",
-                 route_scale: float = 1.0, name=None, policy=None):
+                 route_scale: float = 1.0, zero_experts: int = 0, name=None,
+                 policy=None):
         super().__init__(name=name, policy=policy)
-        if score not in ("softmax", "sigmoid"):
-            raise ValueError(f"score {score!r}: softmax or sigmoid")
+        if score not in ("softmax", "sigmoid", "softmax_raw"):
+            raise ValueError(f"score {score!r}: softmax, sigmoid or "
+                             "softmax_raw")
         self.score, self.route_scale = score, float(route_scale)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.zero_experts = int(zero_experts)
+        self.width = self.num_experts + self.zero_experts   # the router's
         self.held = tuple(int(e) for e in held)
         self.hidden, self.shared = int(hidden), int(shared)
         if not self.held or len(set(self.held)) != len(self.held) or not all(
                 0 <= e < self.num_experts for e in self.held):
             raise ValueError(f"held experts {self.held} are distinct ids "
                              f"below {self.num_experts}")
-        if not 1 <= self.top_k <= self.num_experts:
-            raise ValueError(f"top_k {top_k} not in [1, {num_experts}]")
-        slot = np.full((self.num_experts,), -1, np.int32)
+        if not 1 <= self.top_k <= self.width:
+            raise ValueError(f"top_k {top_k} not in [1, {self.width}]")
+        slot = np.full((self.width,), -1, np.int32)
         slot[list(self.held)] = np.arange(len(self.held))
         self._slot = slot           # expert id -> held slot, -1: elsewhere
 
@@ -311,13 +340,12 @@ class ExpertShare(Module):
             return (jax.random.normal(key, shape, jnp.float32)
                     / math.sqrt(fan_in)).astype(pd)
 
-        params = {"router": normal(ks[0], (d, self.num_experts), d),
+        params = {"router": normal(ks[0], (d, self.width), d),
                   "gate": normal(ks[1], (n, f, d), d),
                   "up": normal(ks[2], (n, f, d), d),
                   "down": normal(ks[3], (n, f, d), f)}
-        if self.score == "sigmoid":
-            params["expert_bias"] = jnp.zeros((self.num_experts,),
-                                              jnp.float32)
+        if self.score != "softmax":
+            params["expert_bias"] = jnp.zeros((self.width,), jnp.float32)
         if self.shared:
             fs = self.shared * f
             params.update(shared_gate=normal(ks[4], (d, fs), d),
@@ -328,21 +356,23 @@ class ExpertShare(Module):
     @jax.named_scope("moe_route")
     def route(self, params, x):
         """x (T, D) -> (ids (T, k) int32, weights (T, k) float32): the
-        ``top_k`` of float32 scores over ALL experts, renormalised (the
-        class says how each ``score`` selects and weighs)."""
+        ``top_k`` of float32 scores over ALL the router's ids (the class
+        says how each ``score`` selects, weighs and normalises)."""
         logits = jnp.matmul(x.astype(jnp.float32),
                             params["router"].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        if self.score == "sigmoid":
-            p = jax.nn.sigmoid(logits)
-            _, ids = jax.lax.top_k(
-                p + params["expert_bias"].astype(jnp.float32), self.top_k)
-            w = jnp.take_along_axis(p, ids, axis=-1)
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        else:
+        if self.score == "softmax":
             w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
                                    self.top_k)
             w = w / jnp.sum(w, axis=-1, keepdims=True)
+        else:
+            p = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
+                else jax.nn.softmax(logits, axis=-1)
+            _, ids = jax.lax.top_k(
+                p + params["expert_bias"].astype(jnp.float32), self.top_k)
+            w = jnp.take_along_axis(p, ids, axis=-1)
+            if self.score == "sigmoid":
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         if self.route_scale != 1.0:
             w = w * self.route_scale
         return ids.astype(jnp.int32), w
@@ -384,16 +414,41 @@ class ExpertShare(Module):
         return src, dest, tile_expert.astype(jnp.int32), live_tiles, counts
 
     def routed(self, params, x, live=None):
-        """What the HELD experts add for x (T, D): (y (T, D) float32, counts
-        (held,) int32 of the assignments each held expert took). ``live``
-        (T,) bool: a dead token takes no expert and counts nowhere."""
-        from ..ops.pallas.expert_gmm import expert_gmm, row_tile
+        """What the HELD experts (and the zero-compute ones) add for x (T,
+        D): (y (T, D) float32, counts (held,) int32 of the assignments each
+        held expert took). ``live`` (T,) bool: a dead token takes no expert
+        and counts nowhere.
 
-        t = x.shape[0]
+        The sorted buffer has room for every assignment, so a step whose
+        buffer would pass ``SORTED_BYTES`` (a prompt step of a wide model
+        with many picks a token) sorts and multiplies its tokens a slice at
+        a time: the same sums, the held weights read once a slice."""
+        t, d = x.shape
         if live is None:
             live = jnp.ones((t,), bool)
         ids, w = self.route(params, x)
-        tile = row_tile(t * self.top_k)
+        row = self.top_k * d * jnp.dtype(self.policy.compute_dtype).itemsize
+        fit = max(1, SORTED_BYTES // row)   # tokens whose picks the bound holds
+        parts = next(p for p in range(1, t + 1)
+                     if t % p == 0 and t // p <= fit)
+        if parts == 1:
+            y, counts = self._held(params, x, ids, w, live)
+        else:
+            y, counts = jax.lax.map(
+                lambda part: self._held(params, *part),
+                tuple(v.reshape((parts, t // parts) + v.shape[1:])
+                      for v in (x, ids, w, live)))
+            y, counts = y.reshape(t, d), jnp.sum(counts, axis=0)
+        if self.zero_experts:
+            y = self._identity(y, x, ids, w, live)
+        return y, counts
+
+    def _held(self, params, x, ids, w, live):
+        """The held experts' weighted sum for routed tokens x (T, D), and
+        their counts."""
+        from ..ops.pallas.expert_gmm import expert_gmm, row_tile
+
+        tile = row_tile(x.shape[0] * self.top_k)
         src, dest, tile_expert, live_tiles, counts = self._sort(
             ids, live, tile)
         cast = self.policy.cast_param
@@ -407,6 +462,23 @@ class ExpertShare(Module):
                             ys[jnp.maximum(dest, 0)].astype(jnp.float32), 0.0)
             y = jnp.sum(got * w[..., None], axis=1)
         return y, counts
+
+    @jax.named_scope("moe_zero")
+    def _identity(self, y, x, ids, w, live):
+        """``y`` plus what a token's picks among the zero-compute experts
+        add (their weights' sum times the token's own row). Inside
+        ``collect_counts`` the layer's two counters of them go to the list's
+        ``zero``: the live tokens' picks that fell on them, and the most
+        REAL experts (held here or elsewhere) any live token picked."""
+        zero = (ids >= self.num_experts) & live[:, None]
+        y = y + jnp.sum(jnp.where(zero, w, 0.0), axis=1)[:, None] \
+            * x.astype(jnp.float32)
+        sink = getattr(_COUNTS, "sink", None)
+        if sink is not None:
+            real = jnp.where(live, self.top_k - jnp.sum(zero, axis=1), 0)
+            sink.zero.append(
+                jnp.stack([jnp.sum(zero), jnp.max(real)]).astype(jnp.int32))
+        return y
 
     @jax.named_scope("moe_shared")
     def shared_out(self, params, x):
@@ -445,4 +517,6 @@ class ExpertShare(Module):
             cfg["score"] = self.score
         if self.route_scale != 1.0:
             cfg["route_scale"] = self.route_scale
+        if self.zero_experts:
+            cfg["zero_experts"] = self.zero_experts
         return cfg

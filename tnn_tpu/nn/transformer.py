@@ -38,7 +38,8 @@ class PagedDecoder:
         toks is (B, Q) with row b carrying ``q_lens[b]`` live new tokens
         starting at position ``offsets[b]`` (the rest padding: their KV lands
         in the pool's scratch page, their logits are garbage); pages_k /
-        pages_v the pool's (L, N, H_kv / p, bs, p * Dh) arrays, L == num_layers;
+        pages_v the pool's (L, N, H_kv / p, bs, p * Dh) arrays, L ==
+        ``cache_layers``;
         block_tables (B, nb) page ids. Every layer writes its new K/V rows
         into their pages and attends over the tables (the block's
         ``apply_paged``): no contiguous cache is ever assembled. Returns
@@ -55,12 +56,26 @@ class PagedDecoder:
                     q_lens=q_lens, **where[i])
         return self._head(params, self._ln_f(params, x)), pages_k, pages_v
 
+    @property
+    def cache_layers(self) -> int:
+        """The layers of the pool this model writes: one a block, or as many
+        as a block of several attention sublayers says (its
+        ``cache_layers``). The engine sizes the pool by this, not by
+        ``num_layers``."""
+        return sum(getattr(b, "cache_layers", 1) for b in self.blocks)
+
     def _paged_layers(self, pages_k, block_tables):
         """Per block, the keywords of its ``apply_paged`` that say where its
         pages are: the step's one table and the block's own layer of the
-        pool, unless the model keeps its layers' pages otherwise."""
-        return [dict(block_tables=block_tables, layer=i)
-                for i in range(len(self.blocks))]
+        pool (a block of several attention sublayers: its layers, as a
+        tuple), unless the model keeps its layers' pages otherwise."""
+        out, at = [], 0
+        for block in self.blocks:
+            n = getattr(block, "cache_layers", 1)
+            out.append(dict(block_tables=block_tables,
+                            layer=at if n == 1 else tuple(range(at, at + n))))
+            at += n
+        return out
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
                            offsets):

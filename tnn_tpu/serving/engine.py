@@ -163,9 +163,11 @@ _ON_HOST = -1
 
 
 def _stacked(counts) -> tuple:
-    """A step program's expert counters as one more output, ``(layers,
-    held)``, or none: a model with no expert layer returns what it did."""
-    return (jnp.stack(counts),) if counts else ()
+    """A step program's expert counters as more outputs, ``(layers, held)``
+    and, of a model with zero-compute experts, ``(layers, 2)``
+    (``nn.moe.collect_counts``), or none: a model with no expert layer
+    returns what it did."""
+    return tuple(jnp.stack(c) for c in (counts, counts.zero) if c)
 
 
 def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
@@ -528,7 +530,7 @@ class InferenceEngine:
         else:
             page_sharding = None
         self.pool = PagedKVPool(
-            num_layers=1 if groups else model.num_layers,
+            num_layers=1 if groups else model.cache_layers,
             num_kv_heads=model.num_kv_heads,
             head_dim=self.head_dim, num_blocks=num_blocks,
             block_size=block_size, dtype=model.policy.compute_dtype,
@@ -1013,13 +1015,15 @@ class InferenceEngine:
             / (pool.capacity * pool.block_size))
 
     def _observe_experts(self, experts, tokens: int) -> None:
-        """A step's ``(layers, held)`` expert counters, fetched with its
+        """A step's expert counters (``_stacked``), fetched with its
         ``tokens`` live tokens' samples (nothing for a model with no expert
         layer): held over all assignments, the held experts hit, the load's
-        imbalance."""
+        imbalance; with zero-compute experts, the picks that fell on them
+        and the most real experts a token picked."""
         if experts:
             self.metrics.observe_experts(
-                np.asarray(experts[0]), tokens * self.model.experts["top_k"])
+                tokens, self.model.experts["top_k"],
+                *(np.asarray(e) for e in experts))
 
     # -- per-request latency breakdown (host-side clocks only) ----------------
 

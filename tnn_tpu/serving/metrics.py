@@ -549,6 +549,12 @@ class ServingMetrics:
         self.expert_layer_steps = 0
         self.expert_hit_sum = 0.0
         self.expert_imbalance_sum = 0.0
+        # ... and zero-compute experts: a live token's picks that fell on
+        # them; the tokens a layer routed; over layer-steps, the most real
+        # experts a token picked over the mean ("compute a token varies")
+        self.expert_zero_picks = 0
+        self.expert_layer_tokens = 0
+        self.expert_picks_spread_sum = 0.0
         # runtime-resilience counters (supervisor / overload degradation)
         self.shed = 0                 # queued requests displaced by priority
         self.engine_restarts = 0      # supervisor-driven engine recoveries
@@ -758,14 +764,25 @@ class ServingMetrics:
         self.dispatched_steps += 1
         self.sampled_steps += int(sampled_rows > 0)
 
-    def observe_experts(self, counts, assignments: int) -> None:
-        """One step of a model with an expert layer: ``counts`` (layers,
-        held) the assignments each held expert took in each layer,
-        ``assignments`` all the step's (live token, expert) pairs a layer,
-        those that fell on experts held elsewhere too."""
+    def observe_experts(self, tokens: int, top_k: int, counts,
+                        zero=None) -> None:
+        """One step of a model with an expert layer, ``tokens`` live tokens
+        of ``top_k`` picks each: ``counts`` (layers, held) the assignments
+        each held expert took in each layer, out of all the step's (live
+        token, expert) pairs a layer, those that fell on experts held
+        elsewhere too. ``zero`` (layers, 2), of a model with zero-compute
+        experts: the picks that fell on them, and the most REAL experts any
+        one token picked."""
         layers = counts.shape[0]
+        assignments = tokens * top_k
+        if zero is not None and tokens:
+            self.expert_zero_picks += int(zero[:, 0].sum())
+            self.expert_layer_tokens += tokens * layers
+            mean = (assignments - zero[:, 0]) / tokens
+            self.expert_picks_spread_sum += float(
+                (zero[:, 1] / (mean + (mean == 0))).sum())
         self.expert_held_assignments += int(counts.sum())
-        self.expert_assignments += int(assignments) * layers
+        self.expert_assignments += assignments * layers
         self.expert_layer_steps += layers
         self.expert_hit_sum += float((counts > 0).mean(axis=1).sum())
         mean = counts.mean(axis=1)
@@ -1188,6 +1205,15 @@ class ServingMetrics:
                                    / self.expert_layer_steps),
                 expert_load_max_over_mean=(self.expert_imbalance_sum
                                            / self.expert_layer_steps))
+        if self.expert_layer_tokens:
+            # ... and only one with zero-compute experts has these
+            real = self.expert_assignments - self.expert_zero_picks
+            out.update(
+                zero_pick_share=(self.expert_zero_picks
+                                 / max(self.expert_assignments, 1)),
+                ffn_picks_per_token_mean=real / self.expert_layer_tokens,
+                ffn_picks_max_over_mean=(self.expert_picks_spread_sum
+                                         / self.expert_layer_steps))
         return out
 
     # -- Prometheus exposition ------------------------------------------------
