@@ -100,6 +100,10 @@ CHIP = dict(
     # read from a table that lists only the pages a row still holds
     window=dict(blocks=288, block_size=128, heads=48, kv_heads=8,
                 head_dim=128, batch=8, window=4096, chunk=64),
+    # the paged kernel at GPT-2 large's packed shape, 36 layers a call: what
+    # a decode row costs in a launch as wide as a prompt chunk
+    short_rows=dict(layers=36, blocks=1024, heads=20, block_size=16,
+                    head_dim=64, batch=16, table=64, chunk=64, iters=10),
     # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
     # 32 rows of Mistral Small 4's slice
     sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
@@ -118,6 +122,8 @@ REHEARSAL = dict(
     experts_wide=dict(held=6, hidden=128, width=256, tokens=(8,)),
     window=dict(blocks=32, block_size=8, heads=4, kv_heads=2, head_dim=32,
                 batch=3, window=16, chunk=8),
+    short_rows=dict(layers=1, blocks=32, heads=4, block_size=16, head_dim=64,
+                    batch=3, table=8, chunk=16, iters=1),
     sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
@@ -261,7 +267,70 @@ def phase_kernel(cfg) -> list:
                             f"kernel {fname}/{pname}/stats={stats}/"
                             f"holes={tname}: error above {tol}")
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
-        + _window_kernel(cfg, rand, rng) + _sampler_steps(cfg, rng)
+        + _window_kernel(cfg, rand, rng) + _short_rows(cfg, rand, rng) \
+        + _sampler_steps(cfg, rng)
+
+
+def _short_rows(cfg, rand, rng) -> list:
+    """What a short row costs in a wide launch: the paged kernel alone over
+    every layer of a pool of two heads a page row, all rows but one decoding
+    beside ONE prompt chunk, timed from the host around
+    ``block_until_ready`` against the same launch with every row a full
+    chunk and against the decode form, whose rows the wide launch's decode
+    rows must equal."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas.paged_attention import paged_attention
+
+    k = cfg["short_rows"]
+    L, N, H, bs, dh = (k["layers"], k["blocks"], k["heads"],
+                       k["block_size"], k["head_dim"])
+    B, nb, qw = k["batch"], k["table"], k["chunk"]
+    pk, pv = (rand((L, N, H // 2, bs, 2 * dh)) for _ in range(2))
+    kv_lens = rng.integers(qw + 1, nb * bs * 4 // 5, B).astype(np.int32)
+    tables = jnp.asarray(rng.integers(1, N, (B, nb)), jnp.int32)
+    q = rand((B, qw, H, dh))
+
+    def launch(q_lens):
+        ql = None if q_lens is None else jnp.asarray(q_lens, jnp.int32)
+
+        @jax.jit
+        def every_layer(q, pk, pv):
+            return sum(paged_attention(
+                q, pk, pv, tables, jnp.asarray(kv_lens), q_lens=ql, layer=i,
+                backend="pallas", interpret=cfg["rehearse"]
+            ).astype(jnp.float32) for i in range(L))
+
+        x = q if q_lens is not None else q[:, 0]
+        out = np.asarray(every_layer(x, pk, pv))           # compiles
+        took = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(k["iters"]):
+                last = every_layer(x, pk, pv)
+            jax.block_until_ready(last)
+            took.append((time.perf_counter() - t0) / k["iters"] * 1e3)
+        return out, min(took)
+
+    decode, t_decode = launch(None)
+    short, t_short = launch([1] * (B - 1) + [qw])
+    _, t_full = launch([qw] * B)
+    name = f"paged mixed {B - 1} x 1 + 1 x {qw}"
+    log(f"{name}: {t_short:.3f} ms a {L} layers, against paged mixed "
+        f"{B} x {qw}: {t_full:.3f} ms")
+    log(f"{name}: {t_short:.3f} ms a {L} layers, against the decode form "
+        f"{B} x 1: {t_decode:.3f} ms (host clock, the best of three times "
+        f"{k['iters']} calls each)")
+    tol = L * KERNEL_TOL["pack2"]
+    err = float(np.max(np.abs(short[:B - 1, 0] - decode[:B - 1])))
+    dead = float(np.max(np.abs(short[:B - 1, 1:])))
+    if not (err <= tol and dead == 0.0):
+        return [f"kernel {name}: its decode rows differ from the decode "
+                f"form's by {err:.2e} (tol {tol:.0e}), dead positions read "
+                f"{dead:.2e}"]
+    return []
 
 
 def _window_kernel(cfg, rand, rng) -> list:

@@ -864,3 +864,199 @@ def test_attn_fetch_fill_mean_is_a_hand_count():
     eng.run_until_complete()
     assert eng.metrics.attn_fetch_row_steps >= 4 + 3
     assert eng.metrics.summary()["attn_fetch_fill_mean"] < want
+
+
+# -- query tiles: a short row in a wide launch computes its first tile alone ---
+#
+# A row's ``Q * g`` query rows are cut into tiles of one register's sublanes
+# (``pa.query_tile``); a grid step of a row whose live query rows fit the
+# first tile computes that tile alone, so a decode row in a wide mixed step
+# pays for one. One launch whose rows hold nothing, one token (twice), less
+# than a tile, a tile and a bit, two tiles and a bit, and the full width;
+# contexts that end inside, at and past a group of 128 positions.
+
+_TILE_Q_LENS = [0, 1, 1, 7, 9, 17, 64]
+_TILE_KV_LENS = [200, 129, 1, 77, 132, 320, 300]
+# GPT-2 large's packed page row: 20 heads of 64, two a row -> to the kernel
+# 10 page rows with a query group of 2. Trinity's: 6 query heads a KV head
+_GPT2_LARGE = dict(heads=(20, 20), head_dim=64, pack=2)
+_TILE_FORMS = {
+    "gpt2-large": dict(_GPT2_LARGE),
+    "gpt2-large-bf16": dict(_GPT2_LARGE, dtype=jnp.bfloat16, atol=3e-2),
+    "stats": dict(_GPT2_LARGE, stats=True),
+    "holes": dict(_GPT2_LARGE, stats=True,
+                  holes=[(0, 1), (5, 3), (5, 9), (6, 0), (6, 18)]),
+    "int8": dict(heads=(20, 20), head_dim=64, pack=1, quant=True),
+    "trinity-g6": dict(heads=(12, 2), head_dim=128, pack=1),
+}
+
+
+def _tile_case(qw, *, heads, head_dim, dtype=jnp.float32, holes=()):
+    q_lens = [min(n, qw) for n in _TILE_Q_LENS]
+    return _group_case(qw + heads[0], _TILE_KV_LENS, heads=heads,
+                       head_dim=head_dim, qw=qw, q_lens=q_lens, dtype=dtype,
+                       holes=holes), q_lens
+
+
+@pytest.mark.parametrize("form", list(_TILE_FORMS))
+@pytest.mark.parametrize("qw", [64, 32])
+def test_query_tiles_of_a_ragged_launch(qw, form):
+    """Live rows to the ragged tests' tolerance against the reference, dead
+    rows (and the tiles never computed) exactly 0; with the statistics, with
+    ``-1`` holes, over int8 pages (float32 queries: tiles of 8) and at a
+    query group of 6."""
+    kw = dict(_TILE_FORMS[form])
+    pack, stats = kw.pop("pack"), kw.pop("stats", False)
+    atol, quant = kw.pop("atol", 2e-5), kw.pop("quant", False)
+    case, q_lens = _tile_case(qw, **kw)
+    q = case[0]
+    g = pack * kw["heads"][0] // kw["heads"][1]
+    tile = pa.query_tile(qw * g, q.dtype)
+    assert tile == (16 if q.dtype == jnp.bfloat16 else 8) < qw * g
+    if quant:
+        case = (q, _quantize(case[1]), _quantize(case[2])) + case[3:]
+    out = np.asarray(_assert_matches_reference(case, atol=atol, stats=stats,
+                                               pack=pack), np.float32)
+    for i, n in enumerate(q_lens):
+        assert np.all(out[i, n:] == 0), i
+    assert np.abs(out[6, :qw]).max() > 0
+
+
+@pytest.mark.parametrize("qw", [64, 8])
+def test_query_tiles_under_a_window(qw):
+    """The same launch with a lower bound a query (a window of 40 positions
+    in pages of 16: the bound lies inside a page): the first tile's mask is
+    the whole tile's over its rows."""
+    (q, pk, pv, tables, kv_lens, q_lens), lens = _tile_case(
+        qw, heads=(4, 2), head_dim=64)
+    kw = dict(q_lens=q_lens, layer=1, window=40)
+    want = pa.paged_attention(q, pk, pv, tables, kv_lens, backend="xla", **kw)
+    got = np.asarray(pa.paged_attention(q, pk, pv, tables, kv_lens,
+                                        backend="pallas", **kw))
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
+    for i, n in enumerate(lens):
+        assert np.all(got[i, n:] == 0), i
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_decode_row_in_a_wide_launch_is_the_decode_form(dtype):
+    """Rows of ``q_len`` 1 in a launch 64 wide (the first tile of the eight
+    or sixteen computed) against the same rows in the decode form."""
+    q3, pk, pv, tables, kv_lens, _ = _group_case(
+        5, [300, 129, 1, 77, 0], heads=(20, 20), head_dim=64, dtype=dtype)
+    pk, pv = _pack(pk, 2), _pack(pv, 2)
+    dec = pa.paged_attention(q3, pk, pv, tables, kv_lens, layer=1,
+                             backend="pallas")
+    wide = jnp.zeros((5, 64) + q3.shape[1:], dtype).at[:, 0].set(q3)
+    q_lens = jnp.asarray([1, 1, 1, 1, 0], jnp.int32)
+    out = np.asarray(pa.paged_attention(wide, pk, pv, tables, kv_lens,
+                                        q_lens=q_lens, layer=1,
+                                        backend="pallas"), np.float32)
+    atol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(out[:4, 0], np.asarray(dec, np.float32)[:4],
+                               atol=atol, rtol=atol)
+    assert np.all(out[:, 1:] == 0) and np.all(out[4] == 0)
+
+
+# (Q * g, queries' dtype) -> rows of a query tile
+_QUERY_TILES = {
+    "gpt2-large-decode": ((2, jnp.bfloat16), 2),
+    "gpt2-large-w8": ((16, jnp.bfloat16), 16),      # one tile: today's body
+    "gpt2-large-w64": ((128, jnp.bfloat16), 16),
+    "gpt2-large-w64-f32": ((128, jnp.float32), 8),
+    "trinity-decode": ((6, jnp.bfloat16), 6),
+    "trinity-w4": ((24, jnp.bfloat16), 24),         # 16 does not divide 24
+    "trinity-w64": ((384, jnp.bfloat16), 16),
+    "f32-w2-g6": ((12, jnp.float32), 12),
+    "f32-w4-g6": ((24, jnp.float32), 8),
+}
+
+
+@pytest.mark.parametrize("shape", list(_QUERY_TILES))
+def test_query_tile_rule(shape):
+    (qg, dtype), want = _QUERY_TILES[shape]
+    assert pa.query_tile(qg, dtype) == want
+    assert qg % want == 0
+
+
+# q_lens, g, (Q * g, tile) -> the tiles the kernel computes a row
+_TILES_COMPUTED = {
+    "w64-g2": ([0, 1, 1, 7, 8, 9, 17, 64], 2, (128, 16),
+               [0, 1, 1, 1, 1, 8, 8, 8]),
+    "w32-g2-f32": ([0, 1, 4, 5, 32], 2, (64, 8), [0, 1, 1, 8, 8]),
+    "w64-g6": ([1, 2, 3, 64], 6, (384, 16), [1, 1, 24, 24]),
+    "one-tile": ([0, 1, 5, 8], 2, (16, 16), [0, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("shape", list(_TILES_COMPUTED))
+def test_query_tiles_computed_is_a_hand_count(shape):
+    q_lens, g, (qg, tile), want = _TILES_COMPUTED[shape]
+    assert list(pa.query_tiles_computed(q_lens, g, qg, tile)) == want
+
+
+@pytest.mark.parametrize("qw", [1, 4, 8, 64])
+def test_a_launch_of_one_tile_traces_to_the_body_it_had(qw):
+    """``Q * g`` up to one tile (every decode program, GPT-2 large's chunks
+    up to 8): the three ``pl.when`` of the parent's body and nothing else.
+    Wider: two more, the first tile alone or the whole; never a loop."""
+    import re
+
+    q = jnp.zeros((2, qw, 4, 64), jnp.bfloat16)
+    pages = jnp.zeros((1, 8, 2, 16, 128), jnp.bfloat16)     # g = 2 packed
+    tables = jnp.zeros((2, 4), jnp.int32)
+    lens = jnp.asarray([5, 9], jnp.int32)
+    text = str(jax.make_jaxpr(lambda *a: pa.paged_attention(
+        *a, q_lens=jnp.minimum(lens, qw), backend="pallas",
+        interpret=False))(q, pages, pages, tables, lens))
+    assert "tnn_paged_attention" in text
+    assert len(re.findall(r"\bcond\[", text)) == (5 if qw * 2 > 16 else 3)
+    assert not re.findall(r"\bwhile\[", text)
+
+
+def test_attn_query_tile_share_is_a_hand_count():
+    """``summary()["attn_query_tile_share"]``: over the paged steps wider
+    than one query tile, the tiles the kernel computes over the tiles the
+    launches hold, with the kernel module's own tile rule."""
+    from tnn_tpu.models.gpt2 import GPT2
+    from tnn_tpu.serving import InferenceEngine
+
+    model = GPT2(vocab_size=128, max_len=512, num_layers=1, d_model=32,
+                 num_heads=2)
+    params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+    eng = InferenceEngine(model, params, num_blocks=40, block_size=16,
+                          max_batch_size=4, max_seq_len=512)
+    # both heads in ONE page row: a query group of 2, bf16 queries
+    assert eng.pool.page_shape == (1, 40, 1, 16, 32)
+    assert eng.pool.dtype == jnp.bfloat16
+    assert "attn_query_tile_share" not in eng.metrics.summary()
+    # a decode step, and a step 8 wide (16 query rows), are one tile wide:
+    # nothing to leave out, nothing counted
+    eng._observe_attention([None] * 4, np.array([1, 128, 129, 300]), 1)
+    eng._observe_attention([None] * 2, np.array([9, 40, 0, 0]), 8,
+                           np.array([8, 3, 0, 0]))
+    assert "attn_query_tile_share" not in eng.metrics.summary()
+    # 64 wide: 8 tiles of 16 a row; rows of 1, 1, 7 and 64 tokens are 2, 2,
+    # 14 and 128 query rows: the first tile, three times, and all 8, of the
+    # 32 held
+    q_lens = np.array([1, 1, 7, 64])
+    eng._observe_attention([None] * 4, 100 + q_lens, 64, q_lens)
+    assert eng.metrics.summary()["attn_query_tile_share"] \
+        == pytest.approx(11 / 32)
+    # 16 wide: 2 tiles a row; an empty row computes none, 18 query rows both
+    q_lens = np.array([1, 0, 16, 9])
+    eng._observe_attention([None] * 3, 50 + q_lens, 16, q_lens)
+    assert eng.metrics.summary()["attn_query_tile_share"] \
+        == pytest.approx((11 + 1 + 0 + 2 + 2) / (32 + 8))
+    fams = {f["name"]: f["samples"][0][-1]
+            for f in eng.metrics.prometheus_series()}
+    assert fams["tnn_serve_attn_query_tiles_computed_total"] == 16
+    assert fams["tnn_serve_attn_query_tiles_total"] == 40
+    # and the engine feeds it: a 20-token prompt goes out 32 wide (4 tiles
+    # a row, 4 rows), its one live row's 40 query rows past the first tile:
+    # all 4
+    eng.submit(np.arange(20, dtype=np.int32), 3)
+    eng.run_until_complete()
+    assert eng.metrics.attn_query_tiles_held == 40 + 16
+    assert eng.metrics.attn_query_tiles_computed == 16 + 4

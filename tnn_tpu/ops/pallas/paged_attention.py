@@ -126,12 +126,40 @@ _GROUP_POSITIONS = 128
 _VMEM_BUDGET = 10 * 2 ** 20
 
 
+def _sublanes(dtype):
+    """Rows of one vector register of ``dtype``: 8 of 4 bytes, 16 of 2, 32
+    of 1."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _tile_bytes(rows, cols, dtype):
     """VMEM bytes of a (rows, cols) tile: lanes padded to 128, sublanes to
-    the dtype's packing (8 rows of 4 bytes, 16 of 2, 32 of 1)."""
-    size = jnp.dtype(dtype).itemsize
-    sub = 8 * (4 // size)
-    return -(-rows // sub) * sub * -(-cols // 128) * 128 * size
+    the dtype's packing (``_sublanes``)."""
+    sub = _sublanes(dtype)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 \
+        * jnp.dtype(dtype).itemsize
+
+
+def query_tile(qg, dtype):
+    """Rows of one QUERY TILE of a row's ``qg = Q * g`` query rows of
+    ``dtype``: one register's sublanes (16 rows of bf16, 8 of float32) where
+    they divide ``qg``, else all ``qg`` (one tile: every decode program's
+    ``1 * g``). A grid step of a row whose live query rows fit the first tile
+    computes that tile alone (``_attn_kernel``). From the shape and the
+    queries' dtype alone: the launch and the engine's
+    ``attn_query_tile_share`` (``query_tiles_computed``) both ask this
+    function."""
+    sub = _sublanes(dtype)
+    return sub if qg % sub == 0 else qg
+
+
+def query_tiles_computed(q_lens, g, qg, tile):
+    """The query tiles ``_attn_kernel`` computes a row (a numpy array),
+    of the ``qg / tile`` a row of the launch holds, for rows of ``q_lens``
+    live tokens of ``g`` query rows each: none for an empty row (no key is
+    live either), ONE where the live rows fit a tile, else all of them."""
+    live = np.asarray(q_lens, np.int64) * g
+    return np.where(live > tile, qg // tile, np.minimum(live, 1))
 
 
 def group_vmem_bytes(pages, heads, *, bs, dh, qg, page_dtype):
@@ -245,7 +273,7 @@ def _over_packed_rows(attend, q, pages, stats):
 
 
 def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
-                 scale: float, bs: int, g: int, qw: int, pages: int,
+                 scale: float, bs: int, g: int, qw: int, tq: int, pages: int,
                  quant: bool, stats: bool, window: Optional[int] = None):
     """One grid step: ``pages`` consecutive table entries of row b, every
     head of the step's head block, ONE online-softmax update per head over
@@ -296,48 +324,74 @@ def _attn_kernel(tables_ref, lens_ref, qlens_ref, layer_ref, q_ref, *refs,
     # what for them) and masked out of the softmax
     @pl.when(j * t < kv_len)
     def _group():
+        def keys_values():
+            ks, vs = zip(*(load(i) for i in range(pages)))
+            return (ks[0] if pages == 1 else jnp.concatenate(ks, axis=1),
+                    vs[0] if pages == 1 else jnp.concatenate(vs, axis=1))
+
+        def update(q, k, v, rows=...):
+            """The softmax update of the query rows ``q``, which are rows
+            ``rows`` of the scratch from its first on (all by default)."""
+            s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.float32) * scale
+            # the key position of each of the group's slots. A NEGATIVE table
+            # entry is a dead hole (sequence-parallel serving stamps -1 on the
+            # pages another shard owns): its keys go past every query's limit
+            slot = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+            entry = tables_ref[b, j * pages]
+            for i in range(1, pages):
+                entry = jnp.where(slot >= i * bs, tables_ref[b, j * pages + i],
+                                  entry)
+            kpos = jnp.where(entry < 0, 2 ** 30, j * t + slot)
+            # query token t sits at absolute position start + t with
+            # start = kv_len - q_live: causal over its own chunk AND over every
+            # previously written position (q_live = 1 degenerates to the decode
+            # mask kpos < kv_len); rows past q_live see no key at all
+            trow = jax.lax.broadcasted_iota(jnp.int32, (q.shape[1], 1), 0)
+            if g > 1:
+                trow = jax.lax.div(trow, jnp.int32(g))
+            limit = jnp.where(trow < q_live, kv_len - q_live + trow, -1)
+            mask = kpos <= limit
+            if window is not None:
+                # the page that straddles a query's lower bound is fetched and
+                # masked; pages wholly before the FIRST query's are never walked
+                mask = mask & (kpos > limit - window)
+            mask = mask[None]                               # (1, rows, t)
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev, l_prev = m_scr[rows], l_scr[rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[rows] = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+            acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            m_scr[rows] = m_new
+
         # (heads, Q*g, Dh), row t*g + i = token t, group member i: the
         # WRAPPER lays q/out/stats out that way, because the v5e Mosaic
         # refuses the (Q, g, Dh) <-> (Q*g, Dh) shape casts in-kernel
-        q = q_ref[...]
-        ks, vs = zip(*(load(i) for i in range(pages)))
-        k = ks[0] if pages == 1 else jnp.concatenate(ks, axis=1)
-        v = vs[0] if pages == 1 else jnp.concatenate(vs, axis=1)
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        # the key position of each of the group's slots. A NEGATIVE table
-        # entry is a dead hole (sequence-parallel serving stamps -1 on the
-        # pages another shard owns): its keys go past every query's limit
-        slot = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
-        entry = tables_ref[b, j * pages]
-        for i in range(1, pages):
-            entry = jnp.where(slot >= i * bs, tables_ref[b, j * pages + i],
-                              entry)
-        kpos = jnp.where(entry < 0, 2 ** 30, j * t + slot)
-        # query token t sits at absolute position start + t with
-        # start = kv_len - q_live: causal over its own chunk AND over every
-        # previously written position (q_live = 1 degenerates to the decode
-        # mask kpos < kv_len); rows past q_live see no key at all
-        trow = jax.lax.broadcasted_iota(jnp.int32, (qw * g, 1), 0)
-        if g > 1:
-            trow = jax.lax.div(trow, jnp.int32(g))
-        limit = jnp.where(trow < q_live, kv_len - q_live + trow, -1)
-        mask = kpos <= limit
-        if window is not None:
-            # the page that straddles a query's lower bound is fetched and
-            # masked; pages wholly before the FIRST query's are never walked
-            mask = mask & (kpos > limit - window)
-        mask = mask[None]                               # (1, Q*g, t)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        if tq == qw * g:
+            # the queries first, then K and V: the order the one-tile body
+            # always traced in (a decode program's text does not move)
+            update(q_ref[...], *keys_values())
+            return
+        # a launch wider than one query tile (``query_tile``): K and V once
+        # a grid step, then ONE of two bodies by the row's live query rows.
+        # A row that fits the first tile (a decode row in a 64-wide mixed
+        # step: 2 rows of 128; a short last chunk) computes that tile alone;
+        # the other tiles keep l = 0 and acc = 0 and leave exactly 0, as a
+        # dead row does. A longer row computes the whole ``Q * g`` at once
+        k, v = keys_values()
+
+        @pl.when(q_live * g <= tq)
+        def _first_tile():
+            first = (slice(None), slice(0, tq))
+            update(q_ref[first], k, v, first)
+
+        @pl.when(q_live * g > tq)
+        def _whole():
+            update(q_ref[...], k, v)
 
     @pl.when(j == nj - 1)
     def _final():
@@ -511,8 +565,8 @@ def _launch(q, *, pages_k, pages_v, block_tables, kv_lens, q_lens, layer,
     )
     out = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, bs=bs, g=g, qw=qw,
-                          pages=pages, quant=quant, stats=stats,
-                          window=window),
+                          tq=query_tile(qw * g, q.dtype), pages=pages,
+                          quant=quant, stats=stats, window=window),
         # the name the device profile shows; the variants are other kernels
         name="tnn_paged_attention" + ("_win" if window else "")
         + ("_int8" if quant else "") + ("_stats" if stats else ""),

@@ -988,13 +988,27 @@ class InferenceEngine:
                 nb=self.blocks_per_seq, **kw)
         return group
 
-    def _observe_attention(self, rows, ends, qw: int) -> None:
+    def _observe_attention(self, rows, ends, qw: int, q_lens=None) -> None:
         """The attention counters of one paged step ``qw`` tokens wide whose
-        row i's tokens end just before position ``ends[i]``. Plain paged
-        attention: the fill of the page slots the kernel fetches in groups.
-        A windowed model: the share of the window's exact positions each row
-        attends over, and the pool's rows that hold summaries."""
+        row i's tokens end just before position ``ends[i]`` (``q_lens`` the
+        launch's live tokens a row; None: the decode form, one tile wide).
+        Plain paged attention: the fill of the page slots the kernel fetches
+        in groups, and of a launch wider than one query tile the tiles the
+        kernel computes over the tiles it holds. A windowed model: the share
+        of the window's exact positions each row attends over, and the pool's
+        rows that hold summaries."""
         pool = self.pool
+        if q_lens is not None and not (pool.window or pool.latent):
+            # the kernel module's own rule, at the launch's shape: the
+            # queries are of the pool's compute dtype, a "head" is a page row
+            from ..ops.pallas.paged_attention import (query_tile,
+                                                      query_tiles_computed)
+            g = self.model.num_heads // pool.page_shape[2]
+            tile = query_tile(qw * g, pool.dtype)
+            if tile < qw * g:
+                self.metrics.observe_attn_query_tiles(
+                    int(query_tiles_computed(q_lens, g, qw * g, tile).sum()),
+                    len(q_lens) * qw * g // tile)
         group = self._attn_group(qw)
         if group is not None:
             pages, _ = group
@@ -1722,7 +1736,8 @@ class InferenceEngine:
             self._recover_pages_if_dead(flight.events)
             return "other"
         pool.update_pages(pk, pv)
-        self._observe_attention(rows, ends, qw)
+        self._observe_attention(rows, ends, qw,
+                                step.q_lens if label == "mixed" else None)
         rec.update(dev=(newtok, ok, *experts), t0=t0, b=b, before=after)
         flight.ahead.append({
             "rec": rec, "rollback": rollback, "key": step_key,
@@ -2597,7 +2612,8 @@ class InferenceEngine:
                 self._abort_batch(rows, f"decode step failed: {e}", events)
                 return
         self.pool.update_pages(pk, pv)
-        self._observe_attention(rows, step.starts + step.q_lens, qw)
+        self._observe_attention(rows, step.starts + step.q_lens, qw,
+                                step.q_lens)
         flight.recs.append({
             "kind": "spec" if spec_on else "mixed",
             "dev": ((accepts, newtok, ok, toks_in) if spec_on

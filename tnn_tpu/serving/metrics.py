@@ -268,6 +268,11 @@ _DIRECT_FAMILIES: Tuple[Tuple[str, str, str, str], ...] = (
      "Longest engine step wall time since this registry was created"),
     ("front_late_max_s", "tnn_serve_front_late_max_seconds", "gauge",
      "Longest overrun of one empty stdin poll of the front end"),
+    ("attn_query_tiles_computed", "tnn_serve_attn_query_tiles_computed_total",
+     "counter", "Query tiles the paged kernel computed (the first alone of "
+     "a row that fits it, else all) in launches wider than one tile"),
+    ("attn_query_tiles_held", "tnn_serve_attn_query_tiles_total", "counter",
+     "Query tiles held by paged launches wider than one tile"),
 )
 
 
@@ -536,6 +541,10 @@ class ServingMetrics:
         self.win_pages_released = 0
         self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
         self.attn_fetch_row_steps = 0
+        # paged launches wider than one query tile: the tiles the kernel
+        # computed (a short row's first alone) and the tiles held
+        self.attn_query_tiles_computed = 0
+        self.attn_query_tiles_held = 0
         # step programs dispatched, and those with a row that asks for a
         # draw: the others run the sampler's argmax alone
         self.dispatched_steps = 0
@@ -757,6 +766,14 @@ class ServingMetrics:
         (``paged_attention.fetch_group``)."""
         self.attn_fetch_fill_sum += float(sum(fills))
         self.attn_fetch_row_steps += len(fills)
+
+    def observe_attn_query_tiles(self, computed: int, held: int) -> None:
+        """One paged step wider than one query tile
+        (``paged_attention.query_tile``): ``computed`` the tiles the kernel
+        computes (``paged_attention.query_tiles_computed``, summed over the
+        launch's rows) of the ``held`` tiles the launch is wide."""
+        self.attn_query_tiles_computed += int(computed)
+        self.attn_query_tiles_held += int(held)
 
     def observe_step_dispatch(self, sampled_rows: int) -> None:
         """One step program dispatched, ``sampled_rows`` of whose rows have
@@ -1182,6 +1199,11 @@ class ServingMetrics:
             # only the paged decode path fetches pages in groups
             out["attn_fetch_fill_mean"] = (self.attn_fetch_fill_sum
                                            / self.attn_fetch_row_steps)
+        if self.attn_query_tiles_held:
+            # only a paged launch wider than one query tile has tiles to
+            # leave out: 1.0 = every row was longer than a tile
+            out["attn_query_tile_share"] = (self.attn_query_tiles_computed
+                                            / self.attn_query_tiles_held)
         if self.eva_row_steps:
             # only a windowed model has these: a reader of a model with
             # every position exact finds nothing, not a zero
