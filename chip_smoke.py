@@ -57,6 +57,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import gc
+import functools
 import io
 import json
 import os
@@ -104,6 +105,13 @@ CHIP = dict(
     # a decode row costs in a launch as wide as a prompt chunk
     short_rows=dict(layers=36, blocks=1024, heads=20, block_size=16,
                     head_dim=64, batch=16, table=64, chunk=64, iters=10),
+    # the gated delta rule's step at Qwen3-Next's cell: 96 rows of 32 value
+    # heads' states of 128 x 128 float32 in 97 slots of 2 layers, a snapshot
+    # at every third row; and the paged kernel at that model's full layers:
+    # 16 query heads over 2 KV heads of 256, pages of 128
+    state=dict(layers=2, rows=96, heads=32, key=128, value=128),
+    heads256=dict(blocks=160, block_size=128, heads=16, kv_heads=2,
+                  head_dim=256, batch=8, table=16, chunk=32),
     # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
     # 32 rows of Mistral Small 4's slice
     sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
@@ -124,6 +132,9 @@ REHEARSAL = dict(
                 batch=3, window=16, chunk=8),
     short_rows=dict(layers=1, blocks=32, heads=4, block_size=16, head_dim=64,
                     batch=3, table=8, chunk=16, iters=1),
+    state=dict(layers=2, rows=3, heads=8, key=16, value=16),
+    heads256=dict(blocks=16, block_size=8, heads=4, kv_heads=2, head_dim=32,
+                  batch=3, table=6, chunk=8),
     sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
@@ -267,8 +278,69 @@ def phase_kernel(cfg) -> list:
                             f"kernel {fname}/{pname}/stats={stats}/"
                             f"holes={tname}: error above {tol}")
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
-        + _window_kernel(cfg, rand, rng) + _short_rows(cfg, rand, rng) \
-        + _sampler_steps(cfg, rng)
+        + _window_kernel(cfg, rand, rng) + _state_kernels(cfg, rand, rng) \
+        + _short_rows(cfg, rand, rng) + _sampler_steps(cfg, rng)
+
+
+def _state_kernels(cfg, rand, rng) -> list:
+    """``tnn_gdn_step`` against the ``jax.numpy`` step at a decode step's
+    shapes (the output, every live state written, and the snapshot of the
+    rows that keep one); and the paged kernel at heads of 256 (2 KV heads,
+    8 query heads each), which no other model runs it at."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas.gdn_step import gdn_step
+    from tnn_tpu.ops.pallas.paged_attention import paged_attention
+
+    interpret, failures = cfg["rehearse"], []
+
+    check = functools.partial(_check_close, failures)
+
+    k = cfg["state"]
+    L, B, H, dk, dv = k["layers"], k["rows"], k["heads"], k["key"], k["value"]
+
+    def f32(shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q, kk, v = unit(f32((B, H, dk))) * dk ** -0.5, unit(f32((B, H, dk))), \
+        f32((B, H, dv))
+    g, beta = -jnp.abs(f32((B, H))), jax.nn.sigmoid(f32((B, H)))
+    rec, snap = f32((L, B + 1, H, dk, dv)), jnp.zeros(
+        (L, 2 * B + 1, H, dk, dv), jnp.float32)
+    slots = jnp.asarray(rng.permutation(B) + 1, jnp.int32)
+    snaps = jnp.where(jnp.arange(B) % 3 == 0, 2 * (slots - 1) + 1, 0)
+    args = (q, kk, v, g, beta, rec, snap, slots, snaps)
+    want = gdn_step(*args, layer=1, backend="xla")
+    got = gdn_step(*args, layer=1, backend="pallas", interpret=interpret)
+    name = f"gdn_step {B} rows x {H} heads"
+    check(name + " out", got[0], want[0], 1e-4)
+    check(name + " states", got[1][:, 1:], want[1][:, 1:], 1e-4)
+    check(name + " snapshots", got[2][:, 1:], want[2][:, 1:], 0.0)
+
+    k = cfg["heads256"]
+    bs, B, nb = k["block_size"], k["batch"], k["table"]
+    pk, pv = (rand((2, k["blocks"], k["kv_heads"], bs, k["head_dim"]))
+              for _ in range(2))
+    tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
+    cap = nb * bs
+    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
+        kv_lens = np.array([qw, bs, bs + 1, cap // 3, cap // 2 - 1,
+                            cap - bs - 5, cap - 24, cap])[:B].clip(
+                                qw, cap).astype(np.int32)
+        q_lens = np.minimum(np.array([qw, 1, qw // 2 + 1] * B)[:B], kv_lens)
+        qq = rand((B, qw, k["heads"], k["head_dim"]))
+        kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32), layer=1)
+        a = (qq, pk, pv, tables, jnp.asarray(kv_lens))
+        check(f"paged {fname} 16 x 256 over 2",
+              paged_attention(*a, backend="pallas", interpret=interpret,
+                              group_positions=512, **kw),
+              paged_attention(*a, backend="xla", **kw), KERNEL_TOL["bf16"])
+    return failures
 
 
 def _short_rows(cfg, rand, rng) -> list:
@@ -376,6 +448,20 @@ def _window_kernel(cfg, rand, rng) -> list:
     return failures
 
 
+def _check_close(failures, name, got, want, tol) -> None:
+    """One line of the kernel table: the largest error of ``got`` against
+    ``want``, held to ``tol``; a failure goes into ``failures``."""
+    import numpy as np
+
+    g, w = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    err = float(np.max(np.abs(g - w)))
+    ok = bool(np.isfinite(g).all()) and err <= tol
+    log(f"{name:28s} max|err| {err:9.2e} tol {tol:6.0e} "
+        f"{'ok' if ok else 'FAIL'}")
+    if not ok:
+        failures.append(f"kernel {name}: error above {tol}")
+
+
 def _latent_and_expert_kernels(cfg, rand, rng) -> list:
     """``tnn_mla_attention`` and ``tnn_expert_gmm`` against their XLA forms
     (the gather and one dense product an expert), decode and chunk shapes."""
@@ -387,14 +473,7 @@ def _latent_and_expert_kernels(cfg, rand, rng) -> list:
 
     interpret, failures = cfg["rehearse"], []
 
-    def check(name, got, want, tol):
-        g, w = np.asarray(got, np.float32), np.asarray(want, np.float32)
-        err = float(np.max(np.abs(g - w)))
-        ok = bool(np.isfinite(g).all()) and err <= tol
-        log(f"{name:28s} max|err| {err:9.2e} tol {tol:6.0e} "
-            f"{'ok' if ok else 'FAIL'}")
-        if not ok:
-            failures.append(f"kernel {name}: error above {tol}")
+    check = functools.partial(_check_close, failures)
 
     for k in (cfg["latent"], cfg["latent_wide"]):
         B, nb, bs, h = k["batch"], k["table"], k["block_size"], k["heads"]

@@ -499,3 +499,65 @@ def test_check_step_writes_refuses_two_writers_and_shared_pages():
         pool.check_step_writes(twice, np.array([2, 0, 0]),
                                np.array([4, 1, 0]))
     pool.check_step_writes(twice, np.array([5, 0, 0]), np.array([0, 1, 0]))
+
+
+# -- state slots beside the pages (PR 44): no state-shaped copy either ---------
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk32"])
+def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
+        form, one_chip, no_compile_cache, alarm):
+    """Qwen3-Next's layers as published (Gated DeltaNet: 16 key and 32 value
+    heads of 128 behind a convolution of 4; full attention: 16 query heads
+    over 2 KV heads of 256; experts of 512 x 2,048, 4 of them held here so
+    that the compile is quick), one period (linear, linear, linear, full),
+    pages of 128, 16 rows: the chip's compiler accepts ``tnn_gdn_step`` (the
+    state aliased in and out, the snapshot's DMA under ``pl.when``) beside
+    the paged kernel at heads of 256 and the grouped expert product, and the
+    step around them copies NEITHER the pool NOR any of the four state
+    arrays: slots are gathered and scattered a row at a time in the layout
+    the arrays rest in."""
+    from tnn_tpu import models
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows = 16
+    model = models.create("qwen3_next_ep4", num_layers=4, held_experts=4)
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
+    assert model.cache_layers == 1
+    pages = spec((1, 256, 2, 128, 256), jnp.bfloat16)
+    group = model.state_group
+    state = {
+        "conv": spec((3, rows + 1) + group["conv"], jnp.bfloat16),
+        "rec": spec((3, rows + 1) + group["rec"], jnp.float32),
+        "conv_snap": spec((3, 2 * rows + 1) + group["conv"], jnp.bfloat16),
+        "rec_snap": spec((3, 2 * rows + 1) + group["rec"], jnp.float32)}
+    tables, lens = spec((rows, 9), jnp.int32), spec((rows,), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        if form == "decode":
+            lowered = jax.jit(
+                lambda p, t, pk, pv, tb, o, s: model.apply_decode_paged(
+                    p, t, pk, pv, tb, o, state=s),
+                donate_argnums=(2, 3, 6)).lower(
+                params, spec((rows,), jnp.int32), pages, pages, tables, lens,
+                state)
+        else:
+            lowered = jax.jit(
+                lambda p, t, pk, pv, tb, o, q, s: model.apply_paged(
+                    p, t, pk, pv, tb, o, q, state=s, head_at=q - 1),
+                donate_argnums=(2, 3, 7)).lower(
+                params, spec((rows, 32), jnp.int32), pages, pages, tables,
+                lens, lens, state)
+        text = lowered.compile().as_text()
+    names = set(re.findall(r"tnn_[a-z_]+[a-z]", text))
+    assert {"tnn_paged_attention", "tnn_expert_gmm"} <= names
+    assert ("tnn_gdn_step" in names) == (form == "decode")
+    for shape in [pages.shape] + [s.shape for s in state.values()]:
+        dims = ",".join(map(str, shape))
+        copy = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
+        assert not [ln for ln in text.splitlines() if copy.search(ln)], dims

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from ..core import rng as rnglib
 from ..core.module import Module, register_module
-from ..nn.attention import (GatedAttention, LatentAttention,
+from ..nn.attention import (GatedAttention, GatedDeltaNet, LatentAttention,
                             MultiHeadAttention)
 from ..nn.embedding import Embedding
 from ..nn.layers import Dense
@@ -43,10 +43,13 @@ class LlamaBlock(Module):
                  chunk: Optional[int] = None, latent: Optional[dict] = None,
                  experts: Optional[dict] = None,
                  gated: Optional[dict] = None, sandwich: bool = False,
-                 name=None, policy=None):
+                 linear: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
         self.mlp_hidden = int(mlp_hidden)
+        # ``linear``: the keywords of ``nn.attention.GatedDeltaNet``: THIS
+        # layer keeps a state updated in place and no page of the pool
+        self.linear = dict(linear) if linear else None
         # the block is GIVEN its attention (heads: K/V of every position;
         # eva: ``window`` and ``chunk``; latent: ``latent``, the keywords of
         # ``nn.attention.LatentAttention``; gated: ``gated``, those of
@@ -74,7 +77,10 @@ class LlamaBlock(Module):
         norm = dict(eps=self.norm_eps, unit_offset=self.norm_unit_offset,
                     policy=p)
         self.ln1 = RMSNorm(**norm)
-        if self.latent:
+        if self.linear:
+            self.attn = GatedDeltaNet(norm_eps=self.norm_eps, policy=p,
+                                      **self.linear)
+        elif self.latent:
             self.attn = LatentAttention(num_heads, norm_eps=self.norm_eps,
                                         backend=backend, policy=p,
                                         **self.latent)
@@ -208,6 +214,25 @@ class LlamaBlock(Module):
             if self.moe is not None else None
         return self._mlp_residual(params, x, live=live), pages_k, pages_v
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the pool's pages the block writes: none where its
+        attention keeps a state instead."""
+        return 0 if self.linear else 1
+
+    def apply_state(self, params, x, state, slots, offsets, state_layer,
+                    q_lens=None):
+        """``apply_paged`` for a block whose attention keeps a state in the
+        pool's state slots (``GatedDeltaNet.apply_state``) and no pages:
+        returns (x, state)."""
+        h, state = self.attn.apply_state(
+            {"params": params["attn"]}, self._ln1(params, x), state, slots,
+            offsets, layer=state_layer, q_lens=q_lens)
+        x = self._attn_residual(params, x, h)
+        live = _live_tokens(x, slots[:, None], q_lens) \
+            if self.moe is not None else None
+        return self._mlp_residual(params, x, live=live), state
+
     def output_shape(self, input_shape):
         return tuple(input_shape)
 
@@ -338,7 +363,7 @@ class ShortcutBlock(Module):
 _BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
                    "residual_f32": False, "window": None, "chunk": None,
                    "latent": None, "experts": None, "gated": None,
-                   "sandwich": False}
+                   "sandwich": False, "linear": None}
 
 
 def _block_options(m):
@@ -378,8 +403,15 @@ class Llama(PagedDecoder, Module):
                  gated: Optional[dict] = None, sandwich: bool = False,
                  embed_scale: bool = False, num_dense_layers: int = 0,
                  dense_hidden: Optional[int] = None, shortcut: bool = False,
-                 name=None, policy=None):
+                 linear: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        # ``linear``: the keywords of ``nn.attention.GatedDeltaNet``, for
+        # the layers whose kind (``gated["layer_types"]``) is
+        # "linear_attention": they keep a state in the pool's state slots
+        # (``state_group``), the others pages
+        self.linear = dict(linear) if linear else None
+        if self.linear and not gated:
+            raise ValueError("linear layers are named by gated's layer_types")
         # ``shortcut``: every block is a ``ShortcutBlock``: two attention
         # sublayers and two dense feed-forwards of ``mlp_hidden`` with the
         # model's ``experts`` across them; ``num_layers`` counts BLOCKS, the
@@ -394,7 +426,8 @@ class Llama(PagedDecoder, Module):
         # ``layer_types``, one of "sliding_attention" / "full_attention" a
         # layer: window layers beside global layers in ONE model
         # (nn.attention.GatedAttention; the pool then holds two groups of
-        # page, ``page_groups``)
+        # page, ``page_groups``). ``rope_full``: the global layers are
+        # rotated too; ``rotary_dim`` / ``norm_unit_offset`` pass through
         self.gated = dict(gated, layer_types=list(gated["layer_types"])) \
             if gated else None
         self.sandwich = bool(sandwich)
@@ -462,12 +495,18 @@ class Llama(PagedDecoder, Module):
             mlp_hidden=self.dense_hidden if dense and self.dense_hidden
             else self.mlp_hidden,
             experts=None if dense else self.experts)
-        if self.gated:
+        if self.gated and self.gated["layer_types"][i] == "linear_attention":
+            opts["linear"] = self.linear
+        elif self.gated:
             sliding = self.gated["layer_types"][i] == "sliding_attention"
+            rotate = sliding or self.gated.get("rope_full")
             opts["gated"] = dict(
                 head_dim=self.gated["head_dim"],
                 window=self.gated["window"] if sliding else None,
-                rope_theta=self.gated["rope_theta"] if sliding else None)
+                rope_theta=self.gated["rope_theta"] if rotate else None)
+            for key in ("rotary_dim", "norm_unit_offset"):
+                if self.gated.get(key):
+                    opts["gated"][key] = self.gated[key]
         return opts
 
     @property
@@ -475,17 +514,46 @@ class Llama(PagedDecoder, Module):
         """What the pool holds for a model of window layers beside global
         ones (``serving.kv_pool``): the window, and how many layers of each
         kind share a page of their group. None: one table for every layer."""
-        if not self.gated:
+        if not self.gated or self.linear:
             return None
         kinds = self.gated["layer_types"]
         n_win = kinds.count("sliding_attention")
         return dict(window=int(self.gated["window"]), window_layers=n_win,
                     full_layers=len(kinds) - n_win)
 
+    @property
+    def state_group(self) -> Optional[dict]:
+        """What the pool holds beside its pages for a model with linear
+        layers (``serving.kv_pool.StateSlots``): how many layers keep a
+        state, the conv positions (``GatedDeltaNet.conv_rows``) and the recurrent
+        state ``(value_heads, key_dim, value_dim)`` of one. None: pages
+        only."""
+        if not self.linear:
+            return None
+        mix = next(b.attn for b in self.blocks if b.linear)
+        return dict(
+            layers=self.gated["layer_types"].count("linear_attention"),
+            conv=mix.conv_rows,
+            rec=(mix.value_heads, mix.key_dim, mix.value_dim))
+
     def _paged_layers(self, pages_k, block_tables):
         """A packed step table of two page groups, by layer: ``[a segment a
         global layer | a segment a window layer | base]`` (``kv_pool``: Two
         page groups); every layer's pages lie in the pool's ONE layer."""
+        if self.linear:
+            # the packed table's LAST entry is the row's state slot; the
+            # layers with pages share the entries before it, each its own
+            # layer of the pool
+            slots, tables = block_tables[:, -1], block_tables[:, :-1]
+            out, at_state, at_pages = [], 0, 0
+            for block in self.blocks:
+                if block.linear:
+                    out.append(dict(slots=slots, state_layer=at_state))
+                    at_state += 1
+                else:
+                    out.append(dict(block_tables=tables, layer=at_pages))
+                    at_pages += 1
+            return out
         if not self.gated:
             return super()._paged_layers(pages_k, block_tables)
         from ..ops.pallas.paged_attention import (group_segments,
@@ -805,6 +873,67 @@ def longcat_flash_tiny(**kw):
                      hidden=32, score="softmax_raw", route_scale=6.0))
     cfg.update(kw)
     return longcat_flash_ep32(**cfg)
+
+
+def qwen3_next_ep4(num_layers: int = 8, held_experts: int = 128,
+                   vocab: int = 37984, **kw):
+    """Qwen3-Next-80B-A3B-Instruct (https://huggingface.co/Qwen/
+    Qwen3-Next-80B-A3B-Instruct, config.json, ``model_type: qwen3_next``) as
+    ONE chip of four that share each layer serves it: the first 8 of the 48
+    layers (two whole periods of three Gated DeltaNet layers and a gated
+    full-attention layer, ``full_attention_interval`` 4), every width as
+    published (2,048 wide; the linear layers 16 key heads and 32 value heads
+    of 128 behind a convolution of 4; the full layers 16 query heads over 2
+    KV heads of 256, unit-offset head norms, rotary over the first 64 lanes
+    at theta 1e7, a sigmoid gate on the output), the softmax router over all
+    512 experts and 10 a token, renormalised, experts 0 .. ``held_experts``
+    - 1 of width 512 held here beside the shared one and its sigmoid gate,
+    rows 0 .. ``vocab`` - 1 of the 151,936-token vocabulary, bf16 weights,
+    RMSNorm with a unit offset. The multi-token-prediction head is not
+    served."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    cfg = dict(
+        vocab_size=vocab, max_len=262144, d_model=2048, num_heads=16,
+        num_kv_heads=2, mlp_hidden=512, full_attention_interval=4,
+        gated=dict(head_dim=256, window=None, rope_theta=10000000.0,
+                   rope_full=True, rotary_dim=64, norm_unit_offset=True),
+        linear=dict(key_heads=16, value_heads=32, key_dim=128, value_dim=128,
+                    conv=4),
+        experts=dict(num_experts=512, held=range(held_experts), top_k=10,
+                     hidden=512, shared=1, shared_gated=True))
+    cfg.update(kw)
+    every = cfg.pop("full_attention_interval")
+    cfg["gated"] = dict(cfg["gated"], layer_types=[
+        "full_attention" if (i + 1) % every == 0 else "linear_attention"
+        for i in range(num_layers)])
+    return Llama(num_layers=num_layers, tie_embeddings=False, norm_eps=1e-6,
+                 norm_unit_offset=True, residual_f32=True, **cfg)
+
+
+def qwen3_next_tiny(**kw):
+    """Qwen3-Next's block at test sizes: 4 layers (linear, linear, linear,
+    full), 64 wide; the linear layers 2 key heads and 4 value heads of 16;
+    the full layer 4 query heads over 2 KV heads of 32, rotary over 8 lanes;
+    8 of 16 experts of width 32 held, 4 a token, a gated shared expert.
+    Float32 unless told otherwise (``mistral_small4_tiny`` says why)."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="float32", param="float32",
+                                        compute="float32"))
+    cfg = dict(
+        num_layers=4, vocab=256, max_len=512, d_model=64, num_heads=4,
+        num_kv_heads=2, mlp_hidden=32,
+        gated=dict(head_dim=32, window=None, rope_theta=10000.0,
+                   rope_full=True, rotary_dim=8, norm_unit_offset=True),
+        linear=dict(key_heads=2, value_heads=4, key_dim=16, value_dim=16,
+                    conv=4),
+        experts=dict(num_experts=16, held=range(8), top_k=4, hidden=32,
+                     shared=1, shared_gated=True))
+    cfg.update(kw)
+    return qwen3_next_ep4(**cfg)
 
 
 def llama_small(**kw):

@@ -85,6 +85,10 @@ register("longcat_flash_ep32")(
     lambda **kw: llama_lib.longcat_flash_ep32(**kw))
 register("longcat_flash_tiny")(
     lambda **kw: llama_lib.longcat_flash_tiny(**kw))
+register("qwen3_next_ep4")(
+    lambda **kw: llama_lib.qwen3_next_ep4(**kw))
+register("qwen3_next_tiny")(
+    lambda **kw: llama_lib.qwen3_next_tiny(**kw))
 register("gpt2_medium")(lambda **kw: gpt2_lib.gpt2_medium(**kw))
 register("gpt2_large")(lambda **kw: gpt2_lib.gpt2_large(**kw))
 register("flash_gpt2_small")(lambda **kw: gpt2_lib.gpt2_small(backend="pallas", **kw))

@@ -662,11 +662,14 @@ class _ProjectAndNorm:
 
         return qmatmul(x, self.policy.cast_param(w)).astype(x.dtype)
 
-    def _norm(self, x, gain, scale: float = 1.0):
-        """RMSNorm in float32, times ``scale`` before the cast back."""
+    def _norm(self, x, gain, scale: float = 1.0, unit_offset: bool = False):
+        """RMSNorm in float32, times ``scale`` before the cast back
+        (``unit_offset``: the gain is ``1 + gain``)."""
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + self.norm_eps) * gain.astype(jnp.float32)
+        g = gain.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(ms + self.norm_eps) * (1.0 + g if unit_offset
+                                                      else g)
         return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
@@ -917,7 +920,12 @@ class GatedAttention(_ProjectAndNorm, Module):
     ``window`` set: each position attends itself and the ``window - 1``
     before it, and queries and keys are rotated (``rope_theta``, half
     rotation over the whole head);
-    ``window`` None: every earlier position, and NO positions at all.
+    ``window`` None: every earlier position, and NO positions at all,
+    unless the layer is given a ``rope_theta`` all the same.
+
+    ``rotary_dim``: only the FIRST ``rotary_dim`` lanes of a head are rotated
+    (a partial rotary factor); ``norm_unit_offset``: the two head norms' gain
+    is ``1 + w``.
 
     ``[q | k | v | g] = x W_qkvg``; ``q, k = rms_q(q), rms_k(k)`` a head;
     ``o = softmax(q k^T / sqrt(head_dim)) v * sigmoid(g)``; ``y = o W_o``.
@@ -929,10 +937,13 @@ class GatedAttention(_ProjectAndNorm, Module):
     def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                  window: Optional[int] = None,
                  rope_theta: Optional[float] = None, norm_eps: float = 1e-5,
-                 backend: str = "xla", name=None, policy=None):
+                 backend: str = "xla", rotary_dim: Optional[int] = None,
+                 norm_unit_offset: bool = False, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
         self.head_dim = int(head_dim)
+        self.rotary_dim = int(rotary_dim) if rotary_dim else None
+        self.norm_unit_offset = bool(norm_unit_offset)
         if self.num_kv_heads <= 0 or self.num_heads % self.num_kv_heads:
             raise ValueError(f"num_kv_heads {num_kv_heads} must be a "
                              f"positive divisor of num_heads {num_heads}")
@@ -962,10 +973,18 @@ class GatedAttention(_ProjectAndNorm, Module):
         q, k, v, gate = jnp.split(
             self._mm(x, params["qkvg_kernel"]),
             [h * dh, (h + hkv) * dh, (h + 2 * hkv) * dh], axis=-1)
-        q = self._norm(q.reshape(b, s, h, dh), params["q_norm"])
-        k = self._norm(k.reshape(b, s, hkv, dh), params["k_norm"])
+        unit = self.norm_unit_offset
+        q = self._norm(q.reshape(b, s, h, dh), params["q_norm"],
+                       unit_offset=unit)
+        k = self._norm(k.reshape(b, s, hkv, dh), params["k_norm"],
+                       unit_offset=unit)
         q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
-        if self.rope_theta:
+        if self.rope_theta and self.rotary_dim:
+            q, k = (jnp.concatenate(
+                [apply_rope(t[..., :self.rotary_dim], offset,
+                            self.rope_theta), t[..., self.rotary_dim:]],
+                axis=-1) for t in (q, k))
+        elif self.rope_theta:
             q = apply_rope(q, offset, self.rope_theta)
             k = apply_rope(k, offset, self.rope_theta)
         return q, k, v.reshape(b, s, hkv, dh).transpose(0, 2, 1, 3), gate
@@ -1054,7 +1073,281 @@ class GatedAttention(_ProjectAndNorm, Module):
         return tuple(input_shape)
 
     def _config(self):
-        return {"num_heads": self.num_heads,
-                "num_kv_heads": self.num_kv_heads, "head_dim": self.head_dim,
-                "window": self.window, "rope_theta": self.rope_theta,
-                "norm_eps": self.norm_eps, "backend": self.backend}
+        cfg = {"num_heads": self.num_heads,
+               "num_kv_heads": self.num_kv_heads, "head_dim": self.head_dim,
+               "window": self.window, "rope_theta": self.rope_theta,
+               "norm_eps": self.norm_eps, "backend": self.backend}
+        if self.rotary_dim:
+            cfg["rotary_dim"] = self.rotary_dim
+        if self.norm_unit_offset:
+            cfg["norm_unit_offset"] = True
+        return cfg
+
+
+# -- gated delta-rule linear attention: a state, no cache of positions ------
+
+# a serving step whose row starts at a multiple of this many positions keeps
+# the state it read in one of the row's two snapshot slots, by turns: what a
+# roll-back of the overlapped loop restores (``serving.kv_pool.StateSlots``)
+SNAPSHOT_EVERY = 16
+
+
+def snapshot_slots(slots, offsets, xp=jnp):
+    """(B,) the slot of the snapshot arrays that takes the state a step's row
+    READ: row ``b`` of slot ``slots[b]`` (0: padding) that starts at position
+    ``offsets[b]``; 0, the dump slot, where the row keeps nothing. A row's two
+    snapshots lie at ``2 * (slot - 1) + 1`` and ``+ 2`` and take turns. The
+    host keeps the same count by the same rule (``xp=numpy``:
+    ``serving.engine._note_snapshots``)."""
+    turn = (offsets // SNAPSHOT_EVERY) % 2
+    keep = (slots > 0) & (offsets % SNAPSHOT_EVERY == 0)
+    return xp.where(keep, 2 * (slots - 1) + turn + 1, 0).astype(xp.int32)
+
+
+@register_module("gated_delta_net")
+class GatedDeltaNet(_ProjectAndNorm, Module):
+    """Gated DeltaNet over (N, S, D): linear attention whose memory of the
+    context is a STATE updated in place at every position, not a cache that
+    grows: ``key_heads`` query/key heads of ``key_dim``, ``value_heads``
+    value heads of ``value_dim`` (each key head serves ``value_heads /
+    key_heads`` of them), a causal depthwise convolution of ``conv`` taps in
+    front.
+
+        [q | k | v | z] = x W_qkvz;   [b | a] = x W_ba
+        [q | k | v] <- silu(conv_t([q | k | v]))     c_t = sum_j w_j u_{t-conv+1+j}
+        q <- q / |q| * key_dim^-1/2;  k <- k / |k|   (a head)
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)   (float32)
+        S <- e^g S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q
+        y = (w * o * rsqrt(mean o^2 + eps) * silu(z)) W_o        (a head)
+
+    What a row carries from one step to the next is ``conv - 1`` positions of
+    ``[q | k | v]`` before the convolution (the policy's compute dtype) and
+    ``S``, (value_heads, key_dim, value_dim) FLOAT32. ``_apply`` and
+    ``apply_cached`` run the chunked closed form from a zero (or the cache's)
+    state; ``apply_state`` is the serving step against the pool's state
+    slots: a decode step through ``ops.pallas.gdn_step`` (one read and one
+    write of each state), a prompt chunk through the chunked form.
+
+    Leaves: ``qkvz_kernel`` (D, 2 key_heads key_dim + 2 value_heads
+    value_dim), ``ba_kernel`` (D, 2 value_heads), ``conv_kernel`` (conv,
+    channels), ``A_log`` and ``dt_bias`` (value_heads,) float32, ``norm``
+    (value_dim,), ``out_kernel`` (value_heads value_dim, D)."""
+
+    def __init__(self, key_heads: int, value_heads: int, key_dim: int,
+                 value_dim: int, conv: int = 4, norm_eps: float = 1e-6,
+                 name=None, policy=None):
+        super().__init__(name=name, policy=policy)
+        self.key_heads, self.value_heads = int(key_heads), int(value_heads)
+        self.key_dim, self.value_dim = int(key_dim), int(value_dim)
+        self.conv, self.norm_eps = int(conv), float(norm_eps)
+        if self.value_heads % self.key_heads:
+            raise ValueError(f"value_heads {value_heads} is a multiple of "
+                             f"key_heads {key_heads}")
+        self.qk = self.key_heads * self.key_dim
+        self.vz = self.value_heads * self.value_dim
+        self.channels = 2 * self.qk + self.vz       # what the conv runs over
+
+    def _init(self, rng, input_shape):
+        d, hv = input_shape[-1], self.value_heads
+        init = initializers.get("xavier_uniform")
+        ks = jax.random.split(rng, 6)
+        pd = self.policy.param_dtype
+        return {
+            "qkvz_kernel": init(ks[0], (d, self.channels + self.vz), pd),
+            "ba_kernel": init(ks[1], (d, 2 * hv), pd),
+            "conv_kernel": (jax.random.normal(
+                ks[2], (self.conv, self.channels), jnp.float32)
+                / math.sqrt(self.conv)).astype(pd),
+            # the family's initial ranges: A in [0, 16), dt in [1e-3, 0.1]
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (hv,), jnp.float32, 1e-3, 16.0)),
+            "dt_bias": _inv_softplus(jnp.exp(jax.random.uniform(
+                ks[4], (hv,), jnp.float32, math.log(1e-3), math.log(0.1)))),
+            "norm": jnp.ones((self.value_dim,), pd),
+            "out_kernel": init(ks[5], (self.vz, d), pd),
+        }, {}
+
+    # -- the four parts of the layer (docs/observability.md) ---------------
+
+    @jax.named_scope("gdn_proj")
+    def _project(self, params, x):
+        """x (B, Q, D) -> u (B, Q, channels) ``[q | k | v]`` before the
+        convolution, z (B, Q, vz), b and a (B, Q, value_heads)."""
+        x = self.policy.cast_in(x)
+        qkvz = self._mm(x, params["qkvz_kernel"])
+        ba = self._mm(x, params["ba_kernel"])
+        hv = self.value_heads
+        return (qkvz[..., :self.channels], qkvz[..., self.channels:],
+                ba[..., :hv], ba[..., hv:])
+
+    @jax.named_scope("gdn_conv")
+    def _convolve(self, params, u, conv0, q_lens):
+        """The causal depthwise convolution of u (B, Q, C) behind the
+        ``conv - 1`` positions ``conv0`` (B, conv - 1, C) kept from before,
+        then SiLU; and the positions to keep for the next step: the last
+        ``conv - 1`` before position ``q_lens[b]`` (None: all ``Q`` live)."""
+        taps = self.conv
+        qw = u.shape[1]
+        ext = jnp.concatenate([conv0.astype(u.dtype), u], axis=1)
+        w = params["conv_kernel"].astype(jnp.float32)
+        c = sum(w[j] * ext[:, j:j + qw].astype(jnp.float32)
+                for j in range(taps))
+        if q_lens is None:
+            kept = ext[:, qw:]
+        else:
+            at = q_lens[:, None] + jnp.arange(taps - 1)[None, :]
+            kept = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+        return jax.nn.silu(c), kept
+
+    def _heads(self, params, c, b_raw, a_raw, q_lens):
+        """The convolved channels as what the rule reads, a VALUE head each,
+        float32: q, k (B, Q, Hv, Dk) normed (q scaled), v (B, Q, Hv, Dv), g
+        and beta (B, Q, Hv). A padding position (past ``q_lens``) gets ``g``
+        = 0 and ``beta`` = 0: it leaves the state as it was."""
+        b, qw = c.shape[:2]
+        hk, hv, dk = self.key_heads, self.value_heads, self.key_dim
+
+        def unit(t):
+            t = t.reshape(b, qw, hk, dk)
+            t = t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                  + 1e-6)
+            return jnp.repeat(t, hv // hk, axis=2)
+
+        q = unit(c[..., :self.qk]) * dk ** -0.5
+        k = unit(c[..., self.qk:2 * self.qk])
+        v = c[..., 2 * self.qk:].reshape(b, qw, hv, self.value_dim)
+        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
+        g = -jnp.exp(params["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a_raw.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
+        if q_lens is not None:
+            live = (jnp.arange(qw)[None, :] < q_lens[:, None])[..., None]
+            g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
+        return q, k, v, g, beta
+
+    @jax.named_scope("gdn_out")
+    def _project_out(self, params, o, z):
+        """o (B, Q, Hv, Dv) float32, z (B, Q, vz): the gated norm a head,
+        then ``W_o``."""
+        b, qw = o.shape[:2]
+        ms = jnp.mean(o * o, axis=-1, keepdims=True)
+        y = o * jax.lax.rsqrt(ms + self.norm_eps) \
+            * params["norm"].astype(jnp.float32)
+        y = y.reshape(b, qw, self.vz) * jax.nn.silu(z.astype(jnp.float32))
+        return self.policy.cast_out(
+            self._mm(y.astype(z.dtype), params["out_kernel"]))
+
+    def _scan(self, parts, rec0):
+        """``gdn_chunk`` over (q, k, v, g, beta) of any width: past ``SUB``
+        positions the chunk is padded to whole sub-chunks (a padding
+        position leaves the state alone)."""
+        from ..ops.pallas.gdn_step import SUB, gdn_chunk
+
+        qw = parts[0].shape[1]
+        pad = -qw % SUB if qw > SUB else 0
+        if pad:
+            parts = tuple(jnp.pad(
+                t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                for t in parts)
+        o, rec1 = gdn_chunk(*parts, rec0)
+        return o[:, :qw], rec1
+
+    def _mix(self, params, x, conv0, rec0, q_lens=None):
+        """The layer over a chunk from the state (conv0, rec0) a row: (y (B,
+        Q, D), the conv positions and the states to keep)."""
+        u, z, b_raw, a_raw = self._project(params, x)
+        c, conv1 = self._convolve(params, u, conv0, q_lens)
+        with jax.named_scope("gdn_state"):
+            o, rec1 = self._scan(
+                self._heads(params, c, b_raw, a_raw, q_lens), rec0)
+        return self._project_out(params, o, z), conv1, rec1
+
+    @property
+    def conv_rows(self):
+        """The shape a slot's ``conv - 1`` kept positions REST in: whole
+        (16, 128) registers of two-byte values, 512 lanes wide, where they
+        fill them (the pool's array is then gathered and scattered a slot at
+        a time in the layout it rests in, with no copy of it); else as they
+        are, ``(conv - 1, channels)``."""
+        total = (self.conv - 1) * self.channels
+        if total % (16 * 512):
+            return (self.conv - 1, self.channels)
+        return (total // 512, 512)
+
+    def _zero_state(self, batch: int):
+        return (jnp.zeros((batch, self.conv - 1, self.channels),
+                          self.policy.compute_dtype),
+                jnp.zeros((batch, self.value_heads, self.key_dim,
+                           self.value_dim), jnp.float32))
+
+    def _apply(self, params, state, x, *, train, rng):
+        y, _, _ = self._mix(params, x, *self._zero_state(x.shape[0]))
+        return y, state
+
+    # -- cached decode (the offline ``generate``): the cache IS the state --
+
+    def init_cache(self, batch: int, max_len: int, d_model: int):
+        conv, rec = self._zero_state(batch)
+        return {"conv": conv, "rec": rec}
+
+    def apply_cached(self, variables, x, cache, offset):
+        y, conv, rec = self._mix(variables["params"], x, cache["conv"],
+                                 cache["rec"])
+        return y, {"conv": conv, "rec": rec}
+
+    # -- the serving step -----------------------------------------------------
+
+    def apply_state(self, variables, x, state, slots, offsets, layer: int,
+                    q_lens=None):
+        """One step against the pool's state slots (``serving.kv_pool``:
+        ``conv`` (L, S) + ``conv_rows``, ``rec`` (L, S, Hv, Dk, Dv) float32,
+        and the snapshots ``conv_snap`` / ``rec_snap``): x (B, Q, D) with
+        ``q_lens[b]`` live tokens a row (None: the decode form, every row
+        ONE token) from position ``offsets[b]``, row ``b``'s state in slot
+        ``slots[b]``. A row at position 0 starts from zeros whatever its slot
+        holds; a row at a multiple of ``SNAPSHOT_EVERY`` keeps the state it
+        read (``snapshot_slots``). Returns (y (B, Q, D), state)."""
+        from ..ops.pallas.gdn_step import gdn_step
+
+        params = variables["params"]
+        snaps = snapshot_slots(slots, offsets)
+        fresh = (offsets == 0)
+        u, z, b_raw, a_raw = self._project(params, x)
+        with jax.named_scope("gdn_conv"):
+            conv0 = state["conv"][layer, slots]
+            conv0 = jnp.where(fresh[:, None, None], 0, conv0)
+            state = dict(state, conv_snap=state["conv_snap"].at[
+                layer, snaps].set(conv0))
+            conv0 = conv0.reshape(-1, self.conv - 1, self.channels)
+        c, conv1 = self._convolve(params, u, conv0, q_lens)
+        with jax.named_scope("gdn_conv"):
+            state["conv"] = state["conv"].at[layer, slots].set(
+                conv1.astype(state["conv"].dtype).reshape(
+                    (-1,) + self.conv_rows))
+        with jax.named_scope("gdn_state"):
+            q, k, v, g, beta = self._heads(params, c, b_raw, a_raw, q_lens)
+            if q_lens is None:
+                o, rec, snap = gdn_step(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    state["rec"], state["rec_snap"], slots, snaps,
+                    layer=layer)
+                o = o[:, None]
+            else:
+                rec0 = jnp.where(fresh[:, None, None, None], 0.0,
+                                 state["rec"][layer, slots])
+                snap = state["rec_snap"].at[layer, snaps].set(rec0)
+                o, rec1 = self._scan((q, k, v, g, beta), rec0)
+                rec = state["rec"].at[layer, slots].set(rec1)
+            state.update(rec=rec, rec_snap=snap)
+        return self._project_out(params, o, z), state
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def _config(self):
+        return {"key_heads": self.key_heads, "value_heads": self.value_heads,
+                "key_dim": self.key_dim, "value_dim": self.value_dim,
+                "conv": self.conv, "norm_eps": self.norm_eps}
+
+
+def _inv_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))

@@ -305,13 +305,19 @@ class ExpertShare(Module):
     (held, hidden, D): "out x in" for gate and up, "in x out" for down, so a
     block of hidden units is contiguous rows in each; the shared expert's
     ``shared_gate`` / ``shared_up`` (D, shared * hidden) and ``shared_down``
-    (shared * hidden, D)."""
+    (shared * hidden, D); with the option ``shared_gated``, ``shared_router``
+    (D, 1): the shared expert's output times ``sigmoid(x . shared_router)``."""
 
     def __init__(self, num_experts: int, held, top_k: int, hidden: int,
                  shared: int = 0, score: str = "softmax",
-                 route_scale: float = 1.0, zero_experts: int = 0, name=None,
-                 policy=None):
+                 route_scale: float = 1.0, zero_experts: int = 0,
+                 shared_gated: bool = False, name=None, policy=None):
         super().__init__(name=name, policy=policy)
+        # the shared expert's output times sigmoid(x . w_sg), a token's own
+        # gate (leaf ``shared_router`` (D, 1))
+        self.shared_gated = bool(shared_gated)
+        if self.shared_gated and not shared:
+            raise ValueError("a gate on the shared expert needs one")
         if score not in ("softmax", "sigmoid", "softmax_raw"):
             raise ValueError(f"score {score!r}: softmax, sigmoid or "
                              "softmax_raw")
@@ -334,7 +340,7 @@ class ExpertShare(Module):
     def _init(self, rng, input_shape):
         d, f, n = input_shape[-1], self.hidden, len(self.held)
         pd = self.policy.param_dtype
-        ks = jax.random.split(rng, 7)
+        ks = jax.random.split(rng, 8 if self.shared_gated else 7)
 
         def normal(key, shape, fan_in):
             return (jax.random.normal(key, shape, jnp.float32)
@@ -351,6 +357,8 @@ class ExpertShare(Module):
             params.update(shared_gate=normal(ks[4], (d, fs), d),
                           shared_up=normal(ks[5], (d, fs), d),
                           shared_down=normal(ks[6], (fs, d), fs))
+        if self.shared_gated:
+            params["shared_router"] = normal(ks[7], (d, 1), d)
         return params, {}
 
     @jax.named_scope("moe_route")
@@ -489,7 +497,14 @@ class ExpertShare(Module):
         g = qmatmul(xc, cast(params["shared_gate"]))
         u = qmatmul(xc, cast(params["shared_up"])).astype(xc.dtype)
         h = jax.nn.silu(g.astype(jnp.float32)).astype(xc.dtype) * u
-        return qmatmul(h, cast(params["shared_down"]))
+        y = qmatmul(h, cast(params["shared_down"]))
+        if self.shared_gated:
+            gate = jax.nn.sigmoid(jnp.matmul(
+                x.astype(jnp.float32),
+                params["shared_router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            y = y.astype(jnp.float32) * gate
+        return y
 
     def _apply(self, params, state, x, *, train, rng, live=None):
         """x (..., D) -> the held experts' share plus the shared expert, in
@@ -519,4 +534,6 @@ class ExpertShare(Module):
             cfg["route_scale"] = self.route_scale
         if self.zero_experts:
             cfg["zero_experts"] = self.zero_experts
+        if self.shared_gated:
+            cfg["shared_gated"] = True
         return cfg

@@ -32,7 +32,7 @@ class PagedDecoder:
     configuration), so the engine's normal path takes each of them."""
 
     def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
-                    offsets, q_lens=None):
+                    offsets, q_lens=None, state=None, head_at=None):
         """Ragged multi-token step against the paged KV pool.
 
         toks is (B, Q) with row b carrying ``q_lens[b]`` live new tokens
@@ -46,15 +46,33 @@ class PagedDecoder:
         (logits (B, Q, V), pages_k, pages_v); the caller reads row b's
         next-token logits at q position ``q_lens[b] - 1``, and donates the
         pages through jit for in-place pool updates. ``q_lens`` None is the
-        decode form (Q == 1)."""
+        decode form (Q == 1).
+
+        ``state``: the pool's state slots, for a model some of whose layers
+        keep a state updated in place and no pages (``_paged_layers`` gives
+        such a layer its ``slots``; its block's ``apply_state`` takes the
+        state and returns it). It is then a fourth result, donated like the
+        pages. ``head_at`` (B,): only that position of each row goes through
+        the head, logits (B, 1, V): a wide step of a large vocabulary then
+        holds no (B, Q, V) cube."""
         x = self._embed(params, toks, offsets)
         where = self._paged_layers(pages_k, block_tables)
         for i, block in enumerate(self.blocks):
             with jax.named_scope(f"h{i}"):
+                if "slots" in where[i]:
+                    x, state = block.apply_state(
+                        params[f"h{i}"], x, state, offsets=offsets,
+                        q_lens=q_lens, **where[i])
+                    continue
                 x, pages_k, pages_v = block.apply_paged(
                     params[f"h{i}"], x, pages_k, pages_v, offsets=offsets,
                     q_lens=q_lens, **where[i])
-        return self._head(params, self._ln_f(params, x)), pages_k, pages_v
+        if head_at is not None:
+            x = jnp.take_along_axis(x, head_at[:, None, None], axis=1)
+        logits = self._head(params, self._ln_f(params, x))
+        if state is None:
+            return logits, pages_k, pages_v
+        return logits, pages_k, pages_v, state
 
     @property
     def cache_layers(self) -> int:
@@ -78,13 +96,15 @@ class PagedDecoder:
         return out
 
     def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
-                           offsets):
+                           offsets, state=None):
         """One decode step: toks (B,) this step's token per row, offsets (B,)
         each row's position (kv length before this token). Returns
-        (last-position logits (B, V), pages_k, pages_v)."""
-        logits, pages_k, pages_v = self.apply_paged(
-            params, toks[:, None], pages_k, pages_v, block_tables, offsets)
-        return logits[:, -1], pages_k, pages_v
+        (last-position logits (B, V), pages_k, pages_v), and the ``state``
+        it was given."""
+        logits, *rest = self.apply_paged(
+            params, toks[:, None], pages_k, pages_v, block_tables, offsets,
+            state=state)
+        return (logits[:, -1], *rest)
 
 
 @register_module("gpt_block")

@@ -95,6 +95,7 @@ import numpy as np
 from ..models import sampling
 from ..profiling.profiler import EventType, Profiler
 from ..nn import moe as moe_lib
+from ..nn.attention import snapshot_slots
 from . import kv_pool as kv_pool_lib
 from . import spec_decode
 from . import step_build
@@ -170,6 +171,15 @@ def _stacked(counts) -> tuple:
     return tuple(jnp.stack(c) for c in (counts, counts.zero) if c)
 
 
+def _sampled(logits, poison, key, t, k, p):
+    """The tail of a plain step program: a row's logits (B, V) with the
+    chaos plan's ``poison`` added -> (its next token, whether they were
+    finite)."""
+    logits = logits + poison[:, None]
+    ok = jnp.isfinite(logits).all(axis=-1)
+    return sampling.sample_ragged(logits, key, t, k, p), ok
+
+
 def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
                     host_tier_bytes=0, kv_dtype="f32") -> Optional[str]:
     """One sentence saying why ``model`` does not serve with the first of
@@ -181,13 +191,28 @@ def refuse_windowed(model, *, prefix_cache=False, spec=False, tp=1, sp=1,
     global layers (two groups of page, the window group's given back from
     behind the window). What assumes K/V blocks of heads, one table a
     request, is refused at start-up, by the engine and by ``tnn-serve``
-    before it makes any weights."""
+    before it makes any weights. The fifth case: layers that keep a state
+    updated in place (Gated DeltaNet) beside layers with pages: state slots
+    beside the pool's pages."""
     window = getattr(model, "window", None)
     latent = getattr(model, "latent", None)
     groups = getattr(model, "page_groups", None)
-    if not window and not latent and not groups:
+    slots = getattr(model, "state_group", None)
+    if not window and not latent and not groups and not slots:
         return None
-    if groups:
+    if slots:
+        state = (f"a state updated in place at every position in "
+                 f"{slots['layers']} of its layers (state slots beside the "
+                 "pages), not K/V blocks of every position in every layer")
+        whys = ("a cached block holds no state, and the state behind a "
+                "shared prefix would have to be a snapshot of it",
+                "a rejected draft has advanced the state, and the verify "
+                "step keeps no snapshot to take it back to",
+                "the state's heads and its kernel are not head-sharded",
+                "a row's state is not block-sharded",
+                "the state is float32 and the full layers' int8 pages have "
+                "not been held against the reference beside it")
+    elif groups:
         state = (f"sliding-window layers (a window of {groups['window']}) "
                  "beside global layers over two groups of page, not one "
                  "table of K/V blocks for every layer")
@@ -536,7 +561,9 @@ class InferenceEngine:
             block_size=block_size, dtype=model.policy.compute_dtype,
             kv_dtype=kv_dtype, sharding=page_sharding, sp=self.sp,
             window=window, chunk=getattr(model, "chunk", None),
-            latent=bool(latent_row), groups=groups)
+            latent=bool(latent_row), groups=groups,
+            state=getattr(model, "state_group", None),
+            state_rows=max_batch_size)
         self.pool.fault_plan = faults
         # static gauge extras spliced into every _health_gauges refresh:
         # lets operators spot a misconfigured replica from /healthz alone
@@ -656,6 +683,11 @@ class InferenceEngine:
         # from. Kept because the benchmark reads it
         # (chipbench/drivers/serve_stdin.py:277-278).
         self.paged_fallback_reason: Optional[str] = None
+        if self.pool.slots is not None:
+            # the roll-back's one program, warmed like the step programs
+            # are: nothing compiles when a chain is first rolled back
+            none = self._put(np.zeros((max_batch_size,), np.int32))
+            self.pool.state = self._restore_fn()(self.pool.state, none, none)
 
     @staticmethod
     def _probe_paged(model) -> None:
@@ -840,6 +872,9 @@ class InferenceEngine:
                                    [r.summary_table for r in rows],
                                    [r.window_table for r in rows],
                                    [r.window_base for r in rows])
+        if self.pool.slots is not None:
+            self.pool.slots.check_invariants(
+                [r.state_slot for r in self.scheduler.running])
         if self.kv_tier is not None:
             self.kv_tier.check_invariants()
 
@@ -876,6 +911,87 @@ class InferenceEngine:
             self.pool.free(held)
             req.block_table, req.summary_table = [], []
             req.window_table, req.window_base = [], 0
+        if req.state_slot:
+            self.pool.slots.free(req.state_slot)
+            req.state_slot, req.snap_at = 0, [None, None]
+
+    # -- state slots (kv_pool: State slots) -----------------------------------
+
+    def _adopt_state(self, rest):
+        """A step program's results behind the pages: of a model with state
+        slots the first is the state arrays, adopted at dispatch like the
+        pages (``update_pages``); returns what is left (expert counters)."""
+        if self.pool.slots is not None:
+            self.pool.state, *rest = rest
+        return rest
+
+    def _note_snapshots(self, rows, starts) -> None:
+        """What a step just dispatched does to its rows' snapshots, by the
+        rule the program follows on the device (``snapshot_slots``):
+        a row it takes from a multiple of ``SNAPSHOT_EVERY`` has the state
+        of that position in the snapshot of that turn from now on."""
+        if self.pool.slots is None:
+            return
+        starts = np.asarray(starts[:len(rows)])
+        keeps = snapshot_slots(
+            np.array([req.state_slot for req in rows]), starts, np)
+        for i in np.flatnonzero(keeps):
+            # a row's two snapshots take turns: odd slots the even turns
+            rows[i].snap_at[(keeps[i] + 1) % 2] = int(starts[i])
+        self.metrics.observe_state_step(self.pool.slots.occupancy,
+                                        int(np.count_nonzero(keeps)))
+
+    def _restore_fn(self):
+        """``tnn_state_restore``: the named rows' snapshot into their live
+        state, the one program a roll-back adds (beside ``tnn_kv_cow``)."""
+        fn = self._jit.get("state_restore")
+        if fn is None:
+            def restore(arrays, slots, snaps):
+                return kv_pool_lib.restore_state(arrays, slots, snaps)
+            restore.__name__ = "tnn_state_restore"
+            fn = self._jit["state_restore"] = jax.jit(restore,
+                                                      donate_argnums=(0,))
+        return fn
+
+    def _restore_rows(self, rows) -> None:
+        """After a chain was rolled back: every surviving row of it goes
+        back to the newest snapshot at or before its committed length (the
+        steps of the chain have advanced its live state past it), or to its
+        first token where it has none (a row at position 0 starts from
+        zeros), and pushes the committed tokens behind that position again
+        as a prompt: the ordinary mixed step, whose page writes rewrite the
+        same rows. A decoding row keeps its pending token, as a resumed one
+        does."""
+        b = self.scheduler.max_batch_size
+        live, at_of = np.zeros((b,), np.int32), np.zeros((b,), np.int32)
+        n = replayed = 0
+        for req in rows:
+            if req.state is not RequestState.RUNNING or not req.state_slot:
+                continue
+            c = req.cache_len
+            at = max((p for p in req.snap_at if p is not None and p <= c),
+                     default=0)
+            # a snapshot a rolled-back step took past the commit is of
+            # tokens that were never committed
+            req.snap_at = [p if p is not None and p <= c else None
+                           for p in req.snap_at]
+            if at:
+                live[n], at_of[n] = req.state_slot, at
+            n += 1
+            replayed += c - at
+            if at < c:
+                if c >= req.prefill_len:    # it decodes: now it replays
+                    self._note_leave_running(req, time.perf_counter())
+                    req.phase, req.phase_t0 = "prefill", time.perf_counter()
+                req.prefill_len = max(req.prefill_len, c)
+                req.cache_len = at
+                req.block_table = self.pool.truncate(req.block_table, at)
+        if live.any():
+            self.pool.state = self._restore_fn()(
+                self.pool.state, self._put(live),
+                self._put(snapshot_slots(live, at_of, np)))
+        if n:
+            self.metrics.observe_state_restore(n, replayed)
 
     # a request's tables, in the order ``_grow_need`` counts and ``_extend``
     # allocates them: every position's pages (a windowed pool: the current
@@ -985,7 +1101,7 @@ class InferenceEngine:
                 qg=tile * (model.num_heads // rows),
                 page_dtype=(jnp.int8 if pool.kv_dtype == "int8"
                             else pool.dtype),
-                nb=self.blocks_per_seq, **kw)
+                nb=self.blocks_per_seq - (pool.slots is not None), **kw)
         return group
 
     def _observe_attention(self, rows, ends, qw: int, q_lens=None) -> None:
@@ -1230,6 +1346,10 @@ class InferenceEngine:
         attrs = self._dispatch_attrs.get(fn)
         if attrs is None:
             attrs = self._dispatch_attrs[fn] = self._program_attrs(fn, qw)
+        if self.pool.slots is not None:
+            # the rows that hold a state slot, as of this dispatch
+            attrs = dict(attrs, state_rows=self.pool.slots.rows
+                         - self.pool.slots.num_free)
         with self.tracer.span("serve.dispatch", EventType.COMPUTE, step=step,
                               kind=kind, ahead=ahead, sampled_rows=sampled,
                               **attrs):
@@ -1239,7 +1359,9 @@ class InferenceEngine:
             t1 = time.perf_counter()
             with self.tracer.span("serve.launch", EventType.COMPUTE,
                                   step=step):
-                out = fn(self.params, self.pool.pages_k, self.pool.pages_v,
+                pool = self.pool
+                out = fn(self.params, pool.pages_k, pool.pages_v,
+                         *(() if pool.slots is None else (pool.state,)),
                          *args)
             t2 = time.perf_counter()
         self.metrics.observe_put(staged + t1 - t0)
@@ -1681,8 +1803,8 @@ class InferenceEngine:
         packed = dict(
             b=b, nb=self.blocks_per_seq, scratch=PagedKVPool.SCRATCH,
             kv_key=self._kv_key, sum_at=pool.exact_width, kinds=pool.kinds,
-            lens=lens, on_device={row.req.rid for row in dec
-                                  if row.src != _ON_HOST})
+            state=pool.slots is not None, lens=lens,
+            on_device={row.req.rid for row in dec if row.src != _ON_HOST})
         t0 = time.perf_counter()
         if len(dec) < len(rows):
             takes = {req.rid: chunks[req.rid] for req in rows[len(dec):]}
@@ -1730,12 +1852,14 @@ class InferenceEngine:
             with self._sync_guard():
                 newtok, ok, pk, pv, *experts = self._dispatch(
                     fn, label, step.temps, qw, stage, ahead=j)
+                experts = self._adopt_state(experts)
         except Exception:  # noqa: BLE001 — speculation must never hurt
             self._unextend(rollback)
             self._reuse_keys.insert(0, step_key)
             self._recover_pages_if_dead(flight.events)
             return "other"
         pool.update_pages(pk, pv)
+        self._note_snapshots(rows, where[0])
         self._observe_attention(rows, ends, qw,
                                 step.q_lens if label == "mixed" else None)
         rec.update(dev=(newtok, ok, *experts), t0=t0, b=b, before=after)
@@ -1810,6 +1934,8 @@ class InferenceEngine:
         if not predicted:
             for s in reversed(ahead):
                 self._unextend(s["rollback"], only_intact=True)
+            if self.pool.slots is not None:
+                self._restore_rows(running)
             self._reuse_keys[:0] = [s["key"] for s in ahead]
             self._adopted_run = 0
             self.metrics.observe_overlap_rebuild()
@@ -1972,6 +2098,10 @@ class InferenceEngine:
                 f"{self.blocks_per_seq}", events, "failed")
             return False
         req.cache_len = 0
+        if self.pool.slots is not None:
+            # whatever the slot's last tenant left: a row at position 0
+            # starts from zeros (GatedDeltaNet.apply_state)
+            req.state_slot, req.snap_at = self.pool.slots.alloc(), [None, None]
         if self.prefix_cache is not None:
             self._match_prefix(req)
         self._note_admit(req, time.perf_counter())
@@ -2540,7 +2670,7 @@ class InferenceEngine:
             b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, spec_on=spec_on,
             kv_key=self._kv_key, sum_at=self.pool.exact_width,
-            kinds=self.pool.kinds)
+            kinds=self.pool.kinds, state=self.pool.slots is not None)
         self._check_step_writes(step, step.starts, step.q_lens)
         b, qw, poison = step.b, step.qw, step.poison
         if self.faults is not None:
@@ -2600,6 +2730,7 @@ class InferenceEngine:
                     accepts, newtok, ok, pk, pv = out
                 else:
                     newtok, ok, pk, pv, *experts = out
+                    experts = self._adopt_state(experts)
                 break
             except FaultInjected as e:
                 # injected pre-call: donated buffers untouched, retryable
@@ -2612,6 +2743,7 @@ class InferenceEngine:
                 self._abort_batch(rows, f"decode step failed: {e}", events)
                 return
         self.pool.update_pages(pk, pv)
+        self._note_snapshots(rows, step.starts)
         self._observe_attention(rows, step.starts + step.q_lens, qw,
                                 step.q_lens)
         flight.recs.append({
@@ -2741,6 +2873,23 @@ class InferenceEngine:
     def _mixed_paged_fn(self, b: int, qw: int, nb: int):
         model = self._step_model
 
+        def with_state(params, pages_k, pages_v, state, toks, starts, q_lens,
+                       tables, t, k, p, key, poison):
+            # ``fn`` below for a model with state slots: the state arrays
+            # ride beside the pages, donated in and returned; the head runs
+            # on each row's last live position alone
+            with moe_lib.collect_counts() as counts:
+                logits, pages_k, pages_v, state = model.apply_paged(
+                    params, toks, pages_k, pages_v, tables, starts, q_lens,
+                    state=state, head_at=jnp.maximum(q_lens - 1, 0))
+            newtok, ok = _sampled(logits[:, 0], poison, key, t, k, p)
+            return (newtok, ok, pages_k, pages_v, state) + _stacked(counts)
+
+        if self.pool.slots is not None:
+            return self._jit_step(f"tnn_serve_mixed_w{qw}", with_state,
+                                  donate_argnums=(1, 2, 3), n_outs=5,
+                                  tables_argnum=7)
+
         def fn(params, pages_k, pages_v, toks, starts, q_lens, tables,
                t, k, p, key, poison):
             # the ragged paged-attention kernel takes decode rows (q_len 1)
@@ -2752,9 +2901,7 @@ class InferenceEngine:
             last = jnp.take_along_axis(
                 logits, jnp.maximum(q_lens - 1, 0)[:, None, None],
                 axis=1)[:, 0]                                   # (B, V)
-            last = last + poison[:, None]
-            ok = jnp.isfinite(last).all(axis=-1)
-            newtok = sampling.sample_ragged(last, key, t, k, p)
+            newtok, ok = _sampled(last, poison, key, t, k, p)
             return (newtok, ok, pages_k, pages_v) + _stacked(counts)
 
         return self._jit_step(f"tnn_serve_mixed_w{qw}", fn,
@@ -2849,6 +2996,21 @@ class InferenceEngine:
     def _paged_decode_fn(self, batch: int, nb: int):
         model = self._step_model
 
+        def with_state(params, pages_k, pages_v, state, toks, offsets,
+                       tables, t, k, p, key, poison):
+            # ``fn`` below for a model with state slots
+            with moe_lib.collect_counts() as counts:
+                logits, pages_k, pages_v, state = model.apply_decode_paged(
+                    params, toks, pages_k, pages_v, tables, offsets,
+                    state=state)
+            newtok, ok = _sampled(logits, poison, key, t, k, p)
+            return (newtok, ok, pages_k, pages_v, state) + _stacked(counts)
+
+        if self.pool.slots is not None:
+            return self._jit_step("tnn_serve_decode", with_state,
+                                  donate_argnums=(1, 2, 3), n_outs=5,
+                                  tables_argnum=6)
+
         def fn(params, pages_k, pages_v, toks, offsets, tables, t, k, p, key,
                poison):
             # no assembled cache: the model scatters each
@@ -2858,9 +3020,7 @@ class InferenceEngine:
             with moe_lib.collect_counts() as counts:
                 logits, pages_k, pages_v = model.apply_decode_paged(
                     params, toks, pages_k, pages_v, tables, offsets)
-            logits = logits + poison[:, None]
-            ok = jnp.isfinite(logits).all(axis=-1)
-            newtok = sampling.sample_ragged(logits, key, t, k, p)
+            newtok, ok = _sampled(logits, poison, key, t, k, p)
             return (newtok, ok, pages_k, pages_v) + _stacked(counts)
 
         return self._jit_step("tnn_serve_decode", fn, donate_argnums=(1, 2),
@@ -2871,8 +3031,11 @@ class InferenceEngine:
         invariant the in-place page write relies on (``q_lens`` None: the
         decode form, one token a row)."""
         if self.pool.debug:
+            # a pool with state slots: the last entry is no block
+            tables = step.tables if self.pool.slots is None \
+                else step.tables[:, :-1]
             self.pool.check_step_writes(
-                step.tables, starts,
+                tables, starts,
                 np.ones_like(starts) if q_lens is None else q_lens)
 
     def _decode_build(self, live: Sequence[Request],
@@ -2886,7 +3049,7 @@ class InferenceEngine:
             live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
             scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
             sum_at=self.pool.exact_width,
-            kinds=self.pool.kinds)
+            kinds=self.pool.kinds, state=self.pool.slots is not None)
         self._check_step_writes(step, step.offsets)
         b, nb, key = step.b, step.nb, step.key
         poison = step.poison
@@ -2913,6 +3076,7 @@ class InferenceEngine:
                         self._put_tables(step.tables), self._put(step.temps),
                         self._put(step.topks), self._put(step.topps),
                         step_key, self._put(poison)))
+                experts = self._adopt_state(experts)
                 break
             except FaultInjected as e:
                 # injected pre-call: donated buffers untouched, retryable
@@ -2927,6 +3091,7 @@ class InferenceEngine:
                 self._abort_batch(live, f"decode step failed: {e}", events)
                 return None
         self.pool.update_pages(pk, pv)
+        self._note_snapshots(live, step.offsets)
         self._observe_attention(live, step.offsets + 1, 1)
         return {"kind": "decode", "dev": (newtok, ok, *experts),
                 "live": list(live), "t0": t0, "b": b}

@@ -113,6 +113,25 @@ registers, so that the pool rests in the layout its readers take):
 ``pages_k`` is ``(L, N, 1, bs, row)`` from the same allocator and tables,
 and the value pool is NOT allocated: ``pages_v`` is a stub of one register
 that rides through the step programs untouched.
+
+State slots
+-----------
+A model some of whose layers keep a STATE that every position updates in
+place (Gated DeltaNet: ``nn.attention.GatedDeltaNet``) holds, beside the
+pages of its other layers, a group of fixed-size SLOTS (``StateSlots``,
+``pool.slots``): a slot is one row's state in every such layer, the
+convolution's last positions and the recurrent state. A request takes a slot
+at admission and gives it back when it leaves; admission counts it beside the
+pages. A row holds THREE sets of a slot: ``live``, which every step advances,
+and two snapshots. A step that takes a row from a position that is a multiple
+of ``nn.attention.SNAPSHOT_EVERY`` writes the state it READ into snapshot
+``(position / SNAPSHOT_EVERY) % 2`` (``nn.attention.snapshot_slots``): while
+the overlapped loop runs up to ``engine.SPECULATE_MAX`` <= ``SNAPSHOT_EVERY``
+steps ahead of the commit, the
+snapshot at or before the committed position is never overwritten, so a
+rolled-back chain restores it (``restore``) and pushes the few committed
+tokens behind it again. A step's packed table carries the row's slot as its
+LAST entry (``table_width``); slot 0 is scratch, as block 0 is.
 """
 from __future__ import annotations
 
@@ -133,6 +152,88 @@ class PoolExhausted(RuntimeError):
     """No free blocks — the scheduler preempts and retries."""
 
 
+class StateSlots:
+    """The pool's state group (module docstring: State slots): ``rows``
+    slots (+ the scratch slot 0) of ``layers`` layers' state each, in four
+    device arrays that ride through the step programs donated, like the
+    pages: ``conv`` (L, rows + 1, taps - 1, channels) and ``rec`` (L, rows +
+    1, heads, Dk, Dv) float32, the live states; ``conv_snap`` / ``rec_snap``
+    with ``2 * rows + 1`` slots, a row's two snapshots at ``2 * (slot - 1) +
+    1`` and ``+ 2`` (0: the dump slot of a row that keeps nothing)."""
+
+    def __init__(self, group: dict, rows: int, dtype, sharding=None):
+        self.layers, self.rows = int(group["layers"]), int(rows)
+        self.conv_shape = tuple(group["conv"])
+        self.rec_shape = tuple(group["rec"])
+        self.dtype, self.sharding = dtype, sharding
+        self._free: List[int] = list(range(self.rows, 0, -1))
+        # made by ``reset`` (the pool's ``reset_pages`` calls it)
+        self.arrays: Dict[str, jax.Array] = {}
+
+    def reset(self) -> None:
+        """Fresh zeroed arrays (explicit puts: ``PagedKVPool.reset_pages``
+        says why)."""
+        def zeros(slots, shape, dtype):
+            x = np.zeros((self.layers, slots) + shape, np.dtype(dtype))
+            return jax.device_put(x, self.sharding) if self.sharding \
+                is not None else jax.device_put(x)
+
+        live, kept = self.rows + 1, 2 * self.rows + 1
+        self.arrays = {
+            "conv": zeros(live, self.conv_shape, self.dtype),
+            "rec": zeros(live, self.rec_shape, np.float32),
+            "conv_snap": zeros(kept, self.conv_shape, self.dtype),
+            "rec_snap": zeros(kept, self.rec_shape, np.float32)}
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def occupancy(self) -> float:
+        return (self.rows - len(self._free)) / max(self.rows, 1)
+
+    def alloc(self) -> int:
+        if not self._free:
+            raise PoolExhausted("no free state slot")
+        return self._free.pop()
+
+    def free(self, slot: int) -> None:
+        if not 1 <= slot <= self.rows or slot in self._free:
+            raise ValueError(f"state slot {slot} is not held")
+        self._free.append(slot)
+
+    def deleted(self) -> bool:
+        return getattr(self.arrays["rec"], "is_deleted", lambda: False)()
+
+    def check_invariants(self, held: Sequence[int]) -> None:
+        """Every slot is free or held by exactly one running request."""
+        held = list(held)
+        if sorted(held + self._free) != list(range(1, self.rows + 1)):
+            raise ValueError(f"state slots: held {sorted(held)} and free "
+                             f"{sorted(self._free)} are not a partition of "
+                             f"1..{self.rows}")
+
+
+@jax.named_scope("state_restore")
+def restore_state(arrays, slots, snaps):
+    """``live[slots[i]] <- snapshot[snaps[i]]`` in every layer, a row at a
+    time (the others: the scratch slot from the dump slot): what a
+    rolled-back chain's rows go back to. Traces into the engine's
+    ``tnn_state_restore``; with the arrays donated it moves the named rows
+    and nothing else (no temporary wider than one row's state)."""
+    def one(i, live):
+        conv, rec = live
+        put = jax.lax.dynamic_update_index_in_dim
+        take = jax.lax.dynamic_index_in_dim
+        return (put(conv, take(arrays["conv_snap"], snaps[i], 1), slots[i], 1),
+                put(rec, take(arrays["rec_snap"], snaps[i], 1), slots[i], 1))
+
+    conv, rec = jax.lax.fori_loop(0, slots.shape[0], one,
+                                  (arrays["conv"], arrays["rec"]))
+    return dict(arrays, conv=conv, rec=rec)
+
+
 class PagedKVPool:
     SCRATCH = 0  # reserved block for padded/inactive batch rows
 
@@ -140,7 +241,17 @@ class PagedKVPool:
                  num_blocks: int, block_size: int = 16, dtype=jnp.float32,
                  kv_dtype: str = "f32", sharding=None, sp: int = 1,
                  window: Optional[int] = None, chunk: Optional[int] = None,
-                 latent: bool = False, groups: Optional[dict] = None):
+                 latent: bool = False, groups: Optional[dict] = None,
+                 state: Optional[dict] = None, state_rows: int = 0):
+        # layers that keep a state updated in place: see "State slots"
+        if state and (window is not None or latent or groups or sp > 1
+                      or kv_dtype != "f32" or state_rows < 1):
+            raise ValueError(
+                "a pool with state slots holds plain K/V pages for its "
+                "other layers and is neither windowed, latent, of two "
+                "groups, block-sharded (sp) nor int8")
+        self.slots: Optional[StateSlots] = StateSlots(
+            state, state_rows, dtype, sharding) if state else None
         if groups and (window is not None or latent or sp > 1
                        or kv_dtype != "f32" or num_layers != 1
                        or min(groups["full_layers"],
@@ -339,7 +450,18 @@ class PagedKVPool:
         together because they are donated together."""
         leaf = self.pages_k.data if isinstance(self.pages_k, QuantPages) \
             else self.pages_k
-        return getattr(leaf, "is_deleted", lambda: False)()
+        return getattr(leaf, "is_deleted", lambda: False)() or (
+            self.slots is not None and self.slots.deleted())
+
+    @property
+    def state(self):
+        """The state group's device arrays (None: pages only): the step
+        programs' third donated argument and fifth result."""
+        return self.slots.arrays if self.slots is not None else None
+
+    @state.setter
+    def state(self, arrays) -> None:
+        self.slots.arrays = arrays
 
     def blocks_for(self, num_tokens: int) -> int:
         """Blocks needed to hold ``num_tokens`` cache positions (in a pool
@@ -432,7 +554,8 @@ class PagedKVPool:
             return (self.full_layers * self.blocks_for(total_tokens)
                     + self.window_layers * self.win_pages + 1)
         if self.window is None:
-            return self.blocks_for(total_tokens)
+            # a pool with state slots: the row's slot rides as the last entry
+            return self.blocks_for(total_tokens) + (self.slots is not None)
         return self.exact_width + max(1, math.ceil(
             (total_tokens // self.chunk) / self.block_size))
 
@@ -919,6 +1042,8 @@ class PagedKVPool:
             self.pages_v = put(np.zeros(
                 (self.num_layers, 1, 1, 8, 128) if self.latent else shape,
                 np.dtype(self.dtype)))
+        if self.slots is not None:
+            self.slots.reset()
 
     def export_blocks(self, blocks: Sequence[int]) \
             -> List[tuple]:

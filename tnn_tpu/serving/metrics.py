@@ -273,6 +273,16 @@ _DIRECT_FAMILIES: Tuple[Tuple[str, str, str, str], ...] = (
      "a row that fits it, else all) in launches wider than one tile"),
     ("attn_query_tiles_held", "tnn_serve_attn_query_tiles_total", "counter",
      "Query tiles held by paged launches wider than one tile"),
+    ("state_slots_occupancy_max", "tnn_serve_state_slots_occupancy_max",
+     "gauge", "Largest share of the pool's state slots held by running "
+     "requests (a model with layers that keep a state)"),
+    ("state_snapshots", "tnn_serve_state_snapshots_total", "counter",
+     "Row states kept in a snapshot slot by a dispatched step"),
+    ("state_restores", "tnn_serve_state_restores_total", "counter",
+     "Rows whose live state a rolled-back chain put back from a snapshot "
+     "(or from its first token)"),
+    ("state_replayed_tokens", "tnn_serve_state_replayed_tokens_total",
+     "counter", "Committed tokens pushed again behind a restored state"),
 )
 
 
@@ -539,6 +549,12 @@ class ServingMetrics:
         self.win_row_steps = 0
         self.win_pool_occupancy_max = 0.0
         self.win_pages_released = 0
+        # a model with state slots (kv_pool: State slots)
+        self.state_steps = 0
+        self.state_slots_occupancy_max = 0.0
+        self.state_snapshots = 0
+        self.state_restores = 0
+        self.state_replayed_tokens = 0
         self.attn_fetch_fill_sum = 0.0      # over attn_fetch_row_steps
         self.attn_fetch_row_steps = 0
         # paged launches wider than one query tile: the tiles the kernel
@@ -754,6 +770,22 @@ class ServingMetrics:
         self.win_row_steps += len(fills)
         self.win_pool_occupancy_max = max(self.win_pool_occupancy_max,
                                           occupancy)
+
+    def observe_state_step(self, occupancy: float, snapshots: int) -> None:
+        """One dispatched step of a model with state slots: the share of
+        the slots held, and how many of its rows kept the state they read
+        in a snapshot."""
+        self.state_steps += 1
+        self.state_slots_occupancy_max = max(self.state_slots_occupancy_max,
+                                             occupancy)
+        self.state_snapshots += int(snapshots)
+
+    def observe_state_restore(self, rows: int, tokens: int) -> None:
+        """A rolled-back chain put ``rows`` rows' states back; ``tokens``
+        committed tokens lie behind the restored positions and are pushed
+        again."""
+        self.state_restores += int(rows)
+        self.state_replayed_tokens += int(tokens)
 
     def observe_window_release(self, blocks: int) -> None:
         """A row gave ``blocks`` window-group blocks back: the pages now
@@ -1218,6 +1250,13 @@ class ServingMetrics:
                 win_fill_mean=self.win_fill_sum / self.win_row_steps,
                 win_pool_occupancy_max=self.win_pool_occupancy_max,
                 win_pages_released=self.win_pages_released)
+        if self.state_steps:
+            # only a model with state slots has these
+            out.update(
+                state_slots_occupancy_max=self.state_slots_occupancy_max,
+                state_snapshots=self.state_snapshots,
+                state_restores=self.state_restores,
+                state_replayed_tokens=self.state_replayed_tokens)
         if self.expert_layer_steps:
             # only a model with an expert layer has these
             out.update(

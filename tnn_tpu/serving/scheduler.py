@@ -90,6 +90,11 @@ class Request:
     #                                     page groups: the window layers'
     #                                     pages it still holds, from logical
     window_base: int = 0                # page ``window_base`` (kv_pool.py)
+    state_slot: int = 0                 # a pool with state slots: the slot
+    #                                     it holds while it runs (0: none),
+    #                                     and the position each of its two
+    #                                     snapshots was taken at (kv_pool.py)
+    snap_at: List[Optional[int]] = field(default_factory=lambda: [None, None])
     cache_len: int = 0                  # tokens resident in the KV pool
     prefill_len: int = 0                # total tokens the current (re-)
     #                                     prefill must push; while cache_len
@@ -309,6 +314,9 @@ class Scheduler:
         sole = not self.running and not admitted
         if budget < 1 and not sole:
             return None
+        slots = getattr(pool, "slots", None)
+        if slots is not None and slots.num_free <= admitted:
+            return None     # a state slot is counted beside the pages
         tokens = req.resume_tokens
         cached = forked = revive = 0
         if self.prefix_cache is not None:

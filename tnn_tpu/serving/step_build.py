@@ -103,8 +103,10 @@ def shard_tables(tables: np.ndarray, sp: int,
 
 
 def _fill_row(step: PackedStep, i: int, req, sum_at: int = 0,
-              kinds=None) -> None:
-    """``sum_at``: where a windowed pool's summary pages start in the packed
+              kinds=None, state: bool = False) -> None:
+    """``state``: a pool with state slots (``kv_pool``: State slots): the
+    row's LAST entry is its slot (a padding row's: the scratch slot 0).
+    ``sum_at``: where a windowed pool's summary pages start in the packed
     table (its exact segment's width, ``PagedKVPool.exact_width``).
     ``kinds``: a pool of two page groups (``PagedKVPool.kinds``: global
     layers, window layers, entries a window layer): the row is a segment a
@@ -121,6 +123,8 @@ def _fill_row(step: PackedStep, i: int, req, sum_at: int = 0,
         row[-1] = req.window_base
     else:
         step.tables[i, :len(req.block_table)] = req.block_table
+        if state:
+            step.tables[i, -1] = req.state_slot
     if req.summary_table:
         step.tables[i, sum_at:sum_at + len(req.summary_table)] = \
             req.summary_table
@@ -141,7 +145,7 @@ def _alloc_common(b: int, nb: int, scratch: int):
 def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
                takes: Dict[int, int], *, b: int, nb: int, scratch: int,
                spec_on: bool, kv_key: Tuple[Any, ...],
-               sum_at: int = 0, kinds=None,
+               sum_at: int = 0, kinds=None, state: bool = False,
                lens: Optional[Dict[int, int]] = None,
                on_device: Collection[int] = ()) -> MixedStep:
     """Pack decode rows (first ``n_dec`` of ``rows``, each 1 token +
@@ -165,7 +169,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(rows):
         start = step.starts[i] = lens[req.rid] if lens else req.cache_len
-        _fill_row(step, i, req, sum_at, kinds)
+        _fill_row(step, i, req, sum_at, kinds, state)
         if i < n_dec:
             d = drafts.get(req.rid, []) if spec_on else []
             if req.rid not in on_device:
@@ -186,6 +190,7 @@ def pack_mixed(rows: Sequence[Any], n_dec: int, drafts: Dict[int, Any],
 
 def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
                 kv_key: Tuple[Any, ...], sum_at: int = 0, kinds=None,
+                state: bool = False,
                 lens: Optional[Dict[int, int]] = None,
                 on_device: Collection[int] = ()) -> DecodeStep:
     """Pack the pure-decode batch. ``lens`` / ``on_device``: the predicted
@@ -202,5 +207,5 @@ def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
         if req.rid not in on_device:
             step.toks[i] = req.next_token
         step.offsets[i] = lens[req.rid] if lens else req.cache_len
-        _fill_row(step, i, req, sum_at, kinds)
+        _fill_row(step, i, req, sum_at, kinds, state)
     return step
