@@ -17,6 +17,9 @@ comes out by the repo's own means. Phases, each printing its name and result:
              chunk widths 8 and 64, spec width 5, bf16 pages (plain, and two
              heads a page row as the pool holds them) and int8 pages, stats
              on and off, -1-holed tables. Max abs error per row of the table.
+             Then the other kernels at their cells' widths against their XLA
+             forms, among them the flash kernels at the training cell's call
+             (forward, and forward + backward) with their sub-tile plan.
   serve      nine token-id requests (prompts of 5..700 tokens, two sharing a
              96-token prefix) through ``tnn-serve --model gpt2_small
              --num-blocks 512 --block-size 16 --max-batch-size 8``, every
@@ -112,6 +115,9 @@ CHIP = dict(
     state=dict(layers=2, rows=96, heads=32, key=128, value=128),
     heads256=dict(blocks=160, block_size=128, heads=16, kv_heads=2,
                   head_dim=256, batch=8, table=16, chunk=32),
+    # the flash kernels at gpt2-medium.train's call: batch 8, 16 heads of
+    # 64 over 1,024 causal positions
+    flash=dict(batch=8, heads=16, seq=1024, head_dim=64, iters=10),
     # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
     # 32 rows of Mistral Small 4's slice
     sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
@@ -135,6 +141,7 @@ REHEARSAL = dict(
     state=dict(layers=2, rows=3, heads=8, key=16, value=16),
     heads256=dict(blocks=16, block_size=8, heads=4, kv_heads=2, head_dim=32,
                   batch=3, table=6, chunk=8),
+    flash=dict(batch=1, heads=2, seq=64, head_dim=32, iters=1),
     sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
@@ -279,7 +286,8 @@ def phase_kernel(cfg) -> list:
                             f"holes={tname}: error above {tol}")
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
         + _window_kernel(cfg, rand, rng) + _state_kernels(cfg, rand, rng) \
-        + _short_rows(cfg, rand, rng) + _sampler_steps(cfg, rng)
+        + _short_rows(cfg, rand, rng) + _flash_training_call(cfg, rand) \
+        + _sampler_steps(cfg, rng)
 
 
 def _state_kernels(cfg, rand, rng) -> list:
@@ -403,6 +411,65 @@ def _short_rows(cfg, rand, rng) -> list:
                 f"form's by {err:.2e} (tol {tol:.0e}), dead positions read "
                 f"{dead:.2e}"]
     return []
+
+
+def _flash_training_call(cfg, rand) -> list:
+    """The flash kernels at the training cell's call (causal, no offset, no
+    mask): the forward, and the forward + backward through ``jax.grad``,
+    against the XLA path, with the sub-tile plan they walk and the ms a call
+    (host clock; a mismatch fails, a time never does)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.nn.attention import local_xla_attention
+    from tnn_tpu.ops.pallas import flash_attention as fa
+
+    k = cfg["flash"]
+    shape = (k["batch"], k["heads"], k["seq"], k["head_dim"])
+    q, kk, v, g = (rand(shape) for _ in range(4))
+
+    def grad_of(attn):
+        return jax.jit(jax.grad(
+            lambda q, kk, v: jnp.sum(attn(q, kk, v, causal=True).astype(
+                jnp.float32) * g.astype(jnp.float32)), argnums=(0, 1, 2)))
+
+    calls = {"forward": (jax.jit(functools.partial(
+                             fa.flash_attention, causal=True)),
+                         jax.jit(functools.partial(
+                             local_xla_attention, causal=True))),
+             "forward + backward": (grad_of(fa.flash_attention),
+                                    grad_of(local_xla_attention))}
+    failures = []
+    for name, (flash, xla) in calls.items():
+        got, want = flash(q, kk, v), xla(q, kk, v)           # compiles
+        t0 = time.perf_counter()
+        for _ in range(k["iters"]):
+            last = flash(q, kk, v)
+        jax.block_until_ready(last)
+        ms = (time.perf_counter() - t0) / k["iters"] * 1e3
+        # a gradient's largest entries are a few units: held as a share of
+        # the largest, like the bf16 ulp that bounds it
+        err = 0.0
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            err = max(err, float(np.max(np.abs(a - b))
+                                 / max(1.0, np.max(np.abs(b)))))
+        ok = err <= KERNEL_TOL["bf16"]
+        log(f"flash {name} {shape}: max|err| {err:9.2e} tol "
+            f"{KERNEL_TOL['bf16']:6.0e} {'ok' if ok else 'FAIL'}, "
+            f"{ms:.3f} ms a call (host clock, {k['iters']} calls)")
+        if not ok:
+            failures.append(f"kernel flash {name}: error above "
+                            f"{KERNEL_TOL['bf16']}")
+    s = k["seq"]
+    plans = [tuple(fa.causal_tile_plan(s, s, block, block, sub, sub))
+             for block, sub in ((fa.DEFAULT_BLOCK_Q, fa.SUB_TILE),
+                                (fa.DEFAULT_BLOCK_FUSED_BWD, fa.SUB_TILE))]
+    log("flash sub-tiles of a head (unmasked, masked, left out): forward "
+        f"{plans[0]}, backward {plans[1]}")
+    return failures
 
 
 def _window_kernel(cfg, rand, rng) -> list:

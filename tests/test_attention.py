@@ -347,6 +347,190 @@ def test_fused_bwd_causal_short_query_no_offset(monkeypatch):
                                    atol=1e-5, err_msg=name)
 
 
+SUB = 16   # the sub-tile the tests shrink to: a block of 64 is a 4 x 4 plan
+
+
+@pytest.fixture
+def small_sub_tiles(monkeypatch):
+    from tnn_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "SUB_TILE", SUB)
+    return fa
+
+
+# name -> (sq, skv, causal, kv heads of 4, extra). Blocks of 64 (forward and
+# backward): "one_block" is the training call, spans static and unrolled;
+# a grid of several blocks, an offset or a mask makes them traced.
+SUB_TILE_CASES = {
+    "one_block": (64, 64, True, 4, None),
+    "grid_2x2": (128, 128, True, 4, None),
+    "padded_last_key_tile": (72, 72, True, 4, None),
+    "short_query_no_offset": (32, 64, True, 4, None),
+    "static_offset": (32, 64, True, 4, "offset"),
+    "traced_offset": (32, 64, True, 4, "traced_offset"),
+    "padding_mask": (64, 64, True, 4, "mask"),
+    "gqa_4_to_1": (64, 64, True, 1, None),
+    "non_causal": (64, 64, False, 4, None),
+    "non_causal_padded": (72, 72, False, 4, None),
+}
+
+
+@pytest.mark.parametrize("fused", ["1", "0"])
+@pytest.mark.parametrize("case", sorted(SUB_TILE_CASES))
+def test_sub_tiles_match_xla(small_sub_tiles, monkeypatch, case, fused):
+    """Forward and dq / dk / dv through the sub-tile walk (skip above the
+    diagonal, masks only where a dead element is) against the XLA path."""
+    from tnn_tpu.nn.attention import local_xla_attention
+
+    fa = small_sub_tiles
+    monkeypatch.setenv("TNN_FLASH_FUSED_BWD", fused)
+    sq, skv, causal, hkv, extra = SUB_TILE_CASES[case]
+    rs = np.random.RandomState(31)
+    q = jnp.asarray(rs.randn(2, 4, sq, 32), jnp.float32)
+    k = jnp.asarray(rs.randn(2, hkv, skv, 32), jnp.float32)
+    v = jnp.asarray(rs.randn(2, hkv, skv, 32), jnp.float32)
+    g = jnp.asarray(rs.randn(2, 4, sq, 32), jnp.float32)
+    mask = None
+    if extra == "mask":     # every row keeps key 0: no row is fully masked
+        mask = jnp.asarray((rs.rand(2, 1, sq, skv) > 0.3)
+                           | (np.arange(skv) == 0))
+    off = None if extra not in ("offset", "traced_offset") else skv - sq
+
+    def both(off):
+        def run(attn):
+            def loss(q, k, v):
+                out = attn(q, k, v)
+                return jnp.vdot(out, g), out
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (out,) + grads
+        return (run(lambda q, k, v: fa.flash_attention(
+                    q, k, v, causal, None, 64, 64, 64, 64, mask=mask,
+                    kv_offset=off)),
+                run(lambda q, k, v: local_xla_attention(
+                    q, k, v, causal=causal, mask=mask, kv_offset=off)))
+
+    if extra == "traced_offset":
+        got, want = jax.jit(both)(jnp.asarray(off, jnp.int32))
+    else:
+        got, want = both(off)
+    for name, a, b in zip("out dq dk dv".split(), got, want):
+        assert np.all(np.isfinite(np.asarray(a))), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+def test_sub_tiles_fully_masked_row_reads_zero_and_inf_lse(small_sub_tiles):
+    """A row whose keys are all masked: output 0, lse +inf (its p is then 0
+    in the backward), with the other rows untouched by it."""
+    fa = small_sub_tiles
+    rs = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rs.randn(1, 2, 64, 32), jnp.float32)
+               for _ in range(3))
+    mask = np.ones((64, 64), bool)
+    mask[37, :] = False
+    mask = fa._norm_mask(jnp.asarray(mask), 1, 2, 64, 64)
+    out, res = fa._flash_fwd(q, k, v, mask, jnp.zeros((1,), jnp.int32), True,
+                             None, 64, 64, clamp_dead=True)
+    lse = np.asarray(res[-1])
+    assert np.all(np.asarray(out)[:, :, 37] == 0)
+    assert np.all(np.isposinf(lse[:, 37]))
+    assert np.isfinite(np.delete(lse, 37, axis=1)).all()
+    assert np.isfinite(np.asarray(out)).all()
+
+
+# (sq, skv, block_q, block_k, tq, tk): blocks and the sub-tiles inside them
+SPAN_GEOMETRIES = [
+    (1024, 1024, 1024, 1024, 256, 256),   # the training cell's call
+    (1024, 1024, 1024, 1024, 256, 512),
+    (1024, 1024, 1024, 1024, 512, 128),
+    (1024, 1024, 512, 512, 256, 256),
+    (1024, 1024, 512, 1024, 128, 256),    # the split backward's blocks
+    (1024, 1024, 512, 1024, 512, 1024),   # ... as they run: one sub-tile
+    (64, 64, 64, 64, 16, 16),
+    (72, 72, 64, 64, 16, 16),             # padding in the last key block
+    (72, 72, 64, 64, 64, 64),
+    (100, 256, 64, 64, 16, 32),           # sq < skv
+    (200, 200, 1024, 1024, 200, 200),     # a block no sub-tile divides
+    (1100, 1100, 1024, 1024, 256, 256),   # sub-tiles that are all padding
+    (4096, 4096, 1024, 1024, 1024, 1024),
+]
+
+
+def _tile_kinds(sq_p, skv_p, skv, tq, tk, causal):
+    """Brute force over the mask: 0 a sub-tile with every element live, 1
+    with a live and a dead one, 2 with none live. Padded keys are dead;
+    padded query rows are rows like any other (the kernels do not mask
+    them: what they read is sliced off)."""
+    live = np.arange(skv_p)[None, :] < skv
+    if causal:
+        live = live & (np.arange(skv_p)[None, :] <= np.arange(sq_p)[:, None])
+    tiles = np.broadcast_to(live, (sq_p, skv_p)).reshape(
+        sq_p // tq, tq, skv_p // tk, tk)
+    return np.where(tiles.all((1, 3)), 0, np.where(tiles.any((1, 3)), 1, 2))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("geometry", SPAN_GEOMETRIES,
+                         ids=lambda g: "x".join(map(str, g)))
+def test_sub_tile_spans_match_the_mask(geometry, causal):
+    """The span the kernels are built from (the query sub-tiles of a key
+    sub-tile) against a brute-force reading of the mask: no sub-tile with a
+    live element is left out, none with a dead element runs unmasked."""
+    from tnn_tpu.ops.pallas import flash_attention as fa
+
+    sq, skv, block_q, block_k, tq, tk = geometry
+    bq, bk, sq_p, skv_p = fa._block_geometry(sq, skv, block_q, block_k)
+    na, nc = bq // tq, bk // tk
+    got = np.full((sq_p // tq, skv_p // tk), 2)
+    for qb in range(sq_p // bq):
+        for kb in range(skv_p // bk):
+            delta = qb * bq - kb * bk if causal else None
+            for c in range(nc):
+                a_first, a_full = fa._query_span(c, delta, skv - kb * bk, tq,
+                                                 tk, na)
+                assert 0 <= a_first <= a_full <= na
+                got[qb * na + a_first:qb * na + a_full, kb * nc + c] = 1
+                got[qb * na + a_full:(qb + 1) * na, kb * nc + c] = 0
+    np.testing.assert_array_equal(
+        got, _tile_kinds(sq_p, skv_p, skv, tq, tk, causal))
+
+
+# (sq, skv, block_q, block_k, sub_q, sub_k) -> what causal_tile_plan says
+PLAN_GEOMETRIES = {
+    # the training cell: 4 x 4 sub-tiles of 256, 6 below the diagonal, 4 on
+    # it, 6 above
+    (1024, 1024, 1024, 1024, 256, 256): (6, 4, 6),
+    (1024, 1024, 1024, 1024, 512, 512): (1, 2, 1),
+    (1024, 1024, 1024, 1024, 128, 128): (28, 8, 28),
+    (1024, 1024, 1024, 1024, 1024, 1024): (0, 1, 0),
+    # a grid of several blocks: the sub-tile is the block
+    (1024, 1024, 512, 512, 256, 256): (1, 2, 1),
+    (4096, 4096, 1024, 1024, 256, 256): (6, 4, 6),
+    (1100, 1100, 1024, 1024, 256, 256): (1, 2, 1),   # the last key block pads
+    (200, 200, 1024, 1024, 256, 256): (0, 1, 0),     # no sub-tile divides 200
+    (64, 64, 64, 64, 16, 16): (6, 4, 6),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(PLAN_GEOMETRIES),
+                         ids=lambda g: "x".join(map(str, g)))
+def test_causal_tile_plan_matches_the_mask(geometry):
+    """The plan's three counts against the brute-force reading at the
+    sub-tile the kernels use there, and against the table above."""
+    from tnn_tpu.ops.pallas import flash_attention as fa
+
+    sq, skv, block_q, block_k, sub_q, sub_k = geometry
+    bq, bk, sq_p, skv_p = fa._block_geometry(sq, skv, block_q, block_k)
+    tq, tk = fa._sub_tiles(bq, bk, sub_q, sub_k, (sq_p, skv_p) == (bq, bk))
+    for causal in (True, False):
+        want = _tile_kinds(sq_p, skv_p, skv, tq, tk, causal)
+        plan = fa.causal_tile_plan(*geometry, causal=causal)
+        assert plan == tuple(int((want == kind).sum()) for kind in (0, 1, 2))
+        assert sum(plan) == want.size
+    assert fa.causal_tile_plan(*geometry) == PLAN_GEOMETRIES[geometry]
+
+
 class TestGQA:
     """Grouped-query attention (beyond reference): H_kv < H shares kv heads
     across query groups; the pallas kernel maps q-head grid indices to kv

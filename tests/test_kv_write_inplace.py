@@ -176,6 +176,45 @@ def test_paged_kernel_compiles_at_plain_llama_shapes(qw, one_chip,
     assert "tnn_paged_attention" in text
 
 
+# (what, (B, H, Sq, Dh), Skv, causal): the calls ``flash_attention`` gets
+_FLASH_CALLS = [
+    ("the training cell", (8, 16, 1024, 64), 1024, True),   # 4 x 4 sub-tiles
+    ("a grid of blocks", (2, 16, 4096, 64), 4096, True),    # whole blocks
+    ("a padded sequence", (2, 12, 1100, 64), 1100, True),
+    ("an encoder", (8, 12, 197, 64), 197, False),           # ViT, not causal
+    ("a cached prompt", (2, 12, 512, 64), 1024, True),      # a traced offset
+]
+
+
+@pytest.mark.parametrize("what,shape,skv,causal", _FLASH_CALLS,
+                         ids=[c[0].replace(" ", "_") for c in _FLASH_CALLS])
+def test_flash_kernels_compile_for_the_chip(what, shape, skv, causal,
+                                            one_chip, no_compile_cache,
+                                            alarm):
+    """The forward and the fused backward as the chip's compiler gets them:
+    the statically unrolled sub-tile walk of a one-block call, and the
+    whole-block forms under ``pl.when`` of every other (the interpreter on
+    the CPU accepts slices and concatenations that Mosaic may refuse)."""
+    from tnn_tpu.ops.pallas.flash_attention import flash_attention
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, sq, d = shape
+    offset = (spec((), jnp.int32),) if sq != skv else ()
+
+    def loss(q, k, v, *off):
+        return flash_attention(q, k, v, causal=causal,
+                               kv_offset=off[0] if off else None).astype(
+                                   jnp.float32).sum()
+
+    with mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            spec(shape, jnp.bfloat16), spec((b, h, skv, d), jnp.bfloat16),
+            spec((b, h, skv, d), jnp.bfloat16), *offset).compile().as_text()
+    assert "tnn_flash_fwd" in text and "tnn_flash_bwd_fused" in text
+
+
 # -- the windowed model's step (PR 28): the same question at its widths -------
 
 
