@@ -19,7 +19,9 @@ comes out by the repo's own means. Phases, each printing its name and result:
              on and off, -1-holed tables. Max abs error per row of the table.
              Then the other kernels at their cells' widths against their XLA
              forms, among them the flash kernels at the training cell's call
-             (forward, and forward + backward) with their sub-tile plan.
+             (forward, and forward + backward) with their sub-tile plan, and
+             the KV row write at the six serving configurations' pages
+             against the whole-page form, bit for bit, in a donated pool.
   serve      nine token-id requests (prompts of 5..700 tokens, two sharing a
              96-token prefix) through ``tnn-serve --model gpt2_small
              --num-blocks 512 --block-size 16 --max-batch-size 8``, every
@@ -118,6 +120,16 @@ CHIP = dict(
     # the flash kernels at gpt2-medium.train's call: batch 8, 16 heads of
     # 64 over 1,024 causal positions
     flash=dict(batch=8, heads=16, seq=1024, head_dim=64, iters=10),
+    # the row write at the six serving configurations' pages, (page rows of
+    # a position, positions a page, lanes a row): a step's rows and one
+    # prompt chunk beside them, into a donated pool of 2 layers
+    row_write=dict(pages={"gpt2-large": (10, 16, 128),
+                          "evabyte": (32, 128, 128),
+                          "trinity-large": (8, 128, 128),
+                          "qwen3-next": (2, 128, 256),
+                          "mistral-small4": (1, 128, 384),
+                          "longcat-flash": (1, 128, 640)},
+                   batch=16, table=3, chunk=64, iters=20),
     # the sampler at the two served vocabularies: GPT-2 large's 16 rows, the
     # 32 rows of Mistral Small 4's slice
     sampler=dict(shapes=((16, 50257), (32, 32768)), iters=50),
@@ -142,6 +154,8 @@ REHEARSAL = dict(
     heads256=dict(blocks=16, block_size=8, heads=4, kv_heads=2, head_dim=32,
                   batch=3, table=6, chunk=8),
     flash=dict(batch=1, heads=2, seq=64, head_dim=32, iters=1),
+    row_write=dict(pages={"packed": (2, 16, 128), "latent": (1, 32, 256)},
+                   batch=3, table=3, chunk=64, iters=1),
     sampler=dict(shapes=((4, 320),), iters=2),
     train_model="mnist_cnn", train_batch=8, train_classes=10,
     degree=2, mesh_steps=2)
@@ -286,7 +300,8 @@ def phase_kernel(cfg) -> list:
                             f"holes={tname}: error above {tol}")
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
         + _window_kernel(cfg, rand, rng) + _state_kernels(cfg, rand, rng) \
-        + _short_rows(cfg, rand, rng) + _flash_training_call(cfg, rand) \
+        + _short_rows(cfg, rand, rng) + _row_writes(cfg, rand, rng) \
+        + _flash_training_call(cfg, rand) \
         + _sampler_steps(cfg, rng)
 
 
@@ -411,6 +426,74 @@ def _short_rows(cfg, rand, rng) -> list:
                 f"form's by {err:.2e} (tol {tol:.0e}), dead positions read "
                 f"{dead:.2e}"]
     return []
+
+
+def _row_writes(cfg, rand, rng) -> list:
+    """``tnn_kv_row_write`` against the whole-page form at each serving
+    configuration's page: a decode step's rows, and a mixed step's (one
+    prompt chunk from mid-tile across a page's edge, a row of one position,
+    an absent row, the others decoding), bit for bit on every page but the
+    scratch page; the pool donated through jit keeps its buffer; and the
+    time of one write in each form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas import paged_attention as pa
+
+    k = cfg["row_write"]
+    B, chunk = k["batch"], k["chunk"]
+    kernel = functools.partial(pa._write_rows_pallas,
+                               interpret=cfg["rehearse"])
+    failures = []
+    for name, (hp, bs, width) in k["pages"].items():
+        nb = max(k["table"], (chunk + bs) // bs + 1)    # a chunk fits a table
+        shape = (2, 1 + B * nb, hp, bs, width)
+        tables = jnp.asarray(1 + rng.permutation(B * nb).reshape(B, nb),
+                             jnp.int32)
+        for form, qw in (("decode", 1), (f"mixed{chunk}", chunk)):
+            starts = rng.integers(0, nb * bs - qw, B).astype(np.int32)
+            q_lens = np.ones(B, np.int32)
+            if qw > 1:      # the chunk leaves its page in mid-tile
+                starts[0], q_lens[0] = bs - 5, qw
+                q_lens[1] = 0
+            rows = rand((B, qw, hp, width))
+            args = (tables, jnp.asarray(starts), rows, jnp.asarray(q_lens),
+                    jnp.asarray(1, jnp.int32))
+            took, out = {}, {}
+            fresh = rand(shape)
+            before = np.asarray(fresh, np.float32)
+            for label, write in (("pages", pa._write_rows_xla),
+                                 ("kernel", kernel)):
+                step = jax.jit(write, donate_argnums=(0,))
+                pool = fresh + 0
+                at = pool.unsafe_buffer_pointer()
+                pool = step(pool, *args)                    # compiles
+                kept = pool.unsafe_buffer_pointer() == at
+                out[label] = np.asarray(pool, np.float32)
+                t0 = time.perf_counter()
+                for _ in range(k["iters"]):
+                    pool = step(pool, *args)
+                jax.block_until_ready(pool)
+                took[label] = (time.perf_counter() - t0) / k["iters"] * 1e3
+                del pool
+            same = np.array_equal(out["kernel"][:, 1:], out["pages"][:, 1:])
+            wrote = int(np.any(out["kernel"][1] != before[1],
+                               axis=(1, 3)).sum())
+            want = int(q_lens.sum())
+            ok = same and wrote == want and (kept or cfg["rehearse"]) \
+                and np.array_equal(out["kernel"][0], before[0])
+            log(f"row write {name:15s} {form:8s} page {hp} x {bs} x {width}: "
+                f"{wrote} positions written (of {want}), "
+                f"{'equal to' if same else 'DIFFERS from'} the page form, "
+                f"donated buffer {'kept' if kept else 'NOT kept'}; "
+                f"{took['kernel']:.3f} ms a write against "
+                f"{took['pages']:.3f} ms in whole pages "
+                f"{'ok' if ok else 'FAIL'}")
+            if not ok:
+                failures.append(f"kernel row write {name}/{form}: not the "
+                                "page form's bits, or the pool was copied")
+    return failures
 
 
 def _flash_training_call(cfg, rand) -> list:

@@ -6,6 +6,7 @@ held against ``chipbench/reference/evabyte.py``: the same module the
 benchmark compares with, which imports nothing of the program."""
 import contextlib
 import io
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from chipbench.reference import evabyte as ref
 from tnn_tpu import models
 from tnn_tpu.core.dtypes import DTypePolicy
 from tnn_tpu.ops.pallas import eva_attention as eva
+from tnn_tpu.ops.pallas import paged_attention as pa
 from tnn_tpu.serving import InferenceEngine
 from tnn_tpu.serving.engine import refuse_windowed
 from tnn_tpu.serving.kv_pool import PagedKVPool
@@ -565,13 +567,29 @@ def test_scopes_and_kernel_name_are_in_the_compiled_step(model, weights):
     and ``eva_summarise`` in op paths, the kernel by ``tnn_eva_attention``."""
     pool = PagedKVPool(2, 4, 16, 16, 8, dtype=jnp.float32, window=W, chunk=C)
     tables = jnp.zeros((2, pool.table_width(64)), jnp.int32)
-    fn = jax.jit(model.apply_decode_paged)
-    text = fn.lower(weights[1], jnp.zeros((2,), jnp.int32), pool.pages_k,
-                    pool.pages_v, tables, jnp.asarray([3, 40])
-                    ).as_text(debug_info=True)
+    def lowered():      # a new function a call: no trace is found again
+        return jax.jit(lambda *a: model.apply_decode_paged(*a)).lower(
+            weights[1], jnp.zeros((2,), jnp.int32), pool.pages_k,
+            pool.pages_v, tables, jnp.asarray([3, 40])
+            ).as_text(debug_info=True)
+
+    text = lowered()
     for scope in ("h0/eva_attn", "h1/eva_summarise", "kv_write", "attn_qkv",
                   "mlp", "lm_head", "embed", "ln_f"):
         assert scope in text, scope
+    assert "tnn_kv_row_write" not in text       # off the chip: whole pages
+    # on the chip both writes are the row-tile kernel, each under its scope
+    # (the tiny pages' rows do not fill the 128 lanes the kernel asks for
+    # there: the choice is made for it, the names are what is held)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.object(pa, "_kernel_writes", lambda pages: True), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "1"}):
+        text = lowered()
+    for i in range(2):
+        for scope in ("kv_write", "eva_summarise"):
+            assert f"h{i}/{scope}/jit(_write_rows_pallas)" in text, (i, scope)
+    assert '"tnn_kv_row_write/' in text
+    assert "eva_attn/tnn_eva_attention/" in text
     call = str(jax.make_jaxpr(lambda *a: eva.eva_attention(
         *a, n_exact=4, backend="pallas", interpret=False))(
         jnp.zeros((2, 4, 128), jnp.bfloat16),

@@ -1,16 +1,22 @@
 """The paged KV write stays in place: what the compiled step programs hold,
-and the engine invariant the whole-page write relies on.
+and the engine invariant the page write relies on.
 
 ``scatter_kv_rows`` / ``scatter_kv_chunk`` (ops/pallas/paged_attention.py)
-rewrite whole pages of the donated pool in the layout the paged kernel reads.
-Two things can silently undo that, and neither shows on the CPU:
+rewrite the sublane tiles of the donated pool that hold a step's rows
+(``tnn_kv_row_write``; whole pages off the chip and for rows that do not
+fill the lanes: GPT-2's unpacked int8 pool, a scale sidecar), in the
+layout the paged kernel reads. Three things can silently undo that, and none
+shows on the CPU:
 
 * a write whose lowering makes the TPU compiler re-lay the WHOLE pool out
   around every layer's scatter (it did: 72 pool copies a decode step, 81% of
   the step). ``test_step_program_has_no_pool_copy_per_layer`` AOT-compiles
   the step programs for the compile-only v5e target and counts them.
+* a write that falls back to moving whole pages: a gather, a select and a
+  scatter of ``bs`` rows for one. ``_assert_row_writes`` holds every bf16
+  program compiled here to the kernel, aliased in and out, inside its scope.
 * a step that writes one non-scratch page from two rows, or a page someone
-  else still reads: the whole-page write would then lose a row. The engine
+  else still reads: the write of a tile or a page would then lose a row. The engine
   never builds such a step (``PagedKVPool.check_step_writes``); the tests at
   the bottom drive it through the copy-on-write case and check every step.
 
@@ -83,6 +89,69 @@ def no_compile_cache():
     yield
 
 
+# bytes of temporaries of each step program compiled here as PR 45 left it,
+# with the whole-page write (``compiled.memory_analysis()``): the row write
+# may not grow them. (It shrinks all but two, the windowed decode step from
+# 19.4 MB to 1.5 MB; the shortcut and the two-group prompt steps lay a
+# chunk's rows out by tile and take 66 KB and 130 KB more, under a
+# thousandth, which is the room given.)
+_TEMP_BYTES = {
+    "gpt2-decode-bf16-2": 132_038_656,
+    "gpt2-decode-bf16-4": 134_554_624,
+    "gpt2-decode-int8-2": 970_429_440,
+    "gpt2-decode-int8-4": 2_151_540_224,
+    "gpt2-chunk64-bf16-2": 131_812_864,
+    "gpt2-chunk64-bf16-4": 136_019_968,
+    "gpt2-chunk64-int8-2": 975_361_536,
+    "gpt2-chunk64-int8-4": 2_160_438_784,
+    "windowed-decode": 19_357_696,
+    "windowed-chunk256": 112_599_552,
+    "latent-decode": 5_967_360,
+    "latent-chunk64": 149_759_488,
+    "shortcut-decode": 25_439_232,
+    "shortcut-chunk32": 446_145_536,
+    "two-group-decode": 9_225_728,
+    "two-group-chunk64": 147_852_800,
+    "state-decode": 12_902_400,
+    "state-chunk32": 208_057_344,
+}
+
+
+def _compiled_text(lowered, program):
+    """``lowered`` compiled for the described chip: its text, once its
+    temporaries are held to what the program took with the page write."""
+    compiled = lowered.compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.001 * _TEMP_BYTES[program], (program, temp)
+    return compiled.as_text()
+
+
+def _assert_row_writes(text, shape, calls, arrays, scope="kv_write",
+                       reads_pages=False):
+    """The compiled program ``text`` makes ``calls`` row writes under
+    ``scope``, each ``tnn_kv_row_write`` over the pool of ``shape`` aliased
+    in and out; nothing else under that scope holds pages (no gather, select
+    or scatter of ``(.., H / p, bs, p * Dh)``; not asked of a scope that
+    ``reads_pages``: EVA's read-back of a chunk's rows); and the program's
+    ``arrays`` pool arguments are donated through."""
+    lines = [x for x in text.splitlines() if f"/{scope}/" in x]
+    dims = ",".join(map(str, shape))
+    # the write is one jitted function, inlined under each site's scope
+    writes = [x for x in lines if "custom-call(" in x
+              and f"/{scope}/jit(_write_rows_pallas)/tnn_kv_row_write/" in x]
+    assert len(writes) == calls, (len(writes), calls)
+    for x in writes:
+        assert re.search(r"= \w+\[%s\]" % dims, x), x[:200]
+        assert "output_to_operand_aliasing={{}: (3, {})}" in x, x[:400]
+    page = re.compile(r"= \w+\[[\d,]*%s\]" % ",".join(map(str, shape[2:])))
+    moved = [x.strip()[:200] for x in lines
+             if "custom-call(" not in x and page.search(x)]
+    assert reads_pages or not moved, moved
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, may-alias\)", header)) >= arrays, \
+        header[:300]
+
+
 def _pool_copies(one_chip, form, dtype, num_layers):
     from tnn_tpu.models.gpt2 import GPT2
 
@@ -118,7 +187,7 @@ def _pool_copies(one_chip, form, dtype, num_layers):
             lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
                 params, spec((_ROWS, 64), jnp.int32), pages, pages, tables,
                 lens, lens)
-        text = lowered.compile().as_text()
+        text = _compiled_text(lowered, f"gpt2-{form}-{dtype}-{num_layers}")
     assert "tnn_paged_attention" in text, "the Pallas kernel is not in it"
     dims = ",".join(map(str, shape))
     pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
@@ -126,6 +195,11 @@ def _pool_copies(one_chip, form, dtype, num_layers):
     # module's first line, ``entry_computation_layout={(args)->(results)}``
     layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
                          text.split("\n", 1)[0].split(")->(")[0])
+    # K and V a layer, the kernel; an int8 pool of heads of 64, unpacked: none
+    if dtype == "bf16":
+        _assert_row_writes(text, shape, 2 * num_layers, 2)
+    else:
+        assert "tnn_kv_row_write" not in text
     return [line.strip()[:160] for line in text.splitlines()
             if pool.search(line)], layouts
 
@@ -137,7 +211,9 @@ def test_step_program_has_no_pool_copy_per_layer(form, dtype, one_chip,
     """Pool-shaped ``copy`` instructions in the step program compiled for the
     v5e: as many at 4 layers as at 2 (none belongs to a layer), and no more
     than the program's entry and exit cost: NONE for the bf16 pool, whose
-    arguments rest in the layout the kernel reads. (Of an int8 pool this
+    arguments rest in the layout the kernel reads, and whose every write is
+    ``tnn_kv_row_write`` inside ``kv_write``, aliased in and out, with no
+    page gathered beside it (``_assert_row_writes``). (Of an int8 pool this
     counts the int8 data array; its f32 scale sidecar has another shape and
     its own copies: PERF.md section 7.)"""
     two, _ = _pool_copies(one_chip, form, dtype, 2)
@@ -215,6 +291,180 @@ def test_flash_kernels_compile_for_the_chip(what, shape, skv, causal,
     assert "tnn_flash_fwd" in text and "tnn_flash_bwd_fused" in text
 
 
+# -- the row write where no cell runs it: a wide batch, and across chips -----
+
+
+@pytest.mark.parametrize("page,batch", [((32, 128, 128), 128),
+                                        ((10, 16, 128), 512)],
+                         ids=["evabyte_x128", "gpt2_large_x512"])
+def test_row_write_compiles_at_a_wide_decode_batch(page, batch, one_chip,
+                                                   no_compile_cache, alarm):
+    """``--max-batch-size`` has no cap, and a decode step's new rows, ONE
+    sublane each, pad to a tile's 16 in VMEM: whole there these two would
+    take 16 and 20 MiB and the chip's compiler refuses them. Past half the
+    write's budget the rows come by DMA beside their tiles instead
+    (``_write_rows_pallas``), and the program compiles."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    shape = (2, 2 * batch) + page
+    assert batch * page[0] * pa._tile_bytes(1, page[2], jnp.bfloat16) \
+        > pa._WRITE_VMEM
+
+    def write(pages, tables, starts, rows):
+        return pa.scatter_kv_rows(pages, tables, starts, rows, layer=1)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        text = jax.jit(write, donate_argnums=(0,)).lower(
+            spec(shape, jnp.bfloat16), spec((batch, 8), jnp.int32),
+            spec((batch,), jnp.int32),
+            spec((batch, page[0], page[2]), jnp.bfloat16)).compile().as_text()
+    # GPT-2's page of 16 rows is one tile: the rows laid out by tile have a
+    # page's shape there, and are not one
+    _assert_row_writes(text, shape, 1, 1, reads_pages=page[1] == 16)
+
+
+@pytest.mark.parametrize("qw", [1, 64])
+@pytest.mark.parametrize("dtype,bs", [("int8", 32), ("int8", 128),
+                                      ("float32", 16), ("float32", 128)])
+def test_row_write_compiles_at_every_tile_height(dtype, bs, qw, one_chip,
+                                                 no_compile_cache, alarm):
+    """The tile follows the dtype (16 rows of bf16 in every program above):
+    a float32 pool's is 8 rows, and an int8 pool's data, where its rows fill
+    the lanes, goes through the kernel at 32 while its scale sidecar, one
+    lane wide, keeps the whole-page form (``_kernel_writes`` asks each
+    array). Mosaic takes both, aliased, with no copy of the pool's data."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    shape = (2, 64, 8, bs, 128)
+    if dtype == "int8":
+        pages = pa.QuantPages(spec(shape, jnp.int8),
+                              spec(shape[:-1] + (1,), jnp.float32))
+        rows = spec((8, qw, 8, 128), jnp.bfloat16)
+    else:
+        pages, rows = spec(shape, jnp.float32), spec((8, qw, 8, 128),
+                                                     jnp.float32)
+
+    def write(pages, tables, starts, rows, q_lens):
+        return pa.scatter_kv_chunk(pages, tables, starts, rows, q_lens,
+                                   layer=1)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        text = jax.jit(write, donate_argnums=(0,)).lower(
+            pages, spec((8, 8), jnp.int32), spec((8,), jnp.int32), rows,
+            spec((8,), jnp.int32)).compile().as_text()
+    _assert_row_writes(text, shape, 1, 2 if dtype == "int8" else 1,
+                       reads_pages=dtype == "int8")
+    # (the scale sidecar has its own copies, as before: PERF.md section 7)
+    pool = re.compile(r"= \w+\[2,64,8,%d,128\]\{[^}]*\} copy\(" % bs)
+    assert not [x.strip()[:160] for x in text.splitlines() if pool.search(x)]
+
+
+def _mesh_program(kind, form, heads):
+    """A GPT-2 of ``heads`` heads of 64 at 2 layers, the step
+    ``serving/tp.py`` / ``serving/sp.py`` run under ``shard_map``, compiled
+    for the described 2x2 host: (the program's text, ONE shard's pool
+    shape). A tensor-parallel shard holds a quarter of the heads, packed by
+    what IT holds (``PagedKVPool.lane_pack``); a sequence-parallel shard a
+    quarter of the blocks."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tnn_tpu.models.gpt2 import GPT2
+    from tnn_tpu.parallel import mesh as mesh_lib
+    from tnn_tpu.serving import sp as sp_lib, tp as tp_lib
+
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    head_dim, rows, blocks = 64, 8, 256
+    model = GPT2(vocab_size=50257, max_len=1024, num_layers=2,
+                 d_model=heads * head_dim, num_heads=heads)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"])
+    if kind == "tp":
+        mesh = mesh_lib.make_mesh(model=4, devices=devices)
+        sharded, page_spec = tp_lib.TPModel(model, 4), tp_lib.PAGE_SPEC
+        param_specs = jax.tree_util.tree_map_with_path(tp_lib._spec_for,
+                                                       params)
+        p = pa.lane_pack(heads // 4, head_dim, jnp.bfloat16)
+    else:
+        mesh = mesh_lib.make_mesh(seq=4, devices=devices)
+        sharded, page_spec = sp_lib.SPModel(model, 4), sp_lib.PAGE_SPEC
+        param_specs = jax.tree_util.tree_map(lambda _: P(), params)
+        p = pa.lane_pack(heads, head_dim, jnp.bfloat16)
+    shape = (2, blocks, heads // p, _BLOCK, p * head_dim)
+
+    def spec(shape, dt, at=P()):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, at))
+
+    args = [jax.tree_util.tree_map(
+                lambda x, at: spec(x.shape, x.dtype, at), params, param_specs),
+            spec((rows,) if form == "decode" else (rows, 64), jnp.int32),
+            spec(shape, jnp.bfloat16, page_spec),
+            spec(shape, jnp.bfloat16, page_spec),
+            spec((rows, 64), jnp.int32), spec((rows,), jnp.int32)]
+    fn = sharded.apply_decode_paged
+    if form != "decode":
+        fn, args = sharded.apply_paged, args + [spec((rows,), jnp.int32)]
+    in_specs = (param_specs, P(), page_spec, page_spec) + (P(),) * (
+        len(args) - 4)
+    body = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=(P(), page_spec, page_spec),
+                         check_vma=False)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
+        text = jax.jit(body, donate_argnums=(2, 3)).lower(
+            *args).compile().as_text()
+    return text, NamedSharding(mesh, page_spec).shard_shape(shape)
+
+
+_COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|all-to-all|"
+                         r"collective-permute|reduce-scatter)")
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk64"])
+@pytest.mark.parametrize("kind", ["tp", "sp"])
+def test_sharded_step_writes_rows_in_place_on_every_chip(
+        kind, form, no_compile_cache, alarm):
+    """Under ``shard_map`` the kernel sees ONE shard's pages (16 heads: 4 a
+    tensor-parallel shard, two packed rows of 128 lanes): every layer's K
+    and V write of the tensor- and the sequence-parallel step is
+    ``tnn_kv_row_write`` over the shard's own pool, aliased in and out, with
+    no page gathered beside it and no pool copy; and no collective stands
+    inside ``kv_write`` (the steps' own are the attention's and the MLP's
+    sums, elsewhere)."""
+    text, local = _mesh_program(kind, form, 16)
+    assert local[-1] == 128
+    _assert_row_writes(text, local, 4, 2)
+    pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\("
+                      % ",".join(map(str, local)))
+    assert not [x.strip()[:160] for x in text.splitlines() if pool.search(x)]
+    assert _COLLECTIVE.search(text), "a sharded step with no collective"
+    assert not [x.strip()[:160] for x in text.splitlines()
+                if "/kv_write/" in x and _COLLECTIVE.search(x)]
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk64"])
+def test_shard_of_half_filled_lanes_keeps_the_page_form(
+        form, no_compile_cache, alarm):
+    """gpt2_small over four chips (``chip_smoke.py``'s four-chip phase): a
+    shard's 3 heads of 64 cannot pack, its page rows fill half the lanes,
+    and Mosaic refuses a tile of them ("slice shape along dimension 4 must
+    be aligned to tiling (128), but is 64"). Such a pool keeps the
+    whole-page write, chosen on the pages' shape, and the step compiles."""
+    text, local = _mesh_program("tp", form, 12)
+    assert local[2:] == (3, _BLOCK, 64)
+    assert "tnn_kv_row_write" not in text and "/kv_write/" in text
+    assert "tnn_paged_attention" in text
+
+
 # -- the windowed model's step (PR 28): the same question at its widths -------
 
 
@@ -245,7 +495,7 @@ def _eva_program(one_chip, form, num_layers, blocks=64):
             lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
                 params, spec((8, 256), jnp.int32), pages, pages, tables,
                 lens, lens)
-        return lowered.compile().as_text(), shape
+        return _compiled_text(lowered, f"windowed-{form}"), shape
 
 
 @pytest.mark.parametrize("form", ["decode", "chunk256"])
@@ -261,6 +511,10 @@ def test_windowed_step_compiles_for_the_chip_with_no_pool_copy(
     pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\("
                       % ",".join(map(str, shape)))
     assert not [line for line in text.splitlines() if pool.search(line)]
+    # the exact rows and the summaries, K and V, each of the 2 layers
+    _assert_row_writes(text, shape, 4, 2)
+    _assert_row_writes(text, shape, 4, 2, scope="eva_summarise",
+                       reads_pages=True)
 
 
 # -- the latent model's step (PR 32): the same question at its widths ---------
@@ -303,7 +557,7 @@ def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
             lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
                 params, spec((32, 64), jnp.int32), pages, stub, tables, lens,
                 lens)
-        text = lowered.compile().as_text()
+        text = _compiled_text(lowered, f"latent-{form}")
     assert "tnn_mla_attention" in text and "tnn_expert_gmm" in text
     assert "tnn_paged_attention" not in text
     dims = ",".join(map(str, shape))
@@ -312,6 +566,7 @@ def test_latent_step_compiles_for_the_chip_with_no_pool_copy(
     layouts = re.findall(r"\w+\[%s\](\{[^}]*\})" % dims,
                          text.split("\n", 1)[0].split(")->(")[0])
     assert layouts and all(x.startswith(_KERNEL_LAYOUT) for x in layouts)
+    _assert_row_writes(text, shape, 2, 1)      # ONE array, a write a layer
 
 
 # -- the shortcut block's step (PR 41): two cache layers a block --------------------
@@ -360,11 +615,12 @@ def test_shortcut_step_compiles_for_the_chip_with_no_pool_copy(
             lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
                 params, spec((64, 32), jnp.int32), pages, stub, tables, lens,
                 lens)
-        text = lowered.compile().as_text()
+        text = _compiled_text(lowered, f"shortcut-{form}")
     assert "tnn_mla_attention" in text and "tnn_expert_gmm" in text
     assert "tnn_paged_attention" not in text
     assert ("bf16[3584,6144]" in text) == (form == "chunk32") \
         and "bf16[25088,6144]" not in text
+    _assert_row_writes(text, shape, 2, 1)      # a write a cache layer
     dims = ",".join(map(str, shape))
     pool = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
     assert not [line for line in text.splitlines() if pool.search(line)]
@@ -416,11 +672,13 @@ def test_two_group_step_compiles_for_the_chip_with_no_pool_copy(
             lowered = jax.jit(model.apply_paged, donate_argnums=(2, 3)).lower(
                 params, spec((32, 64), jnp.int32), pages, pages, tables, lens,
                 lens)
-        text = lowered.compile().as_text()
+        text = _compiled_text(lowered, f"two-group-{form}")
     names = set(re.findall(r"tnn_[a-z_]+[a-z]", text))
     assert {"tnn_paged_attention", "tnn_paged_attention_win",
             "tnn_expert_gmm"} <= names
     assert "tnn_mla_attention" not in names
+    # five layers, K and V, into the ONE layer of pages both groups share
+    _assert_row_writes(text, shape, 10, 2)
     dims = ",".join(map(str, shape))
     pool_copy = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
     assert not [line for line in text.splitlines() if pool_copy.search(line)]
@@ -592,7 +850,7 @@ def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
                 donate_argnums=(2, 3, 7)).lower(
                 params, spec((rows, 32), jnp.int32), pages, pages, tables,
                 lens, lens, state)
-        text = lowered.compile().as_text()
+        text = _compiled_text(lowered, f"state-{form}")
     names = set(re.findall(r"tnn_[a-z_]+[a-z]", text))
     assert {"tnn_paged_attention", "tnn_expert_gmm"} <= names
     assert ("tnn_gdn_step" in names) == (form == "decode")
@@ -600,3 +858,46 @@ def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
         dims = ",".join(map(str, shape))
         copy = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)
         assert not [ln for ln in text.splitlines() if copy.search(ln)], dims
+
+
+# -- the engine's tokens, whichever form writes ---------------------------------
+
+
+@pytest.mark.parametrize("name", ["gpt2_tiny", "evabyte_tiny",
+                                  "mistral_small4_tiny", "trinity_large_tiny",
+                                  "longcat_flash_tiny", "qwen3_next_tiny"])
+def test_served_tokens_do_not_depend_on_the_write_form(name):
+    """Each kind of pool the serving path has (packed K/V pages; window and
+    summary pages; one array of latent rows, in one and in two cache layers
+    a block; two page groups in one layer; pages beside state slots) served
+    twice through the engine, prompts in chunks and then decode steps: with
+    every write ``tnn_kv_row_write`` (interpreted here, the choice made for
+    it: these pages are narrower than the chip's lanes) the tokens are those
+    of the whole-page form."""
+    from tnn_tpu import models
+
+    model = models.create(name)
+    params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, n).astype(np.int32) for n in (37, 16, 50)]
+
+    def serve(kernel):
+        asked = []
+
+        def form(pages):
+            asked.append(pages.shape)
+            return kernel
+
+        with mock.patch.object(pa, "_kernel_writes", form):
+            eng = InferenceEngine(model, params, num_blocks=96, block_size=8,
+                                  max_batch_size=4, chunk_size=16,
+                                  prefix_cache=False, max_seq_len=192)
+            rids = [eng.submit(p, 40) for p in prompts]
+            out = eng.run_until_complete()
+            eng.check_invariants()
+        return [out[r] for r in rids], asked
+
+    want, _ = serve(False)
+    got, asked = serve(True)
+    assert asked, "no write asked for its form"
+    assert got == want and all(len(t) == 40 for t in got)

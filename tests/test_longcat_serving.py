@@ -10,6 +10,7 @@ compares with, which imports nothing of the program and writes the EXPANDED
 form of the attention."""
 import contextlib
 import io
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -509,10 +510,23 @@ def test_the_scopes_split_a_block_into_its_parts(model, weights):
     computed) and the shortcut's add under ``a1/moe_shortcut``."""
     pool = PagedKVPool(4, 1, model.latent_row, 16, 8, dtype=jnp.float32,
                        latent=True)
-    text = jax.jit(model.apply_decode_paged).lower(
-        weights[1], jnp.zeros((2,), jnp.int32), pool.pages_k, pool.pages_v,
-        jnp.zeros((2, 8), jnp.int32), jnp.zeros((2,), jnp.int32)) \
-        .as_text(debug_info=True)
+    def lowered():      # a new function a call: no trace is found again
+        return jax.jit(lambda *a: model.apply_decode_paged(*a)).lower(
+            weights[1], jnp.zeros((2,), jnp.int32), pool.pages_k,
+            pool.pages_v, jnp.zeros((2, 8), jnp.int32),
+            jnp.zeros((2,), jnp.int32)).as_text(debug_info=True)
+
+    # on the chip a half's write is the row-tile kernel, under its scope
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "1"}):
+        text = lowered()
+    for i in range(2):
+        for half in ("a0", "a1"):
+            assert f"h{i}/{half}/kv_write/jit(_write_rows_pallas)" in text
+            assert f"h{i}/{half}/mla_attn/tnn_mla_attention/" in text
+    assert '"tnn_kv_row_write/' in text
+    text = lowered()
+    assert "tnn_kv_row_write" not in text       # off the chip: whole pages
     for i in range(2):
         for half in ("a0", "a1"):
             for scope in ("attn_qkv", "mla_attn", "kv_write", "attn_out",

@@ -10,7 +10,9 @@ heads side by side in a page row, ``pa.lane_pack``) are cases of the same
 tests: the pool is made unpacked, ``_pack`` lays it out as ``PagedKVPool``
 would, and every oracle reads the unpacked one.
 """
+import functools
 import math
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -560,6 +562,368 @@ def test_page_write_matches_per_row_scatter(case, form):
     _assert_same_but_scratch(got, want)
     if case == "all_scratch":   # and nothing but the scratch page changed
         _assert_same_but_scratch(got, pages)
+
+
+# -- the row write: a row's sublane tile through one aliased pallas_call ------
+#
+# ``tnn_kv_row_write`` (what ``write_rows`` is on the chip: ``_kernel_writes``,
+# here answered for it and the kernel interpreted) against the whole-page form,
+# which the cases above hold to the per-row scatter: the same bits on every
+# non-scratch page.
+
+
+def _write_as(kernel, *args, write=pa.write_rows, **kw):
+    """``write`` with the form chosen for it: ``tnn_kv_row_write`` for every
+    array (``kernel``), or whole pages."""
+    with mock.patch.object(pa, "_kernel_writes", lambda pages: kernel):
+        return write(*args, **kw)
+
+
+def _row_tile_case(seed, *, qw, dtype=jnp.bfloat16, bs=32, heads=2, width=128,
+                   pack=1, batch=4, holes=False, past_table=False,
+                   short=None):
+    """A pool of pages of ``bs`` rows (two tiles of 16 bf16 rows, four of 8
+    float32), one-writer tables, and a ragged chunk a row: row 0 absent
+    (``q_lens`` 0), row 1 a full chunk from a page's last row (a start in
+    mid-tile, and the most tiles and pages a chunk can cross), row 2 a short
+    one (``short`` positions, a third of the chunk unless given) from
+    mid-tile, the last a full chunk from a random place. ``holes`` stamps -1
+    over every other table entry; ``past_table`` starts row 1 so that its
+    chunk runs past the table's last entry."""
+    rng = np.random.default_rng(seed)
+    per_row = -(-(qw + bs) // bs) + 1
+    num_blocks = 1 + batch * per_row
+    shape = (2, num_blocks, heads // pack, bs, pack * width)
+    pages = jnp.asarray(rng.normal(size=shape), dtype)
+    tables = rng.permutation(np.arange(1, num_blocks)).reshape(
+        batch, per_row).astype(np.int32)
+    starts = rng.integers(0, per_row * bs - qw + 1, size=batch).astype(
+        np.int32)
+    q_lens = np.full(batch, qw, np.int32)
+    q_lens[0] = 0
+    starts[1] = per_row * bs - qw // 2 - 1 if past_table else bs - 1
+    starts[2], q_lens[2] = bs + 5, short or max(1, qw // 3)
+    if holes:
+        tables[:, 1::2] = -1
+    rows = jnp.asarray(rng.normal(size=(batch, qw, heads, width)), dtype)
+    return (pages, jnp.asarray(tables), jnp.asarray(starts), rows,
+            jnp.asarray(q_lens))
+
+
+_ROW_TILE_CASES = {
+    "decode": dict(qw=1), "chunk16": dict(qw=16), "chunk64": dict(qw=64),
+    "chunk256": dict(qw=256), "chunk5": dict(qw=5),
+    # a ragged chunk of 0, 1 and Q live positions
+    "ragged_0_1_q": dict(qw=64, short=1),
+    "f32_decode": dict(qw=1, dtype=jnp.float32),
+    "f32_chunk16": dict(qw=16, dtype=jnp.float32),
+    # two heads of 64 in one page row of 128 (``pa.lane_pack``)
+    "packed_decode": dict(qw=1, width=64, pack=2),
+    "packed_chunk64": dict(qw=64, width=64, pack=2),
+    "packed_bs16_decode": dict(qw=1, width=64, pack=2, bs=16, heads=4),
+    "packed_bs16_chunk64": dict(qw=64, width=64, pack=2, bs=16, heads=4),
+    "packed_bs128_decode": dict(qw=1, width=64, pack=2, bs=128),
+    "packed_bs128_chunk64": dict(qw=64, width=64, pack=2, bs=128),
+    "one_layer_decode": dict(qw=1), "one_layer_chunk16": dict(qw=16),
+    "traced_layer": dict(qw=16),
+    # the latent pool's ONE head of [c_kv | k_rope | 0] rows
+    "latent384_decode": dict(qw=1, heads=1, width=384),
+    "latent384_chunk64": dict(qw=64, heads=1, width=384),
+    "latent640_decode": dict(qw=1, heads=1, width=640),
+    "latent640_chunk16": dict(qw=16, heads=1, width=640),
+    "holes_decode": dict(qw=1, holes=True),
+    "holes_chunk64": dict(qw=64, holes=True),
+    "past_table": dict(qw=64, past_table=True),
+    # an int8 pool: the data at tiles of 32 rows, the float32 scale sidecar
+    # (one lane) at tiles of 8
+    "int8_decode": dict(qw=1, dtype=jnp.float32, bs=64),
+    "int8_chunk64": dict(qw=64, dtype=jnp.float32, bs=64),
+    "int8_holes": dict(qw=16, dtype=jnp.float32, bs=64, holes=True),
+    "int8_one_layer": dict(qw=16, dtype=jnp.float32, bs=64),
+    # a page that IS one tile (GPT-2's 16 rows), and a page of no whole
+    # tiles, which moves whole
+    "page_is_tile": dict(qw=16, bs=16), "page_is_tile_decode": dict(qw=1,
+                                                                    bs=16),
+    "small_page": dict(qw=5, bs=4),
+    # a decode batch whose new rows are too many to sit whole in VMEM (a
+    # row's ONE sublane pads to 16): they come by DMA, a row beside its tile
+    "wide_batch_decode": dict(qw=1, bs=16, batch=400),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_TILE_CASES))
+def test_row_write_kernel_matches_page_form(case):
+    """Bit for bit on every non-scratch page, and a write that changed what
+    it should: every live row of every writing table entry, nothing else."""
+    pages, tables, starts, rows, q_lens = _row_tile_case(
+        sum(map(ord, case)), **_ROW_TILE_CASES[case])
+    if case.startswith("int8"):
+        pages = _quantize(pages)
+    layer = 1
+    if "one_layer" in case:
+        pages, layer = jax.tree_util.tree_map(lambda x: x[1], pages), None
+
+    def write(kernel, layer=layer):
+        return _write_as(kernel, pages, tables, starts, rows, q_lens,
+                         layer=layer)
+
+    want = write(False)
+    if case == "traced_layer":
+        got = jax.jit(lambda ly: write(True, ly))(
+            jnp.asarray(layer, jnp.int32))
+    else:
+        got = write(True)
+    assert type(got) is type(pages)
+    _assert_same_but_scratch(got, want)
+    if case.startswith("int8"):     # the page form is held to the formula
+        return                      # above; here the two halves agree
+    # the page form itself: the live rows hold the new values
+    bs, nbt = pages.shape[-2], tables.shape[1]
+    flat = np.asarray(rows.reshape(rows.shape[:2] + pages.shape[-3:-2]
+                                   + pages.shape[-1:]), np.float32)
+    out = np.asarray(got if layer is None else got[layer], np.float32)
+    written = 0
+    for i in range(rows.shape[0]):
+        for tkn in range(int(q_lens[i])):
+            pos = int(starts[i]) + tkn
+            if pos // bs < nbt and int(tables[i, pos // bs]) > 0:
+                np.testing.assert_array_equal(
+                    out[int(tables[i, pos // bs]), :, pos % bs], flat[i, tkn])
+                written += 1
+    assert written > 0
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+def test_row_write_kernel_window_relative_starts(form):
+    """The windowed model's exact rows: positions relative to the window's
+    start, through the exact slice of a table that carries the summary
+    pages behind it (``tables[:, :n_exact]``). A chunk that reaches the
+    window's end stops at the slice's last entry, and no summary page
+    changes."""
+    bs, n_exact, n_sum, batch, qw = 32, 3, 2, 4, 1 if form == "decode" else 48
+    rng = np.random.default_rng(7)
+    num_blocks = 1 + batch * (n_exact + n_sum)
+    pages = jnp.asarray(rng.normal(size=(2, num_blocks, 2, bs, 128)),
+                        jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(np.arange(1, num_blocks)).reshape(
+        batch, n_exact + n_sum).astype(np.int32))
+    window = n_exact * bs
+    # absolute positions in the second and third window, one row up to the
+    # window's last position
+    at = np.asarray([window + 5, 2 * window + bs - 3, window * 2 - qw,
+                     window + 2 * bs - 1], np.int32)
+    starts = jnp.asarray(at % window)
+    q_lens = jnp.asarray([qw, qw, qw, min(qw, bs + 1)], jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(batch, qw, 2, 128)), jnp.bfloat16)
+    args = (tables[:, :n_exact], starts, rows, q_lens)
+    want = _write_as(False, pages, *args, layer=0)
+    got = _write_as(True, pages, *args, layer=0)
+    _assert_same_but_scratch(got, want)
+    summary = np.asarray(tables[:, n_exact:]).reshape(-1)
+    np.testing.assert_array_equal(
+        np.asarray(got[:, summary], np.float32),
+        np.asarray(pages[:, summary], np.float32))
+    assert np.any(np.asarray(got[0] != pages[0]))
+
+
+@pytest.mark.parametrize("qw", [1, 16, 64])
+def test_row_write_kernel_summary_rows(qw):
+    """``eva_attention.write_summaries`` whole, in both forms: the rows are a
+    step's COMPLETED chunks (``q_lens`` = chunks completed: 0 for most rows
+    of a decode step), float32 results cast to the pages' dtype, at row
+    ``c`` of the summary pages (``tables[:, n_exact:]``), K and V."""
+    from tnn_tpu.ops.pallas import eva_attention as eva
+
+    bs, n_exact, n_sum, batch, window, chunk = 32, 2, 1, 4, 64, 16
+    rng = np.random.default_rng(qw)
+    num_blocks = 1 + batch * (n_exact + n_sum)
+    shape = (2, num_blocks, 2, bs, 128)
+    pk, pv = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+              for _ in range(2))
+    tables = jnp.asarray(rng.permutation(np.arange(1, num_blocks)).reshape(
+        batch, n_exact + n_sum).astype(np.int32))
+    # row 0 completes nothing, row 1 ends on a chunk's last position, row 2
+    # is absent, row 3 completes every chunk its width can
+    starts = jnp.asarray([3, chunk - min(qw, chunk), 40,
+                          window + chunk - min(qw, chunk)], jnp.int32)
+    q_lens = jnp.asarray([min(qw, 5), min(qw, chunk), 0, qw], jnp.int32)
+    phi, mu = (jnp.asarray(rng.normal(size=(2, 128)), jnp.float32)
+               for _ in range(2))
+    kw = dict(n_exact=n_exact, window=window, chunk=chunk, layer=1, qw=qw)
+    args = (pk, pv, tables, starts, q_lens, phi, mu)
+    want = _write_as(False, *args, write=eva.write_summaries, **kw)
+    got = _write_as(True, *args, write=eva.write_summaries, **kw)
+    for g, w, old in zip(got, want, (pk, pv)):
+        _assert_same_but_scratch(g, w)
+        assert np.any(np.asarray(g[1] != old[1]))       # something completed
+        exact = np.asarray(tables[:, :n_exact]).reshape(-1)
+        np.testing.assert_array_equal(np.asarray(g[:, exact], np.float32),
+                                      np.asarray(old[:, exact], np.float32))
+
+
+@pytest.mark.parametrize("slots", [2, 3, 5])
+@pytest.mark.parametrize("qw", [1, 64])
+def test_row_write_kernel_ring_shorter_than_the_live_tiles(qw, slots,
+                                                           monkeypatch):
+    """More live tiles than the ring has slots (a wide page's ring at real
+    sizes: 24 slots for a prompt step's 136 tiles): a slot takes the tile a
+    ring further on once its own is stored, and the last ones are waited
+    for after the walk."""
+    monkeypatch.setattr(pa, "_WRITE_SLOTS", slots)
+    pages, tables, starts, rows, q_lens = _row_tile_case(qw + slots, qw=qw,
+                                                         batch=7)
+    q_lens = q_lens.at[3].set(0)        # a dead row between live ones
+    args = (pages, tables, starts, rows, q_lens)
+    want = _write_as(False, *args, layer=0)
+    got = _write_as(True, *args, layer=0)
+    _assert_same_but_scratch(got, want)
+    assert np.any(np.asarray(got[0] != pages[0]))
+
+
+def test_row_write_kernel_scratch_only_and_other_layers_untouched():
+    """Tables of scratch entries and holes alone: nothing but page 0 may
+    change; and the layers a write does not name keep every bit."""
+    pages, tables, starts, rows, q_lens = _row_tile_case(5, qw=64)
+    dead = jnp.where(jnp.arange(tables.shape[1]) % 2 == 0, 0, -1) \
+        * jnp.ones_like(tables)
+    got = _write_as(True, pages, dead, starts, rows, q_lens, layer=1)
+    _assert_same_but_scratch(got, pages)
+    got = _write_as(True, pages, tables, starts, rows, q_lens, layer=1)
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(pages[0], np.float32))
+    assert np.any(np.asarray(got[1] != pages[1]))
+
+
+def test_row_write_form_follows_platform_and_shape():
+    """The choice is made from what the code can see, an array at a time:
+    the kernel on the TPU for pages of whole registers, under the
+    ``kv_write`` scope's one level; the whole-page form off the chip, for
+    rows that do not fill the 128 lanes (an int8 pool's scale sidecar; heads
+    of 64 that cannot pack) and for a ``bs`` of no whole tiles. No keyword
+    chooses."""
+    import inspect
+
+    pages, tables, starts, rows, q_lens = _row_tile_case(9, qw=16,
+                                                         dtype=jnp.float32)
+    quant = _quantize(pages)
+
+    def write(p):
+        return pa.scatter_kv_chunk(p, tables, starts, rows, q_lens, layer=1)
+
+    def traced(p):      # a new function a call: no trace is found again
+        return str(jax.make_jaxpr(lambda x: write(x))(p))
+
+    def kernels(p):
+        return traced(p).count("tnn_kv_row_write")
+
+    want = write(quant)
+    assert kernels(pages) == kernels(quant) == 0        # off the chip
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        plain = traced(pages)
+        assert plain.count("pallas_call") == 1 and kernels(pages) == 1
+        assert "scatter" not in plain
+        # bs 32: the int8 data is one tile a page, the scale keeps the pages
+        assert kernels(quant) == 1 and "scatter" in traced(quant)
+        got = write(quant)
+        for shape, dtype, kernel in (
+                ((2, 9, 3, 16, 64), jnp.bfloat16, False),   # half the lanes
+                ((2, 9, 2, 12, 128), jnp.float32, False),   # 1.5 tiles
+                ((2, 9, 2, 24, 128), jnp.bfloat16, False),
+                ((2, 9, 2, 16, 128), jnp.int8, False),      # half a tile
+                ((9, 1, 128, 640), jnp.bfloat16, True)):
+            assert pa._kernel_writes(
+                jax.ShapeDtypeStruct(shape, dtype)) is kernel, shape
+    _assert_same_but_scratch(got, want)
+    for fn in (pa.write_rows, pa.scatter_kv_chunk, pa.scatter_kv_rows):
+        assert set(inspect.signature(fn).parameters) <= {
+            "pages", "block_tables", "starts", "offsets", "rows", "q_lens",
+            "layer"}
+
+
+# the six serving configurations' pages (chipbench/configs/*-serve.json):
+# KV heads of a pool row, head width, page rows; rows a step, chunk width
+_CELL_PAGES = {
+    "gpt2-large": (20, 64, 16, 64), "evabyte-pp2": (32, 128, 128, 256),
+    "trinity-large-ep8": (8, 128, 128, 64),
+    "qwen3-next-ep4": (2, 256, 128, 32),
+    "mistral-small4-ep4": (1, 384, 128, 64),
+    "longcat-flash-ep32": (1, 640, 128, 32),
+}
+
+
+def _row_write_call(batch, qw, hkv, dh, bs):
+    """The kernel as ``write_rows`` traces it at a pool's shapes (nothing is
+    allocated): the ``pallas_call``'s VMEM scratch shapes and the memory
+    space of its new-rows operand."""
+    p = pa.lane_pack(hkv, dh, jnp.bfloat16)
+    shapes = [((2, 3, hkv // p, bs, p * dh), jnp.bfloat16),
+              ((batch, 4), jnp.int32), ((batch,), jnp.int32),
+              ((batch, qw, hkv, dh), jnp.bfloat16), ((batch,), jnp.int32)]
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    call, = calls(jax.make_jaxpr(functools.partial(
+        _write_as, True, layer=1))(
+            *(jax.ShapeDtypeStruct(*s) for s in shapes)).jaxpr)
+    assert call.params["input_output_aliases"] == ((3, 0),)
+    scratch = [a for a in call.params["grid_mapping"].scratch_avals
+               if "vmem" in str(a.memory_space).lower()]
+    new_rows = call.params["jaxpr"].invars[2].aval
+    return [a.shape for a in scratch], str(new_rows.memory_space).lower()
+
+
+@pytest.mark.parametrize("cell", list(_CELL_PAGES))
+def test_row_write_moves_tiles_not_pages(cell):
+    """What the KERNEL moves, read from its traced call: a ring slot is one
+    tile of 16 bf16 rows of the page, ``16 / bs`` of the page the other form
+    gathers and scatters (the same bytes at GPT-2's pages of 16, which are
+    one tile), a decode row walks ONE and a chunk ``row_tiles``, no more
+    than the pages it can touch."""
+    hkv, dh, bs, chunk = _CELL_PAGES[cell]
+    p = pa.lane_pack(hkv, dh, jnp.bfloat16)
+    page = 2 * hkv * dh * bs * 2        # a page of bf16 in and out
+    assert pa.write_tile(bs, jnp.bfloat16) == 16
+    for qw in (1, chunk, 256):
+        (slots, *slot), *new_ring = _row_write_call(8, qw, hkv, dh, bs)[0]
+        assert tuple(slot) == (hkv // p, 16, p * dh) and 2 <= slots <= 32
+        # a chunk's new rows come through a ring like the tiles'; a decode
+        # step's few are whole in VMEM
+        assert new_ring == ([] if qw == 1 else [(slots, *slot)])
+        moved = 2 * pa.row_tiles(qw, 16) * int(np.prod(slot)) * 2
+        assert moved == pa.row_tiles(qw, 16) * page * 16 // bs
+        assert moved <= pa.row_tiles(qw, bs) * page or bs == 16
+        if qw == 1:
+            assert (moved == page) == (cell == "gpt2-large")
+    # a decode row of a 64-wide mixed step: one tile, where the page form
+    # moved every page the chunk's width could touch
+    assert pa.row_tiles(1, 16) == 1 and pa.row_tiles(64, 16) == 5
+    assert pa.row_tiles(256, 16) == 17
+
+
+@pytest.mark.parametrize("cell,batch", [("evabyte-pp2", 128),
+                                        ("gpt2-large", 512)])
+def test_row_write_vmem_is_bounded_in_the_batch(cell, batch):
+    """A decode step's new rows pad ONE sublane to a tile's 16: whole in
+    VMEM they would take 16 and 20 MiB here. Past half the write's budget
+    they stay in HBM, laid out by tile, and come by DMA into a ring beside
+    their tiles', so the kernel's VMEM does not grow with
+    ``--max-batch-size``."""
+    hkv, dh, bs, _ = _CELL_PAGES[cell]
+    p = pa.lane_pack(hkv, dh, jnp.bfloat16)
+    for b, where in ((8, "vmem"), (batch, "any")):
+        scratch, space = _row_write_call(b, 1, hkv, dh, bs)
+        assert where in space
+        assert len(scratch) == (1 if where == "vmem" else 2)
+        held = sum(s[0] * s[1] * pa._tile_bytes(s[2], s[3], jnp.bfloat16)
+                   for s in scratch)
+        if where == "vmem":
+            held += b * hkv // p * pa._tile_bytes(1, p * dh, jnp.bfloat16)
+        assert held <= pa._WRITE_VMEM
 
 
 # -- the grouped grid step: several pages and every head a step ---------------

@@ -479,11 +479,13 @@ class MultiHeadAttention(Module):
             garbage the caller must ignore.
 
         Returns (out (B, Q, D), pages_k, pages_v) — pages updated only at the
-        written rows. The write rewrites the whole pages those rows land in
-        (``scatter_kv_rows`` / ``scatter_kv_chunk``), so with the pool
-        buffers donated through jit it is in place in the layout the kernel
-        reads; it needs the engine's one-writer invariant (a step writes a
-        non-scratch page from one row only).
+        written rows. The write rewrites the sublane tiles of the pages those
+        rows land in (``scatter_kv_rows`` / ``scatter_kv_chunk``: 16 page
+        rows of bf16 for a decode row; whole pages off the chip and for
+        pages the kernel does not take, ``_kernel_writes``), so with the pool buffers donated through jit it is in
+        place in the layout the kernel reads; it needs the engine's
+        one-writer invariant (a step writes a non-scratch page from one row
+        only).
         """
         if self.kv_cache_dtype == "int8":
             raise NotImplementedError(

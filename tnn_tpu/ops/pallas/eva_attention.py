@@ -245,7 +245,7 @@ def write_summaries(pages_k, pages_v, tables, starts, q_lens, phi, mu, *,
         a = softmax_s(k_s . phi)   k~ = sum_s a_s k_s + mu   v~ = sum_s a_s v_s
 
     land at row ``c`` of the row's summary pages (``tables[:, n_exact:]``)
-    through the same whole-page write as the exact rows. Returns the pages.
+    through the same row-tile write as the exact rows. Returns the pages.
     """
     bs = pages_k.shape[-2]
     b = tables.shape[0]

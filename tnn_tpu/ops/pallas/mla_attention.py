@@ -43,7 +43,7 @@ QUERY_ROWS = 512            # query rows (tokens x heads) of a grid step
 def row_width(latent_dim: int) -> int:
     """Lanes of a latent page row: whole 128-lane registers (a row that does
     not fill the lanes rests in another layout than the kernel and the
-    whole-page write read, and every step program copies the pool)."""
+    page write read, and every step program copies the pool)."""
     return -(-latent_dim // 128) * 128
 
 

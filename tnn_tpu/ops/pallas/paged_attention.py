@@ -60,7 +60,9 @@ live page (or page 0) and the mask keeps them out of the softmax.
 into a contiguous cache, masked softmax) — the parity oracle for the kernel
 and the CPU/interpret fallback the router picks off-TPU, mirroring how
 ``flash_attention`` routes. ``scatter_kv_rows`` / ``scatter_kv_chunk`` are the
-write half of the page contract: the new KV rows per sequence per step.
+write half of the page contract: the new KV rows per sequence per step, stored
+by ``tnn_kv_row_write``, which moves the sublane tile of the page that holds a
+row and nothing else (``write_rows``).
 
 INT8 PAGES (``QuantPages``): decode is HBM-bandwidth-bound on KV bytes, so
 the pool may store pages as int8 with a per-(position, head) f32 scale
@@ -213,7 +215,7 @@ def lane_pack(hkv, dh, page_dtype):
     largest divisor of ``hkv`` (the heads ONE device holds: a tensor-parallel
     shard's, so that a shard owns whole groups) that is at most ``128 // dh``.
     A row of ``lane_pack * dh`` then fills the 128 lanes of a vector
-    register, and the pool rests in the layout the kernel and the whole-page
+    register, and the pool rests in the layout the kernel and the page
     write read: at ``dh`` = 64 a row of one head is half a register, the
     compiler keeps such a pool in another layout, and every step program
     converted it in and out (4 whole-pool copies, three quarters of GPT-2
@@ -894,22 +896,32 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
     0, which is never allocated to a request, so they can't corrupt live KV.
     Same layer semantics as ``scatter_kv_rows``.
 
-    The write moves WHOLE PAGES: Q consecutive positions touch at most
-    ``(Q + bs - 2) // bs + 1`` pages of a row's table; each is read, the new
-    rows put in their slots, and the page written back. Gather and scatter
-    index the pool's LEADING dims only ([layer, blk]) and move whole
-    (H, bs, Dh) pages, so both run in the pool's own layout — the one the
-    paged kernel reads — and with the pool buffers donated through jit the
-    update is in place. (Indices on the slot axis, ``.at[layer, blk, :,
+    The write moves the row's SUBLANE TILES, in place: Q consecutive
+    positions touch at most ``(Q + t - 2) // t + 1`` tiles of ``t`` page
+    rows (``write_tile``: one register's sublanes, 16 rows of bf16; ONE tile
+    for a decode row), and ``tnn_kv_row_write`` takes each LIVE one through a
+    DMA into VMEM, found by the scalar-prefetched table entry and tile
+    index, selects the new rows in and sends it back, the pool aliased in
+    and out (``_write_rows_pallas``): nothing else of the pool moves, a dead
+    tile costs a scalar test, and the write runs in the pool's own layout,
+    the one the paged kernel reads. Off the chip, and for pages whose rows
+    do not fill the lanes or are no whole tiles (``_kernel_writes``: an
+    int8 pool's scale sidecar, GPT-2's unpacked int8 heads of 64), the
+    write moves WHOLE PAGES instead, plain XLA (``_write_rows_xla``): each
+    page a chunk can touch is gathered, the new rows selected into their
+    slots, and the page scattered back, on the pool's LEADING dims only
+    ([layer, blk]) so that it too runs in the pool's layout and, donated
+    through jit, in place. (Indices on the slot axis, ``.at[layer, blk, :,
     slot]``, made the TPU scatter want another layout, and the compiler
     re-laid the WHOLE pool out and back around every layer's write.)
 
-    That is exact under the ONE-WRITER invariant the engine keeps
+    Both are exact under the ONE-WRITER invariant the engine keeps
     (``PagedKVPool.check_step_writes``): a step writes a non-scratch page
     from one row only — a shared prefix page is cloned before its first
-    write. Pages of a chunk that hold no live token, and -1 table holes
-    (positions another SP shard owns), divert to the scratch page, where
-    rows may overwrite each other: nothing reads it.
+    write. What holds no live token, and -1 table holes (positions another
+    SP shard owns), the kernel leaves out; the page form diverts those
+    pages to the scratch page, where rows may overwrite each other: nothing
+    reads it.
 
     QuantPages: rows are quantized HERE (write time) and the int8 data and
     f32 scale scatter through the same block-table math, so a row's scale
@@ -918,25 +930,227 @@ def scatter_kv_chunk(pages, block_tables, starts, rows, q_lens, *,
     return write_rows(pages, block_tables, starts, rows, q_lens, layer=layer)
 
 
+def write_tile(bs, page_dtype):
+    """Rows of a page the row write moves at once: one register's sublanes
+    (``_sublanes``: 16 rows of bf16, 8 of float32), the least a block of
+    the page may hold; the whole page where its ``bs`` rows are no whole
+    tiles. From the page's shape and dtype alone."""
+    t = _sublanes(page_dtype)
+    return t if bs % t == 0 else bs
+
+
+def row_tiles(qw, t):
+    """Tiles of ``t`` rows that ``qw`` consecutive positions can touch: one
+    for a decode row, ``(qw + t - 2) // t + 1`` for a chunk that may start
+    in mid-tile."""
+    return (qw + t - 2) // t + 1
+
+
+# what the row write's ring of tiles and its new rows (a decode step's whole,
+# a chunk's a ring of their own) may take of VMEM, and the most tiles it keeps
+# in flight (a DMA semaphore a transfer, three kinds of
+# transfer a slot: a kernel has some 450 semaphores, and a transfer's
+# latency is hidden long before that)
+_WRITE_VMEM = 6 * 2 ** 20
+_WRITE_SLOTS = 32
+
+
+def _row_write_kernel(layer_ref, meta_ref, new_ref, pool_ref, out_ref, buf,
+                      *rest, bn, n):
+    """Every live tile of the step: the tile (and a chunk's new rows for it)
+    comes into a slot of the ring, the rows ``[lo, hi)`` of the tile take
+    the new values, and the tile goes back where it came from. ``meta``:
+    ``bn`` table entries, tile indices, ``lo`` and ``hi``, tile ``i`` = row
+    ``i // n``'s ``i % n``-th (the same numbers for every layer and for K
+    and V of a step, so a program computes them once). A decode step's new rows
+    (one a row) are whole in VMEM; a chunk's (and a batch's too wide for
+    that) stay in HBM, laid out by tile, and come a tile at a time into
+    ``nbuf``. ``pool_ref`` and ``out_ref`` are one buffer."""
+    *nbuf, sem, live_ref = rest
+    slots, _, t, _ = buf.shape          # the ring: ``slots`` tiles of ``t`` rows
+
+    def of(i, field):
+        """Tile ``i``'s table entry (0), tile index (1), ``lo``, ``hi``."""
+        return meta_ref[field * bn + i]
+
+    def mark(i, c):
+        live = of(i, 3) > of(i, 2)
+
+        @pl.when(live)
+        def _keep():
+            live_ref[c] = i
+        return c + live.astype(jnp.int32)
+
+    n_live = jax.lax.fori_loop(0, bn, mark, 0)
+
+    def tile(ref, c):
+        """Live tile ``c``'s place in the pool."""
+        i = live_ref[c]
+        row0 = pl.multiple_of(of(i, 1) * t, t)
+        at = (of(i, 0), slice(None), pl.ds(row0, t), slice(None))
+        return ref.at[(layer_ref[0],) + at if len(ref.shape) == 5 else at]
+
+    def tile_in(c):
+        return pltpu.make_async_copy(tile(pool_ref, c), buf.at[c % slots],
+                                     sem.at[0, c % slots])
+
+    def tile_out(c):
+        return pltpu.make_async_copy(buf.at[c % slots], tile(out_ref, c),
+                                     sem.at[1, c % slots])
+
+    def new_in(c):
+        i = live_ref[c]
+        rows = pl.ds(pl.multiple_of((i % n) * t, t), t)
+        return pltpu.make_async_copy(new_ref.at[i // n, :, rows, :],
+                                     nbuf[0].at[c % slots],
+                                     sem.at[2, c % slots])
+
+    def fetch(c, _):
+        tile_in(c).start()
+        if nbuf:
+            new_in(c).start()
+
+    def stored(c, _):
+        tile_out(c).wait()
+
+    jax.lax.fori_loop(0, jnp.minimum(n_live, slots), fetch, None)
+    r = jax.lax.broadcasted_iota(jnp.int32, buf.shape[1:], 1)
+
+    def one(c, _):
+        i, slot = live_ref[c], c % slots
+        here = (r >= of(i, 2)) & (r < of(i, 3))
+        tile_in(c).wait()
+        if nbuf:
+            new_in(c).wait()
+            fresh = nbuf[0][slot]
+        else:           # the row's ONE new row, laid over the tile
+            fresh = new_ref[i // n]
+        buf[slot] = jnp.where(here, fresh, buf[slot])
+        tile_out(c).start()
+
+        # the slot of the tile before takes the tile a ring further on
+        @pl.when((c >= 1) & (c - 1 + slots < n_live))
+        def _refill():
+            stored(c - 1, None)
+            fetch(c - 1 + slots, None)
+
+    jax.lax.fori_loop(0, n_live, one, None)
+    jax.lax.fori_loop(jnp.maximum(n_live - slots, 0), n_live, stored, None)
+
+
+# jitted: a program writes K and V of every layer through ONE traced and
+# lowered function (tracing the kernel and lowering it for Mosaic cost 90 ms
+# a call site, 6.5 s a 36-layer program, and a warm compile cache saves
+# neither); XLA inlines the calls, each under its own site's scope
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _write_rows_pallas(pages, block_tables, starts, rows, q_lens, layer,
+                       interpret):
+    """The row write as ONE aliased ``pallas_call``. The pool stays in HBM;
+    the kernel walks the step's LIVE tiles (the ``(H / p, t, p * Dh)``
+    sublane tile of the page that holds a row's positions, found through
+    the scalar-prefetched table entry and tile index), each through one DMA
+    in, a select against the tile's live rows and one DMA back, as many in
+    flight as the ring holds. Nothing else of the pool moves, and a dead
+    tile costs a scalar test."""
+    bs = pages.shape[-2]
+    b, qw, hp, width = rows.shape
+    t = write_tile(bs, pages.dtype)
+    n = row_tiles(qw, t)
+    nbt = block_tables.shape[1]
+    g = (starts // t)[:, None] + jnp.arange(n)          # (B, n) tiles, global
+    lo = jnp.clip(starts[:, None] - g * t, 0, t)
+    hi = jnp.clip((starts + q_lens)[:, None] - g * t, 0, t)
+    entry = g // (bs // t)
+    blk = jnp.take_along_axis(block_tables, jnp.clip(entry, 0, nbt - 1),
+                              axis=1)
+    # dead tiles, -1 holes (a raw -1 would wrap to the LAST page) and entries
+    # past the table are not walked at all
+    ok = (hi > lo) & (entry >= 0) & (entry < nbt) & (blk >= 0)
+    meta = jnp.concatenate(
+        [jnp.where(ok, x, 0).reshape(-1)
+         for x in (blk, g % (bs // t), lo, hi)]).astype(jnp.int32)
+    bn = b * n
+    # half the budget for the tiles' ring, half for the new rows. A decode
+    # step's, one a row, sit whole in VMEM where they fit (counted at a
+    # tile's sublanes a row, the most a row of ONE pads to); a chunk's, and
+    # a wider batch's, are laid out by tile here, the gather the page form
+    # makes too, and come by DMA into a ring beside their tiles
+    slots = max(2, min(bn, _WRITE_SLOTS, _WRITE_VMEM // (
+        2 * _tile_bytes(hp * t, width, pages.dtype))))
+    whole = qw == 1 and b * hp * _tile_bytes(1, width, pages.dtype) \
+        <= _WRITE_VMEM // 2
+    if whole:
+        new = rows.reshape(b, hp, 1, width)
+    else:
+        # the chunk token that lands in each row of each touched tile
+        tok = ((starts // t) * t - starts)[:, None] + jnp.arange(n * t)
+        new = jnp.take_along_axis(
+            rows, jnp.clip(tok, 0, qw - 1)[:, :, None, None],
+            axis=1).swapaxes(1, 2)                      # (B, H / p, n * t, W)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    ring = pltpu.VMEM((slots, hp, t, width), pages.dtype)
+    return pl.pallas_call(
+        functools.partial(_row_write_kernel, bn=bn, n=n),
+        name="tnn_kv_row_write",        # what the device profile shows
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM) if whole
+                      else hbm, hbm],
+            out_specs=hbm,
+            scratch_shapes=[ring] * (1 if whole else 2) + [
+                pltpu.SemaphoreType.DMA((3, slots)),
+                pltpu.SMEM((bn,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+        # operands count the two prefetched arrays: the pool is 3
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.reshape(0 if layer is None else layer, (1,)).astype(jnp.int32),
+      meta, new, pages)
+
+
+def _kernel_writes(pages):
+    """Whether the write into ``pages`` (ONE array) is ``tnn_kv_row_write``:
+    on the TPU, for pages of whole registers, rows that fill the 128 lanes
+    (Mosaic refuses a tile of half-filled ones: an odd count of heads of 64,
+    a tensor-parallel shard's 3 of gpt2_small's 12; an int8 pool's scale
+    sidecar is one lane wide) and ``bs`` a whole number of sublane tiles.
+    Everything else, and every pool off the chip, keeps the whole-page
+    form. From the platform and the array alone."""
+    return jax.default_backend() == "tpu" and pages.shape[-1] % 128 == 0 \
+        and pages.shape[-2] % _sublanes(pages.dtype) == 0
+
+
 def write_rows(pages, block_tables, starts, rows, q_lens, *, layer=None):
-    """``scatter_kv_chunk`` under the caller's scope: the whole-page write
-    for rows that are not a step's K/V (EVA's chunk summaries)."""
+    """``scatter_kv_chunk`` under the caller's scope: the write for rows
+    that are not a step's K/V (EVA's chunk summaries). An int8 pool's data
+    and scale each go by their own shape (``_kernel_writes``), each at its
+    own tile height."""
     if isinstance(pages, QuantPages):
-        qrows, srows = quantize_kv_rows(rows)
-        return QuantPages(
-            write_rows(pages.data, block_tables, starts, qrows, q_lens,
-                       layer=layer),
-            write_rows(pages.scale, block_tables, starts, srows, q_lens,
-                       layer=layer))
+        return QuantPages(*(
+            write_rows(p, block_tables, starts, r, q_lens, layer=layer)
+            for p, r in zip(pages, quantize_kv_rows(rows))))
     if pages.ndim == 5 and layer is None:
         raise ValueError("layer is required for (L, N, H, bs, Dh) pages")
-    bs = pages.shape[-2]
-    b, qw = rows.shape[:2]
     # a packed page row holds ``pages.shape[-1] // Dh`` adjacent heads side
     # by side (``lane_pack``): the same bytes as the new rows, regrouped
-    rows = rows.reshape(b, qw, pages.shape[-3], pages.shape[-1])
+    rows = rows.reshape(*rows.shape[:2], pages.shape[-3], pages.shape[-1])
+    form = functools.partial(_write_rows_pallas, interpret=interpret_default()) \
+        if _kernel_writes(pages) else _write_rows_xla
+    return form(pages, block_tables, starts, rows, q_lens, layer)
+
+
+def _write_rows_xla(pages, block_tables, starts, rows, q_lens, layer):
+    """The write in WHOLE PAGES, plain XLA: every page a row's chunk can
+    touch is gathered, the new rows selected into their slots, the page
+    scattered back. Off the chip, for pages the kernel does not take
+    (``_kernel_writes``), and what the kernel is tested against."""
+    bs = pages.shape[-2]
+    b, qw = rows.shape[:2]
     nbt = block_tables.shape[1]
-    npg = (qw + bs - 2) // bs + 1
+    npg = row_tiles(qw, bs)
     entry = (starts // bs)[:, None] + jnp.arange(npg)     # (B, P) table slots
     # the chunk token that lands in each slot of each touched page
     tok = (entry * bs - starts[:, None])[:, :, None] + jnp.arange(bs)

@@ -34,7 +34,7 @@ contract, not an implementation detail:
 - ``p`` (``lane_pack``, from ``ops.pallas.paged_attention.lane_pack``) is
   the largest divisor of the KV heads one device holds that is at most
   ``128 // Dh``: a page row then fills the 128 lanes and the pool rests in
-  the layout the kernel and the whole-page write read (at ``Dh`` = 64 an
+  the layout the kernel and the page write read (at ``Dh`` = 64 an
   unpacked pool was converted in and out by every step program). 1 at
   ``Dh`` >= 128, for an odd head count, for int8 pages and for a windowed
   pool, which is the plain ``(L, N, H_kv, bs, Dh)``. Whoever needs the
@@ -946,7 +946,8 @@ class PagedKVPool:
         ``tables`` (B, nb) are the step's GLOBAL block tables, row i writes
         positions ``starts[i] .. starts[i] + q_lens[i] - 1``. The paged write
         (``ops.pallas.paged_attention.scatter_kv_rows`` / ``scatter_kv_chunk``)
-        rewrites whole pages, which is exact only while every non-scratch
+        rewrites a row's tile of a page (whole pages off the chip and where
+        the kernel does not take the pages), which is exact only while every non-scratch
         page a step writes belongs to one row alone: refcount 1 (a shared
         prefix page is cloned before its first write, ``_match_prefix``) and
         no second row of the batch writing it. Scratch pages take any number
@@ -994,7 +995,7 @@ class PagedKVPool:
                 if blk in writer:
                     raise ValueError(
                         f"block {blk} written by rows {writer[blk]} and {i} "
-                        "of one step — the whole-page write needs one "
+                        "of one step — the page write needs one "
                         "writer per page")
                 writer[blk] = i
                 if self._ref.get(blk) != 1:
