@@ -4,15 +4,17 @@
 Trains the FULL cifar100_wrn16_8 (~11M params) for a few steps through the
 compiled heterogeneous pipeline and through single-device gradient
 accumulation from the SAME init, and writes per-step relative loss diffs to
-benchmarks/results/. This is the functional-correctness evidence behind the
+logs/ (``--out`` names another file). This is the functional-correctness
+evidence behind the
 flagship pipeline (round-3 artifact: rel_diff <= 6e-5 at v=1); --virtual 2
 exercises the interleaved schedule on the same model (round-4, VERDICT #3).
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python scripts/pipeline_equivalence.py --virtual 2 --steps 3
 
-Runs anywhere; the committed artifacts come from the virtual 8-device CPU
-mesh (numerics are platform-independent at f32) and chip runs when available.
+Runs anywhere: on the virtual 8-device CPU mesh (numerics are
+platform-independent at f32) and on the chip. The records of earlier rounds
+that lay under benchmarks/results/ went with PR 48 (no test read them).
 """
 import argparse
 import json
@@ -146,10 +148,10 @@ def main(argv=None):
         "unix_time": time.time(),
     }
     path = args.out or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "results",
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "logs",
         f"wrn16_8_pipeline_equivalence_v{v}_pp{pp}"
         + ("_f32" if args.f32 else "") + ".json")
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=2)
     print(f"wrote {path}; max rel diff {worst:.2e} "

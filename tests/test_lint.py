@@ -174,11 +174,12 @@ class E:
 '''
         assert _rules(src, "use-after-donate") == []
 
-    # sequence-parallel builders route through SPContext.jit_step — same
-    # wrapper name, extra routing kwargs (tables_argnum tells the context
-    # mesh which argument is the per-shard table stack). Donation happens
-    # on every context-mesh shard; the kwargs must not confuse the rule's
-    # donated-position extraction.
+    # a builder that routes through a mesh context's jit_step directly —
+    # same wrapper name, with the routing keywords it took until PR 48
+    # (since then a context reads what an argument is off the body's
+    # parameter names). Donation happens on every context-mesh shard;
+    # further keywords must not confuse the rule's donated-position
+    # extraction.
     SP_BUILDER = '''
 class E:
     def _step_fn(self):
@@ -206,6 +207,38 @@ class E:
         src = self.SP_BUILDER + '''
         self.pool.update_pages(pk, pv)
         shape = self.pool.pages_k.shape
+'''
+        assert _rules(src, "use-after-donate") == []
+
+    # the pool's device arrays as ONE donated value (PR 48): a builder
+    # donates ``cache`` alone, and a call site takes it back through the
+    # pool's setter in the statement that makes the call — a tuple target
+    # between the program's small results
+    CACHE_BUILDER = '''
+class E:
+    def _cow_copy_fn(self):
+        def fn(cache, src, dst):
+            return (), cache, ()
+        return self._jit_step("tnn_kv_cow", fn, donate_argnums=(0,))
+
+    def step(self):
+        fn = self._jit.get(key)
+        if fn is None:
+            fn = self._jit[key] = self._cow_copy_fn()
+'''
+
+    def test_one_cache_read_after_donation_flags(self):
+        src = self.CACHE_BUILDER + '''
+        _, cache, _ = fn(self.pool.cache, src, dst)
+        shape = self.pool.cache[0].shape
+        self.pool.cache = cache
+'''
+        assert _rules(src, "use-after-donate") == ["use-after-donate"]
+
+    def test_one_cache_taken_back_in_the_call_statement_clean(self):
+        src = self.CACHE_BUILDER + '''
+        _, self.pool.cache, _ = fn(self.pool.cache, src, dst)
+        shape = self.pool.cache[0].shape
 '''
         assert _rules(src, "use-after-donate") == []
 

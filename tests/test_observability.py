@@ -854,12 +854,11 @@ class TestStableNames:
         row = dict(t=jnp.zeros(b), k=jnp.zeros(b, jnp.int32), p=jnp.zeros(b))
         tables = jnp.zeros((b, nb), jnp.int32)
         ints = jnp.zeros(b, jnp.int32)
-        pages = (eng.pool.pages_k, eng.pool.pages_v)
         decode = _lowered(
-            eng._paged_decode_fn(b, nb), params, *pages, ints, ints, tables,
-            row["t"], row["k"], row["p"], eng._key, row["t"])
+            eng._step_program(None), params, eng.pool.cache, ints, ints, None,
+            tables, row["t"], row["k"], row["p"], eng._key, row["t"])
         mixed = _lowered(
-            eng._mixed_paged_fn(b, 8, nb), params, *pages,
+            eng._step_program(8), params, eng.pool.cache,
             jnp.zeros((b, 8), jnp.int32), ints, ints + 1, tables,
             row["t"], row["k"], row["p"], eng._key, row["t"])
         for text, module in ((decode, "jit_tnn_serve_decode"),
@@ -872,6 +871,38 @@ class TestStableNames:
             assert "/h1/kv_write/jit(_write_rows_pallas)" in text
             assert '"tnn_kv_row_write/' in text
             assert not re.search(r"/kv_write/(scatter|gather)", text)
+
+    @pytest.mark.parametrize("family", ["gpt2", "llama"])
+    def test_a_mixed_step_holds_no_cube_of_logits(self, family):
+        """Every model's mixed program takes the head at each row's last
+        live position (PR 48; until then only a model with state slots did,
+        and a plain model's program ran the head over all ``B x qw``
+        positions and cut one out of a float32 ``(B, qw, V)`` cube after
+        the product): the engine's own builder, lowered with abstract
+        arguments, holds the logits of ONE position a row and no cube. The
+        vocabulary of 131 is no other width of these models."""
+        from tnn_tpu.models.gpt2 import GPT2
+        from tnn_tpu.models.llama import Llama
+
+        jnp = jax.numpy
+        size = dict(vocab_size=131, max_len=64, num_layers=2, d_model=32,
+                    num_heads=2)
+        model = GPT2(**size) if family == "gpt2" else Llama(**size)
+        params = model.init(jax.random.PRNGKey(0), (1, 8))["params"]
+        eng = InferenceEngine(model, params, **KW)
+        b, nb, qw = KW["max_batch_size"], eng.blocks_per_seq, 8
+
+        def spec_of(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt)
+
+        ints, reals = spec_of((b,), jnp.int32), spec_of((b,), jnp.float32)
+        text = eng._step_program(qw).lower(
+            params, eng.pool.cache, spec_of((b, qw), jnp.int32), ints, ints,
+            spec_of((b, nb), jnp.int32), reals, ints, reals,
+            spec_of((2,), jnp.uint32), reals).as_text()
+        assert f"tensor<{b}x1x131xf32>" in text
+        assert not re.search(rf"tensor<{b}x{qw}x131x", text)
+        assert not re.search(rf"tensor<{b * qw}x131x", text)
 
     def test_train_step_carries_the_catalog(self, as_on_the_chip):
         from tnn_tpu import nn

@@ -8,6 +8,7 @@ compares with, which imports nothing of the program and runs the recurrence
 position by position."""
 import contextlib
 import hashlib
+import inspect
 import io
 
 import jax
@@ -499,45 +500,50 @@ def test_tnn_serve_says_so_at_start_up_before_any_weights(flags, what):
 # -- (8) the older configurations' programs are the parent's ------------------------
 
 # sha256 of ``lower(...).as_text()`` of the engine's own decode and mixed
-# step builders with abstract arguments, on the PARENT of PR 44 (65e11db),
-# made with ``_older_programs`` below in a checkout of it
+# step builder with abstract arguments, made with ``_older_programs`` below.
+# PR 44 took them on its PARENT (65e11db) and held them through its change;
+# PR 48 took them anew on its own tree, because it changed every program's
+# text on purpose (the cache one argument and one result between the small
+# ones, the head at a mixed step's last live position): what they hold from
+# here on is that a LATER configuration leaves these five's programs alone
 PARENT = {
     "gpt2_tiny": {
         "decode":
-        "95a3ec5a966da172f0521f502cb26bec54a0593b8ee48c1d43f185cdcfbc3479",
+        "f96f24465697eef2b517389a29d4cdf17be579a1c20dfa7f7ccbd530d614e248",
         "mixed":
-        "17ad91201245ed0a060687dc3fcd5857a6e1bc37df903bd44fa679fdf3f62465",
+        "4282a4184f9e5b55cb884c65a4cd125f8e6dac847e26bd5ead3530c21a8ab46c",
     },
     "evabyte_tiny": {
         "decode":
-        "dd73fa7b85a1320bd0456b7a7c881b4d611de4a0ae9de91448d8938b69071217",
+        "ddb1acfab25479c7ee452d357c9a5fb4597b19692450854e20ff8bb5924d0045",
         "mixed":
-        "aa15282dcfa00fad58018d01137208e1d10854ea4fa3175a2202df8489fbd2a9",
+        "497828ae733bb1151bfea2104fb4041fa07cdd0db4d624ec4ed7c704c05a2557",
     },
     "mistral_small4_tiny": {
         "decode":
-        "041122dacf9b51ce6d3ae8577c2e9796e2307e63da87c50cb3a6e09bd074f1d6",
+        "3670fff66f14f8629b2c88efc790df9c0c9f3649ec2b510e2c0b85ccb30e4a0d",
         "mixed":
-        "f79b878e3693cdd4ac61b06e9d198e56014199cf3f44891c938c4a30430b9a70",
+        "da8c639a3aa447899da0326b33e0a09baf2326c7637343a79c39974988c56a48",
     },
     "trinity_large_tiny": {
         "decode":
-        "abecf6dc2ed130e4d4b5a9a519b094a0c5687e60a0478551e2a47387380778b6",
+        "4c7618ca1c01d0f1f98d45c42f154146b80102eb53153e6efd544978bfa7527f",
         "mixed":
-        "1af034c12a7ddc6af09e05754fe62a573e2977e914d870e547b7f766c7ee2ce3",
+        "9873a87816aca3c4444eff1919ec04c6510f6729f24546b83d84d3044e7d779f",
     },
     "longcat_flash_tiny": {
         "decode":
-        "73d2dee3440e1113f1f36254d7f2c77746e386180597598514bd80b1ca4e273d",
+        "0c9cae43e91f03ca3618c0dcb9e02ef78fd118893072f625057e9bcb09afeedd",
         "mixed":
-        "b0490a80554afda4d2c92a2c8eb5b6325d1533c8c2ac762e2dfca1cea18d5f8c",
+        "eb6d77e465c277155bc4732e4e1157b4d2a0f62d457b4a7087a2dbae35968ee8",
     },
 }
 
 
-def _older_programs(name):
-    """{kind: sha256 of the lowered text} of configuration ``name``'s decode
-    program and its 16-wide mixed program, as its engine builds them."""
+def _lowered_programs(name):
+    """{kind: (the engine's own builder's program, lowered with abstract
+    arguments)} of configuration ``name``: its decode program and its
+    16-wide mixed program."""
     m = models.create(name)
     params = m.init(jax.random.PRNGKey(0), (1, 8))["params"]
     bs = 16 if name == "trinity_large_tiny" else 32 if name == \
@@ -553,15 +559,21 @@ def _older_programs(name):
     i32, f32 = jnp.int32, jnp.float32
     tail = (spec_of((b,), f32), spec_of((b,), i32), spec_of((b,), f32),
             spec_of((2,), jnp.uint32), spec_of((b,), f32))
-    pk, pv = eng.pool.pages_k, eng.pool.pages_v
-    decode = eng._paged_decode_fn(b, nb).lower(
-        eng.params, pk, pv, spec_of((b,), i32), spec_of((b,), i32),
-        spec_of((b, nb), i32), *tail).as_text()
-    mixed = eng._mixed_paged_fn(b, 16, nb).lower(
-        eng.params, pk, pv, spec_of((b, 16), i32), spec_of((b,), i32),
-        spec_of((b,), i32), spec_of((b, nb), i32), *tail).as_text()
-    return {kind: hashlib.sha256(text.encode()).hexdigest()
-            for kind, text in (("decode", decode), ("mixed", mixed))}
+    decode, mixed = eng._step_program(None), eng._step_program(16)
+    return {
+        "decode": (decode, decode.lower(
+            eng.params, eng.pool.cache, spec_of((b,), i32),
+            spec_of((b,), i32), None, spec_of((b, nb), i32), *tail)),
+        "mixed": (mixed, mixed.lower(
+            eng.params, eng.pool.cache, spec_of((b, 16), i32),
+            spec_of((b,), i32), spec_of((b,), i32), spec_of((b, nb), i32),
+            *tail))}
+
+
+def _older_programs(name):
+    """{kind: sha256 of the lowered text} of ``_lowered_programs(name)``."""
+    return {kind: hashlib.sha256(low.as_text().encode()).hexdigest()
+            for kind, (_, low) in _lowered_programs(name).items()}
 
 
 @pytest.mark.parametrize("name", ["gpt2_tiny", "evabyte_tiny",
@@ -570,6 +582,36 @@ def _older_programs(name):
                                   "longcat_flash_tiny"])
 def test_the_older_configurations_programs_hash_as_the_parents(name):
     assert _older_programs(name) == PARENT[name]
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_state_slots_are_one_more_leaf_of_the_one_cache(kind):
+    """A model with state slots and one without build their step programs
+    from ONE body: the same parameters in the same order, the pool's arrays
+    the one donated argument, the state its third leaf (PR 48: the twin
+    bodies that differed in one positional argument are gone)."""
+    order = ["params", "cache", "toks", "starts", "q_lens", "tables", "t",
+             "k", "p", "key", "poison"]
+    seen = {}
+    for name in ("gpt2_tiny", "qwen3_next_tiny"):
+        program, low = _lowered_programs(name)[kind]
+        assert list(inspect.signature(program).parameters) == order
+        args = low.args_info[0]
+        cache = jax.tree_util.tree_leaves(args[1])
+        assert all(x.donated for x in cache)
+        assert not any(x.donated for i, a in enumerate(args) if i != 1
+                       for x in jax.tree_util.tree_leaves(a))
+        # behind the cache: the same leaves, shape for shape (the step's
+        # table is one entry wider where its last entry is the state slot)
+        seen[name] = (len(cache), jax.tree_util.tree_structure(args[2:]),
+                      [x.shape for x in jax.tree_util.tree_leaves(args[2:])
+                       if len(x.shape) < 2])
+        assert jax.tree_util.tree_structure(args[1]) == \
+            jax.tree_util.tree_structure(
+                (0, 0) if name == "gpt2_tiny" else
+                (0, 0, dict(conv=0, rec=0, conv_snap=0, rec_snap=0)))
+    assert seen["gpt2_tiny"][0] == 2 and seen["qwen3_next_tiny"][0] == 6
+    assert seen["gpt2_tiny"][1:] == seen["qwen3_next_tiny"][1:]
 
 
 # -- the served model, as published ---------------------------------------------------

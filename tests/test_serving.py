@@ -3452,11 +3452,10 @@ class TestSpecDecode:
         with pytest.raises(ValueError, match="spec_k"):
             InferenceEngine(model, params, spec="ngram", spec_k=0,
                             **self.KW)
-        # the one serving path needs the model's paged methods: a model
-        # without them is refused at start-up, in one sentence
+        # the one serving path needs the model's paged method: a model
+        # without it is refused at start-up, in one sentence
         plain = type("NoPaged", (), {"kv_cache_dtype": None})()
-        with pytest.raises(ValueError, match="NoPaged has no "
-                                             "apply_decode_paged"):
+        with pytest.raises(ValueError, match="NoPaged has no apply_paged"):
             InferenceEngine(plain, params, **self.KW)
         from tnn_tpu.models.gpt2 import gpt2_tiny
 

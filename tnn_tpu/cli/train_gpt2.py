@@ -62,7 +62,7 @@ def main(argv=None):
                          "dominates small models (a non-"
                          "divisor remainder runs as one final smaller "
                          "dispatch, so --steps is always exact)")
-    ap.add_argument("--results", default="benchmarks/results")
+    ap.add_argument("--results", default="logs/train_gpt2")
     args = ap.parse_args(argv)
     compile_cache.enable()
 

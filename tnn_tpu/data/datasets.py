@@ -379,10 +379,10 @@ class DigitsDataLoader(ArrayDataLoader):
     Why it exists: the reference's convergence evidence is CIFAR-100 accuracy
     curves (sample_logs/cifar100_wrn16_8), but CIFAR binaries cannot be
     downloaded in an offline environment. This is the one real labeled image
-    dataset shipped inside the baked-in python packages, so it anchors the
-    on-chip convergence artifacts (docs/perf.md). Images are bilinear-upscaled
-    to `image_size` and replicated to 3 channels so the unmodified 32x32x3
-    model zoo (wrn16_8, resnet9...) trains on it.
+    dataset shipped inside the baked-in python packages, so it anchored the
+    builders' on-chip convergence runs (2026-07-30; not measured since).
+    Images are bilinear-upscaled to `image_size` and replicated to 3 channels
+    so the unmodified 32x32x3 model zoo (wrn16_8, resnet9...) trains on it.
 
     Deterministic 80/20 train/val split by a seeded permutation — train=True
     and train=False partition the same shuffle, never overlapping.

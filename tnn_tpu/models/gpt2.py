@@ -256,7 +256,8 @@ def gpt2_small_hd128(**kw):
     """12L/768d/6h — GPT-2 small geometry with 128-wide heads.
 
     TPU-first variant: every attention matmul at head_dim 64 leaves half the
-    128-wide MXU idle (see docs/perf.md rooflines); 6 heads of D=128 keep the
+    128-wide MXU idle (not measured since the builders' rooflines of
+    2026-07-30: no cell runs this geometry); 6 heads of D=128 keep the
     same d_model/params but run the QK^T/PV contractions at full width. No
     reference counterpart — the reference's head_dim is fixed by the GPT-2
     checkpoint (example_models.cpp:384); this exists for from-scratch

@@ -6,8 +6,10 @@ f32 scales, ops/pallas/quant_matmul.py). Layers are quantization-transparent:
 Dense / MultiHeadAttention / Embedding route Int8Weight params through the
 in-VMEM-dequant Pallas kernel and float params through the normal dot.
 
-Decode is HBM-bound on weight bytes (docs/perf.md: bf16 decode sits at ~91% of
-the bf16 roofline), so halving weight bytes is the one lever below it. This is
+Decode is HBM-bound on weight bytes (the builders' figure of 2026-07-30: bf16
+decode at ~91% of the bf16 roofline; not measured since, PERF.md section 4 has
+no cell with quantized weights), so halving weight bytes is the one lever
+below it. This is
 inference-time only: checkpoints store float params; quantize after load.
 Optimizers cannot step Int8Weight params.
 

@@ -51,10 +51,12 @@ class PagedDecoder:
         ``state``: the pool's state slots, for a model some of whose layers
         keep a state updated in place and no pages (``_paged_layers`` gives
         such a layer its ``slots``; its block's ``apply_state`` takes the
-        state and returns it). It is then a fourth result, donated like the
-        pages. ``head_at`` (B,): only that position of each row goes through
+        state and returns it). It is then a fourth result: the pages and
+        the state are what the engine donates as its one ``cache``.
+        ``head_at`` (B,): only that position of each row goes through
         the head, logits (B, 1, V): a wide step of a large vocabulary then
-        holds no (B, Q, V) cube."""
+        holds no (B, Q, V) cube (the engine's mixed step passes each row's
+        last live position, for every model)."""
         x = self._embed(params, toks, offsets)
         where = self._paged_layers(pages_k, block_tables)
         for i, block in enumerate(self.blocks):

@@ -1,7 +1,8 @@
 """Int8 weight-only matmul with in-VMEM dequantization (Pallas TPU kernel).
 
-Why this exists: bs=1 GPT-2 decode is HBM-bandwidth-bound on the WEIGHTS —
-docs/perf.md measured bf16 decode at ~91% of the bf16 HBM roofline, so the only
+Why this exists: bs=1 GPT-2 decode is HBM-bandwidth-bound on the WEIGHTS (the
+builders measured bf16 decode at ~91% of the bf16 HBM roofline on 2026-07-30;
+not measured since: no cell quantizes weights, PERF.md section 4), so the only
 route to faster tokens/sec is moving fewer bytes. Storing weights as int8 +
 per-output-channel f32 scales halves the bytes; the dequantize happens in VMEM
 inside the kernel (XLA cannot fuse a dequant into a dot operand — it

@@ -25,10 +25,11 @@ lengths cost O(log N) compiles.
 
 One serving path: every step program (``tnn_serve_decode``,
 ``tnn_serve_mixed_w<w>``, ``tnn_serve_spec_w<w>``) runs the model's
-``apply_decode_paged`` / ``apply_paged`` over the ragged paged-attention
+``apply_paged`` over the ragged paged-attention
 kernel (ops/pallas/paged_attention.py), which consumes the pool's pages and
-block tables directly: no cache is ever assembled. A model without those
-methods is refused at start-up. The pool buffers are DONATED through every
+block tables directly: no cache is ever assembled. A model without that
+method is refused at start-up. The pool's device arrays travel as ONE value
+(``PagedKVPool.cache``), DONATED through every
 jitted step, so XLA updates pages in place instead of copying the pool each
 token.
 
@@ -161,6 +162,16 @@ class _RowAhead(NamedTuple):
 
 
 _ON_HOST = -1
+
+
+class _LaunchFailed(Exception):
+    """The dispatch of a packed step raised (``InferenceEngine._launch``):
+    reads as its cause, and carries the step's PRNG key for the caller that
+    gives it back."""
+
+    def __init__(self, cause: Exception, key):
+        super().__init__(str(cause))
+        self.key = key
 
 
 def _stacked(counts) -> tuple:
@@ -525,9 +536,10 @@ class InferenceEngine:
         # the model the compiled step bodies trace: the head-sharded
         # adapter under TP (same interface, per-shard math), the
         # block-sharded adapter under SP, the model itself otherwise.
-        # Host-side math keeps reading self.model.
-        self._step_model = (self._tp.model if self._tp
-                            else self._sp.model if self._sp else model)
+        # Host-side math keeps reading self.model. ``_mesh`` is whichever
+        # context spans this engine's mesh (None at tp=sp=1)
+        self._mesh = self._tp or self._sp
+        self._step_model = self._mesh.model if self._mesh else model
         # compile-key suffix: int8 pools trace different step programs
         # (QuantPages operands), so their cache entries must never collide
         # with f32 ones; likewise tp>1 / sp>1 (shard_map bodies). The
@@ -546,10 +558,8 @@ class InferenceEngine:
         # window layers beside global layers: ONE layer of pages, each a
         # layer's of one of two groups (kv_pool: Two page groups)
         groups = getattr(model, "page_groups", None)
-        if self._tp is not None:
-            page_sharding = self._tp.page_sharding
-        elif self._sp is not None:
-            page_sharding = self._sp.page_sharding
+        if self._mesh is not None:
+            page_sharding = self._mesh.page_sharding
         elif device is not None:
             page_sharding = jax.sharding.SingleDeviceSharding(device)
         else:
@@ -629,14 +639,12 @@ class InferenceEngine:
         self.profiler = profiler
         self.metrics = ServingMetrics(profiler)
         self.tracer = Tracer(profiler if trace else None)
-        if self._tp is not None:
+        if self._mesh is not None:
             # every TP step dispatch records a serve.allreduce span (the
-            # 2-psum/layer collective cost is the TP tax worth watching)
-            self._tp.tracer = self.tracer
-        if self._sp is not None:
-            # likewise SP: a serve.spmerge span per dispatch (one
-            # online-softmax merge psum per layer is the SP tax)
-            self._sp.tracer = self.tracer
+            # 2-psum/layer collective cost is the TP tax worth watching),
+            # every SP one a serve.spmerge span (one online-softmax merge
+            # psum per layer is the SP tax)
+            self._mesh.tracer = self.tracer
         self.step_seq = 0                   # monotonically counts step() calls
         self._step_note: Optional[Dict[str, Any]] = None
         self._finished_note: Optional[Dict[str, Any]] = None
@@ -692,10 +700,10 @@ class InferenceEngine:
     @staticmethod
     def _probe_paged(model) -> None:
         """The start-up check of the one serving path: the step programs
-        call the model's paged methods and nothing else."""
-        if not hasattr(model, "apply_decode_paged"):
+        call the model's ``apply_paged`` and nothing else."""
+        if not hasattr(model, "apply_paged"):
             raise ValueError(
-                f"{type(model).__name__} has no apply_decode_paged: serving "
+                f"{type(model).__name__} has no apply_paged: serving "
                 "decodes straight against pool pages (see "
                 "nn/transformer.PagedDecoder)")
 
@@ -916,14 +924,6 @@ class InferenceEngine:
             req.state_slot, req.snap_at = 0, [None, None]
 
     # -- state slots (kv_pool: State slots) -----------------------------------
-
-    def _adopt_state(self, rest):
-        """A step program's results behind the pages: of a model with state
-        slots the first is the state arrays, adopted at dispatch like the
-        pages (``update_pages``); returns what is left (expert counters)."""
-        if self.pool.slots is not None:
-            self.pool.state, *rest = rest
-        return rest
 
     def _note_snapshots(self, rows, starts) -> None:
         """What a step just dispatched does to its rows' snapshots, by the
@@ -1359,10 +1359,7 @@ class InferenceEngine:
             t1 = time.perf_counter()
             with self.tracer.span("serve.launch", EventType.COMPUTE,
                                   step=step):
-                pool = self.pool
-                out = fn(self.params, pool.pages_k, pool.pages_v,
-                         *(() if pool.slots is None else (pool.state,)),
-                         *args)
+                out = fn(self.params, self.pool.cache, *args)
             t2 = time.perf_counter()
         self.metrics.observe_put(staged + t1 - t0)
         self.metrics.observe_launch(t2 - t1)
@@ -1427,15 +1424,6 @@ class InferenceEngine:
         return (dict(self._finished_note)
                 if self._finished_note is not None else None)
 
-    def _note_program(self, kind: str, key, rids: List[int],
-                      fill: float) -> None:
-        """Attach one launched compiled program to the current step's
-        flight record."""
-        if self._step_note is not None:
-            self._step_note["programs"].append(
-                {"kind": kind, "compile_key": list(key), "rids": list(rids),
-                 "fill": round(fill, 4)})
-
     def _sync_guard(self):
         """``jax.transfer_guard("disallow")`` under TNN_DEBUG_SYNC=1: every
         implicit host<->device transfer inside the step raises.  _put and
@@ -1450,10 +1438,8 @@ class InferenceEngine:
         Under TP/SP the put replicates onto the mesh — a committed
         single-device array cannot feed a jit whose other operands live on
         the mesh."""
-        if self._tp is not None:
-            return self._tp.put_replicated(np.asarray(x, dtype))
-        if self._sp is not None:
-            return self._sp.put_replicated(np.asarray(x, dtype))
+        if self._mesh is not None:
+            return self._mesh.put_replicated(np.asarray(x, dtype))
         return jax.device_put(np.asarray(x, dtype), self.device)
 
     def _put_tables(self, tables):
@@ -1475,36 +1461,28 @@ class InferenceEngine:
                                        self.pool.blocks_per_shard)
         return self._put(blk, dtype)
 
-    def _jit_step(self, name: str, fn, *, donate_argnums=(), n_outs: int = 4,
-                  pages_argnums=(1, 2), pages_out=None, params_argnum=0,
-                  tables_argnum=None):
-        """Compile a step body under a stable program ``name`` (the device
+    def _jit_step(self, name: str, fn, *, donate_argnums=()):
+        """Compile a program body under a stable program ``name`` (the device
         profile's ``XLA Modules`` line reads ``jit_<name>``): plain jit at
         tp=sp=1, shard_map over the TP or SP mesh
-        otherwise. The extra keyword arguments describe which
-        operands/outputs are the sharded page bundles and (under SP) which
-        operand is the stacked per-shard block table — plain jit and TP
-        ignore ``tables_argnum`` (TP tables are replicated)."""
+        otherwise. A mesh is told what an argument IS by the body's
+        parameter names, one convention for the step, copy-on-write and
+        adopt programs: ``params``, ``cache`` (the pool's arrays as one
+        donated pytree), under SP ``tables`` (the stacked per-shard block
+        table or block id), the rest replicated; the body returns the cache
+        between two groups of small results, ``(sampled, cache, counts)``
+        (``_step_program`` says why in that order), each of which may be
+        empty."""
         fn.__name__ = name
-        if self._sp is not None:
-            jitted = self._sp.jit_step(
-                fn, donate_argnums=donate_argnums, n_outs=n_outs,
-                pages_argnums=pages_argnums, pages_out=pages_out,
-                params_argnum=params_argnum, tables_argnum=tables_argnum)
-        elif self._tp is None:
-            jitted = jax.jit(fn, donate_argnums=donate_argnums)
-        else:
-            jitted = self._tp.jit_step(
-                fn, donate_argnums=donate_argnums, n_outs=n_outs,
-                pages_argnums=pages_argnums, pages_out=pages_out,
-                params_argnum=params_argnum)
+        jit = jax.jit if self._mesh is None else self._mesh.jit_step
+        jitted = jit(fn, donate_argnums=donate_argnums)
         self._program_names[jitted] = name
         return jitted
 
     def _build_step(self, flight: "StepInFlight") -> None:
         """The build/dispatch phase: everything up to and including the
-        jitted launches. Pool pages returned by each launch are adopted at
-        DISPATCH time (``update_pages``) so the donation chain stays valid
+        jitted launches. The cache each launch returns is adopted at
+        DISPATCH time (``_launch``) so the donation chain stays valid
         when another step is dispatched before this one's fetch."""
         events = flight.events
         if self.faults is not None:
@@ -1576,18 +1554,10 @@ class InferenceEngine:
         self._open_phase("serve.commit", "observe_commit")
         return out
 
-    def _commit_rec(self, rec: Dict[str, Any], out, events) -> None:
-        if rec["kind"] == "decode":
-            self._decode_commit(rec, out, events)
-        else:
-            self._mixed_commit(rec, out, events)
-
     def _abort_flight(self, flight: "StepInFlight", error: str) -> None:
         """Bundle-fetch failure: unattributable to one row, so every row
         the flight touched fails and the pool pages are recovered."""
-        rows: List[Request] = []
-        for rec in flight.recs:
-            rows.extend(rec.get("live") or rec.get("rows") or [])
+        rows = [req for rec in flight.recs for req in rec["rows"]]
         self._abort_batch(rows, error, flight.events)
 
     def _mark_dispatch(self) -> None:
@@ -1692,7 +1662,7 @@ class InferenceEngine:
         why that is not known. A step dispatched ahead starts from the
         prediction it was packed from (``rec["before"]``), a step that
         ``begin_step`` built from the committed state, which stands until
-        its commit. ``_decode_commit`` / ``_mixed_commit`` restated: a
+        its commit. ``_commit_rec`` restated: a
         decode row is one token on, its next token row i of the step's
         samples; a chunk row is its grant on, and at its prompt's end it
         decodes from row i too, or, resumed after a preemption, from the
@@ -1706,8 +1676,7 @@ class InferenceEngine:
             before = [_RowAhead(r, r.cache_len, r.num_generated,
                                 _ON_HOST if r.cache_len >= r.prefill_len
                                 else None) for r in self._running_rows()]
-        rows = rec.get("live") or rec["rows"]
-        n_dec, takes = rec.get("n_dec", len(rows)), rec.get("takes", {})
+        rows, n_dec, takes = rec["rows"], rec["n_dec"], rec["takes"]
         at = {row.req.rid: k for k, row in enumerate(before)}
         if any(req.rid not in at for req in rows):
             return "other"              # a row left while the step flew
@@ -1747,7 +1716,7 @@ class InferenceEngine:
         if [row.req for row in after] != self._running_rows():
             return "other"              # a row left while the chain flew
         pool = self.pool
-        chunked = prev.get("takes", ())     # the rows prev pushes a chunk of
+        chunked = prev["takes"]             # the rows prev pushes a chunk of
         lens: Dict[int, int] = {}
         for row in after:
             req, at = row.req, row.cache_len
@@ -1800,78 +1769,22 @@ class InferenceEngine:
         for i, row in enumerate(dec):
             if row.src != _ON_HOST:
                 idx[i], from_prev[i] = row.src, True
-        packed = dict(
-            b=b, nb=self.blocks_per_seq, scratch=PagedKVPool.SCRATCH,
-            kv_key=self._kv_key, sum_at=pool.exact_width, kinds=pool.kinds,
-            state=pool.slots is not None, lens=lens,
-            on_device={row.req.rid for row in dec if row.src != _ON_HOST})
-        t0 = time.perf_counter()
-        if len(dec) < len(rows):
-            takes = {req.rid: chunks[req.rid] for req in rows[len(dec):]}
-            step = step_build.pack_mixed(rows, len(dec), {}, takes,
-                                         spec_on=False, **packed)
-            self._check_step_writes(step, step.starts, step.q_lens)
-            label, qw = "mixed", step.qw
-            where = (step.starts, step.q_lens)
-            ends = step.starts + step.q_lens
-            rec = {"kind": "mixed", "rows": rows, "n_dec": len(dec),
-                   "takes": takes, "n_draft": step.n_draft, "n_spec": 0,
-                   "qw": qw}
-        else:
-            step = step_build.pack_decode(rows, **packed)
-            self._check_step_writes(step, step.offsets)
-            label, qw = "decode_paged", 1
-            where = (step.offsets,)
-            ends = step.offsets + 1
-            rec = {"kind": "decode", "live": rows}
-        key = step.key
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = self._jit[key] = (
-                self._mixed_paged_fn(b, qw, step.nb) if label == "mixed"
-                else self._paged_decode_fn(b, step.nb))
-            self._warm_splice(step.toks.shape)
-        prev_tok = prev["dev"][0]   # its predecessor's unfetched samples
-        step_key = self._step_key()
-
-        def stage():
-            if label != "mixed" and from_prev[:len(rows)].all() \
-                    and (idx == np.arange(b)).all():
-                toks = prev_tok     # behind a decode step: its samples, as is
-            else:
-                toks = self._put(step.toks)
-                if from_prev.any():
-                    toks = _splice_prev_tokens(
-                        toks, prev_tok, self._put(idx), self._put(from_prev))
-            return (toks, *map(self._put, where),
-                    self._put_tables(step.tables), self._put(step.temps),
-                    self._put(step.topks), self._put(step.topps), step_key,
-                    self._put(step.poison))
-
         try:
-            with self._sync_guard():
-                newtok, ok, pk, pv, *experts = self._dispatch(
-                    fn, label, step.temps, qw, stage, ahead=j)
-                experts = self._adopt_state(experts)
-        except Exception:  # noqa: BLE001 — speculation must never hurt
+            rec = self._launch(
+                "mixed" if len(dec) < len(rows) else "decode", rows, len(dec),
+                {req.rid: chunks[req.rid] for req in rows[len(dec):]},
+                lens=lens, splice=(prev["dev"][0], idx, from_prev), ahead=j)
+        except _LaunchFailed as e:  # speculation must never hurt
             self._unextend(rollback)
-            self._reuse_keys.insert(0, step_key)
+            self._reuse_keys.insert(0, e.key)
             self._recover_pages_if_dead(flight.events)
             return "other"
-        pool.update_pages(pk, pv)
-        self._note_snapshots(rows, where[0])
-        self._observe_attention(rows, ends, qw,
-                                step.q_lens if label == "mixed" else None)
-        rec.update(dev=(newtok, ok, *experts), t0=t0, b=b, before=after)
+        rec["before"] = after
         flight.ahead.append({
-            "rec": rec, "rollback": rollback, "key": step_key,
+            "rec": rec, "rollback": rollback,
             # what its adoption holds the committed rows to: each one's
             # length, and with it whether it decodes or pushes its prompt
-            "offsets": lens, "decoding": {row.req.rid for row in dec},
-            "prog": {"kind": label, "compile_key": list(key),
-                     "rids": [r.rid for r in rows],
-                     "fill": round(len(rows) / b, 4)},
-        })
+            "offsets": lens, "decoding": {row.req.rid for row in dec}})
         return ""
 
     def _warm_splice(self, shape) -> None:
@@ -1936,7 +1849,7 @@ class InferenceEngine:
                 self._unextend(s["rollback"], only_intact=True)
             if self.pool.slots is not None:
                 self._restore_rows(running)
-            self._reuse_keys[:0] = [s["key"] for s in ahead]
+            self._reuse_keys[:0] = [s["rec"]["key"] for s in ahead]
             self._adopted_run = 0
             self.metrics.observe_overlap_rebuild()
             # no step to carry them: they end with the step that committed
@@ -1952,7 +1865,7 @@ class InferenceEngine:
             "step_seq": self.step_seq,
             "queued": queued,
             "running_rids": [r.rid for r in running],
-            "programs": [dict(spec["prog"])],
+            "programs": [dict(rec["prog"])],
             "speculative": True,
         }
         self._step_note = note
@@ -2073,12 +1986,10 @@ class InferenceEngine:
 
     def _next_key(self):
         self._key, sub = jax.random.split(self._key)
-        if self._tp is not None:
+        if self._mesh is not None:
             # jax.random.split runs on the default device; replicate the
             # subkey onto the mesh before it feeds a sharded step
-            sub = self._tp.put_replicated(sub)
-        elif self._sp is not None:
-            sub = self._sp.put_replicated(sub)
+            sub = self._mesh.put_replicated(sub)
         return sub
 
     def _admit_chunked(self, req: Request, events) -> bool:
@@ -2109,32 +2020,24 @@ class InferenceEngine:
         return True
 
     def _cow_copy_fn(self):
-        if self._sp is not None:
+        def fn(cache, src, dst):
+            # kv_pool.copy_blocks: under int8 the scale sidecar clones with
+            # its pages, so the COW block dequantizes identically
+            pages_k, pages_v = cache
+            return (), (kv_pool_lib.copy_blocks(pages_k, src, dst),
+                        kv_pool_lib.copy_blocks(pages_v, src, dst)), ()
+
+        def sp_fn(cache, tables):
             # the clone was allocated on the SOURCE block's shard
             # (_match_prefix), so the copy is shard-local: the owner sees
             # (src_local, dst_local), every other shard sees (-1, -1) ->
             # clamped to its scratch page, a harmless identity write
-            def sp_fn(pages_k, pages_v, pair):
-                src = jnp.maximum(pair[0, 0], 0)
-                dst = jnp.maximum(pair[0, 1], 0)
-                return (kv_pool_lib.copy_blocks(pages_k, src, dst),
-                        kv_pool_lib.copy_blocks(pages_v, src, dst))
-
-            return self._jit_step("tnn_kv_cow", sp_fn, donate_argnums=(0, 1),
-                                  n_outs=2, pages_argnums=(0, 1),
-                                  pages_out=(0, 1), params_argnum=None,
-                                  tables_argnum=2)
-
-        def fn(pages_k, pages_v, src, dst):
-            # kv_pool.copy_blocks: under int8 the scale sidecar clones with
-            # its pages, so the COW block dequantizes identically
-            return (kv_pool_lib.copy_blocks(pages_k, src, dst),
-                    kv_pool_lib.copy_blocks(pages_v, src, dst))
+            return fn(cache, jnp.maximum(tables[0, 0], 0),
+                      jnp.maximum(tables[0, 1], 0))
 
         # donated + traced src/dst: one compile, in-place block copy
-        return self._jit_step("tnn_kv_cow", fn, donate_argnums=(0, 1),
-                              n_outs=2, pages_argnums=(0, 1), pages_out=(0, 1),
-                              params_argnum=None)
+        return self._jit_step("tnn_kv_cow", fn if self._sp is None else sp_fn,
+                              donate_argnums=(0,))
 
     def _demote_blocks(self, blocks: List[int]) -> None:
         """``pool.demote_hook``: salvage reclaimed-but-indexed blocks to
@@ -2159,31 +2062,24 @@ class InferenceEngine:
                                     tier_bytes=self.kv_tier.bytes_used)
 
     def _tier_adopt_fn(self):
-        if self._sp is not None:
-            # handoff adopt under SP: ``blk`` arrives as the per-shard
+        def fn(cache, blk, payload):
+            # kv_pool.write_block: under int8 the payload is a QuantPages
+            # of slices, so data and scales re-adopt together
+            (pages_k, pages_v), (payload_k, payload_v) = cache, payload
+            return (), (kv_pool_lib.write_block(pages_k, blk, payload_k),
+                        kv_pool_lib.write_block(pages_v, blk, payload_v)), ()
+
+        def sp_fn(cache, tables, payload):
+            # handoff adopt under SP: the block id arrives as the per-shard
             # (1, 1) local view (_put_block_id) — the owner writes the
             # replicated payload into its row, every other shard writes it
             # into its scratch page (garbage-by-contract, never read)
-            def sp_fn(pages_k, pages_v, blk, payload_k, payload_v):
-                b = jnp.maximum(blk[0, 0], 0)
-                return (kv_pool_lib.write_block(pages_k, b, payload_k),
-                        kv_pool_lib.write_block(pages_v, b, payload_v))
-
-            return self._jit_step("tnn_tier_adopt", sp_fn,
-                                  donate_argnums=(0, 1), n_outs=2,
-                                  pages_argnums=(0, 1), pages_out=(0, 1),
-                                  params_argnum=None, tables_argnum=2)
-
-        def fn(pages_k, pages_v, blk, payload_k, payload_v):
-            # kv_pool.write_block: under int8 the payload is a QuantPages
-            # of slices, so data and scales re-adopt together
-            return (kv_pool_lib.write_block(pages_k, blk, payload_k),
-                    kv_pool_lib.write_block(pages_v, blk, payload_v))
+            return fn(cache, jnp.maximum(tables[0, 0], 0), payload)
 
         # donated pages + traced block id: one compile serves every readmit
-        return self._jit_step("tnn_tier_adopt", fn, donate_argnums=(0, 1),
-                              n_outs=2, pages_argnums=(0, 1), pages_out=(0, 1),
-                              params_argnum=None)
+        return self._jit_step("tnn_tier_adopt",
+                              fn if self._sp is None else sp_fn,
+                              donate_argnums=(0,))
 
     def _tier_payload(self, leaves):
         """Demoted host leaves -> device payloads for the adopt fn:
@@ -2465,8 +2361,7 @@ class InferenceEngine:
             else:
                 tail = (self._put(blocks[-1], jnp.int32),
                         self._put(copy[0], jnp.int32))
-            pk, pv = fn(self.pool.pages_k, self.pool.pages_v, *tail)
-            self.pool.update_pages(pk, pv)
+            _, self.pool.cache, _ = fn(self.pool.cache, *tail)
             table = table + copy
             self.metrics.observe_prefix_cow()
         req.block_table = table
@@ -2474,23 +2369,15 @@ class InferenceEngine:
 
     # -- decode ---------------------------------------------------------------
 
-    def _ensure_decode_capacity(self, events: Dict[str, List]) -> None:
-        """Every running request must own the block its next token writes to;
-        preempt (LIFO) when the pool runs dry. A victim that already spent
-        its ``preemption_budget`` FAILs instead of requeueing — its freed
-        blocks break the two-large-requests livelock; and an allocation that
-        still fails (injected fault) FAILs only the requesting row."""
-        for req in list(self.scheduler.running):
-            if req.state is not RequestState.RUNNING:
-                continue
-            self._grow_blocks(req, 1, events, chunk=False)
-
     def _grow_blocks(self, req: Request, new_tokens: int, events,
                      *, chunk: bool) -> bool:
         """Grow ``req.block_table`` to cover ``cache_len + new_tokens``
         positions, preempting (LIFO) when the pool runs dry. Returns True
         when the row still runs this step; False when it was preempted,
-        budget-FAILed, or hit an allocation fault — a chunk-boundary alloc
+        budget-FAILed (a victim that already spent its
+        ``preemption_budget`` FAILs instead of requeueing: its freed blocks
+        break the two-large-requests livelock), or hit an allocation fault
+        — a chunk-boundary alloc
         failure fails ONLY this request (``chunk=True`` also routes the
         prefill fault-injection site at the boundary)."""
         need = self._grow_need(req, req.cache_len, new_tokens)
@@ -2568,14 +2455,12 @@ class InferenceEngine:
             d = self.drafter.draft(req, k)
             if not isinstance(d, spec_decode.DeviceDraft):
                 d = [int(t) % vocab for t in d][:k]
-            elif self._tp is not None:
+            elif self._mesh is not None:
                 # the drafter runs single-device; replicate its tokens onto
-                # the TP mesh so the poison shift and the splice below mix
-                # only mesh-resident arrays
-                d = spec_decode.DeviceDraft(self._tp.put_replicated(d.toks))
-            elif self._sp is not None:
-                # same single-device drafter, context mesh instead
-                d = spec_decode.DeviceDraft(self._sp.put_replicated(d.toks))
+                # the TP or SP mesh so the poison shift and the splice below
+                # mix only mesh-resident arrays
+                d = spec_decode.DeviceDraft(
+                    self._mesh.put_replicated(d.toks))
             if not len(d):
                 continue
             if self.faults is not None and self.faults.poison_draft():
@@ -2593,7 +2478,7 @@ class InferenceEngine:
         row takes 1 token and every mid-prefill row with a chunk grant
         pushes its next prompt chunk, all inside ONE compiled program keyed
         on the power-of-two bucket of the widest chunk. Steps with no chunk
-        work run the pure-decode program. ``_mixed_commit`` consumes the
+        work run the pure-decode program. ``_commit_rec`` consumes the
         launch's fetched bundle.
 
         With a drafter installed, decode rows additionally carry their
@@ -2604,17 +2489,6 @@ class InferenceEngine:
         events = flight.events
         t0 = time.perf_counter()
         spec_on = self.drafter is not None
-        has_chunks = any(
-            r.rid in chunks and r.state is RequestState.RUNNING
-            and r.cache_len < r.prefill_len for r in self.scheduler.running)
-        if not has_chunks and not spec_on:
-            self._ensure_decode_capacity(events)
-            live = self._running_rows()
-            if live:
-                rec = self._decode_build(live, events)
-                if rec is not None:
-                    flight.recs.append(rec)
-            return
         # drafts are proposed BEFORE the capacity pass so decode rows can
         # reserve KV headroom for every drafted position up front
         drafts = self._propose_drafts() if spec_on else {}
@@ -2648,117 +2522,175 @@ class InferenceEngine:
         dec = [r for r in live if r.cache_len >= r.prefill_len]
         chk = [(r, chunks[r.rid]) for r in live
                if r.cache_len < r.prefill_len and r.rid in chunks]
-        n_spec = sum(len(drafts.get(r.rid, ())) for r in dec)
-        if not chk and not n_spec:
-            # nothing ragged this step: the pure-decode program is
-            # bit-identical and cheaper. Zero-draft rows still count in the
-            # spec denominator so acceptance stats stay honest.
-            if dec:
-                rec = self._decode_build(dec, events)
-                if rec is not None:
-                    if spec_on:
-                        rec["spec_rows"] = len(dec)
-                    flight.recs.append(rec)
-            return
         rows = dec + [r for r, _ in chk]
-        takes = {r.rid: t for r, t in chk}
+        if not rows:
+            return
+        kind = "spec" if spec_on else "mixed"
+        if not chk and not any(drafts.get(r.rid) for r in dec):
+            # nothing ragged this step: the pure-decode program is
+            # bit-identical and cheaper (its clock starts here, behind the
+            # capacity pass)
+            kind, t0 = "decode", None
+        try:
+            rec = self._launch(kind, rows, len(dec),
+                               {r.rid: t for r, t in chk}, drafts, t0=t0)
+        except _LaunchFailed as e:
+            # a real step failure may have consumed the donated pages:
+            # unattributable, so the live batch aborts but the engine
+            # survives for queued work
+            self._abort_batch(rows, f"decode step failed: {e}", events)
+            return
+        if kind == "decode" and spec_on:
+            rec["spec_rows"] = len(dec)     # the commit counts them
+        flight.recs.append(rec)
+
+    def _launch(self, kind: str, rows: List[Request], n_dec: int,
+                takes: Dict[int, int], drafts=None, *,
+                t0: Optional[float] = None, lens=None, splice=None,
+                ahead: int = 0) -> Dict[str, Any]:
+        """Pack ONE step and send it to the device; returns the record
+        ``_commit_rec`` consumes. ``rows``: the ``n_dec`` decode rows (a
+        token each, and their ``drafts``), then the rows that push
+        ``takes[rid]`` tokens of their prompt; ``kind``: "decode" (no chunk,
+        no draft: the decode program), "mixed" or "spec". In order: pack,
+        the fault plan's poison, the step's note, the program from
+        ``self._jit`` (or made, and its splice warmed), the token matrix of
+        a synchronous mixed step, the step's key, the launch (retried once
+        on a transient injected fault), the cache back into the pool at
+        DISPATCH time, snapshots, attention counters, the record.
+
+        A step dispatched ``ahead`` of its predecessors' commits
+        (``_dispatch_ahead``) packs its rows at the predicted ``lens`` and
+        finishes its tokens on the device from ``splice`` = (the
+        predecessor's unfetched samples, ``idx``, ``from_prev``:
+        ``_splice_prev_tokens``); it leaves the step's note and the host gap
+        alone. A launch that raises comes out as ``_LaunchFailed`` with the
+        step's key: what to do about it is the caller's."""
+        if t0 is None:
+            t0 = time.perf_counter()
+        pool, spec_on = self.pool, kind == "spec"
         # pure host-side packing (compile-width bucketing, row layout,
         # compile key) lives in step_build; fault poisoning and dispatch
         # stay here with the rest of the device state
-        step = step_build.pack_mixed(
-            rows, len(dec), drafts, takes,
+        packed = dict(
             b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
-            scratch=PagedKVPool.SCRATCH, spec_on=spec_on,
-            kv_key=self._kv_key, sum_at=self.pool.exact_width,
-            kinds=self.pool.kinds, state=self.pool.slots is not None)
-        self._check_step_writes(step, step.starts, step.q_lens)
+            scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
+            sum_at=pool.exact_width, kinds=pool.kinds,
+            state=pool.slots is not None, lens=lens,
+            on_device=() if splice is None else
+            {rows[i].rid for i in np.flatnonzero(splice[2])})
+        if kind == "decode":
+            step = step_build.pack_decode(rows, **packed)
+        else:
+            step = step_build.pack_mixed(rows, n_dec, drafts or {}, takes,
+                                         spec_on=spec_on, **packed)
+        self._check_step_writes(step)
         b, qw, poison = step.b, step.qw, step.poison
         if self.faults is not None:
-            if dec:
-                poison[:len(dec)][self.faults.poison_rows(len(dec))] = np.nan
-            for i in range(len(dec), len(rows)):
+            if n_dec:
+                poison[:n_dec][self.faults.poison_rows(n_dec)] = np.nan
+            for i in range(n_dec, len(rows)):
                 if self.faults.poison_prefill():
                     poison[i] = np.nan
-        key = step.key
-        self._note_program("spec" if spec_on else "mixed", key,
-                           [r.rid for r in rows], fill=len(rows) / b)
-        fn = self._jit.get(key)
+        # the launched program in the step's flight record (a step
+        # dispatched ahead gets its note at adoption)
+        label = "decode_paged" if kind == "decode" else kind
+        prog = {"kind": label, "compile_key": list(step.key),
+                "rids": [r.rid for r in rows], "fill": round(len(rows) / b, 4)}
+        if not ahead and self._step_note is not None:
+            self._step_note["programs"].append(prog)
+        fn = self._jit.get(step.key)
         if fn is None:
-            fn = self._jit[key] = (
-                self._spec_paged_fn(b, qw, step.nb) if spec_on
-                else self._mixed_paged_fn(b, qw, step.nb))
+            fn = self._jit[step.key] = self._step_program(
+                None if kind == "decode" else qw, spec_on)
             if not spec_on:
                 self._warm_splice(step.toks.shape)
-        # the token matrix is staged here, inside serve.build, because the
-        # commit wants it back (``rec["dev"]``): a serve.put of its own,
-        # counted into the step's put time at its launch
-        t_put = time.perf_counter()
-        with self.tracer.span("serve.put", step=self.step_seq):
-            toks_in = self._put(step.toks)
-            for i, dd in step.dev_drafts:
-                # splice device-resident drafts into the token matrix
-                # without fetching them. The commit reads draft VALUES back
-                # from the fetched token matrix, so host and device drafts
-                # commit identically. Under TP/SP the draft tensor (produced
-                # on the drafter's single device) replicates onto the mesh
-                # first — a device-to-device transfer, no host sync.
-                mesh = self._tp if self._tp is not None else self._sp
-                draft_toks = dd.toks if mesh is None \
-                    else mesh.put_replicated(dd.toks)
-                toks_in = _splice_draft_row(toks_in, draft_toks[None, :],
-                                            self._put(i, jnp.int32))
-        self._put_pending = time.perf_counter() - t_put
+        toks = None
+        if kind != "decode" and not ahead:
+            # the token matrix is staged here, inside serve.build, because
+            # the commit wants it back (``rec["dev"]``): a serve.put of its
+            # own, counted into the step's put time at its launch
+            t_put = time.perf_counter()
+            with self.tracer.span("serve.put", step=self.step_seq):
+                toks = self._put(step.toks)
+                for i, dd in step.dev_drafts:
+                    # splice device-resident drafts into the token matrix
+                    # without fetching them. The commit reads draft VALUES
+                    # back from the fetched token matrix, so host and device
+                    # drafts commit identically. Under TP/SP the draft tensor
+                    # (produced on the drafter's single device) replicates
+                    # onto the mesh first — a device-to-device transfer, no
+                    # host sync.
+                    draft_toks = dd.toks if self._mesh is None \
+                        else self._mesh.put_replicated(dd.toks)
+                    toks = _splice_draft_row(toks, draft_toks[None, :],
+                                             self._put(i, jnp.int32))
+            self._put_pending = time.perf_counter() - t_put
         # one key per STEP (held across the retry): a transient fault retried
         # with the same key reproduces the fault-free step bit-for-bit
         step_key = self._step_key()
-        self._mark_dispatch()
-        experts = ()
+        if not ahead:
+            self._mark_dispatch()
+
+        def staged_toks():
+            if toks is not None:
+                return toks
+            if splice is None:
+                return self._put(step.toks)
+            prev, idx, from_prev = splice
+            if kind == "decode" and from_prev[:len(rows)].all() \
+                    and (idx == np.arange(b)).all():
+                return prev         # behind a decode step: its samples, as is
+            dev = self._put(step.toks)
+            if from_prev.any():
+                dev = _splice_prev_tokens(dev, prev, self._put(idx),
+                                          self._put(from_prev))
+            return dev
+
+        def stage():
+            # a speculative program takes its rows' draft counts too
+            return (staged_toks(), self._put(step.starts),
+                    None if step.q_lens is None else self._put(step.q_lens),
+                    self._put_tables(step.tables),
+                    *((self._put(step.n_draft),) if spec_on else ()),
+                    self._put(step.temps), self._put(step.topks),
+                    self._put(step.topps), step_key, self._put(poison))
+
         for attempt in (0, 1):
             try:
                 if self.faults is not None:
                     self.faults.on_decode()
-                # a speculative program takes its rows' draft counts too
-                out = self._dispatch(
-                    fn, "spec" if spec_on else "mixed", step.temps, qw,
-                    lambda: (
-                        toks_in, self._put(step.starts),
-                        self._put(step.q_lens), self._put_tables(step.tables),
-                        *((self._put(step.n_draft),) if spec_on else ()),
-                        self._put(step.temps), self._put(step.topks),
-                        self._put(step.topps), step_key, self._put(poison)))
-                if spec_on:
-                    accepts, newtok, ok, pk, pv = out
-                else:
-                    newtok, ok, pk, pv, *experts = out
-                    experts = self._adopt_state(experts)
+                with self._sync_guard():
+                    sampled, cache, counts = self._dispatch(
+                        fn, label, step.temps, qw, stage, ahead=ahead)
                 break
-            except FaultInjected as e:
+            except Exception as e:  # noqa: BLE001 — the caller isolates it
                 # injected pre-call: donated buffers untouched, retryable
-                if attempt == 0 and e.transient:
+                if attempt == 0 and isinstance(e, FaultInjected) \
+                        and e.transient:
                     self.metrics.observe_step_retry()
                     continue
-                self._abort_batch(rows, f"decode step failed: {e}", events)
-                return
-            except Exception as e:  # noqa: BLE001 — isolate, don't crash
-                self._abort_batch(rows, f"decode step failed: {e}", events)
-                return
-        self.pool.update_pages(pk, pv)
+                raise _LaunchFailed(e, step_key) from e
+        pool.cache = cache
         self._note_snapshots(rows, step.starts)
-        self._observe_attention(rows, step.starts + step.q_lens, qw,
-                                step.q_lens)
-        flight.recs.append({
-            "kind": "spec" if spec_on else "mixed",
-            "dev": ((accepts, newtok, ok, toks_in) if spec_on
-                    else (newtok, ok, *experts)),
-            "rows": rows, "n_dec": len(dec), "takes": takes,
-            "n_draft": step.n_draft, "n_spec": n_spec, "t0": t0, "b": b,
-            "qw": qw})
+        self._observe_attention(
+            rows, step.starts + (1 if step.q_lens is None else step.q_lens),
+            qw, step.q_lens)
+        n_draft = getattr(step, "n_draft", None)    # a decode step has none
+        return {"kind": kind,
+                "dev": (*sampled, toks) if spec_on else (*sampled, *counts),
+                "rows": list(rows), "n_dec": n_dec, "takes": takes,
+                "n_draft": n_draft,
+                "n_spec": 0 if n_draft is None else int(n_draft.sum()),
+                "t0": t0, "b": b, "qw": qw, "key": step_key, "prog": prog}
 
-    def _mixed_commit(self, rec: Dict[str, Any], out, events) -> None:
-        """Mixed/spec step commit half: consumes the fetched bundle —
+    def _commit_rec(self, rec: Dict[str, Any], out, events) -> None:
+        """A launched step's commit half, whatever its kind (a decode
+        step's record is a mixed step's with every row a decode row and no
+        ``takes``): consumes the fetched bundle —
         ``(accepts, newtok, ok, token_matrix)`` for spec steps (the token
         matrix carries the drafted values back, so device drafts never
-        synced), ``(newtok, ok)`` otherwise."""
+        synced), ``(newtok, ok)`` and the expert counters otherwise."""
         spec_on = rec["kind"] == "spec"
         if spec_on:
             accepts, newtok, ok, toks_f = out
@@ -2777,6 +2709,9 @@ class InferenceEngine:
             if req.state in TERMINAL_STATES:
                 continue                # cancelled/expired while in flight
             if self.logit_guard and not bool(ok[i]):
+                # poisoned row: only this request fails — its sampled token
+                # is garbage and its KV blocks are freed; the other rows'
+                # tokens in this very batch remain valid
                 self._terminate(
                     req, RequestState.FAILED,
                     "non-finite logits in decode step" if i < n_dec
@@ -2861,51 +2796,78 @@ class InferenceEngine:
                                 rid=req.rid, step=self.step_seq)
             events["tokens"].append((req.rid, tok))
             self._maybe_finish(req, tok, events)
-        self.metrics.observe_mixed_step(
-            n_dec + rec["n_spec"] + sum(takes.values()),
-            rec["b"] * rec["qw"])
+        if rec["kind"] != "decode":
+            self.metrics.observe_mixed_step(
+                n_dec + rec["n_spec"] + sum(takes.values()),
+                rec["b"] * rec["qw"])
         if n_dec:
             self._mark_decode_emit()
             self.metrics.observe_decode(
                 n_committed if spec_on else n_dec,
                 time.perf_counter() - rec["t0"], rec["b"])
+        if rec.get("spec_rows"):
+            # a spec-enabled step that proposed zero drafts ran the plain
+            # decode program; its rows still count in the acceptance
+            # denominator so spec stats stay honest
+            self.metrics.observe_spec(0, 0, n_committed,
+                                      rows=rec["spec_rows"])
 
-    def _mixed_paged_fn(self, b: int, qw: int, nb: int):
+    def _step_program(self, qw: Optional[int], spec: bool = False):
+        """The engine's step programs: ``tnn_serve_decode`` (``qw`` None)
+        and ``tnn_serve_mixed_w<qw>`` are ONE body, the decode program that
+        body at one token a row (``q_lens`` None); ``tnn_serve_spec_w<qw>``
+        has its own, for it judges every position. One signature: the
+        params, the pool's ``cache`` (the ONE donated argument), the packed
+        step's arrays, the sampling parameters, the step's key, the chaos
+        plan's poison; the result is ``(sampled, cache, counts)``: the
+        sampled tokens and their ``ok`` (a spec step's ``accepts`` before
+        them), the cache, the expert counters (``_stacked``: none for a
+        model with no expert layer). The leaves stand in the order they have
+        had since the state and the counters came (PR 32, PR 44): the
+        compiler's schedule follows the ORDER of a program's results, and a
+        decode program with the cache first ran 0.1-0.4% slower in three
+        closed cells (PERF.md section 6, PR 48). ``sampled`` and ``counts``
+        are what the step's ONE fetch brings to the host."""
         model = self._step_model
 
-        def with_state(params, pages_k, pages_v, state, toks, starts, q_lens,
-                       tables, t, k, p, key, poison):
-            # ``fn`` below for a model with state slots: the state arrays
-            # ride beside the pages, donated in and returned; the head runs
-            # on each row's last live position alone
-            with moe_lib.collect_counts() as counts:
-                logits, pages_k, pages_v, state = model.apply_paged(
-                    params, toks, pages_k, pages_v, tables, starts, q_lens,
-                    state=state, head_at=jnp.maximum(q_lens - 1, 0))
-            newtok, ok = _sampled(logits[:, 0], poison, key, t, k, p)
-            return (newtok, ok, pages_k, pages_v, state) + _stacked(counts)
-
-        if self.pool.slots is not None:
-            return self._jit_step(f"tnn_serve_mixed_w{qw}", with_state,
-                                  donate_argnums=(1, 2, 3), n_outs=5,
-                                  tables_argnum=7)
-
-        def fn(params, pages_k, pages_v, toks, starts, q_lens, tables,
-               t, k, p, key, poison):
+        def fn(params, cache, toks, starts, q_lens, tables, t, k, p, key,
+               poison):
             # the ragged paged-attention kernel takes decode rows (q_len 1)
             # and prompt chunks (q_len up to qw) in the same launch; dead
-            # tokens scatter their KV to the scratch page and are masked
+            # tokens scatter their KV to the scratch page and are masked.
+            # No assembled cache: the model scatters each layer's new rows
+            # into their pages and the kernel streams KV via the block
+            # tables — per-step pool traffic is the row writes plus the KV
+            # actually attended over. A model's state slots ride in the
+            # cache beside the pages. The head runs on each row's last live
+            # position alone: no (B, qw, V) cube of a wide step
+            pages_k, pages_v, *state = cache
+            decode = q_lens is None
             with moe_lib.collect_counts() as counts:
-                logits, pages_k, pages_v = model.apply_paged(
-                    params, toks, pages_k, pages_v, tables, starts, q_lens)
-            last = jnp.take_along_axis(
-                logits, jnp.maximum(q_lens - 1, 0)[:, None, None],
-                axis=1)[:, 0]                                   # (B, V)
-            newtok, ok = _sampled(last, poison, key, t, k, p)
-            return (newtok, ok, pages_k, pages_v) + _stacked(counts)
+                logits, *cache = model.apply_paged(
+                    params, toks[:, None] if decode else toks, pages_k,
+                    pages_v, tables, starts, q_lens,
+                    state=state[0] if state else None,
+                    head_at=None if decode else jnp.maximum(q_lens - 1, 0))
+            newtok, ok = _sampled(logits[:, 0], poison, key, t, k, p)
+            return (newtok, ok), tuple(cache), _stacked(counts)
 
-        return self._jit_step(f"tnn_serve_mixed_w{qw}", fn,
-                              donate_argnums=(1, 2), n_outs=4, tables_argnum=6)
+        def spec_fn(params, cache, toks, starts, q_lens, tables, n_draft,
+                    t, k, p, key, poison):
+            # the same ragged launch as the plain mixed step, but the FULL
+            # (B, Q, V) logits cube feeds verification — every drafted
+            # position is judged inside the one program
+            logits, *cache = model.apply_paged(params, toks, *cache, tables,
+                                               starts, q_lens)
+            return self._spec_verify(logits, toks, q_lens, n_draft, t, k, p,
+                                     key, poison), tuple(cache), ()
+
+        if spec:
+            return self._jit_step(f"tnn_serve_spec_w{qw}", spec_fn,
+                                  donate_argnums=(1,))
+        return self._jit_step(
+            "tnn_serve_decode" if qw is None else f"tnn_serve_mixed_w{qw}",
+            fn, donate_argnums=(1,))
 
     # -- speculative verification ----------------------------------------------
 
@@ -2966,24 +2928,6 @@ class InferenceEngine:
                            jnp.argmax(sel, axis=-1))
         return accepts, newtok, ok
 
-    def _spec_paged_fn(self, b: int, qw: int, nb: int):
-        model = self._step_model
-        verify = self._spec_verify
-
-        def fn(params, pages_k, pages_v, toks, starts, q_lens, tables,
-               n_draft, t, k, p, key, poison):
-            # the same ragged launch as the plain mixed step, but the FULL
-            # (B, Q, V) logits cube feeds verification — every drafted
-            # position is judged inside the one program
-            logits, pages_k, pages_v = model.apply_paged(
-                params, toks, pages_k, pages_v, tables, starts, q_lens)
-            accepts, newtok, ok = verify(logits, toks, q_lens, n_draft,
-                                         t, k, p, key, poison)
-            return accepts, newtok, ok, pages_k, pages_v
-
-        return self._jit_step(f"tnn_serve_spec_w{qw}", fn,
-                              donate_argnums=(1, 2), n_outs=5, tables_argnum=6)
-
     def _preempt(self, req: Request) -> None:
         self._note_leave_running(req, time.perf_counter())
         self._free_blocks(req)
@@ -2993,40 +2937,7 @@ class InferenceEngine:
         self.tracer.instant("serve.preempt", trace=req.trace_id,
                             rid=req.rid, step=self.step_seq)
 
-    def _paged_decode_fn(self, batch: int, nb: int):
-        model = self._step_model
-
-        def with_state(params, pages_k, pages_v, state, toks, offsets,
-                       tables, t, k, p, key, poison):
-            # ``fn`` below for a model with state slots
-            with moe_lib.collect_counts() as counts:
-                logits, pages_k, pages_v, state = model.apply_decode_paged(
-                    params, toks, pages_k, pages_v, tables, offsets,
-                    state=state)
-            newtok, ok = _sampled(logits, poison, key, t, k, p)
-            return (newtok, ok, pages_k, pages_v, state) + _stacked(counts)
-
-        if self.pool.slots is not None:
-            return self._jit_step("tnn_serve_decode", with_state,
-                                  donate_argnums=(1, 2, 3), n_outs=5,
-                                  tables_argnum=6)
-
-        def fn(params, pages_k, pages_v, toks, offsets, tables, t, k, p, key,
-               poison):
-            # no assembled cache: the model scatters each
-            # layer's new row into its page and the paged-attention kernel
-            # streams KV via the block tables — per-step pool traffic is B
-            # row writes plus the KV actually attended over
-            with moe_lib.collect_counts() as counts:
-                logits, pages_k, pages_v = model.apply_decode_paged(
-                    params, toks, pages_k, pages_v, tables, offsets)
-            newtok, ok = _sampled(logits, poison, key, t, k, p)
-            return (newtok, ok, pages_k, pages_v) + _stacked(counts)
-
-        return self._jit_step("tnn_serve_decode", fn, donate_argnums=(1, 2),
-                              n_outs=4, tables_argnum=5)
-
-    def _check_step_writes(self, step, starts, q_lens=None) -> None:
+    def _check_step_writes(self, step) -> None:
         """TNN_POOL_DEBUG=1: hold every packed step to the one-writer
         invariant the in-place page write relies on (``q_lens`` None: the
         decode form, one token a row)."""
@@ -3035,102 +2946,8 @@ class InferenceEngine:
             tables = step.tables if self.pool.slots is None \
                 else step.tables[:, :-1]
             self.pool.check_step_writes(
-                tables, starts,
-                np.ones_like(starts) if q_lens is None else q_lens)
-
-    def _decode_build(self, live: Sequence[Request],
-                      events) -> Optional[Dict[str, Any]]:
-        """Pure-decode build/dispatch half: stage the batch, launch the
-        decode program, adopt its pages. Returns the flight
-        record ``_decode_commit`` consumes — or None when the batch
-        aborted."""
-        t0 = time.perf_counter()
-        step = step_build.pack_decode(
-            live, b=self.scheduler.max_batch_size, nb=self.blocks_per_seq,
-            scratch=PagedKVPool.SCRATCH, kv_key=self._kv_key,
-            sum_at=self.pool.exact_width,
-            kinds=self.pool.kinds, state=self.pool.slots is not None)
-        self._check_step_writes(step, step.offsets)
-        b, nb, key = step.b, step.nb, step.key
-        poison = step.poison
-        if self.faults is not None:
-            poison[:len(live)][self.faults.poison_rows(len(live))] = np.nan
-        label = "decode_paged"
-        self._note_program(label, key, [r.rid for r in live],
-                           fill=len(live) / b)
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = self._jit[key] = self._paged_decode_fn(b, nb)
-            self._warm_splice(step.toks.shape)
-        # one key per STEP (held across the retry): a transient fault retried
-        # with the same key reproduces the fault-free step bit-for-bit
-        step_key = self._step_key()
-        self._mark_dispatch()
-        for attempt in (0, 1):
-            try:
-                if self.faults is not None:
-                    self.faults.on_decode()
-                newtok, ok, pk, pv, *experts = self._dispatch(
-                    fn, label, step.temps, 1, lambda: (
-                        self._put(step.toks), self._put(step.offsets),
-                        self._put_tables(step.tables), self._put(step.temps),
-                        self._put(step.topks), self._put(step.topps),
-                        step_key, self._put(poison)))
-                experts = self._adopt_state(experts)
-                break
-            except FaultInjected as e:
-                # injected pre-call: donated buffers untouched, retryable
-                if attempt == 0 and e.transient:
-                    self.metrics.observe_step_retry()
-                    continue
-                self._abort_batch(live, f"decode step failed: {e}", events)
-                return None
-            except Exception as e:  # noqa: BLE001 — a real step failure may
-                # have consumed the donated pages: unattributable, so the
-                # live batch aborts but the engine survives for queued work
-                self._abort_batch(live, f"decode step failed: {e}", events)
-                return None
-        self.pool.update_pages(pk, pv)
-        self._note_snapshots(live, step.offsets)
-        self._observe_attention(live, step.offsets + 1, 1)
-        return {"kind": "decode", "dev": (newtok, ok, *experts),
-                "live": list(live), "t0": t0, "b": b}
-
-    def _decode_commit(self, rec: Dict[str, Any], out, events) -> None:
-        """Pure-decode commit half: consumes the fetched (tokens, ok)
-        pair and replays the per-row token commit."""
-        newtok, ok, *experts = out
-        live = rec["live"]
-        self._observe_experts(experts, len(live))
-        emitted = 0
-        for i, req in enumerate(live):
-            if req.state in TERMINAL_STATES:
-                continue                # cancelled/expired while in flight
-            if self.logit_guard and not bool(ok[i]):
-                # poisoned row: only this request fails — its sampled token
-                # is garbage and its KV blocks are freed; the other rows'
-                # tokens in this very batch remain valid
-                self._terminate(req, RequestState.FAILED,
-                                "non-finite logits in decode step",
-                                events, "failed")
-                continue
-            tok = int(newtok[i])
-            req.cache_len += 1
-            self._end_window(req)
-            req.next_token = tok
-            req.out_tokens.append(tok)
-            events["tokens"].append((req.rid, tok))
-            self._maybe_finish(req, tok, events)
-            emitted += 1
-        self._mark_decode_emit()
-        self.metrics.observe_decode(len(live),
-                                    time.perf_counter() - rec["t0"],
-                                    rec["b"])
-        if rec.get("spec_rows"):
-            # a spec-enabled step that proposed zero drafts ran the plain
-            # decode program; its rows still count in the acceptance
-            # denominator so spec stats stay honest
-            self.metrics.observe_spec(0, 0, emitted, rows=rec["spec_rows"])
+                tables, step.starts, np.ones_like(step.starts)
+                if step.q_lens is None else step.q_lens)
 
     def _abort_batch(self, live: Sequence[Request], error: str,
                      events) -> None:

@@ -454,9 +454,26 @@ class PagedKVPool:
             self.slots is not None and self.slots.deleted())
 
     @property
+    def cache(self):
+        """Every device array of the pool as ONE value, what a step program
+        takes donated and returns: ``(pages_k, pages_v)``, and of a pool
+        with state slots the state group's arrays as a third leaf (a latent
+        pool's ``pages_v`` is its stub)."""
+        pages = (self.pages_k, self.pages_v)
+        return pages if self.slots is None else pages + (self.slots.arrays,)
+
+    @cache.setter
+    def cache(self, cache) -> None:
+        """Adopt what a jitted program returned for the cache it was given
+        (the donated one is dead by then)."""
+        self.pages_k, self.pages_v, *state = cache
+        if state:
+            self.slots.arrays, = state
+
+    @property
     def state(self):
-        """The state group's device arrays (None: pages only): the step
-        programs' third donated argument and fifth result."""
+        """The state group's device arrays (None: pages only): the third
+        leaf of ``cache``."""
         return self.slots.arrays if self.slots is not None else None
 
     @state.setter
@@ -1075,17 +1092,16 @@ class PagedKVPool:
         ``(block_id, payload_k, payload_v)`` where the payloads are
         device-resident values shaped for ``write_block`` (QuantPages
         bundles under int8); ``write_fn`` is the caller's compiled
-        ``(pages_k, pages_v, blk, payload_k, payload_v) -> (pages_k',
-        pages_v')`` adopt step (donation/compile-key discipline stays with
+        ``(cache, blk, (payload_k, payload_v)) -> ((), cache', ())`` adopt step
+        (donation/compile-key discipline stays with
         the engine) and ``put`` the caller's explicit host->device
         transfer for the traced block id. Callers MUST digest-verify wire
         payloads (``kv_tier.tier_digest``) before handing them here — the
         ``tier-adopt-unverified`` lint rule enforces it at every call
         site."""
         for blk, payload_k, payload_v in items:
-            pk, pv = write_fn(self.pages_k, self.pages_v,
-                              put(blk, jnp.int32), payload_k, payload_v)
-            self.update_pages(pk, pv)
+            _, self.cache, _ = write_fn(self.cache, put(blk, jnp.int32),
+                                        (payload_k, payload_v))
 
     def padded_table(self, block_table: Sequence[int], width: int):
         """Right-pad a block table with SCRATCH to a fixed ``width``."""

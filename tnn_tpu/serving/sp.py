@@ -40,12 +40,14 @@ is token-exact.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Tuple
+import inspect
+from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..nn.transformer import PagedDecoder
 from ..parallel import mesh as mesh_lib
 from . import step_build
 
@@ -98,39 +100,28 @@ class SPContext:
 
     # -- step dispatch --------------------------------------------------------
 
-    def jit_step(self, fn, *, donate_argnums=(), n_outs: int,
-                 pages_argnums: Tuple[int, ...] = (1, 2),
-                 pages_out: Optional[Tuple[int, ...]] = None,
-                 params_argnum: Optional[int] = 0,
-                 tables_argnum: Optional[int] = None):
-        """Wrap a step body in shard_map over the context mesh + jit.
+    def jit_step(self, fn, *, donate_argnums=()):
+        """Wrap a program body in shard_map over the context mesh + jit.
 
-        ``fn``'s positional args are replicated except the page buffers
-        (``pages_argnums``, sharded block-wise) and the stacked per-shard
-        block tables (``tables_argnum``: the host stages a (sp, B, nb)
-        array via ``put_tables`` and each shard sees its own (B, nb) slice
-        — the leading unit axis is squeezed before ``fn`` runs, so the
-        step-body code is IDENTICAL to the single-chip program). Of the
-        ``n_outs`` outputs the page buffers (``pages_out``, default the
-        trailing two) come back sharded and everything else replicated.
+        What an argument IS is read off the body's parameter names, the one
+        convention every program of the engine follows: ``cache`` (the
+        pool's arrays as one pytree) takes the page spec as a prefix,
+        sharded block-wise; ``tables`` is the stacked per-shard block table
+        or block id (the host stages a (sp, ...) array via ``put_tables``
+        and each shard sees its own slice: the leading unit axis is
+        squeezed before ``fn`` runs, so the step-body code is IDENTICAL to
+        the single-chip program); everything else, the params too, is
+        replicated. The body returns ``(sampled, cache, counts)``, the
+        cache sharded like the one it was given and the small results on
+        either side of it replicated.
         ``donate_argnums`` passes through to jit, so each shard's page
         buffers are donated and re-adopted exactly as in the single-chip
         step."""
-        n_args = fn.__code__.co_argcount
-        in_specs = [P()] * n_args
-        for i in pages_argnums:
-            in_specs[i] = self.page_spec
-        if params_argnum is not None:
-            in_specs[params_argnum] = P()  # replicated, explicit
-        if tables_argnum is not None:
-            in_specs[tables_argnum] = TABLE_SPEC
-        if pages_out is None:
-            pages_out = (n_outs - 2, n_outs - 1)
-        out_specs = tuple(self.page_spec if i in pages_out else P()
-                          for i in range(n_outs))
+        names = list(inspect.signature(fn).parameters)
+        spec_of = {"cache": self.page_spec, "tables": TABLE_SPEC}
         inner = fn
-        if tables_argnum is not None:
-            t_idx = tables_argnum
+        if "tables" in names:
+            t_idx = names.index("tables")
 
             def inner(*args):
                 args = list(args)
@@ -138,9 +129,9 @@ class SPContext:
                 return fn(*args)
 
         body = jax.shard_map(
-            inner, mesh=self.mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs if n_outs > 1 else out_specs[0],
-            check_vma=False)
+            inner, mesh=self.mesh,
+            in_specs=tuple(spec_of.get(name, P()) for name in names),
+            out_specs=(P(), self.page_spec, P()), check_vma=False)
         jitted = jax.jit(body, donate_argnums=donate_argnums)
         ctx = self
 
@@ -163,7 +154,7 @@ class SPContext:
     def put_tables(self, tables: np.ndarray, blocks_per_shard: int):
         """Stage GLOBAL block tables (any rank — step tables, COW/adopt
         block-id pairs) as the stacked per-shard
-        (sp, ...) device array ``jit_step``'s ``tables_argnum`` consumes:
+        (sp, ...) device array ``jit_step`` hands a body's ``tables``:
         shard s's slice holds LOCAL row ids for the positions it owns and
         ``-1`` holes for everyone else's."""
         stacked = step_build.shard_tables(np.asarray(tables, np.int32),
@@ -171,13 +162,14 @@ class SPContext:
         return jax.device_put(stacked, self.table_sharding)
 
 
-class SPModel:
+class SPModel(PagedDecoder):
     """Block-sharded adapter around a GPT2-family model.
 
     Presents the SAME interface and dimensions as the base model — every
     parameter and every matmul is replicated, so most methods delegate
     verbatim. Only the paged-attention call differs: each shard sweeps its
-    own pages and the partials merge across the mesh (``SPAttention``)."""
+    own pages and the partials merge across the mesh (``SPAttention``),
+    inside ``PagedDecoder``'s own loop over this adapter's blocks."""
 
     def __init__(self, base, sp: int):
         self.base = base
@@ -192,36 +184,18 @@ class SPModel:
         self.kv_cache_dtype = getattr(base, "kv_cache_dtype", None)
         self.policy = base.policy
         self.backend = getattr(base, "backend", "xla")
-        self.wte = base.wte
-        self.wpe = base.wpe
-        self.ln_f = base.ln_f
         self.blocks = [SPBlock(b, sp) for b in base.blocks]
 
-    def _trunk(self, params, ids, train, rng, offset=0):
-        return self.base._trunk(params, ids, train, rng, offset=offset)
+    # PagedDecoder's hooks: everything outside the blocks is the base model's
+
+    def _embed(self, params, toks, offsets):
+        return self.base._embed(params, toks, offsets)
+
+    def _ln_f(self, params, x):
+        return self.base._ln_f(params, x)
 
     def _head(self, params, x):
         return self.base._head(params, x)
-
-    def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
-                           offsets):
-        x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x)[:, -1], pages_k, pages_v
-
-    def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
-                    offsets, q_lens):
-        x, _ = self._trunk(params, toks, False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            x, pages_k, pages_v = block.apply_paged(
-                params[f"h{i}"], x, pages_k, pages_v, block_tables, offsets,
-                layer=i, q_lens=q_lens)
-        x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), pages_k, pages_v
 
 
 class SPBlock:

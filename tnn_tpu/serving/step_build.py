@@ -55,16 +55,17 @@ class PackedStep:
     poison: np.ndarray              # (B,) f32 additive logit poison (chaos)
     b: int                          # compiled batch width
     nb: int                         # compiled table width (blocks per seq)
+    toks: np.ndarray = None         # (B, qw) token matrix; decode form: (B,)
+    starts: np.ndarray = None       # (B,) first write position per row
+    # (B,) live tokens per row; None: the decode form, one token a row
+    q_lens: Optional[np.ndarray] = None
+    qw: int = 1                     # compiled chunk width (pow2 bucket)
 
 
 @dataclasses.dataclass
 class MixedStep(PackedStep):
     """The ragged mixed prefill+decode batch (optionally speculative)."""
-    toks: np.ndarray = None         # (B, qw) token matrix
-    starts: np.ndarray = None       # (B,) first write position per row
-    q_lens: np.ndarray = None       # (B,) live tokens per row
     n_draft: np.ndarray = None      # (B,) drafted lookahead per decode row
-    qw: int = 0                     # compiled chunk width (pow2 bucket)
     # (row index, DeviceDraft) pairs whose tokens splice in on-device
     dev_drafts: List[Tuple[int, Any]] = dataclasses.field(
         default_factory=list)
@@ -72,9 +73,8 @@ class MixedStep(PackedStep):
 
 @dataclasses.dataclass
 class DecodeStep(PackedStep):
-    """The pure-decode batch: one committed token per row."""
-    toks: np.ndarray = None         # (B,) this step's token per row
-    offsets: np.ndarray = None      # (B,) kv length before this token
+    """The pure-decode batch: one committed token per row, ``starts`` its
+    kv length before this token."""
 
 
 def shard_tables(tables: np.ndarray, sp: int,
@@ -194,18 +194,18 @@ def pack_decode(live: Sequence[Any], *, b: int, nb: int, scratch: int,
                 lens: Optional[Dict[int, int]] = None,
                 on_device: Collection[int] = ()) -> DecodeStep:
     """Pack the pure-decode batch. ``lens`` / ``on_device``: the predicted
-    step (module docstring): each row's offset is its predicted length, and
+    step (module docstring): each row's start is its predicted length, and
     a row whose token is on the device leaves it zero. Where that is every
     row, in their predecessor's order, the dispatched program reads its
     predecessor's unfetched sampled tokens directly as its input."""
     step = DecodeStep(
         key=("pdecode", b, nb) + kv_key, b=b, nb=nb,
         toks=np.zeros((b,), np.int32),
-        offsets=np.zeros((b,), np.int32),
+        starts=np.zeros((b,), np.int32),
         **_alloc_common(b, nb, scratch))
     for i, req in enumerate(live):
         if req.rid not in on_device:
             step.toks[i] = req.next_token
-        step.offsets[i] = lens[req.rid] if lens else req.cache_len
+        step.starts[i] = lens[req.rid] if lens else req.cache_len
         _fill_row(step, i, req, sum_at, kinds, state)
     return step

@@ -45,13 +45,15 @@ token-exact.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Tuple
+import inspect
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..nn.transformer import PagedDecoder
 from ..parallel import mesh as mesh_lib
 
 # The pool's (L, N, H_kv / p, bs, p * Dh) arrays split on axis 2, the page
@@ -155,33 +157,25 @@ class TPContext:
 
     # -- step dispatch --------------------------------------------------------
 
-    def jit_step(self, fn, *, donate_argnums=(), n_outs: int,
-                 pages_argnums: Tuple[int, ...] = (1, 2),
-                 pages_out: Optional[Tuple[int, ...]] = None,
-                 params_argnum: Optional[int] = 0):
-        """Wrap a step body in shard_map over the TP mesh + jit.
+    def jit_step(self, fn, *, donate_argnums=()):
+        """Wrap a program body in shard_map over the TP mesh + jit.
 
-        ``fn``'s positional args are replicated except the page buffers
-        (``pages_argnums``, sharded head-wise) and the params
-        (``params_argnum``, per-leaf specs); of its ``n_outs`` outputs the
-        page buffers (``pages_out``, default the trailing two) come back
-        sharded and everything else replicated. ``donate_argnums`` passes
-        through to jit, so each shard's page buffers are donated and
-        re-adopted exactly as in the single-chip step."""
-        n_args = fn.__code__.co_argcount
-        in_specs = [P()] * n_args
-        for i in pages_argnums:
-            in_specs[i] = self.page_spec
-        if params_argnum is not None:
-            in_specs[params_argnum] = self.param_specs
-        if pages_out is None:
-            pages_out = (n_outs - 2, n_outs - 1)
-        out_specs = tuple(self.page_spec if i in pages_out else P()
-                          for i in range(n_outs))
+        What an argument IS is read off the body's parameter names, the one
+        convention every program of the engine follows: ``params`` takes
+        the per-leaf specs, ``cache`` (the pool's arrays as one pytree) the
+        page spec as a prefix, sharded head-wise, everything else is
+        replicated; the body returns ``(sampled, cache, counts)``, the
+        cache sharded like the one it was given and the small results on
+        either side of it replicated.
+        ``donate_argnums`` passes through to jit, so each shard's page
+        buffers are donated and re-adopted exactly as in the single-chip
+        step."""
+        spec_of = {"params": self.param_specs, "cache": self.page_spec}
         body = jax.shard_map(
-            fn, mesh=self.mesh, in_specs=tuple(in_specs),
-            out_specs=out_specs if n_outs > 1 else out_specs[0],
-            check_vma=False)
+            fn, mesh=self.mesh,
+            in_specs=tuple(spec_of.get(name, P())
+                           for name in inspect.signature(fn).parameters),
+            out_specs=(P(), self.page_spec, P()), check_vma=False)
         jitted = jax.jit(body, donate_argnums=donate_argnums)
         ctx = self
 
@@ -202,14 +196,15 @@ class TPContext:
         return jax.device_put(x, self.replicated)
 
 
-class TPModel:
+class TPModel(PagedDecoder):
     """Head-sharded adapter around a GPT2-family model.
 
     Presents the SAME interface and GLOBAL dimensions as the base model (the
     engine's host-side math — head_dim, pool sizing, batch packing — reads
     them unchanged) but its apply methods expect to run INSIDE shard_map
     with locally-sharded params/pages, using per-shard head counts for the
-    attention split and psums to rebuild the residual stream."""
+    attention split and psums to rebuild the residual stream. The paged
+    step is ``PagedDecoder``'s own loop, over this adapter's blocks."""
 
     def __init__(self, base, tp: int):
         self.base = base
@@ -224,40 +219,19 @@ class TPModel:
         self.kv_cache_dtype = getattr(base, "kv_cache_dtype", None)
         self.policy = base.policy
         self.backend = getattr(base, "backend", "xla")
-        self.wte = base.wte
-        self.wpe = base.wpe
-        self.ln_f = base.ln_f
         self.blocks = [TPBlock(b, tp) for b in base.blocks]
 
-    def _trunk(self, params, ids, train, rng, offset=0):
-        return self.base._trunk(params, ids, train, rng, offset=offset)
+    # PagedDecoder's hooks: everything outside the blocks runs replicated
+    # through the base model
+
+    def _embed(self, params, toks, offsets):
+        return self.base._embed(params, toks, offsets)
+
+    def _ln_f(self, params, x):
+        return self.base._ln_f(params, x)
 
     def _head(self, params, x):
         return self.base._head(params, x)
-
-    def apply_decode_paged(self, params, toks, pages_k, pages_v, block_tables,
-                           offsets):
-        x, _ = self._trunk(params, toks[:, None], False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            with jax.named_scope(f"h{i}"):
-                x, pages_k, pages_v = block.apply_paged(
-                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
-                    offsets, layer=i)
-        with jax.named_scope("ln_f"):
-            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x)[:, -1], pages_k, pages_v
-
-    def apply_paged(self, params, toks, pages_k, pages_v, block_tables,
-                    offsets, q_lens):
-        x, _ = self._trunk(params, toks, False, None, offset=offsets)
-        for i, block in enumerate(self.blocks):
-            with jax.named_scope(f"h{i}"):
-                x, pages_k, pages_v = block.apply_paged(
-                    params[f"h{i}"], x, pages_k, pages_v, block_tables,
-                    offsets, layer=i, q_lens=q_lens)
-        with jax.named_scope("ln_f"):
-            x, _ = self.ln_f.apply({"params": params["ln_f"], "state": {}}, x)
-        return self._head(params, x), pages_k, pages_v
 
 
 class TPBlock:
