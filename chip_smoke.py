@@ -117,6 +117,13 @@ CHIP = dict(
     state=dict(layers=2, rows=96, heads=32, key=128, value=128),
     heads256=dict(blocks=160, block_size=128, heads=16, kv_heads=2,
                   head_dim=256, batch=8, table=16, chunk=32),
+    # the Mamba-2 step at granite-4.0-h-micro's cell: 24 rows of 64 heads'
+    # states of 64 x 128 float32 in 25 slots of 2 layers, a snapshot at every
+    # third row; and the paged kernel at that model's attention layers: 32
+    # query heads over 8 KV heads of 64, two heads a page row, pages of 128
+    ssm=dict(layers=2, rows=24, heads=64, head_dim=64, state=128),
+    heads64=dict(blocks=160, block_size=128, heads=32, kv_heads=8,
+                 head_dim=64, batch=8, table=16, chunk=64),
     # the flash kernels at gpt2-medium.train's call: batch 8, 16 heads of
     # 64 over 1,024 causal positions
     flash=dict(batch=8, heads=16, seq=1024, head_dim=64, iters=10),
@@ -153,6 +160,9 @@ REHEARSAL = dict(
     state=dict(layers=2, rows=3, heads=8, key=16, value=16),
     heads256=dict(blocks=16, block_size=8, heads=4, kv_heads=2, head_dim=32,
                   batch=3, table=6, chunk=8),
+    ssm=dict(layers=2, rows=3, heads=8, head_dim=16, state=128),
+    heads64=dict(blocks=16, block_size=8, heads=8, kv_heads=2, head_dim=64,
+                 batch=3, table=6, chunk=8),
     flash=dict(batch=1, heads=2, seq=64, head_dim=32, iters=1),
     row_write=dict(pages={"packed": (2, 16, 128), "latent": (1, 32, 256)},
                    batch=3, table=3, chunk=64, iters=1),
@@ -306,16 +316,19 @@ def phase_kernel(cfg) -> list:
 
 
 def _state_kernels(cfg, rand, rng) -> list:
-    """``tnn_gdn_step`` against the ``jax.numpy`` step at a decode step's
-    shapes (the output, every live state written, and the snapshot of the
-    rows that keep one); and the paged kernel at heads of 256 (2 KV heads,
-    8 query heads each), which no other model runs it at."""
+    """``tnn_gdn_step`` and ``tnn_mamba2_step`` against their ``jax.numpy``
+    steps at a decode step's shapes (the output, every live state written,
+    and the snapshot of the rows that keep one); and the paged kernel at
+    heads of 256 (2 KV heads, 8 query heads each) and at grouped queries over
+    PACKED heads of 64 (two KV heads a page row, 4 query heads each), which
+    no other model runs it at."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from tnn_tpu.ops.pallas.gdn_step import gdn_step
-    from tnn_tpu.ops.pallas.paged_attention import paged_attention
+    from tnn_tpu.ops.pallas.mamba2_step import mamba2_step
+    from tnn_tpu.ops.pallas.paged_attention import lane_pack, paged_attention
 
     interpret, failures = cfg["rehearse"], []
 
@@ -345,24 +358,46 @@ def _state_kernels(cfg, rand, rng) -> list:
     check(name + " states", got[1][:, 1:], want[1][:, 1:], 1e-4)
     check(name + " snapshots", got[2][:, 1:], want[2][:, 1:], 0.0)
 
-    k = cfg["heads256"]
-    bs, B, nb = k["block_size"], k["batch"], k["table"]
-    pk, pv = (rand((2, k["blocks"], k["kv_heads"], bs, k["head_dim"]))
-              for _ in range(2))
-    tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
-    cap = nb * bs
-    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
-        kv_lens = np.array([qw, bs, bs + 1, cap // 3, cap // 2 - 1,
-                            cap - bs - 5, cap - 24, cap])[:B].clip(
-                                qw, cap).astype(np.int32)
-        q_lens = np.minimum(np.array([qw, 1, qw // 2 + 1] * B)[:B], kv_lens)
-        qq = rand((B, qw, k["heads"], k["head_dim"]))
-        kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32), layer=1)
-        a = (qq, pk, pv, tables, jnp.asarray(kv_lens))
-        check(f"paged {fname} 16 x 256 over 2",
-              paged_attention(*a, backend="pallas", interpret=interpret,
-                              group_positions=512, **kw),
-              paged_attention(*a, backend="xla", **kw), KERNEL_TOL["bf16"])
+    k = cfg["ssm"]
+    L, B, H, P, N = (k["layers"], k["rows"], k["heads"], k["head_dim"],
+                     k["state"])
+    step = jax.nn.softplus(f32((B, H)) - 3.0)
+    rec, snap = f32((L, B + 1, H, P, N)), jnp.zeros(
+        (L, 2 * B + 1, H, P, N), jnp.float32)
+    slots = jnp.asarray(rng.permutation(B) + 1, jnp.int32)
+    snaps = jnp.where(jnp.arange(B) % 3 == 0, 2 * (slots - 1) + 1, 0)
+    args = (f32((B, H, P)), step, -8.0 * step, f32((B, N)), f32((B, N)), rec,
+            snap, slots, snaps)
+    want = mamba2_step(*args, layer=1, backend="xla")
+    got = mamba2_step(*args, layer=1, backend="pallas", interpret=interpret)
+    name = f"mamba2_step {B} rows x {H} heads"
+    check(name + " out", got[0], want[0], 1e-4)
+    check(name + " states", got[1][:, 1:], want[1][:, 1:], 1e-4)
+    check(name + " snapshots", got[2][:, 1:], want[2][:, 1:], 0.0)
+
+    for k, what, more in (
+            (cfg["heads256"], "16 x 256 over 2", dict(group_positions=512)),
+            (cfg["heads64"], "32 x 64 over 8 packed", {})):
+        bs, B, nb = k["block_size"], k["batch"], k["table"]
+        pack = lane_pack(k["kv_heads"], k["head_dim"], jnp.bfloat16)
+        pk, pv = (rand((2, k["blocks"], k["kv_heads"] // pack, bs,
+                        pack * k["head_dim"])) for _ in range(2))
+        tables = jnp.asarray(rng.integers(1, k["blocks"], (B, nb)), jnp.int32)
+        cap = nb * bs
+        for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
+            kv_lens = np.array([qw, bs, bs + 1, cap // 3, cap // 2 - 1,
+                                cap - bs - 5, cap - 24, cap])[:B].clip(
+                                    qw, cap).astype(np.int32)
+            q_lens = np.minimum(np.array([qw, 1, qw // 2 + 1] * B)[:B],
+                                kv_lens)
+            qq = rand((B, qw, k["heads"], k["head_dim"]))
+            kw = dict(q_lens=jnp.asarray(q_lens, jnp.int32), layer=1)
+            a = (qq, pk, pv, tables, jnp.asarray(kv_lens))
+            check(f"paged {fname} {what}",
+                  paged_attention(*a, backend="pallas", interpret=interpret,
+                                  **more, **kw),
+                  paged_attention(*a, backend="xla", **kw),
+                  KERNEL_TOL["bf16"])
     return failures
 
 

@@ -114,6 +114,11 @@ _TEMP_BYTES = {
     "two-group-chunk64": 147_852_800,
     "state-decode": 12_902_400,
     "state-chunk32": 208_057_344,
+    # PR 49's own first readings (granite-4.0-h-micro's layers, 24 rows); the
+    # decode step's live ranges fit what the compiler counts as no
+    # temporaries at all: a MB of room
+    "ssm-decode": 1_000_000,
+    "ssm-chunk64": 397_095_424,
 }
 
 
@@ -801,10 +806,36 @@ def test_check_step_writes_refuses_two_writers_and_shared_pages():
 # -- state slots beside the pages (PR 44): no state-shaped copy either ---------
 
 
-@pytest.mark.parametrize("form", ["decode", "chunk32"])
+# what each state model's step is compiled from: its layers at published
+# widths (few of them, so that the compile is quick), the pool's pages, the
+# chunk's width, and the kernels its text must name (the state kernel in the
+# decode form alone: a prompt chunk runs the chunked ``jax.numpy`` form)
+_STATE_MODELS = {
+    "state": dict(
+        name="qwen3_next_ep4", kw=dict(num_layers=4, held_experts=4),
+        rows=16, pages=(1, 256, 2, 128, 256), chunk=32, step="tnn_gdn_step",
+        kernels={"tnn_paged_attention", "tnn_expert_gmm"}),
+    "ssm": dict(
+        # the cell's 24 rows: under ~2 MB the compiler would keep the conv
+        # array in VMEM for the whole step, which is a copy in and out
+        name="granite4_h_micro", rows=24, pages=(1, 256, 4, 128, 128),
+        chunk=64,
+        kw=dict(num_layers=4, layer_types=["mamba", "attention", "mamba",
+                                           "mamba"]),
+        step="tnn_mamba2_step",
+        kernels={"tnn_paged_attention", "tnn_kv_row_write"})}
+
+
+@pytest.mark.parametrize("family,form", [
+    ("state", "decode"), ("state", "chunk32"), ("ssm", "decode"),
+    ("ssm", "chunk64")])
 def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
-        form, one_chip, no_compile_cache, alarm):
-    """Qwen3-Next's layers as published (Gated DeltaNet: 16 key and 32 value
+        family, form, one_chip, no_compile_cache, alarm):
+    """(``ssm``: granite-4.0-h-micro's layers as published, PR 49: a Mamba-2
+    mixer of 64 heads of 64 with a state of 128 on both sides of a plain
+    attention layer of 32 query heads over 8 KV heads of 64, TWO heads a
+    page row under grouped queries, which no chip had compiled; the conv
+    rows rest as 102 rows of 128 lanes.) Qwen3-Next's layers as published (Gated DeltaNet: 16 key and 32 value
     heads of 128 behind a convolution of 4; full attention: 16 query heads
     over 2 KV heads of 256; experts of 512 x 2,048, 4 of them held here so
     that the compile is quick), one period (linear, linear, linear, full),
@@ -819,20 +850,22 @@ def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    rows = 16
-    model = models.create("qwen3_next_ep4", num_layers=4, held_experts=4)
+    what = _STATE_MODELS[family]
+    rows = what["rows"]
+    model = models.create(what["name"], **what["kw"])
     params = jax.tree_util.tree_map(
         lambda x: spec(x.shape, x.dtype),
         jax.eval_shape(
             lambda: model.init(jax.random.PRNGKey(0), (1, 8))["params"]))
     assert model.cache_layers == 1
-    pages = spec((1, 256, 2, 128, 256), jnp.bfloat16)
+    pages = spec(what["pages"], jnp.bfloat16)
     group = model.state_group
+    n = group["layers"]
     state = {
-        "conv": spec((3, rows + 1) + group["conv"], jnp.bfloat16),
-        "rec": spec((3, rows + 1) + group["rec"], jnp.float32),
-        "conv_snap": spec((3, 2 * rows + 1) + group["conv"], jnp.bfloat16),
-        "rec_snap": spec((3, 2 * rows + 1) + group["rec"], jnp.float32)}
+        "conv": spec((n, rows + 1) + group["conv"], jnp.bfloat16),
+        "rec": spec((n, rows + 1) + group["rec"], jnp.float32),
+        "conv_snap": spec((n, 2 * rows + 1) + group["conv"], jnp.bfloat16),
+        "rec_snap": spec((n, 2 * rows + 1) + group["rec"], jnp.float32)}
     tables, lens = spec((rows, 9), jnp.int32), spec((rows,), jnp.int32)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
             mock.patch.dict("os.environ", {"TNN_PALLAS_INTERPRET": "0"}):
@@ -848,12 +881,12 @@ def test_state_step_compiles_for_the_chip_with_no_pool_or_state_copy(
                 lambda p, t, pk, pv, tb, o, q, s: model.apply_paged(
                     p, t, pk, pv, tb, o, q, state=s, head_at=q - 1),
                 donate_argnums=(2, 3, 7)).lower(
-                params, spec((rows, 32), jnp.int32), pages, pages, tables,
-                lens, lens, state)
-        text = _compiled_text(lowered, f"state-{form}")
-    names = set(re.findall(r"tnn_[a-z_]+[a-z]", text))
-    assert {"tnn_paged_attention", "tnn_expert_gmm"} <= names
-    assert ("tnn_gdn_step" in names) == (form == "decode")
+                params, spec((rows, what["chunk"]), jnp.int32), pages, pages,
+                tables, lens, lens, state)
+        text = _compiled_text(lowered, f"{family}-{form}")
+    names = set(re.findall(r"tnn_[a-z0-9_]+[a-z0-9]", text))
+    assert what["kernels"] <= names
+    assert (what["step"] in names) == (form == "decode")
     for shape in [pages.shape] + [s.shape for s in state.values()]:
         dims = ",".join(map(str, shape))
         copy = re.compile(r"= \w+\[%s\]\{[^}]*\} copy\(" % dims)

@@ -17,8 +17,8 @@ import jax.numpy as jnp
 
 from ..core import rng as rnglib
 from ..core.module import Module, register_module
-from ..nn.attention import (GatedAttention, GatedDeltaNet, LatentAttention,
-                            MultiHeadAttention)
+from ..nn.attention import (GatedAttention, LatentAttention,
+                            MultiHeadAttention, state_mixer)
 from ..nn.embedding import Embedding
 from ..nn.layers import Dense
 from ..nn.moe import ExpertShare
@@ -36,20 +36,26 @@ class LlamaBlock(Module):
 
     def __init__(self, num_heads: int, mlp_hidden: int,
                  num_kv_heads: Optional[int] = None,
-                 rope_theta: float = 10000.0, backend: str = "xla",
+                 rope_theta: Optional[float] = 10000.0, backend: str = "xla",
                  kv_cache_dtype: Optional[str] = None,
                  norm_eps: float = 1e-6, norm_unit_offset: bool = False,
                  residual_f32: bool = False, window: Optional[int] = None,
                  chunk: Optional[int] = None, latent: Optional[dict] = None,
                  experts: Optional[dict] = None,
                  gated: Optional[dict] = None, sandwich: bool = False,
-                 linear: Optional[dict] = None, name=None, policy=None):
+                 linear: Optional[dict] = None,
+                 scales: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
         self.mlp_hidden = int(mlp_hidden)
-        # ``linear``: the keywords of ``nn.attention.GatedDeltaNet``: THIS
+        # ``linear``: the keywords of a state mixer (``nn.attention.
+        # state_mixer``: Gated DeltaNet, or the one ``mixer`` names): THIS
         # layer keeps a state updated in place and no page of the pool
         self.linear = dict(linear) if linear else None
+        # ``scales``: the model's published multipliers (``Llama``); a block
+        # reads ``residual`` (each sublayer's output times it before it is
+        # added) and ``attention`` (the softmax's scale)
+        self.scales = dict(scales) if scales else None
         # the block is GIVEN its attention (heads: K/V of every position;
         # eva: ``window`` and ``chunk``; latent: ``latent``, the keywords of
         # ``nn.attention.LatentAttention``; gated: ``gated``, those of
@@ -64,7 +70,9 @@ class LlamaBlock(Module):
         # added (four norms a block)
         self.sandwich = bool(sandwich)
         self.num_kv_heads = int(num_kv_heads) if num_kv_heads else self.num_heads
-        self.rope_theta = float(rope_theta)
+        # None: no rotation at all (positions reach the layer through the
+        # state layers under it)
+        self.rope_theta = float(rope_theta) if rope_theta else None
         self.backend = backend
         self.kv_cache_dtype = kv_cache_dtype
         self.norm_eps = float(norm_eps)
@@ -78,8 +86,8 @@ class LlamaBlock(Module):
                     policy=p)
         self.ln1 = RMSNorm(**norm)
         if self.linear:
-            self.attn = GatedDeltaNet(norm_eps=self.norm_eps, policy=p,
-                                      **self.linear)
+            self.attn = state_mixer(self.linear, norm_eps=self.norm_eps,
+                                    policy=p)
         elif self.latent:
             self.attn = LatentAttention(num_heads, norm_eps=self.norm_eps,
                                         backend=backend, policy=p,
@@ -94,7 +102,7 @@ class LlamaBlock(Module):
                 num_heads, causal=True, backend=backend,
                 num_kv_heads=self.num_kv_heads, rope_theta=self.rope_theta,
                 use_bias=False, kv_cache_dtype=kv_cache_dtype, window=window,
-                chunk=chunk, policy=p)
+                chunk=chunk, scale=_scale(self, "attention"), policy=p)
         self.ln2 = RMSNorm(**norm)
         self.post = RMSNorm(**norm) if self.sandwich else None
         self.moe = ExpertShare(policy=p, **self.experts) \
@@ -156,6 +164,8 @@ class LlamaBlock(Module):
         h = h.astype(x.dtype)
         if self.sandwich:
             h = self.post.apply({"params": post, "state": {}}, h)[0]
+        if _scale(self, "residual"):
+            h = h * jnp.asarray(_scale(self, "residual"), h.dtype)
         return x + h
 
     def _mlp_residual(self, params, x, train=False, live=None):
@@ -222,9 +232,9 @@ class LlamaBlock(Module):
 
     def apply_state(self, params, x, state, slots, offsets, state_layer,
                     q_lens=None):
-        """``apply_paged`` for a block whose attention keeps a state in the
-        pool's state slots (``GatedDeltaNet.apply_state``) and no pages:
-        returns (x, state)."""
+        """``apply_paged`` for a block whose mixer keeps a state in the
+        pool's state slots (``nn.attention._StateMixer.apply_state``,
+        whichever mixer it is) and no pages: returns (x, state)."""
         h, state = self.attn.apply_state(
             {"params": params["attn"]}, self._ln1(params, x), state, slots,
             offsets, layer=state_layer, q_lens=q_lens)
@@ -363,7 +373,17 @@ class ShortcutBlock(Module):
 _BLOCK_DEFAULTS = {"norm_eps": 1e-6, "norm_unit_offset": False,
                    "residual_f32": False, "window": None, "chunk": None,
                    "latent": None, "experts": None, "gated": None,
-                   "sandwich": False, "linear": None}
+                   "sandwich": False, "linear": None, "scales": None}
+
+# the kinds of layer (``layer_types``) that keep a state in the pool's state
+# slots and no pages; every other kind has pages
+STATE_KINDS = ("linear_attention", "mamba")
+
+
+def _scale(m, key: str) -> Optional[float]:
+    """One of a model's (or a block's) published multipliers (``scales``),
+    None where it has none."""
+    return (m.scales or {}).get(key)
 
 
 def _block_options(m):
@@ -392,7 +412,7 @@ class Llama(PagedDecoder, Module):
                  num_layers: int = 12, d_model: int = 768, num_heads: int = 12,
                  num_kv_heads: Optional[int] = None,
                  mlp_hidden: Optional[int] = None,
-                 rope_theta: float = 10000.0, backend: str = "xla",
+                 rope_theta: Optional[float] = 10000.0, backend: str = "xla",
                  tie_embeddings: bool = True,
                  kv_cache_dtype: Optional[str] = None,
                  norm_eps: float = 1e-6, norm_unit_offset: bool = False,
@@ -403,15 +423,30 @@ class Llama(PagedDecoder, Module):
                  gated: Optional[dict] = None, sandwich: bool = False,
                  embed_scale: bool = False, num_dense_layers: int = 0,
                  dense_hidden: Optional[int] = None, shortcut: bool = False,
-                 linear: Optional[dict] = None, name=None, policy=None):
+                 linear: Optional[dict] = None,
+                 layer_types: Optional[list] = None,
+                 scales: Optional[dict] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
-        # ``linear``: the keywords of ``nn.attention.GatedDeltaNet``, for
-        # the layers whose kind (``gated["layer_types"]``) is
-        # "linear_attention": they keep a state in the pool's state slots
+        # ``layer_types``: a kind for each layer, as the model publishes
+        # them, for a model WITHOUT ``gated`` keywords (a gated model's are
+        # ``gated["layer_types"]``): one of ``STATE_KINDS`` keeps a state,
+        # "attention" is the plain attention a block builds by default
+        self.layer_types = list(layer_types) if layer_types else None
+        if self.layer_types and gated:
+            raise ValueError("a gated model's layer_types are gated's")
+        # ``linear``: the keywords of a state mixer (``nn.attention.
+        # state_mixer``), for the layers whose kind is one of
+        # ``STATE_KINDS``: they keep a state in the pool's state slots
         # (``state_group``), the others pages
         self.linear = dict(linear) if linear else None
-        if self.linear and not gated:
-            raise ValueError("linear layers are named by gated's layer_types")
+        if self.linear and not (gated or self.layer_types):
+            raise ValueError("linear layers are named by layer_types")
+        # ``scales``: the multipliers a model publishes beside its weights
+        # (Granite's four), ONE mapping: ``embedding`` (the embedding times
+        # it), ``residual`` and ``attention`` (the blocks': each sublayer's
+        # output times it; the softmax's scale), ``logits`` (the head's
+        # output DIVIDED by it). Absent: none of them
+        self.scales = dict(scales) if scales else None
         # ``shortcut``: every block is a ``ShortcutBlock``: two attention
         # sublayers and two dense feed-forwards of ``mlp_hidden`` with the
         # model's ``experts`` across them; ``num_layers`` counts BLOCKS, the
@@ -454,13 +489,13 @@ class Llama(PagedDecoder, Module):
         # Llama's ~8/3 * d, rounded up to a multiple of 128 (MXU lane width)
         self.mlp_hidden = int(mlp_hidden) if mlp_hidden else (
             (8 * self.d_model // 3 + 127) // 128 * 128)
-        self.rope_theta = float(rope_theta)
+        self.rope_theta = float(rope_theta) if rope_theta else None
         self.backend = backend
         self.tie_embeddings = bool(tie_embeddings)
         self.kv_cache_dtype = kv_cache_dtype
         p = self.policy
         self.wte = Embedding(vocab_size, d_model, policy=p)
-        if self.gated and len(self.gated["layer_types"]) != self.num_layers:
+        if self.kinds and len(self.kinds) != self.num_layers:
             raise ValueError("layer_types names a kind for each of the "
                              f"{self.num_layers} layers")
         self.head_dim = int(self.gated["head_dim"]) if self.gated \
@@ -484,6 +519,11 @@ class Llama(PagedDecoder, Module):
         self.ln_f = RMSNorm(eps=norm_eps, unit_offset=norm_unit_offset,
                             policy=p)
 
+    @property
+    def kinds(self) -> Optional[list]:
+        """The kind of each layer, where the model names them."""
+        return self.gated["layer_types"] if self.gated else self.layer_types
+
     def _layer_options(self, i: int) -> dict:
         """What layer ``i`` is given that its neighbour may not be: its
         feed-forward (dense of ``dense_hidden`` among the first
@@ -495,7 +535,9 @@ class Llama(PagedDecoder, Module):
             mlp_hidden=self.dense_hidden if dense and self.dense_hidden
             else self.mlp_hidden,
             experts=None if dense else self.experts)
-        if self.gated and self.gated["layer_types"][i] == "linear_attention":
+        if self.scales:
+            opts["scales"] = self.scales
+        if self.kinds and self.kinds[i] in STATE_KINDS:
             opts["linear"] = self.linear
         elif self.gated:
             sliding = self.gated["layer_types"][i] == "sliding_attention"
@@ -523,18 +565,16 @@ class Llama(PagedDecoder, Module):
 
     @property
     def state_group(self) -> Optional[dict]:
-        """What the pool holds beside its pages for a model with linear
+        """What the pool holds beside its pages for a model with state
         layers (``serving.kv_pool.StateSlots``): how many layers keep a
-        state, the conv positions (``GatedDeltaNet.conv_rows``) and the recurrent
-        state ``(value_heads, key_dim, value_dim)`` of one. None: pages
-        only."""
+        state, and what the mixer, whichever it is, says a row's is in one
+        of them: the conv positions (``conv_rows``) and the recurrent state
+        (``rec_shape``). None: pages only."""
         if not self.linear:
             return None
         mix = next(b.attn for b in self.blocks if b.linear)
-        return dict(
-            layers=self.gated["layer_types"].count("linear_attention"),
-            conv=mix.conv_rows,
-            rec=(mix.value_heads, mix.key_dim, mix.value_dim))
+        return dict(layers=sum(bool(b.linear) for b in self.blocks),
+                    conv=mix.conv_rows, rec=mix.rec_shape)
 
     def _paged_layers(self, pages_k, block_tables):
         """A packed step table of two page groups, by layer: ``[a segment a
@@ -596,6 +636,13 @@ class Llama(PagedDecoder, Module):
 
     @jax.named_scope("lm_head")
     def _head(self, params, x):
+        logits = self._head_product(params, x)
+        if _scale(self, "logits"):
+            logits = logits / jnp.asarray(_scale(self, "logits"),
+                                          logits.dtype)
+        return logits
+
+    def _head_product(self, params, x):
         if self.tie_embeddings:
             return self.wte.attend(params["wte"], x)
         from ..ops.pallas.quant_matmul import qmatmul
@@ -613,6 +660,8 @@ class Llama(PagedDecoder, Module):
         x = x.astype(jnp.float32) if self.residual_f32 else x
         if self.embed_scale:
             x = x * jnp.asarray(self.d_model ** 0.5, x.dtype)
+        if _scale(self, "embedding"):
+            x = x * jnp.asarray(_scale(self, "embedding"), x.dtype)
         return x
 
     @jax.named_scope("ln_f")
@@ -674,7 +723,7 @@ class Llama(PagedDecoder, Module):
         if self.num_pred_heads != 1:
             cfg["num_pred_heads"] = self.num_pred_heads
         for key in ("embed_scale", "num_dense_layers", "dense_hidden",
-                    "shortcut"):
+                    "shortcut", "layer_types"):
             if getattr(self, key):
                 cfg[key] = getattr(self, key)
         return cfg
@@ -934,6 +983,57 @@ def qwen3_next_tiny(**kw):
                      shared=1, shared_gated=True))
     cfg.update(kw)
     return qwen3_next_ep4(**cfg)
+
+
+def granite4_h_micro(**kw):
+    """granite-4.0-h-micro (https://huggingface.co/ibm-granite/
+    granite-4.0-h-micro, config.json, ``model_type: granitemoehybrid``),
+    WHOLE: 40 layers in the published order, 36 Mamba-2 mixers (64 heads of
+    64 with a state of 128 behind a convolution of 4 with a bias, one group)
+    and, at layers 5, 15, 25 and 35, plain grouped-query attention (32 query
+    heads over 8 KV heads of 64) WITHOUT positions
+    (``position_embedding_type: "nope"``) at the published softmax scale
+    1/64; a gated SiLU feed-forward of 8,192 in every layer (no experts);
+    the embedding times 12, each sublayer's output times 0.22, the tied
+    head's logits over all 100,352 rows divided by 8; plain RMSNorm at 1e-5;
+    bf16 weights: 3.19 G parameters, 6.4 GB."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="bfloat16", param="bfloat16",
+                                        compute="bfloat16"))
+    cfg = dict(
+        vocab_size=100352, max_len=131072, num_layers=40, d_model=2048,
+        num_heads=32, num_kv_heads=8, mlp_hidden=8192,
+        layer_types=["attention" if i % 10 == 5 else "mamba"
+                     for i in range(40)],
+        linear=dict(mixer="mamba2", heads=64, head_dim=64, state=128, conv=4),
+        scales=dict(embedding=12.0, residual=0.22, attention=0.015625,
+                    logits=8.0))
+    cfg.update(kw)
+    return Llama(rope_theta=None, tie_embeddings=True, norm_eps=1e-5,
+                 residual_f32=True, **cfg)
+
+
+def granite4_h_micro_cpu(**kw):
+    """granite-4.0-h-micro's layers at test sizes (NOT Granite-4.0-H-Tiny,
+    which is another published model): 5 layers (mamba, mamba, attention,
+    mamba, mamba), 64 wide; the mixers 8 heads of 16 with a state of 16; the
+    attention 4 query heads over 2 KV heads of 16 at softmax scale 1/8
+    (twice ``16^-1/2``, so a dropped scale shows); feed-forward 128; the
+    four multipliers as published. Float32 unless told otherwise."""
+    from ..core.dtypes import DTypePolicy
+
+    kw.setdefault("policy", DTypePolicy(io="float32", param="float32",
+                                        compute="float32"))
+    cfg = dict(
+        vocab_size=256, max_len=512, num_layers=5, d_model=64, num_heads=4,
+        num_kv_heads=2, mlp_hidden=128,
+        layer_types=["mamba", "mamba", "attention", "mamba", "mamba"],
+        linear=dict(mixer="mamba2", heads=8, head_dim=16, state=16, conv=4),
+        scales=dict(embedding=12.0, residual=0.22, attention=0.125,
+                    logits=8.0))
+    cfg.update(kw)
+    return granite4_h_micro(**cfg)
 
 
 def llama_small(**kw):

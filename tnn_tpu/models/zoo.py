@@ -89,6 +89,10 @@ register("qwen3_next_ep4")(
     lambda **kw: llama_lib.qwen3_next_ep4(**kw))
 register("qwen3_next_tiny")(
     lambda **kw: llama_lib.qwen3_next_tiny(**kw))
+register("granite4_h_micro")(
+    lambda **kw: llama_lib.granite4_h_micro(**kw))
+register("granite4_h_micro_cpu")(
+    lambda **kw: llama_lib.granite4_h_micro_cpu(**kw))
 register("gpt2_medium")(lambda **kw: gpt2_lib.gpt2_medium(**kw))
 register("gpt2_large")(lambda **kw: gpt2_lib.gpt2_large(**kw))
 register("flash_gpt2_small")(lambda **kw: gpt2_lib.gpt2_small(backend="pallas", **kw))

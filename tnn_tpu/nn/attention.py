@@ -260,9 +260,14 @@ class MultiHeadAttention(Module):
                  kv_cache_dtype: Optional[str] = None,
                  rope_theta: Optional[float] = None, use_bias: bool = True,
                  window: Optional[int] = None, chunk: Optional[int] = None,
-                 name=None, policy=None):
+                 scale: Optional[float] = None, name=None, policy=None):
         super().__init__(name=name, policy=policy)
         self.num_heads = int(num_heads)
+        # the softmax's scale; None: head_dim^-1/2 (a model that publishes
+        # its own, Granite's ``attention_multiplier``, says so here)
+        self.scale = float(scale) if scale else None
+        if self.scale and window:
+            raise ValueError("EVA attention takes no softmax scale")
         # EVA attention (ops/pallas/eva_attention.py): exact keys of the
         # current ``window`` positions beside one learned summary (``phi``,
         # ``mu`` a head) for every ``chunk`` earlier tokens. None = every
@@ -371,7 +376,8 @@ class MultiHeadAttention(Module):
             with jax.named_scope("attn_qkv"):
                 q = apply_rope(q, 0, self.rope_theta)
                 k = apply_rope(k, 0, self.rope_theta)
-        attn = sdpa(q, k, v, causal=self.causal, backend=self.backend)
+        attn = sdpa(q, k, v, causal=self.causal, scale=self.scale,
+                    backend=self.backend)
         return self._project_out(params, attn, train, rng), state
 
     # -- cached autoregressive decode (exceeds reference) ----------------------
@@ -452,7 +458,7 @@ class MultiHeadAttention(Module):
             k, v = cache["k"], cache["v"]
         # decode follows the model's configured backend — a "pallas" model
         # runs the flash kernel with kv_offset instead of falling back to XLA
-        out = sdpa(q, k, v, causal=True, kv_offset=offset,
+        out = sdpa(q, k, v, causal=True, kv_offset=offset, scale=self.scale,
                    backend=self.backend if self.backend != "ring" else "xla")
         y = self._project_out(params, out, False, None)
         return y, cache
@@ -520,7 +526,7 @@ class MultiHeadAttention(Module):
                                          rows_v, layer=layer)
             out = pa.paged_attention(q[:, :, 0], pages_k, pages_v,
                                      block_tables, kv_lens=offsets + 1,
-                                     layer=layer)
+                                     layer=layer, scale=self.scale)
             y = self._project_out(params, out[:, :, None, :], False, None)
             return y, pages_k, pages_v
         if q_lens is None:
@@ -538,7 +544,7 @@ class MultiHeadAttention(Module):
                                       q_lens, layer=layer)
         out = pa.paged_attention(q.transpose(0, 2, 1, 3), pages_k, pages_v,
                                  block_tables, kv_lens=offsets + q_lens,
-                                 q_lens=q_lens, layer=layer)
+                                 q_lens=q_lens, layer=layer, scale=self.scale)
         y = self._project_out(params, out.transpose(0, 2, 1, 3), False, None)
         return y, pages_k, pages_v
 
@@ -598,6 +604,8 @@ class MultiHeadAttention(Module):
             cfg["use_bias"] = False
         if self.window:
             cfg["window"], cfg["chunk"] = self.window, self.chunk
+        if self.scale:
+            cfg["scale"] = self.scale
         return cfg
 
 
@@ -1086,7 +1094,7 @@ class GatedAttention(_ProjectAndNorm, Module):
         return cfg
 
 
-# -- gated delta-rule linear attention: a state, no cache of positions ------
+# -- mixers that keep a STATE, no cache of positions ------------------------
 
 # a serving step whose row starts at a multiple of this many positions keeps
 # the state it read in one of the row's two snapshot slots, by turns: what a
@@ -1106,8 +1114,174 @@ def snapshot_slots(slots, offsets, xp=jnp):
     return xp.where(keep, 2 * (slots - 1) + turn + 1, 0).astype(xp.int32)
 
 
+def _inv_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))
+
+
+def _decay_init(k_a, k_dt, heads: int, a_min: float):
+    """(``A_log``, ``dt_bias``) (heads,) float32 in the initial ranges both
+    state mixers' families publish: ``A`` uniform in [a_min, 16), the step
+    ``dt`` log-uniform in [1e-3, 0.1] through the inverse softplus."""
+    return (jnp.log(jax.random.uniform(k_a, (heads,), jnp.float32, a_min,
+                                       16.0)),
+            _inv_softplus(jnp.exp(jax.random.uniform(
+                k_dt, (heads,), jnp.float32, math.log(1e-3),
+                math.log(0.1)))))
+
+
+class _StateMixer(_ProjectAndNorm):
+    """What the mixers share whose memory of the context is a STATE updated
+    in place at every position, and the ONE protocol the serving path asks
+    of either (``serving.kv_pool.StateSlots``, ``models.llama``):
+
+      ``conv_rows``    the shape a slot's ``conv - 1`` kept positions of
+                       ``channels`` values rest in (the compute dtype)
+      ``rec_shape``    a row's recurrent state in one layer, float32
+      ``apply_state``  one step against the pool's state slots
+
+    A mixer is: a projection (``_project`` -> ``u`` the convolution's
+    channels, ``z`` the output gate, and what else its recurrence reads), a
+    causal depthwise convolution of ``conv`` taps then SiLU, a recurrence in
+    float32 (``_heads`` -> its operands a head; ``_step``: ONE position a row
+    against the slots, a kernel on the chip; ``_closed_form``: a chunk of
+    positions from a gathered state, ``_ops().SUB`` positions a sub-chunk;
+    ``_ops`` is the mixer's module of ``ops.pallas``, imported late), and
+    ``_project_out``. Its four scopes in a device profile are ``scope`` +
+    ``_proj`` / ``_conv`` / ``_state`` / ``_out`` (docs/observability.md)."""
+
+    scope = ""
+
+    def _convolve(self, params, u, conv0, q_lens):
+        """The causal depthwise convolution of u (B, Q, C) behind the
+        ``conv - 1`` positions ``conv0`` (B, conv - 1, C) kept from before
+        (plus ``conv_bias`` where the mixer has one), then SiLU; and the
+        positions to keep for the next step: the last ``conv - 1`` before
+        position ``q_lens[b]`` (None: all ``Q`` live)."""
+        with jax.named_scope(self.scope + "_conv"):
+            taps = self.conv
+            qw = u.shape[1]
+            ext = jnp.concatenate([conv0.astype(u.dtype), u], axis=1)
+            w = params["conv_kernel"].astype(jnp.float32)
+            c = sum(w[j] * ext[:, j:j + qw].astype(jnp.float32)
+                    for j in range(taps))
+            if "conv_bias" in params:
+                c = c + params["conv_bias"].astype(jnp.float32)
+            if q_lens is None:
+                kept = ext[:, qw:]
+            else:
+                at = q_lens[:, None] + jnp.arange(taps - 1)[None, :]
+                kept = jnp.take_along_axis(ext, at[:, :, None], axis=1)
+            return jax.nn.silu(c), kept
+
+    def _scan(self, params, parts, rec0):
+        """``_closed_form`` over a chunk of any width: past ``SUB``
+        positions the chunk is padded to whole sub-chunks (a padding
+        position, all zeros, leaves the state alone)."""
+        qw, sub = parts[0].shape[1], self._ops().SUB
+        pad = -qw % sub if qw > sub else 0
+        if pad:
+            parts = tuple(jnp.pad(
+                t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                for t in parts)
+        o, rec1 = self._closed_form(params, parts, rec0)
+        return o[:, :qw], rec1
+
+    def _mix(self, params, x, conv0, rec0, q_lens=None):
+        """The layer over a chunk from the state (conv0, rec0) a row: (y (B,
+        Q, D), the conv positions and the states to keep)."""
+        u, z, *gates = self._project(params, x)
+        c, conv1 = self._convolve(params, u, conv0, q_lens)
+        with jax.named_scope(self.scope + "_state"):
+            o, rec1 = self._scan(
+                params, self._heads(params, c, *gates, q_lens), rec0)
+        return self._project_out(params, o, z), conv1, rec1
+
+    @property
+    def conv_rows(self):
+        """The shape a slot's ``conv - 1`` kept positions REST in: whole
+        (16, 128) registers of two-byte values, 512 lanes wide, where they
+        fill them, else rows of 128 lanes where they fill those (the pool's
+        array is then gathered and scattered a slot at a time in the layout
+        it rests in, with no copy of it: three rows of positions are no
+        whole tile, and the compiler would lay the array out anew at a
+        program's entry and exit); else as they are, ``(conv - 1,
+        channels)``."""
+        total = (self.conv - 1) * self.channels
+        if total % (16 * 512) == 0:
+            return (total // 512, 512)
+        if total % 128 == 0:
+            return (total // 128, 128)
+        return (self.conv - 1, self.channels)
+
+    def _zero_state(self, batch: int):
+        return (jnp.zeros((batch, self.conv - 1, self.channels),
+                          self.policy.compute_dtype),
+                jnp.zeros((batch,) + self.rec_shape, jnp.float32))
+
+    def _apply(self, params, state, x, *, train, rng):
+        y, _, _ = self._mix(params, x, *self._zero_state(x.shape[0]))
+        return y, state
+
+    # -- cached decode (the offline ``generate``): the cache IS the state --
+
+    def init_cache(self, batch: int, max_len: int, d_model: int):
+        conv, rec = self._zero_state(batch)
+        return {"conv": conv, "rec": rec}
+
+    def apply_cached(self, variables, x, cache, offset):
+        y, conv, rec = self._mix(variables["params"], x, cache["conv"],
+                                 cache["rec"])
+        return y, {"conv": conv, "rec": rec}
+
+    # -- the serving step -----------------------------------------------------
+
+    def apply_state(self, variables, x, state, slots, offsets, layer: int,
+                    q_lens=None):
+        """One step against the pool's state slots (``serving.kv_pool``:
+        ``conv`` (L, S) + ``conv_rows``, ``rec`` (L, S) + ``rec_shape``
+        float32, and the snapshots ``conv_snap`` / ``rec_snap``): x (B, Q,
+        D) with ``q_lens[b]`` live tokens a row (None: the decode form,
+        every row ONE token) from position ``offsets[b]``, row ``b``'s state
+        in slot ``slots[b]``. A row at position 0 starts from zeros whatever
+        its slot holds; a row at a multiple of ``SNAPSHOT_EVERY`` keeps the
+        state it read (``snapshot_slots``). Returns (y (B, Q, D), state)."""
+        params = variables["params"]
+        snaps = snapshot_slots(slots, offsets)
+        fresh = (offsets == 0)
+        u, z, *gates = self._project(params, x)
+        with jax.named_scope(self.scope + "_conv"):
+            conv0 = state["conv"][layer, slots]
+            conv0 = jnp.where(fresh[:, None, None], 0, conv0)
+            state = dict(state, conv_snap=state["conv_snap"].at[
+                layer, snaps].set(conv0))
+            conv0 = conv0.reshape(-1, self.conv - 1, self.channels)
+        c, conv1 = self._convolve(params, u, conv0, q_lens)
+        with jax.named_scope(self.scope + "_conv"):
+            state["conv"] = state["conv"].at[layer, slots].set(
+                conv1.astype(state["conv"].dtype).reshape(
+                    (-1,) + self.conv_rows))
+        with jax.named_scope(self.scope + "_state"):
+            parts = self._heads(params, c, *gates, q_lens)
+            if q_lens is None:
+                o, rec, snap = self._step(
+                    params, tuple(t[:, 0] for t in parts), state["rec"],
+                    state["rec_snap"], slots, snaps, layer)
+                o = o[:, None]
+            else:
+                rec0 = jnp.where(fresh[:, None, None, None], 0.0,
+                                 state["rec"][layer, slots])
+                snap = state["rec_snap"].at[layer, snaps].set(rec0)
+                o, rec1 = self._scan(params, parts, rec0)
+                rec = state["rec"].at[layer, slots].set(rec1)
+            state.update(rec=rec, rec_snap=snap)
+        return self._project_out(params, o, z), state
+
+    def output_shape(self, input_shape):
+        return tuple(input_shape)
+
+
 @register_module("gated_delta_net")
-class GatedDeltaNet(_ProjectAndNorm, Module):
+class GatedDeltaNet(_StateMixer, Module):
     """Gated DeltaNet over (N, S, D): linear attention whose memory of the
     context is a STATE updated in place at every position, not a cache that
     grows: ``key_heads`` query/key heads of ``key_dim``, ``value_heads``
@@ -1126,14 +1300,17 @@ class GatedDeltaNet(_ProjectAndNorm, Module):
     ``[q | k | v]`` before the convolution (the policy's compute dtype) and
     ``S``, (value_heads, key_dim, value_dim) FLOAT32. ``_apply`` and
     ``apply_cached`` run the chunked closed form from a zero (or the cache's)
-    state; ``apply_state`` is the serving step against the pool's state
-    slots: a decode step through ``ops.pallas.gdn_step`` (one read and one
-    write of each state), a prompt chunk through the chunked form.
+    state; ``apply_state`` (``_StateMixer``) is the serving step against the
+    pool's state slots: a decode step through ``ops.pallas.gdn_step`` (one
+    read and one write of each state), a prompt chunk through the chunked
+    form.
 
     Leaves: ``qkvz_kernel`` (D, 2 key_heads key_dim + 2 value_heads
     value_dim), ``ba_kernel`` (D, 2 value_heads), ``conv_kernel`` (conv,
     channels), ``A_log`` and ``dt_bias`` (value_heads,) float32, ``norm``
     (value_dim,), ``out_kernel`` (value_heads value_dim, D)."""
+
+    scope = "gdn"
 
     def __init__(self, key_heads: int, value_heads: int, key_dim: int,
                  value_dim: int, conv: int = 4, norm_eps: float = 1e-6,
@@ -1148,28 +1325,25 @@ class GatedDeltaNet(_ProjectAndNorm, Module):
         self.qk = self.key_heads * self.key_dim
         self.vz = self.value_heads * self.value_dim
         self.channels = 2 * self.qk + self.vz       # what the conv runs over
+        self.rec_shape = (self.value_heads, self.key_dim, self.value_dim)
 
     def _init(self, rng, input_shape):
         d, hv = input_shape[-1], self.value_heads
         init = initializers.get("xavier_uniform")
         ks = jax.random.split(rng, 6)
         pd = self.policy.param_dtype
+        a_log, dt_bias = _decay_init(ks[3], ks[4], hv, 1e-3)
         return {
             "qkvz_kernel": init(ks[0], (d, self.channels + self.vz), pd),
             "ba_kernel": init(ks[1], (d, 2 * hv), pd),
             "conv_kernel": (jax.random.normal(
                 ks[2], (self.conv, self.channels), jnp.float32)
                 / math.sqrt(self.conv)).astype(pd),
-            # the family's initial ranges: A in [0, 16), dt in [1e-3, 0.1]
-            "A_log": jnp.log(jax.random.uniform(
-                ks[3], (hv,), jnp.float32, 1e-3, 16.0)),
-            "dt_bias": _inv_softplus(jnp.exp(jax.random.uniform(
-                ks[4], (hv,), jnp.float32, math.log(1e-3), math.log(0.1)))),
+            "A_log": a_log,
+            "dt_bias": dt_bias,
             "norm": jnp.ones((self.value_dim,), pd),
             "out_kernel": init(ks[5], (self.vz, d), pd),
         }, {}
-
-    # -- the four parts of the layer (docs/observability.md) ---------------
 
     @jax.named_scope("gdn_proj")
     def _project(self, params, x):
@@ -1181,25 +1355,6 @@ class GatedDeltaNet(_ProjectAndNorm, Module):
         hv = self.value_heads
         return (qkvz[..., :self.channels], qkvz[..., self.channels:],
                 ba[..., :hv], ba[..., hv:])
-
-    @jax.named_scope("gdn_conv")
-    def _convolve(self, params, u, conv0, q_lens):
-        """The causal depthwise convolution of u (B, Q, C) behind the
-        ``conv - 1`` positions ``conv0`` (B, conv - 1, C) kept from before,
-        then SiLU; and the positions to keep for the next step: the last
-        ``conv - 1`` before position ``q_lens[b]`` (None: all ``Q`` live)."""
-        taps = self.conv
-        qw = u.shape[1]
-        ext = jnp.concatenate([conv0.astype(u.dtype), u], axis=1)
-        w = params["conv_kernel"].astype(jnp.float32)
-        c = sum(w[j] * ext[:, j:j + qw].astype(jnp.float32)
-                for j in range(taps))
-        if q_lens is None:
-            kept = ext[:, qw:]
-        else:
-            at = q_lens[:, None] + jnp.arange(taps - 1)[None, :]
-            kept = jnp.take_along_axis(ext, at[:, :, None], axis=1)
-        return jax.nn.silu(c), kept
 
     def _heads(self, params, c, b_raw, a_raw, q_lens):
         """The convolved channels as what the rule reads, a VALUE head each,
@@ -1226,6 +1381,20 @@ class GatedDeltaNet(_ProjectAndNorm, Module):
             g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
         return q, k, v, g, beta
 
+    @staticmethod
+    def _ops():
+        from ..ops.pallas import gdn_step
+
+        return gdn_step
+
+    def _step(self, params, parts, rec, snap, slots, snaps, layer):
+        return self._ops().gdn_step(*parts, rec, snap, slots, snaps,
+                                    layer=layer)
+
+    def _closed_form(self, params, parts, rec0):
+        ops = self._ops()
+        return ops.gdn_chunk(*parts, rec0, sub=ops.SUB)
+
     @jax.named_scope("gdn_out")
     def _project_out(self, params, o, z):
         """o (B, Q, Hv, Dv) float32, z (B, Q, vz): the gated norm a head,
@@ -1238,118 +1407,141 @@ class GatedDeltaNet(_ProjectAndNorm, Module):
         return self.policy.cast_out(
             self._mm(y.astype(z.dtype), params["out_kernel"]))
 
-    def _scan(self, parts, rec0):
-        """``gdn_chunk`` over (q, k, v, g, beta) of any width: past ``SUB``
-        positions the chunk is padded to whole sub-chunks (a padding
-        position leaves the state alone)."""
-        from ..ops.pallas.gdn_step import SUB, gdn_chunk
-
-        qw = parts[0].shape[1]
-        pad = -qw % SUB if qw > SUB else 0
-        if pad:
-            parts = tuple(jnp.pad(
-                t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                for t in parts)
-        o, rec1 = gdn_chunk(*parts, rec0)
-        return o[:, :qw], rec1
-
-    def _mix(self, params, x, conv0, rec0, q_lens=None):
-        """The layer over a chunk from the state (conv0, rec0) a row: (y (B,
-        Q, D), the conv positions and the states to keep)."""
-        u, z, b_raw, a_raw = self._project(params, x)
-        c, conv1 = self._convolve(params, u, conv0, q_lens)
-        with jax.named_scope("gdn_state"):
-            o, rec1 = self._scan(
-                self._heads(params, c, b_raw, a_raw, q_lens), rec0)
-        return self._project_out(params, o, z), conv1, rec1
-
-    @property
-    def conv_rows(self):
-        """The shape a slot's ``conv - 1`` kept positions REST in: whole
-        (16, 128) registers of two-byte values, 512 lanes wide, where they
-        fill them (the pool's array is then gathered and scattered a slot at
-        a time in the layout it rests in, with no copy of it); else as they
-        are, ``(conv - 1, channels)``."""
-        total = (self.conv - 1) * self.channels
-        if total % (16 * 512):
-            return (self.conv - 1, self.channels)
-        return (total // 512, 512)
-
-    def _zero_state(self, batch: int):
-        return (jnp.zeros((batch, self.conv - 1, self.channels),
-                          self.policy.compute_dtype),
-                jnp.zeros((batch, self.value_heads, self.key_dim,
-                           self.value_dim), jnp.float32))
-
-    def _apply(self, params, state, x, *, train, rng):
-        y, _, _ = self._mix(params, x, *self._zero_state(x.shape[0]))
-        return y, state
-
-    # -- cached decode (the offline ``generate``): the cache IS the state --
-
-    def init_cache(self, batch: int, max_len: int, d_model: int):
-        conv, rec = self._zero_state(batch)
-        return {"conv": conv, "rec": rec}
-
-    def apply_cached(self, variables, x, cache, offset):
-        y, conv, rec = self._mix(variables["params"], x, cache["conv"],
-                                 cache["rec"])
-        return y, {"conv": conv, "rec": rec}
-
-    # -- the serving step -----------------------------------------------------
-
-    def apply_state(self, variables, x, state, slots, offsets, layer: int,
-                    q_lens=None):
-        """One step against the pool's state slots (``serving.kv_pool``:
-        ``conv`` (L, S) + ``conv_rows``, ``rec`` (L, S, Hv, Dk, Dv) float32,
-        and the snapshots ``conv_snap`` / ``rec_snap``): x (B, Q, D) with
-        ``q_lens[b]`` live tokens a row (None: the decode form, every row
-        ONE token) from position ``offsets[b]``, row ``b``'s state in slot
-        ``slots[b]``. A row at position 0 starts from zeros whatever its slot
-        holds; a row at a multiple of ``SNAPSHOT_EVERY`` keeps the state it
-        read (``snapshot_slots``). Returns (y (B, Q, D), state)."""
-        from ..ops.pallas.gdn_step import gdn_step
-
-        params = variables["params"]
-        snaps = snapshot_slots(slots, offsets)
-        fresh = (offsets == 0)
-        u, z, b_raw, a_raw = self._project(params, x)
-        with jax.named_scope("gdn_conv"):
-            conv0 = state["conv"][layer, slots]
-            conv0 = jnp.where(fresh[:, None, None], 0, conv0)
-            state = dict(state, conv_snap=state["conv_snap"].at[
-                layer, snaps].set(conv0))
-            conv0 = conv0.reshape(-1, self.conv - 1, self.channels)
-        c, conv1 = self._convolve(params, u, conv0, q_lens)
-        with jax.named_scope("gdn_conv"):
-            state["conv"] = state["conv"].at[layer, slots].set(
-                conv1.astype(state["conv"].dtype).reshape(
-                    (-1,) + self.conv_rows))
-        with jax.named_scope("gdn_state"):
-            q, k, v, g, beta = self._heads(params, c, b_raw, a_raw, q_lens)
-            if q_lens is None:
-                o, rec, snap = gdn_step(
-                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                    state["rec"], state["rec_snap"], slots, snaps,
-                    layer=layer)
-                o = o[:, None]
-            else:
-                rec0 = jnp.where(fresh[:, None, None, None], 0.0,
-                                 state["rec"][layer, slots])
-                snap = state["rec_snap"].at[layer, snaps].set(rec0)
-                o, rec1 = self._scan((q, k, v, g, beta), rec0)
-                rec = state["rec"].at[layer, slots].set(rec1)
-            state.update(rec=rec, rec_snap=snap)
-        return self._project_out(params, o, z), state
-
-    def output_shape(self, input_shape):
-        return tuple(input_shape)
-
     def _config(self):
         return {"key_heads": self.key_heads, "value_heads": self.value_heads,
                 "key_dim": self.key_dim, "value_dim": self.value_dim,
                 "conv": self.conv, "norm_eps": self.norm_eps}
 
 
-def _inv_softplus(y):
-    return y + jnp.log(-jnp.expm1(-y))
+@register_module("mamba2")
+class Mamba2(_StateMixer, Module):
+    """A Mamba-2 (state-space duality) mixer over (N, S, D): ``heads`` heads
+    of ``head_dim`` (``d_inner = heads head_dim``, the model's ``expand``
+    times D), each with a state ``(head_dim, state)`` float32 that a scalar
+    decay a head shrinks and an outer product grows; ``B`` and ``C`` (state,)
+    are shared by all heads (ONE group); a causal depthwise convolution of
+    ``conv`` taps WITH a bias over ``[x | B | C]`` in front.
+
+        [z | xBC | dt] = x W_in
+        [x | B | C] <- silu(conv_t(xBC) + b_c)
+        D_t = softplus(dt + dt_bias);  a_t = exp(-exp(A_log) D_t)   (a head)
+        S <- a_t S + (D_t x_t) B_t^T;  y_t = S C_t + D x_t
+        out = (w * g rsqrt(mean g^2 + eps)) W_out,  g = y * silu(z)
+
+    The gate comes BEFORE the norm, and the norm runs over all ``d_inner``
+    channels. Nothing clamps the step (the family's ``time_step_limit`` is
+    (0, inf)). What a row carries from one step to the next is ``conv - 1``
+    positions of ``xBC`` before the convolution and ``S``; the serving step
+    (``_StateMixer.apply_state``) runs a decode row through
+    ``ops.pallas.mamba2_step`` (one read and one write of each state) and a
+    prompt chunk through the chunked closed form (``ssd_chunk``).
+
+    Leaves: ``in_kernel`` (D, 2 d_inner + 2 state + heads) laid ``[z | xBC |
+    dt]``, ``conv_kernel`` (conv, d_inner + 2 state), ``conv_bias``,
+    ``A_log``, ``dt_bias`` and ``D`` (heads,) float32, ``norm`` (d_inner,),
+    ``out_kernel`` (d_inner, D)."""
+
+    scope = "ssm"
+
+    def __init__(self, heads: int, head_dim: int, state: int, conv: int = 4,
+                 norm_eps: float = 1e-5, name=None, policy=None):
+        super().__init__(name=name, policy=policy)
+        self.heads, self.head_dim = int(heads), int(head_dim)
+        self.state, self.conv = int(state), int(conv)
+        self.norm_eps = float(norm_eps)
+        self.inner = self.heads * self.head_dim
+        self.channels = self.inner + 2 * self.state  # what the conv runs over
+        self.rec_shape = (self.heads, self.head_dim, self.state)
+
+    def _init(self, rng, input_shape):
+        d = input_shape[-1]
+        init = initializers.get("xavier_uniform")
+        ks = jax.random.split(rng, 5)
+        pd = self.policy.param_dtype
+        a_log, dt_bias = _decay_init(ks[2], ks[3], self.heads, 1.0)
+        return {
+            "in_kernel": init(ks[0], (d, self.inner + self.channels
+                                      + self.heads), pd),
+            "conv_kernel": (jax.random.normal(
+                ks[1], (self.conv, self.channels), jnp.float32)
+                / math.sqrt(self.conv)).astype(pd),
+            "conv_bias": jnp.zeros((self.channels,), pd),
+            "A_log": a_log,
+            "dt_bias": dt_bias,
+            "D": jnp.ones((self.heads,), jnp.float32),
+            "norm": jnp.ones((self.inner,), pd),
+            "out_kernel": init(ks[4], (self.inner, d), pd),
+        }, {}
+
+    @jax.named_scope("ssm_proj")
+    def _project(self, params, x):
+        """x (B, Q, D) -> u (B, Q, channels) ``[x | B | C]`` before the
+        convolution, z (B, Q, d_inner), dt (B, Q, heads)."""
+        zxd = self._mm(self.policy.cast_in(x), params["in_kernel"])
+        at = self.inner + self.channels
+        return zxd[..., self.inner:at], zxd[..., :self.inner], zxd[..., at:]
+
+    def _heads(self, params, c, dt_raw, q_lens):
+        """The convolved channels as what the recurrence reads, float32: x
+        (B, Q, H, P), the step dt and the log decay la (B, Q, H), B and C
+        (B, Q, N). A padding position (past ``q_lens``) gets ``dt`` = 0, so
+        ``la`` = 0: it leaves the state as it was."""
+        b, qw = c.shape[:2]
+        x = c[..., :self.inner].reshape(b, qw, self.heads, self.head_dim)
+        bm = c[..., self.inner:self.inner + self.state]
+        cm = c[..., self.inner + self.state:]
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + params["dt_bias"].astype(jnp.float32))
+        if q_lens is not None:
+            live = (jnp.arange(qw)[None, :] < q_lens[:, None])[..., None]
+            dt = jnp.where(live, dt, 0.0)
+        la = -jnp.exp(params["A_log"].astype(jnp.float32)) * dt
+        return x, dt, la, bm, cm
+
+    def _skip(self, params, y, x):
+        return y + params["D"].astype(jnp.float32)[:, None] * x
+
+    @staticmethod
+    def _ops():
+        from ..ops.pallas import mamba2_step
+
+        return mamba2_step
+
+    def _step(self, params, parts, rec, snap, slots, snaps, layer):
+        y, rec, snap = self._ops().mamba2_step(*parts, rec, snap, slots,
+                                               snaps, layer=layer)
+        return self._skip(params, y, parts[0]), rec, snap
+
+    def _closed_form(self, params, parts, rec0):
+        ops = self._ops()
+        y, rec1 = ops.ssd_chunk(*parts, rec0, sub=ops.SUB)
+        return self._skip(params, y, parts[0]), rec1
+
+    @jax.named_scope("ssm_out")
+    def _project_out(self, params, y, z):
+        """y (B, Q, H, P) float32, z (B, Q, d_inner): the gate, THEN one
+        norm over all ``d_inner`` channels, then ``W_out``."""
+        b, qw = y.shape[:2]
+        g = y.reshape(b, qw, self.inner) * jax.nn.silu(z.astype(jnp.float32))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + self.norm_eps) \
+            * params["norm"].astype(jnp.float32)
+        return self.policy.cast_out(
+            self._mm(g.astype(z.dtype), params["out_kernel"]))
+
+    def _config(self):
+        return {"heads": self.heads, "head_dim": self.head_dim,
+                "state": self.state, "conv": self.conv,
+                "norm_eps": self.norm_eps}
+
+
+# the mixers a model's ``linear`` keywords may name (``mixer``), by the name
+# each is registered under
+STATE_MIXERS = {"gated_delta_net": GatedDeltaNet, "mamba2": Mamba2}
+
+
+def state_mixer(linear: dict, **kw):
+    """The state mixer a model's ``linear`` keywords describe: ``mixer``
+    names its class (absent: Gated DeltaNet), the rest are its own."""
+    linear = dict(linear)
+    return STATE_MIXERS[linear.pop("mixer", "gated_delta_net")](**linear, **kw)

@@ -939,7 +939,8 @@ class InferenceEngine:
             # a row's two snapshots take turns: odd slots the even turns
             rows[i].snap_at[(keeps[i] + 1) % 2] = int(starts[i])
         self.metrics.observe_state_step(self.pool.slots.occupancy,
-                                        int(np.count_nonzero(keeps)))
+                                        int(np.count_nonzero(keeps)),
+                                        self.pool.slots.nbytes)
 
     def _restore_fn(self):
         """``tnn_state_restore``: the named rows' snapshot into their live

@@ -167,8 +167,10 @@ class StateSlots:
         self.rec_shape = tuple(group["rec"])
         self.dtype, self.sharding = dtype, sharding
         self._free: List[int] = list(range(self.rows, 0, -1))
-        # made by ``reset`` (the pool's ``reset_pages`` calls it)
+        # made by ``reset`` (the pool's ``reset_pages`` calls it), with
+        # ``nbytes``: the four arrays' bytes, live states and snapshots
         self.arrays: Dict[str, jax.Array] = {}
+        self.nbytes = 0
 
     def reset(self) -> None:
         """Fresh zeroed arrays (explicit puts: ``PagedKVPool.reset_pages``
@@ -184,6 +186,7 @@ class StateSlots:
             "rec": zeros(live, self.rec_shape, np.float32),
             "conv_snap": zeros(kept, self.conv_shape, self.dtype),
             "rec_snap": zeros(kept, self.rec_shape, np.float32)}
+        self.nbytes = sum(int(x.nbytes) for x in self.arrays.values())
 
     @property
     def num_free(self) -> int:

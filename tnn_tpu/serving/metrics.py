@@ -276,6 +276,9 @@ _DIRECT_FAMILIES: Tuple[Tuple[str, str, str, str], ...] = (
     ("state_slots_occupancy_max", "tnn_serve_state_slots_occupancy_max",
      "gauge", "Largest share of the pool's state slots held by running "
      "requests (a model with layers that keep a state)"),
+    ("state_bytes", "tnn_serve_state_bytes", "gauge",
+     "Bytes of the pool's state arrays, live states and snapshots (a model "
+     "with layers that keep a state)"),
     ("state_snapshots", "tnn_serve_state_snapshots_total", "counter",
      "Row states kept in a snapshot slot by a dispatched step"),
     ("state_restores", "tnn_serve_state_restores_total", "counter",
@@ -552,6 +555,7 @@ class ServingMetrics:
         # a model with state slots (kv_pool: State slots)
         self.state_steps = 0
         self.state_slots_occupancy_max = 0.0
+        self.state_bytes = 0
         self.state_snapshots = 0
         self.state_restores = 0
         self.state_replayed_tokens = 0
@@ -771,11 +775,13 @@ class ServingMetrics:
         self.win_pool_occupancy_max = max(self.win_pool_occupancy_max,
                                           occupancy)
 
-    def observe_state_step(self, occupancy: float, snapshots: int) -> None:
+    def observe_state_step(self, occupancy: float, snapshots: int,
+                           nbytes: int) -> None:
         """One dispatched step of a model with state slots: the share of
-        the slots held, and how many of its rows kept the state they read
-        in a snapshot."""
+        the slots held, how many of its rows kept the state they read in a
+        snapshot, and the bytes of the pool's four state arrays."""
         self.state_steps += 1
+        self.state_bytes = int(nbytes)
         self.state_slots_occupancy_max = max(self.state_slots_occupancy_max,
                                              occupancy)
         self.state_snapshots += int(snapshots)
@@ -1254,6 +1260,7 @@ class ServingMetrics:
             # only a model with state slots has these
             out.update(
                 state_slots_occupancy_max=self.state_slots_occupancy_max,
+                state_bytes=self.state_bytes,
                 state_snapshots=self.state_snapshots,
                 state_restores=self.state_restores,
                 state_replayed_tokens=self.state_replayed_tokens)
