@@ -21,7 +21,9 @@ comes out by the repo's own means. Phases, each printing its name and result:
              forms, among them the flash kernels at the training cell's call
              (forward, and forward + backward) with their sub-tile plan, and
              the KV row write at the six serving configurations' pages
-             against the whole-page form, bit for bit, in a donated pool.
+             against the whole-page form, bit for bit, in a donated pool,
+             and ``tnn_eva_attention`` at the EvaByte cell's shape (decode
+             form and a chunk of 256) with the time a launch.
   serve      nine token-id requests (prompts of 5..700 tokens, two sharing a
              96-token prefix) through ``tnn-serve --model gpt2_small
              --num-blocks 512 --block-size 16 --max-batch-size 8``, every
@@ -127,6 +129,11 @@ CHIP = dict(
     # the flash kernels at gpt2-medium.train's call: batch 8, 16 heads of
     # 64 over 1,024 causal positions
     flash=dict(batch=8, heads=16, seq=1024, head_dim=64, iters=10),
+    # the EVA kernel at evabyte-pp2.decode-docs' shape: 8 rows of 32 heads of
+    # 128 over pages of 128, a window of 16 exact pages beside 16 summary
+    # pages, the decode form and a prompt chunk of 256 (2 layers of the 16)
+    eva=dict(layers=2, blocks=224, heads=32, block_size=128, head_dim=128,
+             batch=8, window=2048, summaries=2048, chunk=256, iters=20),
     # the row write at the six serving configurations' pages, (page rows of
     # a position, positions a page, lanes a row): a step's rows and one
     # prompt chunk beside them, into a donated pool of 2 layers
@@ -164,6 +171,8 @@ REHEARSAL = dict(
     heads64=dict(blocks=16, block_size=8, heads=8, kv_heads=2, head_dim=64,
                  batch=3, table=6, chunk=8),
     flash=dict(batch=1, heads=2, seq=64, head_dim=32, iters=1),
+    eva=dict(layers=1, blocks=32, heads=4, block_size=8, head_dim=32,
+             batch=3, window=32, summaries=24, chunk=8, iters=1),
     row_write=dict(pages={"packed": (2, 16, 128), "latent": (1, 32, 256)},
                    batch=3, table=3, chunk=64, iters=1),
     sampler=dict(shapes=((4, 320),), iters=2),
@@ -311,7 +320,7 @@ def phase_kernel(cfg) -> list:
     return failures + _latent_and_expert_kernels(cfg, rand, rng) \
         + _window_kernel(cfg, rand, rng) + _state_kernels(cfg, rand, rng) \
         + _short_rows(cfg, rand, rng) + _row_writes(cfg, rand, rng) \
-        + _flash_training_call(cfg, rand) \
+        + _flash_training_call(cfg, rand) + _eva_kernel(cfg, rand, rng) \
         + _sampler_steps(cfg, rng)
 
 
@@ -401,6 +410,21 @@ def _state_kernels(cfg, rand, rng) -> list:
     return failures
 
 
+def _best_of_three(call, iters) -> float:
+    """ms a ``call()``: the best of three times ``iters`` calls, each time
+    from the host around ``block_until_ready`` of the last."""
+    import jax
+
+    took = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            last = call()
+        jax.block_until_ready(last)
+        took.append((time.perf_counter() - t0) / iters * 1e3)
+    return min(took)
+
+
 def _short_rows(cfg, rand, rng) -> list:
     """What a short row costs in a wide launch: the paged kernel alone over
     every layer of a pool of two heads a page row, all rows but one decoding
@@ -435,14 +459,8 @@ def _short_rows(cfg, rand, rng) -> list:
 
         x = q if q_lens is not None else q[:, 0]
         out = np.asarray(every_layer(x, pk, pv))           # compiles
-        took = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(k["iters"]):
-                last = every_layer(x, pk, pv)
-            jax.block_until_ready(last)
-            took.append((time.perf_counter() - t0) / k["iters"] * 1e3)
-        return out, min(took)
+        return out, _best_of_three(lambda: every_layer(x, pk, pv),
+                                   k["iters"])
 
     decode, t_decode = launch(None)
     short, t_short = launch([1] * (B - 1) + [qw])
@@ -587,6 +605,62 @@ def _flash_training_call(cfg, rand) -> list:
                                 (fa.DEFAULT_BLOCK_FUSED_BWD, fa.SUB_TILE))]
     log("flash sub-tiles of a head (unmasked, masked, left out): forward "
         f"{plans[0]}, backward {plans[1]}")
+    return failures
+
+
+def _eva_kernel(cfg, rand, rng) -> list:
+    """``tnn_eva_attention`` against its ``jax.numpy`` path at the EvaByte
+    cell's shape, the decode form and a prompt chunk: windows half full on
+    average, a row at a window's first position, a row with no summaries, a
+    full window; and the time a launch (a layer's call), from the host
+    around ``block_until_ready`` over every layer of the pool."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tnn_tpu.ops.pallas import eva_attention as eva
+    from tnn_tpu.ops.pallas.paged_attention import fetch_group
+
+    k = cfg["eva"]
+    L, N, H, bs, dh, B = (k["layers"], k["blocks"], k["heads"],
+                          k["block_size"], k["head_dim"], k["batch"])
+    W, n_exact, n_sum = (k["window"], k["window"] // bs,
+                         k["summaries"] // bs)
+    pk, pv = (rand((L, N, H, bs, dh)) for _ in range(2))
+    tables = jnp.asarray(rng.integers(1, N, (B, n_exact + n_sum)), jnp.int32)
+    # window-relative lengths from a window's first position to its last,
+    # summary rows from none to all but a page's
+    exact = np.linspace(1, W, B).astype(np.int32)
+    sums = np.linspace(k["summaries"] - bs, 0, B).astype(np.int32)
+    sums[::2] = sums[::2][::-1]
+    failures = []
+    for fname, qw in (("decode", 1), (f"chunk{k['chunk']}", k["chunk"])):
+        q_lens = np.array([qw, 1, qw // 2 + 1] * B)[:B].astype(np.int32)
+        args = (rand((B, qw, H, dh)), pk, pv, tables,
+                jnp.asarray(np.maximum(exact, q_lens)), jnp.asarray(sums))
+        kw = dict(n_exact=n_exact, q_lens=jnp.asarray(q_lens))
+        _check_close(
+            failures, f"eva {fname}",
+            eva.eva_attention(*args, layer=L - 1, backend="pallas",
+                              interpret=cfg["rehearse"], **kw),
+            eva.eva_attention(*args, layer=L - 1, backend="xla", **kw),
+            KERNEL_TOL["bf16"])
+
+        @jax.jit
+        def every_layer(*args):
+            return sum(eva.eva_attention(
+                *args, layer=i, backend="pallas", interpret=cfg["rehearse"],
+                **kw).astype(jnp.float32) for i in range(L))
+
+        jax.block_until_ready(every_layer(*args))           # compiles
+        took = _best_of_three(lambda: every_layer(*args), k["iters"]) / L
+        pages, heads = fetch_group(
+            bs=bs, dh=dh, hkv=H, qg=qw, page_dtype=pk.dtype,
+            nb=n_exact + n_sum, positions=eva.GROUP_POSITIONS)
+        log(f"eva {fname} {B} rows x {H} heads of {dh}: {took:.3f} ms a "
+            f"launch, a grid step {pages} page(s) of {heads} heads (host "
+            f"clock, the best of three times {k['iters']} calls of {L} "
+            f"layers)")
     return failures
 
 

@@ -264,25 +264,133 @@ def _pool_case(seed, b, hkv, g, dh, bs, n_exact, n_sum, dtype):
     return rng, pk, pv, tables
 
 
+def _kernel_against_numpy(qw, q_lens, exact_lens, sum_lens, positions,
+                          n_exact=4, n_sum=3):
+    """Both paths at heads of 128 over pages of 8 with a group of
+    ``positions`` key positions; returns the kernel's output."""
+    b = len(q_lens)
+    rng, pk, pv, tables = _pool_case(qw, b, 4, 1, 128, 8, n_exact, n_sum,
+                                     jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, qw, 4, 128)), jnp.float32)
+    args = (q, pk, pv, tables, jnp.asarray(exact_lens), jnp.asarray(sum_lens))
+    kw = dict(n_exact=n_exact, q_lens=jnp.asarray(q_lens), layer=1)
+    want = eva.eva_attention(*args, backend="xla", **kw)
+    with mock.patch.object(eva, "GROUP_POSITIONS", positions):
+        pages, _ = pa.fetch_group(bs=8, dh=128, hkv=4, qg=qw,
+                                  page_dtype=jnp.float32, nb=n_exact + n_sum,
+                                  positions=positions)
+        assert pages == max(1, min(positions // 8, n_exact + n_sum))
+        got = eva.eva_attention(*args, backend="pallas", interpret=True, **kw)
+    # the same float32 sums in another order (a running softmax group by
+    # group)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    dead = np.asarray(q_lens)[:, None] <= np.arange(qw)[None]
+    assert not np.asarray(got)[dead].any()     # padding outputs exactly 0
+    return np.asarray(got)
+
+
 @pytest.mark.kernel
 @pytest.mark.parametrize("qw,q_lens,exact_lens,sum_lens", [
     (1, [1, 1, 1], [5, 32, 1], [0, 17, 24]),
     (8, [8, 3, 0], [8, 30, 0], [8, 0, 3]),
     (16, [16, 9, 1], [20, 32, 1], [0, 24, 5])])
-@pytest.mark.parametrize("pages,heads", [(4, 8), (3, 2)])
+@pytest.mark.parametrize("positions", [8, 24, 32])
 def test_kernel_matches_the_numpy_path_at_head_128(qw, q_lens, exact_lens,
-                                                   sum_lens, pages, heads):
-    rng, pk, pv, tables = _pool_case(qw, 3, 4, 1, 128, 8, 4, 3, jnp.float32)
-    q = jnp.asarray(rng.normal(size=(3, qw, 4, 128)), jnp.float32)
-    args = (q, pk, pv, tables, jnp.asarray(exact_lens), jnp.asarray(sum_lens))
-    kw = dict(n_exact=4, q_lens=jnp.asarray(q_lens), layer=1)
-    want = eva.eva_attention(*args, backend="xla", **kw)
-    got = eva.eva_attention(*args, backend="pallas", interpret=True,
-                            pages_per_step=pages, heads_per_step=heads, **kw)
-    # the same float32 sums in another order (a running softmax page by page)
-    assert float(jnp.abs(got - want).max()) < 2e-5
-    dead = np.asarray(q_lens)[:, None] <= np.arange(qw)[None]
-    assert not np.asarray(got)[dead].any()     # padding outputs exactly 0
+                                                   sum_lens, positions):
+    """The group the kernel derives (``fetch_group`` at ``GROUP_POSITIONS``)
+    of 1, 3 and 4 pages over a table of 4 exact and 3 summary entries: at 3
+    the second group straddles ``n_exact``, at 4 the table is padded by one
+    entry; in the decode form, in a chunk, in a chunk wider than a page."""
+    _kernel_against_numpy(qw, q_lens, exact_lens, sum_lens, positions)
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("qw", [1, 8])
+@pytest.mark.parametrize("positions,n_exact", [(24, 4), (16, 3), (40, 6)])
+def test_a_group_may_straddle_n_exact(qw, positions, n_exact):
+    """``n_exact`` no multiple of ``pages``: one group holds the exact
+    segment's last pages and the summaries' first, each slot under its own
+    segment's rule: rows with both segments live in that group, with the
+    exact pages of it dead, with the summaries of it dead, with neither."""
+    width = n_exact * 8
+    _kernel_against_numpy(
+        qw, [qw, qw, qw, qw], [width, qw, width, qw], [19, 24, 0, 0],
+        positions, n_exact=n_exact)
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("positions", [8, 24, 128])
+def test_rows_at_the_edges_of_the_two_segments(positions):
+    """A row with no summary, a row of ONE live position (a window's first,
+    with summaries and without), a row whose window and summaries are full,
+    and a dead row, whose output is exactly 0, in one decode launch."""
+    got = _kernel_against_numpy(1, [1, 1, 1, 1, 0], [17, 1, 1, 32, 0],
+                                [0, 0, 9, 24, 0], positions)
+    assert not got[4].any() and got[:4].any(axis=(1, 2, 3)).all()
+
+
+@pytest.mark.kernel
+def test_a_row_of_one_live_position_returns_its_value():
+    """One exact position and no summary: the softmax over one key is 1, so
+    the output is that position's value row, whatever the group."""
+    rng, pk, pv, tables = _pool_case(3, 1, 4, 1, 128, 8, 4, 3, jnp.float32)
+    q = jnp.asarray(rng.normal(size=(1, 4, 128)), jnp.float32)
+    got = eva.eva_attention(q, pk, pv, tables, jnp.asarray([1]),
+                            jnp.asarray([0]), n_exact=4, layer=1,
+                            backend="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(got[0]),
+                               np.asarray(pv[1, tables[0, 0], :, 0]),
+                               rtol=1e-6)
+
+
+def test_dead_entries_repeat_what_the_pipeline_fetched_last():
+    """``_fetch_table``: live entries as they are; a dead page repeats the
+    last live page of its segment, and a group with no live page repeats the
+    group before it slot for slot (the same block indices: no DMA)."""
+    tables = jnp.arange(100, 107, dtype=jnp.int32)[None].repeat(3, 0)
+    got = np.asarray(eva._fetch_table(
+        tables, jnp.asarray([9, 32, 0]), jnp.asarray([0, 17, 0]), 4, 8, 2))
+    # row 0: exact pages 0-1 live; group 1 (entries 2, 3) dead: repeats
+    # group 0; no summary: groups 2 and 3 (entries 4-7) dead too
+    assert got[0].tolist() == [100, 101] * 4
+    # row 1: all 4 exact pages, 3 summary pages (17 rows of 8); the padding
+    # entry 7 repeats the last live summary page
+    assert got[1].tolist() == [100, 101, 102, 103, 104, 105, 106, 106]
+    # a dead row stays at entry 0 of each segment's clamp, group 0 repeated
+    assert got[2].tolist() == [100, 100] * 4
+    one = np.asarray(eva._fetch_table(
+        tables, jnp.asarray([9, 32, 0]), jnp.asarray([0, 17, 0]), 4, 8, 1))
+    assert one[0].tolist() == [100, 101, 101, 101, 101, 101, 101]
+
+
+def test_fetch_group_at_the_published_shapes():
+    """``fetch_group``'s answers where the benchmark's cells run it: a later
+    change of the budget or of ``group_vmem_bytes`` shows here. EvaByte (32
+    heads of 128 over pages of 128, a table of 16 + 16): the decode form and
+    a chunk of 256; GPT-2 large (10 page rows of two heads of 64, pages of
+    16); Trinity's global and window layers (8 KV heads of 128, 6 queries a
+    head); Mistral's latent rows of 384 lanes under 32 heads."""
+    from tnn_tpu.nn.attention import GATED_GROUP_POSITIONS
+    from tnn_tpu.ops.pallas import mla_attention as mla
+
+    bf16 = jnp.bfloat16
+    evabyte = dict(bs=128, dh=128, hkv=32, page_dtype=bf16, nb=32,
+                   positions=eva.GROUP_POSITIONS)
+    assert pa.fetch_group(qg=1, **evabyte) == (1, 32)
+    assert pa.fetch_group(qg=256, **evabyte) == (1, 8)
+    gpt2 = dict(bs=16, dh=128, hkv=10, page_dtype=bf16, nb=64)
+    assert pa.fetch_group(qg=2, **gpt2) == (8, 10)
+    assert pa.fetch_group(qg=2 * 64, **gpt2) == (8, 10)
+    trinity = dict(bs=128, dh=128, hkv=8, page_dtype=bf16,
+                   positions=GATED_GROUP_POSITIONS)
+    assert pa.fetch_group(qg=6, nb=289, **trinity) == (4, 8)
+    assert pa.fetch_group(qg=6, nb=pa.window_walk(4096, 1, 128, 34),
+                          **trinity) == (4, 8)
+    mistral = dict(bs=128, dh=384, hkv=1, page_dtype=bf16, nb=256,
+                   positions=mla.GROUP_POSITIONS)
+    assert pa.fetch_group(qg=32, **mistral) == (8, 1)
+    # a chunk of 64: tiles of 16 tokens x 32 heads, and half the pages
+    assert pa.fetch_group(qg=mla.query_tile(64, 32) * 32, **mistral) == (4, 1)
 
 
 @pytest.mark.kernel
@@ -560,6 +668,50 @@ def test_eva_window_fill_mean_on_a_hand_counted_run(model, weights):
         8 / (eng.pool.capacity * 8))
     assert "eva_window_fill_mean" not in InferenceEngine(
         models.create("gpt2_tiny"), {}, num_blocks=4).metrics.summary()
+
+
+def test_attn_fetch_fill_mean_counts_both_segments(model, weights):
+    """``summary()["attn_fetch_fill_mean"]`` of a windowed engine: a row's
+    live pages of BOTH segments (the exact pages by its window-relative
+    length, the summary pages by the 8 rows a finished window leaves) over
+    the page slots of the groups ``tnn_eva_attention`` fetches for them,
+    with the group the kernel's own launch derives. Groups of 3 pages here
+    (24 positions), so that one straddles the 4 exact entries."""
+    with mock.patch.object(eva, "GROUP_POSITIONS", 24):
+        eng = engine(model, weights[1])
+        assert "attn_fetch_fill_mean" not in eng.metrics.summary()
+        assert eng.pool.exact_width == 4 and eng.blocks_per_seq == 10
+        assert eng._attn_group(1) == pa.fetch_group(
+            bs=8, dh=16, hkv=4, qg=1, page_dtype=eng.pool.dtype, nb=10,
+            positions=24) == (3, 4)
+        attrs = eng._program_attrs(None, 1)
+        assert (attrs["attn_pages"], attrs["attn_heads"]) == (3, 4)
+        # rows that end before positions 1, 32, 33, 100 and 96 hold 1, 4, 1,
+        # 1 and 4 exact pages and 0, 0, 1, 3 and 2 summary pages, in 1, 2,
+        # 2, 3 and 2 groups of 3 slots (at 96 the group of entries 3-5 holds
+        # the window's last page AND both summary pages: it counts once);
+        # the sixth entry is not a live row
+        eng._observe_attention([None] * 5,
+                               np.array([1, 32, 33, 100, 96, 7]), 1)
+        want = (1 / 3 + 4 / 6 + 2 / 6 + 4 / 9 + 6 / 6) / 5
+        assert eng.metrics.summary()["attn_fetch_fill_mean"] \
+            == pytest.approx(want)
+        # a step whose rows hold nothing yet adds nothing
+        eng._observe_attention([None] * 2, np.array([0, 0]), 16)
+        assert eng.metrics.summary()["attn_fetch_fill_mean"] \
+            == pytest.approx(want)
+    # and the engine feeds it, at the group the module states: the whole
+    # table of 10 entries is one group of the tiny model's pages of 8.
+    # Prompt 30 in chunks of 16, 5 tokens out: steps that end before 16, 30,
+    # 31, 32 (2, 4, 4, 4 exact pages), then 33, 34 in the next window (1
+    # exact page, 8 summary rows in 1 page)
+    eng = engine(model, weights[1])
+    assert eng._attn_group(1) == (10, 4)
+    eng.submit(np.arange(30, dtype=np.int32), 5)
+    eng.run_until_complete()
+    assert eng.metrics.attn_fetch_row_steps == 6
+    assert eng.metrics.summary()["attn_fetch_fill_mean"] == pytest.approx(
+        (2 + 4 + 4 + 4 + 2 + 2) / 10 / 6)
 
 
 def test_scopes_and_kernel_name_are_in_the_compiled_step(model, weights):

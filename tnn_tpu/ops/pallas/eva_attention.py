@@ -19,11 +19,18 @@ and the first ``sum_lens`` summary rows. With no summary pages and a window
 that never ends it is plain paged attention (``paged_attention``), which
 this kernel shares its layout helpers, page write and masking rules with.
 
-Kernel (``tnn_eva_attention``): grid ``(B, H_kv / heads_per_step, table
-entries / pages_per_step)``. A grid step fetches ``pages_per_step`` pages of
-``heads_per_step`` heads each (contiguous in the pool's layout, so one DMA a
-page) and folds them into the running softmax; dead pages clamp to the last
-live page of their segment, so their DMAs are elided.
+Kernel (``tnn_eva_attention``): grid ``(B, H_kv / heads, table entries /
+pages)``, ``(pages, heads)`` from ``paged_attention.fetch_group`` at
+``GROUP_POSITIONS`` key positions a group, as the paged and the latent
+kernels' (EvaByte: one page of all 32 heads in the decode form, one page of
+8 heads under a chunk of 256). A grid step fetches ``pages`` consecutive
+table entries of ``heads`` heads each (contiguous in the pool's layout, so
+one DMA a page) and makes ONE running-softmax update a head over the group's
+``pages * bs`` slots: one score product batched over the head block, one
+mask, one ``m / l / acc`` update, one value product. The mask is a slot's
+own: a group may straddle ``n_exact``. A group with no live slot is skipped
+whole; dead pages repeat the last live page of their segment and dead groups
+the group before them (``_fetch_table``), so their DMAs are elided.
 """
 from __future__ import annotations
 
@@ -33,23 +40,34 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import (_NEG_INF, _from_head_major, _gather_pages,
-                              _to_head_major, write_rows)
+                              _to_head_major, fetch_group, write_rows)
 from .runtime import interpret_default
 
-PAGES_PER_STEP = 4
-HEADS_PER_STEP = 8
+# key positions a grid step attends over (``fetch_group``'s ``positions``).
+# At EvaByte's shape (32 heads of 128 over pages of 128) 128 gives ONE page of
+# all 32 heads a grid step: one contiguous 1 MB DMA for K, one for V, and no
+# dead page is ever fetched; 256 (2 pages x 16 heads) and 512 (4 x 8) walk
+# the same 256 grid steps a layer and read 9% and 30% slower (PERF.md, PR 50)
+GROUP_POSITIONS = 128
 
 
 def _kernel(tables_ref, elens_ref, slens_ref, qlens_ref, layer_ref, q_ref,
-            *refs, scale, bs, g, qw, n_exact, pages, heads):
+            *refs, scale, bs, g, qw, n_exact, pages):
+    """One grid step: ``pages`` consecutive table entries of row b, every
+    head of the step's head block, ONE running-softmax update a head over
+    the group's ``pages * bs`` slots. ``refs``: per page slot its K and V
+    block ``(heads, bs, Dh)``, the output, the m / l / acc scratch."""
     del layer_ref, tables_ref       # consumed by the index maps
     kv = refs[:2 * pages]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * pages:]
     b, j, nj = pl.program_id(0), pl.program_id(2), pl.num_programs(2)
+    t = pages * bs
+    split = n_exact * bs            # the table position of the first summary
 
     @pl.when(j == 0)
     def _init():
@@ -58,38 +76,40 @@ def _kernel(tables_ref, elens_ref, slens_ref, qlens_ref, layer_ref, q_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     elen, slen, q_live = elens_ref[b], slens_ref[b], qlens_ref[b]
-    for i in range(pages):
-        e = j * pages + i                       # this page's table entry
-        is_sum = e >= n_exact
-        base = jnp.where(is_sum, e - n_exact, e) * bs
-        k_ref, v_ref = kv[2 * i], kv[2 * i + 1]
+    first = j * t                   # the table position of the group's first
 
-        @pl.when(base < jnp.where(is_sum, slen, elen))
-        def _page(is_sum=is_sum, base=base, k_ref=k_ref, v_ref=v_ref):
-            kpos = base + jax.lax.broadcasted_iota(jnp.int32, (qw * g, bs), 1)
-            trow = jax.lax.broadcasted_iota(jnp.int32, (qw * g, 1), 0)
-            if g > 1:
-                trow = jax.lax.div(trow, jnp.int32(g))
-            # a summary row is live below sum_len for every query of the
-            # step; an exact row is causal: token t sits at window-relative
-            # position elen - q_live + t
-            limit = jnp.where(is_sum, slen - 1, elen - q_live + trow)
-            mask = (kpos <= limit) & (trow < q_live)
-            for h in range(heads):
-                q, k, v = q_ref[h], k_ref[h], v_ref[h]
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(mask, s, _NEG_INF)
-                m_prev, l_prev = m_scr[h], l_scr[h]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-                alpha = jnp.exp(m_prev - m_new)
-                l_scr[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_scr[h] = m_new
+    def load(slots):                # the group's K (or V): (heads, t, Dh)
+        xs = [r[...] for r in slots]
+        return xs[0] if pages == 1 else jnp.concatenate(xs, axis=1)
+
+    # a group with no live slot is skipped whole (its fetches repeat the last
+    # live group's, ``_fetch_table``); a group may straddle ``n_exact``
+    @pl.when((first < elen) | ((first + t > split) & (first < split + slen)))
+    def _group():
+        q, k, v = q_ref[...], load(kv[0::2]), load(kv[1::2])
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        trow = jax.lax.broadcasted_iota(jnp.int32, (qw * g, 1), 0)
+        if g > 1:
+            trow = jax.lax.div(trow, jnp.int32(g))
+        # slot ``pos`` of the table (exact positions, then summary rows): a
+        # summary row is live below sum_len for every query of the step; an
+        # exact row is causal: token t sits at window-relative position
+        # elen - q_live + t. Rows past q_live see nothing
+        limit = jnp.where(pos >= split, split + slen - 1,
+                          elen - q_live + trow)
+        mask = ((pos <= limit) & (trow < q_live))[None]     # (1, Q*g, t)
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
     @pl.when(j == nj - 1)
     def _final():
@@ -98,29 +118,73 @@ def _kernel(tables_ref, elens_ref, slens_ref, qlens_ref, layer_ref, q_ref,
                       ).astype(o_ref.dtype)
 
 
+def _fetch_table(tables, exact_lens, sum_lens, n_exact, bs, pages):
+    """The table the kernel walks, ``pages`` entries a grid step: padded to
+    whole groups, every dead entry replaced by the one its DMA should
+    repeat. A dead page repeats the last live page of its segment (the first
+    page of a segment with none), and a group with no live page repeats the
+    group before it slot for slot: a block index that repeats lets the
+    pipeline elide the DMA. (``paged_attention._fetch_table``'s rule over
+    two segments; computed once a step program, not by every index map.)"""
+    nb = tables.shape[1]
+    width = -(-nb // pages) * pages
+    e = np.arange(width, dtype=np.int32)[None, :]
+    last_e = jnp.clip(jax.lax.div(exact_lens + (bs - 1), bs), 1,
+                      n_exact)[:, None] - 1
+    last_s = jnp.clip(jax.lax.div(sum_lens + (bs - 1), bs), 1,
+                      max(nb - n_exact, 1))[:, None] - 1
+    own = jnp.where(e < n_exact, jnp.minimum(e, last_e),
+                    n_exact + jnp.minimum(e - n_exact, last_s))
+    live = jnp.where(e < n_exact, e * bs < exact_lens[:, None],
+                     (e - n_exact) * bs < sum_lens[:, None])
+    group = np.arange(width // pages, dtype=np.int32)[None, :]
+    src = jax.lax.cummax(jnp.where(
+        live.reshape(-1, width // pages, pages).any(axis=2), group, 0),
+        axis=1)
+    at = jnp.take_along_axis(own, jnp.repeat(src, pages, axis=1) * pages
+                             + e % pages, axis=1)
+    return jnp.maximum(jnp.take_along_axis(tables, at, axis=1), 0)
+
+
+def pages_fetched(exact_lens, sum_lens, n_exact, bs, pages):
+    """``(live pages, page slots fetched)`` a row (numpy arrays) of a launch
+    whose grid steps take ``pages`` table entries: the live pages of both
+    segments, and ``pages`` slots for every group that holds one (a group
+    that straddles ``n_exact`` with live pages of both counts once). The
+    kernel's own rule (``_kernel``'s live groups), for the engine's
+    ``attn_fetch_fill_mean``."""
+    live_e = -(-np.asarray(exact_lens, np.int64) // bs)
+    live_s = -(-np.asarray(sum_lens, np.int64) // bs)
+    first_s = n_exact // pages          # the group of the first summary page
+    groups = -(-live_e // pages) + np.where(
+        live_s > 0, (n_exact + live_s - 1) // pages - first_s + 1, 0)
+    groups -= (live_s > 0) & (live_e > 0) & ((live_e - 1) // pages == first_s)
+    return live_e + live_s, groups * pages
+
+
+# inlined for the reason ``_paged_attention_pallas`` is: one trace and one
+# kernel lowering a step program, not one a layer
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("n_exact", "scale", "interpret",
+                                    "positions"))
 def _eva_attention_pallas(q, pages_k, pages_v, tables, exact_lens, sum_lens,
-                          q_lens, n_exact, layer, scale, interpret,
-                          pages_per_step, heads_per_step):
+                          q_lens, layer, *, n_exact, scale, interpret,
+                          positions):
     b, qw, h, dh = q.shape
     _, _, hkv, bs, _ = pages_k.shape
     g = h // hkv
-    heads = math.gcd(heads_per_step, hkv)
-    pages = max(1, min(pages_per_step, tables.shape[1]))
-    pad = -tables.shape[1] % pages
-    if pad:     # whole steps: the padding lies past every summary length
-        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    pages, heads = fetch_group(bs=bs, dh=dh, hkv=hkv, qg=qw * g,
+                               page_dtype=pages_k.dtype, nb=tables.shape[1],
+                               positions=positions)
+    elens, slens = exact_lens.astype(jnp.int32), sum_lens.astype(jnp.int32)
+    tables = _fetch_table(tables.astype(jnp.int32), elens, slens, n_exact,
+                          bs, pages)
     nb = tables.shape[1]
     qg = _to_head_major(q, hkv)                     # (B, H_kv, Q*g, Dh)
 
     def kv_index(i):
         def index(bi, hi, j, tbl, el, sl, ql, ly):
-            e = j * pages + i
-            live_e = (jnp.maximum(el[bi], 1) + bs - 1) // bs
-            live_s = (jnp.maximum(sl[bi], 1) + bs - 1) // bs
-            at = jnp.where(e < n_exact, jnp.minimum(e, live_e - 1),
-                           n_exact + jnp.minimum(e - n_exact, live_s - 1))
-            return (ly[0], jnp.maximum(tbl[bi, jnp.minimum(at, nb - 1)], 0),
-                    hi, 0, 0)
+            return (ly[0], tbl[bi, j * pages + i], hi, 0, 0)
         return index
 
     def q_index(bi, hi, j, tbl, el, sl, ql, ly):
@@ -142,16 +206,15 @@ def _eva_attention_pallas(q, pages_k, pages_v, tables, exact_lens, sum_lens,
                         pltpu.VMEM((heads, qw * g, dh), jnp.float32)])
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, bs=bs, g=g, qw=qw,
-                          n_exact=n_exact, pages=pages, heads=heads),
+                          n_exact=n_exact, pages=pages),
         name="tnn_eva_attention",       # what the device profile shows
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, qw * g, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), exact_lens.astype(jnp.int32),
-      sum_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), *operands)
+    )(tables, elens, slens, q_lens.astype(jnp.int32),
+      jnp.reshape(layer, (1,)), *operands)
     return _from_head_major(out, qw)
 
 
@@ -186,9 +249,7 @@ def _eva_attention_xla(q, pages_k, pages_v, tables, exact_lens, sum_lens,
 def eva_attention(q, pages_k, pages_v, tables, exact_lens, sum_lens, *,
                   n_exact: int, q_lens=None, layer=0,
                   scale: Optional[float] = None, backend: str = "auto",
-                  interpret: Optional[bool] = None,
-                  pages_per_step: int = PAGES_PER_STEP,
-                  heads_per_step: int = HEADS_PER_STEP):
+                  interpret: Optional[bool] = None):
     """One softmax over a row's exact pages and its summary pages.
 
     q : (B, H, Dh), the decode form, or (B, Q, H, Dh) with ``q_lens[b]`` live
@@ -222,9 +283,10 @@ def eva_attention(q, pages_k, pages_v, tables, exact_lens, sum_lens, *,
         elif backend == "pallas":
             out = _eva_attention_pallas(
                 q, pages_k, pages_v, tables, exact_lens, sum_lens, q_lens,
-                n_exact, layer, scale,
-                interpret_default() if interpret is None else interpret,
-                pages_per_step, heads_per_step)
+                jnp.asarray(layer, jnp.int32), n_exact=n_exact,
+                scale=float(scale),
+                interpret=interpret_default() if interpret is None
+                else interpret, positions=GROUP_POSITIONS)
         else:
             raise ValueError(f"unknown eva-attention backend {backend!r}")
     return out[:, 0] if was_3d else out

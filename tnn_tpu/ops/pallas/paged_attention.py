@@ -198,8 +198,10 @@ def fetch_group(*, bs, dh, hkv, qg, page_dtype, nb,
     further heads to fill a grid step with);
     ``heads`` is the largest divisor of
     ``hkv`` whose group fits ``_VMEM_BUDGET`` (``group_vmem_bytes``), and
-    only a group too large with ONE head gives pages up. The launch and the
-    engine's ``attn_fetch_fill_mean`` both ask this function."""
+    only a group too large with ONE head gives pages up. Three launches ask
+    this function (``_launch`` here, ``mla_attention``'s, ``eva_attention``'s
+    over its table of two segments), and the engine's ``attn_fetch_fill_mean``
+    asks it for whichever the step runs (``InferenceEngine._attn_group``)."""
     pages = max(1, min(positions // bs, nb))
     size = functools.partial(group_vmem_bytes, bs=bs, dh=dh, qg=qg,
                              page_dtype=page_dtype)

@@ -1078,14 +1078,16 @@ class InferenceEngine:
                                 at=req.cache_len)
 
     def _attn_group(self, qw: int):
-        """``(pages, heads)`` of a grid step of the paged kernel in the step
-        program ``qw`` tokens wide: what the kernel's own launch derives
-        from the same shapes (a TP shard's pool holds ``1 / tp`` of the
-        heads; the kernel's "head" is a page row, ``kv_lane_pack`` KV heads
-        side by side, attended by that many query groups). None where that
-        kernel is not the step's attention (a windowed model), or runs over
-        two groups of page with a walk of its own a kind of layer."""
-        if self.pool.window or self.pool.sliding:
+        """``(pages, heads)`` of a grid step of the step's attention kernel
+        in the step program ``qw`` tokens wide: what the kernel's own launch
+        derives from the same shapes (a TP shard's pool holds ``1 / tp`` of
+        the heads; the kernel's "head" is a page row, ``kv_lane_pack`` KV
+        heads side by side, attended by that many query groups). The paged
+        kernel's, the latent kernel's, or a windowed model's
+        (``tnn_eva_attention``, whose table is the exact segment and the
+        summaries); None where the kernel runs over two groups of page with
+        a walk of its own a kind of layer."""
+        if self.pool.sliding:
             return None
         group = self._attn_groups.get(qw)
         if group is None:
@@ -1097,6 +1099,9 @@ class InferenceEngine:
                 from ..ops.pallas import mla_attention as mla
                 tile = mla.query_tile(qw, model.num_heads)
                 kw["positions"] = mla.GROUP_POSITIONS
+            elif pool.window:
+                from ..ops.pallas import eva_attention as eva
+                kw["positions"] = eva.GROUP_POSITIONS
             group = self._attn_groups[qw] = fetch_group(
                 bs=bs, dh=width, hkv=rows // self.tp,
                 qg=tile * (model.num_heads // rows),
@@ -1109,11 +1114,13 @@ class InferenceEngine:
         """The attention counters of one paged step ``qw`` tokens wide whose
         row i's tokens end just before position ``ends[i]`` (``q_lens`` the
         launch's live tokens a row; None: the decode form, one tile wide).
-        Plain paged attention: the fill of the page slots the kernel fetches
-        in groups, and of a launch wider than one query tile the tiles the
-        kernel computes over the tiles it holds. A windowed model: the share
-        of the window's exact positions each row attends over, and the pool's
-        rows that hold summaries."""
+        The fill of the page slots the kernel fetches in groups (a windowed
+        model: over both segments of its table, the exact pages by the
+        window-relative length and the summary pages by the summary rows
+        read), and of a plain paged launch wider than one query tile the
+        tiles the kernel computes over the tiles it holds. A windowed model
+        besides: the share of the window's exact positions each row attends
+        over, and the pool's rows that hold summaries."""
         pool = self.pool
         if q_lens is not None and not (pool.window or pool.latent):
             # the kernel module's own rule, at the launch's shape: the
@@ -1129,9 +1136,20 @@ class InferenceEngine:
         group = self._attn_group(qw)
         if group is not None:
             pages, _ = group
-            live = -(-np.asarray(ends[:len(rows)]) // pool.block_size)
-            live = live[live > 0]
-            self.metrics.observe_attn_fetch(live / (-(-live // pages) * pages))
+            upto = np.asarray(ends[:len(rows)])
+            if pool.window:
+                # a step's tokens lie in one window: what ``_eva_paged``
+                # hands the kernel as ``exact_lens`` and ``sum_lens``
+                from ..ops.pallas.eva_attention import pages_fetched
+                last = np.maximum(upto - 1, 0)
+                live, slots = pages_fetched(
+                    np.where(upto > 0, last % pool.window + 1, 0),
+                    last // pool.window * (pool.window // pool.chunk),
+                    pool.exact_width, pool.block_size, pages)
+            else:
+                live = -(-upto // pool.block_size)
+                slots = -(-live // pages) * pages
+            self.metrics.observe_attn_fetch(live[live > 0] / slots[live > 0])
         if pool.sliding:
             held = sum(len(r.window_table) for r in self.scheduler.running)
             self.metrics.observe_window_step(
